@@ -181,8 +181,8 @@ def test_peer_conn_recv_fails_fast_after_peer_death():
     not wait out the full per-tag timeout: the reader thread's death
     broadcast only reaches queues that already exist, and the send side
     already failed fast on self.dead — the asymmetry cost an abrupt-kill
-    survivor two consecutive 30s timeout rounds (HEAL_DRILL_r05
-    sigkill_control) while its peer detected the death in under a second.
+    survivor two consecutive 30s timeout rounds (the sigkill_control
+    drill) while its peer detected the death in under a second.
     A message delivered before the death must still be consumable."""
     import socket as socket_mod
     import time
@@ -502,6 +502,48 @@ def test_allreduce_quantized_accuracy(store):
     # must be meaningfully accurate, not garbage
     err = np.abs(results[0] - expected).mean() / (np.abs(expected).mean() + 1e-9)
     assert err < 0.02, f"mean relative error too high: {err}"
+    for g in groups:
+        g.shutdown()
+
+
+def test_allreduce_quantized_buckets_reach_the_wire_in_issue_order(store):
+    """Several quantized collectives in flight on one PG (DDP's gradient
+    buckets) must pair across replicas by ISSUE order even when their
+    quantize stages finish in another order — each runs on its own thread
+    and the PG numbers ops as they are called. Rank 0 stalls its first
+    bucket's quantize stage; unordered, its second bucket takes the first
+    wire slot and the ranks exchange mismatched payloads."""
+    import time
+
+    from torchft_tpu.collectives import allreduce_quantized
+
+    ws = 2
+    groups = _make_group(store, ws, prefix="quant-order")
+    # Different sizes per bucket: a swap cannot pass by accident.
+    sizes = [4096, 1536, 7168]
+    data = [
+        [np.full(n, float(rank + 1 + b), np.float32) for b, n in enumerate(sizes)]
+        for rank in range(ws)
+    ]
+
+    def run(rank):
+        arrs = [a.copy() for a in data[rank]]
+        stall = (lambda *_: time.sleep(0.5)) if rank == 0 else None
+        works = [
+            allreduce_quantized(
+                groups[rank], [a], on_local_quantized=stall if b == 0 else None
+            )
+            for b, a in enumerate(arrs)
+        ]
+        for w in works:
+            w.wait(timeout=30)
+        return arrs
+
+    results = _run_parallel([lambda r=r: run(r) for r in range(ws)])
+    for arrs in results:
+        for b, arr in enumerate(arrs):
+            want = data[0][b] + data[1][b]
+            np.testing.assert_allclose(arr, want, rtol=0.02)
     for g in groups:
         g.shutdown()
 

@@ -1,9 +1,8 @@
-"""Reproducible OS-process fault drills (the HEAL_DRILL artifacts' harness).
+"""Reproducible OS-process fault drills.
 
 Each drill launches real trainer processes under the keep-alive runner
 against an in-proc C++ lighthouse, injects the fault, and prints ONE
-JSON line with the outcome. These are the exact harnesses behind
-``HEAL_DRILL_r05.json``:
+JSON line with the outcome:
 
     python tools/drills.py soak          # 4 SIGKILLs, DDP int4+EF wire
     python tools/drills.py elastic-up    # third group joins mid-run
@@ -19,8 +18,7 @@ JSON line with the outcome. These are the exact harnesses behind
     python tools/drills.py model-heal --model moe|pipeline|ulysses
 
 elastic-up runs UNPACED (batch 8, full step rate): instead of slowing
-the steady groups so the joiner's import+compile lands mid-run (the r4
-crutch, docs/ROUND4.md §10), the run is simply long enough (default
+the steady groups so the joiner's import+compile lands mid-run, the run is simply long enough (default
 1200 steps) to outlive the joiner's pre-warm latency the way any real
 run would, and the report's joiner_first_step proves the mid-run join
 from the artifact itself.  elastic-down keeps batch 512 only to bound

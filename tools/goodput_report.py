@@ -4,7 +4,7 @@
 Where ``recovery_report.py`` decomposes individual failure episodes,
 this audits the **time-accounting plane**: every committed manager
 journals a ``goodput_window`` event per commit gate, carrying the
-closed-taxonomy split (``telemetry.BADPUT_KINDS``) of the wall-clock
+closed-classification split (``telemetry.BADPUT_KINDS``) of the wall-clock
 window since the previous gate. This tool stitches those windows into
 per-replica and fleet accounts and proves the central invariant:
 
@@ -281,7 +281,7 @@ def analyze(events: List[Dict[str, Any]]) -> Dict[str, Any]:
 
 def check(report: Dict[str, Any]) -> List[str]:
     """Invariant violations (empty = pass): every tiling problem from the
-    audit, plus account sanity (no negative kinds, taxonomy closure)."""
+    audit, plus account sanity (no negative kinds, classification closure)."""
     errs = list(report["problems"])
     for rid, row in report["replicas"].items():
         for k, v in row["badput_s"].items():

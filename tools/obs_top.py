@@ -109,7 +109,7 @@ def _heal_s(digest: Dict[str, Any]) -> Optional[float]:
 def _acct_view(digest: Dict[str, Any]) -> tuple:
     """``(ledger goodput %, worst-badput-kind glyph)`` from the digest's
     cumulative ``acct`` vector (positional by BADPUT_KINDS). ``(None,
-    "-")`` for pre-taxonomy digests or before any accounted second."""
+    "-")`` for pre-classification digests or before any accounted second."""
     acct = digest.get("acct")
     if not isinstance(acct, list) or len(acct) < len(BADPUT_KINDS):
         return None, "-"
@@ -396,7 +396,7 @@ def check_frame(fleet: Dict[str, Any], frame: str,
                     f"not rendered in its SIGNAL column")
         # Time-accounting columns: a digest that carries the cumulative
         # acct vector must render its ledger goodput cell and the
-        # worst-badput-kind glyph; pre-taxonomy digests render dashes.
+        # worst-badput-kind glyph; pre-classification digests render dashes.
         ledger_gp, worst_glyph = _acct_view(replicas[rid].get("digest") or {})
         if ledger_gp is not None:
             row = next(ln for ln in frame_lines if ln.startswith(shown))

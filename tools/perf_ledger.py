@@ -559,8 +559,6 @@ def import_legacy(path: Optional[str] = None) -> int:
             f for f in os.listdir(REPO)
             if f.startswith("BENCH_r0") and f.endswith(".json")
         ), _bench_round_records),
-        (["BENCH_TPU_r03.json"], lambda fn, doc: _bench_round_records(
-            fn, {"parsed": doc}, prefix="tpu.", family="tpu")),
         (["BENCH_PG_allreduce.json"], _pg_records),
         (["BENCH_FLEET.json", "BENCH_FLEET_quick.json"], _fleet_records),
         (["BENCH_WAN.json"], _wan_records),

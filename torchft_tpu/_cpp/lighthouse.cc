@@ -47,7 +47,7 @@ bool known_signal_source(const std::string& s) {
   return false;
 }
 
-// Closed badput taxonomy — positionally mirrors telemetry.BADPUT_KINDS on
+// Closed badput classification — positionally mirrors telemetry.BADPUT_KINDS on
 // the Python side (lint rule badput-kinds). The digest's "acct" array is
 // indexed by this order; index 1 ("compute") is the goodput numerator.
 const char* const kBadputKindNames[] = {
@@ -68,7 +68,7 @@ bool hard_signal_source(const std::string& s) {
 }
 
 // A digest's acct vector, when complete: pre-namespace digests (or ones
-// from a client older than the taxonomy) simply don't contribute.
+// from a client older than the classification) simply don't contribute.
 bool digest_acct(const Json& digest, double out[kNumBadputKinds]) {
   const Json& a = digest.get("acct");
   if (!a.is_array() || a.arr.size() < static_cast<size_t>(kNumBadputKinds))

@@ -185,7 +185,7 @@ class MedianTracker {
   std::multiset<double> lo_, hi_;
 };
 
-// Size of the closed badput taxonomy (telemetry.BADPUT_KINDS); the names
+// Size of the closed badput classification (telemetry.BADPUT_KINDS); the names
 // live in lighthouse.cc (kBadputKindNames, lint-mirrored positionally
 // against the Python tuple). The digest's "acct" array is indexed by it.
 constexpr int kNumBadputKinds = 10;

@@ -1,7 +1,7 @@
 """Shared scaffolding for the checkpoint-transport bench harnesses
 (pg_transport_bench / http_transport_bench): synthetic train-state
 builder, payload accounting, and the content checksum both harnesses
-compare — kept in ONE place so the HEAL_DRILL numbers stay comparable
+compare — kept in ONE place so the heal-bench numbers stay comparable
 across transports."""
 
 from __future__ import annotations
@@ -33,7 +33,6 @@ def build_state(
     rows = max(per_leaf // cols, 1)
     if sharded:
         import jax
-        import jax.numpy as jnp
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
         devs = jax.devices()[:n_devices]
@@ -42,8 +41,10 @@ def build_state(
         sharding = NamedSharding(mesh, P("fsdp", None))
 
         def leaf(i: int):
+            # From host numpy: each device receives only its rows (a
+            # jnp.full would first build the whole leaf on device 0).
             return jax.device_put(
-                jnp.full((rows, cols), fill + i, jnp.float32), sharding
+                np.full((rows, cols), fill + i, np.float32), sharding
             )
 
         leaves = [leaf(i) for i in range(n_leaves)]

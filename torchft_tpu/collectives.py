@@ -15,6 +15,7 @@ quantizing on-device before the device->host pull.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 from typing import Callable, List, Sequence, Tuple
@@ -28,22 +29,70 @@ from torchft_tpu.work import DummyWork, FutureWork, Work
 BLOCK = 512  # values per quantization scale
 
 
-def _spawn_collective(fn) -> "concurrent.futures.Future":
+class _WireOrder:
+    """Admits one process group's quantized collectives to the wire in
+    ISSUE order. Each collective runs on its own thread and quantizes at
+    its own pace, but the PG pairs ops across replicas by a sequence number
+    taken when the op is called: with several buckets in flight, two that
+    reach the wire in different orders on two replicas would exchange each
+    other's payloads (seen on the chip: 16 gradient buckets of a 125M
+    model, device-quantized on one replica and host-quantized on the
+    other, failed every step with reshape errors)."""
+
+    def __init__(self) -> None:
+        self._cv = threading.Condition()
+        self._issued = 0
+        self._serving = 0
+
+    def take(self) -> int:
+        with self._cv:
+            ticket = self._issued
+            self._issued += 1
+            return ticket
+
+    @contextlib.contextmanager
+    def turn(self, ticket: int):
+        """Holds the wire for ``ticket``; a no-op pass-through once that
+        ticket has already been served."""
+        with self._cv:
+            self._cv.wait_for(lambda: self._serving >= ticket)
+        try:
+            yield
+        finally:
+            with self._cv:
+                self._serving = max(self._serving, ticket + 1)
+                self._cv.notify_all()
+
+
+_wire_order_lock = threading.Lock()
+
+
+def _spawn_collective(pg: ProcessGroup, fn) -> "concurrent.futures.Future":
     """One daemon thread per in-flight quantized collective. A bounded pool
     would deadlock when several ranks live in one process (tests, parameter
     server): every rank's pipeline must make progress concurrently for any
-    alltoall to complete."""
+    alltoall to complete.
+
+    ``fn(wire)`` must run its PG ops inside ``with wire():`` — its turn in
+    the issue order of ``pg`` (the ticket is taken here, on the caller's
+    thread)."""
     import concurrent.futures
 
+    with _wire_order_lock:
+        order = pg.__dict__.setdefault("_quant_wire_order", _WireOrder())
+    ticket = order.take()
     fut: concurrent.futures.Future = concurrent.futures.Future()
 
     def run() -> None:
-        if not fut.set_running_or_notify_cancel():
-            return
         try:
-            fut.set_result(fn())
+            if fut.set_running_or_notify_cancel():
+                fut.set_result(fn(lambda: order.turn(ticket)))
         except BaseException as e:  # noqa: BLE001 - delivered via the future
             fut.set_exception(e)
+        finally:
+            # Died before the wire (or cancelled): still pass the turn on.
+            with order.turn(ticket):
+                pass
 
     threading.Thread(target=run, daemon=True, name="quant-collective").start()
     return fut
@@ -193,6 +242,17 @@ def _unflatten_into(
         offset += n
 
 
+def device_quantize() -> bool:
+    """Whether jax-array payloads quantize ON DEVICE (Pallas kernels) or
+    through the host numpy quantizer — the one rule, also journaled per
+    allreduce so a run can prove which branch it took."""
+    import jax
+
+    return jax.default_backend() == "tpu" or knobs.get_bool(
+        "TORCHFT_FORCE_DEVICE_QUANT"
+    )
+
+
 def allreduce_quantized_jax(
     pg: ProcessGroup,
     arrays: Sequence["jax.Array"],  # noqa: F821 - imported lazily
@@ -268,8 +328,7 @@ def allreduce_quantized_jax(
     # device path anyway (Pallas interpreter off-TPU; a no-op on TPU,
     # where the device path is already taken): the cross-path
     # wire-equality test drives it.
-    force_device = knobs.get_bool("TORCHFT_FORCE_DEVICE_QUANT")
-    host_quant = jax.default_backend() != "tpu" and not force_device
+    host_quant = not device_quantize()
 
     # Device path: dispatch the quantize kernels NOW, on the caller's
     # thread. Async dispatch returns immediately, but enqueues the kernels
@@ -277,8 +336,7 @@ def allreduce_quantized_jax(
     # caller's next training window. The deferred host pull then overlaps
     # that window; dispatched lazily from the collective thread instead,
     # the kernels would queue behind the whole next window and the "pull"
-    # would spend its time waiting on unrelated compute (measured 24 s of
-    # a 3 s transfer in BENCH_TPU_r03).
+    # would spend its time waiting on unrelated compute.
     q_chunks = None
     n_elems = 0
     if not host_quant:
@@ -288,7 +346,7 @@ def allreduce_quantized_jax(
         # multi-second wire pipeline too.
         flat = None
 
-    def run() -> List["jax.Array"]:
+    def run(wire) -> List["jax.Array"]:
         with trace_span("torchft::collectives::quantize_pull"):
             if host_quant:
                 flat_host = np.asarray(flat, dtype=np.float32)
@@ -298,7 +356,7 @@ def allreduce_quantized_jax(
                 q_host, s_host, n = Q.pull_transfer_chunks(
                     q_chunks, n_elems, bits
                 )
-        with trace_span("torchft::collectives::wire"):
+        with wire(), trace_span("torchft::collectives::wire"):
             reduced = _quantized_wire_pipeline(pg, q_host, s_host, n, bits)
         with trace_span("torchft::collectives::dequant_push"):
             if isinstance(reduced, np.ndarray):
@@ -352,7 +410,7 @@ def allreduce_quantized_jax(
             # price of the overlap.
         return outs
 
-    return FutureWork(_spawn_collective(run))
+    return FutureWork(_spawn_collective(pg, run))
 
 
 def reduce_scatter_quantized(
@@ -374,7 +432,7 @@ def reduce_scatter_quantized(
     ws = pg.size()
     arrays = list(arrays)
 
-    def run():
+    def run(wire):
         flat, _sizes = _flatten(arrays)
         n = flat.size
         if ws <= 1:
@@ -385,21 +443,24 @@ def reduce_scatter_quantized(
         counts = [len(c) for c in np.array_split(np.arange(blocks), ws)]
         starts = np.concatenate([[0], np.cumsum(counts)]) * BLOCK
         start, end = int(starts[me]), int(min(starts[me + 1], n))
-        if blocks < ws:
-            # Tiny payload: gather-all, reduce locally, slice my range.
-            gathered = pg.allgather([q_host, s_host]).wait()
-            acc = np.zeros(n, np.float32)
-            for g_q, g_s in gathered:
-                acc += dequantize_blockwise(g_q, g_s, n, bits)
-            shard = acc[start:end]
-        else:
-            acc = _alltoall_chunk_reduce(pg, q_host, s_host, counts, bits)
-            shard = acc[: end - start]
+        with wire():
+            if blocks < ws:
+                # Tiny payload: gather-all, reduce locally, slice my range.
+                gathered = pg.allgather([q_host, s_host]).wait()
+                acc = np.zeros(n, np.float32)
+                for g_q, g_s in gathered:
+                    acc += dequantize_blockwise(g_q, g_s, n, bits)
+                shard = acc[start:end]
+            else:
+                acc = _alltoall_chunk_reduce(
+                    pg, q_host, s_host, counts, bits
+                )
+                shard = acc[: end - start]
         if op == ReduceOp.AVG:
             shard = shard / ws
         return shard, (start, end)
 
-    return FutureWork(_spawn_collective(run))
+    return FutureWork(_spawn_collective(pg, run))
 
 
 def bucketize(arrays: Sequence[np.ndarray], cap_bytes: int) -> List[List[int]]:
@@ -573,7 +634,7 @@ def allreduce_quantized(
 
     from torchft_tpu.telemetry import trace_span
 
-    def run() -> List[np.ndarray]:
+    def run(wire) -> List[np.ndarray]:
         # Same span names as the device (jax) path so bench/telemetry
         # consumers see one uniform phase decomposition: "quantize_pull"
         # is the host quantize here (there is no device pull), "wire" the
@@ -585,7 +646,7 @@ def allreduce_quantized(
             q_host, s_host = quantize_blockwise(flat, bits)
             if on_local_quantized is not None:
                 on_local_quantized(flat, q_host, s_host)
-        with trace_span("torchft::collectives::wire"):
+        with wire(), trace_span("torchft::collectives::wire"):
             reduced = _quantized_wire_pipeline(pg, q_host, s_host, n, bits)
         with trace_span("torchft::collectives::dequant_push"):
             if isinstance(reduced, np.ndarray):
@@ -598,4 +659,4 @@ def allreduce_quantized(
             _unflatten_into(arrays, result, sizes)
         return list(arrays)
 
-    return FutureWork(_spawn_collective(run))
+    return FutureWork(_spawn_collective(pg, run))

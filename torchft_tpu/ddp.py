@@ -58,7 +58,7 @@ class DistributedDataParallel:
 
         - device-array grads on TPU ride the manager's DEVICE quantize
           path (Pallas kernels shrink the payload to int8/int4 *before*
-          the device->host pull, so PCIe/tunnel bytes drop 4-8x along
+          the device->host pull, so PCIe bytes drop 4-8x along
           with the wire) — but only when ``error_feedback`` is off: the
           device path has no host-side quantize moment to hook, so an
           EF-enabled DDP takes the host path everywhere rather than

@@ -364,19 +364,7 @@ _ALL = [
         "120",
         "Preemption drain grace window (seconds) shared by every layer that budgets a SIGTERM->SIGKILL gap: orchestration/k8s.py renders it as `terminationGracePeriodSeconds`, the chaos `preempt` kind defaults its `grace=` param to it, and tools/elastic_drill.py waits this long for a drained exit before hard-killing.",
     ),
-    # -- backend probe / collectives --------------------------------------
-    _k(
-        "TORCHFT_PROBE_TIMEOUT",
-        "float",
-        None,
-        "Override the TPU backend-probe timeout (seconds).",
-    ),
-    _k(
-        "TORCHFT_PROBE_NO_CACHE",
-        "bool",
-        None,
-        "Truthy: ignore the cached backend-probe verdict and probe fresh.",
-    ),
+    # -- collectives ------------------------------------------------------
     _k(
         "TORCHFT_FORCE_DEVICE_QUANT",
         "bool",
@@ -447,13 +435,6 @@ _ALL = [
         scope="cpp",
     ),
     # -- repo-root entry script (documented here, read outside the pkg) ---
-    _k(
-        "TORCHFT_XLA_CACHE_DIR",
-        "str",
-        None,
-        "Override the XLA compilation-cache directory used by the TPU dry-run entry script.",
-        scope="entry",
-    ),
     _k(
         "TORCHFT_DRYRUN_XLA_FLAGS",
         "str",

@@ -951,7 +951,7 @@ def rule_signal_sources(root: str) -> List[Finding]:
 
 
 # ----------------------------------------------------------------------
-# badput-kinds: the time-accounting plane's closed taxonomy.
+# badput-kinds: the time-accounting plane's closed classification.
 #
 # telemetry.BADPUT_KINDS (the ledger + the digest's positional "acct"
 # array) and lighthouse.cc kBadputKindNames (the aggregation index) must

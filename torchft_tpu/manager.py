@@ -226,7 +226,7 @@ class Manager:
         # committed gate after a heal is replay/catch-up work, not steady
         # compute, so its residual is priced as replay_catchup.
         self._healed_since_gate = False
-        # Closed-taxonomy wall-clock ledger (BADPUT_KINDS): every second
+        # Closed-classification wall-clock ledger (BADPUT_KINDS): every second
         # since construction lands in exactly one bucket, so the per-kind
         # accounts tile the process lifetime by construction. The legacy
         # _goodput dict above stays as the derived back-compat view.
@@ -916,11 +916,18 @@ class Manager:
                     "the device path quantizes in chunks on-device and has "
                     "no single host-side (flat, q, s) moment to expose"
                 )
-            if self.errored() is not None:
-                return DummyWork(items)
+            # Quorum first, error check second: the socket PG keeps the
+            # previous step's dead-peer latch until this quorum's
+            # reconfigure clears it, and checked the other way round the
+            # stale latch voids the heal step — the survivor skips the
+            # collective that holds it at the barrier, votes no at once and
+            # closes its checkpoint window before the restarted peer has
+            # fetched (a 404 the peer retries for a whole ``timeout``).
             try:
                 self.wait_quorum()
             except Exception:
+                return DummyWork(items)
+            if self.errored() is not None:
                 return DummyWork(items)
             if self._participating_rank is None:
                 import jax.numpy as jnp
@@ -931,7 +938,10 @@ class Manager:
                 1.0 / num_participants if reduce_op == ReduceOp.AVG else 1.0
             )
             try:
-                from torchft_tpu.collectives import allreduce_quantized_jax
+                from torchft_tpu.collectives import (
+                    allreduce_quantized_jax,
+                    device_quantize,
+                )
 
                 work = allreduce_quantized_jax(
                     self._pg,
@@ -948,6 +958,7 @@ class Manager:
                 nbytes=int(sum(getattr(t, "nbytes", 0) for t in items)),
                 quantized=True,
                 bits=quantize_bits,
+                quant_path="device" if device_quantize() else "host",
             )
             return _ManagedWork(self, work, items, scale=1.0, in_place=False)
 
@@ -959,12 +970,13 @@ class Manager:
 
         arrays: List[np.ndarray] = [to_mutable(t) for t in items]
         # Every return path keeps the contract: wait() -> list of arrays.
-        if self.errored() is not None:
-            return DummyWork(arrays)
+        # (Quorum first, error check second — see the device path above.)
         try:
             self.wait_quorum()
         except Exception:
             # error already latched by _async_quorum
+            return DummyWork(arrays)
+        if self.errored() is not None:
             return DummyWork(arrays)
         # Non-participants (healing/spares) contribute zeros
         # (reference: manager.py:410-411); the collective quantizes the
@@ -997,6 +1009,7 @@ class Manager:
             "allreduce_issue",
             nbytes=int(sum(a.nbytes for a in arrays)),
             quantized=bool(should_quantize),
+            quant_path="host" if should_quantize else None,
         )
         return _ManagedWork(
             self,
@@ -1309,7 +1322,7 @@ class Manager:
         ``goodput_frac`` = committed / (committed + failed + heal)) is a
         derived view kept for back-compat: those buckets need NOT tile
         the run window (the pre-first-gate window is unattributed there).
-        The authoritative accounting is the closed-taxonomy ledger:
+        The authoritative accounting is the closed-classification ledger:
         ``badput_s`` (per-:data:`~torchft_tpu.telemetry.BADPUT_KINDS`
         seconds) tiles ``accounted_s`` — wall-clock from construction to
         the last commit gate / drain — within float noise

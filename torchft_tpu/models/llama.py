@@ -22,6 +22,7 @@ the in-repo flagship for the BASELINE.json HSDP Llama-3-8B config.
 from __future__ import annotations
 
 import dataclasses
+import logging
 from typing import Any, Callable, Optional
 
 import jax
@@ -29,6 +30,23 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 Dtype = Any
+
+logger = logging.getLogger(__name__)
+_ATTN_NOTED: set = set()
+
+
+def _note_attention(asked: str, traced: str, seq_len: int) -> None:
+    """Says once per (asked, traced, seq_len), at trace time, which
+    attention implementation a step really took — 'flash' routes to dense
+    below ``flash_min_seq`` or on unsupported tilings, and a chip run
+    must be able to prove which branch it compiled."""
+    key = (asked, traced, seq_len)
+    if key not in _ATTN_NOTED:
+        _ATTN_NOTED.add(key)
+        logger.log(
+            logging.INFO if asked == traced else logging.WARNING,
+            "attention: asked=%s traced=%s seq=%d", asked, traced, seq_len,
+        )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -206,6 +224,7 @@ class Attention(nn.Module):
             assert cfg.attn_fn is not None, (
                 f"{cfg.attn_impl} attention needs cfg.attn_fn"
             )
+            _note_attention(cfg.attn_impl, cfg.attn_impl, q.shape[1])
             out = cfg.attn_fn(q, k, v)
         elif cfg.attn_impl == "flash":
             from torchft_tpu.ops.flash_attention import (
@@ -216,14 +235,17 @@ class Attention(nn.Module):
             if q.shape[1] >= cfg.flash_min_seq and supports(
                 q.shape[1], cfg.flash_block_q, cfg.flash_block_k
             ):
+                _note_attention("flash", "flash", q.shape[1])
                 out = flash_attention(
                     q, k, v,
                     block_q=cfg.flash_block_q,
                     block_k=cfg.flash_block_k,
                 )
             else:
+                _note_attention("flash", "dense", q.shape[1])
                 out = dense_attention(q, k, v)
         else:
+            _note_attention(cfg.attn_impl, "dense", q.shape[1])
             out = dense_attention(q, k, v)
         return nn.DenseGeneral(
             features=cfg.hidden_size,

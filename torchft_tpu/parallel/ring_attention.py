@@ -21,23 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax>=0.4.35 moved shard_map to the public namespace
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def shard_map(f, *, mesh, in_specs, out_specs):
-    # The replication-check kwarg was renamed check_rep -> check_vma across
-    # jax versions; we need it off (ppermute inside fori_loop).
-    try:
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    except TypeError:
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-        )
+# Replication check off: ppermute inside fori_loop.
+shard_map = partial(jax.shard_map, check_vma=False)
 
 
 def _flash_fold_supported(sq: int, skv: int) -> bool:

@@ -75,12 +75,17 @@ def _lookup(table, device_kind: str) -> Optional[float]:
     for key, val in table:
         if key in kind:
             return val
+    if "tpu" in kind:
+        # A TPU that is not in the table is an error, not a missing MFU:
+        # add its published peaks above.
+        raise ValueError(f"no published peak for TPU kind {device_kind!r}")
     return None
 
 
 def peak_tflops(device_kind: str) -> Optional[float]:
     """bf16 peak TFLOP/s for a jax ``device_kind``; None off-TPU (CPU
-    proxy runs report raw FLOP/s but no MFU — there is no honest peak)."""
+    proxy runs report raw FLOP/s but no MFU — there is no honest peak);
+    raises for a TPU kind the table does not know."""
     return _lookup(PEAK_BF16_TFLOPS, device_kind)
 
 
@@ -160,6 +165,27 @@ _COST_LOCK = threading.Lock()
 _STEP_COSTS: Dict[str, Dict[str, Any]] = {}
 
 
+# Persistent-compilation-cache hits seen by this process (JAX's own
+# monitoring event); the listener is installed on first use and stays.
+_cache_hits = 0
+_cache_listening = False
+
+
+def _count_cache_hits() -> int:
+    global _cache_listening
+    if not _cache_listening:
+        import jax.monitoring
+
+        def _on_event(event: str, **_kw: Any) -> None:
+            global _cache_hits
+            if event == "/jax/compilation_cache/cache_hits":
+                _cache_hits += 1
+
+        jax.monitoring.register_event_listener(_on_event)
+        _cache_listening = True
+    return _cache_hits
+
+
 def perf_enabled() -> bool:
     return knobs.get_bool("TORCHFT_PERF")
 
@@ -194,16 +220,29 @@ def record_jit_cost(
     if not (force or perf_enabled()):
         return None
     try:
+        import time
+
         import jax
 
+        hits0 = _count_cache_hits()
+        t0 = time.perf_counter()
         compiled = jitted_fn.lower(*args, **kwargs).compile()
+        compile_s = time.perf_counter() - t0
         cost = compiled_cost(compiled)
         devs = jax.devices()
         rec: Dict[str, Any] = {
             "name": name,
+            "platform": devs[0].platform if devs else "unknown",
             "device_kind": devs[0].device_kind if devs else "unknown",
             "n_devices": len(devs),
             "tokens_per_step": tokens_per_step,
+            # Proof of what was compiled, for chip_smoke.py: seconds spent
+            # in lower+compile, whether the persistent cache served it, and
+            # how many compiled Pallas kernels the program holds (interpret
+            # mode lowers to plain HLO and counts 0).
+            "compile_s": compile_s,
+            "cache_hit": _count_cache_hits() > hits0,
+            "tpu_custom_calls": compiled.as_text().count("tpu_custom_call"),
             **cost,
         }
     except Exception:  # noqa: BLE001 - accounting is best-effort
@@ -218,9 +257,13 @@ def record_jit_cost(
             flops=rec.get("flops"),
             bytes_accessed=rec.get("bytes_accessed"),
             temp_bytes=rec.get("temp_bytes"),
+            platform=rec["platform"],
             device_kind=rec["device_kind"],
             n_devices=rec["n_devices"],
             tokens_per_step=tokens_per_step,
+            compile_s=rec["compile_s"],
+            cache_hit=rec["cache_hit"],
+            tpu_custom_calls=rec["tpu_custom_calls"],
         )
     return rec
 

@@ -681,7 +681,7 @@ class ProcessGroupSocket(ProcessGroup):
                 # peer's tag holds everyone else's next quorum hostage
                 # (survivors can't re-register while blocked), which turned
                 # one SIGKILL into back-to-back 30s timeout rounds before
-                # this (HEAL_DRILL_r05 sigkill_control). TimeoutError is
+                # this (the sigkill_control drill). TimeoutError is
                 # exempt: a per-tag timeout can be a handled, retryable
                 # event (the parameter server's idle keepalive recv), not
                 # proof the collective is doomed — the peers' own timeouts

@@ -29,13 +29,13 @@ import jax
 from _train_common import (
     DurableRegime,
     drain_signal,
+    enable_compile_cache,
     group_data_seed,
-    maybe_pin_cpu,
     perf_note_compiled,
     perf_step_suffix,
 )
 
-maybe_pin_cpu()  # before any backend initializes or package import
+enable_compile_cache()  # before anything compiles
 
 import jax.numpy as jnp
 import numpy as np
