@@ -94,6 +94,10 @@ class _FakeManager:
         self.rank = 1
         self.allreduced = []
         self.quantize_flags = []
+        self.exposed_comm = []
+
+    def note_exposed_comm(self, seconds):
+        self.exposed_comm.append(seconds)
 
     def num_participants(self):
         return self.participants
@@ -207,6 +211,9 @@ def test_managed_mesh_outer_allreduce_roundtrip():
     assert set(out) == {"a", "b"}
     assert out["a"].shape == (8, 8)
     assert fm.allreduced  # went through the manager
+    # ... and told it how long the caller was inside (numpy grads: no
+    # wait for a device to take off)
+    assert len(fm.exposed_comm) == 1 and fm.exposed_comm[0] > 0.0
 
 
 def test_managed_mesh_quantize_flag_propagates():

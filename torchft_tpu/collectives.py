@@ -24,6 +24,12 @@ import numpy as np
 
 from torchft_tpu import knobs
 from torchft_tpu.process_group import ProcessGroup, ReduceOp
+from torchft_tpu.telemetry import (
+    current_span,
+    next_bucket,
+    span_parent,
+    trace_span,
+)
 from torchft_tpu.work import DummyWork, FutureWork, Work
 
 BLOCK = 512  # values per quantization scale
@@ -50,24 +56,24 @@ class _WireOrder:
             self._issued += 1
             return ticket
 
-    @contextlib.contextmanager
-    def turn(self, ticket: int):
-        """Holds the wire for ``ticket``; a no-op pass-through once that
+    def wait(self, ticket: int) -> None:
+        """Blocks until it is ``ticket``'s turn; returns at once when that
         ticket has already been served."""
         with self._cv:
             self._cv.wait_for(lambda: self._serving >= ticket)
-        try:
-            yield
-        finally:
-            with self._cv:
-                self._serving = max(self._serving, ticket + 1)
-                self._cv.notify_all()
+
+    def done(self, ticket: int) -> None:
+        with self._cv:
+            self._serving = max(self._serving, ticket + 1)
+            self._cv.notify_all()
 
 
 _wire_order_lock = threading.Lock()
 
 
-def _spawn_collective(pg: ProcessGroup, fn) -> "concurrent.futures.Future":
+def _spawn_collective(
+    pg: ProcessGroup, fn, bucket: "int | None" = None
+) -> "concurrent.futures.Future":
     """One daemon thread per in-flight quantized collective. A bounded pool
     would deadlock when several ranks live in one process (tests, parameter
     server): every rank's pipeline must make progress concurrently for any
@@ -75,24 +81,38 @@ def _spawn_collective(pg: ProcessGroup, fn) -> "concurrent.futures.Future":
 
     ``fn(wire)`` must run its PG ops inside ``with wire():`` — its turn in
     the issue order of ``pg`` (the ticket is taken here, on the caller's
-    thread)."""
+    thread). The thread has no span stack of its own: it hangs its spans
+    under the span open on the caller's thread now (for DDP a descendant
+    of the ``allreduce_grads`` root), and the time it is blocked for its
+    turn is the ``wire_turn_wait`` span of ``bucket``."""
     import concurrent.futures
 
     with _wire_order_lock:
         order = pg.__dict__.setdefault("_quant_wire_order", _WireOrder())
     ticket = order.take()
+    parent = current_span()
     fut: concurrent.futures.Future = concurrent.futures.Future()
+
+    @contextlib.contextmanager
+    def wire():
+        with trace_span("torchft::collectives::wire_turn_wait", bucket=bucket):
+            order.wait(ticket)
+        try:
+            yield
+        finally:
+            order.done(ticket)
 
     def run() -> None:
         try:
-            if fut.set_running_or_notify_cancel():
-                fut.set_result(fn(lambda: order.turn(ticket)))
+            with span_parent(parent):
+                if fut.set_running_or_notify_cancel():
+                    fut.set_result(fn(wire))
         except BaseException as e:  # noqa: BLE001 - delivered via the future
             fut.set_exception(e)
         finally:
             # Died before the wire (or cancelled): still pass the turn on.
-            with order.turn(ticket):
-                pass
+            order.wait(ticket)
+            order.done(ticket)
 
     threading.Thread(target=run, daemon=True, name="quant-collective").start()
     return fut
@@ -242,6 +262,16 @@ def _unflatten_into(
         offset += n
 
 
+def _bucket_tags(arrays: Sequence) -> dict:
+    """What every span of one bucket's collective carries: the bucket's
+    ordinal under the caller's outermost open span (taken now, on the
+    caller's thread) and its unquantized payload bytes."""
+    return {
+        "bucket": next_bucket(),
+        "nbytes": sum(a.nbytes for a in arrays),
+    }
+
+
 def device_quantize() -> bool:
     """Whether jax-array payloads quantize ON DEVICE (Pallas kernels) or
     through the host numpy quantizer — the one rule, also journaled per
@@ -293,30 +323,7 @@ def allreduce_quantized_jax(
             offset += size
         return outs
 
-    if len(arrays) > 1:
-        flat = jnp.concatenate(
-            [jnp.ravel(a).astype(jnp.float32) for a in arrays]
-        )
-    else:
-        flat = jnp.ravel(arrays[0]).astype(jnp.float32)
     ws = pg.size()
-    if ws <= 1:
-        return DummyWork(rebuild(flat * scale) if scale != 1.0 else arrays)
-    a0 = arrays[0]
-    if len(arrays) == 1 and a0.ndim == 1 and a0.dtype == jnp.float32:
-        # ravel/astype both short-circuited, so ``flat`` aliases the
-        # caller's buffer.  Parts of the pipeline touch ``flat`` after
-        # this call returns (host path: the deferred host pull; device
-        # path: quantize kernels already enqueued but not yet executed)
-        # while the caller's next train step may DONATE this buffer
-        # (make_train_step and bench.py both donate), deleting it
-        # mid-use.  Materialize an independent device snapshot before
-        # returning to the caller.
-        # (Below the ws<=1 return: the single-replica path never defers.)
-        flat = jnp.copy(flat)
-
-    from torchft_tpu.telemetry import trace_span
-
     total_scale = scale / ws if op == ReduceOp.AVG else scale
 
     # On TPU the Pallas kernels quantize/dequantize ON DEVICE (int8 over
@@ -330,24 +337,51 @@ def allreduce_quantized_jax(
     # wire-equality test drives it.
     host_quant = not device_quantize()
 
-    # Device path: dispatch the quantize kernels NOW, on the caller's
-    # thread. Async dispatch returns immediately, but enqueues the kernels
-    # right behind the compute that produced ``flat`` — BEFORE the
-    # caller's next training window. The deferred host pull then overlaps
-    # that window; dispatched lazily from the collective thread instead,
-    # the kernels would queue behind the whole next window and the "pull"
-    # would spend its time waiting on unrelated compute.
-    q_chunks = None
-    n_elems = 0
-    if not host_quant:
-        q_chunks, n_elems = Q.quantize_for_transfer_async(flat, bits)
-        # The enqueued kernels hold their own reference to the snapshot;
-        # don't let the run() closure pin the full fp32 copy across the
-        # multi-second wire pipeline too.
-        flat = None
+    tags = _bucket_tags(arrays)
+    # The caller-thread part; nothing in it waits for the device.
+    with trace_span("torchft::collectives::dispatch", **tags):
+        if len(arrays) > 1:
+            flat = jnp.concatenate(
+                [jnp.ravel(a).astype(jnp.float32) for a in arrays]
+            )
+        else:
+            flat = jnp.ravel(arrays[0]).astype(jnp.float32)
+        if ws <= 1:
+            return DummyWork(
+                rebuild(flat * scale) if scale != 1.0 else arrays
+            )
+        a0 = arrays[0]
+        if len(arrays) == 1 and a0.ndim == 1 and a0.dtype == jnp.float32:
+            # ravel/astype both short-circuited, so ``flat`` aliases the
+            # caller's buffer.  Parts of the pipeline touch ``flat`` after
+            # this call returns (host path: the deferred host pull; device
+            # path: quantize kernels already enqueued but not yet
+            # executed) while the caller's next train step may DONATE this
+            # buffer (make_train_step and bench.py both donate), deleting
+            # it mid-use.  Materialize an independent device snapshot
+            # before returning to the caller.  (Below the ws<=1 return:
+            # the single-replica path never defers.)
+            flat = jnp.copy(flat)
+
+        # Device path: dispatch the quantize kernels NOW, on the caller's
+        # thread. Async dispatch returns immediately, but enqueues the
+        # kernels right behind the compute that produced ``flat`` — BEFORE
+        # the caller's next training window. The deferred host pull then
+        # overlaps that window; dispatched lazily from the collective
+        # thread instead, the kernels would queue behind the whole next
+        # window and the "pull" would spend its time waiting on unrelated
+        # compute.
+        q_chunks = None
+        n_elems = 0
+        if not host_quant:
+            q_chunks, n_elems = Q.quantize_for_transfer_async(flat, bits)
+            # The enqueued kernels hold their own reference to the
+            # snapshot; don't let the run() closure pin the full fp32 copy
+            # across the multi-second wire pipeline too.
+            flat = None
 
     def run(wire) -> List["jax.Array"]:
-        with trace_span("torchft::collectives::quantize_pull"):
+        with trace_span("torchft::collectives::quantize_pull", **tags):
             if host_quant:
                 flat_host = np.asarray(flat, dtype=np.float32)
                 n = flat_host.size
@@ -356,9 +390,9 @@ def allreduce_quantized_jax(
                 q_host, s_host, n = Q.pull_transfer_chunks(
                     q_chunks, n_elems, bits
                 )
-        with wire(), trace_span("torchft::collectives::wire"):
+        with wire(), trace_span("torchft::collectives::wire", **tags):
             reduced = _quantized_wire_pipeline(pg, q_host, s_host, n, bits)
-        with trace_span("torchft::collectives::dequant_push"):
+        with trace_span("torchft::collectives::dequant_push", **tags):
             if isinstance(reduced, np.ndarray):
                 # Tiny payload: the local reduce already produced the full
                 # fp32 sum — push it straight to device, no second lossy
@@ -410,7 +444,7 @@ def allreduce_quantized_jax(
             # price of the overlap.
         return outs
 
-    return FutureWork(_spawn_collective(pg, run))
+    return FutureWork(_spawn_collective(pg, run, tags["bucket"]))
 
 
 def reduce_scatter_quantized(
@@ -563,13 +597,15 @@ def _alltoall_chunk_reduce(
         q_chunks.append(q_host[off * bpb : (off + c) * bpb])
         s_chunks.append(s_host[off : off + c])
         off += c
-    all_q = pg.alltoall(q_chunks).wait()
-    all_s = pg.alltoall(s_chunks).wait()
+    with trace_span("torchft::collectives::wire_alltoall"):
+        all_q = pg.alltoall(q_chunks).wait()
+        all_s = pg.alltoall(s_chunks).wait()
     me = pg.rank()
     n_me = counts[me] * BLOCK
-    acc = np.zeros(n_me, np.float32)
-    for g_q, g_s in zip(all_q, all_s):
-        acc += dequantize_blockwise(g_q, g_s, n_me, bits)
+    with trace_span("torchft::collectives::wire_reduce"):
+        acc = np.zeros(n_me, np.float32)
+        for g_q, g_s in zip(all_q, all_s):
+            acc += dequantize_blockwise(g_q, g_s, n_me, bits)
     return acc
 
 
@@ -593,19 +629,26 @@ def _quantized_wire_pipeline(
     ws = pg.size()
     blocks = s_host.size
     if blocks < ws:
-        gathered = pg.allgather([q_host, s_host]).wait()
-        acc = np.zeros(n, np.float32)
-        for g_q, g_s in gathered:
-            acc += dequantize_blockwise(g_q, g_s, n, bits)
+        with trace_span("torchft::collectives::wire_allgather"):
+            gathered = pg.allgather([q_host, s_host]).wait()
+        with trace_span("torchft::collectives::wire_reduce"):
+            acc = np.zeros(n, np.float32)
+            for g_q, g_s in gathered:
+                acc += dequantize_blockwise(g_q, g_s, n, bits)
         return acc
     # Contiguous block-aligned chunks so each chunk owns whole scales;
     # alltoall -> rank r reduces everyone's r-th chunk.
     counts = [len(c) for c in np.array_split(np.arange(blocks), ws)]
     acc = _alltoall_chunk_reduce(pg, q_host, s_host, counts, bits)
-    rq, rs = quantize_blockwise(acc, bits)
-    gathered = pg.allgather([rq, np.asarray(rs)]).wait()
-    q_final = np.concatenate([g[0] for g in gathered])
-    s_final = np.concatenate([g[1] for g in gathered])
+    with trace_span("torchft::collectives::wire_reduce"):
+        rq, rs = quantize_blockwise(acc, bits)
+    with trace_span("torchft::collectives::wire_allgather"):
+        gathered = pg.allgather([rq, np.asarray(rs)]).wait()
+    # Joining the ranks' chunks is a host copy of the whole payload, not
+    # socket time: booked with the other numpy work of the stage.
+    with trace_span("torchft::collectives::wire_reduce"):
+        q_final = np.concatenate([g[0] for g in gathered])
+        s_final = np.concatenate([g[1] for g in gathered])
     return q_final, s_final
 
 
@@ -631,8 +674,7 @@ def allreduce_quantized(
     ws = pg.size()
     if ws <= 1:
         return DummyWork(list(arrays))
-
-    from torchft_tpu.telemetry import trace_span
+    tags = _bucket_tags(arrays)
 
     def run(wire) -> List[np.ndarray]:
         # Same span names as the device (jax) path so bench/telemetry
@@ -640,15 +682,15 @@ def allreduce_quantized(
         # is the host quantize here (there is no device pull), "wire" the
         # alltoall-reduce-allgather pipeline, "dequant_push" the decode +
         # write-back.
-        with trace_span("torchft::collectives::quantize_pull"):
+        with trace_span("torchft::collectives::quantize_pull", **tags):
             flat, sizes = _flatten(arrays)
             n = flat.size
             q_host, s_host = quantize_blockwise(flat, bits)
             if on_local_quantized is not None:
                 on_local_quantized(flat, q_host, s_host)
-        with wire(), trace_span("torchft::collectives::wire"):
+        with wire(), trace_span("torchft::collectives::wire", **tags):
             reduced = _quantized_wire_pipeline(pg, q_host, s_host, n, bits)
-        with trace_span("torchft::collectives::dequant_push"):
+        with trace_span("torchft::collectives::dequant_push", **tags):
             if isinstance(reduced, np.ndarray):
                 result = reduced
             else:
@@ -659,4 +701,4 @@ def allreduce_quantized(
             _unflatten_into(arrays, result, sizes)
         return list(arrays)
 
-    return FutureWork(_spawn_collective(pg, run))
+    return FutureWork(_spawn_collective(pg, run, tags["bucket"]))

@@ -17,6 +17,7 @@ import jax
 import numpy as np
 
 from torchft_tpu.manager import Manager
+from torchft_tpu.telemetry import DDP_ROOT_SPAN, trace_span
 
 
 class DistributedDataParallel:
@@ -74,6 +75,23 @@ class DistributedDataParallel:
           unlike DiLoCo's residuals which track a whole discarded local
           stream.
         """
+        with trace_span(DDP_ROOT_SPAN) as root:
+            compute_s, out = self._allreduce_grads(
+                grads, should_quantize, quantize_bits
+            )
+        # The ledger's exposed_comm: the caller's time in here, less the
+        # wait for the backward pass that produces the gradients.
+        self._manager.note_exposed_comm(root.elapsed_s - compute_s)
+        return out
+
+    def _allreduce_grads(
+        self,
+        grads: Any,
+        should_quantize: bool,
+        quantize_bits: Optional[int],
+    ) -> Tuple[float, Any]:
+        """``allreduce_grads`` under its root span; returns the seconds of
+        it that were compute (``grads_wait``) and the reduced pytree."""
         if quantize_bits is None:
             quantize_bits = self._quantize_bits
         elif (
@@ -122,8 +140,9 @@ class DistributedDataParallel:
                 reduced = work.wait()
                 for i, r in zip(idx_list, reduced):
                     out[i] = r
-            return jax.tree_util.tree_unflatten(treedef, out)
+            return 0.0, jax.tree_util.tree_unflatten(treedef, out)
         dev_leaves = [x for x in leaves if isinstance(x, jax.Array)]
+        compute_s = 0.0
         if dev_leaves:
             # Guard the device->host pull: if the device computation feeding
             # the grads never completes (wedged inner-mesh collective), the
@@ -145,16 +164,27 @@ class DistributedDataParallel:
             ft_futures.array_timeout(
                 dev_leaves, on_stall, getattr(manager, "_timeout", 60.0)
             )
-        host: List[np.ndarray] = [np.asarray(x) for x in leaves]
+            # The pull below blocks on the same event (the leaves are
+            # outputs of one program), so this wait changes no timing; it
+            # only separates the backward pass from the transfer.
+            with trace_span("torchft::ddp::grads_wait") as waited:
+                jax.block_until_ready(dev_leaves)
+            compute_s = waited.elapsed_s
+        with trace_span(
+            "torchft::ddp::pull", nbytes=sum(x.nbytes for x in dev_leaves)
+        ):
+            host: List[np.ndarray] = [np.asarray(x) for x in leaves]
 
         buckets = self._bucketize(host)
         works: List[Tuple[Any, np.ndarray, List[int]]] = []
         for b_idx, idx_list in enumerate(buckets):
-            flat = np.concatenate([host[i].reshape(-1) for i in idx_list])
             on_quantized = None
-            if should_quantize and self._error_feedback:
-                flat = self._residuals.compensate(b_idx, flat)
-                on_quantized = self._residuals.make_hook(b_idx)
+            with trace_span("torchft::ddp::pack", bucket=b_idx) as pack:
+                flat = np.concatenate([host[i].reshape(-1) for i in idx_list])
+                if should_quantize and self._error_feedback:
+                    flat = self._residuals.compensate(b_idx, flat)
+                    on_quantized = self._residuals.make_hook(b_idx)
+                pack.attrs["nbytes"] = flat.nbytes
             work = self._manager.allreduce(
                 flat,
                 should_quantize=should_quantize,
@@ -164,14 +194,17 @@ class DistributedDataParallel:
             works.append((work, flat, idx_list))
 
         out: List[Optional[np.ndarray]] = [None] * len(host)
-        for work, flat, idx_list in works:
+        for b_idx, (work, flat, idx_list) in enumerate(works):
             (reduced,) = work.wait()
-            offset = 0
-            for i in idx_list:
-                n = host[i].size
-                out[i] = reduced[offset : offset + n].reshape(host[i].shape)
-                offset += n
-        return jax.tree_util.tree_unflatten(treedef, out)
+            with trace_span("torchft::ddp::unpack", bucket=b_idx):
+                offset = 0
+                for i in idx_list:
+                    n = host[i].size
+                    out[i] = reduced[offset : offset + n].reshape(
+                        host[i].shape
+                    )
+                    offset += n
+        return compute_s, jax.tree_util.tree_unflatten(treedef, out)
 
     def _bucketize(self, arrays: List[np.ndarray]) -> List[List[int]]:
         from torchft_tpu.collectives import bucketize

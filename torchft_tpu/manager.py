@@ -45,11 +45,14 @@ from torchft_tpu.coordination import ManagerClient, ManagerServer, QuorumResult
 from torchft_tpu.process_group import ProcessGroup, ReduceOp
 from torchft_tpu.store import StoreClient, TCPStoreServer
 from torchft_tpu.telemetry import (
+    DDP_ROOT_SPAN,
     DigestWindow,
     StepDigest,
     TimeLedger,
+    drain_spans,
     get_event_log,
     get_metrics_logger,
+    in_span,
     observe_span,
     set_default_replica_id,
     timeit,
@@ -215,10 +218,11 @@ class Manager:
         # from the window before bucketing so heal time isn't counted as
         # productive (or doubly as lost) time.
         self._heal_since_gate = 0.0
-        # Allreduce-wait seconds inside the current window (accumulated by
-        # _ManagedWork._finish): subtracting them from the gate dt leaves
-        # the compute residual the live digest reports as its "c" phase.
-        self._allreduce_since_gate = 0.0
+        # Exposed-communication seconds inside the current window
+        # (note_exposed_comm): the ledger's exposed_comm split, and what
+        # the gate subtracts from its dt to get the compute residual the
+        # live digest reports as its "c" phase.
+        self._exposed_comm_since_gate = 0.0
         # Quorum-RPC-wait seconds inside the current window (accumulated
         # by _async_quorum): priced as quorum_wait in the ledger.
         self._quorum_since_gate = 0.0
@@ -450,15 +454,17 @@ class Manager:
     # Quorum
     # ------------------------------------------------------------------
 
-    @traced("torchft::manager::start_quorum")
-    def _journal(self, event: str, **attrs: Any) -> None:
-        """Emits a step-event journal record. No-op (one env read, no
-        allocation) unless TORCHFT_JOURNAL_FILE/_DIR is set."""
+    def _journal(
+        self, event: str, step: Optional[int] = None, **attrs: Any
+    ) -> None:
+        """Emits a step-event journal record (for the current step unless
+        ``step`` says otherwise). No-op (one env read, no allocation)
+        unless TORCHFT_JOURNAL_FILE/_DIR is set."""
         log = get_event_log()
         if log is not None:
             log.emit(
                 event,
-                step=self._step,
+                step=self._step if step is None else step,
                 replica_id=self._replica_id,
                 trace=self._trace_id or None,
                 **attrs,
@@ -510,6 +516,7 @@ class Manager:
             "unreachable_retries": retries,
         }
 
+    @traced("torchft::manager::start_quorum")
     def start_quorum(
         self,
         allow_heal: bool = True,
@@ -924,7 +931,8 @@ class Manager:
             # closes its checkpoint window before the restarted peer has
             # fetched (a 404 the peer retries for a whole ``timeout``).
             try:
-                self.wait_quorum()
+                with trace_span("torchft::manager::quorum_wait"):
+                    self.wait_quorum()
             except Exception:
                 return DummyWork(items)
             if self.errored() is not None:
@@ -968,11 +976,19 @@ class Manager:
                 a = np.array(a)
             return a
 
-        arrays: List[np.ndarray] = [to_mutable(t) for t in items]
+        with trace_span("torchft::manager::host_copy") as copy:
+            arrays: List[np.ndarray] = [to_mutable(t) for t in items]
+            copy.attrs["nbytes"] = sum(a.nbytes for a in arrays)
+            # An array that came back as itself was already writable:
+            # "no copy" is a recorded 0, not an absent number.
+            copy.attrs["copied_bytes"] = sum(
+                a.nbytes for a, t in zip(arrays, items) if a is not t
+            )
         # Every return path keeps the contract: wait() -> list of arrays.
         # (Quorum first, error check second — see the device path above.)
         try:
-            self.wait_quorum()
+            with trace_span("torchft::manager::quorum_wait"):
+                self.wait_quorum()
         except Exception:
             # error already latched by _async_quorum
             return DummyWork(arrays)
@@ -1021,6 +1037,16 @@ class Manager:
                 else 1.0
             ),
         )
+
+    def note_exposed_comm(self, seconds: float) -> None:
+        """Adds caller-thread seconds spent in replica-axis communication
+        that nothing overlapped to the current gate window. The DDP
+        wrapper reports its whole ``allreduce_grads`` span less the wait
+        for the gradients (device->host pull and bucket copies included,
+        the backward pass excluded); ``_ManagedWork`` reports each
+        ``wait()`` made outside that span."""
+        with self._goodput_lock:
+            self._exposed_comm_since_gate += max(float(seconds), 0.0)
 
     # ------------------------------------------------------------------
     # Errors / commit protocol
@@ -1090,7 +1116,21 @@ class Manager:
         """Distributed commit gate (reference: manager.py:760-836)."""
         gated_step = self._step  # _should_commit_inner increments on commit
         t_gate0 = time.monotonic()
-        answer = self._should_commit_inner(timeout)
+        answer = False
+        try:
+            answer = self._should_commit_inner(timeout)
+        finally:
+            # The step's span tree, committed or not (and when the gate
+            # raises at max_retries): every span closed since the last
+            # gate, the gate's own included.
+            spans, dropped = drain_spans()
+            self._journal(
+                "step_spans",
+                step=gated_step,
+                committed=bool(answer),
+                spans=spans,
+                dropped=dropped,
+            )
         log = get_event_log()
         if log is not None:
             log.emit(
@@ -1193,8 +1233,8 @@ class Manager:
                 gate_dt = dt
             self._last_gate_t = now
             self._heal_since_gate = 0.0
-            allreduce_since_gate = self._allreduce_since_gate
-            self._allreduce_since_gate = 0.0
+            exposed_comm_since_gate = self._exposed_comm_since_gate
+            self._exposed_comm_since_gate = 0.0
             quorum_since_gate = self._quorum_since_gate
             self._quorum_since_gate = 0.0
             healed_in_window = self._healed_since_gate
@@ -1220,7 +1260,7 @@ class Manager:
         credited = self._ledger.account(
             {
                 "heal": heal_in_window,
-                "exposed_comm": allreduce_since_gate,
+                "exposed_comm": exposed_comm_since_gate,
                 "quorum_wait": quorum_since_gate,
                 "straggler_idle": commit_wait_s,
             },
@@ -1238,12 +1278,12 @@ class Manager:
 
         if gate_dt is not None:
             # Feed the live-digest window, and record the compute residual
-            # (gate-to-gate time not spent waiting on the allreduce — the
+            # (gate-to-gate time not spent in exposed communication — the
             # digest's "c" phase; heal time is already excluded from dt).
             self._digest_window.note_gate(self._step, answer, gate_dt)
             observe_span(
                 "torchft::manager::step_compute",
-                max(gate_dt - allreduce_since_gate, 0.0),
+                max(gate_dt - exposed_comm_since_gate, 0.0),
             )
 
         if answer:
@@ -1655,53 +1695,53 @@ class _ManagedWork(Work):
                 return
             self._finished = True
             t = timeout if timeout is not None else self._manager._timeout
-            t0 = time.monotonic()
-            try:
-                # Belt and braces: the wait carries a deadline, AND the
-                # timeout engine aborts the pg if the wait wedges past it —
-                # a stalled (non-erroring) peer mid-collective must fail
-                # fast, not hang until socket timeouts (reference:
-                # manager.py:473-515 wrap_future + stream timeouts).
-                # The evidence watcher is armed for the duration of the
-                # blocking wait: first hard peer-failure signal aborts the
-                # pg at heartbeat speed; the timeout engine stays as the
-                # evidence-free backstop.
-                with self._manager._evidence_guard():
-                    with ft_futures.context_timeout(
-                        self._manager._abort_pg_on_stall, t
-                    ):
-                        result = self._work.wait(t)
-                if self._in_place:
-                    for a in self._arrays:
-                        a *= self._scale
-                else:
-                    self._arrays = list(result)
-                elapsed = time.monotonic() - t0
-                self._note_allreduce_wait(elapsed)
-                self._manager._journal(
-                    "allreduce_complete",
-                    ok=True,
-                    elapsed_s=elapsed,
-                )
-            except Exception as e:  # noqa: BLE001
-                self._manager._logger.exception(f"allreduce work failed: {e}")
-                elapsed = time.monotonic() - t0
-                self._note_allreduce_wait(elapsed)
-                self._manager._journal(
-                    "allreduce_complete",
-                    ok=False,
-                    elapsed_s=elapsed,
-                    error=str(e)[:200],
-                )
-                self._manager.report_error(e)
-
-    def _note_allreduce_wait(self, elapsed: float) -> None:
-        # Backend-independent wall time the TRAINER spent blocked on the
-        # allreduce: the live digest's "a" phase, and the amount the commit
-        # gate subtracts from gate-to-gate time to get the compute residual.
-        observe_span("torchft::manager::allreduce_wait", elapsed)
-        with self._manager._goodput_lock:
-            self._manager._allreduce_since_gate += elapsed
+            error: Optional[Exception] = None
+            with trace_span("torchft::manager::allreduce_wait") as waited:
+                try:
+                    # Belt and braces: the wait carries a deadline, AND the
+                    # timeout engine aborts the pg if the wait wedges past
+                    # it — a stalled (non-erroring) peer mid-collective
+                    # must fail fast, not hang until socket timeouts
+                    # (reference: manager.py:473-515 wrap_future + stream
+                    # timeouts). The evidence watcher is armed for the
+                    # duration of the blocking wait: first hard
+                    # peer-failure signal aborts the pg at heartbeat speed;
+                    # the timeout engine stays as the evidence-free
+                    # backstop.
+                    with self._manager._evidence_guard():
+                        with ft_futures.context_timeout(
+                            self._manager._abort_pg_on_stall, t
+                        ):
+                            result = self._work.wait(t)
+                    if self._in_place:
+                        with trace_span(
+                            "torchft::manager::allreduce_scale",
+                            nbytes=sum(a.nbytes for a in self._arrays),
+                        ):
+                            for a in self._arrays:
+                                a *= self._scale
+                    else:
+                        self._arrays = list(result)
+                except Exception as e:  # noqa: BLE001
+                    self._manager._logger.exception(
+                        f"allreduce work failed: {e}"
+                    )
+                    error = e
+            # Backend-independent wall time the TRAINER spent blocked on
+            # the allreduce: the span's histogram is the live digest's "a"
+            # phase. Under the DDP wrapper its root span prices the step's
+            # exposed_comm; a caller that drives Manager.allreduce itself
+            # (DiLoCo, LocalSGD) is priced by these waits.
+            if not in_span(DDP_ROOT_SPAN):
+                self._manager.note_exposed_comm(waited.elapsed_s)
+            self._manager._journal(
+                "allreduce_complete",
+                ok=error is None,
+                elapsed_s=waited.elapsed_s,
+                **({} if error is None else {"error": str(error)[:200]}),
+            )
+            if error is not None:
+                self._manager.report_error(error)
 
     def wait(self, timeout: Optional[float] = None) -> Any:
         self._finish(timeout)

@@ -667,14 +667,17 @@ class ProcessGroupSocket(ProcessGroup):
             op, tag=tag or "", nbytes=nbytes, rank=self._rank, world=self._world
         )
 
+        t_submit = time.monotonic()
+
         def guarded() -> Any:
             t0 = time.monotonic()
+            queued_s = t0 - t_submit  # behind earlier ops on pg-exec
             try:
                 result = fn()
             except Exception as e:
                 flight_recorder.complete(seq, error=str(e))
                 self._journal_collective(
-                    op, nbytes, tag, time.monotonic() - t0, ok=False
+                    op, nbytes, tag, time.monotonic() - t0, queued_s, ok=False
                 )
                 # Tell live peers we abandoned this collective so their
                 # pending tag waits fail NOW: one rank wedged on a dead
@@ -693,7 +696,7 @@ class ProcessGroupSocket(ProcessGroup):
                 raise
             flight_recorder.complete(seq)
             self._journal_collective(
-                op, nbytes, tag, time.monotonic() - t0, ok=True
+                op, nbytes, tag, time.monotonic() - t0, queued_s, ok=True
             )
             return result
 
@@ -709,7 +712,13 @@ class ProcessGroupSocket(ProcessGroup):
             conn.send_abort(tag, str(exc))
 
     def _journal_collective(
-        self, op: str, nbytes: int, tag: Optional[str], dt: float, ok: bool
+        self,
+        op: str,
+        nbytes: int,
+        tag: Optional[str],
+        dt: float,
+        queued_s: float,
+        ok: bool,
     ) -> None:
         """One journal line + one span sample per completed collective,
         IDENTICAL across backends (socket and native both route through
@@ -727,6 +736,7 @@ class ProcessGroupSocket(ProcessGroup):
                 nbytes=int(nbytes),
                 tag=tag or "",
                 elapsed_s=dt,
+                queued_s=queued_s,
                 ok=ok,
             )
 

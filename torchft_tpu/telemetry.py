@@ -24,9 +24,11 @@ TPU-native translation of the reference's observability subsystem:
   NCCL flight-recorder dump via named pipe, process_group.py:89-108,
   812-813).
 
-Everything degrades to near-zero overhead: spans are two monotonic reads
-and a dict update; the recorder is a deque append; metrics/trace windows
-are off unless their env vars are set.
+Everything degrades to near-zero overhead: spans are two monotonic reads,
+a thread-local stack push and a dict update (plus two wall-clock reads
+and a list append while a journal is configured); the recorder is a
+deque append; metrics/trace windows are off unless their env vars are
+set.
 """
 
 from __future__ import annotations
@@ -36,6 +38,7 @@ import bisect
 import collections
 import contextlib
 import functools
+import itertools
 import json
 import os
 import threading
@@ -47,6 +50,13 @@ from . import knobs
 __all__ = [
     "trace_span",
     "traced",
+    "Span",
+    "DDP_ROOT_SPAN",
+    "current_span",
+    "in_span",
+    "next_bucket",
+    "span_parent",
+    "drain_spans",
     "span_stats",
     "span_percentiles",
     "reset_span_stats",
@@ -228,37 +238,188 @@ def reset_byte_stats() -> None:
     _BYTE_COUNTERS.reset()
 
 
-def _jax_annotation(name: str) -> Any:
-    """TraceAnnotation ctx if jax's profiler is importable, else None."""
-    try:
-        from jax.profiler import TraceAnnotation
+# The step-scoped span tree. Every ``trace_span`` is a node: its parent is
+# the span open on the same thread (a thread-local stack; a collective
+# thread borrows its issuer's through ``span_parent``). While a journal
+# is configured, closed spans are also kept in ``_SPAN_BUFFER`` until the
+# Manager's commit gate drains them into ONE ``step_spans`` event, so an
+# operator reads a whole step's tree from the journal and the benchmark
+# reads stage times without the profiler. The buffer is bounded: past
+# ``SPAN_BUFFER_CAP`` spans are counted in ``dropped``, never kept.
+SPAN_BUFFER_CAP = 4096
 
-        return TraceAnnotation(name)
-    except Exception:
+# Root of the tree in a DDP step: everything the replica-axis allreduce
+# does on the caller's thread is inside it, and each bucket's collective
+# thread hangs its stage spans under it. Its time, less the wait for the
+# gradients, is the ledger's exposed_comm (Manager.note_exposed_comm).
+DDP_ROOT_SPAN = "torchft::ddp::allreduce_grads"
+
+_UNRESOLVED = object()
+_TRACE_ANNOTATION: Any = _UNRESOLVED
+_SPAN_IDS = itertools.count(1)
+_SPAN_TLS = threading.local()
+
+
+def _trace_annotation() -> Any:
+    """``jax.profiler.TraceAnnotation``, or None where jax's profiler
+    cannot be imported; resolved on the first span, once."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is _UNRESOLVED:
+        try:
+            from jax.profiler import TraceAnnotation
+        except Exception:  # noqa: BLE001 - spans work without a profiler
+            TraceAnnotation = None
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION
+
+
+class Span:
+    """One ``trace_span``, open or closed. ``attrs`` may be added to
+    inside the block (they reach the journal record, not the profiler's
+    annotation, which is written when the span opens); ``elapsed_s`` is
+    set when the span closes."""
+
+    __slots__ = (
+        "name", "id", "parent", "top", "attrs", "t0", "elapsed_s", "_issued"
+    )
+
+    def __init__(
+        self, name: str, parent: Optional["Span"], attrs: Dict[str, Any]
+    ) -> None:
+        self.name = name
+        self.id = next(_SPAN_IDS)
+        self.parent = None if parent is None else parent.id
+        # The outermost ancestor: what a bucket's ordinal counts under.
+        self.top: "Span" = self if parent is None else parent.top
+        self.attrs = attrs
+        self.t0: Optional[float] = None  # time.time(), when recorded
+        self.elapsed_s: Optional[float] = None
+        self._issued = 0
+
+
+class _SpanBuffer:
+    """Closed spans of the current step, ``[name, t0, t1, id, parent,
+    thread, attrs]`` each, with ``time.time()`` times like every journal
+    ``ts``."""
+
+    def __init__(self, cap: int) -> None:
+        self._lock = threading.Lock()
+        self._cap = cap
+        self._spans: List[list] = []
+        self._dropped = 0
+
+    def add(self, rec: list) -> None:
+        with self._lock:
+            if len(self._spans) < self._cap:
+                self._spans.append(rec)
+            else:
+                self._dropped += 1
+
+    def drain(self) -> Tuple[List[list], int]:
+        with self._lock:
+            spans, dropped = self._spans, self._dropped
+            self._spans, self._dropped = [], 0
+        return spans, dropped
+
+
+_SPAN_BUFFER = _SpanBuffer(SPAN_BUFFER_CAP)
+
+
+def drain_spans() -> Tuple[List[list], int]:
+    """Takes the closed spans buffered since the last drain and the count
+    of those dropped at the cap. A span still open stays out until the
+    drain after it closes."""
+    return _SPAN_BUFFER.drain()
+
+
+def _span_stack() -> List[Span]:
+    stack = getattr(_SPAN_TLS, "stack", None)
+    if stack is None:
+        stack = _SPAN_TLS.stack = []
+    return stack
+
+
+def current_span() -> Optional[Span]:
+    """The innermost span open on this thread."""
+    stack = _span_stack()
+    return stack[-1] if stack else None
+
+
+def in_span(name: str) -> bool:
+    """Whether a span called ``name`` is open on this thread."""
+    return any(s.name == name for s in _span_stack())
+
+
+def next_bucket() -> Optional[int]:
+    """The ordinal of the next collective issued under this thread's
+    outermost open span (for DDP the ``allreduce_grads`` root, so the
+    step's buckets count 0, 1, 2, ...); None with no span open."""
+    span = current_span()
+    if span is None:
         return None
+    top = span.top
+    top._issued += 1
+    return top._issued - 1
 
 
 @contextlib.contextmanager
-def trace_span(name: str) -> Iterator[None]:
-    """Named hot-path span: shows up in jax profiler traces AND in
-    :func:`span_stats`. Span names mirror the reference's
-    ``torchft::manager::*`` convention so traces are comparable."""
-    ann = _jax_annotation(name)
-    t0 = time.monotonic()
-    if ann is not None:
-        try:
-            ann.__enter__()
-        except Exception:
-            ann = None
+def span_parent(parent: Optional[Span]) -> Iterator[None]:
+    """Hangs the spans this thread opens inside the block under
+    ``parent``, a span open on the thread that issued this one's work."""
+    stack = _span_stack()
+    saved = stack[:]
+    stack[:] = [] if parent is None else [parent]
     try:
         yield
     finally:
+        stack[:] = saved
+
+
+@contextlib.contextmanager
+def trace_span(name: str, **attrs: Any) -> Iterator[Span]:
+    """Named hot-path span: shows up in jax profiler traces (``attrs`` as
+    the annotation's arguments) AND in :func:`span_stats`, and is a node
+    of the step's span tree (above). Span names mirror the reference's
+    ``torchft::manager::*`` convention so traces are comparable. Attrs
+    whose value is None are left out."""
+    attrs = {k: v for k, v in attrs.items() if v is not None}
+    stack = _span_stack()
+    span = Span(name, stack[-1] if stack else None, attrs)
+    recorded = bool(_journal_path_from_env())
+    cls = _trace_annotation()
+    ann = None
+    if cls is not None:
+        try:
+            ann = cls(name, **attrs)
+            ann.__enter__()
+        except Exception:
+            ann = None
+    stack.append(span)
+    if recorded:
+        span.t0 = time.time()
+    t0 = time.monotonic()
+    try:
+        yield span
+    finally:
+        span.elapsed_s = time.monotonic() - t0
+        if recorded:
+            _SPAN_BUFFER.add([
+                name,
+                round(span.t0, 6),
+                round(time.time(), 6),
+                span.id,
+                span.parent,
+                threading.get_ident(),
+                span.attrs,
+            ])
+        if stack and stack[-1] is span:
+            stack.pop()
         if ann is not None:
             try:
                 ann.__exit__(None, None, None)
             except Exception:
                 pass
-        _SPAN_STATS.add(name, time.monotonic() - t0)
+        _SPAN_STATS.add(name, span.elapsed_s)
 
 
 def traced(name: str) -> Callable:
@@ -421,6 +582,9 @@ EVENT_KINDS: Dict[str, str] = {
     # -- allreduce lifecycle (manager.py) ------------------------------
     "allreduce_issue": "outer-axis allreduce handed to the data plane",
     "allreduce_complete": "outer-axis allreduce completed (or errored)",
+    "step_spans": "the step's tree of trace spans, one event per commit "
+                  "gate: spans=[[name, t0, t1, id, parent, thread, attrs]], "
+                  "dropped (spans lost at the buffer's cap)",
     # -- process group / native engine (process_group.py) --------------
     "pg_configure": "process group (re)configured for a new quorum",
     "pg_configure_failed": "process group configure attempt failed",
