@@ -915,3 +915,386 @@ def test_allreduce_quantized_int4_three_ranks_odd_size(store):
     np.testing.assert_allclose(results[0], expected, atol=tol)
     for g in groups:
         g.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# The quantized wire stage in reused host buffers (collectives._WireScratch)
+# ---------------------------------------------------------------------------
+
+_B = 512  # collectives.BLOCK
+
+
+def _oracle_quantize(flat, bits):
+    """Blockwise quantize as it was written before the reused buffers:
+    every intermediate a new array."""
+    qmax = {8: 127.0, 4: 7.0}[bits]
+    blocks = (flat.size + _B - 1) // _B
+    padded = np.zeros(blocks * _B, dtype=np.float32)
+    padded[: flat.size] = flat
+    mat = padded.reshape(blocks, _B)
+    s = np.abs(mat).max(axis=1)
+    s /= qmax
+    np.copyto(s, 1.0, where=(s == 0))
+    buf = mat / s[:, None]
+    np.rint(buf, out=buf)
+    np.clip(buf, -qmax, qmax, out=buf)
+    q = np.empty(blocks * _B, dtype=np.int8)
+    q[:] = buf.reshape(-1)
+    if bits == 4:
+        u = q.astype(np.uint8) & 0xF
+        q = (u[0::2] | (u[1::2] << 4)).view(np.int8)
+    return q, s
+
+
+def _oracle_dequantize(q, s, n, bits):
+    if bits == 4:
+        u = q.view(np.uint8)
+        wide = np.empty(u.size * 2, dtype=np.uint8)
+        wide[0::2] = u & 0xF
+        wide[1::2] = u >> 4
+        q = (wide ^ 8).astype(np.int8) - 8
+    mat = q.astype(np.float32).reshape(s.size, _B)
+    mat *= s[:, None]
+    return mat.reshape(-1)[:n]
+
+
+def _oracle_wire(data, bits):
+    """(q_final, s_final, result) of the alltoall -> fp32 reduce ->
+    requantize -> allgather protocol over ``data`` (one flat fp32 array a
+    rank), every rank's view being the same."""
+    ws = len(data)
+    n = data[0].size
+    bpb = _B // (8 // bits)
+    quantized = [_oracle_quantize(d, bits) for d in data]
+    blocks = quantized[0][1].size
+    counts = [len(c) for c in np.array_split(np.arange(blocks), ws)]
+    q_parts, s_parts = [], []
+    off = 0
+    for c in counts:
+        acc = np.zeros(c * _B, np.float32)
+        for q, s in quantized:  # rank order
+            acc += _oracle_dequantize(
+                q[off * bpb : (off + c) * bpb], s[off : off + c], c * _B, bits
+            )
+        rq, rs = _oracle_quantize(acc, bits)
+        q_parts.append(rq)
+        s_parts.append(rs)
+        off += c
+    q_final = np.concatenate(q_parts)
+    s_final = np.concatenate(s_parts)
+    return q_final, s_final, _oracle_dequantize(q_final, s_final, n, bits)
+
+
+def _small_pieces(monkeypatch):
+    """Pieces of 3 blocks and tasks of 8, so that a chunk of a few dozen
+    blocks runs several tasks of several pieces with a ragged last one."""
+    import torchft_tpu.collectives as C
+
+    monkeypatch.setattr(C, "_PIECE_BLOCKS", 3)
+    monkeypatch.setattr(C, "_BLOCKS_PER_TASK", 8)
+
+
+def _wire_data(ws, n, seed):
+    rng = np.random.default_rng(seed)
+    data = [
+        (rng.standard_normal(n) * rng.choice([1e-3, 1.0, 40.0], n)).astype(np.float32)
+        for _ in range(ws)
+    ]
+    for d in data:
+        d[_B : 2 * _B] = 0.0  # an all-zero block: scale 1.0
+    return data
+
+
+def _allreduce_quantized_all(groups, data, bits=8):
+    from torchft_tpu.collectives import allreduce_quantized
+
+    def run(rank):
+        arr = data[rank].copy()
+        allreduce_quantized(groups[rank], [arr], bits=bits).wait(timeout=60)
+        return arr
+
+    return _run_parallel([lambda r=r: run(r) for r in range(len(groups))])
+
+
+@pytest.mark.parametrize("tail", [0, 777], ids=["whole", "ragged"])
+@pytest.mark.parametrize("ws", [2, 3, 4])
+@pytest.mark.parametrize("bits", [8, 4])
+def test_quantized_wire_is_bit_equal_to_the_plain_oracle(
+    store, monkeypatch, bits, ws, tail
+):
+    """Reduced payload, scales and final result equal, bit for bit, what
+    the allocate-everything expressions give: same fp32 ``float(q) *
+    scale`` per peer, summed in rank order, same blockwise requantize."""
+    import torchft_tpu.collectives as C
+
+    _small_pieces(monkeypatch)
+    n = _B * ws * 37 + tail
+    data = _wire_data(ws, n, seed=100 * bits + 10 * ws + (tail > 0))
+    want_q, want_s, want = _oracle_wire(data, bits)
+    groups = _make_group(store, ws, prefix=f"oracle{bits}{ws}{tail}")
+
+    def run(rank):
+        q, s = C.quantize_blockwise(data[rank], bits)
+        return C._quantized_wire_pipeline(groups[rank], q, s, n, bits)
+
+    for q_final, s_final in _run_parallel([lambda r=r: run(r) for r in range(ws)]):
+        assert q_final.dtype == np.int8 and s_final.dtype == np.float32
+        np.testing.assert_array_equal(q_final, want_q)
+        np.testing.assert_array_equal(s_final.view(np.uint32), want_s.view(np.uint32))
+    for got in _allreduce_quantized_all(groups, data, bits):
+        np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+    for g in groups:
+        g.shutdown()
+
+
+def test_quantized_wire_reuse_leaves_no_stale_values(store, monkeypatch):
+    """A large bucket, a smaller one, the large one again on the same
+    process groups: the buffers that held the larger payload have stale
+    values behind the smaller one, and each result still equals a fresh
+    group's."""
+    _small_pieces(monkeypatch)
+    ws = 3
+    sizes = [_B * ws * 41 + 100, _B * ws * 5 + 3, _B * ws * 41 + 100]
+    payloads = [_wire_data(ws, n, seed=7 + i) for i, n in enumerate(sizes)]
+    kept = _make_group(store, ws, prefix="reuse-kept")
+    for i, data in enumerate(payloads):
+        fresh = _make_group(store, ws, prefix=f"reuse-fresh{i}")
+        want = _allreduce_quantized_all(fresh, data)
+        got = _allreduce_quantized_all(kept, data)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g.view(np.uint32), w.view(np.uint32))
+        np.testing.assert_array_equal(got[0], _oracle_wire(data, 8)[2])
+        for g in fresh:
+            g.shutdown()
+    for g in kept:
+        g.shutdown()
+
+
+def _second_wire_before_first_push(monkeypatch, module, name):
+    """Makes the first collective of every process group hold its
+    ``dequant_push`` — the call of ``module.name`` — until the group's
+    second collective has left the wire: the first one's joined payload
+    is then read after a later bucket has been through every buffer the
+    wire turn owns."""
+    import torchft_tpu.collectives as C
+
+    cv = threading.Condition()
+    wires = {}  # id(pg) -> collectives that have left the wire
+    here = threading.local()
+    real_wire = C._quantized_wire_pipeline
+    real_push = getattr(module, name)
+
+    def wire(pg, *args, **kwargs):
+        out = real_wire(pg, *args, **kwargs)
+        with cv:
+            here.pg, here.ordinal = id(pg), wires.get(id(pg), 0)
+            wires[id(pg)] = here.ordinal + 1
+            cv.notify_all()
+        return out
+
+    def push(*args, **kwargs):
+        if here.ordinal == 0:
+            with cv:
+                assert cv.wait_for(lambda: wires[here.pg] >= 2, timeout=60)
+        return real_push(*args, **kwargs)
+
+    monkeypatch.setattr(C, "_quantized_wire_pipeline", wire)
+    monkeypatch.setattr(module, name, push)
+
+
+@pytest.mark.parametrize("path", ["numpy", "device"])
+def test_quantized_wire_result_outlives_the_next_wire_turn(store, monkeypatch, path):
+    """Two buckets of one size in flight on one process group: the first
+    one's result, decoded only after the second has finished its wire
+    turn, is its own."""
+    import jax.numpy as jnp
+
+    import torchft_tpu.collectives as C
+    from torchft_tpu.ops import quantization as Q
+
+    ws = 2
+    n = _B * ws * 4
+    payloads = [_wire_data(ws, n, seed=31), _wire_data(ws, n, seed=32)]
+    want = [_oracle_wire(data, 8)[2] for data in payloads]
+    groups = _make_group(store, ws, prefix=f"own-{path}")
+    if path == "device":
+        monkeypatch.setenv("TORCHFT_FORCE_DEVICE_QUANT", "1")
+        _second_wire_before_first_push(monkeypatch, Q, "dequantize_from_transfer")
+    else:
+        _second_wire_before_first_push(monkeypatch, C, "dequantize_blockwise")
+
+    def run(rank):
+        if path == "device":
+            works = [
+                C.allreduce_quantized_jax(groups[rank], [jnp.asarray(p[rank])])
+                for p in payloads
+            ]
+            return [np.asarray(w.wait(timeout=60)[0]) for w in works]
+        arrs = [p[rank].copy() for p in payloads]
+        works = [C.allreduce_quantized(groups[rank], [a]) for a in arrs]
+        for w in works:
+            w.wait(timeout=60)
+        return arrs
+
+    for outs in _run_parallel([lambda r=r: run(r) for r in range(ws)]):
+        for out, w in zip(outs, want):
+            if path == "device":  # the device decodes the same bytes
+                np.testing.assert_allclose(out, w, rtol=1e-6, atol=1e-7)
+            else:
+                np.testing.assert_array_equal(out.view(np.uint32), w.view(np.uint32))
+    assert not np.allclose(want[0], want[1])
+    for g in groups:
+        g.shutdown()
+
+
+def test_reduce_scatter_quantized_shard_survives_a_later_collective(store):
+    """The shard handed to the caller is a copy: the fp32 sum it was cut
+    from belongs to the next wire turn."""
+    from torchft_tpu.collectives import reduce_scatter_quantized
+
+    ws = 2
+    n = _B * ws * 6
+    first, later = _wire_data(ws, n, seed=41), _wire_data(ws, n, seed=42)
+    groups = _make_group(store, ws, prefix="rs-own")
+
+    def run(rank):
+        shard, span = reduce_scatter_quantized(
+            groups[rank], [first[rank].copy()]
+        ).wait(timeout=60)
+        before = shard.copy()
+        reduce_scatter_quantized(groups[rank], [later[rank].copy()]).wait(timeout=60)
+        return shard, before, span
+
+    for shard, before, (start, end) in _run_parallel(
+        [lambda r=r: run(r) for r in range(ws)]
+    ):
+        np.testing.assert_array_equal(shard, before)
+        np.testing.assert_allclose(
+            shard, (first[0] + first[1])[start:end],
+            atol=np.abs(first[0] + first[1]).max() * 0.05,
+        )
+    for g in groups:
+        g.shutdown()
+
+
+def test_quantized_wire_scratch_is_dropped_on_reconfigure(store):
+    """A quorum that shrinks from three to two changes every chunk size:
+    reconfigure lets the old buffers go, the next collective makes its
+    own, and its result is right."""
+    ws = 3
+    groups = _make_group(store, ws, prefix="drop3")
+    data = _wire_data(ws, _B * 6 * 5 + 9, seed=51)
+    got = _allreduce_quantized_all(groups, data)
+    np.testing.assert_array_equal(got[0], _oracle_wire(data, 8)[2])
+    assert all("_quant_wire_scratch" in g.__dict__ for g in groups)
+    old = groups[0].__dict__["_quant_wire_scratch"]
+
+    groups[2].shutdown()
+    assert "_quant_wire_scratch" not in groups[2].__dict__
+    _run_parallel([
+        lambda r=r: groups[r].configure(f"{store.address()}/drop2", r, 2)
+        for r in range(2)
+    ])
+    assert all("_quant_wire_scratch" not in g.__dict__ for g in groups)
+    got = _allreduce_quantized_all(groups[:2], data[:2])
+    for g in got:
+        np.testing.assert_array_equal(g, _oracle_wire(data[:2], 8)[2])
+    assert groups[0].__dict__["_quant_wire_scratch"] is not old
+    for g in groups[:2]:
+        g.shutdown()
+
+
+def test_wire_scratch_lends_a_result_buffer_to_one_reader_at_a_time():
+    """A joined payload goes back to the free list when its last view is
+    gone (what a host-to-device copy in flight holds is a view), and not
+    before; turn buffers grow once and are then reused."""
+    from torchft_tpu.collectives import _WireScratch
+
+    scratch = _WireScratch()
+    q, s = scratch.result(4096, 8)
+    assert (q.dtype, q.size, s.dtype, s.size) == (np.int8, 4096, np.float32, 8)
+    assert scratch.counts() == {"fresh_bytes": 4096 + 32, "reused_bytes": 0}
+    in_flight = q[100:200]
+    del q, s
+    q2, s2 = scratch.result(4096, 8)
+    assert not np.shares_memory(in_flight, q2)
+    assert scratch.counts()["fresh_bytes"] == 4096 + 32
+    del in_flight
+    q3, s3 = scratch.result(2048, 4)  # the first one is free again, and fits
+    assert scratch.counts() == {"fresh_bytes": 0, "reused_bytes": 2048 + 16}
+    assert not np.shares_memory(q2, q3) and not np.shares_memory(q3, s3)
+
+    # several free buffers of different sizes: the smallest that fits is
+    # lent; one that fits nothing asked for is replaced, not kept
+    big, _ = scratch.result(100_000, 8)
+    del q2, s2, q3, s3, big, _
+    scratch.counts()
+    assert sorted(b.size for b in scratch._free) == [4128, 4128, 100_064]
+    small, _s = scratch.result(4096, 8)
+    large, _l = scratch.result(50_000, 8)
+    assert scratch.counts() == {"fresh_bytes": 0, "reused_bytes": 4128 + 50_080}
+    huge, _h = scratch.result(200_000, 8)
+    assert scratch.counts()["fresh_bytes"] == 200_032
+    assert scratch._free == []  # the last 4,128-byte one made way
+    del small, _s, large, _l, huge, _h
+
+    acc = scratch.turn("acc", np.float32, 1000)
+    acc[:] = 1.0
+    assert scratch.counts() == {"fresh_bytes": 4000, "reused_bytes": 0}
+    again = scratch.turn("acc", np.float32, (10, 50))
+    assert again.shape == (10, 50) and np.shares_memory(acc, again)
+    assert scratch.counts() == {"fresh_bytes": 0, "reused_bytes": 2000}
+
+
+@pytest.mark.timeout(120)
+@pytest.mark.parametrize("path", ["numpy", "device"])
+def test_quantized_wire_many_buckets_in_flight_keep_their_own_results(
+    store, monkeypatch, path
+):
+    """Twelve buckets of three sizes in flight at once on each of two
+    ranks, threads switching every 10 us: the wire turn's buffers pass
+    from bucket to bucket and the joined payloads go out and come back
+    (on the device path whenever JAX lets go of them) while later buckets
+    are on the wire, and every result is its own."""
+    import sys
+
+    import jax.numpy as jnp
+
+    import torchft_tpu.collectives as C
+
+    _small_pieces(monkeypatch)
+    if path == "device":
+        monkeypatch.setenv("TORCHFT_FORCE_DEVICE_QUANT", "1")
+    ws = 2
+    sizes = [_B * ws * n for n in (3, 11, 29)] * 4
+    payloads = [_wire_data(ws, n, seed=60 + i) for i, n in enumerate(sizes)]
+    want = [_oracle_wire(data, 8)[2] for data in payloads]
+    groups = _make_group(store, ws, prefix=f"stress-{path}")
+
+    def run(rank):
+        if path == "device":
+            works = [
+                C.allreduce_quantized_jax(groups[rank], [jnp.asarray(p[rank])])
+                for p in payloads
+            ]
+            return [np.asarray(w.wait(timeout=60)[0]) for w in works]
+        arrs = [p[rank].copy() for p in payloads]
+        works = [C.allreduce_quantized(groups[rank], [a]) for a in arrs]
+        for w in works:
+            w.wait(timeout=60)
+        return arrs
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        results = _run_parallel([lambda r=r: run(r) for r in range(ws)])
+    finally:
+        sys.setswitchinterval(interval)
+    for arrs in results:
+        for got, w in zip(arrs, want):
+            if path == "device":  # the device decodes the same bytes
+                np.testing.assert_allclose(got, w, rtol=1e-6, atol=1e-7)
+            else:
+                np.testing.assert_array_equal(got.view(np.uint32), w.view(np.uint32))
+    for g in groups:
+        g.shutdown()

@@ -485,3 +485,55 @@ def test_quantized_tree_two_ranks_three_buckets(journal):
                    for s in _by_name(tree, "torchft::collectives::wire"))
         assert all(s[THREAD] == caller
                    for s in _by_name(tree, "torchft::collectives::dispatch"))
+
+
+def test_wire_reduce_spans_count_fresh_and_reused_bytes(journal):
+    """From the second collective of a size on, the wire stage allocates
+    nothing: every ``wire_reduce`` span says ``fresh_bytes`` 0 and how
+    many scratch bytes it wrote instead."""
+    from torchft_tpu.collectives import allreduce_quantized
+    from torchft_tpu.process_group import ProcessGroupSocket
+    from torchft_tpu.store import TCPStoreServer
+
+    store = TCPStoreServer()
+    groups = [ProcessGroupSocket(timeout=30.0) for _ in range(2)]
+    n = 512 * 2 * 6
+    rounds = []
+
+    def run(rank):
+        groups[rank].configure(f"{store.address()}/fresh", rank, 2)
+        for _ in range(3):
+            arr = np.full(n, float(rank + 1), np.float32)
+            allreduce_quantized(groups[rank], [arr]).wait(timeout=30)
+            if rank == 0:
+                rounds.append(telemetry.drain_spans()[0])
+        return arr
+
+    try:
+        with ThreadPoolExecutor(max_workers=2) as pool:
+            results = [f.result(timeout=60)
+                       for f in [pool.submit(run, r) for r in range(2)]]
+    finally:
+        for g in groups:
+            g.shutdown()
+        store.shutdown()
+    np.testing.assert_allclose(results[0], 3.0, rtol=0.02)
+    spans = [s for r in rounds for s in r] + telemetry.drain_spans()[0]
+    wires = sorted(_by_name(spans, "torchft::collectives::wire"), key=lambda s: s[T0])
+    assert len(wires) == 2 * 3  # ranks x collectives
+    stages = [
+        [s[ATTRS] for s in sorted(spans, key=lambda s: s[T0])
+         if s[PARENT] == w[ID] and s[NAME].endswith("::wire_reduce")]
+        for w in wires
+    ]
+    assert all(len(attrs) == 3 for attrs in stages)
+    # the two ranks' first collectives grow the scratch ...
+    for attrs in stages[:2]:
+        assert all(a["fresh_bytes"] > 0 for a in attrs)
+    # ... and nothing after them allocates. A chunk of 6 blocks: the
+    # requantized 3,072 B + 24 B of scales (and a piece per task), the
+    # joined payload 6,144 B + 48 B
+    for attrs in stages[2:]:
+        assert [a["fresh_bytes"] for a in attrs] == [0, 0, 0]
+        assert all(a["reused_bytes"] > 0 for a in attrs)
+        assert attrs[2]["reused_bytes"] == 6144 + 48

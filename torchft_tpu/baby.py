@@ -444,6 +444,7 @@ class ProcessGroupBabySocket(ProcessGroup):
         # kill produces must read as intentional teardown, not latch a
         # phantom "child died" error after a clean shutdown/reconfigure.
         self._generation += 1
+        self._drop_wire_scratch()
         if self._child is not None:
             self._child.kill()
             self._child.join(timeout=10.0)

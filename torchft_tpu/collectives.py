@@ -18,6 +18,7 @@ from __future__ import annotations
 import contextlib
 import os
 import threading
+import weakref
 from typing import Callable, List, Sequence, Tuple
 
 import numpy as np
@@ -71,6 +72,94 @@ class _WireOrder:
 _wire_order_lock = threading.Lock()
 
 
+class _WireScratch:
+    """Host buffers of one process group's quantized wire stage, kept
+    across steps: a payload-sized numpy array is mapped, page-faulted in
+    and unmapped again every time it is made, which cost the stage more
+    than its arithmetic. Sizes come from the payloads seen and only grow.
+    The scratch hangs on the group beside its ``_WireOrder`` (several
+    ranks can live in one process, so nothing here is module-global) and
+    is let go when the group is torn down or reconfigured
+    (``ProcessGroup._drop_wire_scratch``).
+
+    Who may write what, and when:
+
+    * ``turn(name, ...)`` buffers — the fp32 sum, the requantized chunk,
+      the per-task pieces — belong to whoever holds the group's wire
+      turn. One collective at a time does (``_spawn_collective``), and
+      nothing of them may leave the turn: a caller that hands one out
+      copies it first (``reduce_scatter_quantized``).
+    * ``result(...)`` buffers — the joined payload and scales — are read
+      after the turn has passed on, by ``dequant_push`` and by
+      host-to-device copies that may still be in flight when the
+      collective's thread returns. So each is lent out through an array
+      of its own, and comes back to the free list only when that array
+      and every view of it are gone: JAX holds the host array until its
+      transfer is done (off-TPU it may alias it for as long as the device
+      array lives), numpy readers until they return. A buffer some reader
+      still sees is never rewritten; a new one is made instead.
+    """
+
+    def __init__(self) -> None:
+        self._turn: dict = {}
+        self._free: List[np.ndarray] = []  # result buffers nobody reads
+        self._fresh = 0
+        self._reused = 0
+
+    def turn(self, name: str, dtype, shape) -> np.ndarray:
+        """The turn's buffer called ``name`` at exactly ``shape``; its
+        contents are whatever the last turn left there."""
+        count = int(np.prod(shape))
+        buf = self._turn.get(name)
+        if buf is None or buf.size < count:
+            buf = self._turn[name] = np.empty(count, dtype=dtype)
+            self._fresh += buf.nbytes
+        else:
+            self._reused += count * buf.itemsize
+        return buf[:count].reshape(shape)
+
+    def result(self, q_bytes: int, n_scales: int) -> Tuple[np.ndarray, np.ndarray]:
+        """Lends (int8 payload, fp32 scales) of exactly these sizes, out
+        of one buffer; see the class docstring for when it comes back."""
+        s_at = -(-q_bytes // 64) * 64
+        nbytes = s_at + 4 * n_scales
+        # By index, never by value: ``list.remove`` would compare arrays.
+        # A buffer that comes back meanwhile is appended, so an index
+        # taken here stays good.
+        free = self._free
+        by_size = sorted(range(len(free)), key=lambda i: free[i].size)
+        fit = next((i for i in by_size if free[i].size >= nbytes), None)
+        if fit is not None:
+            owner = free.pop(fit)
+            self._reused += nbytes
+        else:
+            if by_size:  # outgrown: the new one takes its place
+                free.pop(by_size[0])
+            owner = np.empty(nbytes, dtype=np.uint8)
+            self._fresh += nbytes
+        # Views of ``loan`` name ``loan`` as their base, not ``owner``:
+        # numpy follows a chain of views down to the first array whose
+        # base is not an array, and a memoryview is not one.
+        loan = np.frombuffer(owner.data, dtype=np.uint8)
+        weakref.finalize(loan, self._free.append, owner)
+        return (
+            loan[:q_bytes].view(np.int8),
+            loan[s_at:nbytes].view(np.float32),
+        )
+
+    def counts(self) -> dict:
+        """Bytes allocated and bytes reused since the last call: the
+        ``fresh_bytes``/``reused_bytes`` of one ``wire_reduce`` span."""
+        out = {"fresh_bytes": self._fresh, "reused_bytes": self._reused}
+        self._fresh = self._reused = 0
+        return out
+
+
+def _wire_scratch(pg: ProcessGroup) -> _WireScratch:
+    with _wire_order_lock:
+        return pg.__dict__.setdefault("_quant_wire_scratch", _WireScratch())
+
+
 def _spawn_collective(
     pg: ProcessGroup, fn, bucket: "int | None" = None
 ) -> "concurrent.futures.Future":
@@ -118,12 +207,19 @@ def _spawn_collective(
     return fut
 
 
-# Host-side (de)quantize runs chunk-parallel on threads: numpy ufuncs
-# release the GIL on large arrays, so this scales with cores — measured
-# 125M elements: 16.3s -> ~2s single-pass in-place math across 8 threads.
-# Param-sized DiLoCo pseudograds make this the peer-side critical path of
-# the quantized outer allreduce.
-_HOST_QUANT_CHUNK = 8 * 1024 * 1024  # elements per parallel task
+# Host-side (de)quantize runs task-parallel on threads (numpy ufuncs
+# release the GIL); each task walks its block range in pieces, every pass
+# written in place into buffers that already exist. What the earlier form
+# (``zeros``, ``astype``, ``*=``, ``+=`` over payload-sized temporaries)
+# paid for on the chip's host was fresh pages, not arithmetic: the wire
+# stage of mistral-ft4 (480M gradient elements a step, four ranks at once,
+# transparent hugepages off) took 6.9 s a step in that form and 0.52 s in
+# this one (PR 24's chip runs; PERF.md section 5). The two sizes are from a
+# sweep of the stage alone on that host, four processes at once: pieces of
+# 1M values took 0.34 s a step against 0.49 at 256K (more numpy calls, all
+# needing the GIL) and 0.51 at 8M (out of cache); tasks of 2M values, 0.50.
+_BLOCKS_PER_TASK = 8 * 1024 * 1024 // BLOCK  # 8M values per parallel task
+_PIECE_BLOCKS = 2048  # 1M values a pass
 _host_pool = None
 _host_pool_lock = threading.Lock()
 
@@ -143,17 +239,31 @@ def _pool():
 
 def _parallel_over_blocks(n_blocks: int, fn) -> None:
     """Runs fn(block_start, block_end) over block ranges in parallel."""
-    blocks_per_task = max(_HOST_QUANT_CHUNK // BLOCK, 1)
-    if n_blocks <= blocks_per_task:
+    if n_blocks <= _BLOCKS_PER_TASK:
         fn(0, n_blocks)
         return
     tasks = []
-    for start in range(0, n_blocks, blocks_per_task):
+    for start in range(0, n_blocks, _BLOCKS_PER_TASK):
         tasks.append(
-            _pool().submit(fn, start, min(start + blocks_per_task, n_blocks))
+            _pool().submit(fn, start, min(start + _BLOCKS_PER_TASK, n_blocks))
         )
     for t in tasks:
         t.result()
+
+
+def _task_tmp_shape(n_blocks: int) -> Tuple[int, int, int]:
+    """One fp32 piece per task of :func:`_parallel_over_blocks`."""
+    n_tasks = max(-(-n_blocks // _BLOCKS_PER_TASK), 1)
+    return n_tasks, min(_PIECE_BLOCKS, n_blocks), BLOCK
+
+
+def _task_tmp(tmp: "np.ndarray | None", b0: int, b1: int) -> np.ndarray:
+    """The piece-sized fp32 temporary of the task over blocks [b0, b1):
+    its own slot of ``tmp`` (shaped by :func:`_task_tmp_shape`), or a new
+    one where the caller keeps no scratch."""
+    if tmp is None:
+        return np.empty((min(_PIECE_BLOCKS, b1 - b0), BLOCK), np.float32)
+    return tmp[b0 // _BLOCKS_PER_TASK]
 
 
 def _qmax(bits: int) -> float:
@@ -187,6 +297,50 @@ def unpack_nibbles(p: np.ndarray, n_vals: int) -> np.ndarray:
     return out[:n_vals]
 
 
+def _quantize_into(
+    flat: np.ndarray,
+    bits: int,
+    q_out: np.ndarray,
+    s_out: np.ndarray,
+    tmp: "np.ndarray | None",
+) -> None:
+    """Block-quantizes the contiguous fp32 ``flat`` into ``q_out`` (the
+    payload bytes of ``s_out.size`` whole blocks, nibble-packed for 4
+    bits; a short last block is padded with zeros) and ``s_out``. Every
+    pass writes in place, into a piece of ``tmp`` per task."""
+    n = flat.size
+    qmax = _qmax(bits)
+    bpb = BLOCK // (8 // bits)  # payload bytes per block
+
+    def work(b0: int, b1: int) -> None:
+        t = _task_tmp(tmp, b0, b1)
+        for p0 in range(b0, b1, _PIECE_BLOCKS):
+            p1 = min(p0 + _PIECE_BLOCKS, b1)
+            src = flat[p0 * BLOCK : min(p1 * BLOCK, n)]
+            if src.size != (p1 - p0) * BLOCK:  # tail: pad to whole blocks
+                padded = np.zeros((p1 - p0) * BLOCK, dtype=np.float32)
+                padded[: src.size] = src
+                src = padded
+            mat = src.reshape(p1 - p0, BLOCK)
+            buf = t[: p1 - p0]
+            s = s_out[p0:p1]
+            np.abs(mat, out=buf)
+            np.max(buf, axis=1, out=s)
+            s /= qmax
+            np.copyto(s, 1.0, where=(s == 0))
+            np.divide(mat, s[:, None], out=buf)
+            np.rint(buf, out=buf)
+            np.clip(buf, -qmax, qmax, out=buf)
+            if bits == 4:
+                q_out[p0 * bpb : p1 * bpb] = pack_nibbles(
+                    buf.astype(np.int8).reshape(-1)
+                )
+            else:
+                q_out[p0 * bpb : p1 * bpb] = buf.reshape(-1)
+
+    _parallel_over_blocks(s_out.size, work)
+
+
 def quantize_blockwise(
     flat: np.ndarray, bits: int = 8
 ) -> Tuple[np.ndarray, np.ndarray]:
@@ -194,54 +348,57 @@ def quantize_blockwise(
     values (the rowwise-fp8 analog of quantization.py:44-162). Returns
     (int8 payload, float32 scales); with ``bits=4`` the payload is
     nibble-packed (BLOCK/2 bytes per block)."""
-    n = flat.size
-    qmax = _qmax(bits)
-    blocks = (n + BLOCK - 1) // BLOCK
-    q = np.empty(blocks * BLOCK, dtype=np.int8)
+    blocks = (flat.size + BLOCK - 1) // BLOCK
+    q = np.empty(blocks * (BLOCK // (8 // bits)), dtype=np.int8)
     scales = np.empty(blocks, dtype=np.float32)
     flat = np.ascontiguousarray(flat, dtype=np.float32)
+    _quantize_into(flat, bits, q, scales, None)
+    return q, scales
+
+
+def _dequantize_sum(
+    out: np.ndarray,
+    peers: "Sequence[Tuple[np.ndarray, np.ndarray]]",
+    bits: int,
+    tmp: "np.ndarray | None",
+) -> None:
+    """``out[:]`` = the sum over ``peers`` — (payload, scales) pairs of
+    ``out.size // BLOCK`` whole blocks each — of ``float32(q) * scale``,
+    added in the order given. The first peer is decoded straight into
+    ``out``, each later one into a piece of ``tmp`` per task and added:
+    no zero fill, no payload-sized temporary. One peer is a plain
+    dequantize and needs no ``tmp``."""
+    bpb = BLOCK // (8 // bits)
+    out2 = out.reshape(-1, BLOCK)
 
     def work(b0: int, b1: int) -> None:
-        lo, hi = b0 * BLOCK, min(b1 * BLOCK, n)
-        chunk = flat[lo:hi]
-        pad = b1 * BLOCK - lo
-        if pad != chunk.size:  # tail: pad to whole blocks
-            padded = np.zeros(pad, dtype=np.float32)
-            padded[: chunk.size] = chunk
-            chunk = padded
-        mat = chunk.reshape(b1 - b0, BLOCK)
-        s = np.abs(mat).max(axis=1)
-        s /= qmax
-        np.copyto(s, 1.0, where=(s == 0))
-        scales[b0:b1] = s
-        # In-place pipeline: one fp32 temporary for the chunk only.
-        buf = mat / s[:, None]
-        np.rint(buf, out=buf)
-        np.clip(buf, -qmax, qmax, out=buf)
-        q[b0 * BLOCK : b1 * BLOCK] = buf.reshape(-1)
+        t = _task_tmp(tmp, b0, b1) if len(peers) > 1 else None
+        for p0 in range(b0, b1, _PIECE_BLOCKS):
+            p1 = min(p0 + _PIECE_BLOCKS, b1)
+            acc = out2[p0:p1]
+            for i, (q, scales) in enumerate(peers):
+                piece = q[p0 * bpb : p1 * bpb]
+                if bits == 4:
+                    piece = unpack_nibbles(piece, (p1 - p0) * BLOCK)
+                into = acc if i == 0 else t[: p1 - p0]
+                np.multiply(
+                    piece.reshape(-1, BLOCK),
+                    scales[p0:p1, None],
+                    out=into,
+                    dtype=np.float32,
+                    casting="unsafe",
+                )
+                if i:
+                    acc += into
 
-    _parallel_over_blocks(blocks, work)
-    if bits == 4:
-        return pack_nibbles(q), scales
-    return q, scales
+    _parallel_over_blocks(out2.shape[0], work)
 
 
 def dequantize_blockwise(
     q: np.ndarray, scales: np.ndarray, n: int, bits: int = 8
 ) -> np.ndarray:
-    blocks = scales.size
-    if bits == 4:
-        q = unpack_nibbles(q, blocks * BLOCK)
-    out = np.empty(blocks * BLOCK, dtype=np.float32)
-
-    def work(b0: int, b1: int) -> None:
-        mat = q[b0 * BLOCK : b1 * BLOCK].astype(np.float32).reshape(
-            b1 - b0, BLOCK
-        )
-        mat *= scales[b0:b1, None]
-        out[b0 * BLOCK : b1 * BLOCK] = mat.reshape(-1)
-
-    _parallel_over_blocks(blocks, work)
+    out = np.empty(scales.size * BLOCK, dtype=np.float32)
+    _dequantize_sum(out, [(q, scales)], bits, None)
     return out[:n]
 
 
@@ -487,11 +644,13 @@ def reduce_scatter_quantized(
                 shard = acc[start:end]
             else:
                 acc = _alltoall_chunk_reduce(
-                    pg, q_host, s_host, counts, bits
+                    pg, q_host, s_host, counts, bits, _wire_scratch(pg)
                 )
-                shard = acc[: end - start]
+                # ``acc`` is the next wire turn's to overwrite: what the
+                # caller keeps is a copy, made inside this turn.
+                shard = acc[: end - start].copy()
         if op == ReduceOp.AVG:
-            shard = shard / ws
+            shard /= ws
         return shard, (start, end)
 
     return FutureWork(_spawn_collective(pg, run))
@@ -585,11 +744,13 @@ def _alltoall_chunk_reduce(
     s_host: np.ndarray,
     counts: "List[int]",
     bits: int,
+    scratch: _WireScratch,
 ) -> np.ndarray:
     """Shared wire step of both quantized collectives: split the payload
     into per-rank block-aligned chunks, alltoall, and dequantize-accumulate
     every peer's contribution for MY chunk in fp32. Returns the fp32 sum of
-    this rank's chunk (counts[rank] * BLOCK values, padded)."""
+    this rank's chunk (counts[rank] * BLOCK values, padded) in a buffer of
+    ``scratch`` that the next wire turn overwrites."""
     bpb = BLOCK // (8 // bits)  # payload bytes per block
     q_chunks, s_chunks = [], []
     off = 0
@@ -600,12 +761,12 @@ def _alltoall_chunk_reduce(
     with trace_span("torchft::collectives::wire_alltoall"):
         all_q = pg.alltoall(q_chunks).wait()
         all_s = pg.alltoall(s_chunks).wait()
-    me = pg.rank()
-    n_me = counts[me] * BLOCK
-    with trace_span("torchft::collectives::wire_reduce"):
-        acc = np.zeros(n_me, np.float32)
-        for g_q, g_s in zip(all_q, all_s):
-            acc += dequantize_blockwise(g_q, g_s, n_me, bits)
+    mine = counts[pg.rank()]
+    with trace_span("torchft::collectives::wire_reduce") as span:
+        acc = scratch.turn("acc", np.float32, mine * BLOCK)
+        tmp = scratch.turn("tmp", np.float32, _task_tmp_shape(mine))
+        _dequantize_sum(acc, list(zip(all_q, all_s)), bits, tmp)
+        span.attrs.update(scratch.counts())
     return acc
 
 
@@ -622,9 +783,12 @@ def _quantized_wire_pipeline(
     input types freely — the wire format never depends on the caller's local
     array type.
 
-    Returns (q_final, s_final) int8+scales for the full buffer, or, for tiny
-    payloads (fewer blocks than ranks: allgather-all fallback, no chunking),
-    the fully-reduced fp32 array of length ``n`` directly.
+    Returns (q_final, s_final) int8+scales for the full buffer, lent by
+    the group's :class:`_WireScratch` for as long as the caller (or a
+    transfer it started) holds them, or, for tiny payloads (fewer blocks
+    than ranks: allgather-all fallback, no chunking), the fully-reduced
+    fp32 array of length ``n`` directly. The caller holds ``pg``'s wire
+    turn.
     """
     ws = pg.size()
     blocks = s_host.size
@@ -639,16 +803,25 @@ def _quantized_wire_pipeline(
     # Contiguous block-aligned chunks so each chunk owns whole scales;
     # alltoall -> rank r reduces everyone's r-th chunk.
     counts = [len(c) for c in np.array_split(np.arange(blocks), ws)]
-    acc = _alltoall_chunk_reduce(pg, q_host, s_host, counts, bits)
-    with trace_span("torchft::collectives::wire_reduce"):
-        rq, rs = quantize_blockwise(acc, bits)
+    scratch = _wire_scratch(pg)
+    acc = _alltoall_chunk_reduce(pg, q_host, s_host, counts, bits, scratch)
+    bpb = BLOCK // (8 // bits)  # payload bytes per block
+    mine = acc.size // BLOCK
+    with trace_span("torchft::collectives::wire_reduce") as span:
+        rq = scratch.turn("rq", np.int8, mine * bpb)
+        rs = scratch.turn("rs", np.float32, mine)
+        tmp = scratch.turn("tmp", np.float32, _task_tmp_shape(mine))
+        _quantize_into(acc, bits, rq, rs, tmp)
+        span.attrs.update(scratch.counts())
     with trace_span("torchft::collectives::wire_allgather"):
-        gathered = pg.allgather([rq, np.asarray(rs)]).wait()
+        gathered = pg.allgather([rq, rs]).wait()
     # Joining the ranks' chunks is a host copy of the whole payload, not
     # socket time: booked with the other numpy work of the stage.
-    with trace_span("torchft::collectives::wire_reduce"):
-        q_final = np.concatenate([g[0] for g in gathered])
-        s_final = np.concatenate([g[1] for g in gathered])
+    with trace_span("torchft::collectives::wire_reduce") as span:
+        q_final, s_final = scratch.result(blocks * bpb, blocks)
+        np.concatenate([g[0] for g in gathered], out=q_final)
+        np.concatenate([g[1] for g in gathered], out=s_final)
+        span.attrs.update(scratch.counts())
     return q_final, s_final
 
 

@@ -210,6 +210,14 @@ class ProcessGroup:
     def shutdown(self) -> None:
         self.abort()
 
+    def _drop_wire_scratch(self) -> None:
+        """Lets go of the host buffers the quantized collectives keep on
+        this group between steps (``collectives._WireScratch``). Called
+        wherever a group tears its connections down — reconfigure, abort,
+        shutdown — so a dead or resized group pins no memory; a collective
+        still running keeps its own reference until it ends."""
+        self.__dict__.pop("_quant_wire_scratch", None)
+
     def errored(self) -> Optional[Exception]:
         """Latched async error, if any (reference: process_group.py:361-368)."""
         return None
@@ -619,6 +627,7 @@ class ProcessGroupSocket(ProcessGroup):
                 logger.warning("flight recorder dumped to %s", path)
 
     def _abort_locked(self) -> None:
+        self._drop_wire_scratch()
         for conn in self._peers.values():
             conn.close()
         self._peers = {}
