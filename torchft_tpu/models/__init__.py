@@ -19,4 +19,5 @@ from torchft_tpu.models.llama import (  # noqa: F401
     llama_debug,
     llama_moe_debug,
     llama_small,
+    olmoe_1b_7b,
 )

@@ -84,13 +84,24 @@ class LlamaConfig:
     num_experts: int = 0
     num_experts_per_tok: int = 2
     # Per-sequence expert buffer = capacity_factor * S * k / E tokens;
-    # overflow tokens pass through the residual only (standard GShard drop).
-    expert_capacity_factor: float = 1.25
-    # Switch/GShard load-balancing auxiliary loss coefficient: without it
-    # routing collapses onto a few experts and capacity-drops most tokens.
-    # MoEMLP sows the aux term under "intermediates"; the train loss adds
-    # coef * mean(aux) (parallel/train.py:_loss_fn).
+    # overflow tokens pass through the residual only (standard GShard drop),
+    # top-k gates renormalised, Switch's top-1 balance term. None = no
+    # capacity: every assignment is computed by a sorted (dropless)
+    # dispatch, the gates are the softmax's own values and the balance
+    # term counts all k choices (OLMoE, arXiv:2409.02060).
+    expert_capacity_factor: Optional[float] = 1.25
+    # Load-balancing auxiliary loss coefficient: without it routing
+    # collapses onto a few experts. MoEMLP sows the term under
+    # "intermediates"; the train loss adds coef * mean over layers
+    # (parallel/train.py:_loss_and_metrics).
     router_aux_coef: float = 0.01
+    # Router z-loss coefficient: mean_t logsumexp(router logits)^2, sown
+    # and averaged over layers like the balance term. 0 = not in the loss.
+    router_z_coef: float = 0.0
+    # RMSNorm (learned scale) over the WHOLE query projection and over the
+    # whole key projection, before the split into heads and before RoPE
+    # (OLMoE's form; not a per-head norm).
+    qk_norm: bool = False
     # Bound by parallel.train when attn_impl is 'ring' or 'ulysses'.
     attn_fn: Optional[Callable[..., jax.Array]] = None
 
@@ -114,6 +125,33 @@ def llama_small(**overrides: Any) -> LlamaConfig:
         num_kv_heads=4,
         head_dim=64,
         max_seq_len=2048,
+    )
+    return dataclasses.replace(cfg, **overrides)
+
+
+def olmoe_1b_7b(**overrides: Any) -> LlamaConfig:
+    """OLMoE-1B-7B (allenai/OLMoE-1B-7B-0125-Instruct config.json;
+    arXiv:2409.02060) at its published sizes: 64 experts of width 1024,
+    top-8 dropless and not renormalised, full-width QK-norm, both router
+    losses with the paper's coefficients. 6.9B parameters, 1.3B active:
+    override ``num_layers`` for what one chip holds."""
+    cfg = LlamaConfig(
+        vocab_size=50304,
+        hidden_size=2048,
+        intermediate_size=1024,
+        num_layers=16,
+        num_heads=16,
+        num_kv_heads=16,
+        head_dim=128,
+        max_seq_len=4096,
+        rope_theta=10000.0,
+        norm_eps=1e-5,
+        num_experts=64,
+        num_experts_per_tok=8,
+        expert_capacity_factor=None,
+        router_aux_coef=0.01,
+        router_z_coef=0.001,
+        qk_norm=True,
     )
     return dataclasses.replace(cfg, **overrides)
 
@@ -218,6 +256,11 @@ class Attention(nn.Module):
         q = dense(cfg.num_heads, "wq")(x)
         k = dense(cfg.num_kv_heads, "wk")(x)
         v = dense(cfg.num_kv_heads, "wv")(x)
+        if cfg.qk_norm:
+            whole = lambda t, name: RMSNorm(  # noqa: E731
+                cfg.norm_eps, cfg.param_dtype, name=name
+            )(t.reshape(*t.shape[:2], -1)).reshape(t.shape)
+            q, k = whole(q, "q_norm"), whole(k, "k_norm")
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
         if cfg.attn_impl in ("ring", "ulysses"):
@@ -275,17 +318,70 @@ class MLP(nn.Module):
         return proj(cfg.hidden_size, "down")(nn.silu(gate) * up)
 
 
-class MoEMLP(nn.Module):
-    """Mixture-of-experts MLP (top-k routing, GShard-style dense dispatch).
+@jax.custom_vjp
+def _sorted_rows(x: jax.Array, order: jax.Array, inv: jax.Array) -> jax.Array:
+    """Row ``order[i] // k`` of ``x`` [T, H] for each of the T*k sorted
+    assignments: every token's row k times, grouped by expert. ``inv`` is
+    the inverse permutation of ``order``. The transpose is written out as
+    a gather by ``inv`` and a sum over a token's k copies: autodiff's own
+    is a scatter-add, which a TPU runs row by row."""
+    return x[order // (order.shape[0] // x.shape[0])]
 
-    TPU-first formulation: routing is expressed as one-hot dispatch/combine
-    tensors and the expert FFN as batched einsums over stacked expert
-    weights [E, H, I] — everything is a large static-shape matmul the MXU
-    tiles, and sharding the E dim over the 'ep' mesh axis makes XLA insert
-    the dispatch all-to-all automatically. Tokens beyond an expert's
-    capacity are dropped (contribute only through the residual), the
-    standard GShard/Switch behavior. The reference has no MoE/EP anywhere
-    (SURVEY.md §2.3); this exceeds it the same way ring attention does.
+
+def _sorted_rows_fwd(x, order, inv):
+    return _sorted_rows(x, order, inv), (inv, x.shape[0])
+
+
+def _sorted_rows_bwd(res, g):
+    inv, tokens = res
+    per_token = g[inv].reshape(tokens, -1, g.shape[-1])
+    return per_token.sum(axis=1, dtype=jnp.float32).astype(g.dtype), None, None
+
+
+_sorted_rows.defvjp(_sorted_rows_fwd, _sorted_rows_bwd)
+
+
+@jax.custom_vjp
+def _permute_rows(x: jax.Array, perm: jax.Array, inv: jax.Array) -> jax.Array:
+    """``x[perm]`` for a permutation ``perm`` with inverse ``inv``: the
+    transpose is ``g[inv]``, a gather too."""
+    return x[perm]
+
+
+def _permute_rows_fwd(x, perm, inv):
+    return x[perm], inv
+
+
+def _permute_rows_bwd(inv, g):
+    return g[inv], None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+class MoEMLP(nn.Module):
+    """Mixture-of-experts MLP: a float32 softmax router, top-k experts per
+    token, SwiGLU experts stacked as [E, H, I] weights. Two dispatches,
+    chosen by ``cfg.expert_capacity_factor``:
+
+    - a number: GShard-style dense dispatch. Routing is one-hot
+      dispatch/combine tensors [B,S,E,C] and the expert FFN batched
+      einsums, so sharding the E dim over the 'ep' mesh axis makes XLA
+      insert the all-to-all. Tokens beyond an expert's per-sequence
+      capacity C are dropped (they contribute only through the residual),
+      the top-k gates are renormalised, the balance term is Switch's
+      (top-1 fractions).
+    - None: sorted, dropless. The T*k assignments are stable-sorted by
+      expert, each expert multiplies its own contiguous rows (a grouped
+      matmul over ``group_sizes``), and the rows go back by the inverse
+      permutation. Memory is O(T*k*H) whatever the routing, nothing is
+      dropped, the gates are the softmax's own values and the balance
+      term counts all k choices: OLMoE's layer (arXiv:2409.02060).
+
+    Sown per layer under "intermediates" (parallel/train.py reads them by
+    name): ``router_aux``, ``router_z``, ``moe_max_load`` (largest
+    expert's assignments over the mean), ``moe_dropped`` (assignments not
+    computed). The reference has no MoE/EP anywhere (SURVEY.md §2.3).
     """
 
     cfg: LlamaConfig
@@ -299,8 +395,7 @@ class MoEMLP(nn.Module):
             raise ValueError(
                 f"num_experts_per_tok ({K}) > num_experts ({E})"
             )
-        B, S, H = x.shape
-        C = max(int(cfg.expert_capacity_factor * S * K / E), 1)
+        H = x.shape[-1]
 
         # Router in fp32 for numerically stable softmax/top-k.
         router_logits = nn.Dense(
@@ -312,38 +407,8 @@ class MoEMLP(nn.Module):
         )(x.astype(jnp.float32))  # [B,S,E]
         probs = jax.nn.softmax(router_logits, axis=-1)
         gate_vals, gate_idx = jax.lax.top_k(probs, K)  # [B,S,K]
-        gate_vals = gate_vals / jnp.maximum(
-            gate_vals.sum(-1, keepdims=True), 1e-9
-        )
-
-        # Switch-style load-balancing aux loss: E * sum_e f_e * P_e, where
-        # f_e = fraction of tokens whose TOP choice is e and P_e = mean
-        # router prob of e. Minimized (=1) at uniform routing. Sown so the
-        # train loss can add cfg.router_aux_coef * mean over layers.
-        top1 = jax.nn.one_hot(gate_idx[..., 0], E, dtype=jnp.float32)
-        f_e = top1.mean(axis=(0, 1))  # [E]
-        p_e = probs.mean(axis=(0, 1))
-        self.sow("intermediates", "router_aux", E * jnp.sum(f_e * p_e))
-
-        # Capacity-bounded positions: k-th choices are lower priority than
-        # all (k-1)-th choices (carried counts), tokens in sequence order.
-        counts = jnp.zeros((B, E), jnp.float32)
-        dispatch = jnp.zeros((B, S, E, C), jnp.float32)
-        combine = jnp.zeros((B, S, E, C), jnp.float32)
-        for k in range(K):  # K is tiny (2); static unroll
-            mk = jax.nn.one_hot(gate_idx[..., k], E, dtype=jnp.float32)
-            pos = counts[:, None, :] + jnp.cumsum(mk, axis=1) - mk  # [B,S,E]
-            keep = mk * (pos < C)
-            counts = counts + keep.sum(axis=1)
-            pos_tok = (pos * keep).sum(-1).astype(jnp.int32)  # [B,S]
-            slot = jax.nn.one_hot(pos_tok, C, dtype=jnp.float32)  # [B,S,C]
-            disp_k = keep[..., None] * slot[:, :, None, :]  # [B,S,E,C]
-            dispatch = dispatch + disp_k
-            combine = combine + disp_k * gate_vals[..., k][..., None, None]
-
-        xe = jnp.einsum(
-            "bsec,bsh->bech", dispatch.astype(cfg.dtype), x.astype(cfg.dtype)
-        )  # [B,E,C,H]
+        lse = jax.nn.logsumexp(router_logits, axis=-1)
+        self.sow("intermediates", "router_z", jnp.mean(jnp.square(lse)))
 
         expert = lambda shape, name: self.param(  # noqa: E731
             name, nn.initializers.lecun_normal(), shape, cfg.param_dtype
@@ -351,6 +416,82 @@ class MoEMLP(nn.Module):
         w_gate = expert((E, H, cfg.intermediate_size), "experts_gate")
         w_up = expert((E, H, cfg.intermediate_size), "experts_up")
         w_down = expert((E, cfg.intermediate_size, H), "experts_down")
+        dropless = cfg.expert_capacity_factor is None
+        dispatch = self._sorted if dropless else self._capacity
+        return dispatch(x, probs, gate_vals, gate_idx, w_gate, w_up, w_down)
+
+    def _sow_routing(self, probs, load, fractions, dropped) -> None:
+        """``load`` [E]: assignments routed to each expert; ``fractions``
+        [E]: the f_e of the balance term E * sum_e f_e * P_e (1 at uniform
+        routing), P_e the mean router probability of e."""
+        E = load.shape[0]
+        p_e = probs.reshape(-1, E).mean(axis=0)
+        self.sow("intermediates", "router_aux", E * jnp.sum(fractions * p_e))
+        self.sow("intermediates", "moe_max_load", load.max() * E / load.sum())
+        self.sow("intermediates", "moe_dropped", dropped)
+
+    def _sorted(self, x, probs, gate_vals, gate_idx, w_gate, w_up, w_down):
+        cfg = self.cfg
+        E, K, H = cfg.num_experts, cfg.num_experts_per_tok, x.shape[-1]
+        T = x.shape[0] * x.shape[1]
+        flat_idx = gate_idx.reshape(T * K)
+        # A compare-and-sum, not bincount's scatter-add.
+        group_sizes = jnp.sum(
+            flat_idx[:, None] == jnp.arange(E, dtype=flat_idx.dtype)[None, :],
+            axis=0, dtype=jnp.int32,
+        )
+        load = group_sizes.astype(jnp.float32)
+        self._sow_routing(probs, load, load / (T * K), jnp.zeros(()))
+
+        order = jnp.argsort(flat_idx, stable=True)  # sorted row -> assignment
+        inv = jnp.argsort(order)  # assignment -> sorted row
+        xs = _sorted_rows(x.reshape(T, H).astype(cfg.dtype), order, inv)
+        gmm = lambda a, w: jax.lax.ragged_dot(  # noqa: E731
+            a, w, group_sizes, preferred_element_type=cfg.dtype
+        )
+        ys = gmm(nn.silu(gmm(xs, w_gate)) * gmm(xs, w_up), w_down)  # [T*K,H]
+        y = _permute_rows(ys, inv, order).reshape(T, K, H)
+        out = jnp.einsum(
+            "tkh,tk->th", y, gate_vals.reshape(T, K),
+            preferred_element_type=jnp.float32,
+        )
+        return out.reshape(x.shape).astype(x.dtype)
+
+    def _capacity(self, x, probs, gate_vals, gate_idx, w_gate, w_up, w_down):
+        cfg = self.cfg
+        E, K = cfg.num_experts, cfg.num_experts_per_tok
+        B, S, H = x.shape
+        C = max(int(cfg.expert_capacity_factor * S * K / E), 1)
+        gate_vals = gate_vals / jnp.maximum(
+            gate_vals.sum(-1, keepdims=True), 1e-9
+        )
+
+        # Capacity-bounded positions: k-th choices are lower priority than
+        # all (k-1)-th choices (carried counts), tokens in sequence order.
+        counts = jnp.zeros((B, E), jnp.float32)
+        load = jnp.zeros((E,), jnp.float32)
+        dispatch = jnp.zeros((B, S, E, C), jnp.float32)
+        combine = jnp.zeros((B, S, E, C), jnp.float32)
+        for k in range(K):  # K is tiny (2); static unroll
+            mk = jax.nn.one_hot(gate_idx[..., k], E, dtype=jnp.float32)
+            pos = counts[:, None, :] + jnp.cumsum(mk, axis=1) - mk  # [B,S,E]
+            keep = mk * (pos < C)
+            counts = counts + keep.sum(axis=1)
+            load = load + mk.sum(axis=(0, 1))
+            if k == 0:  # Switch: f_e = share of tokens whose TOP choice is e
+                top1_fractions = mk.mean(axis=(0, 1))
+            pos_tok = (pos * keep).sum(-1).astype(jnp.int32)  # [B,S]
+            slot = jax.nn.one_hot(pos_tok, C, dtype=jnp.float32)  # [B,S,C]
+            disp_k = keep[..., None] * slot[:, :, None, :]  # [B,S,E,C]
+            dispatch = dispatch + disp_k
+            combine = combine + disp_k * gate_vals[..., k][..., None, None]
+        self._sow_routing(
+            probs, load, top1_fractions, B * S * K - counts.sum()
+        )
+
+        xe = jnp.einsum(
+            "bsec,bsh->bech", dispatch.astype(cfg.dtype), x.astype(cfg.dtype)
+        )  # [B,E,C,H]
         hidden = nn.silu(
             jnp.einsum("bech,ehi->beci", xe, w_gate)
         ) * jnp.einsum("bech,ehi->beci", xe, w_up)

@@ -139,37 +139,60 @@ def _lm_head_projection(model: Transformer, params):
 
 
 def _apply_with_aux(model: Transformer, params, inputs, **kw):
-    """model.apply + the MoE router load-balancing aux term (mean of the
-    per-layer Switch aux values MoEMLP sows; 0.0 for dense models)."""
+    """model.apply + what MoEMLP sows per layer, by name and stacked over
+    the layers ({} for dense models): ``router_aux``, ``router_z``,
+    ``moe_max_load``, ``moe_dropped``."""
     if model.cfg.num_experts <= 0:
-        return model.apply({"params": params}, inputs, **kw), jnp.zeros(())
+        return model.apply({"params": params}, inputs, **kw), {}
     out, inter = model.apply(
         {"params": params}, inputs, mutable=["intermediates"], **kw
     )
-    vals = [
-        jnp.ravel(leaf)
-        for leaf in jax.tree_util.tree_leaves(inter)
-    ]
-    aux = (
-        jnp.concatenate(vals).mean() if vals else jnp.zeros(())
-    )
-    return out, aux
+    sown: dict = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(inter):
+        name = next(
+            k.key for k in reversed(path) if isinstance(k, jax.tree_util.DictKey)
+        )
+        sown.setdefault(name, []).append(jnp.ravel(leaf))
+    return out, {k: jnp.concatenate(v) for k, v in sown.items()}
 
 
-def _loss_fn(model: Transformer, params, inputs, targets, mask):
+def _loss_and_metrics(model: Transformer, params, inputs, targets, mask):
+    """(loss, router metrics). The loss is the mean next-token
+    cross-entropy plus, for a model with experts, ``router_aux_coef`` x
+    the load-balancing term and ``router_z_coef`` x the router z-loss,
+    each a mean over the layers. The metrics are {} for a dense model,
+    else ``router_aux``, ``router_z``, ``moe_max_load`` (worst layer) and
+    ``moe_dropped`` (assignments not computed, all layers)."""
     B, S = inputs.shape
     C = min(_LOSS_CHUNK, S)
     mask_f = mask.astype(jnp.float32)
     denom = jnp.maximum(mask_f.sum(), 1.0)
-    aux_coef = getattr(model.cfg, "router_aux_coef", 0.0)
+    cfg = model.cfg
+
+    def with_router_terms(ce, sown):
+        if not sown:
+            # The zero term is the dense step's jaxpr as it always was: a
+            # persistent compile cache keyed on it keeps hitting.
+            return ce + cfg.router_aux_coef * jnp.zeros(()), {}
+        metrics = {
+            "router_aux": sown["router_aux"].mean(),
+            "router_z": sown["router_z"].mean(),
+            "moe_max_load": sown["moe_max_load"].max(),
+            "moe_dropped": sown["moe_dropped"].sum(),
+        }
+        loss = ce + cfg.router_aux_coef * metrics["router_aux"]
+        if cfg.router_z_coef:
+            loss = loss + cfg.router_z_coef * metrics["router_z"]
+        return loss, jax.lax.stop_gradient(metrics)
+
     if S % C != 0:  # odd seq len: the plain full-logits path
-        logits, aux = _apply_with_aux(model, params, inputs)
+        logits, sown = _apply_with_aux(model, params, inputs)
         losses = optax.softmax_cross_entropy_with_integer_labels(
             logits, targets
         )
-        return (losses * mask_f).sum() / denom + aux_coef * aux
+        return with_router_terms((losses * mask_f).sum() / denom, sown)
 
-    h, aux = _apply_with_aux(model, params, inputs, return_hidden=True)
+    h, sown = _apply_with_aux(model, params, inputs, return_hidden=True)
     w, head_dtype = _lm_head_projection(model, params)
     w = w.astype(head_dtype)
     n = S // C
@@ -192,7 +215,11 @@ def _loss_fn(model: Transformer, params, inputs, targets, mask):
     total, _ = jax.lax.scan(
         jax.checkpoint(chunk), jnp.zeros((), jnp.float32), (h_r, t_r, m_r)
     )
-    return total / denom + aux_coef * aux
+    return with_router_terms(total / denom, sown)
+
+
+def _loss_fn(model: Transformer, params, inputs, targets, mask):
+    return _loss_and_metrics(model, params, inputs, targets, mask)[0]
 
 
 def make_train_step(
@@ -204,7 +231,9 @@ def make_train_step(
     accum_steps: int = 1,
 ) -> Callable[[TrainState, Any], Tuple[TrainState, Any]]:
     """batch = {"inputs": [B,S] i32, "targets": [B,S] i32, "mask": [B,S]}.
-    Returns jitted (state, batch) -> (state, metrics).
+    Returns jitted (state, batch) -> (state, metrics): ``loss``,
+    ``grad_norm`` and, for a model with experts, the router metrics of
+    ``_loss_and_metrics`` (means over the microbatches when accumulating).
 
     ``accum_steps > 1`` runs gradient accumulation: the global batch is
     split into ``accum_steps`` microbatches along the batch dim and
@@ -222,16 +251,17 @@ def make_train_step(
 
     def grads_and_loss(params, batch):
         inputs = jax.lax.with_sharding_constraint(batch["inputs"], bsh)
-        loss, grads = jax.value_and_grad(
-            lambda p: _loss_fn(
+        (loss, router), grads = jax.value_and_grad(
+            lambda p: _loss_and_metrics(
                 model, p, inputs, batch["targets"], batch["mask"]
-            )
+            ),
+            has_aux=True,
         )(params)
-        return loss, grads
+        return loss, router, grads
 
     def step_fn(state: TrainState, batch) -> Tuple[TrainState, Any]:
         if accum_steps <= 1:
-            loss, grads = grads_and_loss(state.params, batch)
+            loss, router, grads = grads_and_loss(state.params, batch)
         else:
             B = batch["inputs"].shape[0]
             if B % accum_steps != 0:
@@ -262,18 +292,19 @@ def make_train_step(
 
             def body(carry, mb):
                 acc_g, acc_loss = carry
-                loss, grads = grads_and_loss(state.params, mb)
+                loss, router, grads = grads_and_loss(state.params, mb)
                 acc_g = jax.tree_util.tree_map(
                     lambda a, g: a + g.astype(jnp.float32), acc_g, grads
                 )
-                return (acc_g, acc_loss + loss), None
+                return (acc_g, acc_loss + loss), router
 
-            (gsum, loss_sum), _ = jax.lax.scan(
+            (gsum, loss_sum), routers = jax.lax.scan(
                 body, (g0, jnp.zeros((), jnp.float32)), micro
             )
             inv = 1.0 / accum_steps
             grads = jax.tree_util.tree_map(lambda g: g * inv, gsum)
             loss = loss_sum * inv
+            router = {k: v.mean() for k, v in routers.items()}
         updates, opt_state = optimizer.update(
             grads, state.opt_state, state.params
         )
@@ -282,7 +313,7 @@ def make_train_step(
         new_state = TrainState(
             step=state.step + 1, params=params, opt_state=opt_state
         )
-        return new_state, {"loss": loss, "grad_norm": gnorm}
+        return new_state, {"loss": loss, "grad_norm": gnorm, **router}
 
     return jax.jit(
         step_fn,
@@ -296,17 +327,21 @@ def make_grad_step(
     model: Transformer,
     mesh: Mesh,
     shardings: TrainState,
-) -> Callable[[Any, Any], Tuple[jax.Array, Any]]:
+    with_metrics: bool = False,
+) -> Callable[[Any, Any], Tuple[Any, Any]]:
     """(params, batch) -> (loss, grads): the DDP variant where the optimizer
-    update is applied *after* the Manager's outer-axis gradient allreduce."""
+    update is applied *after* the Manager's outer-axis gradient allreduce.
+    ``with_metrics``: ((loss, router metrics), grads), the metrics those
+    of ``_loss_and_metrics`` ({} for a dense model)."""
     bsh = batch_sharding(mesh)
     batch_sh = {"inputs": bsh, "targets": bsh, "mask": bsh}
 
     def fn(params, batch):
         return jax.value_and_grad(
-            lambda p: _loss_fn(
+            lambda p: (_loss_and_metrics if with_metrics else _loss_fn)(
                 model, p, batch["inputs"], batch["targets"], batch["mask"]
-            )
+            ),
+            has_aux=with_metrics,
         )(params)
 
     return jax.jit(

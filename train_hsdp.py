@@ -33,7 +33,7 @@ def main() -> int:
     parser = argparse.ArgumentParser()
     parser.add_argument("--steps", type=int, default=20)
     parser.add_argument(
-        "--model", choices=["debug", "small", "moe", "pipeline"],
+        "--model", choices=["debug", "small", "moe", "olmoe", "pipeline"],
         default="debug",
     )
     parser.add_argument("--batch", type=int, default=8)
@@ -97,7 +97,12 @@ def main() -> int:
 
     from torchft_tpu.device_mesh import ft_init_device_mesh
     from torchft_tpu.manager import Manager
-    from torchft_tpu.models import llama_debug, llama_moe_debug, llama_small
+    from torchft_tpu.models import (
+        llama_debug,
+        llama_moe_debug,
+        llama_small,
+        olmoe_1b_7b,
+    )
     from torchft_tpu.parallel import auto_mesh
     from torchft_tpu.parallel.train import (
         build_model,
@@ -150,8 +155,9 @@ def main() -> int:
         )
         loss_fn = make_pipeline_loss(cfg, mesh, n_micro=2)
         bsh = NamedSharding(mesh, P("dp", None))
+        # (loss, no router metrics), grads: make_grad_step's form below.
         grad_step = jax.jit(
-            jax.value_and_grad(loss_fn),
+            jax.value_and_grad(lambda p, b: (loss_fn(p, b), {}), has_aux=True),
             in_shardings=(
                 shardings.params,
                 {"inputs": bsh, "targets": bsh, "mask": bsh},
@@ -163,6 +169,9 @@ def main() -> int:
             "debug": llama_debug,
             "small": llama_small,
             "moe": llama_moe_debug,
+            # The published sizes: 6.9B parameters, for a group's mesh
+            # of chips, not for one.
+            "olmoe": olmoe_1b_7b,
         }[args.model]()
         if args.attn != "default":
             import dataclasses
@@ -172,7 +181,7 @@ def main() -> int:
         state, shardings = init_train_state(
             model, mesh, jax.random.PRNGKey(0), (B, S)
         )
-        grad_step = make_grad_step(model, mesh, shardings)
+        grad_step = make_grad_step(model, mesh, shardings, with_metrics=True)
     params, opt_state = state.params, state.opt_state
 
     # TORCHFT_PERF: record the compiled step's FLOPs/bytes once (same
@@ -333,7 +342,8 @@ def main() -> int:
                 ),
                 "mask": jnp.ones((B, S), jnp.int32),
             }
-            loss, grads = grad_step(params, batch)  # inner: compiled HSDP
+            # inner: compiled HSDP; `router` is {} for a model with no experts
+            (loss, router), grads = grad_step(params, batch)
             grads = mm.allreduce_grads(
                 grads,
                 should_quantize=args.quantize,
@@ -348,9 +358,11 @@ def main() -> int:
                     params, opt_state = apply_step(params, opt_state, grads)
             if committed:
                 losses.append(float(loss))
+                router = {k: float(v) for k, v in router.items()}
                 logging.info(
-                    "[group %s] step %d loss %.4f participants %d%s",
+                    "[group %s] step %d loss %.4f participants %d%s%s",
                     group, step, losses[-1], mm.replica_size(),
+                    "".join(f" {k} {v:.4g}" for k, v in router.items()),
                     _perf.format_step_metrics(
                         _perf.step_metrics(
                             "hsdp_grad_step", time.time() - t_step0
@@ -364,6 +376,7 @@ def main() -> int:
                         num_participants=mm.replica_size(),
                         committed=1.0,
                         step_s=time.time() - t_step0,
+                        **router,
                     )
                 if ckpt is not None:
                     ckpt.on_commit(manager.current_step(), durable_state_fn)
