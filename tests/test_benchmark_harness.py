@@ -20,17 +20,20 @@ from benchmark import cells
 from benchmark import conftest as _outgrown
 
 MODULES = (
-    "test_arch", "test_flops", "test_reference", "test_run_end_to_end",
-    "test_span_metrics", "test_trace_reduce", "test_wire_fresh_bytes",
+    "test_ar_pack_fresh_bytes", "test_arch", "test_flops", "test_reference",
+    "test_run_end_to_end", "test_span_metrics", "test_trace_reduce",
+    "test_wire_fresh_bytes",
 )
 SUBPROCESS_RUNS = {
     "test_tiny_raw_cell_end_to_end_metrics",
     "test_tiny_two_group_cell_traced_per_layer_metrics",
     "test_a_cell_of_the_repos_table_needs_a_tpu",
 }
-# Two tests assert that the table is what it was when they were written
-# (benchmark/conftest.py says which, and the one-line edits a benchmark PR
-# owes them); they are restated below for the table as it is.
+_TESTS = [importlib.import_module(f"benchmark.tests.{m}") for m in MODULES]
+# Three tests assert that the table is what it was when they were written
+# (benchmark/conftest.py says which two, test_ar_pack_fresh_bytes.py files
+# the third there as it is imported, each with the one-line edit a
+# benchmark PR owes it); they are restated below for the table as it is.
 OUTGROWN = set(_outgrown.OUTGROWN)
 
 
@@ -40,8 +43,8 @@ def _is_fixture(obj):
     )
 
 
-for _mod in MODULES:
-    for _name, _obj in vars(importlib.import_module(f"benchmark.tests.{_mod}")).items():
+for _mod in _TESTS:
+    for _name, _obj in vars(_mod).items():
         if _is_fixture(_obj):
             globals()[_name] = _obj
         elif _name.startswith("test_") and callable(_obj):
@@ -74,6 +77,26 @@ def test_wire_fresh_bytes_step_is_an_entry_for_the_four_chip_cell_only():
     entries = {m["name"]: m for m in cells.load_json(REPO_TABLE)["per_layer"]}
     assert entries["wire_fresh_bytes_step"]["workloads"] == ["mistral-ft4"]
     assert entries["wire_fresh_bytes_step"]["source"] == "program_counter"
+
+
+def test_every_span_metric_is_an_entry_of_the_table_for_its_path_only():
+    from benchmark.tests import test_span_metrics
+
+    new = "ar_pack_fresh_bytes_step"
+    ten = {m.__name__.rsplit(".", 1)[1] for m in test_span_metrics.ALL}
+    entries = {m["name"]: m for m in cells.load_json(REPO_TABLE)["per_layer"]}
+    for name in ten | {new}:
+        assert entries[name]["layer"] == "replica-axis allreduce"
+        assert entries[name]["moves"] == "tok_s_chip"
+    ft1 = {m["name"] for m in cells.load_cell("mistral-ft1").per_layer}
+    ft4 = {m["name"] for m in cells.load_cell("mistral-ft4").per_layer}
+    assert {n for n in ft1 | ft4 if n.startswith(("ar_", "wire_"))} - {
+        "wire_ms", "wire_bytes_step", "wire_fresh_bytes_step", new} == ten
+    assert "ar_pull_ms" not in ft4 and "wire_busy_ms" not in ft1
+    assert new in ft1 - ft4
+    for cell in ("mistral-raw", "internlm2-raw", "olmoe-raw"):
+        raw = {m["name"] for m in cells.load_cell(cell).per_layer}
+        assert not (ten | {new}) & raw
 
 
 # -- the architecture `olmoe` and its configuration ----------------------

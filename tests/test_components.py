@@ -105,6 +105,9 @@ class _FakeManager:
     def participating_rank(self):
         return self.rank
 
+    def errored(self):
+        return None
+
     def allreduce(self, tensors, should_quantize=False, quantize_bits=8, on_local_quantized=None):
         from torchft_tpu.work import DummyWork
 

@@ -278,10 +278,11 @@ def test_host_path_spans_and_hand_worked_bytes(journal):
     assert pull[ATTRS] == {"nbytes": 2000}  # the two device leaves
     assert len(_by_name(spans, "torchft::ddp::grads_wait")) == 1
     packs = _by_name(spans, "torchft::ddp::pack")
+    # a wrapper's first call sizes its bucket buffers: every byte fresh
     assert [p[ATTRS] for p in packs] == [
-        {"bucket": 0, "nbytes": 1200},
-        {"bucket": 1, "nbytes": 800},
-        {"bucket": 2, "nbytes": 400},
+        {"bucket": 0, "nbytes": 1200, "fresh_bytes": 1200, "reused_bytes": 0},
+        {"bucket": 1, "nbytes": 800, "fresh_bytes": 800, "reused_bytes": 0},
+        {"bucket": 2, "nbytes": 400, "fresh_bytes": 400, "reused_bytes": 0},
     ]
     copies = _by_name(spans, "torchft::manager::host_copy")
     # the concatenated bucket is writable: to_mutable copies nothing

@@ -20,6 +20,37 @@ from torchft_tpu.manager import Manager
 from torchft_tpu.telemetry import DDP_ROOT_SPAN, trace_span
 
 
+class _PackBuffers:
+    """The flat bucket buffers one ``DistributedDataParallel`` keeps
+    between calls: a gradient-sized numpy array made every step is
+    mapped, page-faulted in as it is written and unmapped again, which
+    cost ``pack`` more than its copy (the fault ``_WireScratch`` removed
+    from the quantized wire stage). A set is valid for one bucket layout,
+    ``(dtype, element count)`` per bucket; a call with another layout
+    sizes a new set."""
+
+    def __init__(self) -> None:
+        self._layout: Optional[Tuple[Tuple[Any, int], ...]] = None
+        self._flats: List[np.ndarray] = []
+
+    def take(
+        self, layout: Tuple[Tuple[Any, int], ...]
+    ) -> Tuple[List[np.ndarray], bool]:
+        """The set for ``layout``, one flat array a bucket, and whether it
+        was kept from the last call (else: made now, pages untouched)."""
+        kept = layout == self._layout
+        if not kept:
+            self._layout = layout
+            self._flats = [np.empty(n, dtype=dtype) for dtype, n in layout]
+        return self._flats, kept
+
+    def retire(self) -> None:
+        """Lets the set go; the next call sizes a new one. Whoever still
+        holds the old arrays (a collective's thread, the caller's leaves)
+        keeps them alive and to itself."""
+        self._layout, self._flats = None, []
+
+
 class DistributedDataParallel:
     """Averages gradient pytrees across the fault-tolerant replica axis.
 
@@ -28,6 +59,32 @@ class DistributedDataParallel:
         ddp = DistributedDataParallel(manager)
         grads = grad_fn(params, batch)          # inner-axis psum inside jit
         grads = ddp.allreduce_grads(grads)      # outer-axis average over DCN
+
+    **Who owns what comes back** (host path: fp32, or quantized on the
+    host). The leaves ``allreduce_grads`` returns are views of flat bucket
+    buffers this wrapper owns and writes again in its next call: they are
+    valid until the next ``allreduce_grads`` on the same wrapper, and a
+    caller that keeps one longer copies it (``np.array(leaf)``; off-TPU a
+    ``jnp.asarray(leaf)`` may alias the host memory rather than copy it).
+    The training loops consume them before that: ``apply_step`` takes them
+    as numpy arguments, and the next call packs only after ``grads_wait``
+    on gradients that depend on that ``apply_step``'s output, so its
+    host-to-device transfer has finished by data dependence. The device
+    (int8) path and ``PureDistributedDataParallel`` return new arrays.
+
+    **A failed step retires the buffers.** If the call raises, or the
+    manager holds an error when it ends (a bucket's work failed or timed
+    out), the set is dropped and the next call sizes a new one: an aborted
+    collective's thread may still hold, and write into, the array it was
+    given (``ProcessGroupSocket.abort`` does not join it). The step after
+    a failure pays for fresh pages once.
+
+    ``torchft::ddp::pack`` says which happened, per bucket:
+    ``fresh_bytes`` is host memory the pack had to allocate (a new set:
+    the wrapper's first call, another layout, the call after a failed
+    step; and the compensated copy under error feedback), ``reused_bytes``
+    what it wrote into memory it already had. Steady state is
+    ``fresh_bytes`` 0 and ``reused_bytes`` = ``nbytes``.
     """
 
     def __init__(
@@ -44,6 +101,7 @@ class DistributedDataParallel:
         from torchft_tpu.collectives import ErrorFeedback
 
         self._residuals = ErrorFeedback(quantize_bits)
+        self._pack_buffers = _PackBuffers()
 
     def allreduce_grads(
         self,
@@ -75,10 +133,18 @@ class DistributedDataParallel:
           unlike DiLoCo's residuals which track a whole discarded local
           stream.
         """
-        with trace_span(DDP_ROOT_SPAN) as root:
-            compute_s, out = self._allreduce_grads(
-                grads, should_quantize, quantize_bits
-            )
+        clean = False
+        try:
+            with trace_span(DDP_ROOT_SPAN) as root:
+                compute_s, out = self._allreduce_grads(
+                    grads, should_quantize, quantize_bits
+                )
+            clean = self._manager.errored() is None
+        finally:
+            if not clean:
+                # A collective that failed or was aborted may still write
+                # into the bucket it was given: never pack into it again.
+                self._pack_buffers.retire()
         # The ledger's exposed_comm: the caller's time in here, less the
         # wait for the backward pass that produces the gradients.
         self._manager.note_exposed_comm(root.elapsed_s - compute_s)
@@ -176,15 +242,31 @@ class DistributedDataParallel:
             host: List[np.ndarray] = [np.asarray(x) for x in leaves]
 
         buckets = self._bucketize(host)
+        flats, kept = self._pack_buffers.take(
+            tuple(
+                (host[idx_list[0]].dtype, sum(host[i].size for i in idx_list))
+                for idx_list in buckets
+            )
+        )
         works: List[Tuple[Any, np.ndarray, List[int]]] = []
         for b_idx, idx_list in enumerate(buckets):
             on_quantized = None
             with trace_span("torchft::ddp::pack", bucket=b_idx) as pack:
-                flat = np.concatenate([host[i].reshape(-1) for i in idx_list])
+                flat = flats[b_idx]
+                np.concatenate(
+                    [host[i].reshape(-1) for i in idx_list], out=flat
+                )
+                reused = flat.nbytes if kept else 0
+                fresh = flat.nbytes - reused
                 if should_quantize and self._error_feedback:
-                    flat = self._residuals.compensate(b_idx, flat)
+                    compensated = self._residuals.compensate(b_idx, flat)
+                    if compensated is not flat:  # flat + residual: new memory
+                        fresh += compensated.nbytes
+                        flat = compensated
                     on_quantized = self._residuals.make_hook(b_idx)
-                pack.attrs["nbytes"] = flat.nbytes
+                pack.attrs.update(
+                    nbytes=flat.nbytes, fresh_bytes=fresh, reused_bytes=reused
+                )
             work = self._manager.allreduce(
                 flat,
                 should_quantize=should_quantize,
