@@ -166,7 +166,7 @@ def test_device_and_host_bucket_layouts_identical() -> None:
 
 @pytest.mark.parametrize("bits", [8, 4])
 def test_device_and_host_wire_payloads_identical(monkeypatch, bits) -> None:
-    """ADVICE r4 #4: wire symmetry between a device-path (TPU) replica
+    """Wire symmetry between a device-path (TPU) replica
     and a host-path (CPU) peer rests on the device path's per-bucket
     payload matching ``quantize_blockwise`` of the concatenated host
     flat BYTE-FOR-BYTE — layout equality alone

@@ -598,7 +598,7 @@ def test_error_feedback_residuals_reset_on_heal():
 
 
 def test_error_feedback_generation_guard_drops_stale_hook_writes():
-    """ADVICE r3: an in-flight allreduce issued pre-heal must not
+    """An in-flight allreduce issued pre-heal must not
     re-insert a stale residual after _load_state_dict cleared the store.
     The hook captures its creation-time generation; clear() bumps it,
     so the late collective-thread write is dropped."""

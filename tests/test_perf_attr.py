@@ -1,7 +1,6 @@
 """Perf attribution plane: interval-overlap math (telemetry), the
-critical-path profiler (tools/perf_report.py), the shared MFU module
-(torchft_tpu/perf.py), and the benchmark ledger + regression gate
-(tools/perf_ledger.py, tools/perf_gate.py).
+critical-path profiler (tools/perf_report.py) and the trainers' MFU
+module (torchft_tpu/perf.py).
 
 The synthetic journals pin EXACT ground truth: each fixture constructs
 events whose phase windows are known by construction (fully-hidden,
@@ -24,8 +23,6 @@ sys.path.insert(
     ),
 )
 
-import perf_gate  # noqa: E402
-import perf_ledger  # noqa: E402
 import perf_report  # noqa: E402
 
 
@@ -88,25 +85,62 @@ def test_fully_hidden_allreduce():
     assert attr["exposed_frac"] == pytest.approx(0.0)
 
 
-def test_fully_exposed_allreduce():
+def _blocked_steps():
+    """Four steps on two replicas that do little but wait for a socket
+    allreduce: per step 0.97 ms quorum, 1.65 ms grad compute, 190.44 ms
+    blocked allreduce, 0.45+0.83 ms commit/apply."""
+    evs = []
+    t = 1000.0
+    for step in range(4):
+        for rid in ("r0", "r1"):
+            q, g, ar, cm = 0.97e-3, 1.65e-3, 190.44e-3, (0.45 + 0.83) * 1e-3
+            evs += [
+                _ev("quorum_start", t, step=step, rid=rid),
+                _ev("quorum_ready", t + q, step=step, rid=rid, elapsed_s=q),
+                _ev("allreduce_issue", t + q + g, step=step, rid=rid),
+                _ev("allreduce_complete", t + q + g + ar, step=step,
+                    rid=rid, elapsed_s=ar),
+                _ev("commit_gate", t + q + g + ar + cm, step=step, rid=rid,
+                    elapsed_s=cm, committed=True),
+            ]
+        t += 0.2
+    return evs
+
+
+@pytest.mark.parametrize("evs, total_s, allreduce_s, compute_s, fingerprint", [
     # The trainer blocked for the ENTIRE in-flight window: issue at 0.1,
     # complete at 1.0, wait_s=0.9. No compute anywhere.
-    evs = _step_events(
-        100.0, quorum_s=0.1, issue_at=0.1, complete_at=1.0, wait_s=0.9,
-        commit_s=0.05,
-    )
-    attr = telemetry.comm_attribution(telemetry.step_phase_windows(evs))
-    assert attr["total_s"] == pytest.approx(1.05)
-    assert attr["allreduce_s"] == pytest.approx(0.9)
+    # 86% allreduce, 10% quorum, 5% commit (rounded).
+    pytest.param(
+        _step_events(100.0, quorum_s=0.1, issue_at=0.1, complete_at=1.0,
+                     wait_s=0.9, commit_s=0.05),
+        1.05, 0.9, 0.0, "a86>q10>m5", id="one_step"),
+    # A sliver of compute before a blocked allreduce 115 times its length:
+    # the whole run reads ~0.98 exposed, on every step of both replicas.
+    pytest.param(_blocked_steps(), 194.34e-3, 190.44e-3, 1.65e-3, "a98",
+                 id="four_steps_two_replicas"),
+])
+def test_fully_exposed_allreduce(evs, total_s, allreduce_s, compute_s,
+                                 fingerprint):
+    first = [e for e in evs if e["step"] == 0 and e["replica_id"] == "r0"]
+    attr = telemetry.comm_attribution(telemetry.step_phase_windows(first))
+    assert attr["total_s"] == pytest.approx(total_s)
+    assert attr["allreduce_s"] == pytest.approx(allreduce_s)
     assert attr["comm_hidden_s"] == pytest.approx(0.0)
-    assert attr["compute_s"] == pytest.approx(0.0)
+    assert attr["compute_s"] == pytest.approx(compute_s)
     assert attr["overlap_frac"] == pytest.approx(0.0)
-    assert attr["exposed_frac"] == pytest.approx(0.9 / 1.05)
+    assert attr["exposed_frac"] == pytest.approx(allreduce_s / total_s)
     assert telemetry.dominant_exposed(attr) == (
-        "allreduce", pytest.approx(0.9)
+        "allreduce", pytest.approx(allreduce_s)
     )
-    # 86% allreduce, 10% quorum, 5% commit (rounded): a86>q10>m5
-    assert telemetry.perf_fingerprint(attr) == "a86>q10>m5"
+    report = perf_report.analyze(evs)
+    assert perf_report.check(report) == []
+    assert report["summary"]["exposed_allreduce_frac"] == pytest.approx(
+        allreduce_s / total_s)
+    assert report["summary"]["dominant_exposed"] == "allreduce"
+    # Every step's critical-path fingerprint leads with exposed allreduce.
+    for srec in report["steps"].values():
+        assert srec["fingerprint"].startswith(fingerprint)
 
 
 def test_partial_overlap_allreduce():
@@ -186,38 +220,6 @@ def test_multi_replica_skew_critical_path():
     assert report["summary"]["exposed_allreduce_frac"] == pytest.approx(
         1.25 / 1.80
     )
-
-
-def test_bench_r05_ground_truth_regime():
-    """BENCH_r05's measured socket-PG DDP leg, reconstructed as a
-    journal: per step 0.97 ms quorum, 1.65 ms grad compute, 190.44 ms
-    blocked allreduce, 0.45+0.83 ms commit/apply → the profiler must
-    report the exposed-allreduce fraction within 10% of the ~0.98 the
-    artifact pins (190.44 / 194.54)."""
-    evs = []
-    t = 1000.0
-    for step in range(4):
-        for rid in ("r0", "r1"):
-            q, g, ar, cm = 0.97e-3, 1.65e-3, 190.44e-3, (0.45 + 0.83) * 1e-3
-            evs += [
-                _ev("quorum_start", t, step=step, rid=rid),
-                _ev("quorum_ready", t + q, step=step, rid=rid, elapsed_s=q),
-                _ev("allreduce_issue", t + q + g, step=step, rid=rid),
-                _ev("allreduce_complete", t + q + g + ar, step=step,
-                    rid=rid, elapsed_s=ar),
-                _ev("commit_gate", t + q + g + ar + cm, step=step, rid=rid,
-                    elapsed_s=cm, committed=True),
-            ]
-        t += 0.2
-    report = perf_report.analyze(evs)
-    assert perf_report.check(report) == []
-    frac = report["summary"]["exposed_allreduce_frac"]
-    assert abs(frac - 0.98) <= 0.10, frac
-    assert frac == pytest.approx(190.44 / 194.34, abs=0.01)
-    assert report["summary"]["dominant_exposed"] == "allreduce"
-    # Every step's critical-path fingerprint leads with exposed allreduce.
-    for srec in report["steps"].values():
-        assert srec["fingerprint"].startswith("a98")
 
 
 def test_perf_report_emit_round_trip(tmp_path):
@@ -319,133 +321,3 @@ def test_record_jit_cost_noop_when_knob_off(monkeypatch):
     perf.reset_step_costs()
     assert perf.record_jit_cost("toy2", None) is None
     assert perf.get_step_cost("toy2") is None
-
-
-# ---------------------------------------------------------------------------
-# Ledger + gate
-# ---------------------------------------------------------------------------
-
-
-def test_ledger_round_trip(tmp_path):
-    path = str(tmp_path / "ledger.jsonl")
-    r1 = perf_ledger.record(
-        "x.ms", 10.0, "ms", "lower", "x", "test", path=path
-    )
-    assert r1 is not None and perf_ledger.validate(r1) == []
-    perf_ledger.record("x.ms", 12.0, "ms", "lower", "x", "test", path=path)
-    perf_ledger.record("y.gib", 3.0, "GiB/s", "higher", "y", "test",
-                       path=path)
-    records = perf_ledger.load(path)
-    assert len(records) == 3
-    heads = perf_ledger.head(records)
-    assert heads["x.ms"]["value"] == 12.0
-    assert len(perf_ledger.history(records, "x.ms")) == 2
-    assert all(r["env"]["platform"] for r in records)
-
-
-def test_ledger_rejects_garbage_without_raising(tmp_path, capsys):
-    path = str(tmp_path / "ledger.jsonl")
-    assert perf_ledger.record(
-        "bad", float("nan"), "ms", "lower", "x", "t", path=path
-    ) is None
-    assert perf_ledger.record(
-        "bad", 1.0, "ms", "sideways", "x", "t", path=path
-    ) is None
-    assert perf_ledger.load(path) == []
-    assert "skipped" in capsys.readouterr().err
-
-
-def test_gate_passes_at_head_and_fails_on_regression(tmp_path):
-    ledger = str(tmp_path / "ledger.jsonl")
-    baselines = str(tmp_path / "baselines.json")
-    for v in (10.0, 10.5, 9.8):
-        perf_ledger.record("a.ms", v, "ms", "lower", "a", "t", path=ledger)
-    perf_ledger.record("b.gib", 4.0, "GiB/s", "higher", "b", "t",
-                       path=ledger)
-    doc = perf_gate.pin(ledger, baselines)
-    assert set(doc["metrics"]) == {"a.ms", "b.gib"}
-
-    # Head == baseline → everything ok.
-    result = perf_gate.compare(
-        perf_ledger.head(perf_ledger.load(ledger)),
-        perf_gate.load_baselines(baselines),
-    )
-    assert not result["regressions"] and not result["missing"]
-    assert len(result["ok"]) == 2
-
-    # Inject a deliberate regression on each direction.
-    perf_ledger.record("a.ms", 50.0, "ms", "lower", "a", "t", path=ledger)
-    perf_ledger.record("b.gib", 0.5, "GiB/s", "higher", "b", "t",
-                       path=ledger)
-    result = perf_gate.compare(
-        perf_ledger.head(perf_ledger.load(ledger)),
-        perf_gate.load_baselines(baselines),
-    )
-    assert {r["metric"] for r in result["regressions"]} == {"a.ms", "b.gib"}
-    rc = perf_gate.main(
-        ["--check", "--ledger", ledger, "--baselines", baselines]
-    )
-    assert rc == 1
-
-    # An improvement must pass.
-    perf_ledger.record("a.ms", 5.0, "ms", "lower", "a", "t", path=ledger)
-    perf_ledger.record("b.gib", 9.0, "GiB/s", "higher", "b", "t",
-                       path=ledger)
-    rc = perf_gate.main(
-        ["--check", "--ledger", ledger, "--baselines", baselines]
-    )
-    assert rc == 0
-
-
-def test_gate_missing_metric_fails_unpinned_passes(tmp_path):
-    ledger = str(tmp_path / "ledger.jsonl")
-    baselines = str(tmp_path / "baselines.json")
-    perf_ledger.record("a.ms", 10.0, "ms", "lower", "a", "t", path=ledger)
-    perf_gate.pin(ledger, baselines)
-
-    # New unpinned metric: reported, not fatal.
-    perf_ledger.record("new.ms", 1.0, "ms", "lower", "n", "t", path=ledger)
-    result = perf_gate.compare(
-        perf_ledger.head(perf_ledger.load(ledger)),
-        perf_gate.load_baselines(baselines),
-    )
-    assert [r["metric"] for r in result["unpinned"]] == ["new.ms"]
-    assert not result["regressions"] and not result["missing"]
-
-    # Pinned metric vanishing from the ledger: the trajectory went dark.
-    empty = str(tmp_path / "empty.jsonl")
-    result = perf_gate.compare(
-        perf_ledger.head(perf_ledger.load(empty)),
-        perf_gate.load_baselines(baselines),
-    )
-    assert [r["metric"] for r in result["missing"]] == ["a.ms"]
-    rc = perf_gate.main(
-        ["--check", "--ledger", empty, "--baselines", baselines]
-    )
-    assert rc == 1
-
-
-def test_noise_aware_tolerance():
-    flat = [{"value": 100.0}] * 5
-    assert perf_gate.noise_rel_tol(flat) == perf_gate.DEFAULT_REL_TOL
-    wobbly = [{"value": v} for v in (80.0, 120.0, 100.0)]
-    # spread = 40/100 → 1.5x = 0.6, capped at MAX_REL_TOL.
-    assert perf_gate.noise_rel_tol(wobbly) == perf_gate.MAX_REL_TOL
-    assert perf_gate.noise_rel_tol([{"value": 1.0}]) == \
-        perf_gate.DEFAULT_REL_TOL
-
-
-def test_repo_ledger_and_baselines_are_consistent():
-    """The committed BENCH_LEDGER.jsonl must satisfy the committed
-    PERF_BASELINES.json (the suite_gate perf lane runs this for real)."""
-    records = perf_ledger.load()
-    assert len(records) >= 3, "committed ledger went missing"
-    families = {r["family"] for r in records}
-    assert len(families) >= 3, f"expected >=3 metric families: {families}"
-    for r in records:
-        assert perf_ledger.validate(r) == [], r
-    result = perf_gate.compare(
-        perf_ledger.head(records), perf_gate.load_baselines()
-    )
-    assert result["regressions"] == [], result["regressions"]
-    assert result["missing"] == [], result["missing"]

@@ -1,9 +1,9 @@
 """Recovery forensics plane: the failure-episode detector
 (telemetry.detect_episodes), heal-transfer accounting (``heal_xfer``
 events from both checkpoint transports), the episode report / Chrome
-trace overlay (tools/recovery_report.py, tools/obs_trace.py), the
-obs_top TTR-budget column, and the recovery metrics' ledger extractor +
-regression gate.
+trace overlay (tools/recovery_report.py, tools/obs_trace.py) and the
+obs_top TTR-budget column. (The recovery drill's budgets:
+tests/test_drill_budgets.py.)
 
 The synthetic journals pin EXACT ground truth: a kill+heal fixture
 whose phase windows are known by construction (including an aborted
@@ -28,8 +28,6 @@ sys.path.insert(0, os.path.join(REPO, "tools"))
 import obs_report  # noqa: E402
 import obs_top  # noqa: E402
 import obs_trace  # noqa: E402
-import perf_gate  # noqa: E402
-import perf_ledger  # noqa: E402
 import recovery_report  # noqa: E402
 
 
@@ -458,95 +456,3 @@ def test_obs_top_ttr_budget_column():
     # A frame that drops the over-budget tag must fail the check.
     bad = frame.replace(" TTR_BUDGET", "")
     assert obs_top.check_frame(fleet, bad, ttr_budget_s=60.0)
-
-
-def _bench_recovery_doc():
-    report = recovery_report.analyze(kill_heal_fixture())
-    return {"drill": "recovery", "summary": report["summary"]}
-
-
-def test_recovery_extractor_metric_names():
-    rows = perf_ledger._recovery_records("live", _bench_recovery_doc())
-    metrics = {r[0]: r for r in rows}
-    assert "recovery.ttr_p50_s" in metrics
-    assert "recovery.ttr_p95_s" in metrics
-    for ph in telemetry.RECOVERY_PHASES:
-        assert f"recovery.phase_p95_s.{ph}" in metrics
-    assert "recovery.heal_gib_s.http" in metrics
-    m = metrics["recovery.ttr_p95_s"]
-    assert m[2] == "s" and m[3] == "lower" and m[4] == "recovery"
-    assert metrics["recovery.heal_gib_s.http"][3] == "higher"
-
-
-def test_recovery_gate_catches_ttr_regression(tmp_path):
-    """Pin the fixture's recovery metrics, then inject a 10x TTR
-    regression and a collapsed heal bandwidth: perf_gate must fail."""
-    ledger = str(tmp_path / "ledger.jsonl")
-    baselines = str(tmp_path / "baselines.json")
-    n = perf_ledger.record_report(
-        "recovery", _bench_recovery_doc(), "t", path=ledger
-    )
-    assert n >= 8
-    perf_gate.pin(ledger, baselines)
-    rc = perf_gate.main(
-        ["--check", "--ledger", ledger, "--baselines", baselines]
-    )
-    assert rc == 0
-    perf_ledger.record("recovery.ttr_p95_s", 68.0, "s", "lower",
-                       "recovery", "t", path=ledger)
-    perf_ledger.record("recovery.heal_gib_s.http", 0.001, "GiB/s",
-                       "higher", "recovery", "t", path=ledger)
-    result = perf_gate.compare(
-        perf_ledger.head(perf_ledger.load(ledger)),
-        perf_gate.load_baselines(baselines),
-    )
-    assert {r["metric"] for r in result["regressions"]} == {
-        "recovery.ttr_p95_s", "recovery.heal_gib_s.http",
-    }
-    rc = perf_gate.main(
-        ["--check", "--ledger", ledger, "--baselines", baselines]
-    )
-    assert rc == 1
-
-
-def test_recovery_gate_budget_mode(tmp_path):
-    """Budget-gated metrics ignore relative drift (bimodal clean-run TTR
-    must not flake the gate) but fail on an absolute budget breach; the
-    budget survives a re-pin."""
-    ledger = str(tmp_path / "ledger.jsonl")
-    baselines = str(tmp_path / "baselines.json")
-    perf_ledger.record_report(
-        "recovery", _bench_recovery_doc(), "t", path=ledger
-    )
-    perf_gate.pin(ledger, baselines,
-                  budgets={"recovery.ttr_p95_s": 60.0,
-                           "recovery.heal_gib_s.http": 0.02})
-    # 5x the baseline TTR but under budget: ok, not a regression.
-    perf_ledger.record("recovery.ttr_p95_s", 34.0, "s", "lower",
-                       "recovery", "t", path=ledger)
-    result = perf_gate.compare(
-        perf_ledger.head(perf_ledger.load(ledger)),
-        perf_gate.load_baselines(baselines),
-    )
-    assert not any(r["metric"] == "recovery.ttr_p95_s"
-                   for r in result["regressions"] + result["improvements"])
-    # Re-pin (no budgets arg): the budget must be preserved.
-    perf_gate.pin(ledger, baselines)
-    doc = perf_gate.load_baselines(baselines)
-    assert doc["metrics"]["recovery.ttr_p95_s"]["budget"] == 60.0
-    # Breach both directions: over the TTR ceiling, under the GiB/s floor.
-    perf_ledger.record("recovery.ttr_p95_s", 61.0, "s", "lower",
-                       "recovery", "t", path=ledger)
-    perf_ledger.record("recovery.heal_gib_s.http", 0.001, "GiB/s",
-                       "higher", "recovery", "t", path=ledger)
-    result = perf_gate.compare(
-        perf_ledger.head(perf_ledger.load(ledger)),
-        perf_gate.load_baselines(baselines),
-    )
-    assert {"recovery.ttr_p95_s", "recovery.heal_gib_s.http"} <= {
-        r["metric"] for r in result["regressions"]
-    }
-    rc = perf_gate.main(
-        ["--check", "--ledger", ledger, "--baselines", baselines]
-    )
-    assert rc == 1

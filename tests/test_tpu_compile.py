@@ -66,8 +66,8 @@ def _qkv(one_chip, B, S, Hq, Hkv, D):
     )
 
 
-# (B, S, Hq, Hkv, D): chip_smoke's step, bench.py's step, the long-context
-# point.
+# (B, S, Hq, Hkv, D): chip_smoke's step, a wider batch of shorter rows, the
+# long-context point.
 FLASH_SHAPES = [(4, 2048, 12, 4, 64), (8, 1024, 12, 4, 64)]
 LONG_SHAPE = (2, 8192, 12, 4, 64)
 

@@ -24,10 +24,11 @@ are journaled, so ``tools/detect_report.py`` can re-derive the same
 attribution offline from the journal alone.
 
 The outcome is ONE JSON line plus a ``BENCH_DETECT.json`` artifact with
-per-(fault kind x signal source) detection p50/p95, which
-``perf_ledger`` records and ``perf_gate.py`` gates under the absolute
-budgets below. ``--replay`` re-derives the fault schedule from the
-artifact's seed and asserts it reproduces the recorded multiset.
+per-(fault kind x signal source) detection p50/p95. The drill checks
+``BUDGETS`` below against that report itself: a broken or unmeasured
+budget is listed under ``budget_problems`` and fails the run.
+``--replay`` re-derives the fault schedule from the artifact's seed and
+asserts it reproduces the recorded multiset.
 
 ``--quick`` is the ``suite_gate.sh detect`` lane shape: 6 replicas,
 8 faults (every kind at least once), fixed seed.
@@ -56,6 +57,7 @@ from torchft_tpu.coordination import (  # noqa: E402
 from torchft_tpu.telemetry import EventLog  # noqa: E402
 
 import obs_export  # noqa: E402
+from drills import check_budgets  # noqa: E402
 
 QUICK_SEED = 4242
 HB_INTERVAL_MS = 50
@@ -71,20 +73,35 @@ EXPECTED_SOURCE = {
     "abort_piggyback": "native_abort",
 }
 
-# Absolute detection budgets (seconds), asserted by the drill AND pinned
-# in PERF_BASELINES.json. hb_lapse pays the cadence-aware evict budget
-# (600ms at drill cadence) plus scan tick plus poll cadence; the others
-# surface on the next heartbeat/RPC frame. Shared-1-core-CI headroom on
-# top — these are detection-wedge tripwires, not latency targets.
-DETECT_BUDGET_S = {
-    "hb_lapse": 5.0,
-    "digest_anomaly": 2.0,
-    "proc_death": 2.0,
-    "abort_piggyback": 2.0,
-    "native_abort": 2.0,
-}
+# The drill's budgets: (metric, direction, bound, why), in seconds, with
+# shared-core headroom on top of each mechanism's own latency — these
+# are detection-wedge tripwires, not latency targets.
+BUDGETS = (
+    ("detect.p95_s", "lower", 5.0,
+     "over all faults; the slowest source (hb_lapse) sets it"),
+    ("detect.hb_stop.hb_lapse.p95_s", "lower", 5.0,
+     "evict budget (600 ms at drill cadence) + scan tick + poll cadence"),
+    ("detect.digest_stall.digest_anomaly.p95_s", "lower", 2.0,
+     "surfaces on the victim's next heartbeat frame"),
+    ("detect.dead_leave.proc_death.p95_s", "lower", 2.0,
+     "surfaces on the leave RPC itself"),
+    ("detect.abort_piggyback.native_abort.p95_s", "lower", 2.0,
+     "rides the survivor's next heartbeat frame"),
+)
+# Signal source -> the bound one fault of it is held to (and, x4, how
+# long the drill waits for it before calling it undetected).
+DETECT_BUDGET_S = {m.split(".")[2]: b for m, _d, b, _w in BUDGETS
+                   if m.count(".") == 3}
 POLL_S = 0.02
 FAULT_GAP_S = 0.25  # settle time between injections
+
+
+def budget_values(report: Dict[str, Any]) -> Dict[str, Any]:
+    summ = report.get("summary") or {}
+    vals = {"detect.p95_s": summ.get("detect_p95_s")}
+    for pair, row in (summ.get("detect") or {}).items():
+        vals[f"detect.{pair}.p95_s"] = row.get("p95_s")
+    return vals
 
 
 def fault_schedule(seed: int, n_faults: int) -> List[Dict[str, Any]]:
@@ -378,21 +395,13 @@ def run_drill(args) -> dict:
         "budgets_s": DETECT_BUDGET_S,
         "wall_s": round(wall_s, 1),
         "journal_dir": journal_dir,
-        "ok": not undetected and not over_budget,
     }
+    problems = check_budgets(budget_values(result), BUDGETS)
+    result["budget_problems"] = problems
+    result["ok"] = not undetected and not over_budget and not problems
     artifact = {**result, "rows": rows}
     with open(args.out, "w") as f:
         json.dump(artifact, f, indent=1, default=str)
-    if result["ok"]:
-        try:
-            import perf_ledger
-
-            perf_ledger.record_report(
-                "detect", artifact, "tools/detect_drill.py (live)"
-            )
-        except Exception as e:  # noqa: BLE001 - the drill already ran
-            print(f"detect_drill: ledger append skipped: {e}",
-                  file=sys.stderr)
     return result
 
 

@@ -48,6 +48,28 @@ from torchft_tpu.orchestration import (  # noqa: E402
 )
 
 
+def check_budgets(values, budgets) -> list:
+    """A drill's budgets against the values of the run it has just made.
+
+    ``budgets`` is the drill's own table of ``(metric, direction, bound,
+    why)`` rows: ``"lower"`` holds while the value is at most the bound,
+    ``"higher"`` while it is at least the bound. Returns one line per
+    row that is broken or whose value the run did not produce; the
+    drill lists them in its report and folds them into its exit code."""
+    problems = []
+    for metric, direction, bound, _why in budgets:
+        value = values.get(metric)
+        if value is None:
+            problems.append(
+                f"{metric}: not measured (budget {bound:g}, "
+                f"{direction} is better)")
+        elif (value > bound) if direction == "lower" else (value < bound):
+            problems.append(
+                f"{metric}: {value:g} breaks budget {bound:g} "
+                f"({direction} is better)")
+    return problems
+
+
 def _lighthouse(min_replicas: int = 2) -> LighthouseServer:
     return LighthouseServer(
         bind="127.0.0.1:0",

@@ -34,15 +34,17 @@ Asserted invariants:
   E3 agreement  — the three survivors finish at the full step count
                   with bitwise-identical parameters; no wedge.
   E4 goodput    — elastic-leg samples/s >= ``--goodput-floor`` x the
-                  static baseline (the 0.80 budget perf_gate pins).
+                  static baseline, and the 0.80 of ``BUDGETS`` below
+                  whatever the option says.
   E5 replay     — ``--replay BENCH_ELASTIC.json`` re-derives the
                   preemption plan from the recorded seed and asserts
                   the injection multiset is identical.
 
 The outcome is ONE JSON line plus a ``BENCH_ELASTIC.json`` artifact
 (time_to_join_p95_s, heal GiB/s from the joiners' receiver-side
-``heal_xfer`` accounting, goodput_retention) appended to the perf
-ledger and gated by ``perf_gate.py``.
+``heal_xfer`` accounting, goodput_retention). The drill checks
+``BUDGETS`` against that report itself: a broken or unmeasured budget
+is listed under ``budget_problems`` and fails the run.
 
 ``--quick`` is the suite_gate lane shape: the full 2 -> 8 -> 3 walk at
 a short step count with a fixed seed.
@@ -74,6 +76,20 @@ from torchft_tpu.orchestration import (  # noqa: E402
 )
 
 import obs_report  # noqa: E402
+from drills import check_budgets  # noqa: E402
+
+# The drill's budgets: (metric, direction, bound, why).
+BUDGETS = (
+    ("elastic.goodput_retention", "higher", 0.80,
+     "committed samples/s through the 2->8->3 walk against the static "
+     "2-replica leg: a resize may cost a fifth of goodput, not more"),
+)
+
+
+def budget_values(report: dict) -> dict:
+    summ = report.get("summary") or {}
+    return {"elastic.goodput_retention": summ.get("goodput_retention")}
+
 
 # p < 1 makes the seed pick WHICH groups get the eviction notice (the
 # plan sweeps the fleet until enough victims fired, so the count is
@@ -555,8 +571,10 @@ def run_drill(args) -> dict:
         "wall_s": round(time.time() - t0, 1),
         "journal_dir": leg["journal_dir"],
     }
+    result["budget_problems"] = check_budgets(budget_values(result), BUDGETS)
     result["ok"] = bool(
         result["wedge_free"] and all(result["invariants"].values())
+        and not result["budget_problems"]
     )
     artifact = {
         **result,
@@ -569,16 +587,6 @@ def run_drill(args) -> dict:
     }
     with open(args.out, "w") as f:
         json.dump(artifact, f, indent=1)
-    if result["ok"]:
-        try:
-            import perf_ledger
-
-            perf_ledger.record_report(
-                "elastic", artifact, "tools/elastic_drill.py (live)"
-            )
-        except Exception as e:  # noqa: BLE001 - the drill already ran
-            print(f"elastic_drill: ledger append skipped: {e}",
-                  file=sys.stderr)
     return result
 
 
@@ -634,7 +642,7 @@ def main() -> int:
                "shared-core box, so samples/s compares worlds "
                "fairly (overhead-dominant steps would charge "
                "resizing for scheduler contention)")
-    p.add_argument("--goodput-floor", type=float, default=0.80)
+    p.add_argument("--goodput-floor", type=float, default=BUDGETS[0][2])
     p.add_argument("--deadline", type=float, default=900.0)
     p.add_argument("--out", type=str,
                    default=os.path.join(REPO, "BENCH_ELASTIC.json"))

@@ -22,7 +22,8 @@ At the largest N it also runs the before/after experiment the scaling
 rework is judged by: ``/fleet.json`` serve p95 under full heartbeat load
 with snapshot caching off (``fleet_snap_ms=0``, the old build-under-lock
 behaviour) vs on (100 ms). The run fails unless caching cuts p95 by >= 2x
-and the stated latency budgets hold.
+and the budgets of ``BUDGETS`` and ``TRIPWIRES`` below that bind in it
+hold; the broken or unmeasured ones are listed under ``budget_problems``.
 
 Usage::
 
@@ -54,29 +55,91 @@ from torchft_tpu import _net  # noqa: E402
 from torchft_tpu.coordination import LighthouseServer  # noqa: E402
 from torchft_tpu.telemetry import StepDigest  # noqa: E402
 
-# p95 budgets, asserted against the measured numbers. Generous multiples
-# of what the reworked lighthouse does on this class of box (single
-# shared core, N server threads): the budgets are tripwires for O(N)
-# regressions on the hot paths, not performance targets.
-BUDGETS_US = {
-    64: {"heartbeat_p95_us": 100_000, "fleet_json_p95_us": 200_000,
-         "quorum_formation_ms": 1500},
-    256: {"heartbeat_p95_us": 200_000, "fleet_json_p95_us": 300_000,
-          "quorum_formation_ms": 2000},
-    1024: {"heartbeat_p95_us": 400_000, "fleet_json_p95_us": 500_000,
-           # Half the 4003 ms the pre-incremental (timer-scan) quorum
-           # recorded at this N: the delta-driven gate must fire the
-           # round inline at the last arrival, not wait out tick scans.
-           "quorum_formation_ms": 2000},
-}
+from drills import check_budgets  # noqa: E402
+
+# The drill's budgets: (metric, direction, bound, why). Generous
+# multiples of what the reworked lighthouse does on this class of box
+# (single shared core, N server threads): tripwires for O(N) regressions
+# on the hot paths, not performance targets. A row binds in the run that
+# measures it: ``.n<N>`` in a ladder that has N replicas, ``restart_*``
+# under --restart-lighthouse, ``multijob_*.m<M>x<N>`` under --multijob
+# at that shape.
+BUDGETS = (
+    ("fleet.fleet_json_p95_us.n256", "lower", 300_000,
+     "served from the 100 ms snapshot while 256 replicas heartbeat"),
+    ("fleet.fleet_json_p95_us.n1024", "lower", 500_000,
+     "the same at 1,024: a cached serve, not a rebuild under the lock"),
+    ("fleet.quorum_formation_ms.n1024", "lower", 2000,
+     "half the 4003 ms the timer-scan quorum took at this N: the "
+     "delta-driven gate fires the round inline at the last arrival"),
+    ("fleet.restart_reregister_s.n256", "lower", 30,
+     "a warm restart slower to re-absorb the fleet blows the "
+     "control-plane TTR budget"),
+    ("fleet.restart_repopulate_s.n256", "lower", 60,
+     "/fleet.json's aggregate back to N within the TTR ceiling"),
+    ("fleet.multijob_formation_p95_ms.m4x2", "lower", 2000,
+     "per-job quorum formation across M jobs sharing two districts"),
+    ("fleet.multijob_formation_p95_ms.m16x4", "lower", 2000,
+     "the same at the full shape"),
+    ("fleet.multijob_sibling_hb_p95_us.m4x2", "lower", 400_000,
+     "a sibling job's heartbeat hot path DURING another job's churn storm"),
+    ("fleet.multijob_sibling_hb_p95_us.m16x4", "lower", 400_000,
+     "the same at the full shape"),
+    ("fleet.multijob_isolation_violations.m4x2", "lower", 0,
+     "sibling control-plane state stays bit-exact through the storm"),
+    ("fleet.multijob_isolation_violations.m16x4", "lower", 0,
+     "the same at the full shape"),
+)
+# What the ladder and the quick restart are held to besides, checked the
+# same way: the heartbeat hot path at every N, and the small sizes.
+TRIPWIRES = (
+    ("fleet.hb_p95_us.n64", "lower", 100_000, "heartbeat + digest round trip"),
+    ("fleet.hb_p95_us.n256", "lower", 200_000, "the same"),
+    ("fleet.hb_p95_us.n1024", "lower", 400_000, "the same"),
+    ("fleet.fleet_json_p95_us.n64", "lower", 200_000, "cached serve"),
+    ("fleet.quorum_formation_ms.n64", "lower", 1500, "first register to broadcast"),
+    ("fleet.quorum_formation_ms.n256", "lower", 2000, "the same"),
+    ("fleet.restart_reregister_s.n64", "lower", 30, "as at n256"),
+    ("fleet.restart_repopulate_s.n64", "lower", 60, "as at n256"),
+)
 MIN_SPEEDUP = 2.0  # cached vs uncached /fleet.json p95 at the largest N
 
-# Multi-job federation scenario budgets (M jobs x N replicas across a
-# district->root topology). Same philosophy: O(N)-regression tripwires.
-MULTIJOB_BUDGETS = {
-    "formation_p95_ms": 2000,       # per-job quorum formation across M jobs
-    "sibling_hb_p95_us": 400_000,   # sibling hot path DURING a churn storm
-}
+
+def budget_values(report: Dict[str, Any]) -> Dict[str, Any]:
+    """Every budgeted metric of the sections ``report`` holds (``fleets``,
+    ``restart``, ``multijob``), None where the section lacks the value."""
+    vals: Dict[str, Any] = {}
+    for n, res in (report.get("fleets") or {}).items():
+        vals[f"fleet.hb_p95_us.n{n}"] = (
+            res.get("heartbeat") or {}).get("p95_us")
+        vals[f"fleet.fleet_json_p95_us.n{n}"] = (
+            (res.get("http") or {}).get("fleet_json") or {}).get("p95_us")
+        vals[f"fleet.quorum_formation_ms.n{n}"] = (
+            res.get("quorum") or {}).get("formation_ms")
+    rst = report.get("restart")
+    if rst:
+        for key in ("reregister_s", "repopulate_s"):
+            vals[f"fleet.restart_{key}.n{rst.get('n')}"] = rst.get(key)
+    mj = report.get("multijob")
+    if mj:
+        tag = f"m{mj.get('m_jobs')}x{mj.get('n_per_job')}"
+        viol = (mj.get("isolation") or {}).get("violations")
+        vals[f"fleet.multijob_formation_p95_ms.{tag}"] = mj.get(
+            "formation_p95_ms")
+        vals[f"fleet.multijob_sibling_hb_p95_us.{tag}"] = (
+            mj.get("sibling_heartbeat") or {}).get("p95_us")
+        vals[f"fleet.multijob_isolation_violations.{tag}"] = (
+            None if viol is None else len(viol))
+    return vals
+
+
+def budget_problems(report: Dict[str, Any], section: str) -> List[str]:
+    """The budgets that bind in the run that measured ``section`` of the
+    report (its metrics, at the sizes it ran), checked against it."""
+    values = budget_values({section: report.get(section)})
+    rows = [r for r in BUDGETS + TRIPWIRES if r[0] in values]
+    return check_budgets(values, rows)
+
 
 _CLK_TCK = os.sysconf("SC_CLK_TCK")
 
@@ -600,7 +663,7 @@ def multijob_scenario(m_jobs: int, n_per_job: int,
 
     * **per-job quorum formation** — every job forms its own quorum on a
       shared district lighthouse; formation p50/p95 across jobs goes into
-      the report (budgeted via MULTIJOB_BUDGETS),
+      the report (budgeted in BUDGETS),
     * **cross-job isolation** — a seeded churn storm (leave/rejoin bursts)
       inside one job must leave every sibling job's quorum id/generation,
       join/leave counters, and anomaly ring bit-exact, while the siblings'
@@ -741,15 +804,6 @@ def multijob_scenario(m_jobs: int, n_per_job: int,
         if not storm_state["anomaly_seq"]:
             failures.append(
                 "multijob: storm job's commit-stall anomaly never fired")
-        if sib_hb["p95_us"] > MULTIJOB_BUDGETS["sibling_hb_p95_us"]:
-            failures.append(
-                f"multijob: sibling heartbeat p95 {sib_hb['p95_us']}us > "
-                f"budget {MULTIJOB_BUDGETS['sibling_hb_p95_us']}us")
-        if out["formation_p95_ms"] > MULTIJOB_BUDGETS["formation_p95_ms"]:
-            failures.append(
-                f"multijob: per-job formation p95 "
-                f"{out['formation_p95_ms']}ms > budget "
-                f"{MULTIJOB_BUDGETS['formation_p95_ms']}ms")
 
         # District failover drill: a warm standby (same durable state dir)
         # takes over d0 with a bumped fencing epoch; the root must count
@@ -882,13 +936,15 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     if args.multijob:
         # Standalone scenario: merge into the existing BENCH_FLEET.json
-        # (the ladder results stay) and append to the ledger.
+        # (the ladder results stay).
         m = args.jobs if args.jobs is not None else (4 if args.quick else 16)
         npj = (args.per_job if args.per_job is not None
                else (2 if args.quick else 4))
         print(f"[fleet_load] multijob: {m} jobs x {npj} replicas, "
               f"district->root topology, seed={args.seed}", flush=True)
         mj = multijob_scenario(m, npj, seed=args.seed)
+        mj["budget_problems"] = budget_problems({"multijob": mj}, "multijob")
+        mj["pass"] = not mj["failures"] and not mj["budget_problems"]
         try:
             with open(args.out) as f:
                 report = json.load(f)
@@ -898,28 +954,18 @@ def main(argv: Optional[List[str]] = None) -> int:
         with open(args.out, "w") as f:
             json.dump(report, f, indent=2, sort_keys=True)
             f.write("\n")
-        try:
-            import perf_ledger
-
-            perf_ledger.record_report(
-                "fleet", {"fleets": {}, "multijob": mj},
-                "tools/fleet_load.py (live)"
-            )
-        except Exception as e:  # noqa: BLE001
-            print(f"[fleet_load] ledger append skipped: {e}",
-                  file=sys.stderr)
         print(f"[fleet_load] multijob: formation p95="
               f"{mj['formation_p95_ms']}ms sibling hb p95="
               f"{mj['sibling_heartbeat']['p95_us']}us "
               f"violations={len(mj['isolation']['violations'])} "
               f"-> {args.out}", flush=True)
-        for msg in mj["failures"]:
+        for msg in mj["failures"] + mj["budget_problems"]:
             print(f"[fleet_load] MULTIJOB FAIL: {msg}", file=sys.stderr)
         return 0 if mj["pass"] else 1
 
     if args.restart_lighthouse:
         # Standalone scenario: merge into the existing BENCH_FLEET.json
-        # (the ladder results stay) and append to the ledger.
+        # (the ladder results stay).
         n = 64 if args.quick else 256
         print(f"[fleet_load] N={n}: lighthouse warm-restart storm",
               flush=True)
@@ -930,29 +976,12 @@ def main(argv: Optional[List[str]] = None) -> int:
         except (OSError, ValueError):
             report = {"schema": 1, "fleets": {}}
         report["restart"] = rst
-        failures = []
-        # Tripwires, not targets: a warm restart that takes this long to
-        # re-absorb the fleet would blow the control-plane TTR budget.
-        if rst["reregister_s"] > 30:
-            failures.append(
-                f"N={n}: re-register storm {rst['reregister_s']}s > 30s")
-        if rst["repopulate_s"] > 60:
-            failures.append(
-                f"N={n}: fleet repopulate {rst['repopulate_s']}s > 60s")
-        report["restart"]["pass"] = not failures
+        failures = budget_problems(report, "restart")
+        rst["budget_problems"] = failures
+        rst["pass"] = not failures
         with open(args.out, "w") as f:
             json.dump(report, f, indent=2, sort_keys=True)
             f.write("\n")
-        try:
-            import perf_ledger
-
-            perf_ledger.record_report(
-                "fleet", {"fleets": {}, "restart": rst},
-                "tools/fleet_load.py (live)"
-            )
-        except Exception as e:  # noqa: BLE001
-            print(f"[fleet_load] ledger append skipped: {e}",
-                  file=sys.stderr)
         print(f"[fleet_load] restart: down={rst['restart_s']}s "
               f"reregister={rst['reregister_s']}s "
               f"repopulate={rst['repopulate_s']}s -> {args.out}",
@@ -965,7 +994,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         "schema": 1, "quick": bool(args.quick),
         "rounds": args.rounds, "probes": args.probes,
         "http_concurrency": args.http_concurrency,
-        "budgets": {str(n): BUDGETS_US.get(n) for n in sizes},
         "fleets": {},
     }
     failures: List[str] = []
@@ -980,24 +1008,6 @@ def main(argv: Optional[List[str]] = None) -> int:
               f"quorum formation={res['quorum']['formation_ms']}ms "
               f"fleet.json p95={res['http']['fleet_json']['p95_us']}us",
               flush=True)
-        budget = BUDGETS_US.get(n)
-        if budget:
-            if res["heartbeat"]["p95_us"] > budget["heartbeat_p95_us"]:
-                failures.append(
-                    f"N={n}: heartbeat p95 {res['heartbeat']['p95_us']}us "
-                    f"> budget {budget['heartbeat_p95_us']}us")
-            if (res["http"]["fleet_json"]["p95_us"]
-                    > budget["fleet_json_p95_us"]):
-                failures.append(
-                    f"N={n}: /fleet.json p95 "
-                    f"{res['http']['fleet_json']['p95_us']}us > budget "
-                    f"{budget['fleet_json_p95_us']}us")
-            if (res["quorum"]["formation_ms"]
-                    > budget["quorum_formation_ms"]):
-                failures.append(
-                    f"N={n}: quorum formation "
-                    f"{res['quorum']['formation_ms']}ms > budget "
-                    f"{budget['quorum_formation_ms']}ms")
 
     if not args.quick:
         # Before/after at the largest N: the same probe mix with the
@@ -1026,6 +1036,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                 f"N={n}: cached /fleet.json speedup {speedup:.2f}x "
                 f"< required {MIN_SPEEDUP}x")
 
+    report["budget_problems"] = budget_problems(report, "fleets")
+    failures += report["budget_problems"]
     report["pass"] = not failures
     report["failures"] = failures
     # The ladder rewrite keeps the standalone merge-in scenarios
@@ -1041,14 +1053,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     with open(args.out, "w") as f:
         json.dump(report, f, indent=2, sort_keys=True)
         f.write("\n")
-    try:
-        import perf_ledger
-
-        perf_ledger.record_report(
-            "fleet", report, "tools/fleet_load.py (live)"
-        )
-    except Exception as e:  # noqa: BLE001 - the measurement already ran
-        print(f"[fleet_load] ledger append skipped: {e}", file=sys.stderr)
     print(f"[fleet_load] wrote {args.out}", flush=True)
     for msg in failures:
         print(f"[fleet_load] BUDGET FAIL: {msg}", file=sys.stderr)

@@ -27,7 +27,7 @@ On top of the audited accounts it reports:
 * the headline: fleet goodput fraction and **goodput retention** —
   1 - fault_badput / (accounted - init_compile), the share of
   steady-state capacity that survived the faults. This is the number
-  ``goodput_soak.py`` pins in BENCH_GOODPUT.json under perf_gate.
+  ``goodput_soak.py`` holds to its 0.95 budget.
 
 Usage::
 

@@ -17,18 +17,9 @@ replicas' own ``goodput_window`` journals via tools/goodput_report.py:
                     cost table has a populated ``process_loss`` row.
 
 The headline is **goodput retention** — 1 - fault_badput /
-(accounted - init_compile) — which the artifact pins in the perf
-ledger under an absolute 0.95 budget (the paper's <5% throughput-loss
-claim at one failure per hundred steps)::
-
-    python tools/perf_gate.py --pin --metrics goodput.retention \\
-        goodput.fleet_fraction goodput.fault_badput_s \\
-        --budget goodput.retention=0.95 \\
-        --budget goodput.fault_badput_s=12
-
-(``fault_badput_s`` carries an *absolute* budget, not a relative
-baseline — raw fault-badput seconds swing with where the kill lands,
-the same bimodality that makes the recovery TTR pins budget-gated.)
+(accounted - init_compile). The soak checks ``BUDGETS`` below against
+the report it has just built: a broken or unmeasured budget is listed
+under ``budget_problems`` and fails the run.
 
 The outcome is ONE JSON line plus a ``BENCH_GOODPUT.json`` artifact
 carrying the seed, spec, full goodput report, and journal dir (which
@@ -63,6 +54,27 @@ from torchft_tpu.orchestration import (  # noqa: E402
 
 import goodput_report  # noqa: E402
 import obs_report  # noqa: E402
+from drills import check_budgets  # noqa: E402
+
+# The soak's budgets: (metric, direction, bound, why). Both are absolute:
+# raw fault-badput seconds swing with where the kill lands, so a bound
+# relative to an earlier run either flakes or cannot fail.
+BUDGETS = (
+    ("goodput.retention", "higher", 0.95,
+     "the paper's claim: under 5% throughput lost at one failure per "
+     "hundred steps"),
+    ("goodput.fault_badput_s", "lower", 12.0,
+     "replica-seconds one kill may cost: about 7 s here (down, heal, "
+     "the survivor's quorum wait), with room for where in a step it lands"),
+)
+
+
+def budget_values(report: dict) -> dict:
+    summ = report.get("summary") or {}
+    return {
+        "goodput.retention": summ.get("goodput_retention"),
+        "goodput.fault_badput_s": summ.get("fault_badput_s"),
+    }
 
 # Light control-plane-only chaos: bounded commit-vote delays that land in
 # the straggler_idle/exposed_comm accounts, NOT the fault-badput kinds —
@@ -225,7 +237,9 @@ def run_soak(args) -> dict:
         "wall_s": round(wall_s, 1),
         "journal_dir": journal_dir,
     }
-    result["ok"] = bool(g1 and g2 and g3 and wedge_free)
+    result["budget_problems"] = check_budgets(budget_values(result), BUDGETS)
+    result["ok"] = bool(g1 and g2 and g3 and wedge_free
+                        and not result["budget_problems"])
     artifact = {
         **result,
         "replicas_acct": report["replicas"],
@@ -237,16 +251,6 @@ def run_soak(args) -> dict:
     }
     with open(args.out, "w") as f:
         json.dump(artifact, f, indent=1)
-    if result["ok"]:
-        try:
-            import perf_ledger
-
-            perf_ledger.record_report(
-                "goodput", artifact, "tools/goodput_soak.py (live)"
-            )
-        except Exception as e:  # noqa: BLE001 - the soak already ran
-            print(f"goodput_soak: ledger append skipped: {e}",
-                  file=sys.stderr)
     return result
 
 

@@ -13,9 +13,7 @@ asserting:
     training processes are still running, not in a post-mortem report,
   * ``obs_top.py --once --check`` renders the live table cleanly,
   * the lighthouse anomalies journal as ``anomaly`` events through the
-    exporter's cursor helper,
-  * the heartbeat-digest duty-cycle overhead A/B stays under 1% (merged
-    into ``BENCH_PG_allreduce.json`` as ``digest_overhead``).
+    exporter's cursor helper.
 
 Run directly or via ``bash tools/suite_gate.sh fleet``.
 """
@@ -171,14 +169,6 @@ def main() -> int:
     assert anomaly_events, "exporter journal has no anomaly events"
     kinds = {e.get("attrs", {}).get("kind") for e in anomaly_events}
     assert "hb_jitter" in kinds, f"anomaly kinds journaled: {kinds}"
-
-    # Digest duty-cycle overhead gate, merged into the committed report.
-    rc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "bench_pg.py"),
-         "--digest-ab-only", "--assert-digest-overhead", "1.0"],
-        timeout=180,
-    ).returncode
-    assert rc == 0, f"digest overhead A/B gate failed rc={rc}"
 
     print(
         f"\nfleet smoke OK: straggler={straggler_live[0]} "

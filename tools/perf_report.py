@@ -13,8 +13,7 @@ windows the journal already carries (``telemetry.step_phase_windows`` /
   = 98% exposed allreduce);
 * per step: the critical (slowest) replica and its dominant exposed
   phase;
-* run-level: the exposed-allreduce fraction of total step wall (the
-  number BENCH_r05 pins at ~0.98 for the socket-PG DDP leg) and, when
+* run-level: the exposed-allreduce fraction of total step wall and, when
   the native engine's flight-recorder lanes are present, per-(peer,
   stripe, dir) sole-runner exposure — the lane tail each collective's
   completion actually waited on;

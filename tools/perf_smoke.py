@@ -8,13 +8,11 @@ with the event journal AND ``TORCHFT_PERF`` on, then checks that:
 * the merged journal analyzes into per-(step, replica) critical-path
   rows whose phases tile the step window exactly (``perf_report.check``);
 * the run-level exposed allreduce is the dominant exposed interval and
-  clears a conservative floor. (The BENCH_r05 ~0.98 regime — 190 ms
-  socket allreduce against 1.65 ms of grad compute — needs the llama
-  payload; the CNN drill's per-step quorum round is the same order as
-  its 0.4 MB allreduce, so its fraction sits far lower. The exact-0.98
-  reproduction is pinned in tests/test_perf_attr.py's
-  ``test_bench_r05_ground_truth_regime`` from the artifact's measured
-  per-step parts.);
+  clears a conservative floor. (The CNN drill's per-step quorum round
+  is the same order as its 0.4 MB allreduce, so its fraction sits far
+  below that of a step that does nothing but wait for the allreduce;
+  tests/test_perf_attr.py's ``test_fully_exposed_allreduce`` checks the
+  algebra on such a step.);
 * ``--emit``-equivalent re-journaling produces ``perf_step`` events;
 * the ``perf_model`` event from the TORCHFT_PERF compile-time hook is
   present, so the MFU plumbing is exercised (CPU ⇒ mfu=None, honestly).

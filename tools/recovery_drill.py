@@ -24,8 +24,9 @@ rotation-aware loading included) and the drill asserts:
 The outcome is ONE JSON line plus a ``BENCH_RECOVERY.json`` artifact
 carrying TTR p50/p95 (total and per phase), heal bandwidth per
 transport, the full episode list, and the journal dir — which
-``tools/recovery_report.py --from-bench`` renders and ``perf_gate.py``
-gates after the drill appends the headline numbers to the perf ledger.
+``tools/recovery_report.py --from-bench`` renders. The drill checks
+``BUDGETS`` below against that report itself: a broken or unmeasured
+budget is listed under ``budget_problems`` and fails the run.
 
 ``--quick`` is the suite_gate lane shape: 2 replicas, one kill, fixed
 seed, heal chaos armed.
@@ -53,6 +54,49 @@ from torchft_tpu.orchestration import (  # noqa: E402
 
 import obs_report  # noqa: E402
 import recovery_report  # noqa: E402
+from drills import check_budgets  # noqa: E402
+
+# The drill's budgets: (metric, direction, bound, why). Absolute, because
+# a clean run's TTR is bimodal: it swings 5 s <-> 30 s with how many
+# commit-gate vote timeouts (10 s each here) land inside the window, so
+# a bound relative to an earlier run either flakes or cannot fail.
+BUDGETS = (
+    ("recovery.ttr_p50_s", "lower", 60.0,
+     "TORCHFT_TTR_BUDGET_S's default: the bound obs_top flags live "
+     "replicas against"),
+    ("recovery.ttr_p95_s", "lower", 60.0,
+     "the same ceiling on the tail, both injected heal failures included"),
+    ("recovery.phase_p95_s.detect", "lower", 12.0,
+     "in-run latches are immediate; a silent kill costs up to "
+     "TORCHFT_TIMEOUT_SEC (10 s here)"),
+    ("recovery.phase_p95_s.quorum", "lower", 30.0,
+     "about one TORCHFT_TIMEOUT_SEC per failed heal attempt, and the "
+     "re-quorum itself"),
+    ("recovery.phase_p95_s.transfer", "lower", 5.0,
+     "state bytes over heal bandwidth: milliseconds at drill scale"),
+    ("recovery.phase_p95_s.rebuild", "lower", 5.0,
+     "process-group reconfigure: under a second at drill scale"),
+    ("recovery.phase_p95_s.catchup", "lower", 60.0,
+     "vote-gather timeouts after failed heals, recompile, re-warm: the "
+     "remainder of the TTR ceiling"),
+    ("recovery.heal_gib_s.http", "higher", 0.02,
+     "a tenth of what the drill's loopback heal shows: a collapsed "
+     "transfer, not a slow box"),
+)
+
+
+def budget_values(report: dict) -> dict:
+    summ = report.get("summary") or {}
+    vals = {
+        "recovery.ttr_p50_s": summ.get("ttr_p50_s"),
+        "recovery.ttr_p95_s": summ.get("ttr_p95_s"),
+    }
+    for ph, row in (summ.get("phases") or {}).items():
+        vals[f"recovery.phase_p95_s.{ph}"] = row.get("p95_s")
+    for transport, row in (summ.get("heal_gib_s") or {}).items():
+        vals[f"recovery.heal_gib_s.{transport}"] = row.get("p50")
+    return vals
+
 
 # First heal attempt dies in planning (abort_heal), the second gets a
 # truncated checkpoint stream mid-transfer (ckpt_truncate), the third
@@ -205,7 +249,9 @@ def run_drill(args) -> dict:
         "wall_s": round(wall_s, 1),
         "journal_dir": journal_dir,
     }
-    result["ok"] = bool(r1 and r2 and r3 and wedge_free)
+    result["budget_problems"] = check_budgets(budget_values(result), BUDGETS)
+    result["ok"] = bool(r1 and r2 and r3 and wedge_free
+                        and not result["budget_problems"])
     artifact = {
         **result,
         "episodes": episodes,
@@ -215,16 +261,6 @@ def run_drill(args) -> dict:
     }
     with open(args.out, "w") as f:
         json.dump(artifact, f, indent=1)
-    if result["ok"]:
-        try:
-            import perf_ledger
-
-            perf_ledger.record_report(
-                "recovery", artifact, "tools/recovery_drill.py (live)"
-            )
-        except Exception as e:  # noqa: BLE001 - the drill already ran
-            print(f"recovery_drill: ledger append skipped: {e}",
-                  file=sys.stderr)
     return result
 
 

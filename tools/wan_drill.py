@@ -393,14 +393,6 @@ def run_drill(args) -> dict:
     }
     with open(args.out, "w") as f:
         json.dump(artifact, f, indent=1)
-    try:
-        import perf_ledger
-
-        perf_ledger.record_report(
-            "wan", artifact, "tools/wan_drill.py (live)"
-        )
-    except Exception as e:  # noqa: BLE001 - the drill already ran
-        print(f"wan_drill: ledger append skipped: {e}", file=sys.stderr)
     return report
 
 

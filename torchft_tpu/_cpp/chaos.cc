@@ -399,8 +399,7 @@ Decision pick(int32_t kind, const std::string& site) {
   // Lock-free pre-scan over the (immutable once armed) rule filters: if
   // nothing can match this visit, no counter moves — so skip the schedule
   // mutex entirely. Keeps an armed-but-narrowly-scoped schedule from
-  // serializing every unrelated stripe thread on one global lock (the
-  // bench_pg chaos A/B measures this path).
+  // serializing every unrelated stripe thread on one global lock.
   bool any = false;
   for (const Rule& r : st.rules) {
     if (r.kind != kind) continue;
@@ -529,8 +528,7 @@ Decision on_write(int fd, size_t len) {
   Decision none;
   if (!g_armed.load(std::memory_order_acquire) || !t_ctx.set) return none;
   // Skip the site-string allocation and the pick() scans when the armed
-  // schedule cannot touch this ctx (bench_pg --chaos-ab measures exactly
-  // this path).
+  // schedule cannot touch this ctx.
   if (!ctx_maybe(*g_state)) return none;
   const std::string site =
       "send:" + (t_ctx.peer.empty() ? std::string("?") : t_ctx.peer);

@@ -514,7 +514,7 @@ def allreduce_quantized_jax(
             # this call returns (host path: the deferred host pull; device
             # path: quantize kernels already enqueued but not yet
             # executed) while the caller's next train step may DONATE this
-            # buffer (make_train_step and bench.py both donate), deleting
+            # buffer (make_train_step donates its state), deleting
             # it mid-use.  Materialize an independent device snapshot
             # before returning to the caller.  (Below the ws<=1 return:
             # the single-replica path never defers.)
@@ -578,10 +578,10 @@ def allreduce_quantized_jax(
             # would add is latching execution faults of the trivial
             # elementwise rebuild ops — and on a 1-core box it DRAINS THE
             # DEVICE QUEUE through the caller's whole in-flight training
-            # window (measured: a 0.05 MB fragment's "dequant_push" span at
-            # 14.7 s in BENCH_r04, with a 3.1 s exposed tail in the
-            # caller's wait), turning the overlapped sync into a serialized
-            # one.  The r03 TPU rationale below now applies everywhere.
+            # window (seen once on such a box: a 0.05 MB fragment's
+            # "dequant_push" span at 14.7 s, with a 3.1 s exposed tail in
+            # the caller's wait), turning the overlapped sync into a
+            # serialized one.  The TPU rationale below applies everywhere.
             #
             # TPU: leave the dequantize async-dispatched. Its execution
             # naturally queues behind whatever window the caller has in

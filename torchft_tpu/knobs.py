@@ -126,12 +126,6 @@ _ALL = [
         None,
         "Truthy: trainers record per-jitted-step FLOPs/bytes from XLA cost analysis at compile time (one `perf_model` journal event) and append MFU/roofline to step logs; unset costs nothing.",
     ),
-    _k(
-        "TORCHFT_PERF_LEDGER",
-        "str",
-        None,
-        "Override the benchmark ledger path tools/perf_ledger.py appends to (default `<repo>/BENCH_LEDGER.jsonl`).",
-    ),
     # -- flight recorder / tracing ----------------------------------------
     _k(
         "TORCHFT_TRACE_DIR",

@@ -1,17 +1,14 @@
-"""Step-level MFU/roofline accounting — the one FLOP-counting module.
+"""Step-level MFU/roofline accounting for the trainers' ``TORCHFT_PERF``
+step log.
 
-Three layers, shared by every consumer (bench.py, tools/mfu_sweep.py,
-tools/mfu_cost_rank.py, the trainers under ``TORCHFT_PERF``):
+Two layers:
 
-- **Analytic estimate**: :func:`flops_per_step` is the standard 6ND
-  dense estimate plus the causal-attention term — model-shape math, no
-  compile needed (what bench.py's headline ``mfu_est`` always used).
 - **Measured cost**: :func:`compiled_cost` reads XLA's own cost analysis
   (flops, bytes accessed) plus memory analysis (temp/arg/output bytes)
   off a lowered+compiled executable, tolerant of backends that return
-  lists or partial keys. Known caveat (tools/mfu_cost_rank.py): XLA
-  counts a ``lax.scan`` body ONCE, so scanned programs under-report; the
-  rank tool applies its own correction.
+  lists or partial keys. Known caveat: XLA counts a ``lax.scan`` body
+  ONCE, so scanned programs under-report. (A count made from the
+  model's shapes is the benchmark's: ``benchmark/arch/*/flops.py``.)
 - **Peaks/roofline**: bf16 peak TFLOP/s and HBM GB/s per TPU
   generation, and :func:`roofline` combining achieved FLOP/s with the
   program's arithmetic intensity into an MFU and an attainable-roofline
@@ -36,7 +33,6 @@ __all__ = [
     "PEAK_HBM_GBPS",
     "peak_tflops",
     "peak_hbm_gbps",
-    "flops_per_step",
     "compiled_cost",
     "perf_enabled",
     "record_jit_cost",
@@ -47,8 +43,7 @@ __all__ = [
 ]
 
 # Published bf16 peak per chip, by device_kind substring (first match
-# wins, so "v5p" must precede "v5"). Same table bench.py shipped since
-# r2; kept here so there is exactly one copy.
+# wins, so "v5p" must precede "v5").
 PEAK_BF16_TFLOPS = [
     ("v6", 918.0),  # Trillium
     ("v5p", 459.0),
@@ -92,13 +87,6 @@ def peak_tflops(device_kind: str) -> Optional[float]:
 def peak_hbm_gbps(device_kind: str) -> Optional[float]:
     """HBM GB/s for a jax ``device_kind``; None off-TPU."""
     return _lookup(PEAK_HBM_GBPS, device_kind)
-
-
-def flops_per_step(n_params: int, cfg, B: int, S: int) -> float:
-    """Standard 6ND estimate + causal attention term (fwd+bwd)."""
-    dense = 6.0 * n_params * B * S
-    attn = 6.0 * cfg.num_layers * B * S * S * cfg.num_heads * cfg.head_dim
-    return dense + attn
 
 
 def compiled_cost(compiled) -> Dict[str, Any]:
