@@ -20,9 +20,9 @@ from benchmark import cells
 from benchmark import conftest as _outgrown
 
 MODULES = (
-    "test_ar_pack_fresh_bytes", "test_arch", "test_flops", "test_reference",
-    "test_run_end_to_end", "test_span_metrics", "test_trace_reduce",
-    "test_wire_fresh_bytes",
+    "test_ar_pack_fresh_bytes", "test_arch", "test_flops", "test_liveness_metrics",
+    "test_reference", "test_run_end_to_end", "test_span_metrics",
+    "test_trace_reduce", "test_wire_fresh_bytes",
 )
 SUBPROCESS_RUNS = {
     "test_tiny_raw_cell_end_to_end_metrics",

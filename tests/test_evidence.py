@@ -6,6 +6,7 @@ detection-latency attribution report."""
 
 import json
 import os
+import re
 import sys
 import time
 import urllib.request
@@ -521,3 +522,235 @@ def test_recovery_report_detect_attribution_split():
         episodes,
     )
     assert episodes[0]["detect_signal"] is None
+
+
+# ---------------------------------------------------------------------------
+# A dropped group explains itself: the eviction on both clocks
+# ---------------------------------------------------------------------------
+
+_LH_LINE = re.compile(r"^(\d{13}) \[lighthouse\] (.*)$")
+
+
+def _lighthouse_lines(capfd):
+    """What the lighthouse printed (it inherits this process's stderr):
+    (wall-clock ms, text) of every line; a line without a clock fails."""
+    out = []
+    for line in capfd.readouterr().err.splitlines():
+        if "[lighthouse]" not in line:
+            continue
+        m = _LH_LINE.match(line)
+        assert m, f"a lighthouse line without a clock: {line!r}"
+        out.append((int(m.group(1)), m.group(2)))
+    return out
+
+
+def _field(text, name):
+    return int(re.search(rf"{name}=(-?\d+)", text).group(1))
+
+
+def test_evicted_parked_quorum_request_is_put_back(monkeypatch, capfd):
+    """ROADMAP S9(a), the thing itself: a live replica whose heartbeats
+    lapse while its quorum request is parked is evicted with that request
+    erased. Its next heartbeat re-admits it, tells it so in the ack (what
+    was erased, the gap, how long it was out), and the parked request is
+    registered again, so the peer's quorum forms at once instead of
+    waiting out the evicted replica's quorum timeout."""
+    import threading
+    from concurrent.futures import ThreadPoolExecutor
+
+    monkeypatch.setenv("TORCHFT_LH_EVICT_FLOOR_MS", "400")
+    server = LighthouseServer(
+        min_replicas=2, join_timeout_ms=60000, quorum_tick_ms=20,
+        heartbeat_timeout_ms=60000, fleet_snap_ms=0,
+    )
+    stop = threading.Event()
+    pool = ThreadPoolExecutor(max_workers=2)
+    clients = [LighthouseClient(server.address()) for _ in range(4)]
+    a, b, a_quorum, b_quorum = clients
+    try:
+        def beat_b():
+            while not stop.is_set():
+                b.heartbeat("b", hb_interval_ms=50)
+                time.sleep(0.05)
+
+        t_wall0 = time.time()
+        a.heartbeat("a", hb_interval_ms=50)
+        pool.submit(beat_b)
+        parked = pool.submit(a_quorum.quorum, "a", timeout=15.0)
+        # a's heartbeats stop (budget: max(400, 12 x 50) ms) while its
+        # request is parked; b stays live and has not asked yet.
+        watch = LighthouseClient(server.address())
+        deadline = time.time() + 10.0
+        lapse = []
+        while time.time() < deadline and not lapse:
+            lapse = [r for r in watch.fleet().get("signals") or []
+                     if r["source"] == "hb_lapse"]
+            time.sleep(0.02)
+        watch.close()
+        assert [r["replica_id"] for r in lapse] == ["a"]
+        assert not parked.done()
+        ack = a.heartbeat("a", hb_interval_ms=50)
+        ev = ack["evicted"]
+        assert ev["erased"] == "heartbeat+participant+quorum_request"
+        assert ev["via"] == "heartbeat"
+        assert ev["seq"] == lapse[0]["seq"]
+        assert ev["budget_ms"] == 600
+        assert ev["gap_ms"] >= ev["open_gap_ms"] > ev["budget_ms"]
+        assert 0 <= ev["out_ms"] <= ev["gap_ms"]
+        # Told once.
+        assert "evicted" not in a.heartbeat("a", hb_interval_ms=50)
+        # The repair: b's request completes the quorum with a's parked
+        # one, long before either would time out.
+        t0 = time.monotonic()
+        q = b_quorum.quorum("b", timeout=5.0)
+        assert time.monotonic() - t0 < 3.0
+        assert sorted(m.replica_id for m in q.participants) == ["a", "b"]
+        assert parked.result(timeout=5.0).quorum_id == q.quorum_id
+    finally:
+        stop.set()
+        pool.shutdown(wait=True)
+        for c in clients:
+            c.close()
+        server.shutdown()
+
+    lines = _lighthouse_lines(capfd)
+    assert all(t_wall0 * 1e3 - 5 <= ms <= time.time() * 1e3 + 5 for ms, _ in lines)
+
+    def one(needle):
+        (hit,) = [(ms, text) for ms, text in lines if needle in text]
+        return hit
+
+    t_sig, sig = one("hb_lapse on a")
+    assert '"gap_ms"' in sig and '"budget_ms"' in sig
+    t_ev, evicted = one("evicted a on evidence")
+    assert "erased=heartbeat+participant+quorum_request" in evicted
+    assert _field(evicted, "gap_ms") == ev["open_gap_ms"]
+    t_re, readmit = one("re-admitted a by its heartbeat")
+    assert _field(readmit, "out_ms") == ev["out_ms"]
+    assert t_re - t_ev == pytest.approx(ev["out_ms"], abs=3)
+    _, back = one("heartbeat of evicted a back")
+    assert _field(back, "gap_ms") == ev["gap_ms"]
+    one("re-registered the parked quorum request of a")
+    assert t_sig <= t_ev <= t_re
+
+
+def test_a_stalled_heartbeat_is_in_both_journals(tmp_path, monkeypatch, capfd):
+    """Two live Managers in lockstep; the chaos plane stalls ONE heartbeat
+    of the victim's manager server past the lighthouse's budget. The
+    lighthouse's log has the lapse, the eviction and the re-admission with
+    a clock; the victim's journal has ``lh_evicted`` with the lighthouse's
+    gap beside its own sender's numbers; the peer's journal has the
+    ``failure_signal``; every gate has the heartbeat counters, and they
+    were reset at the gate."""
+    import threading
+
+    from torchft_tpu import chaos, telemetry
+    from torchft_tpu.manager import Manager
+    from torchft_tpu.process_group import ProcessGroupDummy
+
+    path = str(tmp_path / "journal.jsonl")
+    monkeypatch.setenv("TORCHFT_JOURNAL_FILE", path)
+    telemetry.reset_event_log()
+    server = LighthouseServer(
+        min_replicas=2, join_timeout_ms=60000, quorum_tick_ms=20,
+        heartbeat_timeout_ms=60000,
+    )
+    managers = []
+
+    def make(name, chaos_spec=None):
+        if chaos_spec:
+            monkeypatch.setenv("TORCHFT_CHAOS", chaos_spec)
+        try:
+            m = Manager(
+                pg=ProcessGroupDummy(), state_dict=lambda: {},
+                load_state_dict=lambda s: None, min_replica_size=2,
+                timeout=20.0, quorum_timeout=20.0, connect_timeout=10.0,
+                replica_id=name, lighthouse_addr=server.address(),
+                group_rank=0, group_world_size=1, init_sync=False,
+            )
+        finally:
+            monkeypatch.delenv("TORCHFT_CHAOS", raising=False)
+        managers.append(m)
+        return m
+
+    def loop(m, t_end):
+        while time.monotonic() < t_end:
+            m.start_quorum()
+            m.should_commit()
+            time.sleep(0.05)
+
+    try:
+        # The 31st heartbeat of the victim's server, ~3 s after it starts,
+        # takes 1.5 s longer; the budget is max(1000, 12 x 100) ms.
+        victim = make(
+            "victim",
+            "seed:7,spec:stall@ctrl:match=heartbeat:ms=1500:after=30:count=1",
+        )
+        peer = make("peer")
+        t_end = time.monotonic() + 6.5
+        threads = [threading.Thread(target=loop, args=(m, t_end))
+                   for m in (victim, peer)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        for m in managers:
+            m.shutdown()
+        server.shutdown()
+        telemetry.reset_event_log()
+        chaos.reset()
+
+    with open(path) as fh:
+        events = [json.loads(line) for line in fh]
+
+    def of(who, kind):
+        return [e for e in events if e["event"] == kind
+                and str(e["replica_id"]).startswith(who)]
+
+    # Every gate says under what it was judged and how the heartbeats went.
+    for who in ("victim", "peer"):
+        gates = of(who, "commit_gate")
+        assert len(gates) > 20
+        for g in gates:
+            a = g["attrs"]
+            assert {"local_vote", "cause", "quorum_id", "participants",
+                    "hb_rounds", "hb_gap_max_ms", "hb_rtt_max_ms", "hb_late",
+                    "rss_peak_bytes"} <= set(a)
+            assert (a["cause"] == "ok") == a["committed"]
+            assert a["rss_peak_bytes"] > 2**20
+        # Reset at the gate: steps take ~60 ms, so no gate after the first
+        # can have counted more than a few 100 ms rounds, and the rounds of
+        # all gates are the heartbeats of the run.
+        assert max(g["attrs"]["hb_rounds"] for g in gates[1:]) <= 20
+        assert sum(g["attrs"]["hb_rounds"] for g in gates) >= 30
+
+    evicted = of("victim", "lh_evicted")
+    assert evicted, "the victim never learned that it was evicted"
+    ev = evicted[0]["attrs"]
+    assert ev["budget_ms"] == 1200 and ev["gap_ms"] > 1200
+    assert "heartbeat" in ev["erased"]
+    own_gap = max(g["attrs"]["hb_gap_max_ms"] for g in of("victim", "commit_gate"))
+    own_rtt = max(g["attrs"]["hb_rtt_max_ms"] for g in of("victim", "commit_gate"))
+    assert own_gap > 1200 and own_rtt > 1200  # a stalled send is a long trip
+    assert ev["gap_ms"] >= own_gap - 100
+    assert ev["sender_rtt_ms"] == pytest.approx(own_rtt, abs=1.0)
+    assert max(g["attrs"]["hb_late"] for g in of("victim", "commit_gate")) >= 1
+    # The peer ran quietly, and read of the lapse in its own acks.
+    assert max(g["attrs"]["hb_gap_max_ms"] for g in of("peer", "commit_gate")) < 1000
+    seen = [e["attrs"] for e in of("peer", "failure_signal")
+            if e["attrs"]["source"] == "hb_lapse"]
+    assert seen and all(s["subject"].startswith("victim") for s in seen)
+    assert seen[0]["site"] == "manager.gate"
+    assert seen[0]["detail"]["gap_ms"] > seen[0]["detail"]["budget_ms"] == 1200
+    assert len({s["seq"] for s in seen}) == len(seen)  # each journaled once
+    assert not of("peer", "lh_evicted")
+
+    lines = _lighthouse_lines(capfd)
+    texts = [text for _, text in lines]
+    assert any("hb_lapse on victim" in t for t in texts)
+    assert any("evicted victim" in t and "erased=heartbeat" in t for t in texts)
+    assert any("re-admitted victim" in t and "out_ms=" in t for t in texts)
+    backs = [t for t in texts if "heartbeat of evicted victim" in t]
+    assert _field(backs[0], "gap_ms") == ev["gap_ms"]

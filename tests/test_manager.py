@@ -805,3 +805,170 @@ def test_managed_work_replace_mode():
         np.testing.assert_allclose(out[0], 9.0)
     finally:
         m.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# The gate says why
+# ---------------------------------------------------------------------------
+
+
+def _journaled(tmp_path, monkeypatch, drive, **manager_kwargs):
+    """Runs ``drive(manager)`` with a journal; returns its events."""
+    import json
+
+    from torchft_tpu import telemetry
+
+    path = str(tmp_path / "journal.jsonl")
+    monkeypatch.setenv("TORCHFT_JOURNAL_FILE", path)
+    telemetry.reset_event_log()
+    try:
+        m = make_manager(**manager_kwargs)
+        m._test_client.evidence_status.return_value = {
+            "ok": True, "signal_seq": 0, "evicted": [], "signals": [],
+            "hb": {"rounds": 13, "gap_max_ms": 101.5, "rtt_max_ms": 0.4,
+                   "late": 0, "interval_ms": 100},
+        }
+        try:
+            drive(m)
+        finally:
+            m.shutdown()
+    finally:
+        telemetry.reset_event_log()
+    with open(path) as fh:
+        return [json.loads(line) for line in fh]
+
+
+def _latched_error(m):
+    m.start_quorum()
+    m.wait_quorum()
+    m.report_error(RuntimeError("collective died: " + "x" * 300))
+    assert not m.should_commit()
+
+
+def _peer_vote(m):
+    m._test_client.should_commit.side_effect = None
+    m._test_client.should_commit.return_value = False
+    m.start_quorum()
+    m.wait_quorum()
+    assert not m.should_commit()
+
+
+def _too_few_replicas(m):
+    m.start_quorum()
+    m.wait_quorum()
+    assert not m.should_commit()
+
+
+def _failed_heal(m):
+    m._test_transport.recv_checkpoint.side_effect = ConnectionResetError("torn")
+    m.start_quorum()
+    assert not m.should_commit()
+
+
+def _gate_rpc_timeout(m):
+    m._test_client.should_commit.side_effect = TimeoutError("barrier")
+    m.start_quorum()
+    m.wait_quorum()
+    assert not m.should_commit()
+
+
+def _committed(m):
+    m.start_quorum()
+    m.wait_quorum()
+    assert m.should_commit()
+
+
+@pytest.mark.parametrize("drive,kwargs,want", [
+    (_committed, {}, {"cause": "ok", "local_vote": True}),
+    (_latched_error, {}, {"cause": "local_error", "local_vote": False,
+                          "error_class": "RuntimeError"}),
+    (_peer_vote, {}, {"cause": "peer_voted_no", "local_vote": True}),
+    (_too_few_replicas,
+     {"min_replica_size": 3,
+      "quorum_result": make_quorum_result(replica_world_size=2, max_world_size=2)},
+     {"cause": "not_enough_replicas", "local_vote": False}),
+    (_failed_heal,
+     {"quorum_result": make_quorum_result(
+         heal=True, max_step=5, recover_src_replica_rank=1,
+         recover_src_manager_address="127.0.0.1:1")},
+     {"cause": "healing", "local_vote": False}),
+    (_gate_rpc_timeout, {}, {"cause": "local_error", "local_vote": True,
+                             "error_class": "TimeoutError"}),
+], ids=["ok", "latched-error", "peer-vote", "too-few-replicas", "failed-heal",
+        "gate-rpc-failed"])
+def test_every_gate_carries_its_cause(tmp_path, monkeypatch, drive, kwargs, want):
+    from torchft_tpu.manager import GATE_CAUSES
+
+    events = _journaled(tmp_path, monkeypatch, drive, **kwargs)
+    (gate,) = [e["attrs"] for e in events if e["event"] == "commit_gate"]
+    assert gate["cause"] in GATE_CAUSES
+    assert {k: gate[k] for k in want} == want
+    assert gate["committed"] == (want["cause"] == "ok")
+    assert ("error" in gate) == ("error_class" in gate) == (
+        want["cause"] in ("local_error", "healing"))
+    if "error" in gate:
+        assert 0 < len(gate["error"]) <= 200
+    # Under what it was judged, and how the host stood.
+    assert gate["quorum_id"] == 1 and isinstance(gate["participants"], list)
+    assert (gate["hb_rounds"], gate["hb_gap_max_ms"], gate["hb_rtt_max_ms"],
+            gate["hb_late"]) == (13, 101.5, 0.4, 0)
+    assert gate["rss_peak_bytes"] > 2**20
+    assert gate["elapsed_s"] >= 0
+
+
+def test_the_gate_reads_and_resets_the_liveness_counters_once(tmp_path, monkeypatch):
+    """One ``evidence_status(reset=True)`` a gate; an eviction the
+    lighthouse reports is journaled as ``lh_evicted``; a signal an ack
+    showed is journaled once, however often it is handed out."""
+    lapse = {"seq": 7, "ts_ms": 1234, "replica_id": "other:u1",
+             "source": "hb_lapse", "site": "lighthouse.fleet_scan",
+             "detail": {"gap_ms": 1207, "budget_ms": 1200}}
+    evicted = {"seq": 8, "ts_ms": 2000, "gap_ms": 1650, "open_gap_ms": 1210,
+               "budget_ms": 1200, "out_ms": 440,
+               "erased": "heartbeat+participant+quorum_request",
+               "via": "heartbeat", "sender_gap_ms": 100.2, "sender_rtt_ms": 1549.0}
+
+    def drive(m):
+        status = m._test_client.evidence_status
+        for step in range(3):
+            base = dict(status.return_value)
+            base["signals"] = [lapse]  # handed out again and again
+            base["evicted"] = [evicted] if step == 1 else []
+            status.return_value = base
+            m.start_quorum()
+            m.wait_quorum()
+            assert m.should_commit()
+        assert status.call_count == 3
+        for call in status.call_args_list:
+            assert call.kwargs["reset"] is True
+
+    events = _journaled(tmp_path, monkeypatch, drive)
+    assert len([e for e in events if e["event"] == "commit_gate"]) == 3
+    (sig,) = [e for e in events if e["event"] == "failure_signal"]
+    assert sig["step"] == 0 and sig["attrs"]["seq"] == 7
+    assert (sig["attrs"]["source"], sig["attrs"]["subject"], sig["attrs"]["site"],
+            sig["attrs"]["origin"]) == (
+        "hb_lapse", "other:u1", "manager.gate", "lighthouse.fleet_scan")
+    assert sig["attrs"]["detail"] == {"gap_ms": 1207, "budget_ms": 1200}
+    (ev,) = [e for e in events if e["event"] == "lh_evicted"]
+    assert ev["step"] == 1 and ev["attrs"] == evicted
+
+
+def test_a_server_without_the_counters_gives_a_gate_without_them(tmp_path, monkeypatch):
+    """An older manager server's ``evidence_status`` has no ``hb``, and
+    one that cannot answer raises: the gate is journaled all the same."""
+    def drive(m):
+        m._test_client.evidence_status.return_value = {"ok": True, "signal_seq": 0}
+        m.start_quorum()
+        m.wait_quorum()
+        assert m.should_commit()
+        m._test_client.evidence_status.side_effect = TimeoutError("busy")
+        m.start_quorum()
+        m.wait_quorum()
+        assert m.should_commit()
+
+    events = _journaled(tmp_path, monkeypatch, drive)
+    gates = [e["attrs"] for e in events if e["event"] == "commit_gate"]
+    assert len(gates) == 2
+    for g in gates:
+        assert g["cause"] == "ok" and "hb_rounds" not in g and "rss_peak_bytes" in g

@@ -6,6 +6,7 @@
 
 #include <algorithm>
 #include <chrono>
+#include <cstdarg>
 #include <cstdio>
 #include <cstdlib>
 #include <cstring>
@@ -19,12 +20,22 @@
 namespace tft {
 
 namespace {
-// Steady-clock microseconds for the hot-path histograms (wall clock can
-// step; a latency sample must not).
-int64_t now_us_steady() {
-  return std::chrono::duration_cast<std::chrono::microseconds>(
-             std::chrono::steady_clock::now().time_since_epoch())
-      .count();
+// Every line the lighthouse prints starts with the wall clock in
+// milliseconds: the clock of ``ts_ms`` in its signal and anomaly rings and
+// (times 1000) of ``ts`` in a manager's journal, so a line can be set
+// beside what a replica group wrote down for the same instant. One write a
+// line, so lines of concurrent handlers do not interleave.
+__attribute__((format(printf, 1, 2))) void lh_log(const char* fmt, ...) {
+  char buf[2048];
+  int n = snprintf(buf, sizeof(buf), "%lld ", static_cast<long long>(now_ms()));
+  va_list ap;
+  va_start(ap, fmt);
+  int m = vsnprintf(buf + n, sizeof(buf) - n, fmt, ap);
+  va_end(ap);
+  if (m < 0) return;
+  size_t len = std::min(sizeof(buf) - 1, static_cast<size_t>(n + m));
+  if (len > 0 && buf[len - 1] != '\n') buf[len - 1] = '\n';  // truncated
+  fwrite(buf, 1, len, stderr);
 }
 
 // Wire back-compat: pre-namespace clients send no "job" field; an absent or
@@ -119,8 +130,8 @@ void Lighthouse::persist_locked(int64_t job_qid, int64_t job_gen) {
   d.quorum_id = dur_quorum_id_;
   d.generation = dur_gen_ + kGenReserve;
   if (!lh_state_save(opts_.state_dir, d)) {
-    fprintf(stderr, "[lighthouse] WARNING: failed to persist state to %s\n",
-            opts_.state_dir.c_str());
+    lh_log("[lighthouse] WARNING: failed to persist state to %s\n",
+           opts_.state_dir.c_str());
   }
 }
 
@@ -172,13 +183,13 @@ bool Lighthouse::start() {
       epoch_ = d.epoch;
       restored_quorum_id_ = dur_quorum_id_ = d.quorum_id;
       restored_gen_ = dur_gen_ = d.generation;
-      fprintf(stderr,
-              "[lighthouse] warm restart from %s: epoch=%lld quorum_id=%lld "
-              "gen=%lld%s\n",
-              opts_.state_dir.c_str(), static_cast<long long>(epoch_.load()),
-              static_cast<long long>(restored_quorum_id_),
-              static_cast<long long>(restored_gen_),
-              active_ ? "" : " (standby)");
+      lh_log(
+          "[lighthouse] warm restart from %s: epoch=%lld quorum_id=%lld "
+          "gen=%lld%s\n",
+          opts_.state_dir.c_str(), static_cast<long long>(epoch_.load()),
+          static_cast<long long>(restored_quorum_id_),
+          static_cast<long long>(restored_gen_),
+          active_ ? "" : " (standby)");
     }
     if (active_ && epoch_ == 0) epoch_ = 1;  // fresh active boot
     if (active_) persist_locked(dur_quorum_id_, dur_gen_);
@@ -270,8 +281,8 @@ void Lighthouse::district_loop() {
   int port = 0;
   const bool addr_ok = split_host_port(opts_.root_addr, &host, &port);
   if (!addr_ok) {
-    fprintf(stderr, "[lighthouse] bad root address '%s'; federation off\n",
-            opts_.root_addr.c_str());
+    lh_log("[lighthouse] bad root address '%s'; federation off\n",
+           opts_.root_addr.c_str());
     return;
   }
   int fd = -1;
@@ -396,11 +407,11 @@ Json Lighthouse::handle_request(const Json& req, int64_t deadline_ms,
           js.last_reason = "fenced: observed epoch " +
                            std::to_string(observed_epoch_.load()) +
                            " > own epoch " + std::to_string(epoch_.load());
-          fprintf(stderr,
-                  "[lighthouse] demoting to standby: fleet is on epoch %lld, "
-                  "ours is %lld (stale primary fenced out)\n",
-                  static_cast<long long>(observed_epoch_.load()),
-                  static_cast<long long>(epoch_.load()));
+          lh_log(
+              "[lighthouse] demoting to standby: fleet is on epoch %lld, "
+              "ours is %lld (stale primary fenced out)\n",
+              static_cast<long long>(observed_epoch_.load()),
+              static_cast<long long>(epoch_.load()));
         }
       }
       // A drained replica's manager may have one heartbeat in flight when
@@ -411,6 +422,26 @@ Json Lighthouse::handle_request(const Json& req, int64_t deadline_ms,
         int64_t now = now_ms();
         // Gate counter: a replica heartbeating but not (yet) registered
         // holds the "all healthy joined" condition open.
+        // First heartbeat from an id the evidence plane evicted: close
+        // its record against the fleet row as it stood (the closed gap),
+        // and wake its parked quorum request so that it registers again.
+        readmit_locked(js, replica_id, "heartbeat", now);
+        auto evd = js.evicted.find(replica_id);
+        if (evd != js.evicted.end() && evd->second.gap_ms < 0) {
+          // The gap that got it evicted, closed: arrival to arrival.
+          auto fe = js.fleet.find(replica_id);
+          evd->second.gap_ms =
+              fe != js.fleet.end() ? now - fe->second.last_hb_ms : -1;
+          lh_log(
+              "[lighthouse] heartbeat of evicted %s back (job %s): signal "
+              "#%lld gap_ms=%lld budget_ms=%lld out_ms=%lld via=%s\n",
+              replica_id.c_str(), js.name.c_str(),
+              static_cast<long long>(evd->second.seq),
+              static_cast<long long>(evd->second.gap_ms),
+              static_cast<long long>(evd->second.budget_ms),
+              static_cast<long long>(evd->second.out_ms),
+              evd->second.via.c_str());
+        }
         if (!js.state.heartbeats.count(replica_id) &&
             !js.state.participants.count(replica_id))
           js.hb_not_joined += 1;
@@ -450,6 +481,37 @@ Json Lighthouse::handle_request(const Json& req, int64_t deadline_ms,
       // zero extra RPCs. Old managers ignore both keys.
       resp["signal_seq"] = Json::of(js.signal_seq);
       if (!js.signals.empty()) resp["signal"] = js.signals.back();
+      // A sender that says how far it has read gets every signal past
+      // that (the newest eight): two groups evicted by one scan are two
+      // signals, and ``signal`` alone shows the second.
+      const int64_t cursor = req.get("signal_seq").as_int(-1);
+      if (cursor >= 0 && cursor < js.signal_seq) {
+        size_t first = js.signals.size();
+        while (first > 0 && js.signals.size() - first < 8 &&
+               js.signals[first - 1].get("seq").as_int(0) > cursor)
+          first -= 1;
+        Json arr = Json::array();
+        for (size_t k = first; k < js.signals.size(); k++)
+          arr.push(js.signals[k]);
+        resp["signals"] = std::move(arr);
+      }
+      // The group that was dropped learns that it was, with the
+      // lighthouse's numbers for the interval: once, in the first ack
+      // after it was heard from again. Old managers ignore the key.
+      auto ev = js.evicted.find(replica_id);
+      if (ev != js.evicted.end() && ev->second.gap_ms >= 0) {
+        Json e = Json::object();
+        e["seq"] = Json::of(ev->second.seq);
+        e["ts_ms"] = Json::of(ev->second.at_ms);
+        e["gap_ms"] = Json::of(ev->second.gap_ms);
+        e["open_gap_ms"] = Json::of(ev->second.open_gap_ms);
+        e["budget_ms"] = Json::of(ev->second.budget_ms);
+        e["out_ms"] = Json::of(ev->second.out_ms);
+        e["erased"] = Json::of(ev->second.erased);
+        e["via"] = Json::of(ev->second.via);
+        resp["evicted"] = std::move(e);
+        js.evicted.erase(ev);
+      }
     }
     resp["ok"] = Json::of(true);
     hist_heartbeat_.observe_us(now_us_steady() - hb_t0);
@@ -501,8 +563,8 @@ Json Lighthouse::handle_request(const Json& req, int64_t deadline_ms,
       // timer tick — and sibling jobs are untouched.
       job_tick_locked(js, now_ms());
     }
-    fprintf(stderr, "[lighthouse] replica %s left gracefully (job %s)\n",
-            replica_id.c_str(), js.name.c_str());
+    lh_log("[lighthouse] replica %s left gracefully (job %s)\n",
+           replica_id.c_str(), js.name.c_str());
     resp["ok"] = Json::of(true);
     return resp;
   }
@@ -670,8 +732,8 @@ void Lighthouse::job_tick_locked(JobState& js, int64_t now) {
   hist_quorum_.observe_us(now_us_steady() - q_t0);
   if (!members) {
     if (reason != js.last_reason && !js.state.participants.empty()) {
-      fprintf(stderr, "[lighthouse] no quorum (job %s): %s\n",
-              js.name.c_str(), reason.c_str());
+      lh_log("[lighthouse] no quorum (job %s): %s\n",
+             js.name.c_str(), reason.c_str());
     }
     js.last_reason = reason;
     return;
@@ -744,16 +806,16 @@ void Lighthouse::job_tick_locked(JobState& js, int64_t now) {
   }
   js.quorum_gen += 1;
   js.last_reason.clear();
-  fprintf(stderr, "[lighthouse] quorum %lld formed with %zu members (job %s)\n",
-          static_cast<long long>(q.quorum_id), q.participants.size(),
-          js.name.c_str());
+  lh_log("[lighthouse] quorum %lld formed with %zu members (job %s)\n",
+         static_cast<long long>(q.quorum_id), q.participants.size(),
+         js.name.c_str());
   if (std::getenv("TORCHFT_LH_DEBUG") != nullptr) {
     std::string ids;
     for (const auto& m : q.participants) ids += m.replica_id + " ";
-    fprintf(stderr, "[lighthouse] +%lld formed gen=%lld job=%s members: %s\n",
-            static_cast<long long>(now_ms() % 1000000),
-            static_cast<long long>(js.quorum_gen), js.name.c_str(),
-            ids.c_str());
+    lh_log("[lighthouse] +%lld formed gen=%lld job=%s members: %s\n",
+           static_cast<long long>(now_ms() % 1000000),
+           static_cast<long long>(js.quorum_gen), js.name.c_str(),
+           ids.c_str());
   }
   js.cv.notify_all();
 }
@@ -787,22 +849,34 @@ Json Lighthouse::quorum_rpc(const Json& req, int64_t deadline_ms,
       active_ = true;
       takeovers_ += 1;
       persist_locked(js.state.quorum_id, js.quorum_gen);
-      fprintf(stderr,
-              "[lighthouse] standby takeover: now active with epoch %lld "
-              "(first quorum request from %s, job %s)\n",
-              static_cast<long long>(epoch_.load()), me.replica_id.c_str(),
-              js.name.c_str());
+      lh_log(
+          "[lighthouse] standby takeover: now active with epoch %lld "
+          "(first quorum request from %s, job %s)\n",
+          static_cast<long long>(epoch_.load()), me.replica_id.c_str(),
+          js.name.c_str());
     }
   }
+  // Parked from here to every return (the guard dies before the lock).
+  struct Parked {
+    JobState& js;
+    const std::string& id;
+    Parked(JobState& j, const std::string& i) : js(j), id(i) {
+      js.parked[id]++;
+    }
+    ~Parked() {
+      if (--js.parked[id] <= 0) js.parked.erase(id);
+    }
+  } parked_guard(js, me.replica_id);
+  readmit_locked(js, me.replica_id, "quorum request", now_ms());
   register_participant_locked(js, me);
   int64_t my_gen = js.quorum_gen;
   if (debug) {
-    fprintf(stderr,
-            "[lighthouse] +%lld register %s job=%s step=%lld gen=%lld "
-            "pool=%zu\n",
-            static_cast<long long>(now_ms() % 1000000), me.replica_id.c_str(),
-            js.name.c_str(), static_cast<long long>(me.step),
-            static_cast<long long>(my_gen), js.state.participants.size());
+    lh_log(
+        "[lighthouse] +%lld register %s job=%s step=%lld gen=%lld "
+        "pool=%zu\n",
+        static_cast<long long>(now_ms() % 1000000), me.replica_id.c_str(),
+        js.name.c_str(), static_cast<long long>(me.step),
+        static_cast<long long>(my_gen), js.state.participants.size());
   }
   // Incremental quorum: the O(1) gate decides whether this registration
   // could complete a quorum; only then does the full quorum_compute run —
@@ -814,6 +888,22 @@ Json Lighthouse::quorum_rpc(const Json& req, int64_t deadline_ms,
   while (running_) {
     // Wait for a fresh quorum broadcast.
     while (running_ && js.quorum_gen == my_gen) {
+      // Evicted on evidence while parked here, and heard from since (the
+      // heartbeat that re-admitted the id woke us): the registration went
+      // with the eviction and nobody else files it again before this
+      // request times out, so file it. Within one generation only an
+      // eviction or a leave erases a registration, and a leave leaves a
+      // tombstone.
+      if (!js.state.participants.count(me.replica_id) &&
+          js.state.heartbeats.count(me.replica_id) &&
+          !js.state.left.count(me.replica_id)) {
+        register_participant_locked(js, me);
+        lh_log("[lighthouse] re-registered the parked quorum request of %s "
+               "(job %s)\n",
+               me.replica_id.c_str(), js.name.c_str());
+        if (quorum_gate_locked(js)) job_tick_locked(js, now_ms());
+        continue;
+      }
       if (js.cv.wait_until(lk, std::chrono::system_clock::time_point(
                                    std::chrono::milliseconds(deadline_ms))) ==
           std::cv_status::timeout) {
@@ -973,10 +1063,10 @@ Json Lighthouse::district_note(const Json& req) {
     // failed over (standby takeover bumps the epoch). Only this district's
     // row changes; siblings and other jobs' tables are untouched.
     e.failovers += 1;
-    fprintf(stderr,
-            "[lighthouse] district %s failed over: epoch %lld -> %lld\n",
-            name.c_str(), static_cast<long long>(e.epoch),
-            static_cast<long long>(ep));
+    lh_log(
+        "[lighthouse] district %s failed over: epoch %lld -> %lld\n",
+        name.c_str(), static_cast<long long>(e.epoch),
+        static_cast<long long>(ep));
   }
   e.epoch = ep;
   e.last_hb_ms = now_ms();
@@ -994,9 +1084,9 @@ void Lighthouse::district_scan(int64_t now) {
     if (!e.lost && now - e.last_hb_ms > opts_.heartbeat_timeout_ms) {
       e.lost = true;
       district_losses_ += 1;
-      fprintf(stderr,
-              "[lighthouse] district %s lost: no rollup for %lld ms\n",
-              kv.first.c_str(), static_cast<long long>(now - e.last_hb_ms));
+      lh_log(
+          "[lighthouse] district %s lost: no rollup for %lld ms\n",
+          kv.first.c_str(), static_cast<long long>(now - e.last_hb_ms));
     }
   }
 }
@@ -1033,6 +1123,7 @@ constexpr int64_t kFleetJitterMult = 8;      // budget = mult * cadence
 constexpr int64_t kFleetJitterFloorMs = 1000;
 constexpr int64_t kFleetEwmaWarmup = 5;      // gaps before EWMA budget counts
 constexpr size_t kFleetSignalRing = 64;      // failure signals kept
+constexpr size_t kEvictedCap = 256;          // eviction records awaiting an ack
 // (The old full-sort fleet_median lived here; the MedianTracker members in
 // lighthouse.hpp maintain the identical upper median incrementally.)
 }  // namespace
@@ -1071,9 +1162,9 @@ void Lighthouse::fleet_set_flag(JobState& js, const std::string& replica_id,
     js.anomalies.pop_front();
     js.anomalies_dropped += 1;
   }
-  fprintf(stderr, "[lighthouse] anomaly #%lld: %s on %s (job %s) %s\n",
-          static_cast<long long>(js.anomaly_seq), kind.c_str(),
-          replica_id.c_str(), js.name.c_str(), detail.dump().c_str());
+  lh_log("[lighthouse] anomaly #%lld: %s on %s (job %s) %s\n",
+         static_cast<long long>(js.anomaly_seq), kind.c_str(),
+         replica_id.c_str(), js.name.c_str(), detail.dump().c_str());
   // Digest-driven anomaly rise-edges double as failure evidence (the
   // heartbeat-gap rules have their own cadence-aware hb_lapse source in
   // the scan/eviction path, so they are excluded here).
@@ -1142,21 +1233,23 @@ void Lighthouse::signal_note_locked(JobState& js, const std::string& source,
     it->second.last_signal_ms = now;
   }
   js.fleet_gen += 1;
-  fprintf(stderr, "[lighthouse] signal #%lld: %s on %s via %s (job %s)\n",
-          static_cast<long long>(js.signal_seq), source.c_str(),
-          replica_id.c_str(), site.c_str(), js.name.c_str());
+  lh_log("[lighthouse] signal #%lld: %s on %s via %s (job %s) %s\n",
+         static_cast<long long>(js.signal_seq), source.c_str(),
+         replica_id.c_str(), site.c_str(), js.name.c_str(),
+         js.signals.back().get("detail").dump().c_str());
 }
 
 void Lighthouse::evidence_evict_locked(JobState& js,
                                        const std::string& replica_id,
-                                       int64_t now) {
+                                       int64_t now, int64_t seq,
+                                       int64_t open_gap_ms,
+                                       int64_t budget_ms) {
   // Evidence says this replica is dead: drop it from the quorum tables NOW
   // so the next evaluation forms the shrunken quorum, instead of waiting
   // out heartbeat_timeout_ms. Same gate fixups as a graceful leave, but NO
   // tombstone — evidence can be wrong, and the replica's next heartbeat or
   // registration re-admits it with zero ceremony. The fleet row stays
   // (flags, digest, last_signal intact) as detection forensics.
-  (void)now;
   const bool was_part = js.state.participants.count(replica_id) > 0;
   const bool was_hb = js.state.heartbeats.count(replica_id) > 0;
   if (!was_part && !was_hb) return;
@@ -1165,6 +1258,45 @@ void Lighthouse::evidence_evict_locked(JobState& js,
   js.state.participants.erase(replica_id);
   if (was_hb && !was_part) js.hb_not_joined -= 1;
   if (was_part && js.prev_ids.count(replica_id)) js.prev_present -= 1;
+  // What went, for the log and for the evicted id itself (its next ack):
+  // a participant entry IS its registration for the next quorum, and with a
+  // handler parked in quorum_rpc it is a request somebody is waiting on.
+  auto pk = js.parked.find(replica_id);
+  const bool was_parked = was_part && pk != js.parked.end() && pk->second > 0;
+  JobState::Evicted ev;
+  ev.seq = seq;
+  ev.at_ms = now;
+  ev.open_gap_ms = open_gap_ms;
+  ev.budget_ms = budget_ms;
+  ev.erased = was_hb ? "heartbeat" : "";
+  if (was_part) ev.erased += "+participant";
+  if (was_parked) ev.erased += "+quorum_request";
+  if (js.evicted.size() >= kEvictedCap) js.evicted.erase(js.evicted.begin());
+  js.evicted[replica_id] = ev;
+  lh_log(
+      "[lighthouse] evicted %s on evidence (job %s): signal #%lld gap_ms=%lld "
+      "budget_ms=%lld erased=%s\n",
+      replica_id.c_str(), js.name.c_str(), static_cast<long long>(seq),
+      static_cast<long long>(open_gap_ms), static_cast<long long>(budget_ms),
+      ev.erased.c_str());
+}
+
+void Lighthouse::readmit_locked(JobState& js, const std::string& replica_id,
+                                const char* via, int64_t now) {
+  auto it = js.evicted.find(replica_id);
+  if (it == js.evicted.end() || it->second.out_ms >= 0) return;
+  JobState::Evicted& ev = it->second;
+  ev.out_ms = now - ev.at_ms;
+  ev.via = via;
+  lh_log(
+      "[lighthouse] re-admitted %s by its %s (job %s): signal #%lld "
+      "out_ms=%lld erased=%s\n",
+      replica_id.c_str(), via, js.name.c_str(),
+      static_cast<long long>(ev.seq), static_cast<long long>(ev.out_ms),
+      ev.erased.c_str());
+  // A request parked in quorum_rpc lost its registration to the eviction:
+  // wake it; it registers again now that the id is known to be alive.
+  if (js.parked.count(replica_id)) js.cv.notify_all();
 }
 
 // Retire / fold one entry's digest contributions. Together these keep the
@@ -1325,11 +1457,11 @@ void Lighthouse::fleet_note_heartbeat(JobState& js,
             js.slo_dropped += 1;
           }
           js.fleet_gen += 1;
-          fprintf(stderr,
-                  "[lighthouse] slo_burn #%lld: job %s goodput %.4f vs "
-                  "target %.4f (burn %.2fx)\n",
-                  static_cast<long long>(js.slo_seq), js.name.c_str(), gp,
-                  slo_goodput_, burn);
+          lh_log(
+              "[lighthouse] slo_burn #%lld: job %s goodput %.4f vs "
+              "target %.4f (burn %.2fx)\n",
+              static_cast<long long>(js.slo_seq), js.name.c_str(), gp,
+              slo_goodput_, burn);
         }
       } else if (js.slo_burning) {
         js.slo_burning = false;  // fall edge: budget spend back in bounds
@@ -1386,8 +1518,10 @@ void Lighthouse::fleet_scan_locked(JobState& js, int64_t now) {
       d["budget_ms"] =
           Json::of(std::max(e.hb_interval_ms * opts_.evict_mult,
                             opts_.evict_floor_ms));
+      const int64_t gap = d.get("gap_ms").as_int();
+      const int64_t budget = d.get("budget_ms").as_int();
       signal_note_locked(js, "hb_lapse", id, "lighthouse.fleet_scan", d, now);
-      evidence_evict_locked(js, id, now);
+      evidence_evict_locked(js, id, now, js.signal_seq, gap, budget);
     }
     // Evidence tick: fresh evidence re-evaluates the quorum NOW; the
     // periodic tick and the timeout landing stay as the fallback.

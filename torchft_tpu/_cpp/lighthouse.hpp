@@ -282,6 +282,24 @@ class Lighthouse {
     int64_t signal_seq = 0;
     int64_t signals_dropped = 0;
     std::map<std::string, int64_t> signal_counts;  // per-source totals
+    // Evidence evictions not yet told to the evicted id: what the scan saw
+    // and erased, and (once the id is heard from again) how long it was
+    // out. The first heartbeat ack after the re-admission carries the
+    // record back to the sender and drops it. Bounded: kEvictedCap.
+    struct Evicted {
+      int64_t seq = 0;          // the hb_lapse signal's seq
+      int64_t at_ms = 0;        // when the scan evicted it
+      int64_t open_gap_ms = 0;  // the open gap the scan judged
+      int64_t budget_ms = 0;
+      std::string erased;       // "heartbeat", "+participant", "+quorum_request"
+      int64_t gap_ms = -1;      // closed gap, arrival to arrival (-1: open)
+      int64_t out_ms = -1;      // eviction -> first frame from the id
+      std::string via;          // that frame: "heartbeat", "quorum request"
+    };
+    std::map<std::string, Evicted> evicted;
+    // Quorum requests parked in quorum_rpc, by requester: what an eviction
+    // finds to erase besides the table entries.
+    std::map<std::string, int64_t> parked;
     int64_t fleet_gen = 0;  // bumped on every fleet-table mutation
     int64_t flagged = 0;    // entries with a non-empty flag set
     int64_t n_digest = 0;   // entries with a digest
@@ -380,9 +398,16 @@ class Lighthouse {
                           const std::string& site, Json detail, int64_t now);
   // Evidence-driven hb-lapse eviction (js.mu held): drop `replica_id` from
   // the quorum tables with leave-style gate fixups but NO tombstone (a
-  // relaunch rejoins normally) and keep the fleet row as forensics.
+  // relaunch rejoins normally) and keep the fleet row as forensics. What
+  // it erased goes on record (js.evicted) with the scan's numbers.
   void evidence_evict_locked(JobState& js, const std::string& replica_id,
-                             int64_t now);
+                             int64_t now, int64_t seq, int64_t open_gap_ms,
+                             int64_t budget_ms);
+  // The first frame from an evicted id (js.mu held): notes how long it was
+  // out and by what it came back, prints the re-admission, and wakes its
+  // parked quorum request. The heartbeat handler closes the gap.
+  void readmit_locked(JobState& js, const std::string& replica_id,
+                      const char* via, int64_t now);
   void fleet_clear_flag(JobState& js, FleetEntry& e, const std::string& kind);
   void fleet_erase(JobState& js, const std::string& replica_id);
   void fleet_agg_remove(JobState& js, const FleetEntry& e);

@@ -110,6 +110,8 @@ void ManagerServer::heartbeat_loop() {
   // lighthouse saying no) on the active entry: hard evidence the process is
   // gone, consumed by the evidence failover below.
   uint64_t active_fail_streak = 0;
+  // When the last heartbeat to the active entry was sent (0: none yet).
+  int64_t last_send_us = 0;
   while (running_) {
     if (draining_) {
       // Graceful drain in progress: no more heartbeats (a fresh heartbeat
@@ -206,6 +208,9 @@ void ManagerServer::heartbeat_loop() {
         // lighthouse ring tolerates duplicates, losing evidence is worse.
         size_t attached = 0;
         if (is_active) {
+          // How far we have read the island's signals: the ack then shows
+          // every one past it, not the last alone.
+          req["signal_seq"] = Json::of(lh_signal_seq_.load());
           std::lock_guard<std::mutex> lk(signal_mu_);
           if (!signal_outbox_.empty()) {
             Json arr = Json::array();
@@ -214,8 +219,26 @@ void ManagerServer::heartbeat_loop() {
             req["signals"] = std::move(arr);
           }
         }
+        // The sender's view of the liveness path: the gap from the
+        // previous send to this one, and this round trip. A heartbeat the
+        // lighthouse finds late is late by one or the other.
+        const int64_t send_us = now_us_steady();
+        int64_t gap_us = 0;
+        if (is_active) {
+          if (last_send_us > 0) gap_us = send_us - last_send_us;
+          last_send_us = send_us;
+        }
         Json resp;
-        if (call_json(fds[i], req, &resp, 5000)) {
+        const bool answered = call_json(fds[i], req, &resp, 5000);
+        const int64_t rtt_us = now_us_steady() - send_us;
+        if (is_active) {
+          std::lock_guard<std::mutex> lk(hb_mu_);
+          hb_.rounds += 1;
+          hb_.gap_max_us = std::max(hb_.gap_max_us, gap_us);
+          hb_.rtt_max_us = std::max(hb_.rtt_max_us, rtt_us);
+          if (gap_us > 3 * opts_.heartbeat_interval_ms * 1000) hb_.late += 1;
+        }
+        if (answered) {
           acked = resp.get("ok").as_bool();
           if (acked && is_active) {
             // Evidence cursor: the ack carries the island's failure-signal
@@ -230,6 +253,34 @@ void ManagerServer::heartbeat_loop() {
               }
               std::lock_guard<std::mutex> lk(signal_mu_);
               if (resp.has("signal")) last_signal_ = resp.get("signal");
+            }
+            {
+              // Every signal an ack shows, once, for the gate's journal
+              // (``signals``: all past our cursor; an older lighthouse
+              // sends the last one alone), and the lighthouse's word that
+              // it had evicted this very group, beside this sender's
+              // numbers for the heartbeat that brought it back.
+              std::lock_guard<std::mutex> lk(hb_mu_);
+              auto note = [&](const Json& sg) {
+                int64_t q = sg.get("seq").as_int(0);
+                if (!sg.is_object() || q <= seen_signal_seq_) return;
+                seen_signal_seq_ = q;
+                seen_signals_.push_back(sg);
+                while (seen_signals_.size() > kAckRing)
+                  seen_signals_.pop_front();
+              };
+              if (resp.get("signals").is_array()) {
+                for (const auto& sg : resp.get("signals").arr) note(sg);
+              } else if (resp.has("signal")) {
+                note(resp.get("signal"));
+              }
+              if (resp.get("evicted").is_object()) {
+                Json e = resp.get("evicted");
+                e["sender_gap_ms"] = Json::of(gap_us / 1000.0);
+                e["sender_rtt_ms"] = Json::of(rtt_us / 1000.0);
+                evicted_.push_back(std::move(e));
+                while (evicted_.size() > kAckRing) evicted_.pop_front();
+              }
             }
             if (attached > 0) {
               std::lock_guard<std::mutex> lk(signal_mu_);
@@ -402,6 +453,31 @@ Json ManagerServer::handle_request(const Json& req, int64_t deadline_ms) {
       resp["outbox_dropped"] = Json::of(signal_outbox_dropped_);
     }
     resp["lh"] = lh_info_json();
+    {
+      // The sender's view of its own heartbeats since the last read that
+      // reset it (the commit gate's, once a step), and what the acks said
+      // since: evictions of this group, signals about any.
+      const bool reset = req.get("reset").as_bool();
+      std::lock_guard<std::mutex> lk(hb_mu_);
+      Json hb = Json::object();
+      hb["rounds"] = Json::of(hb_.rounds);
+      hb["gap_max_ms"] = Json::of(hb_.gap_max_us / 1000.0);
+      hb["rtt_max_ms"] = Json::of(hb_.rtt_max_us / 1000.0);
+      hb["late"] = Json::of(hb_.late);
+      hb["interval_ms"] = Json::of(opts_.heartbeat_interval_ms);
+      resp["hb"] = std::move(hb);
+      Json ev = Json::array();
+      for (const auto& e : evicted_) ev.push(e);
+      resp["evicted"] = std::move(ev);
+      Json sg = Json::array();
+      for (const auto& g : seen_signals_) sg.push(g);
+      resp["signals"] = std::move(sg);
+      if (reset) {
+        hb_ = HbStats();
+        evicted_.clear();
+        seen_signals_.clear();
+      }
+    }
     return resp;
   }
   if (type == "info") {

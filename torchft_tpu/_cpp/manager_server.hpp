@@ -154,6 +154,24 @@ class ManagerServer {
   Json last_signal_ = Json::null();
   std::deque<Json> signal_outbox_;
   int64_t signal_outbox_dropped_ = 0;
+  // ---- the liveness path as this sender saw it (hb_mu_) ----
+  // Since the last evidence_status read that asked to reset: heartbeat
+  // rounds to the active lighthouse, the largest gap between two sends and
+  // the largest round trip of one (steady clock), and the gaps over three
+  // intervals. With them, what the acks said of this group (an eviction
+  // the lighthouse has just taken back) and every signal seen in an ack.
+  struct HbStats {
+    int64_t rounds = 0;
+    int64_t gap_max_us = 0;
+    int64_t rtt_max_us = 0;
+    int64_t late = 0;
+  };
+  std::mutex hb_mu_;
+  HbStats hb_;
+  std::deque<Json> evicted_;       // capped at kAckRing
+  std::deque<Json> seen_signals_;  // capped at kAckRing, distinct by seq
+  int64_t seen_signal_seq_ = 0;
+  static constexpr size_t kAckRing = 16;
   int port_ = 0;
   int listen_fd_ = -1;
   std::atomic<bool> running_{false};

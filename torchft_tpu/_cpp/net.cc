@@ -24,6 +24,12 @@ int64_t now_ms() {
       .count();
 }
 
+int64_t now_us_steady() {
+  return std::chrono::duration_cast<std::chrono::microseconds>(
+             std::chrono::steady_clock::now().time_since_epoch())
+      .count();
+}
+
 uint64_t now_realtime_ns() {
   timespec ts{};
   clock_gettime(CLOCK_REALTIME, &ts);

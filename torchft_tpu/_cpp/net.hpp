@@ -17,6 +17,9 @@ namespace tft {
 // Returns ms since epoch (steady for intervals where it matters we use the
 // same clock consistently).
 int64_t now_ms();
+// Steady-clock microseconds: for a latency, a gap or a round trip, which
+// must not jump when the wall clock steps.
+int64_t now_us_steady();
 
 // Wall-clock nanoseconds (CLOCK_REALTIME), chosen over CLOCK_MONOTONIC so
 // timestamps recorded in the data plane align with the Python journal's

@@ -597,7 +597,7 @@ class LighthouseClient:
         epoch: int = 0,
         job: str = "",
         signals: Optional[List[Dict[str, Any]]] = None,
-    ) -> None:
+    ) -> Dict[str, Any]:
         """One heartbeat, optionally carrying a :class:`~torchft_tpu.
         telemetry.StepDigest` wire dict (``StepDigest.to_wire()``) plus
         the sender's nominal heartbeat interval and the max quorum epoch
@@ -624,7 +624,10 @@ class LighthouseClient:
             # (source/replica_id/site/detail dicts). Old lighthouses drop
             # the key unread.
             req["signals"] = list(signals)
-        self._client.call(req, timeout)
+        # The ack: ``signal_seq`` and ``signal`` (the job's evidence
+        # cursor), and once after an evidence eviction of this id was
+        # taken back, ``evicted`` (what the lighthouse saw and erased).
+        return self._client.call(req, timeout)
 
     def fleet(self, timeout: float = 5.0, job: str = "") -> Dict[str, Any]:
         """Live fleet-health table (the framed twin of ``GET
@@ -970,18 +973,29 @@ class ManagerClient:
             req["detail"] = detail
         self._client.call(req, timeout, retry=False)
 
-    def evidence_status(self, timeout: float = 2.0) -> Dict[str, Any]:
+    def evidence_status(
+        self, timeout: float = 2.0, reset: bool = False
+    ) -> Dict[str, Any]:
         """Poll the manager's evidence cursor: the active lighthouse
         island's failure-signal seq (``signal_seq``), the last signal it
         acked back (``signal``), and the lighthouse HA attribution
         (``lh.detect_ms`` / ``lh.evidence``). The trainer-side evidence
         watcher uses a seq RISE with a hard source on a peer to abort a
-        wedged collective early."""
-        return self._client.call(
-            {"type": "evidence_status", "timeout_ms": int(timeout * 1000)},
-            timeout,
-            retry=False,
-        )
+        wedged collective early.
+
+        It also carries the liveness path as the sender saw it: ``hb``
+        (heartbeat ``rounds`` to the active lighthouse, ``gap_max_ms``
+        between two sends, ``rtt_max_ms`` of one round trip, ``late``
+        gaps over three intervals), ``evicted`` (what the lighthouse said
+        of evictions of this very group) and ``signals`` (each signal an
+        ack showed), all since the last call with ``reset=True``: the
+        commit gate's, once a step."""
+        req: Dict[str, Any] = {
+            "type": "evidence_status", "timeout_ms": int(timeout * 1000)
+        }
+        if reset:
+            req["reset"] = True
+        return self._client.call(req, timeout, retry=False)
 
     def kill(self, msg: str = "") -> None:
         try:

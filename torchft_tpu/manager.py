@@ -25,6 +25,7 @@ import concurrent.futures
 import json
 import logging
 import os
+import resource
 import socket
 import threading
 import time
@@ -329,6 +330,14 @@ class Manager:
         # and aborting a healthy collective over it would turn forensics
         # into an outage.
         self._evidence_peers: set = set()
+        # The id of the last quorum delivered (``_quorum_id`` is the one
+        # the process group is configured for, which lags on a failed
+        # configure), and why the last gate answered as it did: both for
+        # the ``commit_gate`` event.
+        self._delivered_quorum_id = -1
+        self._gate_cause: Dict[str, Any] = {}
+        # Largest signal seq the gate has journaled as ``failure_signal``.
+        self._signal_seq_journaled = 0
 
         ft_futures.start_watchdog()
 
@@ -624,6 +633,7 @@ class Manager:
                 self._quorum_since_gate += time.monotonic() - t_quorum0
 
         quorum_id_changed = result.quorum_id != self._quorum_id
+        self._delivered_quorum_id = result.quorum_id
         heal = result.heal and allow_heal
         # Mint the step-scoped trace id for this quorum generation. Every
         # replica derives the same value from the shared quorum result, so
@@ -1133,6 +1143,7 @@ class Manager:
             )
         log = get_event_log()
         if log is not None:
+            elapsed_s = time.monotonic() - t_gate0
             log.emit(
                 "commit_gate",
                 step=gated_step,
@@ -1140,7 +1151,16 @@ class Manager:
                 trace=self._trace_id or None,
                 committed=bool(answer),
                 num_participants=self.num_participants(),
-                elapsed_s=time.monotonic() - t_gate0,
+                elapsed_s=elapsed_s,
+                quorum_id=self._delivered_quorum_id,
+                participants=sorted(self._evidence_peers),
+                # Peak resident set of this process so far (Linux counts
+                # ru_maxrss in KiB).
+                rss_peak_bytes=resource.getrusage(
+                    resource.RUSAGE_SELF
+                ).ru_maxrss * 1024,
+                **self._gate_cause,
+                **self._read_liveness(gated_step),
             )
         metrics = get_metrics_logger()
         if metrics is not None:
@@ -1152,6 +1172,50 @@ class Manager:
                 replica_id=self._replica_id,
             )
         return answer
+
+    def _read_liveness(self, gated_step: int) -> Dict[str, Any]:
+        """Once a gate: reads, and (group rank 0) resets, the manager
+        server's view of its own heartbeats since the last gate and what
+        the lighthouse's acks said since. Returns the four ``hb_*`` fields
+        of ``commit_gate``; journals ``lh_evicted`` for an eviction of
+        this very group that the lighthouse has taken back, and
+        ``failure_signal`` for each signal not journaled yet — whether or
+        not the evidence watcher was armed when it came. Never raises: a
+        server that cannot answer (or one from before these fields) gives
+        a gate without them."""
+        try:
+            st = self._client.evidence_status(
+                timeout=1.0, reset=self._group_rank == 0
+            )
+        except Exception:  # noqa: BLE001 - observability must not fail a step
+            return {}
+        for ev in st.get("evicted") or []:
+            self._journal("lh_evicted", step=gated_step, **ev)
+        for sig in st.get("signals") or []:
+            seq = int(sig.get("seq", 0))
+            if seq <= self._signal_seq_journaled:
+                continue
+            self._signal_seq_journaled = seq
+            self._journal(
+                "failure_signal",
+                step=gated_step,
+                source=str(sig.get("source", "")),
+                subject=str(sig.get("replica_id", "")),
+                site="manager.gate",
+                origin=str(sig.get("site", "")),
+                seq=seq,
+                ts_ms=sig.get("ts_ms"),
+                detail=sig.get("detail"),
+            )
+        hb = st.get("hb")
+        if not isinstance(hb, dict):
+            return {}
+        return {
+            "hb_rounds": int(hb.get("rounds", 0)),
+            "hb_gap_max_ms": float(hb.get("gap_max_ms", 0.0)),
+            "hb_rtt_max_ms": float(hb.get("rtt_max_ms", 0.0)),
+            "hb_late": int(hb.get("late", 0)),
+        }
 
     @traced("torchft::manager::should_commit")
     def _should_commit_inner(self, timeout: Optional[float]) -> bool:
@@ -1188,6 +1252,7 @@ class Manager:
             err is None
             and self._participating_world_size >= self._min_replica_size
         )
+        rpc_err: Optional[Exception] = None
         t_gate_rpc0 = time.monotonic()
         try:
             answer = self._client.should_commit(
@@ -1200,6 +1265,10 @@ class Manager:
         except Exception as e:
             self._logger.exception(f"should_commit RPC failed: {e}")
             answer = False
+            rpc_err = e
+        self._gate_cause = _gate_cause(
+            answer, local_ok, err or rpc_err, self._healing
+        )
         # Time blocked in the commit-gate barrier RPC: waiting on the
         # slowest peer to arrive — the ledger's straggler_idle split.
         commit_wait_s = max(time.monotonic() - t_gate_rpc0, 0.0)
@@ -1541,6 +1610,38 @@ class Manager:
             self._manager_server.shutdown()
         if self._store_server is not None:
             self._store_server.shutdown()
+
+
+# Why a commit gate answered as it did: the closed set of
+# ``commit_gate.cause`` (docs/OBSERVABILITY.md, "Why a step was refused").
+GATE_CAUSES = (
+    "ok", "local_error", "peer_voted_no", "not_enough_replicas", "healing",
+)
+
+
+def _gate_cause(
+    answer: bool, local_vote: bool, err: Optional[BaseException],
+    healing: bool,
+) -> Dict[str, Any]:
+    """The ``local_vote`` and ``cause`` of one gate, and for a latched
+    error its class and first 200 characters. ``err`` is the error this
+    rank had latched when it voted, or the exception that ended the vote's
+    own RPC."""
+    out: Dict[str, Any] = {"local_vote": bool(local_vote)}
+    if answer:
+        out["cause"] = "ok"
+    elif err is not None:
+        # An error latched while this group was receiving or applying a
+        # peer's state is the heal's failure, not the step's.
+        out["cause"] = "healing" if healing and not local_vote else "local_error"
+        out["error_class"] = type(err).__name__
+        out["error"] = str(err)[:200]
+    elif not local_vote:
+        out["cause"] = "not_enough_replicas"
+    else:
+        out["cause"] = "peer_voted_no"
+    assert out["cause"] in GATE_CAUSES
+    return out
 
 
 class _EvidenceWatcher:

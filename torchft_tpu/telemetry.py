@@ -569,7 +569,10 @@ EVENT_KINDS: Dict[str, str] = {
     "quorum_start": "quorum attempt begins (async or sync path)",
     "quorum_ready": "quorum returned; carries replica set + max_step",
     "quorum_abort": "quorum failed or was aborted; collectives poisoned",
-    "commit_gate": "should_commit verdict for the step window",
+    "commit_gate": "should_commit verdict for the step window: committed, "
+                   "local_vote, cause (manager.GATE_CAUSES), quorum_id, "
+                   "participants, hb_rounds/hb_gap_max_ms/hb_rtt_max_ms/"
+                   "hb_late since the last gate, rss_peak_bytes",
     "goodput": "per-commit goodput/step-rate sample",
     # -- healing / checkpoint (manager.py, checkpointing/*) ------------
     "heal_start": "this replica starts healing from a live peer",
@@ -640,6 +643,10 @@ EVENT_KINDS: Dict[str, str] = {
                       "site, monotonic signal seq",
     "signal_overflow": "lighthouse signal ring dropped records (rise "
                        "edge, like anomaly_overflow)",
+    "lh_evicted": "the lighthouse's evidence plane had evicted THIS group "
+                  "and has heard from it again: its gap_ms, budget_ms, "
+                  "out_ms and what it erased, beside the sender's own gap "
+                  "and round trip for the heartbeat that came back",
     # -- goodput ledger (manager.py, tools/goodput_report.py) -----------
     "goodput_window": "one accounted wall-clock window: per-kind second "
                       "splits (BADPUT_KINDS) that tile [t0, t1] exactly",
