@@ -22,7 +22,7 @@ from benchmark import conftest as _outgrown
 MODULES = (
     "test_ar_pack_fresh_bytes", "test_arch", "test_flops", "test_liveness_metrics",
     "test_reference", "test_run_end_to_end", "test_span_metrics",
-    "test_trace_reduce", "test_wire_fresh_bytes",
+    "test_trace_reduce", "test_wire_fresh_bytes", "test_wire_schedule_metrics",
 )
 SUBPROCESS_RUNS = {
     "test_tiny_raw_cell_end_to_end_metrics",
@@ -30,10 +30,11 @@ SUBPROCESS_RUNS = {
     "test_a_cell_of_the_repos_table_needs_a_tpu",
 }
 _TESTS = [importlib.import_module(f"benchmark.tests.{m}") for m in MODULES]
-# Three tests assert that the table is what it was when they were written
-# (benchmark/conftest.py says which two, test_ar_pack_fresh_bytes.py files
-# the third there as it is imported, each with the one-line edit a
-# benchmark PR owes it); they are restated below for the table as it is.
+# Four tests assert that the table is what it was when they were written
+# (benchmark/conftest.py says which two, test_ar_pack_fresh_bytes.py and
+# test_wire_schedule_metrics.py file the other two there as they are
+# imported, each with the one-line edit a benchmark PR owes it); they are
+# restated below for the table as it is.
 OUTGROWN = set(_outgrown.OUTGROWN)
 
 
@@ -83,20 +84,43 @@ def test_every_span_metric_is_an_entry_of_the_table_for_its_path_only():
     from benchmark.tests import test_span_metrics
 
     new = "ar_pack_fresh_bytes_step"
+    schedule = {"wire_start_ms", "wire_starved_ms"}  # the wire's, so ft4's alone
     ten = {m.__name__.rsplit(".", 1)[1] for m in test_span_metrics.ALL}
     entries = {m["name"]: m for m in cells.load_json(REPO_TABLE)["per_layer"]}
-    for name in ten | {new}:
+    for name in ten | {new} | schedule:
         assert entries[name]["layer"] == "replica-axis allreduce"
         assert entries[name]["moves"] == "tok_s_chip"
     ft1 = {m["name"] for m in cells.load_cell("mistral-ft1").per_layer}
     ft4 = {m["name"] for m in cells.load_cell("mistral-ft4").per_layer}
     assert {n for n in ft1 | ft4 if n.startswith(("ar_", "wire_"))} - {
-        "wire_ms", "wire_bytes_step", "wire_fresh_bytes_step", new} == ten
+        "wire_ms", "wire_bytes_step", "wire_fresh_bytes_step", new} - schedule == ten
     assert "ar_pull_ms" not in ft4 and "wire_busy_ms" not in ft1
     assert new in ft1 - ft4
+    assert schedule <= ft4 - ft1
     for cell in ("mistral-raw", "internlm2-raw", "olmoe-raw"):
         raw = {m["name"] for m in cells.load_cell(cell).per_layer}
-        assert not (ten | {new}) & raw
+        assert not (ten | {new} | schedule) & raw
+
+
+def test_the_five_liveness_metrics_are_entries_for_the_ft_cells_only():
+    """``test_liveness_metrics.py``'s table test, with the five looked up
+    by name: entries added since follow them in the list."""
+    from benchmark.tests import test_liveness_metrics
+
+    names = list(test_liveness_metrics.NAMES)
+    table = cells.load_json(REPO_TABLE)["per_layer"]
+    assert [m["name"] for m in table if m["name"] in names] == names
+    for e in (m for m in table if m["name"] in names):
+        assert (e["source"], e["layer"], e["moves"], e["better"], e["workloads"]) == (
+            "program_counter", "control plane", "tok_s_chip", "lower",
+            ["mistral-ft1", "mistral-ft4"])
+        assert set(e) == {"name", "unit", "better", "source", "layer", "moves",
+                          "workloads"}
+        assert os.path.isfile(os.path.join(cells.HERE, "metrics", e["name"] + ".py"))
+    for cell in ("mistral-ft1", "mistral-ft4"):
+        assert set(names) <= {m["name"] for m in cells.load_cell(cell).per_layer}
+    for cell in ("mistral-raw", "internlm2-raw", "olmoe-raw"):
+        assert not set(names) & {m["name"] for m in cells.load_cell(cell).per_layer}
 
 
 # -- the architecture `olmoe` and its configuration ----------------------

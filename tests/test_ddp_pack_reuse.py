@@ -22,7 +22,8 @@ sys.path.insert(0, REPO)
 
 from tests.test_manager import make_manager, make_quorum_result  # noqa: E402
 from torchft_tpu import telemetry  # noqa: E402
-from torchft_tpu.ddp import DistributedDataParallel  # noqa: E402
+from torchft_tpu.collectives import bucketize  # noqa: E402
+from torchft_tpu.ddp import DistributedDataParallel, issue_order  # noqa: E402
 from torchft_tpu.process_group import (  # noqa: E402
     ProcessGroupDummy,
     ProcessGroupSocket,
@@ -196,7 +197,11 @@ def test_from_the_second_call_on_every_pack_reuses_its_buffer(cap_mb, n_buckets)
     try:
         first, _, packs = _step(m, ddp, _mixed_tree(0, 0))
         assert len(packs) == n_buckets
-        assert [p["bucket"] for p in packs] == list(range(n_buckets))
+        # every layout bucket once, fewest elements first
+        leaves = _leaves(_mixed_tree(0, 0))
+        assert [p["bucket"] for p in packs] == issue_order(
+            leaves, bucketize(leaves, ddp._bucket_cap))
+        assert sorted(p["bucket"] for p in packs) == list(range(n_buckets))
         assert _all_fresh(packs)
         assert sum(p["nbytes"] for p in packs) == 4 * 567 + 2 * 140
         first = _leaves(first)

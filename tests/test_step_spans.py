@@ -278,20 +278,21 @@ def test_host_path_spans_and_hand_worked_bytes(journal):
     assert pull[ATTRS] == {"nbytes": 2000}  # the two device leaves
     assert len(_by_name(spans, "torchft::ddp::grads_wait")) == 1
     packs = _by_name(spans, "torchft::ddp::pack")
-    # a wrapper's first call sizes its bucket buffers: every byte fresh
+    # a wrapper's first call sizes its bucket buffers: every byte fresh.
+    # Issued smallest first (ddp.issue_order), each under its layout index.
     assert [p[ATTRS] for p in packs] == [
-        {"bucket": 0, "nbytes": 1200, "fresh_bytes": 1200, "reused_bytes": 0},
-        {"bucket": 1, "nbytes": 800, "fresh_bytes": 800, "reused_bytes": 0},
         {"bucket": 2, "nbytes": 400, "fresh_bytes": 400, "reused_bytes": 0},
+        {"bucket": 1, "nbytes": 800, "fresh_bytes": 800, "reused_bytes": 0},
+        {"bucket": 0, "nbytes": 1200, "fresh_bytes": 1200, "reused_bytes": 0},
     ]
     copies = _by_name(spans, "torchft::manager::host_copy")
     # the concatenated bucket is writable: to_mutable copies nothing
     assert [c[ATTRS]["copied_bytes"] for c in copies] == [0, 0, 0]
-    assert [c[ATTRS]["nbytes"] for c in copies] == [1200, 800, 400]
+    assert [c[ATTRS]["nbytes"] for c in copies] == [400, 800, 1200]
     scales = _by_name(spans, "torchft::manager::allreduce_scale")
-    assert [s[ATTRS]["nbytes"] for s in scales] == [1200, 800, 400]
+    assert [s[ATTRS]["nbytes"] for s in scales] == [400, 800, 1200]
     assert [u[ATTRS]["bucket"] for u in _by_name(spans, "torchft::ddp::unpack")] == [
-        0, 1, 2
+        2, 1, 0
     ]
     for name in ("torchft::manager::allreduce_wait", "torchft::manager::quorum_wait"):
         assert len(_by_name(spans, name)) == 3

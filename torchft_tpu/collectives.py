@@ -44,7 +44,26 @@ class _WireOrder:
     reach the wire in different orders on two replicas would exchange each
     other's payloads (seen on the chip: 16 gradient buckets of a 125M
     model, device-quantized on one replica and host-quantized on the
-    other, failed every step with reshape errors)."""
+    other, failed every step with reshape errors).
+
+    Who relies on it: wire order = issue order, and the callers' issue
+    order is the same on every replica of a quorum. For
+    ``DistributedDataParallel`` that is ``ddp.issue_order`` of the bucket
+    layout, ascending size, on the device and the host branch alike; a
+    quorum that mixes replicas from before and after that order would
+    pair different buckets and is not supported.
+
+    A second instance on the same group (``_quant_pull_order``) gives the
+    device-to-host pulls of the device path their turns, in the same
+    order: bucket k+1 pulls while bucket k is on the wire and no two
+    pulls share the one path off the device (ten at once moved 0.33 GB/s
+    together and held the wire back 1.4 s a step on the chip). It is
+    taken only by a collective that has device chunks to pull, hangs on
+    the group for the same reason the wire's does, and is let go with the
+    wire scratch (``ProcessGroup._drop_wire_scratch``): the collectives of
+    an aborted step pass their pull turns on among themselves, and the
+    next step's pulls do not queue behind them (its wire turns do, as
+    they always have: this instance stays)."""
 
     def __init__(self) -> None:
         self._cv = threading.Condition()
@@ -160,8 +179,24 @@ def _wire_scratch(pg: ProcessGroup) -> _WireScratch:
         return pg.__dict__.setdefault("_quant_wire_scratch", _WireScratch())
 
 
+def _turn(order: _WireOrder, ticket: int, wait_span: str, bucket: "int | None"):
+    """``with held():`` waits for ``ticket``'s turn in ``order`` under a
+    span called ``wait_span`` and passes the turn on at the end."""
+
+    @contextlib.contextmanager
+    def held():
+        with trace_span(wait_span, bucket=bucket):
+            order.wait(ticket)
+        try:
+            yield
+        finally:
+            order.done(ticket)
+
+    return held
+
+
 def _spawn_collective(
-    pg: ProcessGroup, fn, bucket: "int | None" = None
+    pg: ProcessGroup, fn, bucket: "int | None" = None, pulls: bool = False
 ) -> "concurrent.futures.Future":
     """One daemon thread per in-flight quantized collective. A bounded pool
     would deadlock when several ranks live in one process (tests, parameter
@@ -173,35 +208,48 @@ def _spawn_collective(
     thread). The thread has no span stack of its own: it hangs its spans
     under the span open on the caller's thread now (for DDP a descendant
     of the ``allreduce_grads`` root), and the time it is blocked for its
-    turn is the ``wire_turn_wait`` span of ``bucket``."""
+    turn is the ``wire_turn_wait`` span of ``bucket``.
+
+    With ``pulls`` (a collective that has device chunks to bring to the
+    host) it is ``fn(wire, pull)``, and ``with pull():`` is its turn on the
+    device-to-host path, in the same issue order (``pull_turn_wait``)."""
     import concurrent.futures
 
     with _wire_order_lock:
         order = pg.__dict__.setdefault("_quant_wire_order", _WireOrder())
-    ticket = order.take()
+        pull_order = (
+            pg.__dict__.setdefault("_quant_pull_order", _WireOrder())
+            if pulls
+            else None
+        )
+    # Tickets now, on the caller's thread. ``turns`` is in the order the
+    # collective takes them, which is the order the ``finally`` below
+    # passes on those it never took.
+    wire_turn = (order, order.take())
+    turns = [wire_turn]
+    args = [_turn(*wire_turn, "torchft::collectives::wire_turn_wait", bucket)]
+    if pull_order is not None:
+        pull_turn = (pull_order, pull_order.take())
+        turns.insert(0, pull_turn)
+        args.append(
+            _turn(*pull_turn, "torchft::collectives::pull_turn_wait", bucket)
+        )
     parent = current_span()
     fut: concurrent.futures.Future = concurrent.futures.Future()
-
-    @contextlib.contextmanager
-    def wire():
-        with trace_span("torchft::collectives::wire_turn_wait", bucket=bucket):
-            order.wait(ticket)
-        try:
-            yield
-        finally:
-            order.done(ticket)
 
     def run() -> None:
         try:
             with span_parent(parent):
                 if fut.set_running_or_notify_cancel():
-                    fut.set_result(fn(wire))
+                    fut.set_result(fn(*args))
         except BaseException as e:  # noqa: BLE001 - delivered via the future
             fut.set_exception(e)
         finally:
-            # Died before the wire (or cancelled): still pass the turn on.
-            order.wait(ticket)
-            order.done(ticket)
+            # Died before a turn of its own (or cancelled): still pass it
+            # on, the pull's before the wire's.
+            for o, t in turns:
+                o.wait(t)
+                o.done(t)
 
     threading.Thread(target=run, daemon=True, name="quant-collective").start()
     return fut
@@ -537,8 +585,10 @@ def allreduce_quantized_jax(
             # across the multi-second wire pipeline too.
             flat = None
 
-    def run(wire) -> List["jax.Array"]:
-        with trace_span("torchft::collectives::quantize_pull", **tags):
+    def run(wire, pull=contextlib.nullcontext) -> List["jax.Array"]:
+        # Device chunks are pulled one bucket at a time, in issue order;
+        # the host quantizer pulls nothing and takes no turn.
+        with pull(), trace_span("torchft::collectives::quantize_pull", **tags):
             if host_quant:
                 flat_host = np.asarray(flat, dtype=np.float32)
                 n = flat_host.size
@@ -601,7 +651,9 @@ def allreduce_quantized_jax(
             # price of the overlap.
         return outs
 
-    return FutureWork(_spawn_collective(pg, run, tags["bucket"]))
+    return FutureWork(
+        _spawn_collective(pg, run, tags["bucket"], pulls=q_chunks is not None)
+    )
 
 
 def reduce_scatter_quantized(

@@ -11,13 +11,29 @@ in flight — the comm-hook overlap analog).
 
 from __future__ import annotations
 
-from typing import Any, List, Optional, Tuple
+from typing import Any, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 
 from torchft_tpu.manager import Manager
-from torchft_tpu.telemetry import DDP_ROOT_SPAN, trace_span
+from torchft_tpu.telemetry import DDP_ROOT_SPAN, name_next_bucket, trace_span
+
+
+def issue_order(arrays: Sequence[Any], buckets: List[List[int]]) -> List[int]:
+    """The order in which a step's buckets are handed to
+    ``Manager.allreduce``: smallest first, by element count, ties in
+    layout order. ``buckets`` is ``bucketize``'s layout over ``arrays``;
+    the answer is a permutation of its indices and depends on nothing
+    else, so jax leaves and their numpy copies give the same one.
+
+    Each bucket is pulled to the host and then put on the wire, one
+    bucket at a time in either stage, and the pull is the shorter stage
+    for every bucket: Johnson's rule for such a two-stage line puts the
+    shortest first stage first, and the wire then starts after the
+    smallest pull instead of the largest."""
+    sizes = [sum(arrays[i].size for i in idx_list) for idx_list in buckets]
+    return sorted(range(len(buckets)), key=lambda b: (sizes[b], b))
 
 
 class _PackBuffers:
@@ -85,6 +101,19 @@ class DistributedDataParallel:
     step; and the compensated copy under error feedback), ``reused_bytes``
     what it wrote into memory it already had. Steady state is
     ``fresh_bytes`` 0 and ``reused_bytes`` = ``nbytes``.
+
+    **The order of a step's collectives** is :func:`issue_order` of the
+    bucket layout, ascending size, in both branches below, and it is part
+    of the wire protocol: the process group pairs the ops of two replicas
+    by a sequence number taken at the call, and the quantized collectives
+    go to the wire in issue order (``collectives._WireOrder``), so every
+    replica of one quorum must issue the same buckets in the same order,
+    a device-path replica and a host-path one alike. Every replica of one
+    version does; a quorum that mixes this version with one that issued
+    in layout order would exchange each other's buckets and is not
+    supported. The layout itself is ``bucketize``'s and a bucket keeps
+    its layout index as its name: ``bucket=`` on its spans, the key of
+    its error-feedback residual, its slot among the kept buffers.
     """
 
     def __init__(
@@ -188,19 +217,19 @@ class DistributedDataParallel:
             # against a peer's per-bucket ones would desync the wire.
             # Each bucket's leaves go down as a list: the quantized jax
             # collective concatenates them on device, matching the host
-            # path's flat bucket payload byte-for-byte.
+            # path's flat bucket payload byte-for-byte. The order of the
+            # calls is the host path's too (issue_order).
             buckets = self._bucketize(leaves)
-            works = [
-                (
-                    self._manager.allreduce(
-                        [leaves[i] for i in idx_list],
-                        should_quantize=True,
-                        quantize_bits=quantize_bits,
-                    ),
-                    idx_list,
+            works = []
+            for b_idx in issue_order(leaves, buckets):
+                idx_list = buckets[b_idx]
+                name_next_bucket(b_idx)
+                work = self._manager.allreduce(
+                    [leaves[i] for i in idx_list],
+                    should_quantize=True,
+                    quantize_bits=quantize_bits,
                 )
-                for idx_list in buckets
-            ]
+                works.append((work, idx_list))
             out: List[Optional[Any]] = [None] * len(leaves)
             for work, idx_list in works:
                 reduced = work.wait()
@@ -248,8 +277,9 @@ class DistributedDataParallel:
                 for idx_list in buckets
             )
         )
-        works: List[Tuple[Any, np.ndarray, List[int]]] = []
-        for b_idx, idx_list in enumerate(buckets):
+        works: List[Tuple[int, Any, List[int]]] = []
+        for b_idx in issue_order(host, buckets):
+            idx_list = buckets[b_idx]
             on_quantized = None
             with trace_span("torchft::ddp::pack", bucket=b_idx) as pack:
                 flat = flats[b_idx]
@@ -267,16 +297,17 @@ class DistributedDataParallel:
                 pack.attrs.update(
                     nbytes=flat.nbytes, fresh_bytes=fresh, reused_bytes=reused
                 )
+            name_next_bucket(b_idx)
             work = self._manager.allreduce(
                 flat,
                 should_quantize=should_quantize,
                 quantize_bits=quantize_bits,
                 on_local_quantized=on_quantized,
             )
-            works.append((work, flat, idx_list))
+            works.append((b_idx, work, idx_list))
 
         out: List[Optional[np.ndarray]] = [None] * len(host)
-        for b_idx, (work, flat, idx_list) in enumerate(works):
+        for b_idx, work, idx_list in works:
             (reduced,) = work.wait()
             with trace_span("torchft::ddp::unpack", bucket=b_idx):
                 offset = 0

@@ -215,8 +215,12 @@ class ProcessGroup:
         this group between steps (``collectives._WireScratch``). Called
         wherever a group tears its connections down — reconfigure, abort,
         shutdown — so a dead or resized group pins no memory; a collective
-        still running keeps its own reference until it ends."""
+        still running keeps its own reference until it ends. The turns of
+        the device-to-host pulls go with it: the collectives of the torn
+        step pass theirs on among themselves, and the pulls of what is
+        issued next do not queue behind one that may never end."""
         self.__dict__.pop("_quant_wire_scratch", None)
+        self.__dict__.pop("_quant_pull_order", None)
 
     def errored(self) -> Optional[Exception]:
         """Latched async error, if any (reference: process_group.py:361-368)."""

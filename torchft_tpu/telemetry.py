@@ -55,6 +55,7 @@ __all__ = [
     "current_span",
     "in_span",
     "next_bucket",
+    "name_next_bucket",
     "span_parent",
     "drain_spans",
     "span_stats",
@@ -360,6 +361,16 @@ def next_bucket() -> Optional[int]:
     top = span.top
     top._issued += 1
     return top._issued - 1
+
+
+def name_next_bucket(bucket: int) -> None:
+    """Makes ``bucket`` the next answer of :func:`next_bucket` on this
+    thread (and ``bucket + 1`` the one after): a caller that issues its
+    buckets in another order than it numbers them (DDP: smallest first)
+    keeps each one's own number on its collective's spans."""
+    span = current_span()
+    if span is not None:
+        span.top._issued = bucket
 
 
 @contextlib.contextmanager
