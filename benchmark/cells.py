@@ -6,6 +6,18 @@ mix, a cell, a per-layer metric or an architecture as new files plus an
 entry in BENCHMARK.json; nothing here, in run.py or in worker.py names
 one.
 
+The end-to-end metrics of every cell are run.py's (``join``), from the
+groups' result files: ``setup_s``, the parent's start to the first
+step of the earliest group's window; ``tok_s_chip``, the committed
+tokens of all groups from the first window's start to the last one's
+end, over the cell's chips; ``peak_hbm_gib``, the SMALLEST over the
+cell's groups of the group's largest ``peak_bytes_in_use``. The smallest,
+because an undonated step loop races the next gradient against the
+update and a group's process-lifetime peak lands on one of three levels
+by no rule (``join`` has the account); the largest over four groups
+flipped between them and refused PRs that ran no code of the cell. The
+largest and every group's reading stay on the line under ``device``.
+
 An architecture is a directory of three files, ``arch/<name>/`` beside
 the table or else under ``benchmark/``, named by the configuration
 file's ``"arch"`` key (``DEFAULT_ARCH`` where the file has none):

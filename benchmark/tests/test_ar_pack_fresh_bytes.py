@@ -19,23 +19,10 @@ import os
 import pytest
 
 from benchmark import cells
-from benchmark import conftest as outgrown
 from benchmark.tests import test_span_metrics as recorded
 
 ar_pack_fresh_bytes_step = importlib.import_module(
     "benchmark.metrics.ar_pack_fresh_bytes_step")
-
-# A third test that asserts the table as it was: this entry's name starts
-# with ``ar_`` and is not one of test_span_metrics.py's ten. The PR that
-# adds the entry may not edit that file nor benchmark/conftest.py, so the
-# reason is filed here, beside the entry's own test, for the runs that
-# collect both; tests/test_benchmark_harness.py restates the test.
-outgrown.OUTGROWN.setdefault(
-    "test_every_new_metric_is_an_entry_of_the_table",
-    "asserts that the ar_* and wire_* entries of the FT cells are its own ten "
-    "and three it names; ar_pack_fresh_bytes_step is a fourth (edit: add the "
-    "name to the set it subtracts)",
-)
 
 JOURNAL = os.path.join(os.path.dirname(__file__), "data", "ar_pack_journal.jsonl")
 PACK = "torchft::ddp::pack"

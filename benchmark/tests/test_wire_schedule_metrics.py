@@ -26,34 +26,11 @@ import os
 import pytest
 
 from benchmark import cells
-from benchmark import conftest as outgrown
 from benchmark.tests import test_span_metrics as recorded
 
 wire_start_ms = importlib.import_module("benchmark.metrics.wire_start_ms")
 wire_starved_ms = importlib.import_module("benchmark.metrics.wire_starved_ms")
 BOTH = (wire_start_ms, wire_starved_ms)
-
-# test_span_metrics.py asserts that the wire_* entries of the FT cells are
-# its own and three it names; these two are more. The PR that adds them may
-# not edit that file nor benchmark/conftest.py, so, as
-# test_ar_pack_fresh_bytes.py does, the reason is filed here for the runs
-# that collect both; tests/test_benchmark_harness.py restates the test.
-outgrown.OUTGROWN.setdefault(
-    "test_every_new_metric_is_an_entry_of_the_table",
-    "asserts that the ar_* and wire_* entries of the FT cells are its own ten "
-    "and three it names; ar_pack_fresh_bytes_step, wire_start_ms and "
-    "wire_starved_ms are more (edit: add the names to the set it subtracts)",
-)
-
-# test_liveness_metrics.py asserts that its five entries are the table's
-# LAST five; new entries go at the end of the list, so these two follow
-# them. Filed and restated the same way.
-outgrown.OUTGROWN.setdefault(
-    "test_the_five_are_entries_of_the_table_with_files_for_the_ft_cells_only",
-    'asserts that `table["per_layer"][-5:]` are the five liveness metrics; '
-    "wire_start_ms and wire_starved_ms were appended after them (edit: look "
-    "the five up by name)",
-)
 
 JOURNAL = os.path.join(os.path.dirname(__file__), "data", "wire_schedule_journal.jsonl")
 WIRE = "torchft::collectives::wire"

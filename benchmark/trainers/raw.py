@@ -43,9 +43,12 @@ class Trainer:
         with ctx.span("step"):
             self.state, metrics = self.train_step(self.state, batch)
             ctx.block(self.state)
-        # The loss comes out of the fused program: reading it waits for
-        # the whole step, optimizer included.
-        return StepOut(True, float(metrics["loss"]), ctx.tokens_per_step)
+        # The metrics come out of the fused program: reading them waits
+        # for the whole step, optimizer included. One transfer for all of
+        # them; what is not the loss goes to the worker's records under
+        # the program's own names, for the per-layer metrics that read it.
+        counters = {k: float(v) for k, v in jax.device_get(metrics).items()}
+        return StepOut(True, counters.pop("loss"), ctx.tokens_per_step, counters)
 
     def sync(self) -> None:
         jax.block_until_ready(self.state)

@@ -29,7 +29,7 @@ import sys
 import time
 from typing import Any, Callable, Dict, Iterator, List, Optional
 
-from benchmark import cells
+from benchmark import cells, gate_readers
 
 SPAN_PREFIX = "bench::"
 # The reference check's sample: one sequence of this many tokens (or the
@@ -43,6 +43,9 @@ class StepOut:
     committed: bool
     loss: float  # read on the host: the step's device work is done
     tokens: int  # training tokens this step contributed if committed
+    # What else the step's program counted (its ``metrics`` but the loss),
+    # as host floats by the program's own names; {} where it counts nothing.
+    counters: Dict[str, float] = dataclasses.field(default_factory=dict)
 
 
 class CompileLog:
@@ -233,6 +236,8 @@ def reference_check(ctx: Ctx) -> Dict[str, Any]:
         "loss_rel_diff": loss_rel,
         "grad_rel_l2_worst": float(worst),
         "grad_rel_l2_worst_leaf": jax.tree_util.keystr(worst_path),
+        "loss_rel_tol": reference.LOSS_REL_TOL,
+        "grad_rel_l2_tol": reference.GRAD_REL_L2_TOL,
         "ok": bool(
             loss_rel <= reference.LOSS_REL_TOL
             and float(worst) <= reference.GRAD_REL_L2_TOL
@@ -392,6 +397,7 @@ def main() -> int:
             t1 = time.time()
             records.append({"i": i, "t0": t0, "t1": t1, "loss": out.loss,
                             "committed": out.committed, "tokens": out.tokens,
+                            "counters": out.counters,
                             "spans": ctx.step_spans, "traced": tracing})
             if tracing and i == trace_first + trace_n - 1:
                 jax.profiler.stop_trace()
@@ -442,6 +448,12 @@ def main() -> int:
     say(f"window: {len(records)} steps in {window_s:.2f}s, "
         f"{tokens / window_s:.0f} tok/s, peak {peak / 2**30:.2f} GiB, "
         f"step median {statistics.median(result['step_s']):.3f}s")
+    journal: List[Dict[str, Any]] = []
+    if args.trace or result["window"]["failed"]:
+        journal = read_jsonl(os.environ.get("TORCHFT_JOURNAL_FILE", ""))
+    if result["window"]["failed"]:
+        # A run that lost a step says why: run.py prints these.
+        result["lost_steps"] = gate_readers.explain(journal)
 
     if args.trace:
         setup = {
@@ -458,8 +470,7 @@ def main() -> int:
                                               "*", "*.xplane.pb")))
         trace = trace_reduce.reduce(files[-1], SPAN_PREFIX) if files else None
         window_events = [
-            e for e in read_jsonl(os.environ.get("TORCHFT_JOURNAL_FILE", ""))
-            if records[0]["t0"] <= e.get("ts", 0.0) <= t_end
+            e for e in journal if records[0]["t0"] <= e.get("ts", 0.0) <= t_end
         ]
         run = {
             "cell": cell,
