@@ -209,7 +209,8 @@ def _table_with(tmp_path, config):
     for c in table["configs"]:
         c["file"] = os.path.join(cells.ROOT, c["file"])
     table["traffic_dir"] = os.path.join(cells.HERE, "traffic")
-    table["configs"][-1]["file"] = str(tmp_path / "config.json")
+    olmoe = next(c for c in table["configs"] if c["name"] == "olmoe-1b-7b-l1")
+    olmoe["file"] = str(tmp_path / "config.json")
     (tmp_path / "config.json").write_text(json.dumps(config))
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(table))
     return str(tmp_path / "BENCHMARK.json")

@@ -12,6 +12,7 @@ from torchft_tpu.models.resnet import (  # noqa: F401
     resnet50,
     resnet101,
 )
+from torchft_tpu.models.mamba2 import Mamba2Config  # noqa: F401
 from torchft_tpu.models.llama import (  # noqa: F401
     LlamaConfig,
     Transformer,
@@ -19,5 +20,7 @@ from torchft_tpu.models.llama import (  # noqa: F401
     llama_debug,
     llama_moe_debug,
     llama_small,
+    nemotron3_nano,
+    nemotron_h_debug,
     olmoe_1b_7b,
 )

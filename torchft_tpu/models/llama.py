@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from torchft_tpu.models.mamba2 import Mamba2Config, Mamba2Mixer
+
 Dtype = Any
 
 logger = logging.getLogger(__name__)
@@ -102,6 +104,34 @@ class LlamaConfig:
     # whole key projection, before the split into heads and before RoPE
     # (OLMoE's form; not a per-head norm).
     qk_norm: bool = False
+    # False: attention without rotary embeddings (a hybrid whose
+    # state-space layers carry the order, Nemotron-H).
+    rope: bool = True
+    # A stack of unlike layers, one character a layer, each layer ONE mixer
+    # between a pre-norm and the residual add: 'M' a Mamba-2 mixer
+    # (``mamba``), 'E' the expert layer, '*' attention. None: ``num_layers``
+    # scanned blocks of attention + MLP.
+    layer_pattern: Optional[str] = None
+    mamba: Optional[Mamba2Config] = None
+    # The dropless expert layer's variants (DeepSeek-V3's router, Nemotron's
+    # experts). 'sigmoid': the k experts are the top-k of sigmoid(logits) +
+    # a selection bias (a parameter that gets no gradient), the gates those
+    # sigmoids without the bias, divided by their sum and times
+    # ``routed_scaling``. 'relu2': down(relu(up(x))^2), no gate
+    # matrix. ``shared_expert_size`` > 0: one such expert of that width
+    # every token passes through, added to the routed result.
+    router_score: str = "softmax"
+    routed_scaling: float = 1.0
+    expert_act: str = "swiglu"
+    shared_expert_size: int = 0
+    # (first, count): this layer HOLDS experts first .. first+count-1 of
+    # ``num_experts`` (a chip's share under expert parallelism). It routes
+    # over all of them, computes the assignments that land on its own, and
+    # returns that partial sum (plus the shared expert). None: all.
+    experts_held: Optional[tuple] = None
+    # Initial scale of the projections that write the residual stream
+    # (Nemotron-H's rescale_prenorm_residual: 1/sqrt(layers)).
+    residual_init_scale: float = 1.0
     # Bound by parallel.train when attn_impl is 'ring' or 'ulysses'.
     attn_fn: Optional[Callable[..., jax.Array]] = None
 
@@ -152,6 +182,69 @@ def olmoe_1b_7b(**overrides: Any) -> LlamaConfig:
         router_aux_coef=0.01,
         router_z_coef=0.001,
         qk_norm=True,
+    )
+    return dataclasses.replace(cfg, **overrides)
+
+
+def nemotron3_nano(**overrides: Any) -> LlamaConfig:
+    """NVIDIA-Nemotron-3-Nano-30B-A3B (config.json of model_type
+    ``nemotron_h``; Nemotron-H arXiv:2504.03624, Mamba-2 arXiv:2405.21060)
+    at its published sizes: 52 layers of ONE mixer each (23 Mamba-2, 23
+    expert layers of 128 relu^2 experts with a sigmoid router, top-6 and
+    a shared expert, 6 rope-free GQA attentions). 31.6B parameters:
+    override ``layer_pattern``, ``experts_held`` and ``vocab_size`` for
+    what one chip holds (docs/DESIGN.md, "A stack of unlike layers")."""
+    pattern = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+    cfg = LlamaConfig(
+        vocab_size=131072,
+        hidden_size=2688,
+        intermediate_size=1856,
+        num_layers=len(pattern),
+        num_heads=32,
+        num_kv_heads=2,
+        head_dim=128,
+        max_seq_len=262144,
+        norm_eps=1e-5,
+        rope=False,
+        layer_pattern=pattern,
+        mamba=Mamba2Config(),
+        num_experts=128,
+        num_experts_per_tok=6,
+        expert_capacity_factor=None,
+        router_score="sigmoid",
+        routed_scaling=2.5,
+        expert_act="relu2",
+        shared_expert_size=3712,
+        router_aux_coef=1e-4,
+        router_z_coef=0.0,
+        residual_init_scale=len(pattern) ** -0.5,
+    )
+    return dataclasses.replace(cfg, **overrides)
+
+
+def nemotron_h_debug(**overrides: Any) -> LlamaConfig:
+    """Tiny hybrid (pattern MEMEM*EME, 16 experts of which 4 are held) for
+    tests and ``train_hsdp.py --model nemotron_h``."""
+    pattern = "MEMEM*EME"
+    cfg = nemotron3_nano(
+        vocab_size=256,
+        hidden_size=64,
+        intermediate_size=48,
+        num_layers=len(pattern),
+        layer_pattern=pattern,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        max_seq_len=128,
+        mamba=Mamba2Config(
+            num_heads=8, head_dim=16, n_groups=2, state_size=16, chunk_size=16
+        ),
+        num_experts=16,
+        num_experts_per_tok=3,
+        experts_held=(0, 4),
+        shared_expert_size=96,
+        residual_init_scale=len(pattern) ** -0.5,
+        remat=False,
     )
     return dataclasses.replace(cfg, **overrides)
 
@@ -261,8 +354,9 @@ class Attention(nn.Module):
                 cfg.norm_eps, cfg.param_dtype, name=name
             )(t.reshape(*t.shape[:2], -1)).reshape(t.shape)
             q, k = whole(q, "q_norm"), whole(k, "k_norm")
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        if cfg.rope:
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
         if cfg.attn_impl in ("ring", "ulysses"):
             assert cfg.attn_fn is not None, (
                 f"{cfg.attn_impl} attention needs cfg.attn_fn"
@@ -359,6 +453,80 @@ def _permute_rows_bwd(inv, g):
 _permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
 
 
+@jax.custom_vjp
+def _held_rows(x: jax.Array, token: jax.Array, slot: jax.Array) -> jax.Array:
+    """Row ``token[r]`` of ``x`` [T, H] for each of the R rows of the held
+    dispatch's buffer. ``slot`` [T, K] is each assignment's row in the
+    buffer, R for one that has none. The transpose gathers by ``slot``
+    from the row gradients with a zero row appended and sums a token's K
+    copies: a gather again, where autodiff's own is a scatter-add."""
+    return x[token]
+
+
+def _held_rows_fwd(x, token, slot):
+    return x[token], slot
+
+
+def _gather_sum(rows, slot, weights=None):
+    """sum_k weights[:, k] * rows_with_a_zero_row_appended[slot[:, k]] in
+    float32, one [T, H] gather a k: the [T, K, H] tensor of all of them at
+    once is K times a layer's activations, most of it the zero row."""
+    padded = jnp.concatenate([rows, jnp.zeros((1, rows.shape[-1]), rows.dtype)])
+    total = jnp.zeros((slot.shape[0], rows.shape[-1]), jnp.float32)
+    for k in range(slot.shape[1]):
+        part = padded[slot[:, k]].astype(jnp.float32)
+        total = total + (part if weights is None else part * weights[:, k, None])
+    return total
+
+
+def _held_rows_bwd(slot, g):
+    return _gather_sum(g, slot).astype(g.dtype), None, None
+
+
+_held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
+
+
+@jax.custom_vjp
+def _combine_held(ys, gates, slot, rows, valid):
+    """out[t] = sum_k gates[t, k] * ys[slot[t, k]] in float32, a row past
+    the buffer (``slot`` = R) counting as zero. ``rows`` [R] is each
+    buffer row's assignment (token * K + k), ``valid`` [R] whether the row
+    holds one. Both transposes are written from the buffer's side: R-row
+    gathers and a gather of scalars, no scatter-add."""
+    return _gather_sum(ys, slot, gates)
+
+
+def _combine_held_fwd(ys, gates, slot, rows, valid):
+    return _gather_sum(ys, slot, gates), (ys, gates, slot, rows, valid)
+
+
+def _combine_held_bwd(res, g):
+    ys, gates, slot, rows, valid = res
+    g_rows = g[rows // gates.shape[1]]  # [R, H] float32
+    row_gate = jnp.where(valid, gates.reshape(-1)[rows], 0.0)
+    d_ys = (g_rows * row_gate[:, None]).astype(ys.dtype)
+    dots = jnp.where(valid, jnp.sum(ys.astype(jnp.float32) * g_rows, axis=-1), 0.0)
+    d_gates = jnp.concatenate([dots, jnp.zeros((1,), dots.dtype)])[slot]
+    return d_ys, d_gates.astype(gates.dtype), None, None, None
+
+
+_combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
+
+
+# The held dispatch's static row buffer, as a multiple of the rows a
+# uniform router sends to the experts held (MoEMLP._sorted_held says why 4).
+HELD_ROW_FACTOR = 4.0
+
+
+def held_buffer_rows(cfg: LlamaConfig, tokens: int) -> int:
+    """Rows of the held dispatch's static buffer for ``tokens`` tokens a
+    step: ``HELD_ROW_FACTOR`` times the uniform router's share of the
+    T*K assignments, a multiple of 8, T*K at most."""
+    assignments = tokens * cfg.num_experts_per_tok
+    share = -(-assignments * cfg.experts_held[1] // cfg.num_experts)
+    return min(assignments, -(-int(HELD_ROW_FACTOR * share) // 8) * 8)
+
+
 class MoEMLP(nn.Module):
     """Mixture-of-experts MLP: a float32 softmax router, top-k experts per
     token, SwiGLU experts stacked as [E, H, I] weights. Two dispatches,
@@ -378,10 +546,19 @@ class MoEMLP(nn.Module):
       dropped, the gates are the softmax's own values and the balance
       term counts all k choices: OLMoE's layer (arXiv:2409.02060).
 
+    Variants of the dropless form (the LlamaConfig fields say what each
+    computes): ``router_score="sigmoid"`` (a selection bias chooses, the
+    sigmoids weigh), ``expert_act="relu2"`` (two matrices an expert),
+    ``shared_expert_size`` (one expert every token passes through) and
+    ``experts_held`` (the layer holds a share of the experts, routes over
+    all of them and returns its own part: ``_sorted_held``).
+
     Sown per layer under "intermediates" (parallel/train.py reads them by
-    name): ``router_aux``, ``router_z``, ``moe_max_load`` (largest
-    expert's assignments over the mean), ``moe_dropped`` (assignments not
-    computed). The reference has no MoE/EP anywhere (SURVEY.md §2.3).
+    name): ``router_aux``, ``router_z`` (softmax router only),
+    ``moe_max_load`` (largest expert's assignments over the mean),
+    ``moe_dropped`` (assignments not computed) and, from a layer that
+    holds a share, ``moe_held_share`` (the share of all assignments that
+    landed on it). The reference has no MoE/EP anywhere (SURVEY.md §2.3).
     """
 
     cfg: LlamaConfig
@@ -396,6 +573,7 @@ class MoEMLP(nn.Module):
                 f"num_experts_per_tok ({K}) > num_experts ({E})"
             )
         H = x.shape[-1]
+        held = E if cfg.experts_held is None else cfg.experts_held[1]
 
         # Router in fp32 for numerically stable softmax/top-k.
         router_logits = nn.Dense(
@@ -405,20 +583,92 @@ class MoEMLP(nn.Module):
             param_dtype=cfg.param_dtype,
             name="router",
         )(x.astype(jnp.float32))  # [B,S,E]
-        probs = jax.nn.softmax(router_logits, axis=-1)
-        gate_vals, gate_idx = jax.lax.top_k(probs, K)  # [B,S,K]
-        lse = jax.nn.logsumexp(router_logits, axis=-1)
-        self.sow("intermediates", "router_z", jnp.mean(jnp.square(lse)))
+        if cfg.router_score == "sigmoid":
+            with jax.named_scope("moe/route"):
+                scores = jax.nn.sigmoid(router_logits)
+                bias = self.param(
+                    "router_bias", nn.initializers.zeros, (E,), cfg.param_dtype
+                )
+                # The bias chooses and does not weigh: no gradient reaches it.
+                _, gate_idx = jax.lax.top_k(scores + bias.astype(jnp.float32), K)
+                gate_vals = jnp.take_along_axis(scores, gate_idx, axis=-1)
+                gate_vals = cfg.routed_scaling * (
+                    gate_vals / (gate_vals.sum(-1, keepdims=True) + 1e-20)
+                )
+                # The balance term's P_e: the scores as shares of their sum.
+                probs = scores / scores.sum(-1, keepdims=True)
+        else:
+            probs = jax.nn.softmax(router_logits, axis=-1)
+            gate_vals, gate_idx = jax.lax.top_k(probs, K)  # [B,S,K]
+            lse = jax.nn.logsumexp(router_logits, axis=-1)
+            self.sow("intermediates", "router_z", jnp.mean(jnp.square(lse)))
 
-        expert = lambda shape, name: self.param(  # noqa: E731
-            name, nn.initializers.lecun_normal(), shape, cfg.param_dtype
+        # lecun_normal reads an [E, H, I] stack's leading axis as part of
+        # the fan-in, so E experts start sqrt(E) smaller than one would: the
+        # layer that holds all its experts keeps that (its accepted cell's
+        # weights are drawn so); a held share starts each expert from its
+        # own fan-in.
+        expert = lambda shape, name, scale=1.0: self.param(  # noqa: E731
+            name,
+            nn.initializers.lecun_normal() if cfg.experts_held is None
+            else nn.initializers.variance_scaling(
+                scale * scale, "fan_in", "normal", batch_axis=(0,)
+            ),
+            shape, cfg.param_dtype,
         ).astype(cfg.dtype)
-        w_gate = expert((E, H, cfg.intermediate_size), "experts_gate")
-        w_up = expert((E, H, cfg.intermediate_size), "experts_up")
-        w_down = expert((E, cfg.intermediate_size, H), "experts_down")
-        dropless = cfg.expert_capacity_factor is None
-        dispatch = self._sorted if dropless else self._capacity
-        return dispatch(x, probs, gate_vals, gate_idx, w_gate, w_up, w_down)
+        gated = cfg.expert_act == "swiglu"
+        if not gated and cfg.expert_act != "relu2":
+            raise ValueError(f"expert_act {cfg.expert_act!r}")
+        weights = (
+            (expert((held, H, cfg.intermediate_size), "experts_gate"),)
+            if gated else ()
+        ) + (
+            expert((held, H, cfg.intermediate_size), "experts_up"),
+            expert(
+                (held, cfg.intermediate_size, H), "experts_down",
+                cfg.residual_init_scale,
+            ),
+        )
+        if cfg.experts_held is not None:
+            if cfg.expert_capacity_factor is not None:
+                raise ValueError("a share of the experts needs the dropless dispatch")
+            out = self._sorted_held(x, probs, gate_vals, gate_idx, weights)
+        elif cfg.expert_capacity_factor is None:
+            out = self._sorted(x, probs, gate_vals, gate_idx, weights)
+        elif gated:
+            out = self._capacity(x, probs, gate_vals, gate_idx, *weights)
+        else:
+            raise ValueError("the capacity dispatch computes SwiGLU experts only")
+        if cfg.shared_expert_size:
+            with jax.named_scope("moe/shared"):
+                out = out + self._shared(x)
+        return out
+
+    def _ffn(self, xs, weights, gmm):
+        """One expert's feed-forward over its rows, ``gmm`` the (grouped)
+        matmul: SwiGLU over (gate, up, down) or relu^2 over (up, down)."""
+        if len(weights) == 3:
+            w_gate, w_up, w_down = weights
+            return gmm(nn.silu(gmm(xs, w_gate)) * gmm(xs, w_up), w_down)
+        w_up, w_down = weights
+        return gmm(jnp.square(nn.relu(gmm(xs, w_up))), w_down)
+
+    def _shared(self, x):
+        """The expert every token passes through, of the routed experts'
+        form at width ``shared_expert_size``."""
+        cfg = self.cfg
+        proj = lambda f, name, scale=1.0: nn.Dense(  # noqa: E731
+            f, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            kernel_init=nn.initializers.variance_scaling(
+                scale * scale, "fan_in", "truncated_normal"
+            ),
+            name=name,
+        )
+        names = ("shared_gate", "shared_up") if cfg.expert_act == "swiglu" else ("shared_up",)
+        weights = tuple(proj(cfg.shared_expert_size, n) for n in names) + (
+            proj(x.shape[-1], "shared_down", cfg.residual_init_scale),
+        )
+        return self._ffn(x, weights, lambda a, layer: layer(a))
 
     def _sow_routing(self, probs, load, fractions, dropped) -> None:
         """``load`` [E]: assignments routed to each expert; ``fractions``
@@ -430,7 +680,7 @@ class MoEMLP(nn.Module):
         self.sow("intermediates", "moe_max_load", load.max() * E / load.sum())
         self.sow("intermediates", "moe_dropped", dropped)
 
-    def _sorted(self, x, probs, gate_vals, gate_idx, w_gate, w_up, w_down):
+    def _sorted(self, x, probs, gate_vals, gate_idx, weights):
         cfg = self.cfg
         E, K, H = cfg.num_experts, cfg.num_experts_per_tok, x.shape[-1]
         T = x.shape[0] * x.shape[1]
@@ -449,12 +699,73 @@ class MoEMLP(nn.Module):
         gmm = lambda a, w: jax.lax.ragged_dot(  # noqa: E731
             a, w, group_sizes, preferred_element_type=cfg.dtype
         )
-        ys = gmm(nn.silu(gmm(xs, w_gate)) * gmm(xs, w_up), w_down)  # [T*K,H]
+        ys = self._ffn(xs, weights, gmm)  # [T*K,H]
         y = _permute_rows(ys, inv, order).reshape(T, K, H)
         out = jnp.einsum(
             "tkh,tk->th", y, gate_vals.reshape(T, K),
             preferred_element_type=jnp.float32,
         )
+        return out.reshape(x.shape).astype(x.dtype)
+
+    def _sorted_held(self, x, probs, gate_vals, gate_idx, weights):
+        """The sorted dispatch of a layer that holds ``count`` of the
+        experts. The T*K assignments are sorted by held expert, those of
+        absent experts last; the first R rows of that order are the
+        buffer the grouped matmuls run over, and what they give goes back
+        to its tokens weighted by the gates. What absent experts would
+        have added is left out.
+
+        R is static: a uniform router sends T*K*count/E rows here, and R is
+        ``HELD_ROW_FACTOR`` (4) times that, T*K at most. A full T*K
+        buffer would make every grouped matmul E/count (16) times longer
+        for rows that are never filled; four times the uniform share is
+        far above what a router under its balance term sends (the sown
+        ``moe_held_share`` says how far). An assignment to a held expert
+        that does not fit is not computed and is counted in ``moe_dropped``,
+        which a run checks is 0."""
+        cfg = self.cfg
+        first, count = cfg.experts_held
+        E, K, H = cfg.num_experts, cfg.num_experts_per_tok, x.shape[-1]
+        T = x.shape[0] * x.shape[1]
+        R = held_buffer_rows(cfg, T)
+        with jax.named_scope("moe/route"):
+            flat_idx = gate_idx.reshape(T * K)
+            all_sizes = jnp.sum(
+                flat_idx[:, None] == jnp.arange(E, dtype=flat_idx.dtype)[None, :],
+                axis=0, dtype=jnp.int32,
+            )
+            sizes = all_sizes[first : first + count]
+            # Group sizes cut to the buffer: the last groups lose what is past R.
+            ends = jnp.minimum(jnp.cumsum(sizes), R)
+            fit = jnp.diff(ends, prepend=0)
+            n_fit = ends[-1]
+            load = sizes.astype(jnp.float32)
+            p_e = probs.reshape(-1, E).mean(axis=0)
+            self.sow(
+                "intermediates", "router_aux",
+                E * jnp.sum(all_sizes.astype(jnp.float32) / (T * K) * p_e),
+            )
+            self.sow(
+                "intermediates", "moe_max_load",
+                load.max() * count / jnp.maximum(load.sum(), 1.0),
+            )
+            self.sow("intermediates", "moe_dropped", load.sum() - n_fit)
+            self.sow("intermediates", "moe_held_share", load.sum() / (T * K))
+
+            local = flat_idx - first
+            key = jnp.where((local >= 0) & (local < count), local, count)
+            order = jnp.argsort(key, stable=True)  # sorted row -> assignment
+            inv = jnp.argsort(order)  # assignment -> sorted row
+            rows = order[:R]
+            valid = jnp.arange(R) < n_fit
+            slot = jnp.where(inv < n_fit, inv, R).reshape(T, K)
+        with jax.named_scope("moe/experts"):
+            xs = _held_rows(x.reshape(T, H).astype(cfg.dtype), rows // K, slot)
+            gmm = lambda a, w: jax.lax.ragged_dot(  # noqa: E731
+                a, w, fit, preferred_element_type=cfg.dtype
+            )
+            ys = self._ffn(xs, weights, gmm)  # [R,H]
+            out = _combine_held(ys, gate_vals.reshape(T, K), slot, rows, valid)
         return out.reshape(x.shape).astype(x.dtype)
 
     def _capacity(self, x, probs, gate_vals, gate_idx, w_gate, w_up, w_down):
@@ -519,6 +830,31 @@ class Block(nn.Module):
         return x
 
 
+class MixerLayer(nn.Module):
+    """One layer of a ``layer_pattern`` stack: x + mixer(RMSNorm(x)), the
+    mixer named for its kind so that the sharding rules find it."""
+
+    cfg: LlamaConfig
+    kind: str
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        h = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="norm")(x)
+        if self.kind == "M":
+            return x + Mamba2Mixer(
+                cfg.mamba, cfg.hidden_size, cfg.norm_eps, cfg.residual_init_scale,
+                cfg.dtype, cfg.param_dtype, name="mamba",
+            )(h)
+        if self.kind == "E":
+            return x + MoEMLP(cfg, name="mlp")(h)
+        if self.kind == "*":
+            if cfg.rope:
+                raise ValueError("a pattern stack's attention takes no rotary embedding")
+            return x + Attention(cfg, name="attn")(h, None, None)
+        raise ValueError(f"layer kind {self.kind!r} is none of 'M', 'E', '*'")
+
+
 class _ScanBlock(Block):
     """Block with the (carry, ys) return contract nn.scan requires."""
 
@@ -557,6 +893,15 @@ class Transformer(nn.Module):
             name="embed",
         )
         x = embed(tokens)
+        if cfg.layer_pattern is not None:
+            # Unlike layers cannot share one scanned body: each is its own
+            # module, ``layers_<i>``, remat'd alone (outside a scan XLA would
+            # otherwise merge the recomputation with the forward pass and
+            # keep every layer's activations: prevent_cse stays on).
+            layer = nn.remat(MixerLayer) if cfg.remat else MixerLayer
+            for i, kind in enumerate(cfg.layer_pattern):
+                x = layer(cfg, kind, name=f"layers_{i}")(x)
+            return self._head(embed, x, return_hidden)
         cos, sin = rope_table(positions, cfg.head_dim, cfg.rope_theta, cfg.dtype)
 
         block = _ScanBlock
@@ -578,6 +923,10 @@ class Transformer(nn.Module):
             in_axes=(nn.broadcast, nn.broadcast),
         )(cfg, name="layers")
         x, _ = stack(x, cos, sin)
+        return self._head(embed, x, return_hidden)
+
+    def _head(self, embed: nn.Embed, x: jax.Array, return_hidden: bool) -> jax.Array:
+        cfg = self.cfg
         x = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")(x)
         if return_hidden:
             return x

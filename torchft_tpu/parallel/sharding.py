@@ -2,7 +2,8 @@
 
 The model stays mesh-agnostic; these rules map each parameter to a
 PartitionSpec over the (dp, fsdp, sp, tp) mesh. The scan-stacked layer dim
-(leading axis of every ``layers/*`` param) is unsharded — XLA scans over it.
+(leading axis of every ``layers/*`` param) is unsharded — XLA scans over it;
+a ``layer_pattern`` stack's ``layers_<i>/*`` params have no such axis.
 
 Layout (standard HSDP+TP recipe, cf. the public scaling playbook):
 - contraction-input dims shard over ``fsdp`` (all-gathered per layer),
@@ -36,6 +37,15 @@ _RULES: Dict[Tuple[str, str], Tuple[Any, ...]] = {
     ("mlp", "experts_up"): ("ep", "fsdp", "tp"),
     ("mlp", "experts_down"): ("ep", "tp", "fsdp"),
     ("router", "kernel"): ("fsdp", None),
+    # The shared expert shards like the dense MLP. The Mamba-2 mixer's two
+    # projections shard like an MLP's up and down; its convolution, decay,
+    # step and norm parameters are per channel or per head and replicate,
+    # as does the router's selection bias.
+    ("shared_gate", "kernel"): ("fsdp", "tp"),
+    ("shared_up", "kernel"): ("fsdp", "tp"),
+    ("shared_down", "kernel"): ("tp", "fsdp"),
+    ("in_proj", "kernel"): ("fsdp", "tp"),
+    ("out_proj", "kernel"): ("tp", "fsdp"),
 }
 
 

@@ -156,13 +156,25 @@ def _apply_with_aux(model: Transformer, params, inputs, **kw):
     return out, {k: jnp.concatenate(v) for k, v in sown.items()}
 
 
+# What an expert layer sows, and how the layers' values become one metric.
+_SOWN_OVER_LAYERS = (
+    ("router_aux", jnp.mean),
+    ("router_z", jnp.mean),
+    ("moe_max_load", jnp.max),
+    ("moe_dropped", jnp.sum),
+    ("moe_held_share", jnp.mean),
+)
+
+
 def _loss_and_metrics(model: Transformer, params, inputs, targets, mask):
     """(loss, router metrics). The loss is the mean next-token
     cross-entropy plus, for a model with experts, ``router_aux_coef`` x
     the load-balancing term and ``router_z_coef`` x the router z-loss,
     each a mean over the layers. The metrics are {} for a dense model,
-    else ``router_aux``, ``router_z``, ``moe_max_load`` (worst layer) and
-    ``moe_dropped`` (assignments not computed, all layers)."""
+    else ``router_aux``, ``router_z``, ``moe_max_load`` (worst layer),
+    ``moe_dropped`` (assignments not computed, all layers) and, from
+    layers that hold a share of their experts, ``moe_held_share`` (the
+    share of all assignments that landed on held experts)."""
     B, S = inputs.shape
     C = min(_LOSS_CHUNK, S)
     mask_f = mask.astype(jnp.float32)
@@ -175,10 +187,8 @@ def _loss_and_metrics(model: Transformer, params, inputs, targets, mask):
             # persistent compile cache keyed on it keeps hitting.
             return ce + cfg.router_aux_coef * jnp.zeros(()), {}
         metrics = {
-            "router_aux": sown["router_aux"].mean(),
-            "router_z": sown["router_z"].mean(),
-            "moe_max_load": sown["moe_max_load"].max(),
-            "moe_dropped": sown["moe_dropped"].sum(),
+            name: over_layers(sown[name])
+            for name, over_layers in _SOWN_OVER_LAYERS if name in sown
         }
         loss = ce + cfg.router_aux_coef * metrics["router_aux"]
         if cfg.router_z_coef:

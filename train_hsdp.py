@@ -33,7 +33,8 @@ def main() -> int:
     parser = argparse.ArgumentParser()
     parser.add_argument("--steps", type=int, default=20)
     parser.add_argument(
-        "--model", choices=["debug", "small", "moe", "olmoe", "pipeline"],
+        "--model",
+        choices=["debug", "small", "moe", "olmoe", "nemotron_h", "pipeline"],
         default="debug",
     )
     parser.add_argument("--batch", type=int, default=8)
@@ -101,6 +102,7 @@ def main() -> int:
         llama_debug,
         llama_moe_debug,
         llama_small,
+        nemotron_h_debug,
         olmoe_1b_7b,
     )
     from torchft_tpu.parallel import auto_mesh
@@ -172,6 +174,11 @@ def main() -> int:
             # The published sizes: 6.9B parameters, for a group's mesh
             # of chips, not for one.
             "olmoe": olmoe_1b_7b,
+            # The small preset of the hybrid stack (Mamba-2, experts of
+            # which a share is held, rope-free attention): pattern
+            # MEMEM*EME at test widths. models.nemotron3_nano() is the
+            # published model, for a deployment's meshes.
+            "nemotron_h": nemotron_h_debug,
         }[args.model]()
         if args.attn != "default":
             import dataclasses
