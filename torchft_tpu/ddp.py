@@ -11,7 +11,8 @@ in flight — the comm-hook overlap analog).
 
 from __future__ import annotations
 
-from typing import Any, List, Optional, Sequence, Tuple
+import collections
+from typing import Any, Deque, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -77,16 +78,32 @@ class DistributedDataParallel:
         grads = ddp.allreduce_grads(grads)      # outer-axis average over DCN
 
     **Who owns what comes back** (host path: fp32, or quantized on the
-    host). The leaves ``allreduce_grads`` returns are views of flat bucket
-    buffers this wrapper owns and writes again in its next call: they are
-    valid until the next ``allreduce_grads`` on the same wrapper, and a
-    caller that keeps one longer copies it (``np.array(leaf)``; off-TPU a
+    host). Two cases, by what was handed in.
+
+    *Every leaf a ``jax.Array``*: new device arrays with each input
+    leaf's sharding and dtype, landed when the call returns
+    (``torchft::ddp::push_wait``), as the device (int8) path and
+    ``PureDistributedDataParallel`` return new arrays. No view of a kept
+    buffer leaves the wrapper, so its next call may write the buffers
+    whether or not the step commits, and the wrapper keeps no reference
+    to a device array past its return. The buckets form a line: every
+    leaf's device-to-host copy starts at once, as soon as the gradients
+    exist (after ``grads_wait``), and per bucket, in issue order, the
+    caller waits for that bucket's copies
+    (``torchft::ddp::pull``), packs and issues it, and before it blocks
+    on the next pull sends back every earlier bucket whose collective is
+    done (``torchft::ddp::push``: ``jax.device_put`` of each leaf's slice
+    of the reduced bucket, asynchronous). Off the TPU a ``device_put`` of
+    a numpy view may alias the host memory rather than copy it, so there
+    the slice is copied first. A bucket whose collective failed is not
+    pushed (its buffer may still be written, below): its input leaves
+    come back in its place, on a step that will not commit.
+
+    *Any leaf a numpy array*: the leaves are views of flat bucket buffers
+    this wrapper owns and writes again in its next call: they are valid
+    until the next ``allreduce_grads`` on the same wrapper, and a caller
+    that keeps one longer copies it (``np.array(leaf)``; off-TPU a
     ``jnp.asarray(leaf)`` may alias the host memory rather than copy it).
-    The training loops consume them before that: ``apply_step`` takes them
-    as numpy arguments, and the next call packs only after ``grads_wait``
-    on gradients that depend on that ``apply_step``'s output, so its
-    host-to-device transfer has finished by data dependence. The device
-    (int8) path and ``PureDistributedDataParallel`` return new arrays.
 
     **A failed step retires the buffers.** If the call raises, or the
     manager holds an error when it ends (a bucket's work failed or timed
@@ -237,6 +254,10 @@ class DistributedDataParallel:
                     out[i] = r
             return 0.0, jax.tree_util.tree_unflatten(treedef, out)
         dev_leaves = [x for x in leaves if isinstance(x, jax.Array)]
+        # Device leaves only: the buckets form a line and device arrays go
+        # back. A tree with a numpy leaf in it is pulled whole, then
+        # packed, then waited for, and gets views of the kept buffers.
+        line = bool(leaves) and len(dev_leaves) == len(leaves)
         compute_s = 0.0
         if dev_leaves:
             # Guard the device->host pull: if the device computation feeding
@@ -265,21 +286,86 @@ class DistributedDataParallel:
             with trace_span("torchft::ddp::grads_wait") as waited:
                 jax.block_until_ready(dev_leaves)
             compute_s = waited.elapsed_s
-        with trace_span(
-            "torchft::ddp::pull", nbytes=sum(x.nbytes for x in dev_leaves)
-        ):
-            host: List[np.ndarray] = [np.asarray(x) for x in leaves]
+        host: List[Optional[np.ndarray]] = [None] * len(leaves)
+        if not line:
+            with trace_span(
+                "torchft::ddp::pull", nbytes=sum(x.nbytes for x in dev_leaves)
+            ):
+                host = [np.asarray(x) for x in leaves]
+        # The layout is the same read off jax leaves or their numpy copies.
+        like: Sequence[Any] = leaves if line else host
+        buckets = self._bucketize(like)
+        order = issue_order(like, buckets)
+        if line:
+            # Every copy starts now, in the order the buckets will be
+            # wanted, and they run back to back while the host packs,
+            # reduces and pushes the buckets that have arrived. Not before
+            # the gradients exist: copies asked of a program's pending
+            # outputs are not served in the order asked, and the first
+            # bucket then waits for most of the gradient (PERF.md §6, PR 39).
+            for b_idx in order:
+                for i in buckets[b_idx]:
+                    leaves[i].copy_to_host_async()
+        # device_put of a numpy view copies on the TPU and may alias off it.
+        copy_first = line and any(
+            d.platform != "tpu" for d in leaves[0].devices()
+        )
 
-        buckets = self._bucketize(host)
         flats, kept = self._pack_buffers.take(
             tuple(
-                (host[idx_list[0]].dtype, sum(host[i].size for i in idx_list))
+                (like[idx_list[0]].dtype, sum(like[i].size for i in idx_list))
                 for idx_list in buckets
             )
         )
-        works: List[Tuple[int, Any, List[int]]] = []
-        for b_idx in issue_order(host, buckets):
+        out: List[Any] = [None] * len(leaves)
+        issued: Deque[Tuple[int, Any, List[int]]] = collections.deque()
+
+        def land() -> None:
+            """The oldest issued bucket: waits for its collective, slices
+            the reduced buffer back into leaves and, on the line, sends
+            them to the device."""
+            b_idx, work, idx_list = issued.popleft()
+            (reduced,) = work.wait()
+            if line and self._manager.errored() is not None:
+                # An aborted collective's thread may still write into this
+                # bucket: nothing is read from it. The step will not
+                # commit; the caller gets its own leaves back and drops them.
+                for i in idx_list:
+                    out[i] = leaves[i]
+                return
+            with trace_span("torchft::ddp::unpack", bucket=b_idx):
+                offset = 0
+                for i in idx_list:
+                    n = like[i].size
+                    out[i] = reduced[offset : offset + n].reshape(
+                        like[i].shape
+                    )
+                    offset += n
+            if not line:
+                return
+            # Asynchronous: the span is the dispatch, push_wait the landing.
+            with trace_span(
+                "torchft::ddp::push", bucket=b_idx, nbytes=reduced.nbytes
+            ):
+                for i in idx_list:
+                    out[i] = jax.device_put(
+                        np.array(out[i]) if copy_first else out[i],
+                        leaves[i].sharding,
+                    )
+
+        for b_idx in order:
             idx_list = buckets[b_idx]
+            if line:
+                # A finished bucket goes back while this one's copy arrives.
+                while issued and issued[0][1].done():
+                    land()
+                with trace_span(
+                    "torchft::ddp::pull",
+                    bucket=b_idx,
+                    nbytes=sum(leaves[i].nbytes for i in idx_list),
+                ):
+                    for i in idx_list:
+                        host[i] = np.asarray(leaves[i])
             on_quantized = None
             with trace_span("torchft::ddp::pack", bucket=b_idx) as pack:
                 flat = flats[b_idx]
@@ -304,19 +390,12 @@ class DistributedDataParallel:
                 quantize_bits=quantize_bits,
                 on_local_quantized=on_quantized,
             )
-            works.append((b_idx, work, idx_list))
-
-        out: List[Optional[np.ndarray]] = [None] * len(host)
-        for b_idx, work, idx_list in works:
-            (reduced,) = work.wait()
-            with trace_span("torchft::ddp::unpack", bucket=b_idx):
-                offset = 0
-                for i in idx_list:
-                    n = host[i].size
-                    out[i] = reduced[offset : offset + n].reshape(
-                        host[i].shape
-                    )
-                    offset += n
+            issued.append((b_idx, work, idx_list))
+        while issued:
+            land()
+        if line:
+            with trace_span("torchft::ddp::push_wait"):
+                jax.block_until_ready(out)
         return compute_s, jax.tree_util.tree_unflatten(treedef, out)
 
     def _bucketize(self, arrays: List[np.ndarray]) -> List[List[int]]:
