@@ -485,11 +485,18 @@ def test_an_abort_mid_step_passes_the_turns_on_and_the_next_step_completes(
     works = [_issue(groups[0], [p[0] for p in torn])]
     assert in_pull.wait(30)
     works.append(_issue(groups[1], [p[1] for p in torn]))
-    time.sleep(0.2)  # rank 1 has pulled and waits on the wire for rank 0
+    # rank 1 has pulled and waits on the wire for rank 0
+    deadline = time.monotonic() + 30
+    while "_quant_wire_scratch" not in groups[1].__dict__:
+        assert time.monotonic() < deadline
+        time.sleep(0.01)
+    time.sleep(0.2)
     assert "_quant_pull_order" in groups[0].__dict__
     for g in groups:
         g.abort()
-    # the turns went with the wire scratch: what is issued next starts over
+    # the turns went with the wire scratch: what is issued next starts
+    # over, and a collective of the torn step that reaches the wire after
+    # the abort hangs no new scratch on the dead group
     for g in groups:
         assert "_quant_pull_order" not in g.__dict__
         assert "_quant_wire_scratch" not in g.__dict__
@@ -503,6 +510,8 @@ def test_an_abort_mid_step_passes_the_turns_on_and_the_next_step_completes(
                 failed += 1
     assert failed >= 1
     assert _no_collective_thread_is_left()
+    for g in groups:
+        assert "_quant_wire_scratch" not in g.__dict__
 
     def configure(rank):
         groups[rank].configure(f"{store.address()}/torn-again", rank, ws)

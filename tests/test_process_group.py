@@ -113,6 +113,16 @@ def test_allgather_broadcast_reduce_scatter_alltoall_barrier(store):
         inputs = [np.array([rank * 10 + j]) for j in range(ws)]
         out = pg.alltoall(inputs).wait(timeout=30)
         assert [o[0] for o in out] == [j * 10 + rank for j in range(ws)]
+        # ... and a list of arrays a rank travels in the one collective
+        inputs = [
+            [np.array([rank * 10 + j]), np.full(3, rank - j, np.float32)]
+            for j in range(ws)
+        ]
+        out = pg.alltoall(inputs).wait(timeout=30)
+        assert [o[0][0] for o in out] == [j * 10 + rank for j in range(ws)]
+        for j, o in enumerate(out):
+            assert o[1].dtype == np.float32
+            np.testing.assert_array_equal(o[1], np.full(3, j - rank))
         # barrier
         pg.barrier().wait(timeout=30)
         return True
@@ -120,6 +130,69 @@ def test_allgather_broadcast_reduce_scatter_alltoall_barrier(store):
     assert all(_run_parallel([lambda r=r: run(r) for r in range(ws)]))
     for g in groups:
         g.shutdown()
+
+
+def _alltoall_groups(backend, store, ws):
+    if backend == "dummy":
+        return [ProcessGroupDummy(rank=0, world=1)]
+    if backend == "socket":
+        return _make_group(store, ws, prefix="a2a-lists")
+    if backend == "native":
+        from torchft_tpu.process_group import ProcessGroupNative as cls
+    else:
+        from torchft_tpu.baby import ProcessGroupBabySocket as cls
+    groups = [cls(timeout=20.0) for _ in range(ws)]
+    _run_parallel(
+        [
+            lambda r=r: groups[r].configure(
+                f"{store.address()}/a2a-{backend}", r, ws
+            )
+            for r in range(ws)
+        ]
+    )
+    return groups
+
+
+@pytest.mark.parametrize("backend", ["socket", "native", "dummy", "baby"])
+def test_alltoall_takes_a_list_of_arrays_a_rank(store, backend):
+    """What the quantized wire sends a turn: every rank's payload chunk
+    and its scales in ONE alltoall. Arrays of a list keep their dtypes
+    and shapes, one large enough for the baby group's shared memory; a
+    bare array a rank still comes back bare."""
+    groups = _alltoall_groups(backend, store, 2)
+    ws = len(groups)
+
+    def run(rank):
+        pg = groups[rank]
+        inputs = [
+            [
+                np.full(70_000, rank * 10 + j, dtype=np.int8),
+                np.full((2, 3), rank - j, dtype=np.float32),
+            ]
+            for j in range(ws)
+        ]
+        nested = pg.alltoall(inputs).wait(timeout=60)
+        bare = pg.alltoall([np.array([rank * 10 + j]) for j in range(ws)])
+        return nested, bare.wait(timeout=60)
+
+    try:
+        results = _run_parallel([lambda r=r: run(r) for r in range(ws)])
+        for rank, (nested, bare) in enumerate(results):
+            assert len(nested) == len(bare) == ws
+            for src in range(ws):
+                q, s = nested[src]
+                assert q.dtype == np.int8 and q.shape == (70_000,)
+                assert s.dtype == np.float32 and s.shape == (2, 3)
+                assert (q == src * 10 + rank).all() and (s == src - rank).all()
+                assert bare[src].shape == (1,) and bare[src][0] == src * 10 + rank
+        if ws > 1:
+            # as many arrays for every rank, or the collective refuses
+            uneven = [[np.zeros(1)], [np.zeros(1), np.zeros(1)]]
+            with pytest.raises(Exception, match="as many arrays"):
+                groups[0].alltoall(uneven).wait(timeout=30)
+    finally:
+        for g in groups:
+            g.shutdown()
 
 
 @pytest.mark.parametrize("dtype", [np.float32, np.float64, np.int32, np.float16])
@@ -1119,7 +1192,9 @@ def test_quantized_wire_result_outlives_the_next_wire_turn(store, monkeypatch, p
     groups = _make_group(store, ws, prefix=f"own-{path}")
     if path == "device":
         monkeypatch.setenv("TORCHFT_FORCE_DEVICE_QUANT", "1")
-        _second_wire_before_first_push(monkeypatch, Q, "dequantize_from_transfer")
+        _second_wire_before_first_push(
+            monkeypatch, Q, "dequantize_leaves_from_transfer"
+        )
     else:
         _second_wire_before_first_push(monkeypatch, C, "dequantize_blockwise")
 
@@ -1201,6 +1276,25 @@ def test_quantized_wire_scratch_is_dropped_on_reconfigure(store):
         np.testing.assert_array_equal(g, _oracle_wire(data[:2], 8)[2])
     assert groups[0].__dict__["_quant_wire_scratch"] is not old
     for g in groups[:2]:
+        g.shutdown()
+
+
+def test_a_quantized_collective_on_an_aborted_group_hangs_no_scratch_on_it(store):
+    """What a torn step's collective does when it gets the wire turn
+    after the abort: it fails, and the dead group pins no buffers."""
+    from torchft_tpu.collectives import allreduce_quantized
+
+    ws = 2
+    groups = _make_group(store, ws, prefix="dead2")
+    data = _wire_data(ws, _B * 6 * 5 + 9, seed=52)
+    _allreduce_quantized_all(groups, data)
+    for g in groups:
+        g.abort()
+        assert "_quant_wire_scratch" not in g.__dict__
+        with pytest.raises(RuntimeError):
+            allreduce_quantized(g, [data[0].copy()]).wait(timeout=30)
+        assert "_quant_wire_scratch" not in g.__dict__
+    for g in groups:
         g.shutdown()
 
 

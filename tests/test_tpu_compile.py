@@ -136,7 +136,13 @@ def compiled_quant(monkeypatch):
     from torchft_tpu.ops import quantization as Q
 
     monkeypatch.setattr(Q, "_interpret", lambda: False)
-    return Q
+    # The module's inner jits (``_quantize_rows``, ``_dequantize_rows``)
+    # are traced once a shape and keep what they traced: an interpreted
+    # kernel from an earlier CPU test of this worker, or, after these
+    # tests, a compiled one a later CPU test could not run.
+    jax.clear_caches()
+    yield Q
+    jax.clear_caches()
 
 
 ROWS = 32768  # x 512-wide blocks = 16M elements, one transfer chunk
@@ -160,6 +166,53 @@ def test_fused_dequantize_compiles(one_chip, compiled_quant, bits):
     _assert_kernel(
         lambda q, s: Q.fused_dequantize(q, s, n, bits=bits), q, scales
     )
+
+
+# A bucket of the int8 replica allreduce, down and up, at mistral-ft4's
+# shapes: the 131M-element embedding (one float32 leaf) and the three
+# norms (a joined bucket of 12,288 elements, not whole tiles).
+BUCKETS = {"embedding": [(32000, 4096)], "norms": [(4096,)] * 3}
+
+
+@pytest.mark.parametrize("name", list(BUCKETS))
+def test_bucket_programs_compile_to_one_kernel_each_way(
+    one_chip, compiled_quant, name
+):
+    """One program a bucket each way, one kernel in each, under the names
+    a device trace shows (``_quantize_rows.N``, ``_dequantize_rows.N``),
+    and none of the chunk path's ``dynamic-update-slice``s."""
+    Q = compiled_quant
+    shapes = tuple(BUCKETS[name])
+    dtypes = (jnp.dtype(jnp.float32),) * len(shapes)
+    leaves = [_spec(one_chip, shape, jnp.float32) for shape in shapes]
+    n = sum(functools.reduce(lambda a, b: a * b, shape) for shape in shapes)
+    rows = -(-n // Q.BLOCK)
+
+    def down(*ls):
+        return Q._quantize_leaves.__wrapped__(list(ls), 8)
+
+    def up(q, s, scale):
+        return Q._dequantize_leaves.__wrapped__(q, s, scale, shapes, dtypes, 8)
+
+    down_text = jax.jit(down).lower(*leaves).compile().as_text()
+    up_text = (
+        jax.jit(up)
+        .lower(
+            _spec(one_chip, (rows, Q.BLOCK), jnp.int8),
+            _spec(one_chip, (rows,), jnp.float32),
+            _spec(one_chip, (), jnp.float32),
+        )
+        .compile()
+        .as_text()
+    )
+    for text, kernel in ((down_text, "_quantize_rows"), (up_text, "_dequantize_rows")):
+        calls = [
+            line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+        ]
+        assert len(calls) == 1, calls
+        assert calls[0].strip().lstrip("%").startswith(kernel + "."), calls[0]
+        assert "dynamic-update-slice" not in text
 
 
 def test_fused_reduce_int8_compiles(one_chip, compiled_quant):

@@ -36,7 +36,12 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from torchft_tpu.process_group import ProcessGroup, ReduceOp, _as_list
+from torchft_tpu.process_group import (
+    ProcessGroup,
+    ReduceOp,
+    _as_list,
+    _per_rank_lists,
+)
 from torchft_tpu.work import ErrorWork, Work
 
 import logging
@@ -180,6 +185,11 @@ def _run_op(pg, name: str, arrays, kwargs: Dict[str, Any], timeout: float):
     if name == "reduce_scatter":
         return pg.reduce_scatter(arrays, ReduceOp(kwargs["op"])).wait(timeout)
     if name == "alltoall":
+        parts = kwargs["parts"]
+        if parts:  # a list of arrays a rank, flattened for the pipe
+            arrays = [
+                arrays[i : i + parts] for i in range(0, len(arrays), parts)
+            ]
         return pg.alltoall(arrays).wait(timeout)
     if name == "barrier":
         return pg.barrier().wait(timeout)
@@ -606,7 +616,17 @@ class ProcessGroupBabySocket(ProcessGroup):
         return self._issue("reduce_scatter", _as_list(inputs), op=op.value)
 
     def alltoall(self, inputs: Sequence[Any]) -> Work:
-        return self._issue("alltoall", _as_list(inputs))
+        nested, per_rank = _per_rank_lists(inputs)
+        parts = len(per_rank[0]) if nested and per_rank else 0
+        if any(len(p) != parts for p in per_rank if nested):
+            return ErrorWork(
+                ValueError(
+                    "alltoall needs as many arrays for every rank, got "
+                    f"{[len(p) for p in per_rank]}"
+                )
+            )
+        flat = [a for p in per_rank for a in p]
+        return self._issue("alltoall", flat, parts=parts)
 
     def barrier(self) -> Work:
         return self._issue("barrier", [])

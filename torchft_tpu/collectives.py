@@ -55,9 +55,11 @@ class _WireOrder:
 
     A second instance on the same group (``_quant_pull_order``) gives the
     device-to-host pulls of the device path their turns, in the same
-    order: bucket k+1 pulls while bucket k is on the wire and no two
-    pulls share the one path off the device (ten at once moved 0.33 GB/s
-    together and held the wire back 1.4 s a step on the chip). It is
+    order: bucket k+1 pulls while bucket k is on the wire, and the
+    smallest bucket's payload is the first the host waits for (ten
+    blocking pulls at once moved 0.33 GB/s together and held the wire
+    back 1.4 s a step on the chip; so did ten copies asked for at once
+    and waited for in turn: PERF.md section 6, PR 40). It is
     taken only by a collective that has device chunks to pull, hangs on
     the group for the same reason the wire's does, and is let go with the
     wire scratch (``ProcessGroup._drop_wire_scratch``): the collectives of
@@ -175,8 +177,24 @@ class _WireScratch:
 
 
 def _wire_scratch(pg: ProcessGroup) -> _WireScratch:
+    """``pg``'s scratch, made on first use, but never on a group that has
+    latched an error: a collective of a torn step that gets its wire turn
+    after the abort fails here (its socket operations would fail next)
+    instead of hanging a new scratch on the dead group. ``abort`` latches
+    the error before it drops the scratch, so the look after the
+    ``setdefault`` catches an abort that ran beside it."""
+
+    def check() -> None:
+        err = pg.errored()
+        if err is not None:
+            pg.__dict__.pop("_quant_wire_scratch", None)
+            raise RuntimeError(f"process group errored: {err}") from err
+
+    check()
     with _wire_order_lock:
-        return pg.__dict__.setdefault("_quant_wire_scratch", _WireScratch())
+        scratch = pg.__dict__.setdefault("_quant_wire_scratch", _WireScratch())
+    check()
+    return scratch
 
 
 def _turn(order: _WireOrder, ticket: int, wait_span: str, bucket: "int | None"):
@@ -502,11 +520,21 @@ def allreduce_quantized_jax(
     dequantize ON DEVICE (reference: collectives.py:297-415, with the
     device-side quantize the Triton kernels provide there).
 
+    A bucket costs one of each thing, each way: one cached compiled
+    program down (``ops.quantization.quantize_for_transfer_async``: the
+    leaves joined, quantized and laid out for the wire), one
+    device-to-host copy, asked for in the bucket's pull turn (so one at a
+    time, in issue order), one alltoall, one allgather, one
+    host-to-device transfer and one cached compiled program up
+    (``dequantize_leaves_from_transfer``: its outputs are the leaves). No
+    eager device operation runs per call but the snapshot below; a
+    payload too large for one program takes the bounded path of those
+    two functions, chosen from its size.
+
     Returns Work whose result is a list of NEW jax arrays (original
     shapes/dtypes), scaled by ``scale`` on device. The inputs are not
     mutated (jax arrays are immutable).
     """
-    import jax
     import jax.numpy as jnp
 
     from torchft_tpu.ops import quantization as Q
@@ -514,19 +542,8 @@ def allreduce_quantized_jax(
     if op not in (ReduceOp.SUM, ReduceOp.AVG):
         raise ValueError(f"allreduce_quantized supports SUM/AVG, got {op}")
     arrays = list(arrays)
-    shapes = [a.shape for a in arrays]
-    dtypes = [a.dtype for a in arrays]
-    sizes = [a.size for a in arrays]
-
-    def rebuild(flat: "jax.Array") -> List["jax.Array"]:
-        outs = []
-        offset = 0
-        for shape, dtype, size in zip(shapes, dtypes, sizes):
-            outs.append(
-                flat[offset : offset + size].reshape(shape).astype(dtype)
-            )
-            offset += size
-        return outs
+    shapes = tuple(a.shape for a in arrays)
+    dtypes = tuple(a.dtype for a in arrays)
 
     ws = pg.size()
     total_scale = scale / ws if op == ReduceOp.AVG else scale
@@ -545,45 +562,49 @@ def allreduce_quantized_jax(
     tags = _bucket_tags(arrays)
     # The caller-thread part; nothing in it waits for the device.
     with trace_span("torchft::collectives::dispatch", **tags):
-        if len(arrays) > 1:
-            flat = jnp.concatenate(
-                [jnp.ravel(a).astype(jnp.float32) for a in arrays]
-            )
-        else:
-            flat = jnp.ravel(arrays[0]).astype(jnp.float32)
         if ws <= 1:
+            if scale == 1.0:
+                return DummyWork(arrays)
             return DummyWork(
-                rebuild(flat * scale) if scale != 1.0 else arrays
+                Q.cut_leaves(
+                    Q.flatten_leaves(arrays),
+                    np.float32(scale),
+                    shapes=shapes,
+                    dtypes=dtypes,
+                )
             )
         a0 = arrays[0]
         if len(arrays) == 1 and a0.ndim == 1 and a0.dtype == jnp.float32:
-            # ravel/astype both short-circuited, so ``flat`` aliases the
-            # caller's buffer.  Parts of the pipeline touch ``flat`` after
-            # this call returns (host path: the deferred host pull; device
-            # path: quantize kernels already enqueued but not yet
-            # executed) while the caller's next train step may DONATE this
-            # buffer (make_train_step donates its state), deleting
-            # it mid-use.  Materialize an independent device snapshot
-            # before returning to the caller.  (Below the ws<=1 return:
-            # the single-replica path never defers.)
-            flat = jnp.copy(flat)
+            # Nothing below copies such an input before this call
+            # returns (its ravel and cast are the array itself), yet
+            # parts of the pipeline touch it afterwards (host path: the
+            # deferred host pull; device path: the quantize program,
+            # enqueued but not yet executed) while the caller's next
+            # train step may DONATE this buffer (make_train_step donates
+            # its state), deleting it mid-use.  Materialize an
+            # independent device snapshot before returning to the
+            # caller.  (Below the ws<=1 return: the single-replica path
+            # never defers.)
+            arrays = [jnp.copy(a0)]
 
-        # Device path: dispatch the quantize kernels NOW, on the caller's
+        # Device path: dispatch the quantize program NOW, on the caller's
         # thread. Async dispatch returns immediately, but enqueues the
-        # kernels right behind the compute that produced ``flat`` — BEFORE
-        # the caller's next training window. The deferred host pull then
-        # overlaps that window; dispatched lazily from the collective
-        # thread instead, the kernels would queue behind the whole next
-        # window and the "pull" would spend its time waiting on unrelated
-        # compute.
-        q_chunks = None
+        # kernel right behind the compute that produced the leaves —
+        # BEFORE the caller's next training window. The deferred host
+        # pull then overlaps that window; dispatched lazily from the
+        # collective thread instead, the kernel would queue behind the
+        # whole next window and the "pull" would spend its time waiting
+        # on unrelated compute.
+        flat = q_chunks = None
         n_elems = 0
-        if not host_quant:
-            q_chunks, n_elems = Q.quantize_for_transfer_async(flat, bits)
-            # The enqueued kernels hold their own reference to the
-            # snapshot; don't let the run() closure pin the full fp32 copy
-            # across the multi-second wire pipeline too.
-            flat = None
+        if host_quant:
+            flat = Q.flatten_leaves(arrays)
+        else:
+            q_chunks, n_elems = Q.quantize_for_transfer_async(arrays, bits)
+        # The enqueued program holds its own reference to the leaves (or
+        # the snapshot); don't let the run() closure pin the fp32 inputs
+        # across the multi-second wire pipeline too.
+        del arrays, a0
 
     def run(wire, pull=contextlib.nullcontext) -> List["jax.Array"]:
         # Device chunks are pulled one bucket at a time, in issue order;
@@ -600,27 +621,25 @@ def allreduce_quantized_jax(
         with wire(), trace_span("torchft::collectives::wire", **tags):
             reduced = _quantized_wire_pipeline(pg, q_host, s_host, n, bits)
         with trace_span("torchft::collectives::dequant_push", **tags):
+            if host_quant and not isinstance(reduced, np.ndarray):
+                reduced = dequantize_blockwise(*reduced, n, bits)
             if isinstance(reduced, np.ndarray):
-                # Tiny payload: the local reduce already produced the full
-                # fp32 sum — push it straight to device, no second lossy
-                # round trip.
-                out = jnp.asarray(reduced)
+                # Already fp32 on the host: the host quantizer's decode,
+                # or a tiny payload (the local reduce produced the full
+                # sum; no second lossy round trip).
+                outs = Q.cut_leaves(
+                    jnp.asarray(reduced),
+                    None if total_scale == 1.0 else np.float32(total_scale),
+                    shapes=shapes,
+                    dtypes=dtypes,
+                )
             else:
-                q_final, s_final = reduced
-                if host_quant:
-                    out = jnp.asarray(
-                        dequantize_blockwise(q_final, s_final, n, bits)
-                    )
-                else:
-                    # Device-side dequantize (chunked; the sum stayed fp32
-                    # on the wire pipeline so only one quantize->dequantize
-                    # round trip of error per value).
-                    out = Q.dequantize_from_transfer(
-                        q_final, s_final, n, bits
-                    )
-            if total_scale != 1.0:
-                out = out * total_scale
-            outs = rebuild(out)
+                # Device-side dequantize (the sum stayed fp32 on the wire
+                # pipeline so only one quantize->dequantize round trip of
+                # error per value).
+                outs = Q.dequantize_leaves_from_transfer(
+                    *reduced, shapes, dtypes, total_scale, bits
+                )
             # BOTH backends: leave the final device arrays async-dispatched.
             # On CPU the dequantize itself already ran on the host above, so
             # every real error class (wire, shape, quantize, reduce) has
@@ -642,8 +661,8 @@ def allreduce_quantized_jax(
             # FT error-latch boundary under async dispatch: everything
             # DISPATCH-time still raises here on the collective thread and
             # latches (shape errors, and HBM OOM — PJRT allocates output
-            # buffers at dispatch, so the big fp32 allocation in
-            # dequantize_from_transfer fails synchronously).  Only an
+            # buffers at dispatch, so the leaves' fp32 allocation in
+            # dequantize_leaves_from_transfer fails synchronously).  Only an
             # EXECUTION-time device fault defers to the caller's next
             # materialize, outside the latch — for static-shaped
             # elementwise kernels on TPU there is no analog of CUDA's
@@ -811,13 +830,14 @@ def _alltoall_chunk_reduce(
         s_chunks.append(s_host[off : off + c])
         off += c
     with trace_span("torchft::collectives::wire_alltoall"):
-        all_q = pg.alltoall(q_chunks).wait()
-        all_s = pg.alltoall(s_chunks).wait()
+        peers = pg.alltoall(
+            [[q, s] for q, s in zip(q_chunks, s_chunks)]
+        ).wait()
     mine = counts[pg.rank()]
     with trace_span("torchft::collectives::wire_reduce") as span:
         acc = scratch.turn("acc", np.float32, mine * BLOCK)
         tmp = scratch.turn("tmp", np.float32, _task_tmp_shape(mine))
-        _dequantize_sum(acc, list(zip(all_q, all_s)), bits, tmp)
+        _dequantize_sum(acc, peers, bits, tmp)
         span.attrs.update(scratch.counts())
     return acc
 

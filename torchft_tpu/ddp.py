@@ -233,9 +233,9 @@ class DistributedDataParallel:
             # ops in issue order, and a single whole-pytree allreduce
             # against a peer's per-bucket ones would desync the wire.
             # Each bucket's leaves go down as a list: the quantized jax
-            # collective concatenates them on device, matching the host
-            # path's flat bucket payload byte-for-byte. The order of the
-            # calls is the host path's too (issue_order).
+            # collective joins them on device in ONE program, matching
+            # the host path's flat bucket payload byte-for-byte. The
+            # order of the calls is the host path's too (issue_order).
             buckets = self._bucketize(leaves)
             works = []
             for b_idx in issue_order(leaves, buckets):

@@ -29,7 +29,8 @@ by exactly one owner rank, and all replicas decode identical bytes.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+import math
+from typing import Any, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -42,16 +43,6 @@ _TILE = 32  # rows per kernel instance; int8 min sublane count
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
-
-
-def _pad_blocks(x: jax.Array) -> Tuple[jax.Array, int]:
-    n = x.size
-    blocks = max((n + BLOCK - 1) // BLOCK, 1)
-    # Row count padded to the tile so the grid divides evenly.
-    rows = ((blocks + _TILE - 1) // _TILE) * _TILE
-    padded = jnp.zeros((rows * BLOCK,), jnp.float32)
-    padded = padded.at[:n].set(x.reshape(-1).astype(jnp.float32))
-    return padded.reshape(rows, BLOCK), n
 
 
 def _requantize(
@@ -116,18 +107,86 @@ def _unpack_nibbles_jnp(p: jax.Array) -> jax.Array:
 from torchft_tpu.collectives import _qmax as _bits_qmax  # noqa: E402
 
 
+def _flat_f32(leaves: Sequence[jax.Array]) -> jax.Array:
+    """The bucket's flat payload as the host path lays it out
+    (``ddp``'s pack, ``collectives._flatten``): the leaves raveled in the
+    order given, as float32. One float32 leaf is a reshape and nothing
+    else."""
+    parts = [jnp.ravel(a).astype(jnp.float32) for a in leaves]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+
+def _tile_rows(n: int) -> int:
+    """Rows of BLOCK values that hold ``n``, in whole kernel tiles."""
+    blocks = max(-(-n // BLOCK), 1)
+    return -(-blocks // _TILE) * _TILE
+
+
+def _as_words(q: jax.Array) -> jax.Array:
+    """int8 [rows, BLOCK] -> uint32 [rows, BLOCK/4], word j of a row its
+    bytes 4j..4j+3, lowest first: on a little-endian host the words'
+    int8 view IS the payload. Why: the TPU keeps int8 in tiles that pack
+    four ROWS a word, and undoing that byte by byte is what a
+    device-to-host copy of int8 pays for (0.65 GB/s where the same bytes
+    as 32-bit values move 2.4; PERF.md section 6, PR 40). XLA has no
+    cheap way to put four neighbouring COLUMNS into a word (a minor
+    dimension of 4 is padded 32-fold, strided slices become gathers), but
+    the MXU has: each byte as an unsigned bf16 value (0..255, exact) times
+    a 0/1/256 selection matrix, accumulated in float32, gives the two
+    16-bit halves of every word exactly (two nonzero terms a sum, each
+    under 2**16)."""
+    u = (q.astype(jnp.int32) & 0xFF).astype(jnp.bfloat16)
+    col = jnp.arange(BLOCK)
+    same_word = (col // 4)[:, None] == jnp.arange(BLOCK // 4)[None, :]
+    weight = jnp.where(col % 2 == 0, 1.0, 256.0)[:, None]
+
+    def half(which: int) -> jax.Array:
+        sel = jnp.where(
+            same_word & ((col % 4) // 2 == which)[:, None], weight, 0.0
+        ).astype(jnp.bfloat16)
+        return jnp.dot(u, sel, preferred_element_type=jnp.float32).astype(
+            jnp.uint32
+        )
+
+    return half(0) | (half(1) << 16)
+
+
+@functools.partial(jax.jit, static_argnames=("bits", "words"))
+def _quantize_leaves(
+    leaves: Sequence[jax.Array], bits: int = 8, words: bool = False
+) -> Tuple[jax.Array, jax.Array]:
+    """The way down, one compiled program a bucket layout (the leaves'
+    shapes and dtypes, ``bits``): ravel and join the leaves, pad to whole
+    tiles only where the element count is not one already, the quantize
+    kernel over all rows, nibble packing for 4 bits. Returns (payload
+    [rows, BLOCK or BLOCK/2], scales [rows]) with ``rows`` in whole tiles:
+    the first ``ceil(n / BLOCK)`` rows, row-major, are the bytes of
+    ``collectives.quantize_blockwise``. With ``words`` (int8 payloads
+    only) the same bytes leave as uint32 [rows, BLOCK/4], the form that
+    crosses to the host (:func:`_as_words`)."""
+    flat = _flat_f32(leaves)
+    rows = _tile_rows(flat.size)
+    if rows * BLOCK != flat.size:
+        flat = jnp.pad(flat, (0, rows * BLOCK - flat.size))
+    q, s = _quantize_rows(flat.reshape(rows, BLOCK), _bits_qmax(bits))
+    if bits == 4:
+        q = _pack_nibbles_jnp(q)
+    elif words:
+        q = _as_words(q)
+    return q, s[:, 0]
+
+
 def fused_quantize(
     x: jax.Array, bits: int = 8
 ) -> Tuple[jax.Array, jax.Array, int]:
     """Quantizes a device array to (payload [rows, BLOCK or BLOCK/2], fp32
-    scales [rows], element count). Pull the first two to host for a ~4x
-    (int8) or ~8x (int4 nibble-packed) smaller DCN transfer (reference:
-    fused_quantize_into_fp8, quantization.py:531+)."""
-    x2d, n = _pad_blocks(x)
-    q, s = _quantize_rows(x2d, _bits_qmax(bits))
-    if bits == 4:
-        q = _pack_nibbles_jnp(q)
-    return q, s[:, 0], n
+    scales [rows], element count), ``rows`` padded to whole kernel tiles,
+    in one compiled program a shape (:func:`_quantize_leaves`, the program
+    the quantized allreduce runs a bucket). Pull the first two to host for
+    a ~4x (int8) or ~8x (int4 nibble-packed) smaller DCN transfer
+    (reference: fused_quantize_into_fp8, quantization.py:531+)."""
+    q, s = _quantize_leaves([x], bits)
+    return q, s, x.size
 
 
 def fused_quantize_int8(x: jax.Array) -> Tuple[jax.Array, jax.Array, int]:
@@ -152,20 +211,12 @@ def _pad_rows(x: jax.Array) -> jax.Array:
     return jnp.pad(x, pad_widths)
 
 
-def fused_dequantize(
-    q: jax.Array, scales: jax.Array, n: int, bits: int = 8
-) -> jax.Array:
-    """Inverse of :func:`fused_quantize`; returns a flat fp32 array of
-    length ``n``. Accepts host-quantized payloads too (any row count)."""
-    if bits == 4:
-        q = _unpack_nibbles_jnp(jnp.asarray(q).reshape(-1, BLOCK // 2))
-    q = _pad_rows(jnp.asarray(q).reshape(-1, BLOCK))
-    rows = q.shape[0]
-    scales = jnp.asarray(scales).reshape(-1)
-    s2d = jnp.broadcast_to(
-        _pad_rows(scales.reshape(-1, 1)).astype(jnp.float32), (rows, 128)
-    )
-    out = pl.pallas_call(
+@jax.jit
+def _dequantize_rows(q2d: jax.Array, s2d: jax.Array) -> jax.Array:
+    """The dequantize kernel over whole tiles: int8 [rows, BLOCK] times
+    the scales [rows, 128] (a row's scale across its lanes)."""
+    rows = q2d.shape[0]
+    return pl.pallas_call(
         _dequantize_kernel,
         grid=(rows // _TILE,),
         in_specs=[
@@ -175,8 +226,70 @@ def fused_dequantize(
         out_specs=pl.BlockSpec((_TILE, BLOCK), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, BLOCK), jnp.float32),
         interpret=_interpret(),
-    )(q, s2d)
-    return out.reshape(-1)[:n]
+    )(q2d, s2d)
+
+
+def _cut_leaves(
+    flat: jax.Array,
+    scale: "jax.Array | None",
+    shapes: Tuple[Tuple[int, ...], ...],
+    dtypes: Tuple[Any, ...],
+) -> List[jax.Array]:
+    """The leaves of a flat float32 payload: times ``scale`` where one is
+    given, then cut, reshaped and cast, in layout order."""
+    if scale is not None:
+        flat = flat * scale
+    leaves = []
+    offset = 0
+    for shape, dtype in zip(shapes, dtypes):
+        size = math.prod(shape)
+        leaves.append(
+            flat[offset : offset + size].reshape(shape).astype(dtype)
+        )
+        offset += size
+    return leaves
+
+
+cut_leaves = jax.jit(_cut_leaves, static_argnames=("shapes", "dtypes"))
+flatten_leaves = jax.jit(_flat_f32)
+
+
+@functools.partial(jax.jit, static_argnames=("shapes", "dtypes", "bits"))
+def _dequantize_leaves(
+    q: jax.Array,
+    scales: jax.Array,
+    scale: "jax.Array | None",
+    shapes: Tuple[Tuple[int, ...], ...],
+    dtypes: Tuple[Any, ...],
+    bits: int = 8,
+) -> List[jax.Array]:
+    """The way up, one compiled program a bucket layout: the dequantize
+    kernel over all rows of a payload in the wire layout (any shape, one
+    scale a block of it), the result times ``scale`` where one is given
+    (a traced scalar: a quorum that changes size compiles nothing), and
+    the leaves cut, reshaped and cast. The program's outputs are the
+    leaves; no payload-sized array outlives it."""
+    if bits == 4:
+        q = _unpack_nibbles_jnp(q.reshape(-1, BLOCK // 2))
+    q = _pad_rows(q.reshape(-1, BLOCK))
+    s2d = jnp.broadcast_to(
+        _pad_rows(scales.reshape(-1, 1)).astype(jnp.float32),
+        (q.shape[0], 128),
+    )
+    flat = _dequantize_rows(q, s2d).reshape(-1)
+    return _cut_leaves(flat, scale, shapes, dtypes)
+
+
+def fused_dequantize(
+    q: jax.Array, scales: jax.Array, n: int, bits: int = 8
+) -> jax.Array:
+    """Inverse of :func:`fused_quantize`; returns a flat fp32 array of
+    length ``n``. Accepts host-quantized payloads too (any row count).
+    One compiled program a shape (:func:`_dequantize_leaves` with the one
+    leaf ``[n]``)."""
+    return _dequantize_leaves(
+        q, scales, None, shapes=((n,),), dtypes=(jnp.float32,), bits=bits
+    )[0]
 
 
 def fused_dequantize_int8(
@@ -235,27 +348,32 @@ def fused_reduce_int8(
     return qo, so[:, 0]
 
 
-# Elements per quantize-and-pull chunk. Bounds peak device memory at
-# ~5 bytes/elem of extra HBM (padded fp32 copy + int8 + scales) no matter
-# how large the payload: a 500 MB pseudograd otherwise needs >1 GB of
-# transient HBM on top of the train state.
-_TRANSFER_CHUNK = 16 * 1024 * 1024  # 16M elems = 64 MB fp32 per chunk
+# The most elements one program takes down or up. The TPU lays a float32
+# leaf out in (8, 128) tiles of ITS OWN last two dimensions, so the reshape
+# to rows of BLOCK that the kernel reads is a relayout, not a bitcast: a
+# float32 temporary of the payload's size beside the kernel's outputs
+# (compiled for a described v5e: 524 + 131 MB for a 131M-element leaf,
+# whole tiles or not, one dimension or two; PERF.md section 6, PR 40). Up
+# to this size that stands, and a bucket costs one program each way: every
+# bucket DDP makes (capped at 32 MiB but for a single larger leaf), the
+# 131M-element embedding of a 7B model among them. A larger payload is
+# worked through in pieces of this size, so the transient HBM stays
+# ~6 bytes/elem of ONE piece (0.75 GiB) however large the payload.
+_TRANSFER_CHUNK = 128 * 1024 * 1024  # 2**27 elems = 512 MiB fp32
 
 
 def quantize_for_transfer(
-    x: jax.Array, bits: int = 8
+    x: "jax.Array | Sequence[jax.Array]", bits: int = 8
 ) -> Tuple[np.ndarray, np.ndarray, int]:
     """Device-quantize then pull to host: the device->host (and then DCN)
     transfer moves the quantized payload + per-block scales instead of
     fp32. The returned (payload, scales, n) is exactly the layout of
-    ``collectives.quantize_blockwise``, so the receiving host (or device,
-    via :func:`fused_dequantize`) can decode it directly.
+    ``collectives.quantize_blockwise`` of the flat payload (``x``: one
+    array, or the leaves of a bucket in layout order), so the receiving
+    host (or device, via :func:`fused_dequantize`) can decode it directly.
 
-    Composition of the async pair (one implementation of the chunking /
-    trimming logic; tests pin the two paths bit-identical): dispatch all
-    chunk kernels, then pull. Per-chunk double buffering emerges from the
-    same structure — every kernel is enqueued before the first pull
-    blocks."""
+    Composition of the async pair (one implementation; tests pin the two
+    bit-identical): dispatch, then pull."""
     return pull_transfer_chunks(*quantize_for_transfer_async(x, bits), bits)
 
 
@@ -263,24 +381,20 @@ def quantize_for_transfer(
 def _quantize_row(
     flat: jax.Array, row: jax.Array, n_full: int, bits: int = 8
 ):
-    """One full-size chunk: slice + pad + quantize fused in ONE jitted
-    computation (the slice never materializes as a standalone dispatched
-    buffer — with many chunks enqueued at once, per-chunk fp32 slice
-    copies would otherwise sum to a second full-size payload of queued
-    HBM).  The chunk is addressed as a ROW of the (n_full, chunk) view
-    rather than by flat element offset: the traced index stays a small
-    int32 row number, so payloads past 2**31 elements can't silently
-    slice the wrong region (jax x64 is disabled, so a traced element
-    offset would wrap)."""
+    """One full-size chunk of the bounded path: slice + pad + quantize
+    fused in ONE jitted computation (the slice never materializes as a
+    standalone dispatched buffer — with many chunks enqueued at once,
+    per-chunk fp32 slice copies would otherwise sum to a second full-size
+    payload of queued HBM).  The chunk is addressed as a ROW of the
+    (n_full, chunk) view rather than by flat element offset: the traced
+    index stays a small int32 row number, so payloads past 2**31 elements
+    can't silently slice the wrong region (jax x64 is disabled, so a
+    traced element offset would wrap)."""
     body = flat[: n_full * _TRANSFER_CHUNK].reshape(n_full, _TRANSFER_CHUNK)
     piece = jax.lax.dynamic_slice(
         body, (row, 0), (1, _TRANSFER_CHUNK)
     ).reshape(-1)
-    x2d, _ = _pad_blocks(piece)
-    q, s = _quantize_rows(x2d, _bits_qmax(bits))
-    if bits == 4:
-        q = _pack_nibbles_jnp(q)
-    return q, s[:, 0]
+    return _quantize_leaves.__wrapped__([piece], bits, words=True)
 
 
 @functools.partial(jax.jit, static_argnames=("start", "m", "bits"))
@@ -288,22 +402,21 @@ def _quantize_tail(flat: jax.Array, start: int, m: int, bits: int = 8):
     """The final partial chunk. ``start`` is STATIC (one value per flat
     size, so no compile blowup) — a static basic-index slice carries
     64-bit offsets and is safe past 2**31 elements."""
-    piece = flat[start : start + m]
-    x2d, _ = _pad_blocks(piece)
-    q, s = _quantize_rows(x2d, _bits_qmax(bits))
-    if bits == 4:
-        q = _pack_nibbles_jnp(q)
-    return q, s[:, 0]
+    return _quantize_leaves.__wrapped__(
+        [flat[start : start + m]], bits, words=True
+    )
 
 
 def quantize_for_transfer_async(
-    x: jax.Array, bits: int = 8
+    x: "jax.Array | Sequence[jax.Array]", bits: int = 8
 ) -> Tuple[list, int]:
-    """Dispatch-only half of :func:`quantize_for_transfer`: enqueues every
-    chunk's quantize kernel (async — returns as soon as XLA has the work)
-    WITHOUT pulling anything to host. Returns (chunks, n) where chunks is
-    ``[(q, s, m), ...]`` of not-yet-materialized device arrays; finish with
-    :func:`pull_transfer_chunks`, possibly on another thread.
+    """Dispatch-only half of :func:`quantize_for_transfer`: enqueues the
+    quantize program(s) (async — returns as soon as XLA has the work)
+    WITHOUT pulling anything to host. ``x`` is one array or the leaves of
+    a bucket in layout order. Returns (chunks, n) where chunks is
+    ``[(q, s, m), ...]`` of not-yet-materialized device arrays covering
+    the flat payload in order; finish with :func:`pull_transfer_chunks`,
+    possibly on another thread.
 
     Why two halves: the pull blocks until the kernels (and everything
     queued before them) execute. Dispatching the kernels on the CALLER's
@@ -311,17 +424,20 @@ def quantize_for_transfer_async(
     ``x`` — before the caller's next training window — so a deferred pull
     overlaps that window instead of waiting behind it.
 
-    Peak queued HBM beyond the input: the int8+scales outputs (~1.25
-    bytes/elem total — they must coexist anyway, they ARE the payload)
-    plus ONE executing chunk's fp32 intermediates (slice/pad live only
-    inside `_quantize_row`'s execution, not per queued chunk). At most
-    two slice-size compilations exist per flat size (full-chunk rows,
-    where only the row INDEX is traced, + the static tail).
+    One chunk, one cached compiled program (:func:`_quantize_leaves`),
+    up to ``_TRANSFER_CHUNK`` elements. Past it the bounded path, chosen
+    by the payload's size alone: the leaves are joined on the device and
+    worked through in pieces of that size, at most two compilations a
+    flat size (full rows, where only the row INDEX is traced, and the
+    static tail); peak HBM beyond the joined input is the outputs (~1.25
+    bytes/elem: they ARE the payload) plus ONE executing piece's fp32
+    intermediates.
     """
-    flat = x.reshape(-1)
-    n = flat.size
+    leaves = list(x) if isinstance(x, (list, tuple)) else [x]
+    n = sum(a.size for a in leaves)
     if n <= _TRANSFER_CHUNK:
-        return [fused_quantize(flat, bits)], n
+        return [(*_quantize_leaves(leaves, bits, words=True), n)], n
+    flat = flatten_leaves(leaves)
     n_full = n // _TRANSFER_CHUNK
     chunks = []
     for i in range(n_full):
@@ -337,15 +453,26 @@ def quantize_for_transfer_async(
 def pull_transfer_chunks(
     chunks: list, n: int, bits: int = 8
 ) -> Tuple[np.ndarray, np.ndarray, int]:
-    """Pulls the device chunks from :func:`quantize_for_transfer_async` to
-    host, returning the same (q, scales, n) layout — bit-identical — as
-    :func:`quantize_for_transfer`."""
+    """Waits for the device chunks from :func:`quantize_for_transfer_async`
+    on the host, returning the same (q, scales, n) layout — bit-identical —
+    as :func:`quantize_for_transfer`. One chunk (one program) comes back
+    as views of the arrays the transfer wrote, trimmed to whole blocks of
+    ``n``; only the bounded path joins its pieces. The copies of a chunk's
+    payload and scales are asked for together and here, one chunk at a
+    time: copies asked of several payloads at once, or of a program's
+    pending outputs, are not served in the order asked but share the path
+    off the device (PERF.md section 6, PR 39 and PR 40)."""
     bpb = BLOCK // (8 // bits)
     q_parts = []
     s_parts = []
     for i, (q, s, m) in enumerate(chunks):
         blocks = (m + BLOCK - 1) // BLOCK
-        q_parts.append(np.asarray(q).reshape(-1)[: blocks * bpb])
+        q.copy_to_host_async()
+        s.copy_to_host_async()
+        # An int8 payload crosses as 32-bit words (:func:`_as_words`).
+        q_parts.append(
+            np.asarray(q).view(np.int8).reshape(-1)[: blocks * bpb]
+        )
         s_parts.append(np.asarray(s)[:blocks])
         # Release the device buffers as they are consumed: the caller's
         # closure may keep `chunks` alive through the whole wire pipeline,
@@ -363,17 +490,15 @@ def _place_chunk(buf: jax.Array, piece: jax.Array, start) -> jax.Array:
     return jax.lax.dynamic_update_slice(buf, piece, (start,))
 
 
-def dequantize_from_transfer(
-    q: np.ndarray, scales: np.ndarray, n: int, bits: int = 8
+def _dequantize_flat_bounded(
+    q: np.ndarray, scales: np.ndarray, n: int, bits: int
 ) -> jax.Array:
-    """Host quantized payload -> device fp32, chunked like
-    :func:`quantize_for_transfer`: each chunk is dequantized and written
-    (buffer-donated) into a preallocated output, so peak transient HBM is
-    output + one chunk regardless of payload size."""
-    if n <= _TRANSFER_CHUNK:
-        return fused_dequantize(q, scales, n, bits)
+    """The bounded path up: each ``_TRANSFER_CHUNK`` piece is dequantized
+    and written (buffer-donated) into a preallocated output, so peak
+    transient HBM is output + one piece regardless of payload size."""
     bpb = BLOCK // (8 // bits)
     blocks_per_chunk = _TRANSFER_CHUNK // BLOCK
+    q = np.asarray(q).reshape(-1)
     out = jnp.zeros((n,), jnp.float32)
     for start_blk in range(0, (n + BLOCK - 1) // BLOCK, blocks_per_chunk):
         start = start_blk * BLOCK
@@ -386,3 +511,44 @@ def dequantize_from_transfer(
         piece = fused_dequantize(q_piece, s_piece, m, bits)
         out = _place_chunk(out, piece, jnp.asarray(start))
     return out
+
+
+def dequantize_leaves_from_transfer(
+    q: np.ndarray,
+    scales: np.ndarray,
+    shapes: Sequence[Tuple[int, ...]],
+    dtypes: Sequence[Any],
+    scale: float = 1.0,
+    bits: int = 8,
+) -> List[jax.Array]:
+    """Host quantized payload (the wire layout, any shape) -> the leaves
+    of its bucket on the device, each ``float32(q) * its block's scale``
+    times ``scale`` (left out where it is 1), cut at ``shapes`` and cast
+    to ``dtypes``. One host-to-device transfer and one cached compiled
+    program (:func:`_dequantize_leaves`) up to ``_TRANSFER_CHUNK``
+    elements; the bounded path past it. Asynchronously dispatched: the
+    host arrays are held by the transfer until it is done."""
+    shapes = tuple(tuple(int(d) for d in shape) for shape in shapes)
+    dtypes = tuple(jnp.dtype(d) for d in dtypes)
+    n = sum(math.prod(shape) for shape in shapes)
+    factor = None if scale == 1.0 else np.float32(scale)
+    if n <= _TRANSFER_CHUNK:
+        bpb = BLOCK // (8 // bits)
+        q_dev, s_dev = jax.device_put(
+            (np.asarray(q).reshape(-1, bpb), np.asarray(scales))
+        )
+        return _dequantize_leaves(
+            q_dev, s_dev, factor, shapes=shapes, dtypes=dtypes, bits=bits
+        )
+    flat = _dequantize_flat_bounded(q, scales, n, bits)
+    return cut_leaves(flat, factor, shapes=shapes, dtypes=dtypes)
+
+
+def dequantize_from_transfer(
+    q: np.ndarray, scales: np.ndarray, n: int, bits: int = 8
+) -> jax.Array:
+    """Host quantized payload -> device fp32, flat, of length ``n``:
+    :func:`dequantize_leaves_from_transfer` with the one leaf ``[n]``."""
+    return dequantize_leaves_from_transfer(
+        q, scales, [(n,)], [jnp.float32], 1.0, bits
+    )[0]

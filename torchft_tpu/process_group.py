@@ -63,6 +63,13 @@ def _as_list(tensors: Any) -> List[np.ndarray]:
     return [np.asarray(tensors)]
 
 
+def _per_rank_lists(inputs: Sequence[Any]) -> Tuple[bool, List[List[np.ndarray]]]:
+    """``alltoall``'s inputs, one entry a destination rank, each one array
+    or a list of arrays: whether any is a list, and every entry as one."""
+    nested = any(isinstance(x, (list, tuple)) for x in inputs)
+    return nested, [_as_list(x) for x in inputs]
+
+
 # -- per-peer link policy (TORCHFT_LINKS) ------------------------------------
 
 # class -> (connect_ms, io_ms, q8). Streams always default to the engine's
@@ -184,6 +191,9 @@ class ProcessGroup:
         raise NotImplementedError
 
     def alltoall(self, inputs: Sequence[Any]) -> Work:
+        """``inputs``: per destination rank one array, or a list of arrays
+        (as many for every rank) that travel in the one collective.
+        Result: per source rank what it sent here, in the same form."""
         raise NotImplementedError
 
     def barrier(self) -> Work:
@@ -878,23 +888,36 @@ class ProcessGroupSocket(ProcessGroup):
         )
 
     def alltoall(self, inputs: Sequence[Any]) -> Work:
-        arrays = _as_list(inputs)
+        nested, per_rank = _per_rank_lists(inputs)
         tag = self._next_tag()
 
-        def run() -> List[np.ndarray]:
-            if len(arrays) != self._world:
+        def run() -> List[Any]:
+            if len(per_rank) != self._world:
                 raise ValueError(
                     f"alltoall needs one input per rank ({self._world}), "
-                    f"got {len(arrays)}"
+                    f"got {len(per_rank)}"
                 )
-            out: List[Optional[np.ndarray]] = [None] * self._world
-            out[self._rank] = arrays[self._rank].copy()
+            parts = len(per_rank[self._rank])
+            if any(len(p) != parts for p in per_rank):
+                raise ValueError(
+                    "alltoall needs as many arrays for every rank, got "
+                    f"{[len(p) for p in per_rank]}"
+                )
+            # One array a rank keeps the bare tag; a list is tagged as
+            # allgather tags its arrays.
+            tags = [f"{tag}.{i}" for i in range(parts)] if nested else [tag]
+            out: List[Optional[List[np.ndarray]]] = [None] * self._world
+            out[self._rank] = [a.copy() for a in per_rank[self._rank]]
             for peer, conn in self._peers.items():
-                conn.send(tag, arrays[peer])
+                for t, a in zip(tags, per_rank[peer]):
+                    conn.send(t, a)
             for peer, conn in self._peers.items():
-                out[peer] = conn.recv(tag, self._timeout)
-            return out  # type: ignore[return-value]
+                out[peer] = [conn.recv(t, self._timeout) for t in tags]
+            return out if nested else [o[0] for o in out]  # type: ignore[index]
 
+        # No ``nbytes``: the journal's ``pg_collective.nbytes`` of a step
+        # has always been the allgathers' alone, and the benchmark's
+        # ``wire_bytes_step`` reads it so.
         return self._submit(run, op="alltoall", tag=tag)
 
     def barrier(self) -> Work:
@@ -1485,7 +1508,8 @@ class ProcessGroupDummy(ProcessGroup):
         return DummyWork(_as_list(inputs)[0])
 
     def alltoall(self, inputs: Sequence[Any]) -> Work:
-        return DummyWork(_as_list(inputs))
+        nested, per_rank = _per_rank_lists(inputs)
+        return DummyWork(per_rank if nested else [p[0] for p in per_rank])
 
     def barrier(self) -> Work:
         return DummyWork(None)
