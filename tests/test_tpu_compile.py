@@ -267,3 +267,85 @@ def test_ssd_fwd_and_grad_compile_and_lead_with_chunk_laid_results(one_chip):
     assert sorted(c.split(".")[0] for c in calls) == ["ssd_bwd", "ssd_fwd"], calls
     scan = re.compile(ssm_ms.any_of(ssm_ms.scan_patterns(SSD_DIMS)))
     assert all(scan.search(c) for c in calls), calls
+
+
+# -- the lfm2-raw cell: the flash kernel at head width 64 and the whole step --
+
+# (B, S, Hq, Hkv, D) of the cell's one attention layer.
+LFM2_FLASH_SHAPE = (2, 8192, 32, 8, 64)
+ALLOCATOR_BYTES = 15.75 * 2**30  # what the chip's allocator hands out
+
+
+def _custom_calls(text):
+    from benchmark import trace_reduce
+
+    return [
+        trace_reduce.short_name(line.strip().removeprefix("ROOT "))
+        for line in text.splitlines()
+        if "tpu_custom_call" in line and " custom-call(" in line
+    ]
+
+
+def _entry_instructions(text):
+    """The entry computation's instructions as the trace names them."""
+    from benchmark import trace_reduce
+
+    body = text[text.index("\nENTRY "):]
+    return [
+        trace_reduce.short_name(line.strip().removeprefix("ROOT "))
+        for line in body[: body.index("\n}")].splitlines()
+        if line.strip().startswith(("%", "ROOT %")) and " = " in line
+    ]
+
+
+def test_flash_at_head_width_64_compiles_under_the_name_the_metrics_match(one_chip):
+    """Half a lane tile a head, four query heads a key/value head: the
+    blocks take the array's own last dimension. Alone the three kernels
+    are named for the jit around them; inside a step program they are
+    ``flash_attention.N``, which the test below pins."""
+    text = jax.jit(
+        jax.value_and_grad(_flash_loss, argnums=(0, 1, 2))
+    ).lower(*_qkv(one_chip, *LFM2_FLASH_SHAPE)).compile().as_text()
+    calls = _custom_calls(text)
+    assert len(calls) == 3 and all("flash_attention" in c for c in calls), calls
+    assert any("bf16[2,32,8192,64]" in c for c in calls), calls
+
+
+@pytest.mark.timeout(900)
+def test_the_lfm2_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
+    """The whole fused step of ``lfm2-raw`` at the published widths for a
+    described v5e: what the compiler says it needs is under what the
+    allocator gives, the flash kernels (forward, remat's forward, dq, dkv)
+    and the grouped matmuls are in it under the names the metrics match."""
+    import re
+
+    from benchmark import cells
+    from benchmark.metrics import flash_ms, short_conv_ms
+    from benchmark.tests.test_v5e_compile import _programs
+    from torchft_tpu.ops import flash_attention
+
+    monkeypatch.setattr(flash_attention, "_interpret", lambda: False)
+    programs, resident = _programs(cells.load_cell("lfm2-raw"), topo)
+    prog, args = programs["step"]
+    compiled = prog.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert resident == 12 * 507_820_288 + 8  # weights, two moments, two counters
+    assert resident < need < ALLOCATOR_BYTES, need
+    text = compiled.as_text()
+    calls = _custom_calls(text)
+    flash = [c for c in calls if re.search(flash_ms.PATTERN, c)]
+    assert len(flash) == 4 and all("8192,64]" in c for c in flash), flash
+    assert sum(c.startswith("ragged-dot") for c in calls) >= 4 * 9
+    # What ``short_conv_ms`` names of the four mixers, and nothing else of
+    # the step: the float32 B*u of each forward and of remat's forward, and
+    # each backward's stand-alone reduce that leads with the depthwise
+    # kernel's two per-channel sums.
+    entry = _entry_instructions(text)
+    gate, taps_grad = (
+        [i for i in entry if re.search(p, i)]
+        for p in short_conv_ms.patterns({"b": 2, "s": 8192, "h": 2048})[1:]
+    )
+    assert [i.split(".")[0] for i in gate] == ["convert_multiply_fusion"] * 8, gate
+    assert [i.split(".")[0] for i in taps_grad] == ["multiply_reduce_fusion"] * 4, taps_grad

@@ -19,6 +19,8 @@ from torchft_tpu.models.llama import (  # noqa: F401
     llama3_8b,
     llama_debug,
     llama_moe_debug,
+    lfm2_8b_a1b,
+    lfm2_moe_debug,
     llama_small,
     nemotron3_nano,
     nemotron_h_debug,

@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from torchft_tpu.models.mamba2 import Mamba2Config, Mamba2Mixer
+from torchft_tpu.models.mamba2 import Mamba2Config, Mamba2Mixer, conv_kernel_init
 
 Dtype = Any
 
@@ -100,19 +100,27 @@ class LlamaConfig:
     # Router z-loss coefficient: mean_t logsumexp(router logits)^2, sown
     # and averaged over layers like the balance term. 0 = not in the loss.
     router_z_coef: float = 0.0
-    # RMSNorm (learned scale) over the WHOLE query projection and over the
-    # whole key projection, before the split into heads and before RoPE
-    # (OLMoE's form; not a per-head norm).
-    qk_norm: bool = False
+    # True: RMSNorm (learned scale) over the WHOLE query projection and
+    # over the whole key projection, before the split into heads and before
+    # RoPE (OLMoE's form). "head": over each head's ``head_dim`` values, one
+    # learned vector for the queries and one for the keys, shared by the
+    # heads, before RoPE (LFM2's form).
+    qk_norm: Any = False
     # False: attention without rotary embeddings (a hybrid whose
     # state-space layers carry the order, Nemotron-H).
     rope: bool = True
     # A stack of unlike layers, one character a layer, each layer ONE mixer
     # between a pre-norm and the residual add: 'M' a Mamba-2 mixer
-    # (``mamba``), 'E' the expert layer, '*' attention. None: ``num_layers``
-    # scanned blocks of attention + MLP.
+    # (``mamba``), 'E' the expert layer, '*' attention (rotary where
+    # ``rope``), 'C' a gated short convolution of ``SHORT_CONV_TAPS`` taps,
+    # 'D' a dense SwiGLU feed-forward. A model whose published layer is an
+    # operator and a feed-forward is two characters a layer ("CD", "*E").
+    # None: ``num_layers`` scanned blocks of attention + MLP.
     layer_pattern: Optional[str] = None
     mamba: Optional[Mamba2Config] = None
+    # The dense feed-forward's width where it is not the experts'
+    # (``intermediate_size`` is then an expert's). None: one width for both.
+    dense_intermediate_size: Optional[int] = None
     # The dropless expert layer's variants (DeepSeek-V3's router, Nemotron's
     # experts). 'sigmoid': the k experts are the top-k of sigmoid(logits) +
     # a selection bias (a parameter that gets no gradient), the gates those
@@ -122,6 +130,15 @@ class LlamaConfig:
     # every token passes through, added to the routed result.
     router_score: str = "softmax"
     routed_scaling: float = 1.0
+    # What the sigmoid router adds to the chosen scores' sum before it
+    # divides by it (Nemotron's code has none; LFM2's 1e-6).
+    gate_eps: float = 1e-20
+    # > 0: the step moves each expert layer's selection bias after the
+    # optimizer update, out of the gradient (parallel/train.py:
+    # ``make_train_step``; DeepSeek-V3 arXiv:2412.19437 section 2.1.2), by
+    # this much towards the experts the router under-used, and the layer
+    # sows the assignments each expert got (``moe_load``).
+    router_bias_update_rate: float = 0.0
     expert_act: str = "swiglu"
     shared_expert_size: int = 0
     # (first, count): this layer HOLDS experts first .. first+count-1 of
@@ -249,6 +266,76 @@ def nemotron_h_debug(**overrides: Any) -> LlamaConfig:
     return dataclasses.replace(cfg, **overrides)
 
 
+def lfm2_8b_a1b(**overrides: Any) -> LlamaConfig:
+    """LFM2-8B-A1B (LiquidAI/LFM2-8B-A1B config.json, model_type
+    ``lfm2_moe``) at its published sizes: 24 layers of an operator and a
+    feed-forward each (18 gated short convolutions of 3 taps, 6 rotary GQA
+    attentions of 32 heads on 8 at head width 64 with per-head QK norms;
+    two leading dense SwiGLU feed-forwards of width 7168, then 32 SiLU-gated
+    experts of width 1792, 4 a token, sigmoid router with a selection bias
+    the step updates), tied head. 8.34B parameters, 1.56B active: override
+    ``layer_pattern``, ``experts_held`` and ``vocab_size`` for what one
+    chip holds. ``num_layers`` counts the published layers, the pattern
+    their sub-layers. The bias update's rate is DeepSeek-V3's (LFM2's is
+    not published)."""
+    # Two characters a published layer: its operator ('*' at the six
+    # attention layers, 'C' elsewhere), then its feed-forward ('D' in the
+    # two leading layers, 'E' after).
+    pattern = "".join(
+        ("*" if i in (2, 6, 10, 14, 18, 21) else "C") + ("D" if i < 2 else "E")
+        for i in range(24)
+    )
+    cfg = LlamaConfig(
+        vocab_size=65536,
+        hidden_size=2048,
+        intermediate_size=1792,
+        dense_intermediate_size=7168,
+        num_layers=24,
+        num_heads=32,
+        num_kv_heads=8,
+        head_dim=64,
+        max_seq_len=128000,
+        rope_theta=1e6,
+        norm_eps=1e-5,
+        tie_embeddings=True,
+        qk_norm="head",
+        layer_pattern=pattern,
+        num_experts=32,
+        num_experts_per_tok=4,
+        expert_capacity_factor=None,
+        router_score="sigmoid",
+        routed_scaling=1.0,
+        gate_eps=1e-6,
+        router_aux_coef=0.0,
+        router_z_coef=0.0,
+        router_bias_update_rate=1e-3,
+    )
+    return dataclasses.replace(cfg, **overrides)
+
+
+def lfm2_moe_debug(**overrides: Any) -> LlamaConfig:
+    """Tiny LFM2 (published layers 1-5: conv + dense, attention + experts,
+    three of conv + experts; 16 experts of which 4 are held) for tests and
+    ``train_hsdp.py --model lfm2_moe``."""
+    cfg = lfm2_8b_a1b(
+        vocab_size=256,
+        hidden_size=64,
+        intermediate_size=48,
+        dense_intermediate_size=160,
+        num_layers=5,
+        layer_pattern="CD*ECECECE",
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        max_seq_len=128,
+        num_experts=16,
+        num_experts_per_tok=3,
+        experts_held=(0, 4),
+        remat=False,
+    )
+    return dataclasses.replace(cfg, **overrides)
+
+
 def llama_moe_debug(**overrides: Any) -> LlamaConfig:
     """Tiny MoE config (4 experts, top-2) for tests and the ep dryrun."""
     cfg = llama_debug(num_experts=4, num_experts_per_tok=2)
@@ -349,7 +436,12 @@ class Attention(nn.Module):
         q = dense(cfg.num_heads, "wq")(x)
         k = dense(cfg.num_kv_heads, "wk")(x)
         v = dense(cfg.num_kv_heads, "wv")(x)
-        if cfg.qk_norm:
+        if cfg.qk_norm == "head":
+            per_head = lambda name: RMSNorm(  # noqa: E731
+                cfg.norm_eps, cfg.param_dtype, name=name
+            )
+            q, k = per_head("q_norm")(q), per_head("k_norm")(k)
+        elif cfg.qk_norm:
             whole = lambda t, name: RMSNorm(  # noqa: E731
                 cfg.norm_eps, cfg.param_dtype, name=name
             )(t.reshape(*t.shape[:2], -1)).reshape(t.shape)
@@ -407,8 +499,9 @@ class MLP(nn.Module):
             param_dtype=cfg.param_dtype,
             name=name,
         )
-        gate = proj(cfg.intermediate_size, "gate")(x)
-        up = proj(cfg.intermediate_size, "up")(x)
+        width = cfg.dense_intermediate_size or cfg.intermediate_size
+        gate = proj(width, "gate")(x)
+        up = proj(width, "up")(x)
         return proj(cfg.hidden_size, "down")(nn.silu(gate) * up)
 
 
@@ -558,7 +651,9 @@ class MoEMLP(nn.Module):
     ``moe_max_load`` (largest expert's assignments over the mean),
     ``moe_dropped`` (assignments not computed) and, from a layer that
     holds a share, ``moe_held_share`` (the share of all assignments that
-    landed on it). The reference has no MoE/EP anywhere (SURVEY.md §2.3).
+    landed on it); where ``router_bias_update_rate`` > 0, ``moe_load`` (the
+    assignments each of the E experts got, a vector). The reference has no
+    MoE/EP anywhere (SURVEY.md §2.3).
     """
 
     cfg: LlamaConfig
@@ -593,7 +688,7 @@ class MoEMLP(nn.Module):
                 _, gate_idx = jax.lax.top_k(scores + bias.astype(jnp.float32), K)
                 gate_vals = jnp.take_along_axis(scores, gate_idx, axis=-1)
                 gate_vals = cfg.routed_scaling * (
-                    gate_vals / (gate_vals.sum(-1, keepdims=True) + 1e-20)
+                    gate_vals / (gate_vals.sum(-1, keepdims=True) + cfg.gate_eps)
                 )
                 # The balance term's P_e: the scores as shares of their sum.
                 probs = scores / scores.sum(-1, keepdims=True)
@@ -680,6 +775,14 @@ class MoEMLP(nn.Module):
         self.sow("intermediates", "moe_max_load", load.max() * E / load.sum())
         self.sow("intermediates", "moe_dropped", dropped)
 
+    def _sow_load(self, load) -> None:
+        """``moe_load`` [E]: the assignments each of ALL the experts got
+        from this step's tokens, held here or not, for the step's
+        selection-bias update. Only a model that asks for the update sows
+        it, so that the others' programs stay what they were."""
+        if self.cfg.router_bias_update_rate:
+            self.sow("intermediates", "moe_load", load)
+
     def _sorted(self, x, probs, gate_vals, gate_idx, weights):
         cfg = self.cfg
         E, K, H = cfg.num_experts, cfg.num_experts_per_tok, x.shape[-1]
@@ -692,6 +795,7 @@ class MoEMLP(nn.Module):
         )
         load = group_sizes.astype(jnp.float32)
         self._sow_routing(probs, load, load / (T * K), jnp.zeros(()))
+        self._sow_load(load)
 
         order = jnp.argsort(flat_idx, stable=True)  # sorted row -> assignment
         inv = jnp.argsort(order)  # assignment -> sorted row
@@ -751,6 +855,7 @@ class MoEMLP(nn.Module):
             )
             self.sow("intermediates", "moe_dropped", load.sum() - n_fit)
             self.sow("intermediates", "moe_held_share", load.sum() / (T * K))
+            self._sow_load(all_sizes.astype(jnp.float32))
 
             local = flat_idx - first
             key = jnp.where((local >= 0) & (local < count), local, count)
@@ -830,6 +935,57 @@ class Block(nn.Module):
         return x
 
 
+# The gated short convolution's taps (LFM2's ``conv_L_cache``): one value
+# is in use, so it is a constant and no field of the configuration.
+SHORT_CONV_TAPS = 3
+_SHORT_CONV_NOTED: set = set()
+
+
+def _note_short_conv(taps: int, seq_len: int) -> None:
+    """Says once per (taps, seq_len), at trace time, which form of the
+    gated short convolution a step took (plain XLA is the only one)."""
+    key = (taps, seq_len)
+    if key not in _SHORT_CONV_NOTED:
+        _SHORT_CONV_NOTED.add(key)
+        logger.info("short_conv: traced=xla taps=%d seq=%d", taps, seq_len)
+
+
+class ShortConvMixer(nn.Module):
+    """LFM2's gated short convolution (``Lfm2ShortConv``): for x [B, S, H]
+
+        [b | c | u] = in_proj(x)                   H -> 3H, no bias
+        w_t = sum_j k_j * (b * u)_{t-(L-1)+j}      depthwise, causal, L taps,
+                                                   zero before the sequence
+        out = out_proj(c * w)                      H -> H
+
+    No activation and no state beyond L-1 positions. The projections run
+    in the compute type; both gates and the taps are float32 products
+    (one elementwise pass between the two matmuls for XLA to fuse)."""
+
+    cfg: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        cfg, f32 = self.cfg, jnp.float32
+        taps, seq, h = SHORT_CONV_TAPS, x.shape[1], x.shape[-1]
+        dense = lambda f, name: nn.Dense(  # noqa: E731
+            f, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            name=name,
+        )
+        _note_short_conv(taps, seq)
+        b, c, u = jnp.split(dense(3 * h, "in_proj")(x), 3, axis=-1)
+        kernel = self.param(
+            "conv_kernel", conv_kernel_init(taps), (taps, h), cfg.param_dtype
+        )
+        with jax.named_scope("short_conv/gated"):
+            v = jnp.pad(
+                b.astype(f32) * u.astype(f32), ((0, 0), (taps - 1, 0), (0, 0))
+            )
+            w = sum(v[:, j : j + seq] * kernel[j].astype(f32) for j in range(taps))
+            y = (c.astype(f32) * w).astype(cfg.dtype)
+        return dense(h, "out_proj")(y)
+
+
 class MixerLayer(nn.Module):
     """One layer of a ``layer_pattern`` stack: x + mixer(RMSNorm(x)), the
     mixer named for its kind so that the sharding rules find it."""
@@ -838,7 +994,10 @@ class MixerLayer(nn.Module):
     kind: str
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(
+        self, x: jax.Array,
+        cos: Optional[jax.Array] = None, sin: Optional[jax.Array] = None,
+    ) -> jax.Array:
         cfg = self.cfg
         h = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="norm")(x)
         if self.kind == "M":
@@ -848,11 +1007,15 @@ class MixerLayer(nn.Module):
             )(h)
         if self.kind == "E":
             return x + MoEMLP(cfg, name="mlp")(h)
+        if self.kind == "D":
+            return x + MLP(cfg, name="mlp")(h)
+        if self.kind == "C":
+            return x + ShortConvMixer(cfg, name="conv")(h)
         if self.kind == "*":
-            if cfg.rope:
-                raise ValueError("a pattern stack's attention takes no rotary embedding")
-            return x + Attention(cfg, name="attn")(h, None, None)
-        raise ValueError(f"layer kind {self.kind!r} is none of 'M', 'E', '*'")
+            return x + Attention(cfg, name="attn")(h, cos, sin)
+        raise ValueError(
+            f"layer kind {self.kind!r} is none of 'M', 'E', 'D', 'C', '*'"
+        )
 
 
 class _ScanBlock(Block):
@@ -899,8 +1062,16 @@ class Transformer(nn.Module):
             # otherwise merge the recomputation with the forward pass and
             # keep every layer's activations: prevent_cse stays on).
             layer = nn.remat(MixerLayer) if cfg.remat else MixerLayer
+            # Only a rotary attention layer is handed the tables: the
+            # other kinds' calls (and a rope-free stack's) stay as they were.
+            rotary = (
+                rope_table(positions, cfg.head_dim, cfg.rope_theta, cfg.dtype)
+                if cfg.rope else ()
+            )
             for i, kind in enumerate(cfg.layer_pattern):
-                x = layer(cfg, kind, name=f"layers_{i}")(x)
+                x = layer(cfg, kind, name=f"layers_{i}")(
+                    x, *(rotary if kind == "*" else ())
+                )
             return self._head(embed, x, return_hidden)
         cos, sin = rope_table(positions, cfg.head_dim, cfg.rope_theta, cfg.dtype)
 

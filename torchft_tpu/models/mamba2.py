@@ -177,7 +177,7 @@ def _a_log_init(key, shape, dtype=jnp.float32):
     return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)).astype(dtype)
 
 
-def _conv_init(kernel_len: int):
+def conv_kernel_init(kernel_len: int):
     """Uniform within 1/sqrt(fan-in); a depthwise kernel's fan-in is its
     length (the published implementation's default for kernel and bias)."""
     bound = kernel_len ** -0.5
@@ -212,7 +212,7 @@ class Mamba2Mixer(nn.Module):
         z, xbc, dt = jnp.split(zxbcdt, [m.d_inner, m.d_inner + m.conv_dim], axis=-1)
 
         with jax.named_scope("mamba2/conv"):
-            conv_init = _conv_init(m.conv_kernel)
+            conv_init = conv_kernel_init(m.conv_kernel)
             kernel = self.param(
                 "conv_kernel", conv_init, (m.conv_kernel, m.conv_dim), self.param_dtype
             )

@@ -44,7 +44,7 @@ from torchft_tpu.models.llama import (
     Transformer,
     rope_table,
 )
-from torchft_tpu.parallel.sharding import _path_keys, tree_specs_like
+from torchft_tpu.parallel.sharding import path_keys, tree_specs_like
 from torchft_tpu.parallel.train import TrainState, default_optimizer
 
 
@@ -93,7 +93,7 @@ def pipeline_param_specs(params: Any) -> Any:
     pipeline composes with dp on the batch, not with fsdp/tp, in this v1)."""
 
     def spec(path, leaf):
-        keys = _path_keys(path)
+        keys = path_keys(path)
         if "layers" in keys:
             return P(*(("pp",) + (None,) * (leaf.ndim - 1)))
         return P()
@@ -238,7 +238,7 @@ def init_pipeline_state(
     spec_dict = {}
 
     def record(path, spec):
-        spec_dict[_path_keys(path)] = spec
+        spec_dict[path_keys(path)] = spec
 
     jax.tree_util.tree_map_with_path(
         record, p_specs, is_leaf=lambda x: isinstance(x, P)

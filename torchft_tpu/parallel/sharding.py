@@ -46,6 +46,10 @@ _RULES: Dict[Tuple[str, str], Tuple[Any, ...]] = {
     ("shared_down", "kernel"): ("tp", "fsdp"),
     ("in_proj", "kernel"): ("fsdp", "tp"),
     ("out_proj", "kernel"): ("tp", "fsdp"),
+    # The gated short-convolution mixer (``conv``) has the same two names:
+    # its in_proj's [H, 3H] output splits into thirds that GSPMD re-shards
+    # where ``tp`` cuts across them; its depthwise ``conv_kernel`` [taps, H]
+    # is per channel and replicates, as the Mamba-2 mixer's does.
 }
 
 
@@ -58,7 +62,7 @@ def _spec_for(path: Tuple[str, ...], ndim: int) -> P:
     return P(*((None,) * pad + tuple(rule)))
 
 
-def _path_keys(path) -> Tuple[str, ...]:
+def path_keys(path) -> Tuple[str, ...]:
     keys = []
     for entry in path:
         if hasattr(entry, "key"):
@@ -74,7 +78,7 @@ def param_specs(params: Any) -> Any:
     """Pytree of PartitionSpec matching ``params`` (works on real arrays or
     ShapeDtypeStructs)."""
     return jax.tree_util.tree_map_with_path(
-        lambda path, leaf: _spec_for(_path_keys(path), leaf.ndim), params
+        lambda path, leaf: _spec_for(path_keys(path), leaf.ndim), params
     )
 
 
@@ -95,7 +99,7 @@ def tree_specs_like(tree: Any, params_spec_by_path: Dict[Tuple[str, ...], P]) ->
     that param's spec; everything else (counts, scalars) replicates."""
 
     def lookup(path, leaf):
-        keys = _path_keys(path)
+        keys = path_keys(path)
         for start in range(len(keys)):
             suffix = keys[start:]
             if suffix in params_spec_by_path:
@@ -109,7 +113,7 @@ def params_spec_dict(params: Any) -> Dict[Tuple[str, ...], P]:
     out: Dict[Tuple[str, ...], P] = {}
 
     def record(path, leaf):
-        out[_path_keys(path)] = _spec_for(_path_keys(path), leaf.ndim)
+        out[path_keys(path)] = _spec_for(path_keys(path), leaf.ndim)
         return leaf
 
     jax.tree_util.tree_map_with_path(record, params)

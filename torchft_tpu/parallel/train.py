@@ -11,6 +11,7 @@ reference composes with (SURVEY.md §2.3) — here it is in-repo.
 from __future__ import annotations
 
 import dataclasses
+import logging
 from typing import Any, Callable, Optional, Tuple
 
 import jax
@@ -24,8 +25,11 @@ from torchft_tpu.parallel.sharding import (
     batch_sharding,
     param_specs,
     params_spec_dict,
+    path_keys,
     tree_specs_like,
 )
+
+logger = logging.getLogger(__name__)
 
 
 @jax.tree_util.register_dataclass
@@ -163,7 +167,44 @@ _SOWN_OVER_LAYERS = (
     ("moe_max_load", jnp.max),
     ("moe_dropped", jnp.sum),
     ("moe_held_share", jnp.mean),
+    # Not a scalar: every expert's assignments, layer after layer in the
+    # parameter tree's order, for the selection-bias update of
+    # ``make_train_step`` or of a loop around ``make_grad_step`` (sown
+    # only by a model that asks for it).
+    ("moe_load", lambda loads: loads),
 )
+
+
+def update_router_bias(params, old_params, loads, rate: float):
+    """``params`` with every ``router_bias`` leaf set to its value in
+    ``old_params`` plus ``rate * sign(mean load - load_e)``: the selection
+    bias moves towards the experts the router under-used, out of the
+    gradient (arXiv:2408.15664; DeepSeek-V3 arXiv:2412.19437 section 2.1.2).
+    ``loads`` holds each expert layer's ``moe_load``, layer after layer in
+    the tree's order (what ``_apply_with_aux`` gathers). Written from the
+    OLD value: AdamW's weight decay, which would pull a bias that gets no
+    gradient back towards zero every step, does not touch it."""
+    at = 0
+
+    def leaf(path, new, old):
+        nonlocal at
+        if path_keys(path)[-1] != "router_bias":
+            return new
+        load = loads[at : at + old.size].reshape(old.shape)
+        at += old.size
+        under = jnp.sign(load.mean(axis=-1, keepdims=True) - load)
+        return old + rate * under.astype(old.dtype)
+
+    return jax.tree_util.tree_map_with_path(leaf, params, old_params)
+
+
+def router_bias_abs_max(params) -> jax.Array:
+    """The largest |selection bias| over the expert layers."""
+    return jnp.max(jnp.stack([
+        jnp.max(jnp.abs(leaf))
+        for path, leaf in jax.tree_util.tree_leaves_with_path(params)
+        if path_keys(path)[-1] == "router_bias"
+    ]))
 
 
 def _loss_and_metrics(model: Transformer, params, inputs, targets, mask):
@@ -172,9 +213,11 @@ def _loss_and_metrics(model: Transformer, params, inputs, targets, mask):
     the load-balancing term and ``router_z_coef`` x the router z-loss,
     each a mean over the layers. The metrics are {} for a dense model,
     else ``router_aux``, ``router_z``, ``moe_max_load`` (worst layer),
-    ``moe_dropped`` (assignments not computed, all layers) and, from
-    layers that hold a share of their experts, ``moe_held_share`` (the
-    share of all assignments that landed on held experts)."""
+    ``moe_dropped`` (assignments not computed, all layers), from
+    layers that hold a share of their experts ``moe_held_share`` (the
+    share of all assignments that landed on held experts) and, from a
+    model whose step updates its selection biases, the vector
+    ``moe_load``."""
     B, S = inputs.shape
     C = min(_LOSS_CHUNK, S)
     mask_f = mask.astype(jnp.float32)
@@ -244,6 +287,10 @@ def make_train_step(
     Returns jitted (state, batch) -> (state, metrics): ``loss``,
     ``grad_norm`` and, for a model with experts, the router metrics of
     ``_loss_and_metrics`` (means over the microbatches when accumulating).
+    A model with ``router_bias_update_rate`` > 0 gets its selection biases
+    moved after the optimizer update (``update_router_bias``, from this
+    step's ``moe_load``, summed over the microbatches) and
+    ``router_bias_abs_max`` in the metrics in ``moe_load``'s place.
 
     ``accum_steps > 1`` runs gradient accumulation: the global batch is
     split into ``accum_steps`` microbatches along the batch dim and
@@ -314,11 +361,20 @@ def make_train_step(
             inv = 1.0 / accum_steps
             grads = jax.tree_util.tree_map(lambda g: g * inv, gsum)
             loss = loss_sum * inv
-            router = {k: v.mean() for k, v in routers.items()}
+            router = {
+                k: v.sum(axis=0) if k == "moe_load" else v.mean()
+                for k, v in routers.items()
+            }
         updates, opt_state = optimizer.update(
             grads, state.opt_state, state.params
         )
         params = optax.apply_updates(state.params, updates)
+        if "moe_load" in router:
+            params = update_router_bias(
+                params, state.params, router.pop("moe_load"),
+                model.cfg.router_bias_update_rate,
+            )
+            router["router_bias_abs_max"] = router_bias_abs_max(params)
         gnorm = optax.global_norm(grads)
         new_state = TrainState(
             step=state.step + 1, params=params, opt_state=opt_state
@@ -342,9 +398,26 @@ def make_grad_step(
     """(params, batch) -> (loss, grads): the DDP variant where the optimizer
     update is applied *after* the Manager's outer-axis gradient allreduce.
     ``with_metrics``: ((loss, router metrics), grads), the metrics those
-    of ``_loss_and_metrics`` ({} for a dense model)."""
+    of ``_loss_and_metrics`` ({} for a dense model).
+
+    A model with ``router_bias_update_rate`` > 0 hands its loads out in
+    the metrics (``moe_load``, a vector): the loop takes them out, reduces
+    them over the replicas with the gradients and gives them to
+    ``update_router_bias`` after its own optimizer update, as
+    ``train_hsdp.py`` does. Without ``with_metrics`` no load leaves the
+    step, so a loop built on it would train that model with its selection
+    biases standing still: said once here, as a warning."""
     bsh = batch_sharding(mesh)
     batch_sh = {"inputs": bsh, "targets": bsh, "mask": bsh}
+    if model.cfg.router_bias_update_rate and not with_metrics:
+        logger.warning(
+            "make_grad_step(with_metrics=False) for a model with "
+            "router_bias_update_rate=%g: the step returns no moe_load, so "
+            "whatever applies its gradients cannot move the selection "
+            "biases (ask for the metrics and call update_router_bias; a "
+            "caller that only compares loss and gradients loses nothing)",
+            model.cfg.router_bias_update_rate,
+        )
 
     def fn(params, batch):
         return jax.value_and_grad(

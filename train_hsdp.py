@@ -34,7 +34,7 @@ def main() -> int:
     parser.add_argument("--steps", type=int, default=20)
     parser.add_argument(
         "--model",
-        choices=["debug", "small", "moe", "olmoe", "nemotron_h", "pipeline"],
+        choices=["debug", "small", "moe", "olmoe", "nemotron_h", "lfm2_moe", "pipeline"],
         default="debug",
     )
     parser.add_argument("--batch", type=int, default=8)
@@ -99,6 +99,7 @@ def main() -> int:
     from torchft_tpu.device_mesh import ft_init_device_mesh
     from torchft_tpu.manager import Manager
     from torchft_tpu.models import (
+        lfm2_moe_debug,
         llama_debug,
         llama_moe_debug,
         llama_small,
@@ -111,6 +112,8 @@ def main() -> int:
         default_optimizer,
         init_train_state,
         make_grad_step,
+        router_bias_abs_max,
+        update_router_bias,
     )
     from torchft_tpu.process_group import make_process_group
 
@@ -179,6 +182,15 @@ def main() -> int:
             # MEMEM*EME at test widths. models.nemotron3_nano() is the
             # published model, for a deployment's meshes.
             "nemotron_h": nemotron_h_debug,
+            # The small preset of LFM2's stack (two sub-layers a layer:
+            # gated short convolutions, rotary attention with per-head QK
+            # norms, a dense feed-forward, SiLU-gated experts of which a
+            # share is held, a tied head): pattern CD*ECECECE at test
+            # widths; models.lfm2_8b_a1b() is the published model. Its
+            # selection biases move here as in make_train_step: the step's
+            # loads ride the replica allreduce beside the gradients and
+            # apply_step updates the biases after the optimizer.
+            "lfm2_moe": lfm2_moe_debug,
         }[args.model]()
         if args.attn != "default":
             import dataclasses
@@ -208,15 +220,25 @@ def main() -> int:
                            tokens_per_step=B * S)
         del probe
 
-    def apply_fn(params, opt_state, grads):
+    def apply_fn(params, opt_state, grads, loads):
         import optax
 
         updates, opt_state = optimizer.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt_state
+        new = optax.apply_updates(params, updates)
+        if loads is not None:
+            # A model whose recipe moves its routers' selection biases out
+            # of the gradient: from the replicas' mean loads, which has the
+            # sign of their sum.
+            new = update_router_bias(
+                new, params, loads, cfg.router_bias_update_rate
+            )
+        return new, opt_state
 
     apply_step = jax.jit(
         apply_fn,
-        in_shardings=(shardings.params, shardings.opt_state, shardings.params),
+        in_shardings=(
+            shardings.params, shardings.opt_state, shardings.params, None,
+        ),
         out_shardings=(shardings.params, shardings.opt_state),
     )
 
@@ -351,8 +373,12 @@ def main() -> int:
             }
             # inner: compiled HSDP; `router` is {} for a model with no experts
             (loss, router), grads = grad_step(params, batch)
-            grads = mm.allreduce_grads(
-                grads,
+            # Every expert's assignments, where the model's step updates
+            # its selection biases (None elsewhere): they are averaged
+            # over the replicas in the gradients' own collective.
+            loads = router.pop("moe_load", None)
+            grads, loads = mm.allreduce_grads(
+                (grads, loads),
                 should_quantize=args.quantize,
                 quantize_bits=args.quantize_bits
             )  # outer: FT replica axis over DCN
@@ -362,10 +388,16 @@ def main() -> int:
             with manager.fenced_state_dict():
                 committed = manager.should_commit()
                 if committed:
-                    params, opt_state = apply_step(params, opt_state, grads)
+                    params, opt_state = apply_step(
+                        params, opt_state, grads, loads
+                    )
             if committed:
                 losses.append(float(loss))
                 router = {k: float(v) for k, v in router.items()}
+                if loads is not None:
+                    router["router_bias_abs_max"] = float(
+                        router_bias_abs_max(params)
+                    )
                 logging.info(
                     "[group %s] step %d loss %.4f participants %d%s%s",
                     group, step, losses[-1], mm.replica_size(),
