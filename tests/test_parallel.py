@@ -290,7 +290,7 @@ def test_dryrun_multichip_driver_budget(tmp_path):
     )
 
 
-def test_chunked_loss_matches_full_logits_loss():
+def test_chunked_loss_matches_full_logits_loss(monkeypatch):
     """The chunked vocab-projection loss must match the plain full-logits
     loss — tied and untied heads, fp32 (tied computes fp32 like
     embed.attend; untied computes in cfg.dtype like Dense)."""
@@ -301,8 +301,11 @@ def test_chunked_loss_matches_full_logits_loss():
 
     from torchft_tpu.models import Transformer
     from torchft_tpu.models.llama import llama_debug
+    from torchft_tpu.parallel import train
     from torchft_tpu.parallel.train import _loss_fn
 
+    # Two chunks a row: the rule from the shapes gives these sizes one.
+    monkeypatch.setattr(train, "_LOSS_CHUNK", 128)
     for tied in (False, True):
         cfg = llama_debug(
             max_seq_len=256, dtype=jnp.float32, tie_embeddings=tied,
@@ -326,7 +329,7 @@ def test_chunked_loss_matches_full_logits_loss():
         )
 
 
-def test_chunked_loss_matches_full_logits_loss_bf16_tied():
+def test_chunked_loss_matches_full_logits_loss_bf16_tied(monkeypatch):
     """bf16 + tied embeddings: the chunked head must compute in cfg.dtype
     exactly like flax Embed.attend (which promotes query AND embedding to
     dtype), so both loss paths agree to bf16 tolerance."""
@@ -337,8 +340,10 @@ def test_chunked_loss_matches_full_logits_loss_bf16_tied():
 
     from torchft_tpu.models import Transformer
     from torchft_tpu.models.llama import llama_debug
+    from torchft_tpu.parallel import train
     from torchft_tpu.parallel.train import _loss_fn
 
+    monkeypatch.setattr(train, "_LOSS_CHUNK", 128)  # two chunks a row
     cfg = llama_debug(
         max_seq_len=256, dtype=jnp.bfloat16, tie_embeddings=True, remat=False
     )

@@ -286,16 +286,40 @@ def _custom_calls(text):
     ]
 
 
-def _entry_instructions(text):
-    """The entry computation's instructions as the trace names them."""
+def _computations(text):
+    """name -> (header line, whole block) of every computation of a
+    compiled module's text."""
+    import re
+
+    return {
+        m.group(1): (m.group(0).split("\n", 1)[0], m.group(0))
+        for m in re.finditer(r"^(?:ENTRY )?%(\S+) \(.*?\n\}", text, re.M | re.S)
+    }
+
+
+def _instructions(block, running=False):
+    """A computation's instructions as the trace names them; ``running``:
+    without those that are no event of a trace (a tuple's element, a
+    parameter, a bitcast)."""
+    import re
+
     from benchmark import trace_reduce
 
-    body = text[text.index("\nENTRY "):]
+    idle = re.compile(r"\} (?:get-tuple-element|parameter|bitcast|constant|tuple)\(")
     return [
         trace_reduce.short_name(line.strip().removeprefix("ROOT "))
-        for line in body[: body.index("\n}")].splitlines()
+        for line in block.splitlines()[1:]
         if line.strip().startswith(("%", "ROOT %")) and " = " in line
+        and not (running and idle.search(line))
     ]
+
+
+def _entry_instructions(text):
+    """The entry computation's instructions as the trace names them."""
+    return _instructions(next(
+        block for head, block in _computations(text).values()
+        if head.startswith("ENTRY")
+    ))
 
 
 def test_flash_at_head_width_64_compiles_under_the_name_the_metrics_match(one_chip):
@@ -349,3 +373,107 @@ def test_the_lfm2_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
     )
     assert [i.split(".")[0] for i in gate] == ["convert_multiply_fusion"] * 8, gate
     assert [i.split(".")[0] for i in taps_grad] == ["multiply_reduce_fusion"] * 4, taps_grad
+
+
+# -- the head and loss: one pass a chunk, in the programs of two cells --------
+
+
+# cell, program, the most its temporaries may take. ``mistral-ft1`` holds
+# 13.85 GiB of live buffers beside its grad program: the bound is what the
+# parent's grad program (the checkpointed scan over chunks of 128, PR 41)
+# needed. ``internlm2-raw``'s step program with its state has to fit what
+# the allocator gives.
+HEAD_LOSS_PROGRAMS = [
+    ("mistral-ft1", "grad", 2_258_315_264),
+    ("internlm2-raw", "step", None),
+]
+
+
+@pytest.mark.timeout(600)
+@pytest.mark.parametrize(
+    "name, program, temp_bound", HEAD_LOSS_PROGRAMS, ids=lambda v: str(v)
+)
+def test_head_and_loss_is_three_vocabulary_wide_matmuls_in_one_loop_and_fits(
+    topo, monkeypatch, name, program, temp_bound
+):
+    """The compiled program holds three matmuls with a vocabulary-sized
+    dimension (logits, dh, dW), all called from one ``while`` body: no
+    logits recomputed, nothing vocabulary-wide in the backward pass. No
+    ``copy`` or ``transpose`` materialises a head-shaped [H,V] or a
+    transposed [V,rows] tensor. ``head_loss_ms``'s patterns name that
+    body's five operations and, outside it, only tensors shaped like the
+    head or like the hidden states laid out by chunk."""
+    import re
+
+    from benchmark import cells
+    from benchmark.metrics import head_loss_ms
+    from benchmark.tests.test_v5e_compile import _programs
+    from torchft_tpu.ops import flash_attention
+    from torchft_tpu.parallel.train import loss_chunk
+
+    monkeypatch.setattr(flash_attention, "_interpret", lambda: False)
+    cell = cells.load_cell(name)
+    b, s = int(cell.mix["batch"]), int(cell.mix["seq"])
+    h, v = cell.config["hidden_size"], cell.config["vocab_size"]
+    c = loss_chunk(b, s, v)
+    assert b * c == 2048
+    programs, resident = _programs(cell, topo)
+    prog, args = programs[program]
+    compiled = prog.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    if temp_bound is not None:
+        assert mem.temp_size_in_bytes <= temp_bound, mem.temp_size_in_bytes
+    else:
+        assert resident < need < ALLOCATOR_BYTES, need
+
+    text = compiled.as_text()
+    comps = _computations(text)
+    wide_dim = re.compile(rf"[\[,]{v}[,\]]")
+    matmuls = [
+        n for n, (head, block) in comps.items()
+        if " convolution(" in block and wide_dim.search(head)
+    ]
+    assert len(matmuls) == 3, matmuls
+    callers = {
+        caller for n in matmuls for caller, (_, block) in comps.items()
+        if re.search(rf"calls=%{re.escape(n)}[,)\s]", block)
+    }
+    loop_bodies = set(re.findall(r"body=%(\S+?)[,)\s]", text))
+    assert len(callers) == 1 and callers <= loop_bodies, callers
+
+    moved = re.compile(
+        rf"^\S*(?:copy|transpose)\S* \(?\w+\[(?:{h},{v}|{v},{h}|{v},{b * c}|{v},{b},{c})\]"
+    )
+    everything = [i for _, block in comps.values() for i in _instructions(block)]
+    assert not [i for i in everything if moved.search(i)]
+
+    named = re.compile("|".join(
+        f"(?:{p})" for p in head_loss_ms.patterns({"b": b, "s": s, "h": h, "v": v})
+    ))
+    (body,) = callers
+    r, n = b * c, s // c
+
+    def named_of(block):
+        return [i for i in _instructions(block, running=True) if named.search(i)]
+
+    in_body = named_of(comps[body][1])
+    assert sorted(re.sub(r"\.\d+ ", " ", i).split("{")[0] for i in in_body) == sorted([
+        f"fusion (f32[{r}]",  # logits, with the rows' maxima
+        f"fusion (f32[{r}]",  # sum of exponentials, the target's logit
+        f"fusion bf16[{r},{v}]",  # the logits' gradient, written out once
+        f"fusion bf16[{n},{r},{h}]",  # dh
+        f"convolution_add_fusion f32[{h},{v}]",  # dW, accumulated in float32
+    ]), in_body
+    # Outside the loop: the head's cast, dW's product with the cotangent,
+    # the hidden states laid out by chunk and back; none of them a matmul.
+    entry = next(block for head, block in comps.values() if head.startswith("ENTRY"))
+    outside = named_of(entry)
+    shapes = (f"[{h},{v}]", f"[{n},{r},{h}]", f"[{n},{b},{c},{h}]")
+    if r == h:  # the optimizer's update of a norm weight leads as the row sums do
+        shapes += (f"(f32[{h}]",)
+    assert outside and all(
+        any(shape in i for shape in shapes) and "convolution" not in i
+        for i in outside
+    ), outside

@@ -368,8 +368,8 @@ _ALL = [
     _k(
         "TORCHFT_LOSS_CHUNK",
         "int",
-        "128",
-        "Per-shard microbatch chunk size used when computing loss without materializing full logits.",
+        "0",
+        "Tokens a row of the batch in one chunk of the head and loss, which never hold the full [B,S,V] logits. 0 (the default) derives it from the shapes (`parallel.train.loss_chunk`): the smallest divisor of S, a multiple of 128, that brings a chunk to 2048 rows of the batch (where the weight gradient's float32 accumulator hides under its matmul), its float32 logits `4*B*C*V` under 1 GiB.",
     ),
     _k(
         "TORCHFT_TTR_BUDGET_S",

@@ -11,6 +11,7 @@ reference composes with (SURVEY.md §2.3) — here it is in-repo.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 from typing import Any, Callable, Optional, Tuple
 
@@ -118,16 +119,44 @@ def init_train_state(
     return state, shardings
 
 
-# Tokens per chunked-loss slice. The [B,S,V] fp32 logits of a 32k-vocab
-# model at B=8,S=1024 are >1 GB and their log_softmax + backward dlogits
-# multiply that — the dominant HBM transient of the whole step. Chunking
-# bounds it at [B,_LOSS_CHUNK,V] (~130 MB) with jax.checkpoint recompute.
-# Env-tunable (TORCHFT_LOSS_CHUNK) so the on-chip MFU sweep can A/B chunk
-# sizes without code edits — larger chunks = fewer scan iterations and
-# bigger head matmuls at proportionally more transient HBM.
+# The head and loss never hold the [B,S,V] logits (float32, >2 GB at
+# 16k tokens of a 32k vocabulary): rows go through it C tokens a row of the
+# batch at a time (``_head_loss_sum``). TORCHFT_LOSS_CHUNK sets C; unset
+# (0) it is derived from the shapes by ``loss_chunk``.
 from torchft_tpu import knobs as _knobs
 
 _LOSS_CHUNK = _knobs.get_int("TORCHFT_LOSS_CHUNK")
+# Rows (B x C) a chunk should reach, and the most its float32 logits may
+# take. The weight gradient's float32 accumulator is read and written
+# whole every chunk: 8*H*V bytes under 2*R*H*V FLOP, R/4 FLOP a byte,
+# level with a v5e's ridge (240 FLOP/B) at 960 rows and hidden under the
+# matmul at twice that. On that chip, the head and loss of 16,384 tokens
+# alone: 138.7 ms at 1,024 rows, 125.1 at 2,048, 125.5 at 4,096 under a
+# 92.5k vocabulary; 82.6, 81.0, 80.9 under 32k; 27.3, 28.4, 29.6 under
+# 16k (PERF.md section 6, PR 42). The byte cap keeps the chunk's
+# temporaries (logits, their gradient in the compute dtype) a small part
+# of a 16 GB chip beside a fine-tune's state: 2,048 rows of a 92.5k
+# vocabulary are 758 MB.
+_LOSS_CHUNK_ROWS = 2048
+_LOSS_CHUNK_LOGITS_BYTES = 1 << 30
+
+
+def loss_chunk(B: int, S: int, V: int) -> int:
+    """Tokens a row of the batch in one chunk of the head and loss, from
+    the shapes alone: the smallest divisor of ``S`` that is a multiple of
+    128 and brings the chunk to ``_LOSS_CHUNK_ROWS`` rows, among those
+    whose float32 logits ``4*B*C*V`` stay under
+    ``_LOSS_CHUNK_LOGITS_BYTES`` (the largest of those where none gets
+    there, 128 where none fits). A sequence no multiple of 128 divides
+    gets ``min(128, S)``: one chunk if it is shorter, else the caller's
+    plain full-logits path."""
+    divisors = [c for c in range(128, S + 1, 128) if S % c == 0]
+    if not divisors:
+        return min(128, S)
+    fits = [
+        c for c in divisors if 4 * B * c * V <= _LOSS_CHUNK_LOGITS_BYTES
+    ] or divisors[:1]
+    return next((c for c in fits if B * c >= _LOSS_CHUNK_ROWS), fits[-1])
 
 
 def _lm_head_projection(model: Transformer, params):
@@ -207,6 +236,100 @@ def router_bias_abs_max(params) -> jax.Array:
     ]))
 
 
+def _head_loss_chunks(h, w, targets, mask, C: int):
+    """What both passes of ``_head_loss_sum`` scan over: the head in the
+    compute dtype and the chunks of the hidden states, the targets and the
+    mask, the ``B*C`` rows of a chunk as one axis ([n,B*C,...]: three
+    plain matmuls a chunk where a batch axis would make them batched
+    convolutions, a fifth slower on a v5e)."""
+    B, S, _ = h.shape
+    n = S // C
+    chunks = tuple(
+        jnp.moveaxis(x.reshape(B, n, C, *x.shape[2:]), 1, 0).reshape(
+            n, B * C, *x.shape[2:]
+        )
+        for x in (h, targets, mask)
+    )
+    return w.astype(h.dtype), chunks
+
+
+def _chunk_loss(hc, tc, mc, wc):
+    """A chunk's masked loss sum, its float32 logits [R,V], their
+    log-sum-exp and where the targets sit in them."""
+    logits = jnp.dot(hc, wc, preferred_element_type=jnp.float32)
+    top = logits.max(axis=-1, keepdims=True)
+    lse = jnp.log(jnp.exp(logits - top).sum(axis=-1)) + top[..., 0]
+    hit = jnp.arange(logits.shape[-1]) == tc[..., None]
+    picked = jnp.where(hit, logits, 0.0).sum(axis=-1)
+    return ((lse - picked) * mc).sum(), logits, lse, hit
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _head_loss_sum(h, w, targets, mask, C: int):
+    """The masked sum of the next-token cross-entropies of ``h`` [B,S,H]
+    (in the compute dtype) under the head ``w`` [H,V] (float32), ``C``
+    tokens a row at a time: logits from operands in ``h``'s dtype
+    accumulated in float32, log-sum-exp and loss in float32. ``mask`` is
+    float32; ``S % C == 0``.
+
+    Differentiated, a chunk forms its own gradient while its logits are
+    live: ``dlogits = (softmax - onehot) * mask`` needs nothing the forward
+    pass does not hold there, so the vocabulary-wide matmul runs three
+    times a chunk (logits, dh, dW) and never a fourth for logits
+    recomputed, and the backward pass is two multiplies by the scalar
+    cotangent. dW accumulates across the chunks in float32."""
+    wc, chunks = _head_loss_chunks(h, w, targets, mask, C)
+
+    def chunk(total, xs):
+        return total + _chunk_loss(*xs, wc)[0], None
+
+    return jax.lax.scan(chunk, jnp.zeros((), jnp.float32), chunks)[0]
+
+
+def _head_loss_sum_fwd(h, w, targets, mask, C: int):
+    B, S, H = h.shape
+    wc, chunks = _head_loss_chunks(h, w, targets, mask, C)
+
+    def chunk(carry, xs):
+        total, dw = carry
+        hc, tc, mc = xs
+        loss, logits, lse, hit = _chunk_loss(hc, tc, mc, wc)
+        # Into the MXU in the compute dtype, as the cotangent of float32
+        # logits goes at the chip's default precision, and written out
+        # once: fused into its two matmuls it is formed again from the
+        # float32 logits for every tile of each (a v5e, 4 x 4096 tokens of
+        # a 32k vocabulary: dW 38.9 ms a step so, 25.9 + 4.5 behind the
+        # barrier).
+        dlogits = jax.lax.optimization_barrier((
+            (jnp.exp(logits - lse[..., None]) - hit) * mc[..., None]
+        ).astype(h.dtype))
+        dh = jax.lax.dot_general(
+            dlogits, wc, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ).astype(h.dtype)  # [R,H]
+        dw = dw + jax.lax.dot_general(
+            hc, dlogits, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [H,V]
+        return (total + loss, dw), dh
+
+    (total, dw), dh = jax.lax.scan(
+        chunk,
+        (jnp.zeros((), jnp.float32), jnp.zeros(w.shape, jnp.float32)),
+        chunks,
+    )
+    dh = jnp.moveaxis(dh.reshape(S // C, B, C, H), 0, 1).reshape(B, S, H)
+    return total, (dh, dw)
+
+
+def _head_loss_sum_bwd(C, res, g):
+    dh, dw = res
+    return (dh * g).astype(dh.dtype), dw * g, None, None
+
+
+_head_loss_sum.defvjp(_head_loss_sum_fwd, _head_loss_sum_bwd)
+
+
 def _loss_and_metrics(model: Transformer, params, inputs, targets, mask):
     """(loss, router metrics). The loss is the mean next-token
     cross-entropy plus, for a model with experts, ``router_aux_coef`` x
@@ -218,11 +341,13 @@ def _loss_and_metrics(model: Transformer, params, inputs, targets, mask):
     share of all assignments that landed on held experts) and, from a
     model whose step updates its selection biases, the vector
     ``moe_load``."""
+    cfg = model.cfg
     B, S = inputs.shape
-    C = min(_LOSS_CHUNK, S)
+    C = min(_LOSS_CHUNK, S) if _LOSS_CHUNK > 0 else loss_chunk(
+        B, S, cfg.vocab_size
+    )
     mask_f = mask.astype(jnp.float32)
     denom = jnp.maximum(mask_f.sum(), 1.0)
-    cfg = model.cfg
 
     def with_router_terms(ce, sown):
         if not sown:
@@ -247,26 +372,8 @@ def _loss_and_metrics(model: Transformer, params, inputs, targets, mask):
 
     h, sown = _apply_with_aux(model, params, inputs, return_hidden=True)
     w, head_dtype = _lm_head_projection(model, params)
-    w = w.astype(head_dtype)
-    n = S // C
-    h_r = jnp.moveaxis(h.reshape(B, n, C, h.shape[-1]), 1, 0)  # [n,B,C,H]
-    t_r = jnp.moveaxis(targets.reshape(B, n, C), 1, 0)
-    m_r = jnp.moveaxis(mask_f.reshape(B, n, C), 1, 0)
-
-    # A hand-written VJP for this scan (saved-lse + bf16 dlogits) is 2x
-    # faster in isolation but 8% slower composed into the full step (XLA
-    # overlaps this checkpointed scan's backward with the trunk backward;
-    # a custom_vjp boundary defeats that) — measured on v5e, B=8 S=1024.
-    def chunk(acc, xs):
-        hc, tc, mc = xs
-        logits = jnp.dot(
-            hc.astype(head_dtype), w, preferred_element_type=jnp.float32
-        )  # [B,C,V] fp32, exists only inside this chunk
-        losses = optax.softmax_cross_entropy_with_integer_labels(logits, tc)
-        return acc + (losses * mc).sum(), None
-
-    total, _ = jax.lax.scan(
-        jax.checkpoint(chunk), jnp.zeros((), jnp.float32), (h_r, t_r, m_r)
+    total = _head_loss_sum(
+        h.astype(head_dtype), w.astype(jnp.float32), targets, mask_f, C
     )
     return with_router_terms(total / denom, sown)
 
