@@ -477,3 +477,33 @@ def test_head_and_loss_is_three_vocabulary_wide_matmuls_in_one_loop_and_fits(
         any(shape in i for shape in shapes) and "convolution" not in i
         for i in outside
     ), outside
+
+
+# -- the sdar-raw cell: the flash kernels under the block-diffusion mask -----
+
+# (B, 2L, Hq, Hkv, D, block length, tile) of the cell's attention, and of a
+# block length that is no power of two in tiles of 384 (the mask then takes
+# a remainder where the cell's takes a bitwise and).
+BLOCK_DIFFUSION_SHAPES = [(2, 16384, 32, 4, 128, 4, 512), (1, 1536, 8, 4, 128, 12, 384)]
+
+
+@pytest.mark.parametrize("shape", BLOCK_DIFFUSION_SHAPES)
+def test_flash_block_diffusion_compiles_under_the_name_the_metrics_match(one_chip, shape):
+    """Forward, dq and the two dkv kernels (the noisy stream's kv tiles and
+    the clean one's) at the cell's widths: four kernels, each named for
+    the jit around it, which ``flash_ms``'s pattern finds in a step."""
+    from torchft_tpu.ops.flash_attention import flash_attention_block_diffusion
+
+    *qkv_shape, b, tile = shape
+
+    def loss(q, k, v):
+        out = flash_attention_block_diffusion(
+            q, k, v, block_length=b, block=tile, interpret=False
+        )
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        *_qkv(one_chip, *qkv_shape)
+    ).compile().as_text()
+    calls = _custom_calls(text)
+    assert len(calls) == 4 and all("flash_attention_block_diffusion" in c for c in calls), calls

@@ -25,4 +25,6 @@ from torchft_tpu.models.llama import (  # noqa: F401
     nemotron3_nano,
     nemotron_h_debug,
     olmoe_1b_7b,
+    sdar_30b_a3b,
+    sdar_moe_debug,
 )

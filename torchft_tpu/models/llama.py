@@ -149,6 +149,26 @@ class LlamaConfig:
     # Initial scale of the projections that write the residual stream
     # (Nemotron-H's rescale_prenorm_residual: 1/sqrt(layers)).
     residual_init_scale: float = 1.0
+    # True: the softmax router's k chosen probabilities are divided by
+    # their sum (Qwen3-MoE's and SDAR's ``norm_topk_prob``); OLMoE keeps
+    # the softmax's own values. The sigmoid router always renormalises.
+    norm_topk_prob: bool = False
+    # What a training step predicts. "next_token": every position its
+    # successor under the causal mask. "block_diffusion" (SDAR
+    # arXiv:2510.06303, Block Diffusion arXiv:2503.09573): the trunk runs
+    # TWO streams of L positions laid end to end, a noisy x_t (positions
+    # masked to ``mask_token_id`` with a probability t drawn a block of
+    # ``block_length`` from U(``diffusion_t_min``, ``diffusion_t_max``))
+    # and the clean x_0, both at rotary positions 0..L-1 under
+    # ``block_diffusion_mask``, and the loss is the cross-entropy of the
+    # noisy stream's masked positions against x_0, weighed by 1/t
+    # (parallel/train.py:_loss_and_metrics). The noise is a pure function
+    # of the batch's own tokens (``diffusion_streams``).
+    objective: str = "next_token"
+    block_length: int = 0
+    mask_token_id: int = 0
+    diffusion_t_min: float = 0.0
+    diffusion_t_max: float = 1.0
     # Bound by parallel.train when attn_impl is 'ring' or 'ulysses'.
     attn_fn: Optional[Callable[..., jax.Array]] = None
 
@@ -336,6 +356,75 @@ def lfm2_moe_debug(**overrides: Any) -> LlamaConfig:
     return dataclasses.replace(cfg, **overrides)
 
 
+def sdar_30b_a3b(**overrides: Any) -> LlamaConfig:
+    """SDAR-30B-A3B-Chat (JetLM/SDAR-30B-A3B-Chat config.json, model_type
+    ``sdar_moe``; arXiv:2510.06303) at its published sizes: 48 layers of
+    rotary GQA attention (32 heads on 4 at head width 128, per-head QK
+    norms) and 128 SiLU-gated experts of width 768, 8 a token under a
+    softmax router with renormalised gates, an untied 151,936-row head;
+    trained by block diffusion in blocks of 4. 30.5B parameters, 3.35B
+    active: override ``num_layers``, ``experts_held`` and ``vocab_size``
+    for what one chip holds. The mask token, the block length, t's
+    interval (Block Diffusion's clipped schedule for blocks of 4,
+    U[0.45, 0.95]) and the balance coefficient are not in the published
+    file
+    (benchmark/configs/sdar-30b-a3b-l6e16.json, ``assumed``). The stack is
+    spelt as a pattern, "*E" a published layer: each sub-layer is then
+    remat'd alone and its gradient meets the optimizer as soon as it
+    exists, where one scanned block of both keeps the stacked gradient
+    and a whole block's activations (19.1 GB against 13.0 for the
+    benchmark's six layers, by the v5e compiler's own account)."""
+    cfg = LlamaConfig(
+        vocab_size=151936,
+        hidden_size=2048,
+        intermediate_size=768,
+        num_layers=48,
+        layer_pattern="*E" * 48,
+        num_heads=32,
+        num_kv_heads=4,
+        head_dim=128,
+        max_seq_len=32768,
+        rope_theta=1e6,
+        norm_eps=1e-6,
+        qk_norm="head",
+        num_experts=128,
+        num_experts_per_tok=8,
+        expert_capacity_factor=None,
+        norm_topk_prob=True,
+        router_aux_coef=0.001,
+        router_z_coef=0.0,
+        objective="block_diffusion",
+        block_length=4,
+        mask_token_id=151669,
+        diffusion_t_min=0.45,
+        diffusion_t_max=0.95,
+    )
+    return dataclasses.replace(cfg, **overrides)
+
+
+def sdar_moe_debug(**overrides: Any) -> LlamaConfig:
+    """Tiny SDAR (2 layers, 16 experts of which 4 are held, blocks of 4,
+    the vocabulary's last row the mask token) for tests and
+    ``train_hsdp.py --model sdar_moe``."""
+    cfg = sdar_30b_a3b(
+        vocab_size=256,
+        hidden_size=64,
+        intermediate_size=48,
+        num_layers=2,
+        layer_pattern="*E*E",
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        max_seq_len=128,
+        num_experts=16,
+        num_experts_per_tok=3,
+        experts_held=(0, 4),
+        mask_token_id=255,
+        remat=False,
+    )
+    return dataclasses.replace(cfg, **overrides)
+
+
 def llama_moe_debug(**overrides: Any) -> LlamaConfig:
     """Tiny MoE config (4 experts, top-2) for tests and the ep dryrun."""
     cfg = llama_debug(num_experts=4, num_experts_per_tok=2)
@@ -383,8 +472,10 @@ def dense_attention(
     v: jax.Array,
     *,
     causal: bool = True,
+    mask: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """Plain causal GQA attention. q: [B,S,Hq,Dh], k/v: [B,S,Hkv,Dh].
+    """Plain GQA attention, causal or under a boolean ``mask`` [S,S] (True:
+    kept). q: [B,S,Hq,Dh], k/v: [B,S,Hkv,Dh].
 
     Single large einsum pair so XLA tiles it onto the MXU; softmax in fp32.
     """
@@ -394,12 +485,50 @@ def dense_attention(
     qg = q.reshape(b, s, hkv, g, dh)
     scale = dh**-0.5
     scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k).astype(jnp.float32) * scale
-    if causal:
+    if mask is None and causal:
         mask = jnp.tril(jnp.ones((s, s), dtype=bool))
+    if mask is not None:
         scores = jnp.where(mask[None, None, None], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
     return out.reshape(b, s, hq, dh)
+
+
+def block_diffusion_mask(stream_len: int, block_length: int) -> jax.Array:
+    """[2L,2L] boolean, True where a query row sees a key column, over the
+    two streams [noisy x_t | clean x_0] of L positions in blocks of b: a
+    noisy query sees the noisy keys of its own block and the clean keys of
+    strictly earlier blocks; a clean query the clean keys of its own and
+    earlier blocks; nothing clean sees anything noisy."""
+    at = jnp.arange(2 * stream_len)
+    noisy, blk = at < stream_len, at % stream_len // block_length
+    (qn, kn), (qb, kb) = ((v[:, None], v[None, :]) for v in (noisy, blk))
+    return jnp.where(qn, jnp.where(kn, qb == kb, kb < qb), ~kn & (kb <= qb))
+
+
+def block_diffusion_attention(cfg: LlamaConfig, rows: int) -> tuple:
+    """(branch, kept share) of the two-stream attention over ``rows`` = 2L
+    rows: 'flash' where the kernels take the shape (the share of the score
+    entries in the tiles they run that the mask keeps) or 'dense' (the
+    whole 2L x 2L square is computed)."""
+    from torchft_tpu.ops import flash_attention as fa
+
+    L, b = rows // 2, cfg.block_length
+    if rows % 2 or b <= 0 or L % b:
+        raise ValueError(
+            f"block diffusion: {rows} rows are not two streams of whole "
+            f"blocks of {b}"
+        )
+    block = cfg.flash_block_q
+    if (
+        cfg.attn_impl == "flash"
+        and rows >= cfg.flash_min_seq
+        and block == cfg.flash_block_k
+        and fa.supports_block_diffusion(L, b, block)
+    ):
+        kept, run = fa.block_diffusion_tiles(L, b, block)
+        return "flash", kept / run
+    return "dense", (L * L + L * b) / (rows * rows)
 
 
 class RMSNorm(nn.Module):
@@ -449,7 +578,35 @@ class Attention(nn.Module):
         if cfg.rope:
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
-        if cfg.attn_impl in ("ring", "ulysses"):
+        if cfg.objective == "block_diffusion":
+            # x holds [x_t | x_0]: two streams, one mask over both.
+            if cfg.attn_impl not in ("flash", "dense"):
+                raise ValueError(
+                    f"block diffusion under attn_impl={cfg.attn_impl!r}: the "
+                    "two-stream mask exists for 'flash' and 'dense'"
+                )
+            rows = q.shape[1]
+            traced, kept = block_diffusion_attention(cfg, rows)
+            # The schedule is this layer's, so the share is counted here
+            # (read where a step collects what its layers sow).
+            self.sow("intermediates", "bd_kept_share", jnp.float32(kept))
+            _note_attention(
+                f"{cfg.attn_impl}/block_diffusion", f"{traced}/block_diffusion", rows
+            )
+            if traced == "flash":
+                from torchft_tpu.ops.flash_attention import (
+                    flash_attention_block_diffusion,
+                )
+
+                out = flash_attention_block_diffusion(
+                    q, k, v, block_length=cfg.block_length, block=cfg.flash_block_q
+                )
+            else:
+                out = dense_attention(
+                    q, k, v,
+                    mask=block_diffusion_mask(rows // 2, cfg.block_length),
+                )
+        elif cfg.attn_impl in ("ring", "ulysses"):
             assert cfg.attn_fn is not None, (
                 f"{cfg.attn_impl} attention needs cfg.attn_fn"
             )
@@ -695,6 +852,8 @@ class MoEMLP(nn.Module):
         else:
             probs = jax.nn.softmax(router_logits, axis=-1)
             gate_vals, gate_idx = jax.lax.top_k(probs, K)  # [B,S,K]
+            if cfg.norm_topk_prob:
+                gate_vals = gate_vals / gate_vals.sum(-1, keepdims=True)
             lse = jax.nn.logsumexp(router_logits, axis=-1)
             self.sow("intermediates", "router_z", jnp.mean(jnp.square(lse)))
 
@@ -1027,7 +1186,9 @@ class _ScanBlock(Block):
 
 
 class Transformer(nn.Module):
-    """Decoder-only LM. __call__(tokens [B,S], positions [B,S]) -> logits."""
+    """Decoder-only LM. __call__(tokens [B,S], positions [B,S]) -> logits.
+    Under ``objective="block_diffusion"`` the S tokens are two streams of
+    S/2, [x_t | x_0] (``LlamaConfig.objective``)."""
 
     cfg: LlamaConfig
 
@@ -1045,9 +1206,12 @@ class Transformer(nn.Module):
         materialized."""
         cfg = self.cfg
         if positions is None:
-            positions = jnp.broadcast_to(
-                jnp.arange(tokens.shape[1]), tokens.shape
-            )
+            if cfg.objective == "block_diffusion":
+                # Two streams, each at positions 0..L-1.
+                at = jnp.tile(jnp.arange(tokens.shape[1] // 2), 2)
+            else:
+                at = jnp.arange(tokens.shape[1])
+            positions = jnp.broadcast_to(at, tokens.shape)
         embed = nn.Embed(
             cfg.vocab_size,
             cfg.hidden_size,

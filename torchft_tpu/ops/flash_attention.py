@@ -1,4 +1,4 @@
-"""Pallas TPU flash attention (causal GQA forward).
+"""Pallas TPU flash attention (GQA, forward and backward, three masks).
 
 The reference has no attention kernel of its own (it delegates compute to
 torchtitan); this kernel exists because the flagship bench model's dense
@@ -8,18 +8,34 @@ K/V blocks through VMEM with an online softmax so scores never leave
 the chip (reference for the FLOPs budget: SURVEY.md §6; technique:
 Dao et al. 2022, standard TPU formulation as in jax's pallas examples).
 
+Three masks, each a mask closure and a skip predicate around the one
+step math (``_fwd_step``, ``_bwd_dq_step``, ``_bwd_dkv_step``):
+
+- ``flash_attention``: causal (or none) over one sequence, static.
+- ``flash_attention_block``: causal at GLOBAL offsets that are dynamic
+  scalars, for the ring (one streamed k/v block a call, merged by lse).
+- ``flash_attention_block_diffusion``: the training mask of block
+  diffusion (arXiv:2503.09573) over two streams of L positions laid end
+  to end, a noisy x_t then the clean x_0, with blocks of ``b`` positions:
+  a clean query sees the clean keys of its own and earlier blocks, a noisy
+  query the clean keys of strictly earlier blocks and the noisy keys of
+  its own block, and nothing sees otherwise. L^2 + L*b score entries are
+  kept of the 4 L^2 of the square; the sweeps visit the kept tiles only.
+
 Layout: model-native [B, S, H, D] in/out (matching
 ``models/llama.py:dense_attention``); internally transposed to
 [B, H, S, D] so the S×D blocks are MXU-shaped. GQA folds the q-head →
 kv-head mapping into the K/V BlockSpec index maps — no K/V replication
 in HBM or VMEM.
 
-Grid = (B, Hq, S/block_q, S/block_k), kv innermost: TPU grids execute
+Grid = (B, Hq, q tiles, kv steps), kv innermost: TPU grids execute
 sequentially, so the fp32 accumulator + online-softmax stats live in VMEM
 scratch across the kv sweep and the output block is written once at the
-final kv step. Causal blocks strictly above the diagonal are skipped via
-``pl.when`` (their DMA still runs; the compute — the expensive part — does
-not).
+final kv step. A step whose tile the mask empties is skipped via
+``pl.when`` (no compute), and under the static masks its index map names
+the tile the sweep already holds, so nothing is fetched for it either;
+the ring's offset kernels, whose skip is decided by a dynamic scalar,
+still fetch the tile they skip.
 
 Numerics: scores and softmax accumulate in fp32 regardless of input
 dtype; output is cast back to the input dtype. Tested bitwise-free
@@ -41,7 +57,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention", "flash_attention_block", "supports"]
+__all__ = [
+    "flash_attention",
+    "flash_attention_block",
+    "flash_attention_block_diffusion",
+    "block_diffusion_tiles",
+    "supports",
+    "supports_block_diffusion",
+]
 
 _NEG_INF = -1e30
 
@@ -109,9 +132,12 @@ def _fwd_step(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, scale, mask_fn):
 
 def _fwd_finish(o_ref, lse_ref, acc_ref, m_ref, l_ref):
     """Final normalization + logsumexp residual write."""
-    # All-masked rows can't happen under causal (the diagonal is always
-    # kept) but CAN in an offset block entirely in the future: denom guard
-    # makes out 0 and lse ~ -1e30, which the block merge weighs to zero.
+    # A row with every entry masked cannot happen under the causal mask
+    # (the diagonal is always kept) nor under the block-diffusion mask (a
+    # row's own block is always kept, and its sweep starts on a tile that
+    # holds it), but CAN in an offset block entirely in the future: the
+    # denom guard makes out 0 and lse ~ -1e30, which the block merge
+    # weighs to zero.
     denom = jnp.maximum(l_ref[:, :1], 1e-30)
     o_ref[0, 0] = (acc_ref[:] / denom).astype(o_ref.dtype)
     # TPU tiles need the last two block dims (sublane, lane) aligned, so
@@ -474,6 +500,16 @@ def _backward_impl(qt, kt, vt, do, lse, delta, causal, block_q, block_k,
     return dq, dk, dv
 
 
+def _row_delta(do, out):
+    """Delta_i = rowsum(dO_i * O_i) [B, Hq, S] (a tiny elementwise + reduce
+    that XLA fuses), sublane-broadcast to the lse residual's layout
+    [B, Hq, 8, S]."""
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    return jnp.broadcast_to(
+        delta[:, :, None, :], (*delta.shape[:2], 8, delta.shape[-1])
+    )
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash(qt, kt, vt, causal, block_q, block_k, interpret):
     out, _ = _forward_impl(qt, kt, vt, causal, block_q, block_k, interpret)
@@ -487,17 +523,10 @@ def _flash_fwd(qt, kt, vt, causal, block_q, block_k, interpret):
 
 def _flash_bwd(causal, block_q, block_k, interpret, res, do):
     qt, kt, vt, out, lse = res
-    # Delta_i = rowsum(dO_i * O_i) — tiny elementwise+reduce, XLA fuses it.
-    delta = jnp.sum(
-        do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
-    )  # [B, Hq, S]
-    delta = jnp.broadcast_to(
-        delta[:, :, None, :], (*delta.shape[:2], 8, delta.shape[-1])
-    )  # sublane-broadcast to match the lse residual layout
-    dq, dk, dv = _backward_impl(
-        qt, kt, vt, do, lse, delta, causal, block_q, block_k, interpret
+    return _backward_impl(
+        qt, kt, vt, do, lse, _row_delta(do, out), causal, block_q, block_k,
+        interpret,
     )
-    return dq, dk, dv
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -787,11 +816,7 @@ def _flash_block_fwd(qt, kt, vt, qoff, koff, block_q, block_k, interpret):
 def _flash_block_bwd(block_q, block_k, interpret, res, cts):
     qt, kt, vt, qoff, koff, out, lse = res
     do, dlse = cts  # BOTH outputs carry cotangents (the ring merge uses lse)
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-
-    delta = jnp.broadcast_to(
-        delta[:, :, None, :], (*delta.shape[:2], 8, delta.shape[-1])
-    )
+    delta = _row_delta(do, out)
     # dlse is already in the raw [B,Hq,8,S] kernel layout (the sublane
     # slice happens in the public wrapper, outside this vjp); the kernels
     # read sublane 0, which is exactly where the slice cotangent lands.
@@ -839,3 +864,333 @@ def flash_attention_block(
     out, lse = _flash_block(qt, kt, vt, qoff, koff, block_q, block_k, itp)
     # lse is sublane-broadcast [B,Hq,8,Sq]; take one sublane.
     return jnp.swapaxes(out, 1, 2), lse[:, :, 0, :]
+
+
+# ---------------------------------------------------------------------------
+# Block-diffusion variant (models/llama.py:Attention under
+# ``objective="block_diffusion"``): q/k/v hold two streams of L positions
+# end to end, rows 0..L-1 the noisy x_t and L..2L-1 the clean x_0, both at
+# positions 0..L-1, in tiles of ``block`` (a multiple of the block length
+# ``b``, so a tile lies in one stream and cuts no block). With n = L/block
+# the 2n x 2n tiles hold n(n+1)/2 kept clean-on-clean tiles, as many
+# noisy-on-clean and n noisy-on-noisy: n^2 + 2n of 4n^2. No kernel's grid
+# walks the square: a q tile sweeps its own kept kv tiles (n + 1 steps at
+# most, as a causal sweep over L would take n), a clean kv tile the q tiles
+# of both streams from its own on, a noisy kv tile its one q tile. A step
+# past the end of a sweep repeats the sweep's last tile, so nothing is
+# fetched for it. Everything is static: no scalar reaches the kernels.
+# ---------------------------------------------------------------------------
+
+
+def supports_block_diffusion(stream_len: int, block_length: int, block: int = 512) -> bool:
+    """Whether the block-diffusion kernels handle two streams of
+    ``stream_len`` positions under blocks of ``block_length``: whole
+    tiles a stream, whole blocks a tile and, compiled, tiles of whole
+    lane widths (a stream is half of the array, so the per-row residuals'
+    blocks cannot be the array's own last dimension as a short causal
+    sequence's are)."""
+    blk = min(block, stream_len)
+    return (
+        block_length > 0
+        and supports(stream_len, blk, blk)
+        and blk % block_length == 0
+        and (blk % 128 == 0 or _interpret())
+    )
+
+
+def block_diffusion_tiles(stream_len: int, block_length: int, block: int = 512):
+    """(kept score entries, score entries of the tiles a sweep runs) a
+    head and sequence, forward; the backward kernels run the same tiles."""
+    blk = min(block, stream_len)
+    n = stream_len // blk
+    kept = stream_len * stream_len + stream_len * block_length
+    return kept, (n * n + 2 * n) * blk * blk
+
+
+def _bd_kv_sweep(n, iq, j):
+    """Step ``j`` of q tile ``iq``'s sweep over the kv tiles: (tile,
+    whether it runs). A noisy tile i starts on the noisy tile i, which
+    holds every row's own block (so the running max is finite from the
+    first step on), then takes the clean tiles 0..i; a clean tile i takes
+    the clean tiles 0..i."""
+    noisy = iq < n
+    i = jnp.where(noisy, iq, iq - n)
+    last = jnp.where(noisy, i + 1, i)
+    jj = jnp.minimum(j, last)
+    clean_tile = n + jnp.where(noisy, jj - 1, jj)
+    return jnp.where(noisy & (jj == 0), i, clean_tile), j <= last
+
+
+def _bd_q_sweep(n, ik, s):
+    """Step ``s`` of the clean kv tile ``ik``'s sweep over the q tiles:
+    the noisy tiles ik..n-1 (steps ik..n-1), then the clean ones (steps
+    n+ik..2n-1); a step before them repeats the first."""
+    i = s % n
+    return jnp.where(s >= n, n, 0) + jnp.maximum(i, ik), i >= ik
+
+
+def _bd_mask(n, b, block, iq, ikv):
+    """The mask of q tile ``iq`` on kv tile ``ikv`` (tiles of the 2n): a
+    row at position p of block first(p)..first(p)+b-1 keeps the columns in
+    [lo, hi), by the two tiles' streams. A sweep never pairs a clean q
+    tile with a noisy kv tile."""
+
+    def mask_fn(s):
+        q_noisy, k_noisy = iq < n, ikv < n
+        rows = jax.lax.broadcasted_iota(jnp.int32, (s.shape[0], 1), 0)
+        qpos = rows + (iq - jnp.where(q_noisy, 0, n)) * block
+        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        kpos = cols + (ikv - jnp.where(k_noisy, 0, n)) * block
+        # b a power of two: a bitwise and (the VPU has no integer divide).
+        first = (qpos & -b) if b & (b - 1) == 0 else qpos - qpos % b
+        lo = first * k_noisy.astype(jnp.int32)  # noisy keys: the own block only
+        # Noisy on clean: strictly earlier blocks. Else up to the own block's end.
+        hi = first + jnp.where(q_noisy & jnp.logical_not(k_noisy), 0, b)
+        return jnp.where((kpos >= lo) & (kpos < hi), s, _NEG_INF)
+
+    return mask_fn
+
+
+def _flash_bd_kernel(
+    q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
+    *, scale: float, n: int, b: int, block: int,
+):
+    iq = pl.program_id(2)
+    j = pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    ikv, run = _bd_kv_sweep(n, iq, j)
+
+    @pl.when(run)
+    def _step():
+        _fwd_step(
+            q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, scale,
+            _bd_mask(n, b, block, iq, ikv),
+        )
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _finish():
+        _fwd_finish(o_ref, lse_ref, acc_ref, m_ref, l_ref)
+
+
+def _flash_bd_bwd_dq_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
+    *, scale: float, n: int, b: int, block: int,
+):
+    iq = pl.program_id(2)
+    j = pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    ikv, run = _bd_kv_sweep(n, iq, j)
+
+    @pl.when(run)
+    def _step():
+        _bwd_dq_step(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, None,
+            dq_acc, scale, _bd_mask(n, b, block, iq, ikv),
+        )
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _finish():
+        dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
+
+
+def _flash_bd_bwd_dkv_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+    dk_acc, dv_acc,
+    *, scale: float, n: int, b: int, block: int, clean: bool, sweep: int,
+):
+    # Grid = (B, Hkv, n, q_per_kv * sweep) over the kv tiles of ONE stream:
+    # the clean ones (``sweep`` = 2n q tiles a head) or the noisy ones (1).
+    ik = pl.program_id(2)
+    inner = pl.program_id(3)
+
+    @pl.when(inner == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    if clean:
+        ikv = n + ik
+        iq, run = _bd_q_sweep(n, ik, inner % sweep)
+    else:
+        ikv, iq, run = ik, ik, True
+
+    @pl.when(run)
+    def _step():
+        _bwd_dkv_step(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, None,
+            dk_acc, dv_acc, scale, _bd_mask(n, b, block, iq, ikv),
+        )
+
+    @pl.when(inner == pl.num_programs(3) - 1)
+    def _finish():
+        dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _bd_q_sweep_specs(n, q_per_kv, block, D):
+    """Block specs of the kernels whose grid is (B, Hq, 2n q tiles, n + 1
+    sweep steps), forward and dq: a q tile's own block (q, o, do, dq), the
+    kv tile its sweep is at (GQA: q head h reads kv head h // q_per_kv),
+    its rows' residuals (lse, delta)."""
+    q_spec = pl.BlockSpec((1, 1, block, D), lambda bb, h, iq, j: (bb, h, iq, 0))
+    kv_spec = pl.BlockSpec(
+        (1, 1, block, D),
+        lambda bb, h, iq, j: (bb, h // q_per_kv, _bd_kv_sweep(n, iq, j)[0], 0),
+    )
+    row_spec = pl.BlockSpec((1, 1, 8, block), lambda bb, h, iq, j: (bb, h, 0, iq))
+    return q_spec, kv_spec, row_spec
+
+
+def _bd_forward_impl(qt, kt, vt, b, block, interpret):
+    B, Hq, S, D = qt.shape
+    n = S // 2 // block
+    q_spec, kv_spec, row_spec = _bd_q_sweep_specs(n, Hq // kt.shape[1], block, D)
+    return pl.pallas_call(
+        functools.partial(
+            _flash_bd_kernel, scale=1.0 / math.sqrt(D), n=n, b=b, block=block
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, Hq, S, D), qt.dtype),
+            jax.ShapeDtypeStruct((B, Hq, 8, S), jnp.float32),
+        ],
+        grid=(B, Hq, 2 * n, n + 1),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, row_spec],
+        scratch_shapes=[
+            pltpu.VMEM((block, D), jnp.float32),
+            pltpu.VMEM((block, 128), jnp.float32),
+            pltpu.VMEM((block, 128), jnp.float32),
+        ],
+        interpret=interpret,
+    )(qt, kt, vt)
+
+
+def _bd_backward_impl(qt, kt, vt, do, lse, delta, b, block, interpret):
+    B, Hq, S, D = qt.shape
+    Hkv = kt.shape[1]
+    q_per_kv = Hq // Hkv
+    n = S // 2 // block
+    static = dict(scale=1.0 / math.sqrt(D), n=n, b=b, block=block)
+
+    q_spec, kv_spec, row_spec = _bd_q_sweep_specs(n, q_per_kv, block, D)
+    dq = pl.pallas_call(
+        functools.partial(_flash_bd_bwd_dq_kernel, **static),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, S, D), qt.dtype),
+        grid=(B, Hq, 2 * n, n + 1),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=q_spec,
+        scratch_shapes=[pltpu.VMEM((block, D), jnp.float32)],
+        interpret=interpret,
+    )(qt, kt, vt, do, lse, delta)
+
+    def dkv(clean: bool):
+        """dk, dv of one stream's kv tiles, [B, Hkv, L, D] each."""
+        sweep = 2 * n if clean else 1
+
+        def q_at(hk, ik, inner):
+            iq = _bd_q_sweep(n, ik, inner % sweep)[0] if clean else ik
+            return hk * q_per_kv + inner // sweep, iq
+
+        def q_idx(bb, hk, ik, inner):
+            head, iq = q_at(hk, ik, inner)
+            return bb, head, iq, 0
+
+        def row_idx(bb, hk, ik, inner):
+            head, iq = q_at(hk, ik, inner)
+            return bb, head, 0, iq
+
+        first = n if clean else 0
+        q_spec2 = pl.BlockSpec((1, 1, block, D), q_idx)
+        row_spec2 = pl.BlockSpec((1, 1, 8, block), row_idx)
+        kv_spec2 = pl.BlockSpec(
+            (1, 1, block, D), lambda bb, hk, ik, inner: (bb, hk, first + ik, 0)
+        )
+        out_spec = pl.BlockSpec(
+            (1, 1, block, D), lambda bb, hk, ik, inner: (bb, hk, ik, 0)
+        )
+        return pl.pallas_call(
+            functools.partial(
+                _flash_bd_bwd_dkv_kernel, clean=clean, sweep=sweep, **static
+            ),
+            out_shape=[
+                jax.ShapeDtypeStruct((B, Hkv, S // 2, D), kt.dtype),
+                jax.ShapeDtypeStruct((B, Hkv, S // 2, D), vt.dtype),
+            ],
+            grid=(B, Hkv, n, q_per_kv * sweep),
+            in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2, row_spec2],
+            out_specs=[out_spec, out_spec],
+            scratch_shapes=[
+                pltpu.VMEM((block, D), jnp.float32),
+                pltpu.VMEM((block, D), jnp.float32),
+            ],
+            interpret=interpret,
+        )(qt, kt, vt, do, lse, delta)
+
+    (dk_noisy, dv_noisy), (dk_clean, dv_clean) = dkv(False), dkv(True)
+    return (
+        dq,
+        jnp.concatenate([dk_noisy, dk_clean], axis=2),
+        jnp.concatenate([dv_noisy, dv_clean], axis=2),
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_bd(qt, kt, vt, b, block, interpret):
+    return _bd_forward_impl(qt, kt, vt, b, block, interpret)[0]
+
+
+def _flash_bd_fwd(qt, kt, vt, b, block, interpret):
+    out, lse = _bd_forward_impl(qt, kt, vt, b, block, interpret)
+    return out, (qt, kt, vt, out, lse)
+
+
+def _flash_bd_bwd(b, block, interpret, res, do):
+    qt, kt, vt, out, lse = res
+    return _bd_backward_impl(
+        qt, kt, vt, do, lse, _row_delta(do, out), b, block, interpret
+    )
+
+
+_flash_bd.defvjp(_flash_bd_fwd, _flash_bd_bwd)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("block_length", "block", "interpret")
+)
+def flash_attention_block_diffusion(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    block_length: int,
+    block: int = 512,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """GQA flash attention under the block-diffusion training mask,
+    differentiable. q: [B,2L,Hq,D]; k/v: [B,2L,Hkv,D]: rows 0..L-1 the
+    noisy stream, L..2L-1 the clean one. Returns [B,2L,Hq,D] in q's dtype.
+    The kernels' trace names start ``flash_attention``, as the causal
+    ones' do (a kernel is named for the jit around it)."""
+    B, S, Hq, D = q.shape
+    assert Hq % k.shape[2] == 0, (Hq, k.shape[2])
+    L = S // 2
+    if S % 2 or not supports_block_diffusion(L, block_length, block):
+        raise ValueError(
+            f"flash_attention_block_diffusion: two streams of {L} positions "
+            f"in blocks of {block_length} do not tile by {min(block, L)}"
+        )
+    itp = _interpret() if interpret is None else interpret
+    out = _flash_bd(
+        jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
+        block_length, min(block, L), itp,
+    )
+    return jnp.swapaxes(out, 1, 2)

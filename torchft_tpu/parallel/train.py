@@ -196,6 +196,10 @@ _SOWN_OVER_LAYERS = (
     ("moe_max_load", jnp.max),
     ("moe_dropped", jnp.sum),
     ("moe_held_share", jnp.mean),
+    # From the attention under the block-diffusion mask: of the score
+    # entries it computes, the share its mask keeps
+    # (``block_diffusion_attention``).
+    ("bd_kept_share", jnp.mean),
     # Not a scalar: every expert's assignments, layer after layer in the
     # parameter tree's order, for the selection-bias update of
     # ``make_train_step`` or of a loop around ``make_grad_step`` (sown
@@ -236,10 +240,10 @@ def router_bias_abs_max(params) -> jax.Array:
     ]))
 
 
-def _head_loss_chunks(h, w, targets, mask, C: int):
+def _head_loss_chunks(h, w, targets, weights, C: int):
     """What both passes of ``_head_loss_sum`` scan over: the head in the
     compute dtype and the chunks of the hidden states, the targets and the
-    mask, the ``B*C`` rows of a chunk as one axis ([n,B*C,...]: three
+    weights, the ``B*C`` rows of a chunk as one axis ([n,B*C,...]: three
     plain matmuls a chunk where a batch axis would make them batched
     convolutions, a fifth slower on a v5e)."""
     B, S, _ = h.shape
@@ -248,14 +252,15 @@ def _head_loss_chunks(h, w, targets, mask, C: int):
         jnp.moveaxis(x.reshape(B, n, C, *x.shape[2:]), 1, 0).reshape(
             n, B * C, *x.shape[2:]
         )
-        for x in (h, targets, mask)
+        for x in (h, targets, weights)
     )
     return w.astype(h.dtype), chunks
 
 
 def _chunk_loss(hc, tc, mc, wc):
-    """A chunk's masked loss sum, its float32 logits [R,V], their
-    log-sum-exp and where the targets sit in them."""
+    """A chunk's weighted loss sum (``mc`` each row's weight), its
+    float32 logits [R,V], their log-sum-exp and where the targets sit in
+    them."""
     logits = jnp.dot(hc, wc, preferred_element_type=jnp.float32)
     top = logits.max(axis=-1, keepdims=True)
     lse = jnp.log(jnp.exp(logits - top).sum(axis=-1)) + top[..., 0]
@@ -265,20 +270,23 @@ def _chunk_loss(hc, tc, mc, wc):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _head_loss_sum(h, w, targets, mask, C: int):
-    """The masked sum of the next-token cross-entropies of ``h`` [B,S,H]
-    (in the compute dtype) under the head ``w`` [H,V] (float32), ``C``
-    tokens a row at a time: logits from operands in ``h``'s dtype
-    accumulated in float32, log-sum-exp and loss in float32. ``mask`` is
-    float32; ``S % C == 0``.
+def _head_loss_sum(h, w, targets, weights, C: int):
+    """The weighted sum of the cross-entropies of ``h`` [B,S,H] (in the
+    compute dtype) against ``targets`` under the head ``w`` [H,V]
+    (float32), ``C`` tokens a row at a time: logits from operands in
+    ``h``'s dtype accumulated in float32, log-sum-exp and loss in float32.
+    ``weights`` [B,S] is float32 and any value a position: a 0/1 mask for
+    the next-token loss, masked/t for block diffusion. What the sum is
+    divided by (the data positions) is the caller's and no part of the
+    weights. ``S % C == 0``.
 
     Differentiated, a chunk forms its own gradient while its logits are
-    live: ``dlogits = (softmax - onehot) * mask`` needs nothing the forward
+    live: ``dlogits = (softmax - onehot) * weight`` needs nothing the forward
     pass does not hold there, so the vocabulary-wide matmul runs three
     times a chunk (logits, dh, dW) and never a fourth for logits
     recomputed, and the backward pass is two multiplies by the scalar
     cotangent. dW accumulates across the chunks in float32."""
-    wc, chunks = _head_loss_chunks(h, w, targets, mask, C)
+    wc, chunks = _head_loss_chunks(h, w, targets, weights, C)
 
     def chunk(total, xs):
         return total + _chunk_loss(*xs, wc)[0], None
@@ -286,9 +294,9 @@ def _head_loss_sum(h, w, targets, mask, C: int):
     return jax.lax.scan(chunk, jnp.zeros((), jnp.float32), chunks)[0]
 
 
-def _head_loss_sum_fwd(h, w, targets, mask, C: int):
+def _head_loss_sum_fwd(h, w, targets, weights, C: int):
     B, S, H = h.shape
-    wc, chunks = _head_loss_chunks(h, w, targets, mask, C)
+    wc, chunks = _head_loss_chunks(h, w, targets, weights, C)
 
     def chunk(carry, xs):
         total, dw = carry
@@ -330,17 +338,69 @@ def _head_loss_sum_bwd(C, res, g):
 _head_loss_sum.defvjp(_head_loss_sum_fwd, _head_loss_sum_bwd)
 
 
+# The constant folded with a batch's tokens into its noise key.
+NOISE_SEED = 0
+
+
+def diffusion_streams(cfg: LlamaConfig, inputs, mask):
+    """Block diffusion's input and weights for x_0 = ``inputs`` [B,L]:
+    ``([x_t | x_0] [B,2L], weights [B,L] float32, masked [B,L] bool)``.
+    The noise is a pure function of the batch: the key is ``NOISE_SEED``
+    folded with the wrapping uint32 sum of the batch's own tokens, so no
+    RNG lives in ``TrainState``: a step that is discarded and run again,
+    or replayed by a healed replica, draws the same noise, and replicas
+    differ in noise because they differ in data. The key is split in two;
+    the first half draws t [B,L/b] from U(``diffusion_t_min``,
+    ``diffusion_t_max``), one a sequence and block, the second a uniform u
+    [B,L]; position p of block j is masked where u_p < t_j (probability
+    t_j), x_t holds ``mask_token_id`` there and x_0's token elsewhere, and
+    the weight is mask_p * masked_p / t_j. (The order and shapes are the
+    contract with a reference that shares no code:
+    benchmark/configs/sdar-30b-a3b-l6e16.json, ``assumed``.)"""
+    B, L = inputs.shape
+    b = cfg.block_length
+    if b <= 0 or L % b:
+        raise ValueError(f"block diffusion: blocks of {b} do not divide {L}")
+    key = jax.random.fold_in(
+        jax.random.PRNGKey(NOISE_SEED), jnp.sum(inputs.astype(jnp.uint32))
+    )
+    key_t, key_u = jax.random.split(key)
+    t = jnp.repeat(
+        jax.random.uniform(
+            key_t, (B, L // b), jnp.float32,
+            cfg.diffusion_t_min, cfg.diffusion_t_max,
+        ),
+        b, axis=1,
+    )
+    masked = jax.random.uniform(key_u, (B, L), jnp.float32) < t
+    x_t = jnp.where(masked, jnp.asarray(cfg.mask_token_id, inputs.dtype), inputs)
+    weights = mask.astype(jnp.float32) * masked / t
+    return jnp.concatenate([x_t, inputs], axis=1), weights, masked
+
+
 def _loss_and_metrics(model: Transformer, params, inputs, targets, mask):
-    """(loss, router metrics). The loss is the mean next-token
-    cross-entropy plus, for a model with experts, ``router_aux_coef`` x
-    the load-balancing term and ``router_z_coef`` x the router z-loss,
-    each a mean over the layers. The metrics are {} for a dense model,
-    else ``router_aux``, ``router_z``, ``moe_max_load`` (worst layer),
+    """(loss, metrics), by the model's ``objective``.
+
+    "next_token": the mean over the data positions (``mask``) of the
+    cross-entropy of each position's successor (``targets``).
+    "block_diffusion": x_0 = ``inputs``; the trunk runs once over
+    ``diffusion_streams``' [x_t | x_0], only the noisy stream's L rows
+    reach the head, and the loss is sum_p weight_p CE(head(h_noisy[p]),
+    x_0[p]) / sum_p mask_p: the token AT a masked position, weighed by 1/t;
+    ``targets`` is not read.
+
+    Either way a model with experts adds ``router_aux_coef`` x the
+    load-balancing term and ``router_z_coef`` x the router z-loss, each a
+    mean over the layers (and, under block diffusion, over both streams'
+    rows). The metrics are {} for a dense next-token model, else
+    ``router_aux``, ``router_z``, ``moe_max_load`` (worst layer),
     ``moe_dropped`` (assignments not computed, all layers), from
     layers that hold a share of their experts ``moe_held_share`` (the
-    share of all assignments that landed on held experts) and, from a
-    model whose step updates its selection biases, the vector
-    ``moe_load``."""
+    share of all assignments that landed on held experts), from a
+    model whose step updates its selection biases the vector
+    ``moe_load``, under block diffusion ``diffusion_masked_share``
+    (masked data positions over data positions) and, sown by its
+    attention, ``bd_kept_share``."""
     cfg = model.cfg
     B, S = inputs.shape
     C = min(_LOSS_CHUNK, S) if _LOSS_CHUNK > 0 else loss_chunk(
@@ -348,12 +408,20 @@ def _loss_and_metrics(model: Transformer, params, inputs, targets, mask):
     )
     mask_f = mask.astype(jnp.float32)
     denom = jnp.maximum(mask_f.sum(), 1.0)
+    weights, extra = mask_f, {}
+    two_streams = cfg.objective == "block_diffusion"
+    if two_streams:
+        targets = inputs
+        inputs, weights, masked = diffusion_streams(cfg, inputs, mask)
+        extra = {"diffusion_masked_share": (mask_f * masked).sum() / denom}
+    elif cfg.objective != "next_token":
+        raise ValueError(f"objective {cfg.objective!r}")
 
     def with_router_terms(ce, sown):
         if not sown:
             # The zero term is the dense step's jaxpr as it always was: a
             # persistent compile cache keyed on it keeps hitting.
-            return ce + cfg.router_aux_coef * jnp.zeros(()), {}
+            return ce + cfg.router_aux_coef * jnp.zeros(()), extra
         metrics = {
             name: over_layers(sown[name])
             for name, over_layers in _SOWN_OVER_LAYERS if name in sown
@@ -361,19 +429,25 @@ def _loss_and_metrics(model: Transformer, params, inputs, targets, mask):
         loss = ce + cfg.router_aux_coef * metrics["router_aux"]
         if cfg.router_z_coef:
             loss = loss + cfg.router_z_coef * metrics["router_z"]
-        return loss, jax.lax.stop_gradient(metrics)
+        return loss, jax.lax.stop_gradient({**metrics, **extra})
+
+    def data_rows(x):
+        """The rows the loss is over: under block diffusion the noisy
+        stream's, the first S of the trunk's 2S."""
+        return x[:, :S] if two_streams else x
 
     if S % C != 0:  # odd seq len: the plain full-logits path
         logits, sown = _apply_with_aux(model, params, inputs)
         losses = optax.softmax_cross_entropy_with_integer_labels(
-            logits, targets
+            data_rows(logits), targets
         )
-        return with_router_terms((losses * mask_f).sum() / denom, sown)
+        return with_router_terms((losses * weights).sum() / denom, sown)
 
     h, sown = _apply_with_aux(model, params, inputs, return_hidden=True)
     w, head_dtype = _lm_head_projection(model, params)
     total = _head_loss_sum(
-        h.astype(head_dtype), w.astype(jnp.float32), targets, mask_f, C
+        data_rows(h).astype(head_dtype), w.astype(jnp.float32), targets,
+        weights, C,
     )
     return with_router_terms(total / denom, sown)
 

@@ -34,7 +34,8 @@ def main() -> int:
     parser.add_argument("--steps", type=int, default=20)
     parser.add_argument(
         "--model",
-        choices=["debug", "small", "moe", "olmoe", "nemotron_h", "lfm2_moe", "pipeline"],
+        choices=["debug", "small", "moe", "olmoe", "nemotron_h", "lfm2_moe", "sdar_moe",
+                 "pipeline"],
         default="debug",
     )
     parser.add_argument("--batch", type=int, default=8)
@@ -105,6 +106,7 @@ def main() -> int:
         llama_small,
         nemotron_h_debug,
         olmoe_1b_7b,
+        sdar_moe_debug,
     )
     from torchft_tpu.parallel import auto_mesh
     from torchft_tpu.parallel.train import (
@@ -191,6 +193,16 @@ def main() -> int:
             # loads ride the replica allreduce beside the gradients and
             # apply_step updates the biases after the optimizer.
             "lfm2_moe": lfm2_moe_debug,
+            # The small preset of SDAR's stack (rotary attention with
+            # per-head QK norms, SiLU-gated experts of which a share is
+            # held under a renormalised softmax router, an untied head)
+            # trained by block diffusion: the step builds the noisy stream
+            # from the batch's own tokens (no RNG in the state, so a
+            # healed or replaying replica draws the same noise), runs
+            # [x_t | x_0] through the trunk and logs
+            # diffusion_masked_share beside the loss;
+            # models.sdar_30b_a3b() is the published model.
+            "sdar_moe": sdar_moe_debug,
         }[args.model]()
         if args.attn != "default":
             import dataclasses
