@@ -5,6 +5,8 @@ exact parity with the host-side numpy quantizer in collectives.py, so either
 end of a DCN transfer can (de)quantize the other's payload.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -310,6 +312,110 @@ def test_flash_gradients_bf16_tolerance():
         a32, b32 = a.astype(jnp.float32), b.astype(jnp.float32)
         rel = float(jnp.max(jnp.abs(a32 - b32)) / (jnp.max(jnp.abs(b32)) + 1e-9))
         assert rel < 5e-2, rel
+
+
+# -- the forward's softmax state kept by the lane ---------------------------
+
+
+def _rel_err(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.max(np.abs(a - b)) / (np.max(np.abs(b)) + 1e-9))
+
+
+def _assert_forward_and_gradients_match(flash, dense, q, k, v, w, tol):
+    """``flash`` against ``dense``: the output and, of the loss sum(out * w),
+    the gradients by q, k and v, each to ``tol`` of the largest entry."""
+
+    def loss(fn, q, k, v):
+        return jnp.sum(fn(q, k, v).astype(jnp.float32) * w)
+
+    assert _rel_err(flash(q, k, v), dense(q, k, v)) <= tol
+    gf = jax.grad(lambda *a: loss(flash, *a), (0, 1, 2))(q, k, v)
+    gd = jax.grad(lambda *a: loss(dense, *a), (0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", gf, gd):
+        assert _rel_err(a, b) <= tol, (name, _rel_err(a, b))
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5), (jnp.bfloat16, 2e-2)], ids=["fp32", "bf16"])
+@pytest.mark.parametrize("head_dim", [128, 64])
+@pytest.mark.parametrize("S,block_k", [(512, 128), (512, 256), (512, 64), (384, 96)])
+def test_flash_lanewise_softmax_state_matches_dense(S, block_k, head_dim, dtype, tol):
+    """Forward and all three gradients against dense attention, GQA 4:1,
+    over sweeps that hold whole, masked and skipped tiles, with the row sum
+    kept as partial sums by the lane: at kv tiles of one and two whole lane
+    groups (128, 256: what compiles, interpreted here), of half a group
+    (64) and of three groups of 32 lanes (96)."""
+    from torchft_tpu.models.llama import dense_attention
+    from torchft_tpu.ops.flash_attention import flash_attention
+
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    q = jax.random.normal(ks[0], (1, S, 4, head_dim), dtype)
+    k = jax.random.normal(ks[1], (1, S, 1, head_dim), dtype)
+    v = jax.random.normal(ks[2], (1, S, 1, head_dim), dtype)
+    w = jax.random.normal(ks[3], (1, S, 4, head_dim), jnp.float32)
+    flash = functools.partial(flash_attention, block_q=128, block_k=block_k)
+    _assert_forward_and_gradients_match(flash, dense_attention, q, k, v, w, tol)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5), (jnp.bfloat16, 2e-2)], ids=["fp32", "bf16"])
+def test_flash_block_diffusion_lanewise_tiles_match_dense(dtype, tol):
+    """The block-diffusion kernels at tiles of a whole lane group, as they
+    compile (the sdar tests interpret tiles of 16 to 48)."""
+    from torchft_tpu.models.llama import block_diffusion_mask, dense_attention
+    from torchft_tpu.ops.flash_attention import flash_attention_block_diffusion
+
+    L, b, block, D = 256, 4, 128, 128
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    q = jax.random.normal(ks[0], (1, 2 * L, 4, D), dtype)
+    k = jax.random.normal(ks[1], (1, 2 * L, 1, D), dtype)
+    v = jax.random.normal(ks[2], (1, 2 * L, 1, D), dtype)
+    w = jax.random.normal(ks[3], (1, 2 * L, 4, D), jnp.float32)
+    mask = block_diffusion_mask(L, b)
+    flash = functools.partial(flash_attention_block_diffusion, block_length=b, block=block)
+    dense = functools.partial(dense_attention, mask=mask)
+    _assert_forward_and_gradients_match(flash, dense, q, k, v, w, tol)
+
+
+@pytest.mark.parametrize("block_k", [128, 64])
+def test_flash_block_with_a_fully_masked_q_tile_merges_to_zero_weight(block_k):
+    """An offset block whose first q tile sees no key (every step of its
+    sweep skipped): out 0 and lse <= -1e29 there under the deferred row
+    sum, and the ring's merge gives those rows no weight."""
+    from torchft_tpu.models.llama import dense_attention
+    from torchft_tpu.ops.flash_attention import flash_attention_block
+
+    S, D = 256, 128
+    ks = jax.random.split(jax.random.PRNGKey(13), 5)
+    q = jax.random.normal(ks[0], (1, S, 4, D), jnp.float32)
+    k_old, k_new = (jax.random.normal(kk, (1, S, 1, D), jnp.float32) for kk in ks[1:3])
+    v_old, v_new = (jax.random.normal(kk, (1, S, 1, D), jnp.float32) for kk in ks[3:5])
+    block = functools.partial(flash_attention_block, block_q=128, block_k=block_k)
+    # q rows at 0..255; the "new" keys at 128..383: rows 0..127 see none.
+    o_new, lse_new = block(q, k_new, v_new, 0, 128)
+    np.testing.assert_array_equal(np.asarray(o_new[:, :128]), 0.0)
+    assert float(jnp.max(lse_new[:, :, :128])) <= -1e29
+    assert bool(jnp.all(jnp.isfinite(lse_new[:, :, 128:]) & (lse_new[:, :, 128:] > -1e29)))
+    # ... and a block wholly in the future is that for every row.
+    o_far, lse_far = block(q, k_new, v_new, 0, 4096)
+    np.testing.assert_array_equal(np.asarray(o_far), 0.0)
+    assert float(jnp.max(lse_far)) <= -1e29
+    # The ring's merge (parallel/ring_attention.py, fold): the "old" keys
+    # sit at -256..-1, every row sees all of them.
+    o_old, lse_old = block(q, k_old, v_old, 0, -S)
+    merged = jnp.logaddexp(lse_old, lse_new)
+    w_old = jnp.exp(lse_old - merged).transpose(0, 2, 1)[..., None]
+    w_new = jnp.exp(lse_new - merged).transpose(0, 2, 1)[..., None]
+    assert float(jnp.max(w_new[:, :128])) == 0.0
+    out = w_old * o_old + w_new * o_new
+    np.testing.assert_allclose(  # rows 0..127: the old keys alone
+        np.asarray(out[:, :128]), np.asarray(o_old[:, :128]), rtol=0, atol=0
+    )
+    key_at = jnp.concatenate([jnp.arange(-S, 0), jnp.arange(128, 128 + S)])
+    want = dense_attention(
+        q, jnp.concatenate([k_old, k_new], 1), jnp.concatenate([v_old, v_new], 1),
+        mask=jnp.arange(S)[:, None] >= key_at[None, :],
+    )
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
 
 # ---------------------------------------------------------------------------

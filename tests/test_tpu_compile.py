@@ -101,6 +101,21 @@ def test_flash_long_context_fwd_and_grad_compile(one_chip):
     )
 
 
+# (B, S, Hq, Hkv, D) of the benchmark's causal cells: internlm2-raw (and
+# nemotron3-raw's one attention layer), lfm2-raw, mistral-raw and olmoe-raw.
+CELL_FLASH_SHAPES = [(2, 8192, 16, 8, 128), (2, 8192, 32, 8, 64), (4, 4096, 32, 8, 128)]
+
+
+@pytest.mark.parametrize("shape", CELL_FLASH_SHAPES, ids=str)
+def test_flash_at_the_cells_shapes_compiles(one_chip, shape):
+    """Forward, dq and dkv at tiles of 512 (the lane-wise softmax state at
+    head widths 128 and 64): three kernels named for the jit around them,
+    which is how ``flash_ms`` finds them in a trace."""
+    fn = jax.value_and_grad(_flash_loss, argnums=(0, 1, 2))
+    calls = _custom_calls(jax.jit(fn).lower(*_qkv(one_chip, *shape)).compile().as_text())
+    assert len(calls) == 3 and all("flash_attention" in c for c in calls), calls
+
+
 def _flash_block(q, k, v, q_offset, k_offset):
     from torchft_tpu.ops.flash_attention import flash_attention_block
 

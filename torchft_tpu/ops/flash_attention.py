@@ -35,7 +35,10 @@ final kv step. A step whose tile the mask empties is skipped via
 ``pl.when`` (no compute), and under the static masks its index map names
 the tile the sweep already holds, so nothing is fetched for it either;
 the ring's offset kernels, whose skip is decided by a dynamic scalar,
-still fetch the tile they skip.
+still fetch the tile they skip. The forward keeps its softmax state by
+the lane (``_fwd_step``): the row max replicated across 128 lanes, the
+row sum as 128 partial sums reduced once at the sweep's end, so a step
+has one cross-lane reduction (the max) and broadcasts nothing to store.
 
 Numerics: scores and softmax accumulate in fp32 regardless of input
 dtype; output is cast back to the input dtype. Tested bitwise-free
@@ -67,6 +70,7 @@ __all__ = [
 ]
 
 _NEG_INF = -1e30
+_LANES = 128
 
 
 def _interpret() -> bool:
@@ -111,23 +115,43 @@ def _scores(q_ref, k_ref, scale, mask_fn):
     return mask_fn(s)
 
 
+def _lanes_to(x, width: int):
+    """A lane-replicated [rows, 128] value at an accumulator's width."""
+    if width <= _LANES:
+        return x[:, :width]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], width))
+
+
 def _fwd_step(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, scale, mask_fn):
-    """One online-softmax accumulation of a kv block into the scratch."""
+    """One online-softmax accumulation of a kv block into the scratch.
+    The softmax state is kept by the lane: ``m_ref`` holds the running row
+    max replicated across its 128 lanes, ``l_ref`` PARTIAL row sums a row,
+    the tile's column groups folded in with elementwise adds (groups of
+    128 lanes in every compiled tile; of gcd(block_k, 128) under the CPU
+    tests' small tiles, the lanes past them staying 0), and
+    ``_fwd_finish`` reduces them across the lanes once. alpha is computed
+    on the replicated form, so the max is the one cross-lane reduction a
+    tile and nothing is broadcast to be stored."""
     s = _scores(q_ref, k_ref, scale, mask_fn)
-    m_prev = m_ref[:, :1]  # [block_q, 1]
-    l_prev = l_ref[:, :1]
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+    block_k = s.shape[1]
+    w = math.gcd(block_k, _LANES)
     v = v_ref[0, 0]
-    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+    m_prev = m_ref[:]  # [block_q, 128]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    groups = [
+        jnp.exp(s[:, g:g + w] - m_new[:, :w]) for g in range(0, block_k, w)
+    ]
+    p = jnp.concatenate(groups, axis=1)
+    l_ref[:, :w] = (
+        alpha[:, :w] * l_ref[:, :w] + functools.reduce(jnp.add, groups)
+    )
+    m_ref[:] = m_new
+    acc = acc_ref[:] * _lanes_to(alpha, acc_ref.shape[1])
+    acc_ref[:] = acc + jax.lax.dot_general(
         p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
 
 def _fwd_finish(o_ref, lse_ref, acc_ref, m_ref, l_ref):
@@ -138,7 +162,7 @@ def _fwd_finish(o_ref, lse_ref, acc_ref, m_ref, l_ref):
     # holds it), but CAN in an offset block entirely in the future: the
     # denom guard makes out 0 and lse ~ -1e30, which the block merge
     # weighs to zero.
-    denom = jnp.maximum(l_ref[:, :1], 1e-30)
+    denom = jnp.maximum(jnp.sum(l_ref[:], axis=-1, keepdims=True), 1e-30)
     o_ref[0, 0] = (acc_ref[:] / denom).astype(o_ref.dtype)
     # TPU tiles need the last two block dims (sublane, lane) aligned, so
     # the per-row LSE is broadcast across 8 sublanes: array [B,H,8,S].
@@ -233,7 +257,7 @@ def _flash_kernel(
     lse_ref,  # [1, 1, 8, block_q] f32 (logsumexp residual)
     acc_ref,  # VMEM [block_q, D] f32
     m_ref,  # VMEM [block_q, 128] f32 (row max, lane-broadcast)
-    l_ref,  # VMEM [block_q, 128] f32 (row sum, lane-broadcast)
+    l_ref,  # VMEM [block_q, 128] f32 (partial row sums by the lane)
     *,
     scale: float,
     causal: bool,
