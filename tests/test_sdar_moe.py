@@ -13,8 +13,6 @@ import logging
 import os
 import subprocess
 import sys
-import threading
-from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
@@ -24,7 +22,6 @@ import pytest
 from benchmark import cells
 from benchmark.tests import test_sdar_reference as _reference_tests
 from torchft_tpu.coordination import LighthouseServer
-from torchft_tpu.manager import Manager
 from torchft_tpu.models import llama, sdar_30b_a3b, sdar_moe_debug
 from torchft_tpu.models.llama import MoEMLP, block_diffusion_attention, block_diffusion_mask
 from torchft_tpu.ops.flash_attention import (
@@ -36,7 +33,6 @@ from torchft_tpu.parallel import auto_mesh
 from torchft_tpu.parallel.train import (
     TrainState,
     build_model,
-    default_optimizer,
     diffusion_streams,
     init_train_state,
     make_eval_step,
@@ -44,7 +40,7 @@ from torchft_tpu.parallel.train import (
     make_train_step,
     state_shardings,
 )
-from torchft_tpu.process_group import ProcessGroupSocket
+from tests.test_ft_step import two_replicas
 
 adapter = cells.arch_module("sdar_moe", "adapter")
 reference = cells.arch_module("sdar_moe", "reference")
@@ -305,56 +301,13 @@ def test_the_eight_shares_add_up_to_the_whole_layer_under_renormalised_gates():
 # -- (h) two replicas under Managers --------------------------------------------
 
 
-def _replica(replica, lighthouse_addr, barrier, steps=2):
-    """One replica group as a thread: the DDP loop of train_hsdp.py around
-    ``make_grad_step`` under this objective, every replica fed ONE batch."""
-    import optax
-
-    cfg = sdar_moe_debug(dtype=jnp.float32)
-    mesh = auto_mesh(1, devices=jax.devices()[:1])
-    model = build_model(cfg, mesh)
-    state, sh = init_train_state(model, mesh, jax.random.PRNGKey(0), (2, 32))
-    assert {f.name for f in dataclasses.fields(TrainState)} == {"step", "params", "opt_state"}
-    grad_step = make_grad_step(model, mesh, sh)
-    optimizer = default_optimizer()
-    params, opt_state = state.params, state.opt_state
-    manager = Manager(
-        pg=ProcessGroupSocket(timeout=15.0), min_replica_size=2, use_async_quorum=False,
-        timeout=15.0, quorum_timeout=30.0, replica_id=f"sdar{replica}",
-        lighthouse_addr=lighthouse_addr, group_rank=0, group_world_size=1, init_sync=False,
-    )
-    losses = []
-    try:
-        for step in range(steps):
-            barrier.wait(timeout=120)
-            manager.start_quorum()
-            loss, grads = grad_step(params, _data(cfg.vocab_size, 2, 32, seed=step))
-            leaves, tree = jax.tree_util.tree_flatten(grads)
-            works = [manager.allreduce(np.asarray(leaf)) for leaf in leaves]
-            reduced = jax.tree_util.tree_unflatten(tree, [w.wait(timeout=30)[0] for w in works])
-            if manager.should_commit():
-                updates, opt_state = optimizer.update(reduced, opt_state, params)
-                params = optax.apply_updates(params, updates)
-            losses.append(float(loss))
-    finally:
-        manager.shutdown()
-    return losses, [np.asarray(x) for x in jax.tree_util.tree_leaves(params)]
-
-
 @pytest.mark.timeout(300)
 def test_two_replicas_fed_one_batch_commit_bitwise_equal_parameters():
-    """No RNG in the state and none in the loop: two replicas that see the
-    same tokens draw the same noise, compute the same loss and, after the
+    """No RNG in the state and none in the loop (``FTStep``): two replicas that
+    see the same tokens draw the same noise, compute the same loss and, after the
     Managers' allreduce and commit, hold the same parameters bit for bit."""
-    lighthouse = LighthouseServer(
-        bind="127.0.0.1:0", min_replicas=2, join_timeout_ms=20000, quorum_tick_ms=50)
-    barrier = threading.Barrier(2)
-    try:
-        with ThreadPoolExecutor(max_workers=2) as pool:
-            futs = [pool.submit(_replica, r, lighthouse.address(), barrier) for r in range(2)]
-            (losses0, leaves0), (losses1, leaves1) = [f.result(timeout=240) for f in futs]
-    finally:
-        lighthouse.shutdown()
+    assert {f.name for f in dataclasses.fields(TrainState)} == {"step", "params", "opt_state"}
+    (losses0, leaves0), (losses1, leaves1) = two_replicas(sdar_moe_debug, "sdar")
     assert losses0 == losses1 and len(losses0) == 2 and losses0[0] != losses0[1]
     assert all(np.array_equal(a, b) for a, b in zip(leaves0, leaves1))
     cfg = sdar_moe_debug(dtype=jnp.float32)

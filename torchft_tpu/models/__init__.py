@@ -28,3 +28,15 @@ from torchft_tpu.models.llama import (  # noqa: F401
     sdar_30b_a3b,
     sdar_moe_debug,
 )
+
+# What ``train_hsdp.py --model`` names: each architecture's small preset, the 125M
+# ``small``, and ``olmoe`` at its published 6.9B: for a group's mesh of chips, not one.
+PRESETS = {
+    "debug": llama_debug,
+    "small": llama_small,
+    "moe": llama_moe_debug,
+    "olmoe": olmoe_1b_7b,
+    "nemotron_h": nemotron_h_debug,
+    "lfm2_moe": lfm2_moe_debug,
+    "sdar_moe": sdar_moe_debug,
+}

@@ -3,9 +3,9 @@
 Design notes (why this is not a torch port):
 - flax.linen + einsum contractions keep every FLOP on the MXU; compute in
   bfloat16, params in float32 (standard TPU mixed precision).
-- The layer stack is an ``nn.scan`` over a single remat'd block: one XLA
-  while-loop body compiled once regardless of depth (fast compiles, and
-  rematerialization trades HBM for FLOPs as the scaling playbook suggests).
+- The layer stack is an ``nn.scan`` over a single remat'd block (one XLA
+  while-loop body compiled once regardless of depth) or, for the unlike layers
+  of a ``layer_pattern``, a loop over ``MixerLayer``s, each remat'd alone.
 - Attention is pluggable: ``dense`` (single-chip / short context) or
   ``ring`` (context parallelism over a mesh axis via shard_map + ppermute —
   see torchft_tpu/parallel/ring_attention.py). Long-context is first-class,

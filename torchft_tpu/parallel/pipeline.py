@@ -45,7 +45,7 @@ from torchft_tpu.models.llama import (
     rope_table,
 )
 from torchft_tpu.parallel.sharding import path_keys, tree_specs_like
-from torchft_tpu.parallel.train import TrainState, default_optimizer
+from torchft_tpu.parallel.train import TrainState, apply_update, default_optimizer
 
 
 def gpipe_loop(
@@ -271,10 +271,9 @@ def make_pipeline_train_step(
 
     def step_fn(state: TrainState, batch):
         loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
-        updates, opt_state = optimizer.update(
-            grads, state.opt_state, state.params
+        params, opt_state = apply_update(
+            optimizer, 0.0, state.params, state.opt_state, (grads, None)
         )
-        params = optax.apply_updates(state.params, updates)
         return (
             TrainState(
                 step=state.step + 1, params=params, opt_state=opt_state

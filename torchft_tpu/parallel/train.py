@@ -240,6 +240,18 @@ def router_bias_abs_max(params) -> jax.Array:
     ]))
 
 
+def apply_update(optimizer, rate: float, params, opt_state, reduced):
+    """The fused and the split step's update -> (params, opt_state).
+    ``reduced`` = (grads, loads): the loads are every expert's assignments
+    where the recipe moves the selection biases at ``rate``, else None."""
+    grads, loads = reduced
+    updates, opt_state = optimizer.update(grads, opt_state, params)
+    new = optax.apply_updates(params, updates)
+    if loads is not None:
+        new = update_router_bias(new, params, loads, rate)
+    return new, opt_state
+
+
 def _head_loss_chunks(h, w, targets, weights, C: int):
     """What both passes of ``_head_loss_sum`` scan over: the head in the
     compute dtype and the chunks of the hidden states, the targets and the
@@ -546,15 +558,12 @@ def make_train_step(
                 k: v.sum(axis=0) if k == "moe_load" else v.mean()
                 for k, v in routers.items()
             }
-        updates, opt_state = optimizer.update(
-            grads, state.opt_state, state.params
+        loads = router.pop("moe_load", None)
+        params, opt_state = apply_update(
+            optimizer, model.cfg.router_bias_update_rate,
+            state.params, state.opt_state, (grads, loads),
         )
-        params = optax.apply_updates(state.params, updates)
-        if "moe_load" in router:
-            params = update_router_bias(
-                params, state.params, router.pop("moe_load"),
-                model.cfg.router_bias_update_rate,
-            )
+        if loads is not None:
             router["router_bias_abs_max"] = router_bias_abs_max(params)
         gnorm = optax.global_norm(grads)
         new_state = TrainState(
@@ -576,18 +585,13 @@ def make_grad_step(
     shardings: TrainState,
     with_metrics: bool = False,
 ) -> Callable[[Any, Any], Tuple[Any, Any]]:
-    """(params, batch) -> (loss, grads): the DDP variant where the optimizer
-    update is applied *after* the Manager's outer-axis gradient allreduce.
+    """(params, batch) -> (loss, grads): the bare gradient program.
     ``with_metrics``: ((loss, router metrics), grads), the metrics those
-    of ``_loss_and_metrics`` ({} for a dense model).
-
-    A model with ``router_bias_update_rate`` > 0 hands its loads out in
-    the metrics (``moe_load``, a vector): the loop takes them out, reduces
-    them over the replicas with the gradients and gives them to
-    ``update_router_bias`` after its own optimizer update, as
-    ``train_hsdp.py`` does. Without ``with_metrics`` no load leaves the
-    step, so a loop built on it would train that model with its selection
-    biases standing still: said once here, as a warning."""
+    of ``_loss_and_metrics`` ({} for a dense model), ``moe_load`` among
+    them; a loop takes ``make_split_grad_step`` and ``make_apply_step``.
+    Without ``with_metrics`` no load leaves the step, so a loop built on
+    it would train a model with ``router_bias_update_rate`` > 0 with its
+    selection biases standing still: said once here, as a warning."""
     bsh = batch_sharding(mesh)
     batch_sh = {"inputs": bsh, "targets": bsh, "mask": bsh}
     if model.cfg.router_bias_update_rate and not with_metrics:
@@ -595,8 +599,9 @@ def make_grad_step(
             "make_grad_step(with_metrics=False) for a model with "
             "router_bias_update_rate=%g: the step returns no moe_load, so "
             "whatever applies its gradients cannot move the selection "
-            "biases (ask for the metrics and call update_router_bias; a "
-            "caller that only compares loss and gradients loses nothing)",
+            "biases (build the loop on make_split_grad_step and "
+            "make_apply_step; a caller that only compares loss and "
+            "gradients loses nothing)",
             model.cfg.router_bias_update_rate,
         )
 
@@ -612,6 +617,42 @@ def make_grad_step(
         fn,
         in_shardings=(shardings.params, batch_sh),
         out_shardings=(None, shardings.params),
+    )
+
+
+def make_split_grad_step(model: Transformer, mesh: Mesh, shardings: TrainState):
+    """The split step, its update *after* the Manager's outer-axis allreduce:
+    (params, batch) -> (loss, metrics, to_reduce). ``to_reduce`` = (grads,
+    loads) is all that rides that allreduce (the loads' mean over the replicas
+    has the sign of their sum) and what ``make_apply_step`` takes whole."""
+    bsh = batch_sharding(mesh)
+    batch_sh = {"inputs": bsh, "targets": bsh, "mask": bsh}
+    grad_step = make_grad_step(model, mesh, shardings, with_metrics=True)
+
+    def fn(params, batch):
+        (loss, metrics), grads = grad_step(params, batch)
+        loads = metrics.pop("moe_load", None)
+        return loss, metrics, (grads, loads)
+
+    return jax.jit(
+        fn,
+        in_shardings=(shardings.params, batch_sh),
+        out_shardings=(None, None, (shardings.params, None)),
+    )
+
+
+def make_apply_step(model: Transformer, shardings: TrainState, optimizer=None):
+    """(params, opt_state, reduced) -> (params, opt_state):
+    ``make_train_step``'s own update. Not donated (ROADMAP R2)."""
+    return jax.jit(
+        functools.partial(
+            apply_update, optimizer or _DEFAULT_OPT,
+            model.cfg.router_bias_update_rate,
+        ),
+        in_shardings=(
+            shardings.params, shardings.opt_state, (shardings.params, None),
+        ),
+        out_shardings=(shardings.params, shardings.opt_state),
     )
 
 

@@ -30,13 +30,12 @@ import time
 
 
 def main() -> int:
+    from torchft_tpu.models import PRESETS
+
     parser = argparse.ArgumentParser()
     parser.add_argument("--steps", type=int, default=20)
     parser.add_argument(
-        "--model",
-        choices=["debug", "small", "moe", "olmoe", "nemotron_h", "lfm2_moe", "sdar_moe",
-                 "pipeline"],
-        default="debug",
+        "--model", choices=[*PRESETS, "pipeline"], default="debug"
     )
     parser.add_argument("--batch", type=int, default=8)
     parser.add_argument("--seq", type=int, default=64)
@@ -98,24 +97,15 @@ def main() -> int:
     import numpy as np
 
     from torchft_tpu.device_mesh import ft_init_device_mesh
+    from torchft_tpu.ft_step import FTStep
     from torchft_tpu.manager import Manager
-    from torchft_tpu.models import (
-        lfm2_moe_debug,
-        llama_debug,
-        llama_moe_debug,
-        llama_small,
-        nemotron_h_debug,
-        olmoe_1b_7b,
-        sdar_moe_debug,
-    )
     from torchft_tpu.parallel import auto_mesh
     from torchft_tpu.parallel.train import (
         build_model,
-        default_optimizer,
         init_train_state,
-        make_grad_step,
+        make_apply_step,
+        make_split_grad_step,
         router_bias_abs_max,
-        update_router_bias,
     )
     from torchft_tpu.process_group import make_process_group
 
@@ -147,7 +137,6 @@ def main() -> int:
     else:
         mesh = auto_mesh(n_dev)
     B, S = args.batch, args.seq
-    optimizer = default_optimizer()
     if args.model == "pipeline":
         from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -156,54 +145,28 @@ def main() -> int:
             make_pipeline_loss,
         )
 
-        cfg = llama_debug(num_layers=4)
+        cfg = PRESETS["debug"](num_layers=4)
+        model = build_model(cfg, mesh)  # its cfg only: the trunk is the pipeline's
         state, shardings = init_pipeline_state(
             cfg, mesh, jax.random.PRNGKey(0), (B, S)
         )
         loss_fn = make_pipeline_loss(cfg, mesh, n_micro=2)
         bsh = NamedSharding(mesh, P("dp", None))
-        # (loss, no router metrics), grads: make_grad_step's form below.
+
+        def loss_and_grads(params, batch):
+            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+            return loss, {}, (grads, None)  # make_split_grad_step's form
+
         grad_step = jax.jit(
-            jax.value_and_grad(lambda p, b: (loss_fn(p, b), {}), has_aux=True),
+            loss_and_grads,
             in_shardings=(
                 shardings.params,
                 {"inputs": bsh, "targets": bsh, "mask": bsh},
             ),
-            out_shardings=(None, shardings.params),
+            out_shardings=(None, None, (shardings.params, None)),
         )
     else:
-        cfg = {
-            "debug": llama_debug,
-            "small": llama_small,
-            "moe": llama_moe_debug,
-            # The published sizes: 6.9B parameters, for a group's mesh
-            # of chips, not for one.
-            "olmoe": olmoe_1b_7b,
-            # The small preset of the hybrid stack (Mamba-2, experts of
-            # which a share is held, rope-free attention): pattern
-            # MEMEM*EME at test widths. models.nemotron3_nano() is the
-            # published model, for a deployment's meshes.
-            "nemotron_h": nemotron_h_debug,
-            # The small preset of LFM2's stack (two sub-layers a layer:
-            # gated short convolutions, rotary attention with per-head QK
-            # norms, a dense feed-forward, SiLU-gated experts of which a
-            # share is held, a tied head): pattern CD*ECECECE at test
-            # widths; models.lfm2_8b_a1b() is the published model. Its
-            # selection biases move here as in make_train_step: the step's
-            # loads ride the replica allreduce beside the gradients and
-            # apply_step updates the biases after the optimizer.
-            "lfm2_moe": lfm2_moe_debug,
-            # The small preset of SDAR's stack (rotary attention with
-            # per-head QK norms, SiLU-gated experts of which a share is
-            # held under a renormalised softmax router, an untied head)
-            # trained by block diffusion: the step builds the noisy stream
-            # from the batch's own tokens (no RNG in the state, so a
-            # healed or replaying replica draws the same noise), runs
-            # [x_t | x_0] through the trunk and logs
-            # diffusion_masked_share beside the loss;
-            # models.sdar_30b_a3b() is the published model.
-            "sdar_moe": sdar_moe_debug,
-        }[args.model]()
+        cfg = PRESETS[args.model]()
         if args.attn != "default":
             import dataclasses
 
@@ -212,8 +175,8 @@ def main() -> int:
         state, shardings = init_train_state(
             model, mesh, jax.random.PRNGKey(0), (B, S)
         )
-        grad_step = make_grad_step(model, mesh, shardings, with_metrics=True)
-    params, opt_state = state.params, state.opt_state
+        grad_step = make_split_grad_step(model, mesh, shardings)
+    apply_step = make_apply_step(model, shardings)
 
     # TORCHFT_PERF: record the compiled step's FLOPs/bytes once (same
     # shapes the loop runs) so step logs carry MFU/roofline. The guard
@@ -228,51 +191,14 @@ def main() -> int:
             "targets": jax.random.randint(k0, (B, S), 0, cfg.vocab_size),
             "mask": jnp.ones((B, S), jnp.int32),
         }
-        perf_note_compiled("hsdp_grad_step", grad_step, params, probe,
+        perf_note_compiled("hsdp_grad_step", grad_step, state.params, probe,
                            tokens_per_step=B * S)
         del probe
 
-    def apply_fn(params, opt_state, grads, loads):
-        import optax
-
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        new = optax.apply_updates(params, updates)
-        if loads is not None:
-            # A model whose recipe moves its routers' selection biases out
-            # of the gradient: from the replicas' mean loads, which has the
-            # sign of their sum.
-            new = update_router_bias(
-                new, params, loads, cfg.router_bias_update_rate
-            )
-        return new, opt_state
-
-    apply_step = jax.jit(
-        apply_fn,
-        in_shardings=(
-            shardings.params, shardings.opt_state, shardings.params, None,
-        ),
-        out_shardings=(shardings.params, shardings.opt_state),
-    )
-
-    # Heal contract. http: the recovering group receives params + optimizer
-    # state as host numpy pytrees and re-shards them onto its own mesh.
-    # pg-sharded: leaves stay jax arrays end to end — the sender ships only
-    # addressable shards and the receiver rebuilds each leaf directly onto
-    # its shardings (reference pg_transport.py:230-298 in-place receive).
+    # Heal contract (FTStep): host numpy pytrees over http; pg-sharded keeps
+    # the leaves jax arrays end to end, addressable shards rebuilt straight
+    # onto the receiver's shardings (reference pg_transport.py:230-298).
     sharded_heal = args.ckpt_transport == "pg-sharded"
-
-    def hsdp_state_dict():
-        if sharded_heal:
-            return {"params": params, "opt_state": opt_state}
-        return {
-            "params": jax.tree_util.tree_map(np.asarray, params),
-            "opt_state": jax.tree_util.tree_map(np.asarray, opt_state),
-        }
-
-    def hsdp_load_state(state_dict):
-        nonlocal params, opt_state
-        params = jax.device_put(state_dict["params"], shardings.params)
-        opt_state = jax.device_put(state_dict["opt_state"], shardings.opt_state)
 
     pg = make_process_group(timeout=30.0)
     checkpoint_transport = None
@@ -282,11 +208,7 @@ def main() -> int:
         def ckpt_target():
             # Structure mirrors Manager._manager_state_dict(); the
             # "torchft" scalars need no device target.
-            return {
-                "user": {
-                    "default": {"params": params, "opt_state": opt_state}
-                }
-            }
+            return {"user": {"default": ft.state_dict()}}
 
         checkpoint_transport = PGTransport(
             pg, timeout=60.0, state_dict_fn=ckpt_target, sharded=True
@@ -295,8 +217,6 @@ def main() -> int:
     manager = Manager(
         pg=pg,
         checkpoint_transport=checkpoint_transport,
-        state_dict=hsdp_state_dict,
-        load_state_dict=hsdp_load_state,
         min_replica_size=args.min_replicas,
         use_async_quorum=True,
         timeout=60.0,
@@ -305,6 +225,12 @@ def main() -> int:
         max_retries=20,
     )
     mm = ft_init_device_mesh(manager, mesh=mesh)
+    ft = FTStep(
+        manager, mm, grad_step, apply_step, state.params, state.opt_state,
+        quantize_bits=args.quantize_bits if args.quantize else None,
+        sharded_heal=sharded_heal,
+    )
+    del state
     # Mesh-relative views (reference ManagedDeviceMesh surface): the
     # HSDP selection pairs the dynamic replica dim with the fsdp shard
     # axis; "world" flattens every axis for a composite rank/size.
@@ -320,7 +246,7 @@ def main() -> int:
     )
 
     # Durable regime: host-numpy params + optimizer + manager scalars.
-    # Restore goes through hsdp_load_state (the heal loader), which
+    # Restore goes through FTStep.load_state_dict (the heal loader), which
     # re-shards onto this group's mesh; the optimizer tree is re-hung on
     # the live structure by leaf order first (orbax round-trips optax
     # NamedTuples as plain containers).
@@ -328,8 +254,8 @@ def main() -> int:
 
     def durable_state_fn():
         return {
-            "params": jax.tree_util.tree_map(np.asarray, params),
-            "opt_state": jax.tree_util.tree_map(np.asarray, opt_state),
+            "params": jax.tree_util.tree_map(np.asarray, ft.params),
+            "opt_state": jax.tree_util.tree_map(np.asarray, ft.opt_state),
             "manager": manager.state_dict(),
         }
 
@@ -341,11 +267,11 @@ def main() -> int:
         )
         snap = ckpt.restore_if_any()
         if snap is not None:
-            hsdp_load_state(
+            ft.load_state_dict(
                 {
                     "params": snap["params"],
                     "opt_state": DurableRegime.rehang_like(
-                        opt_state, snap["opt_state"]
+                        ft.opt_state, snap["opt_state"]
                     ),
                 }
             )
@@ -372,7 +298,6 @@ def main() -> int:
                 break
             t_step0 = time.time()
             telemetry.trace_window(step)
-            manager.start_quorum()
             # Deterministic batch per step: every group that commits step k
             # computes identical params (bitwise) — heal-invariant.
             key = jax.random.PRNGKey(step)
@@ -383,32 +308,15 @@ def main() -> int:
                 ),
                 "mask": jnp.ones((B, S), jnp.int32),
             }
-            # inner: compiled HSDP; `router` is {} for a model with no experts
-            (loss, router), grads = grad_step(params, batch)
-            # Every expert's assignments, where the model's step updates
-            # its selection biases (None elsewhere): they are averaged
-            # over the replicas in the gradients' own collective.
-            loads = router.pop("moe_load", None)
-            grads, loads = mm.allreduce_grads(
-                (grads, loads),
-                should_quantize=args.quantize,
-                quantize_bits=args.quantize_bits
-            )  # outer: FT replica axis over DCN
-            # Fenced: the commit decision + param/opt update must be one
-            # critical section vs concurrent checkpoint sends (async
-            # quorum), or a healed peer snapshots a torn (params, step).
-            with manager.fenced_state_dict():
-                committed = manager.should_commit()
-                if committed:
-                    params, opt_state = apply_step(
-                        params, opt_state, grads, loads
-                    )
+            # inner: compiled HSDP (`router` is {} for a model with no
+            # experts); outer: the FT replica axis over DCN, then the gate
+            committed, loss, router = ft(batch)
             if committed:
                 losses.append(float(loss))
                 router = {k: float(v) for k, v in router.items()}
-                if loads is not None:
+                if cfg.router_bias_update_rate:
                     router["router_bias_abs_max"] = float(
-                        router_bias_abs_max(params)
+                        router_bias_abs_max(ft.params)
                     )
                 logging.info(
                     "[group %s] step %d loss %.4f participants %d%s%s",
@@ -433,7 +341,7 @@ def main() -> int:
                     ckpt.on_commit(manager.current_step(), durable_state_fn)
         if args.result_dir:
             os.makedirs(args.result_dir, exist_ok=True)
-            flat = jax.tree_util.tree_leaves(params)
+            flat = jax.tree_util.tree_leaves(ft.params)
             with open(
                 os.path.join(args.result_dir, f"group{group}.json"), "w"
             ) as f:
@@ -478,7 +386,7 @@ def main() -> int:
                                 ),
                             }
                             for x in jax.tree_util.tree_leaves(
-                                (params, opt_state)
+                                (ft.params, ft.opt_state)
                             )
                         ],
                         "memory": [
