@@ -25,6 +25,7 @@ from torchft_tpu.ddp import DistributedDataParallel  # noqa: E402
 from torchft_tpu.ft_step import FTStep  # noqa: E402
 from torchft_tpu.manager import Manager  # noqa: E402
 from torchft_tpu.models import (  # noqa: E402
+    joyai_flash_debug,
     lfm2_moe_debug,
     llama_debug,
     llama_moe_debug,
@@ -49,6 +50,7 @@ SMALL = {
     "nemotron_h_debug": nemotron_h_debug,
     "lfm2_moe_debug": lfm2_moe_debug,
     "sdar_moe_debug": sdar_moe_debug,
+    "joyai_flash_debug": joyai_flash_debug,
     # the published preset cut to test widths, as tests/test_olmoe.py's TINY
     "olmoe_1b_7b": functools.partial(
         olmoe_1b_7b, hidden_size=64, intermediate_size=32, num_layers=2, num_heads=4,
@@ -100,9 +102,11 @@ def test_the_split_step_is_the_fused_step_bit_for_bit(name):
     assert same(params, fused.params, but) and same(opt_state, fused.opt_state, but)
     assert not same(params, state.params)
     assert set(router) == set(metrics) - {"loss", "grad_norm", "router_bias_abs_max"}
+    assert ("loss_mtp" in router) == bool(cfg.mtp_layers) == (name == "joyai_flash_debug")
     assert all(float(router[k]) == float(metrics[k]) for k in router)
     # the loads leave the step only beside the gradients, and only where the recipe moves biases
-    assert (to_reduce[1] is not None) == bool(cfg.router_bias_update_rate) == (name == "lfm2_moe_debug")
+    assert (to_reduce[1] is not None) == bool(cfg.router_bias_update_rate) == (
+        name in ("lfm2_moe_debug", "joyai_flash_debug"))
     if cfg.router_bias_update_rate:
         assert float(router_bias_abs_max(state.params)) == 0.0
         assert float(router_bias_abs_max(params)) == pytest.approx(cfg.router_bias_update_rate)

@@ -1,0 +1,430 @@
+"""JoyAI-LLM-Flash: latent attention (a query and key of a rope-free and a
+rotary part, one rotary key a position for all heads, values of a width of
+their own) and a multi-token-prediction module in the program, against the
+benchmark's plain reference at a small size on the CPU, in float32 with
+seeded weights; the latent Pallas kernels, interpreted, against the dense
+path; the two rotary pairings; the shares of an expert layer against the
+whole layer; a sharded mesh; two replicas under Managers; the presets and
+``train_hsdp.py --model joyai_flash``."""
+
+import dataclasses
+import logging
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import cells
+from benchmark.tests import test_joyai_reference as _reference_tests
+from torchft_tpu.coordination import LighthouseServer
+from torchft_tpu.models import PRESETS, MLAConfig, joyai_flash_debug, joyai_llm_flash, llama
+from torchft_tpu.models.llama import MoEMLP, apply_rope, rope_table
+from torchft_tpu.models.mla import (
+    LatentAttention,
+    apply_rope_interleaved,
+    latent_dense_attention,
+)
+from torchft_tpu.ops.flash_attention import flash_attention_mla, supports_mla
+from torchft_tpu.parallel import auto_mesh, make_mesh
+from torchft_tpu.parallel.sharding import param_specs
+from torchft_tpu.parallel.train import (
+    build_model,
+    init_train_state,
+    make_grad_step,
+    make_train_step,
+    state_shardings,
+)
+from tests.test_ft_step import two_replicas
+from tests.test_sdar_moe import _data, _leaf_errors
+
+adapter = cells.arch_module("joyai_flash", "adapter")
+reference = cells.arch_module("joyai_flash", "reference")
+tiny = _reference_tests.tiny
+
+# The benchmark's own tests of this architecture (benchmark/tests is not in
+# tier-1's path), collected here under their own names, no body copied.
+for _name, _obj in vars(_reference_tests).items():
+    if _name.startswith("test_") and callable(_obj):
+        globals()[_name] = _obj
+
+
+def _setup(c, seq, batch=2, seed=0, **cfg_overrides):
+    cfg = dataclasses.replace(adapter.model_config(c, seq), remat=False, **cfg_overrides)
+    mesh = auto_mesh(1, devices=jax.devices()[:1])
+    model = build_model(cfg, mesh)
+    data = _data(c["vocab_size"], batch, seq, seed + 1)
+    params = model.init(jax.random.PRNGKey(seed), data["inputs"])["params"]
+    return model, mesh, params, data
+
+
+# -- (a) the kernels ---------------------------------------------------------
+
+
+def _per_head_dense(q_nope, q_rope, k_nope, k_rope_heads, v):
+    """Dense causal attention over 192-wide queries and keys joined, the
+    rotary key given for EVERY head ([B,S,H,Dr])."""
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    k = jnp.concatenate([k_nope, k_rope_heads], axis=-1)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    keep = jnp.tril(jnp.ones((q.shape[1],) * 2, dtype=bool))
+    p = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+@pytest.mark.parametrize("seq,heads,widths,blocks", [
+    (128, 3, (32, 16, 32), (32, 32)),   # 4 x 4 tiles: the skipped tiles' clamped index maps
+    (96, 2, (48, 16, 32), (48, 32)),    # unlike tiles, the cell's ratio of widths 192 | 128
+    (64, 4, (16, 8, 24), (64, 64)),     # one tile
+    (128, 1, (128, 64, 128), (64, 64)),  # the published widths, one head
+])
+def test_the_latent_kernels_are_the_dense_path_at_unlike_widths(seq, heads, widths, blocks):
+    """Interpreted, float32: the output and the gradients of both parts of
+    the queries, both parts of the keys and the values against the dense
+    path, and against dense attention over joined 192-wide heads in which
+    every head has a rotary key of its own: the shared key's gradient is
+    the sum of those over the heads."""
+    dn, dr, dv = widths
+    keys = jax.random.split(jax.random.PRNGKey(seq + heads), 6)
+    q_nope, k_nope = (jax.random.normal(k, (2, seq, heads, dn)) for k in keys[:2])
+    q_rope = jax.random.normal(keys[2], (2, seq, heads, dr))
+    k_rope = jax.random.normal(keys[3], (2, seq, dr))
+    v, w = (jax.random.normal(k, (2, seq, heads, dv)) for k in keys[4:])
+    assert supports_mla(seq, dn, dr, dv, *blocks)
+
+    def flash(*a):
+        return flash_attention_mla(*a, block_q=blocks[0], block_k=blocks[1])
+
+    args = (q_nope, q_rope, k_nope, k_rope, v)
+    assert flash(*args).shape == (2, seq, heads, dv)
+    assert jnp.allclose(flash(*args), latent_dense_attention(*args), atol=2e-5)
+    grads = lambda f, *a: jax.grad(  # noqa: E731
+        lambda *b: (f(*b) * w).sum(), argnums=(0, 1, 2, 3, 4))(*a)
+    got, want = grads(flash, *args), grads(latent_dense_attention, *args)
+    for g, r, name in zip(got, want, ("dq_nope", "dq_rope", "dk_nope", "dk_rope", "dv")):
+        assert g.shape == r.shape and jnp.allclose(g, r, atol=1e-4), (
+            name, float(jnp.abs(g - r).max()))
+    a_head = jnp.broadcast_to(k_rope[:, :, None], (2, seq, heads, dr))
+    per_head = grads(_per_head_dense, q_nope, q_rope, k_nope, a_head, v)
+    assert jnp.allclose(got[3], per_head[3].sum(axis=2), atol=1e-4)
+    assert float(jnp.abs(per_head[3][:, :, 0] - got[3]).max()) > 1e-2 or heads == 1
+    for g, r in zip(got[:3] + got[4:], per_head[:3] + per_head[4:]):
+        assert jnp.allclose(g, r, atol=1e-4)
+
+
+def test_the_latent_family_refuses_what_it_does_not_compute(monkeypatch):
+    from torchft_tpu.ops import flash_attention
+
+    assert not supports_mla(100, 128, 64, 128)  # no whole tiles
+    monkeypatch.setattr(flash_attention, "_interpret", lambda: False)
+    assert supports_mla(8192, 128, 64, 128) and supports_mla(8192, 128, 128, 256)
+    assert not supports_mla(8192, 96, 64, 128) and not supports_mla(8192, 128, 64, 64)
+    assert not supports_mla(8192, 128, 48, 128)
+    with pytest.raises(ValueError, match="latent_dense_attention"):
+        flash_attention_mla(
+            jnp.zeros((1, 128, 2, 96)), jnp.zeros((1, 128, 2, 64)),
+            jnp.zeros((1, 128, 2, 96)), jnp.zeros((1, 128, 64)),
+            jnp.zeros((1, 128, 2, 128)))
+
+
+# -- (b) the rotary pairing ---------------------------------------------------
+
+
+def test_the_interleaved_rotation_is_the_references_up_to_one_layout_and_not_the_half_split():
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 12, 3, 8))
+    y = jax.random.normal(jax.random.PRNGKey(1), (2, 12, 1, 8))
+    positions = jnp.broadcast_to(jnp.arange(12), (2, 12))
+    cos, sin = rope_table(positions, 8, 3.2e7, jnp.float32)
+    pairs, halves = apply_rope_interleaved(x, cos, sin), apply_rope(x, cos, sin)
+    want = reference._rotary_pairs(x, 3.2e7)
+    # the program lays the turned pairs out [evens | odds]
+    assert jnp.allclose(pairs[..., :4], want[..., 0::2], atol=1e-6)
+    assert jnp.allclose(pairs[..., 4:], want[..., 1::2], atol=1e-6)
+    score = lambda a, b: jnp.einsum("bqhd,bkd->bhqk", a, b[:, :, 0])  # noqa: E731
+    same = score(pairs, apply_rope_interleaved(y, cos, sin))
+    assert jnp.allclose(same, score(want, reference._rotary_pairs(y, 3.2e7)), atol=1e-5)
+    # half-split pairs channel i with i + D/2: another rotation, other scores
+    assert float(jnp.abs(same - score(halves, apply_rope(y, cos, sin))).max()) > 1e-2
+
+
+# -- (c) the system against the reference --------------------------------------
+
+
+def test_the_latent_mixer_is_the_references_attention(caplog):
+    c = tiny()
+    cfg = dataclasses.replace(adapter.model_config(c, 32), remat=False)
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 32, 64))
+    positions = jnp.broadcast_to(jnp.arange(32), (2, 32))
+    tables = rope_table(positions, 8, cfg.rope_theta, jnp.float32)
+    mixer = LatentAttention(cfg)
+    params = mixer.init(jax.random.PRNGKey(0), x, *tables)["params"]
+    assert {k: v["kernel"].shape for k, v in params.items() if "kernel" in v} == {
+        "wq_a": (64, 48), "wq_b": (48, 4, 24), "wkv_a": (64, 40), "wkv_b": (32, 4, 32),
+        "wo": (4, 16, 64)}
+    assert params["q_norm"]["scale"].shape == (48,) and params["kv_norm"]["scale"].shape == (32,)
+    with jax.default_matmul_precision("highest"):
+        want = reference.attention(x, params, c, lambda a: a)
+        got = mixer.apply({"params": params}, x, *tables)
+    assert jnp.allclose(got, want, atol=2e-5)
+    with pytest.raises(ValueError, match="latent attention under"):
+        LatentAttention(dataclasses.replace(cfg, attn_impl="ring")).apply(
+            {"params": params}, x, *tables)
+
+
+@pytest.mark.parametrize("seq,index,attn,modules", [
+    (64, 1, "flash", 1), (32, 0, "dense", 1), (24, 3, "dense", 2), (32, 2, "dense", 0)])
+def test_loss_and_every_gradient_match_the_reference(seq, index, attn, modules, caplog):
+    """Through ``make_grad_step`` on a plain inputs/targets/mask batch; the
+    chip's share the first, a middle and the last; the kernels (interpreted,
+    two tiles) and the dense fallback; one prediction module, two, none."""
+    c = tiny(expert_parallel_index=index, num_nextn_predict_layers=modules,
+             run={"attn_impl": attn, "compute_dtype": "float32", "param_dtype": "float32"})
+    with caplog.at_level(logging.INFO, logger="torchft_tpu.models.llama"):
+        llama._ATTN_NOTED.clear()
+        model, mesh, params, data = _setup(
+            c, seq, flash_min_seq=64, flash_block_q=32, flash_block_k=32)
+        data["mask"] = data["mask"].at[1, 7].set(0)
+        sh = state_shardings(model, mesh, (2, seq))
+        with jax.default_matmul_precision("highest"):
+            loss, grads = make_grad_step(model, mesh, sh)(params, data)
+            (_, metrics), _ = make_grad_step(model, mesh, sh, with_metrics=True)(params, data)
+    assert f"asked={attn}/mla traced={attn}/mla seq={seq}" in caplog.text
+    loss_ref, grads_ref = jax.jit(lambda p, b: reference.loss_and_grads(p, b, c))(params, data)
+    assert float(loss) == pytest.approx(float(loss_ref), rel=1e-5)
+    errs = _leaf_errors(grads, grads_ref)
+    errs = {k: v for k, v in errs.items() if "router_bias" not in k}  # no gradient: 0 / 0
+    # an attention layer 8 leaves with its norm, the dense feed-forward 4, an expert
+    # layer 8 beside its bias; the table, the final norm and the head; a module 3 + 8 + 8
+    assert len(errs) == 3 * 8 + 4 + 2 * 8 + 3 + modules * 19 and max(errs.values()) < 2e-4, errs
+    assert all(float(jnp.abs(g).max()) == 0 for p, g in
+               jax.tree_util.tree_leaves_with_path(grads) if "router_bias" in jax.tree_util.keystr(p))
+    if modules:
+        _, main, mtp = reference.losses(params, data, c)
+        assert float(metrics["loss_main"]) == pytest.approx(float(main), rel=1e-5)
+        assert float(metrics["loss_mtp"]) == pytest.approx(float(mtp), rel=1e-5)
+        assert 0 < 0.3 * float(metrics["loss_mtp"]) / float(loss) < 0.3  # mtp_loss_share
+    else:
+        assert "loss_mtp" not in metrics and "mtp_0" not in params
+
+
+def test_the_last_rows_successor_weighs_nothing_and_an_odd_length_is_one_chunk():
+    """The prediction module's targets are the targets rolled by one, so
+    its last row's target is a token from the sequence's start: that row
+    is no part of its loss. And a length no chunk divides goes through the
+    same head-and-loss code."""
+    c = tiny()
+    model, mesh, params, data = _setup(c, 32)
+    sh = state_shardings(model, mesh, (2, 32))
+    step = make_grad_step(model, mesh, sh, with_metrics=True)
+    (_, m0), _ = step(params, data)
+    total, main, mtp = reference.losses(params, data, c)
+    assert float(m0["loss_mtp"]) == pytest.approx(float(mtp), rel=1e-5)
+    # Under the causal mask the last input token reaches the last row alone: another token
+    # there moves the main loss (that row predicts) and not the module's (its weight is 0).
+    other = dict(data, inputs=data["inputs"].at[:, -1].add(1) % c["vocab_size"])
+    (_, m1), _ = step(params, other)
+    assert float(m1["loss_mtp"]) == pytest.approx(float(m0["loss_mtp"]), rel=1e-6)
+    assert abs(float(m1["loss_main"]) - float(m0["loss_main"])) > 1e-5
+    # the row before it does weigh
+    before = dict(data, inputs=data["inputs"].at[:, -2].add(1) % c["vocab_size"])
+    assert abs(float(step(params, before)[0][1]["loss_mtp"]) - float(m0["loss_mtp"])) > 1e-5
+    odd = _setup(c, 200)  # 200 = 128 + 72: no multiple of 128 divides it
+    (loss, m), _ = make_grad_step(
+        odd[0], odd[1], state_shardings(odd[0], odd[1], (2, 200)), with_metrics=True
+    )(odd[2], odd[3])
+    ref = reference.losses(odd[2], odd[3], c)
+    assert float(loss) == pytest.approx(float(ref[0]), rel=1e-4)
+    assert float(m["loss_mtp"]) == pytest.approx(float(ref[2]), rel=1e-4)
+
+
+def test_a_model_without_a_module_and_generation_take_the_main_head():
+    cfg = joyai_flash_debug(dtype=jnp.float32)
+    model = build_model(cfg, None)
+    toks = jax.random.randint(jax.random.PRNGKey(0), (2, 16), 0, 256)
+    params = model.init(jax.random.PRNGKey(0), toks)["params"]
+    logits = model.apply({"params": params}, toks)
+    assert logits.shape == (2, 16, 256) and logits.dtype == jnp.float32
+    hidden, predicted = model.apply({"params": params}, toks, return_hidden=True)
+    assert hidden.shape == predicted.shape == (2, 16, 64)
+    # the logits are the main head's: the module's parameters do not move them
+    spoiled = dict(params, mtp_0=jax.tree_util.tree_map(jnp.zeros_like, params["mtp_0"]))
+    assert jnp.array_equal(model.apply({"params": spoiled}, toks), logits)
+    # given the successors, the module reads them; left alone, the tokens rolled by one
+    rolled = model.apply({"params": params}, toks, return_hidden=True,
+                         next_tokens=jnp.roll(toks, -1, axis=1))
+    assert jnp.array_equal(rolled[1], predicted)
+    other = model.apply({"params": params}, toks, return_hidden=True,
+                        next_tokens=jnp.roll(toks, -2, axis=1))
+    assert jnp.array_equal(other[0], hidden) and not jnp.allclose(other[1], predicted)
+    with pytest.raises(ValueError, match="layer_pattern stack"):
+        build_model(dataclasses.replace(cfg, layer_pattern=None, num_experts=0), None).init(
+            jax.random.PRNGKey(0), toks)
+    with pytest.raises(ValueError, match="under block diffusion"):
+        bad = build_model(dataclasses.replace(cfg, objective="block_diffusion", block_length=4,
+                                              mla=None, head_dim=16), None)
+        p = bad.init(jax.random.PRNGKey(0), toks)["params"]
+        mesh = auto_mesh(1, devices=jax.devices()[:1])
+        make_grad_step(bad, mesh, state_shardings(bad, mesh, (2, 16)))(p, _data(256, 2, 16))
+
+
+# -- (d) the expert layer ------------------------------------------------------
+
+
+def test_the_four_shares_add_up_to_the_whole_layer_with_the_shared_expert_once():
+    """Four chips hold eight experts each of one layer's thirty-two. The
+    routed parts the four compute, plus the shared expert ONCE, are the
+    uncut reference layer."""
+    whole = tiny(n_routed_experts=32, expert_parallel_chips=1, expert_parallel_index=0,
+                 num_experts_per_tok=6)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, whole["hidden_size"]))
+    layer = MoEMLP(adapter.model_config(whole, 32))
+    params = layer.init(jax.random.PRNGKey(0), x)["params"]
+    params = dict(params, router_bias=0.05 * jax.random.normal(jax.random.PRNGKey(2), (32,)))
+    m = x.reshape(-1, whole["hidden_size"])
+    ident = lambda a: a  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        want = reference.experts(m, params, whole, ident)
+        shared = want - reference.experts(m, params, whole, ident, shared=False)
+        total, with_shared, held_share = jnp.zeros_like(want), jnp.zeros_like(want), 0.0
+        for index in range(4):
+            share = tiny(n_routed_experts=8, expert_parallel_chips=4,
+                         expert_parallel_index=index, num_experts_per_tok=6)
+            own = dict(params, **{
+                k: params[k][8 * index : 8 * index + 8]
+                for k in ("experts_gate", "experts_up", "experts_down")})
+            out, sown = MoEMLP(adapter.model_config(share, 32)).apply(
+                {"params": own}, x, mutable=["intermediates"])
+            sown = sown["intermediates"]
+            with_shared = with_shared + out.reshape(want.shape)
+            total = total + out.reshape(want.shape) - shared  # every chip computes it alike
+            held_share += float(sown["moe_held_share"][0])
+            assert float(sown["moe_dropped"][0]) == 0.0
+            # the share's own reference is the share
+            assert jnp.allclose(out.reshape(want.shape),
+                                reference.experts(m, own, share, ident), rtol=1e-4, atol=1e-5)
+    assert jnp.allclose(total + shared, want, rtol=1e-4, atol=1e-5)
+    assert held_share == pytest.approx(1.0) and float(jnp.linalg.norm(shared)) > 0.1
+    # counted four times, the shared expert would be three too many
+    assert jnp.allclose(with_shared - want, 3 * shared, rtol=1e-4, atol=1e-5)
+
+
+def test_the_train_step_reports_the_counters_moves_the_biases_and_accumulates():
+    model, mesh, params, data = _setup(tiny(), 32, batch=4)
+    state, sh = init_train_state(model, mesh, jax.random.PRNGKey(0), (4, 32))
+    new, metrics = make_train_step(model, mesh, sh, donate=False)(state, data)
+    assert {"loss", "loss_main", "loss_mtp", "grad_norm", "moe_held_share", "moe_dropped",
+            "router_bias_abs_max"} <= set(metrics) and "moe_load" not in metrics
+    assert float(metrics["loss"]) == pytest.approx(
+        float(metrics["loss_main"]) + 0.3 * float(metrics["loss_mtp"]), rel=1e-6)
+    assert float(metrics["moe_dropped"]) == 0.0 and int(new.step) == 1
+    assert float(metrics["router_bias_abs_max"]) == pytest.approx(1e-3)
+    # the module's own selection bias moves with the stack's
+    for name in ("layers_3", "layers_5"):
+        assert float(jnp.abs(new.params[name]["mlp"]["router_bias"]).max()) == pytest.approx(1e-3)
+    assert float(jnp.abs(new.params["mtp_0"]["layers_1"]["mlp"]["router_bias"]).max()) == (
+        pytest.approx(1e-3))
+    _, m2 = make_train_step(model, mesh, sh, donate=False, accum_steps=2)(state, data)
+    assert np.isfinite(float(m2["loss"])) and float(m2["loss_mtp"]) > 0
+
+
+# -- (e) sharding ---------------------------------------------------------------
+
+
+def test_the_rules_name_the_new_leaves_and_a_sharded_mesh_computes_the_same_step():
+    """fsdp=2 x tp=2 on four virtual devices against one device."""
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four (virtual) devices")
+    cfg = joyai_flash_debug(dtype=jnp.float32)
+    shapes = jax.eval_shape(lambda: build_model(cfg, None).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    specs = param_specs(shapes)
+    attn, module = specs["layers_2"]["attn"], specs["mtp_0"]
+    P = jax.sharding.PartitionSpec
+    assert attn["wq_a"]["kernel"] == attn["wkv_a"]["kernel"] == P("fsdp", None)
+    assert attn["wq_b"]["kernel"] == attn["wkv_b"]["kernel"] == P("fsdp", "tp", None)
+    assert attn["wo"]["kernel"] == P("tp", None, "fsdp")
+    assert attn["q_norm"]["scale"] == attn["kv_norm"]["scale"] == P()
+    assert module["eh_proj"]["kernel"] == P("tp", "fsdp")
+    assert module["hnorm"]["scale"] == module["enorm"]["scale"] == P()
+    assert module["layers_0"]["attn"]["wq_b"]["kernel"] == P("fsdp", "tp", None)
+    data = _data(cfg.vocab_size, 4, 64)
+    seen = []
+    for mesh in (auto_mesh(1, devices=jax.devices()[:1]), make_mesh(fsdp=2, tp=2)):
+        model = build_model(cfg, mesh)
+        state, sh = init_train_state(model, mesh, jax.random.PRNGKey(0), (4, 64))
+        new, metrics = make_train_step(model, mesh, sh, donate=False)(state, data)
+        seen.append([float(metrics[k]) for k in ("loss", "loss_mtp", "grad_norm",
+                                                  "moe_held_share")])
+    # a sharded contraction adds in another order; an assignment may flip at a tie
+    assert seen[0][0] == pytest.approx(seen[1][0], rel=1e-4)
+    assert seen[0][1] == pytest.approx(seen[1][1], rel=1e-4)
+    assert seen[0][2] == pytest.approx(seen[1][2], rel=2e-3)
+    assert seen[0][3] == pytest.approx(seen[1][3], abs=2 / 768)
+
+
+# -- (f) two replicas under Managers --------------------------------------------
+
+
+@pytest.mark.timeout(300)
+def test_two_replicas_fed_one_batch_commit_bitwise_equal_parameters():
+    """``FTStep`` over the split step: the loads of every expert layer, the
+    module's among them, ride the allreduce beside the gradients, and both
+    replicas hold the same parameters and selection biases bit for bit."""
+    (losses0, leaves0), (losses1, leaves1) = two_replicas(joyai_flash_debug, "joyai")
+    assert losses0 == losses1 and len(losses0) == 2 and losses0[0] != losses0[1]
+    assert all(np.array_equal(a, b) for a, b in zip(leaves0, leaves1))
+
+
+# -- the presets and the entry point ---------------------------------------------
+
+
+def test_the_presets():
+    cfg = joyai_llm_flash()
+    assert (cfg.hidden_size, cfg.num_layers, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+            cfg.intermediate_size, cfg.dense_intermediate_size, cfg.vocab_size,
+            cfg.max_seq_len) == (2048, 40, 32, 32, 64, 768, 7168, 129280, 131072)
+    assert cfg.mla == MLAConfig(1536, 512, 128, 64, 128) and cfg.mla.qk_head_dim == 192
+    assert (cfg.num_experts, cfg.num_experts_per_tok, cfg.router_score, cfg.routed_scaling,
+            cfg.shared_expert_size, cfg.tie_embeddings, cfg.experts_held, cfg.rope_theta,
+            cfg.norm_eps, cfg.mtp_layers, llama.MTP_BLOCK, cfg.layer_pattern) == (
+        256, 8, "sigmoid", 2.5, 768, False, None, 3.2e7, 1e-6, 1, "*E", "*D" + "*E" * 39)
+    published, small = _reference_tests.PUBLISHED, joyai_flash_debug()
+    cut = adapter.model_config(published, 8192)
+    assert cut.layer_pattern == cfg.layer_pattern[:12] and small.layer_pattern == "*D*E*E"
+    same = ("hidden_size", "num_heads", "num_kv_heads", "head_dim", "intermediate_size",
+            "dense_intermediate_size", "rope_theta", "norm_eps", "mla", "num_experts",
+            "num_experts_per_tok", "router_score", "routed_scaling", "gate_eps",
+            "shared_expert_size", "router_aux_coef", "router_bias_update_rate", "mtp_layers",
+            "mtp_loss_coef", "tie_embeddings", "expert_capacity_factor")
+    assert all(getattr(cut, k) == getattr(cfg, k) for k in same)
+    assert PRESETS["joyai_flash"] is joyai_flash_debug
+    assert (small.experts_held, small.mtp_layers, small.vocab_size) == ((0, 4), 1, 256)
+
+
+@pytest.mark.timeout(300)
+def test_train_hsdp_runs_the_small_preset(tmp_path):
+    """``train_hsdp.py --model joyai_flash``: one group, the Manager in the
+    loop, three committed steps on the CPU."""
+    lighthouse = LighthouseServer(
+        bind="127.0.0.1:0", min_replicas=1, join_timeout_ms=20000, quorum_tick_ms=50)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", TORCHFT_LIGHTHOUSE=lighthouse.address(),
+               REPLICA_GROUP_ID="0", NUM_REPLICA_GROUPS="1",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    env.pop("XLA_FLAGS", None)  # one device: the preset's mesh of one
+    try:
+        proc = subprocess.run(
+            [sys.executable, "train_hsdp.py", "--model", "joyai_flash", "--steps", "3",
+             "--batch", "2", "--seq", "32", "--result-dir", str(tmp_path / "out")],
+            cwd=root, env=env, capture_output=True, text=True, timeout=240,
+        )
+    finally:
+        lighthouse.shutdown()
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    steps = [line for line in proc.stderr.splitlines() if " loss " in line]
+    assert len(steps) == 3 and all("loss_mtp" in line for line in steps), steps
+    assert "asked=dense/mla traced=dense/mla seq=32" in proc.stderr
+    assert cells.load_json(str(tmp_path / "out" / "group0.json"))["final_step"] == 3

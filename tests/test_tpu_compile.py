@@ -522,3 +522,81 @@ def test_flash_block_diffusion_compiles_under_the_name_the_metrics_match(one_chi
     ).compile().as_text()
     calls = _custom_calls(text)
     assert len(calls) == 4 and all("flash_attention_block_diffusion" in c for c in calls), calls
+
+
+# -- the joyai-raw cell: the flash kernels at latent attention's widths -------
+
+# (B, S, heads, rope-free, rotary, value widths) of the cell's attention, and
+# a rotary part of a whole lane tile under other tiles.
+MLA_SHAPES = [(2, 8192, 32, 128, 64, 128, 512), (1, 2048, 4, 128, 128, 256, 256)]
+
+
+@pytest.mark.parametrize("shape", MLA_SHAPES)
+def test_flash_mla_compiles_under_the_name_the_metrics_match(one_chip, shape):
+    """Forward, dq and dkv at the cell's widths (a 128-wide and a 64-wide
+    contraction a score, 128-wide values, the rotary key one head in HBM):
+    three kernels, each named for the jit around it, ``flash_attention_mla``;
+    the shared key and its gradient stay [B,1,S,Dr]."""
+    from torchft_tpu.ops.flash_attention import flash_attention_mla
+
+    B, S, H, dn, dr, dv, tile = shape
+
+    def loss(q_nope, q_rope, k_nope, k_rope, v):
+        out = flash_attention_mla(
+            q_nope, q_rope, k_nope, k_rope, v, block_q=tile, block_k=tile, interpret=False
+        )
+        return out.astype(jnp.float32).sum()
+
+    specs = [
+        _spec(one_chip, s, jnp.bfloat16) for s in (
+            (B, S, H, dn), (B, S, H, dr), (B, S, H, dn), (B, S, dr), (B, S, H, dv))
+    ]
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *specs
+    ).compile().as_text()
+    calls = _custom_calls(text)
+    assert len(calls) == 3 and all("flash_attention_mla" in c for c in calls), calls
+    assert f"bf16[{B},1,{S},{dr}]" in text and f"bf16[{B},{H},{S},{dr}]" in text
+
+
+@pytest.mark.timeout(900)
+def test_the_joyai_cells_step_compiles_with_the_kernels_under_the_names_the_metrics_match(
+    topo, monkeypatch
+):
+    """The fused step of ``joyai-raw`` at the published widths, cut to the
+    dense layer and the prediction module for the compile's length (two
+    latent attentions, one expert layer, the head and loss twice): inside
+    a step program the kernels are ``flash_attention_mla.N`` (forward,
+    remat's forward, dq, dkv a layer), which ``flash_ms`` finds and
+    ``mla_proj_ms`` leaves out; the projections ``mla_proj_ms`` names are
+    there under its patterns."""
+    import dataclasses
+    import re
+
+    from benchmark import cells
+    from benchmark.metrics import flash_ms, mla_proj_ms
+    from benchmark.tests.test_v5e_compile import _programs
+    from torchft_tpu.ops import flash_attention
+
+    monkeypatch.setattr(flash_attention, "_interpret", lambda: False)
+    cell = cells.load_cell("joyai-raw")
+    cell = dataclasses.replace(cell, config={**cell.config, "num_hidden_layers": 1})
+    programs, _ = _programs(cell, topo)
+    prog, args = programs["step"]
+    text = prog.lower(*args).compile().as_text()
+    calls = _custom_calls(text)
+    flash = [c for c in calls if re.search(flash_ms.PATTERN, c)]
+    assert len(flash) == 2 * 4 and all(c.startswith("flash_attention_mla.") for c in flash), flash
+    assert sum(c.startswith("ragged-dot") for c in calls) >= 9
+    entry = _entry_instructions(text)
+    shapes = {"b": 2, "s": 8192, "h": 32, "rq": 1536, "rkv": 512, "dn": 128, "dr": 64, "dv": 128}
+    named = [
+        [i for i in entry if re.search(p, i)] for p in mla_proj_ms.patterns(shapes)
+    ]
+    assert not any(i.startswith("flash_attention") for found in named for i in found)
+    # W_kva's matmul, W_qb's and W_kvb's (forward and remat's forward, two
+    # layers), and the backward pass of both bottlenecks' norms
+    assert sum("bf16[2,8192,576]" in i and "fusion" in i for i in named[0]) >= 4, named[0]
+    assert sum("bf16[2,8192,32,192]" in i for i in named[1]) >= 4, named[1]
+    assert sum("bf16[2,8192,32,256]" in i for i in named[1]) >= 4, named[1]
+    assert len(named[2]) == 4 and all(i.startswith("fusion") for i in named[2]), named[2]

@@ -13,9 +13,12 @@ from torchft_tpu.models.resnet import (  # noqa: F401
     resnet101,
 )
 from torchft_tpu.models.mamba2 import Mamba2Config  # noqa: F401
+from torchft_tpu.models.mla import MLAConfig  # noqa: F401
 from torchft_tpu.models.llama import (  # noqa: F401
     LlamaConfig,
     Transformer,
+    joyai_flash_debug,
+    joyai_llm_flash,
     llama3_8b,
     llama_debug,
     llama_moe_debug,
@@ -39,4 +42,5 @@ PRESETS = {
     "nemotron_h": nemotron_h_debug,
     "lfm2_moe": lfm2_moe_debug,
     "sdar_moe": sdar_moe_debug,
+    "joyai_flash": joyai_flash_debug,
 }

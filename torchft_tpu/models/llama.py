@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from torchft_tpu.models.mamba2 import Mamba2Config, Mamba2Mixer, conv_kernel_init
+from torchft_tpu.models.mla import LatentAttention, MLAConfig
 
 Dtype = Any
 
@@ -112,12 +113,14 @@ class LlamaConfig:
     # A stack of unlike layers, one character a layer, each layer ONE mixer
     # between a pre-norm and the residual add: 'M' a Mamba-2 mixer
     # (``mamba``), 'E' the expert layer, '*' attention (rotary where
-    # ``rope``), 'C' a gated short convolution of ``SHORT_CONV_TAPS`` taps,
+    # ``rope``; latent attention where ``mla`` is set, models/mla.py),
+    # 'C' a gated short convolution of ``SHORT_CONV_TAPS`` taps,
     # 'D' a dense SwiGLU feed-forward. A model whose published layer is an
     # operator and a feed-forward is two characters a layer ("CD", "*E").
     # None: ``num_layers`` scanned blocks of attention + MLP.
     layer_pattern: Optional[str] = None
     mamba: Optional[Mamba2Config] = None
+    mla: Optional[MLAConfig] = None
     # The dense feed-forward's width where it is not the experts'
     # (``intermediate_size`` is then an expert's). None: one width for both.
     dense_intermediate_size: Optional[int] = None
@@ -169,6 +172,17 @@ class LlamaConfig:
     mask_token_id: int = 0
     diffusion_t_min: float = 0.0
     diffusion_t_max: float = 1.0
+    # Multi-token prediction (DeepSeek-V3 arXiv:2412.19437 section 2.2): after the
+    # stack, ``mtp_layers`` modules in a row (``MTPModule``), the k-th fed
+    # by the one before it (the first by the stack's output before the
+    # final norm) and the embedding of the token k places on; each is a
+    # block of ``MTP_BLOCK`` and predicts the token k + 1 places on
+    # through the SHARED final norm, table and head. The loss is the main
+    # one plus ``mtp_loss_coef`` times the modules' mean
+    # (parallel/train.py:_loss_and_metrics). Training only: logits are the
+    # main head's. A ``layer_pattern`` stack's.
+    mtp_layers: int = 0
+    mtp_loss_coef: float = 0.3
     # Bound by parallel.train when attn_impl is 'ring' or 'ulysses'.
     attn_fn: Optional[Callable[..., jax.Array]] = None
 
@@ -420,6 +434,78 @@ def sdar_moe_debug(**overrides: Any) -> LlamaConfig:
         num_experts_per_tok=3,
         experts_held=(0, 4),
         mask_token_id=255,
+        remat=False,
+    )
+    return dataclasses.replace(cfg, **overrides)
+
+
+def joyai_llm_flash(**overrides: Any) -> LlamaConfig:
+    """JoyAI-LLM-Flash (jdopensource/JoyAI-LLM-Flash config.json, model_type
+    ``joyai_llm_flash``, 48B-A2.7B; every layer's equations are
+    DeepSeek-V3's, arXiv:2412.19437 sections 2.1 and 2.2) at its published
+    sizes: 40 layers of latent attention (32 heads, a 128-wide rope-free
+    and a 64-wide rotary part a query and key, 128-wide values, ranks 1536
+    and 512, interleaved rotary pairs at theta 3.2e7) and a feed-forward,
+    dense (7168) in the first layer, then 256 SiLU-gated experts of width
+    768, 8 a token by sigmoid scores plus a selection bias the step
+    updates, gates renormalised and scaled 2.5, one shared expert; one
+    multi-token-prediction module; an untied 129,280-row head. Override
+    ``num_layers`` with ``layer_pattern``, ``experts_held`` and
+    ``vocab_size`` for what one chip holds. The bias update's rate and the
+    module's coefficient are DeepSeek-V3's (the published file has
+    neither; benchmark/configs/joyai-llm-flash-l6e8.json, ``assumed``)."""
+    cfg = LlamaConfig(
+        vocab_size=129280,
+        hidden_size=2048,
+        intermediate_size=768,
+        dense_intermediate_size=7168,
+        num_layers=40,
+        # Two characters a published layer: its attention, its feed-forward.
+        layer_pattern="*D" + "*E" * 39,
+        num_heads=32,
+        num_kv_heads=32,
+        head_dim=64,  # the published key: the rotary part's width
+        max_seq_len=131072,
+        rope_theta=3.2e7,
+        norm_eps=1e-6,
+        mla=MLAConfig(),
+        num_experts=256,
+        num_experts_per_tok=8,
+        expert_capacity_factor=None,
+        router_score="sigmoid",
+        routed_scaling=2.5,
+        shared_expert_size=768,
+        router_aux_coef=0.0,
+        router_z_coef=0.0,
+        router_bias_update_rate=1e-3,
+        mtp_layers=1,
+    )
+    return dataclasses.replace(cfg, **overrides)
+
+
+def joyai_flash_debug(**overrides: Any) -> LlamaConfig:
+    """Tiny JoyAI-LLM-Flash (a dense layer and two expert layers, 16
+    experts of which 4 are held, one prediction module) for tests and
+    ``train_hsdp.py --model joyai_flash``."""
+    cfg = joyai_llm_flash(
+        vocab_size=256,
+        hidden_size=64,
+        intermediate_size=48,
+        dense_intermediate_size=160,
+        num_layers=3,
+        layer_pattern="*D*E*E",
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=8,
+        max_seq_len=128,
+        mla=MLAConfig(
+            q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16,
+        ),
+        num_experts=16,
+        num_experts_per_tok=3,
+        experts_held=(0, 4),
+        shared_expert_size=48,
         remat=False,
     )
     return dataclasses.replace(cfg, **overrides)
@@ -1171,10 +1257,47 @@ class MixerLayer(nn.Module):
         if self.kind == "C":
             return x + ShortConvMixer(cfg, name="conv")(h)
         if self.kind == "*":
-            return x + Attention(cfg, name="attn")(h, cos, sin)
+            mixer = Attention if cfg.mla is None else LatentAttention
+            return x + mixer(cfg, name="attn")(h, cos, sin)
         raise ValueError(
             f"layer kind {self.kind!r} is none of 'M', 'E', 'D', 'C', '*'"
         )
+
+
+# A prediction module's block, as a ``layer_pattern``: attention, then the
+# expert layer. One value is in use, so it is a constant and no field of
+# the configuration.
+MTP_BLOCK = "*E"
+
+
+class MTPModule(nn.Module):
+    """One multi-token-prediction module (DeepSeek-V3 arXiv:2412.19437
+    section 2.2): for ``h`` [B, S, H], the hidden states it continues
+    (before any final norm), and ``emb`` [B, S, H], the embedding of the
+    token one place further on than those states have seen,
+
+        h' = eh_proj([RMSNorm(h) | RMSNorm(emb)])        2H -> H, no bias
+
+    then one block of ``MTP_BLOCK``, the stack's own layer classes.
+    What comes out goes through the model's final norm and head (shared,
+    ``Transformer``'s) and to the next module."""
+
+    cfg: LlamaConfig
+
+    @nn.compact
+    def __call__(self, h: jax.Array, emb: jax.Array, *rotary: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        norm = lambda name: RMSNorm(cfg.norm_eps, cfg.param_dtype, name=name)  # noqa: E731
+        x = nn.Dense(
+            cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, name="eh_proj",
+        )(jnp.concatenate([norm("hnorm")(h), norm("enorm")(emb)], axis=-1))
+        layer = nn.remat(MixerLayer) if cfg.remat else MixerLayer
+        for i, kind in enumerate(MTP_BLOCK):
+            x = layer(cfg, kind, name=f"layers_{i}")(
+                x, *(rotary if kind == "*" else ())
+            )
+        return x
 
 
 class _ScanBlock(Block):
@@ -1198,13 +1321,23 @@ class Transformer(nn.Module):
         tokens: jax.Array,
         positions: Optional[jax.Array] = None,
         return_hidden: bool = False,
+        next_tokens: Optional[jax.Array] = None,
     ) -> jax.Array:
         """``return_hidden=True`` returns the post-final-norm hidden states
         [B,S,H] in cfg.dtype instead of logits — the chunked-loss path
         (parallel/train.py:_loss_fn) projects them onto the vocab in
         sequence chunks so the full [B,S,V] fp32 logits are never
-        materialized."""
+        materialized. A model with ``mtp_layers`` then returns a tuple,
+        the main hidden states and each prediction module's, and reads
+        ``next_tokens`` [B,S], each position's successor (None: ``tokens``
+        rolled by one, whose last position reads the first token; the loss
+        gives that row no weight)."""
         cfg = self.cfg
+        if (cfg.mla is not None or cfg.mtp_layers) and cfg.layer_pattern is None:
+            raise ValueError(
+                "latent attention and prediction modules are a "
+                "layer_pattern stack's"
+            )
         if positions is None:
             if cfg.objective == "block_diffusion":
                 # Two streams, each at positions 0..L-1.
@@ -1228,15 +1361,26 @@ class Transformer(nn.Module):
             layer = nn.remat(MixerLayer) if cfg.remat else MixerLayer
             # Only a rotary attention layer is handed the tables: the
             # other kinds' calls (and a rope-free stack's) stay as they were.
+            rotary_dim = cfg.head_dim if cfg.mla is None else cfg.mla.qk_rope_head_dim
             rotary = (
-                rope_table(positions, cfg.head_dim, cfg.rope_theta, cfg.dtype)
+                rope_table(positions, rotary_dim, cfg.rope_theta, cfg.dtype)
                 if cfg.rope else ()
             )
             for i, kind in enumerate(cfg.layer_pattern):
                 x = layer(cfg, kind, name=f"layers_{i}")(
                     x, *(rotary if kind == "*" else ())
                 )
-            return self._head(embed, x, return_hidden)
+            predicted = []
+            # The modules serve the training loss alone (and ``init``,
+            # which has to meet their parameters).
+            if cfg.mtp_layers and (return_hidden or self.is_initializing()):
+                nxt = jnp.roll(tokens, -1, axis=1) if next_tokens is None else next_tokens
+                for k in range(cfg.mtp_layers):
+                    predicted.append(MTPModule(cfg, name=f"mtp_{k}")(
+                        predicted[-1] if predicted else x, embed(nxt), *rotary
+                    ))
+                    nxt = jnp.roll(nxt, -1, axis=1)
+            return self._head(embed, x, return_hidden, predicted)
         cos, sin = rope_table(positions, cfg.head_dim, cfg.rope_theta, cfg.dtype)
 
         block = _ScanBlock
@@ -1260,10 +1404,15 @@ class Transformer(nn.Module):
         x, _ = stack(x, cos, sin)
         return self._head(embed, x, return_hidden)
 
-    def _head(self, embed: nn.Embed, x: jax.Array, return_hidden: bool) -> jax.Array:
+    def _head(
+        self, embed: nn.Embed, x: jax.Array, return_hidden: bool, predicted=(),
+    ) -> jax.Array:
         cfg = self.cfg
-        x = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")(x)
+        final_norm = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")
+        x = final_norm(x)
         if return_hidden:
+            if predicted:
+                return (x, *(final_norm(h) for h in predicted))
             return x
         if cfg.tie_embeddings:
             logits = embed.attend(x.astype(cfg.param_dtype))

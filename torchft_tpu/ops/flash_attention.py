@@ -1,4 +1,5 @@
-"""Pallas TPU flash attention (GQA, forward and backward, three masks).
+"""Pallas TPU flash attention (GQA, forward and backward, three masks;
+latent attention's two-part heads under the causal one).
 
 The reference has no attention kernel of its own (it delegates compute to
 torchtitan); this kernel exists because the flagship bench model's dense
@@ -21,6 +22,10 @@ step math (``_fwd_step``, ``_bwd_dq_step``, ``_bwd_dkv_step``):
   query the clean keys of strictly earlier blocks and the noisy keys of
   its own block, and nothing sees otherwise. L^2 + L*b score entries are
   kept of the 4 L^2 of the square; the sweeps visit the kept tiles only.
+
+``flash_attention_mla`` is the causal mask again over heads of another
+shape: a query and key of two parts (rope-free and rotary, the rotary key
+one a position for all heads) and values of a width of their own.
 
 Layout: model-native [B, S, H, D] in/out (matching
 ``models/llama.py:dense_attention``); internally transposed to
@@ -64,9 +69,11 @@ __all__ = [
     "flash_attention",
     "flash_attention_block",
     "flash_attention_block_diffusion",
+    "flash_attention_mla",
     "block_diffusion_tiles",
     "supports",
     "supports_block_diffusion",
+    "supports_mla",
 ]
 
 _NEG_INF = -1e30
@@ -102,16 +109,22 @@ def supports(seq_len: int, block_q: int = 512, block_k: int = 512) -> bool:
 # ---------------------------------------------------------------------------
 
 
+def _parts(x) -> tuple:
+    """The latent family's score is a sum of two contractions (a rope-free
+    and a rotary part of each query and key), so its kernels hand the step
+    math TUPLES of refs and of accumulators, a part each; every other
+    family hands one ref, which is a tuple of one."""
+    return x if isinstance(x, tuple) else (x,)
+
+
 def _scores(q_ref, k_ref, scale, mask_fn):
-    q = q_ref[0, 0]
-    k = k_ref[0, 0]
-    s = (
+    s = functools.reduce(jnp.add, [
         jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q[0, 0], k[0, 0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        * scale
-    )  # [block_q, block_k] fp32
+        for q, k in zip(_parts(q_ref), _parts(k_ref))
+    ]) * scale  # [block_q, block_k] fp32
     return mask_fn(s)
 
 
@@ -198,11 +211,12 @@ def _bwd_dq_step(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref,
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref,
         scale, mask_fn,
     )
-    k = k_ref[0, 0]
-    dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
-        ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale
+    for k_part, acc in zip(_parts(k_ref), _parts(dq_acc)):
+        k = k_part[0, 0]
+        acc[:] = acc[:] + jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale
 
 
 def _bwd_dkv_step(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref,
@@ -211,18 +225,19 @@ def _bwd_dkv_step(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref,
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref,
         scale, mask_fn,
     )
-    q = q_ref[0, 0]
+    qs = [q[0, 0] for q in _parts(q_ref)]
     do = do_ref[0, 0]
     # dv += P^T @ dO
     dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
         p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    # dk += dS^T @ Q * scale
-    dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
-        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale
+    # dk += dS^T @ Q * scale, a part of the key each
+    for q, acc in zip(qs, _parts(dk_acc)):
+        acc[:] = acc[:] + jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale
 
 
 def _static_mask(causal, q_start, k_start):
@@ -313,7 +328,8 @@ def _flash_bwd_dq_kernel(
 
     @pl.when(ik == 0)
     def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
+        for acc in _parts(dq_acc):
+            acc[:] = jnp.zeros_like(acc)
 
     q_start = iq * block_q
     k_start = ik * block_k
@@ -328,7 +344,8 @@ def _flash_bwd_dq_kernel(
 
     @pl.when(ik == nk - 1)
     def _finish():
-        dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
+        for out, acc in zip(_parts(dq_ref), _parts(dq_acc)):
+            out[0, 0] = acc[:].astype(out.dtype)
 
 
 def _flash_bwd_dkv_kernel(
@@ -1216,5 +1233,294 @@ def flash_attention_block_diffusion(
     out = _flash_bd(
         jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
         block_length, min(block, L), itp,
+    )
+    return jnp.swapaxes(out, 1, 2)
+
+
+# ---------------------------------------------------------------------------
+# Latent-attention variant (models/mla.py, DeepSeek-V3 arXiv:2412.19437
+# section 2.1.1 in its training form): a head's query and key are a
+# rope-free part of ``Dn`` channels and a rotary part of ``Dr``, the score
+# (q_nope . k_nope + q_rope . k_rope) / sqrt(Dn + Dr), the values ``Dv``
+# wide, and ONE rotary key a position serves every head. The rotary key
+# stays one head in HBM, [B, 1, S, Dr]: every head's index map names that
+# head, and its gradient is accumulated over the heads inside the dkv
+# kernel. Causal over one sequence, static; the causal kernels' bodies,
+# their refs and accumulators handed in parts (``_parts``).
+#
+# The score is TWO contractions a tile, Dn deep and Dr deep, summed. The
+# other form, one contraction over the parts joined in VMEM (Dn + Dr = 192
+# lanes, padded to 256), was measured beside it on a v5e at the cell's
+# shapes ([2,8192,32] heads of 128 + 64 | 128, tiles of 512; PERF.md
+# section 6, PR 51): forward 15.9 ms against 16.1, forward and backward
+# 61.0 against 60.8, within 1.2% at every tiling tried. Either is two
+# passes of a 128-deep MXU, so nothing is won by joining, and the joined
+# form copies both operands inside the kernel every tile: the two
+# contractions stay. What the 64-deep pass costs is seen against head width
+# 128's kernels: 15.9 ms forward for 1.6 times the work of their 12.0.
+# ---------------------------------------------------------------------------
+
+
+def supports_mla(
+    seq_len: int, nope: int, rope: int, v_dim: int,
+    block_q: int = 512, block_k: int = 512,
+) -> bool:
+    """Whether the latent kernels handle this sequence and these widths:
+    the causal kernels' tilings and, compiled, a rope-free part and values
+    of whole lane tiles and a rotary part of half a tile or whole ones
+    (what has been compiled for the chip; the interpreter takes any)."""
+    lanes_ok = nope % _LANES == 0 and v_dim % _LANES == 0 and rope % (_LANES // 2) == 0
+    return supports(seq_len, block_q, block_k) and (lanes_ok or _interpret())
+
+
+def _mla_fwd_kernel(
+    qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
+    **static,
+):
+    _flash_kernel(
+        (qn_ref, qr_ref), (kn_ref, kr_ref), v_ref, o_ref, lse_ref,
+        acc_ref, m_ref, l_ref, causal=True, **static,
+    )
+
+
+def _mla_bwd_dq_kernel(
+    qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref, delta_ref,
+    dqn_ref, dqr_ref, dqn_acc, dqr_acc, **static,
+):
+    _flash_bwd_dq_kernel(
+        (qn_ref, qr_ref), (kn_ref, kr_ref), v_ref, do_ref, lse_ref, delta_ref,
+        (dqn_ref, dqr_ref), (dqn_acc, dqr_acc), causal=True, **static,
+    )
+
+
+def _mla_bwd_dkv_kernel(
+    qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref, delta_ref,
+    dkn_ref, dkr_ref, dv_ref,  # out: [1,1,block_k,Dn], [1,1,block_k,Dr] (head 0), [1,1,block_k,Dv]
+    dkn_acc, dkr_acc, dv_acc,
+    *, scale: float, block_q: int, block_k: int, nq: int,
+):
+    # Grid = (B, nk, H * nq): everything that accumulates into THIS kv tile
+    # is the innermost dimension, head after head. A head's dk_nope and dv
+    # are bracketed by its own nq steps (their output blocks move on with
+    # the head); the shared rotary key's gradient by the whole sweep.
+    ik = pl.program_id(1)
+    inner = pl.program_id(2)
+    iq = inner % nq
+
+    @pl.when(inner == 0)
+    def _init_shared():
+        dkr_acc[:] = jnp.zeros_like(dkr_acc)
+
+    @pl.when(iq == 0)
+    def _init_head():
+        dkn_acc[:] = jnp.zeros_like(dkn_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    q_start = iq * block_q
+    k_start = ik * block_k
+
+    @pl.when(k_start <= q_start + block_q - 1)
+    def _step():
+        _bwd_dkv_step(
+            (qn_ref, qr_ref), (kn_ref, kr_ref), v_ref, do_ref, lse_ref,
+            delta_ref, None, (dkn_acc, dkr_acc), dv_acc, scale,
+            _static_mask(True, q_start, k_start),
+        )
+
+    @pl.when(iq == nq - 1)
+    def _finish_head():
+        dkn_ref[0, 0] = dkn_acc[:].astype(dkn_ref.dtype)
+        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
+
+    @pl.when(inner == pl.num_programs(2) - 1)
+    def _finish_shared():
+        dkr_ref[0, 0] = dkr_acc[:].astype(dkr_ref.dtype)
+
+
+def _mla_q_sweep_specs(dims, block_q, block_k):
+    """Block specs of the kernels whose grid is (B, H, q tiles, kv tiles),
+    forward and dq: (q_nope, q_rope, k_nope, k_rope, v, out/do, rows). A
+    kv tile above the diagonal is skipped and its index clamped to the
+    diagonal's, as in the causal kernels; the rotary key is head 0's."""
+    dn, dr, dv = dims
+
+    def q_idx(b, h, iq, ik):
+        return (b, h, iq, 0)
+
+    def kv_at(iq, ik):
+        return jnp.minimum(ik, (iq * block_q + block_q - 1) // block_k)
+
+    def kv_idx(b, h, iq, ik):
+        return (b, h, kv_at(iq, ik), 0)
+
+    def shared_idx(b, h, iq, ik):
+        return (b, 0, kv_at(iq, ik), 0)
+
+    return (
+        pl.BlockSpec((1, 1, block_q, dn), q_idx),
+        pl.BlockSpec((1, 1, block_q, dr), q_idx),
+        pl.BlockSpec((1, 1, block_k, dn), kv_idx),
+        pl.BlockSpec((1, 1, block_k, dr), shared_idx),
+        pl.BlockSpec((1, 1, block_k, dv), kv_idx),
+        pl.BlockSpec((1, 1, block_q, dv), q_idx),
+        pl.BlockSpec((1, 1, 8, block_q), lambda b, h, iq, ik: (b, h, 0, iq)),
+    )
+
+
+def _mla_forward_impl(qn, qr, kn, kr, vt, block_q, block_k, interpret):
+    B, H, S, dn = qn.shape
+    dr, dv = qr.shape[-1], vt.shape[-1]
+    qn_spec, qr_spec, kn_spec, kr_spec, v_spec, o_spec, row_spec = _mla_q_sweep_specs(
+        (dn, dr, dv), block_q, block_k
+    )
+    return pl.pallas_call(
+        functools.partial(
+            _mla_fwd_kernel, scale=1.0 / math.sqrt(dn + dr),
+            block_q=block_q, block_k=block_k,
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, H, S, dv), vt.dtype),
+            jax.ShapeDtypeStruct((B, H, 8, S), jnp.float32),
+        ],
+        grid=(B, H, S // block_q, S // block_k),
+        in_specs=[qn_spec, qr_spec, kn_spec, kr_spec, v_spec],
+        out_specs=[o_spec, row_spec],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, dv), jnp.float32),
+            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, 128), jnp.float32),
+        ],
+        interpret=interpret,
+    )(qn, qr, kn, kr, vt)
+
+
+def _mla_backward_impl(qn, qr, kn, kr, vt, do, lse, delta, block_q, block_k, interpret):
+    B, H, S, dn = qn.shape
+    dr, dv = qr.shape[-1], vt.shape[-1]
+    scale = 1.0 / math.sqrt(dn + dr)
+    qn_spec, qr_spec, kn_spec, kr_spec, v_spec, o_spec, row_spec = _mla_q_sweep_specs(
+        (dn, dr, dv), block_q, block_k
+    )
+    dqn, dqr = pl.pallas_call(
+        functools.partial(
+            _mla_bwd_dq_kernel, scale=scale, block_q=block_q, block_k=block_k
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct(qn.shape, qn.dtype),
+            jax.ShapeDtypeStruct(qr.shape, qr.dtype),
+        ],
+        grid=(B, H, S // block_q, S // block_k),
+        in_specs=[qn_spec, qr_spec, kn_spec, kr_spec, v_spec, o_spec, row_spec, row_spec],
+        out_specs=[qn_spec, qr_spec],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, dn), jnp.float32),
+            pltpu.VMEM((block_q, dr), jnp.float32),
+        ],
+        interpret=interpret,
+    )(qn, qr, kn, kr, vt, do, lse, delta)
+
+    nq = S // block_q
+
+    def q_at(ik, inner):
+        # q tiles wholly above the diagonal add nothing: clamp to the
+        # diagonal's so that the repeated index fetches nothing.
+        return inner // nq, jnp.maximum(inner % nq, (ik * block_k) // block_q)
+
+    def q_idx(b, ik, inner):
+        head, iq = q_at(ik, inner)
+        return (b, head, iq, 0)
+
+    def row_idx(b, ik, inner):
+        head, iq = q_at(ik, inner)
+        return (b, head, 0, iq)
+
+    def kv_idx(b, ik, inner):
+        return (b, inner // nq, ik, 0)
+
+    def shared_idx(b, ik, inner):
+        return (b, 0, ik, 0)
+
+    q_specs = [pl.BlockSpec((1, 1, block_q, d), q_idx) for d in (dn, dr)]
+    kv_specs = [
+        pl.BlockSpec((1, 1, block_k, dn), kv_idx),
+        pl.BlockSpec((1, 1, block_k, dr), shared_idx),
+        pl.BlockSpec((1, 1, block_k, dv), kv_idx),
+    ]
+    rows = pl.BlockSpec((1, 1, 8, block_q), row_idx)
+    dkn, dkr, dvt = pl.pallas_call(
+        functools.partial(
+            _mla_bwd_dkv_kernel, scale=scale, block_q=block_q, block_k=block_k, nq=nq
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct(kn.shape, kn.dtype),
+            jax.ShapeDtypeStruct(kr.shape, kr.dtype),
+            jax.ShapeDtypeStruct(vt.shape, vt.dtype),
+        ],
+        grid=(B, S // block_k, H * nq),
+        in_specs=[
+            *q_specs, *kv_specs, pl.BlockSpec((1, 1, block_q, dv), q_idx), rows, rows,
+        ],
+        out_specs=kv_specs,
+        scratch_shapes=[
+            pltpu.VMEM((block_k, dn), jnp.float32),
+            pltpu.VMEM((block_k, dr), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
+        ],
+        interpret=interpret,
+    )(qn, qr, kn, kr, vt, do, lse, delta)
+    return dqn, dqr, dkn, dkr, dvt
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _flash_mla(qn, qr, kn, kr, vt, block_q, block_k, interpret):
+    return _mla_forward_impl(qn, qr, kn, kr, vt, block_q, block_k, interpret)[0]
+
+
+def _flash_mla_fwd(qn, qr, kn, kr, vt, block_q, block_k, interpret):
+    out, lse = _mla_forward_impl(qn, qr, kn, kr, vt, block_q, block_k, interpret)
+    return out, (qn, qr, kn, kr, vt, out, lse)
+
+
+def _flash_mla_bwd(block_q, block_k, interpret, res, do):
+    qn, qr, kn, kr, vt, out, lse = res
+    return _mla_backward_impl(
+        qn, qr, kn, kr, vt, do, lse, _row_delta(do, out), block_q, block_k, interpret
+    )
+
+
+_flash_mla.defvjp(_flash_mla_fwd, _flash_mla_bwd)
+
+
+@functools.partial(jax.jit, static_argnames=("block_q", "block_k", "interpret"))
+def flash_attention_mla(
+    q_nope: jax.Array,
+    q_rope: jax.Array,
+    k_nope: jax.Array,
+    k_rope: jax.Array,
+    v: jax.Array,
+    block_q: int = 512,
+    block_k: int = 512,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Causal latent attention in its training form, differentiable.
+    q_nope, k_nope: [B,S,H,Dn]; q_rope: [B,S,H,Dr]; k_rope: [B,S,Dr], the
+    one rotary key a position that every head reads; v: [B,S,H,Dv].
+    Returns [B,S,H,Dv] in v's dtype. k_rope's gradient is the sum over
+    the heads. The kernels' trace names start ``flash_attention_mla`` (a
+    kernel is named for the jit around it)."""
+    B, S, H, dn = q_nope.shape
+    dr, dv = q_rope.shape[-1], v.shape[-1]
+    block_q = min(block_q, S)
+    block_k = min(block_k, S)
+    if not supports_mla(S, dn, dr, dv, block_q, block_k):
+        raise ValueError(
+            f"flash_attention_mla: seq_len {S} in blocks ({block_q},{block_k}) "
+            f"at widths {dn}+{dr}|{dv}: use latent_dense_attention"
+        )
+    itp = _interpret() if interpret is None else interpret
+    to_heads = lambda x: jnp.swapaxes(x, 1, 2)  # noqa: E731
+    out = _flash_mla(
+        to_heads(q_nope), to_heads(q_rope), to_heads(k_nope), k_rope[:, None],
+        to_heads(v), block_q, block_k, itp,
     )
     return jnp.swapaxes(out, 1, 2)

@@ -26,6 +26,17 @@ _RULES: Dict[Tuple[str, str], Tuple[Any, ...]] = {
     ("wk", "kernel"): ("fsdp", "tp", None),
     ("wv", "kernel"): ("fsdp", "tp", None),
     ("wo", "kernel"): ("tp", None, "fsdp"),
+    # Latent attention (models/mla.py): the two down-projections' input dim
+    # shards over ``fsdp`` and their bottleneck stays whole (its norm needs
+    # every channel); the two up-projections shard their heads over ``tp``
+    # as wq/wk/wv do. The bottlenecks' norms (``q_norm``, ``kv_norm``)
+    # replicate. ``eh_proj`` joins a prediction module's two inputs
+    # [2H, H] and shards like an MLP's down-projection.
+    ("wq_a", "kernel"): ("fsdp", None),
+    ("wkv_a", "kernel"): ("fsdp", None),
+    ("wq_b", "kernel"): ("fsdp", "tp", None),
+    ("wkv_b", "kernel"): ("fsdp", "tp", None),
+    ("eh_proj", "kernel"): ("tp", "fsdp"),
     ("gate", "kernel"): ("fsdp", "tp"),
     ("up", "kernel"): ("fsdp", "tp"),
     ("down", "kernel"): ("tp", "fsdp"),

@@ -401,6 +401,14 @@ def _loss_and_metrics(model: Transformer, params, inputs, targets, mask):
     x_0[p]) / sum_p mask_p: the token AT a masked position, weighed by 1/t;
     ``targets`` is not read.
 
+    A next-token model with ``mtp_layers`` prediction modules
+    (``LlamaConfig.mtp_layers``) adds ``mtp_loss_coef`` x the mean over the
+    modules of module k's cross-entropy against the token k + 1 places on,
+    through the shared head and the same ``_head_loss_sum``: all S rows,
+    the targets rolled by k, the last k rows' weight 0 (their successors
+    lie past the batch) and the others' the product of the masks they
+    span; ``loss_main`` and ``loss_mtp`` are among the metrics.
+
     Either way a model with experts adds ``router_aux_coef`` x the
     load-balancing term and ``router_z_coef`` x the router z-loss, each a
     mean over the layers (and, under block diffusion, over both streams'
@@ -428,6 +436,8 @@ def _loss_and_metrics(model: Transformer, params, inputs, targets, mask):
         extra = {"diffusion_masked_share": (mask_f * masked).sum() / denom}
     elif cfg.objective != "next_token":
         raise ValueError(f"objective {cfg.objective!r}")
+    if two_streams and cfg.mtp_layers:
+        raise ValueError("prediction modules under block diffusion: not built")
 
     def with_router_terms(ce, sown):
         if not sown:
@@ -448,6 +458,29 @@ def _loss_and_metrics(model: Transformer, params, inputs, targets, mask):
         stream's, the first S of the trunk's 2S."""
         return x[:, :S] if two_streams else x
 
+    def head_loss(h, targets, weights, chunk=C):
+        w, head_dtype = _lm_head_projection(model, params)
+        return _head_loss_sum(
+            h.astype(head_dtype), w.astype(jnp.float32), targets, weights, chunk
+        )
+
+    if cfg.mtp_layers:
+        (h, *predicted), sown = _apply_with_aux(
+            model, params, inputs, return_hidden=True, next_tokens=targets
+        )
+        chunk = C if S % C == 0 else S  # an odd length: one chunk
+        main, mtp = head_loss(h, targets, weights, chunk) / denom, 0.0
+        for k, h_k in enumerate(predicted, start=1):
+            # Module k's row p predicts targets[p + k]: the rows that span a
+            # masked position or reach past the batch weigh nothing.
+            weights = weights * jnp.roll(mask_f, -k, axis=1) * (jnp.arange(S) < S - k)
+            mtp = mtp + head_loss(
+                h_k, jnp.roll(targets, -k, axis=1), weights, chunk
+            ) / jnp.maximum(weights.sum(), 1.0)
+        mtp = mtp / len(predicted)
+        extra = jax.lax.stop_gradient({"loss_main": main, "loss_mtp": mtp})
+        return with_router_terms(main + cfg.mtp_loss_coef * mtp, sown)
+
     if S % C != 0:  # odd seq len: the plain full-logits path
         logits, sown = _apply_with_aux(model, params, inputs)
         losses = optax.softmax_cross_entropy_with_integer_labels(
@@ -456,12 +489,7 @@ def _loss_and_metrics(model: Transformer, params, inputs, targets, mask):
         return with_router_terms((losses * weights).sum() / denom, sown)
 
     h, sown = _apply_with_aux(model, params, inputs, return_hidden=True)
-    w, head_dtype = _lm_head_projection(model, params)
-    total = _head_loss_sum(
-        data_rows(h).astype(head_dtype), w.astype(jnp.float32), targets,
-        weights, C,
-    )
-    return with_router_terms(total / denom, sown)
+    return with_router_terms(head_loss(data_rows(h), targets, weights) / denom, sown)
 
 
 def _loss_fn(model: Transformer, params, inputs, targets, mask):
