@@ -418,6 +418,166 @@ def test_flash_block_with_a_fully_masked_q_tile_merges_to_zero_weight(block_k):
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
 
+# -- the kernels choose their tiles from the shape they are given -------------
+
+# (family, length, widths, keywords of choose_tiles, compiled, the tiles)
+CHOICES = [
+    # 1,024 where it divides the length, at every cell's length and head width
+    ("causal", 8192, (128,), {}, True, (1024, 1024)),
+    ("causal", 4096, (128,), {}, True, (1024, 1024)),
+    ("causal", 8192, (64,), {}, True, (1024, 1024)),
+    # 512 where 1,024 does not divide it: still the kernel, never dense
+    ("causal", 1536, (128,), {}, True, (512, 512)),
+    ("causal", 2560, (128,), {}, True, (512, 512)),
+    ("causal", 3584, (128,), {}, False, (512, 512)),
+    # one tile a sequence: the reference check's sample, and the lengths under a tile
+    ("causal", 1024, (128,), {}, True, (1024, 1024)),
+    ("causal", 768, (128,), {}, True, (768, 768)),
+    ("causal", 256, (64,), {}, True, (256, 256)),
+    ("causal", 48, (16,), {}, False, (48, 48)),
+    # no tiling, as before: the caller runs dense attention
+    ("causal", 1280, (128,), {}, True, None),
+    ("causal", 1008, (128,), {}, True, None),  # past 512 rows, no whole lane tiles
+    ("causal", 100, (128,), {}, False, None),
+    # the bounds a caller names are the LARGEST tiles, each side its own
+    ("causal", 8192, (128,), {"block_q": 512, "block_k": 512}, True, (512, 512)),
+    ("causal", 8192, (128,), {"block_q": 1024, "block_k": 512}, True, (1024, 512)),
+    ("causal", 8192, (128,), {"block_q": 2048, "block_k": 2048}, True, (1024, 1024)),
+    ("causal", 128, (16,), {"block_q": 32, "block_k": 32}, False, (32, 32)),
+    ("causal", 384, (128,), {"block_q": 128, "block_k": 96}, False, (128, 96)),
+    ("causal", 96, (16,), {"block_q": 64, "block_k": 64}, False, None),
+    # the ring's offset block: each side by its own length
+    ("block", 2048, (64,), {"kv_len": 1536}, True, (1024, 512)),
+    ("block", 256, (128,), {"kv_len": 256, "block_q": 128, "block_k": 64}, False, (128, 64)),
+    # block diffusion: one square tile of whole blocks, a stream's length the sweep's
+    ("block_diffusion", 8192, (128,), {"block_length": 4}, True, (1024, 1024)),
+    ("block_diffusion", 8192, (128,), {"block_length": 32}, True, (1024, 1024)),
+    ("block_diffusion", 1024, (128,), {"block_length": 4}, True, (1024, 1024)),
+    ("block_diffusion", 1024, (128,), {"block_length": 32}, True, (1024, 1024)),
+    ("block_diffusion", 1536, (128,), {"block_length": 4}, True, (512, 512)),
+    ("block_diffusion", 64, (16,), {"block_length": 4}, False, (64, 64)),
+    ("block_diffusion", 64, (16,), {"block_length": 32}, False, (64, 64)),
+    ("block_diffusion", 64, (128,), {"block_length": 4}, True, None),  # compiled: whole lane tiles
+    ("block_diffusion", 8192, (128,), {"block_length": 4, "block_q": 512, "block_k": 1024}, True, (512, 512)),
+    ("block_diffusion", 768, (128,), {"block_length": 12, "block_q": 384, "block_k": 384}, True, (384, 384)),
+    ("block_diffusion", 1024, (128,), {"block_length": 24}, False, None),  # a tile would cut a block
+    ("block_diffusion", 8192, (128,), {"block_length": 0}, False, None),
+    # latent attention: the causal tilings at the widths that have been compiled
+    ("mla", 8192, (128, 64, 128), {}, True, (1024, 1024)),
+    ("mla", 1536, (128, 64, 128), {}, True, (512, 512)),
+    ("mla", 96, (48, 16, 32), {"block_q": 48, "block_k": 32}, False, (48, 32)),
+    ("mla", 8192, (96, 64, 128), {}, True, None),
+    ("mla", 8192, (128, 48, 128), {}, True, None),
+]
+
+
+@pytest.mark.parametrize("family,length,widths,kw,compiled,want", CHOICES, ids=str)
+def test_the_chooser_takes_the_largest_good_tile_the_shape_admits(
+    family, length, widths, kw, compiled, want, monkeypatch
+):
+    from torchft_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_interpret", lambda: not compiled)
+    assert fa.choose_tiles(family, length, widths, **kw) == want
+    # the predicates answer for the tiles the chooser would take
+    bounds = [kw[b] for b in ("block_q", "block_k") if b in kw]
+    if family == "causal":
+        assert fa.supports(length, *bounds) == (want is not None)
+    elif family == "mla":
+        assert fa.supports_mla(length, *widths, *bounds) == (want is not None)
+    elif family == "block_diffusion":
+        assert fa.supports_block_diffusion(
+            length, kw["block_length"], *([min(bounds)] if bounds else [])
+        ) == (want is not None)
+
+
+def test_no_length_the_kernels_took_at_tiles_of_512_is_lost():
+    """Every multiple of 16 up to 8,192: what the causal predicate admitted
+    while every call ran tiles of 512 (the rule it had) it admits now, at
+    tiles that cut the length whole; a ``LlamaConfig`` of the defaults
+    asks under the same bounds, and one that names 32 still runs 32."""
+    from torchft_tpu.models.llama import LlamaConfig
+    from torchft_tpu.ops.flash_attention import choose_tiles, supports
+
+    def admitted_at_512(s):
+        b = min(512, s)
+        return s % b == 0 and b % 16 == 0
+
+    cfg = LlamaConfig()
+    for s in range(16, 8192 + 1, 16):
+        tiles = choose_tiles("causal", s, (cfg.head_dim,), cfg.flash_block_q, cfg.flash_block_k)
+        assert supports(s) == (tiles is not None)
+        if admitted_at_512(s):
+            assert tiles is not None and s % tiles[0] == 0 and s % tiles[1] == 0, s
+            assert tiles == ((1024, 1024) if s % 1024 == 0 else (min(512, s),) * 2), s
+    small = LlamaConfig(flash_block_q=32, flash_block_k=32)
+    assert choose_tiles(
+        "causal", 8192, (small.head_dim,), small.flash_block_q, small.flash_block_k
+    ) == (32, 32)
+
+
+def _latent_dense_joined(q, k, v):
+    """Latent attention's dense path on arrays joined for the one harness:
+    q = [q_nope | q_rope], k = [k_nope | k_rope of the one shared head
+    broadcast], split again inside."""
+    from torchft_tpu.models.mla import latent_dense_attention
+
+    dn = v.shape[-1]
+    return latent_dense_attention(
+        q[..., :dn], q[..., dn:], k[..., :dn], k[:, :, 0, dn:], v
+    )
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5), (jnp.bfloat16, 2e-2)], ids=["fp32", "bf16"])
+@pytest.mark.parametrize("tile", [256, 512], ids=["two_lane_groups", "one_tile"])
+@pytest.mark.parametrize("family", ["causal", "block", "block_diffusion", "mla"])
+def test_every_family_matches_dense_past_one_lane_group_and_at_one_tile(family, tile, dtype, tol):
+    """Interpreted: forward and every gradient against the dense path over
+    S = 512 (a stream of 512 under block diffusion) at tiles of 256, two
+    whole lane groups a tile and a sweep of whole, masked and skipped
+    tiles, and at ONE tile a sequence or stream (n = 1: every sweep is its
+    first and last step at once), which is what the reference check's
+    1,024-token sample runs since the kernels take tiles of 1,024."""
+    from torchft_tpu.models.llama import block_diffusion_mask, dense_attention
+    from torchft_tpu.ops import flash_attention as fa
+
+    S, D = 512, 128
+    ks = jax.random.split(jax.random.PRNGKey(17), 4)
+    rows = 2 * S if family == "block_diffusion" else S
+    q = jax.random.normal(ks[0], (1, rows, 4, D), dtype)
+    k = jax.random.normal(ks[1], (1, rows, 1, D), dtype)
+    v = jax.random.normal(ks[2], (1, rows, 1, D), dtype)
+    w = jax.random.normal(ks[3], (1, rows, 4, D), jnp.float32)
+    if family == "causal":
+        assert fa.choose_tiles("causal", S, (D,), tile, tile) == (tile, tile)
+        flash = functools.partial(fa.flash_attention, block_q=tile, block_k=tile)
+        dense = dense_attention
+    elif family == "block":  # at offsets 0, 0 the offset block is causal attention
+        flash = lambda q, k, v: fa.flash_attention_block(  # noqa: E731
+            q, k, v, 0, 0, block_q=tile, block_k=tile)[0]
+        dense = dense_attention
+    elif family == "block_diffusion":
+        assert fa.choose_tiles("block_diffusion", S, (D,), tile, tile, block_length=4) == (tile, tile)
+        flash = functools.partial(fa.flash_attention_block_diffusion, block_length=4, block=tile)
+        dense = functools.partial(dense_attention, mask=block_diffusion_mask(S, 4))
+    else:
+        # heads of 128 + 64 | 128 on one shared rotary key: q is 192 wide,
+        # k's one head carries [k_nope | k_rope], the values 128.
+        q = jax.random.normal(ks[0], (1, S, 4, D + 64), dtype)
+        k = jax.random.normal(ks[1], (1, S, 4, D + 64), dtype)
+        k = k.at[..., D:].set(k[:, :, :1, D:])  # one rotary key a position
+        v = jax.random.normal(ks[2], (1, S, 4, D), dtype)
+
+        def flash(q, k, v):
+            return fa.flash_attention_mla(
+                q[..., :D], q[..., D:], k[..., :D], k[:, :, 0, D:], v,
+                block_q=tile, block_k=tile,
+            )
+
+        dense = _latent_dense_joined
+    _assert_forward_and_gradients_match(flash, dense, q, k, v, w, tol)
+
+
 # ---------------------------------------------------------------------------
 # int4 codec (bits=4): packing, parity, transfer layout
 # ---------------------------------------------------------------------------

@@ -101,16 +101,23 @@ def test_flash_long_context_fwd_and_grad_compile(one_chip):
     )
 
 
-# (B, S, Hq, Hkv, D) of the benchmark's causal cells: internlm2-raw (and
-# nemotron3-raw's one attention layer), lfm2-raw, mistral-raw and olmoe-raw.
-CELL_FLASH_SHAPES = [(2, 8192, 16, 8, 128), (2, 8192, 32, 8, 64), (4, 4096, 32, 8, 128)]
+# (B, S, Hq, Hkv, D) of the benchmark's causal cells: internlm2-raw, lfm2-raw,
+# mistral-raw, nemotron3-raw's one attention layer and olmoe-raw.
+CELL_FLASH_SHAPES = [
+    (2, 8192, 16, 8, 128), (2, 8192, 32, 8, 64), (4, 4096, 32, 8, 128),
+    (2, 8192, 32, 2, 128), (4, 4096, 16, 16, 128),
+]
 
 
 @pytest.mark.parametrize("shape", CELL_FLASH_SHAPES, ids=str)
 def test_flash_at_the_cells_shapes_compiles(one_chip, shape):
-    """Forward, dq and dkv at tiles of 512 (the lane-wise softmax state at
-    head widths 128 and 64): three kernels named for the jit around them,
-    which is how ``flash_ms`` finds them in a trace."""
+    """Forward, dq and dkv at the tiles the kernels choose, 1,024 x 1,024
+    at every cell's length (the lane-wise softmax state at head widths 128
+    and 64): three kernels named for the jit around them, which is how
+    ``flash_ms`` finds them in a trace."""
+    from torchft_tpu.ops.flash_attention import choose_tiles
+
+    assert choose_tiles("causal", shape[1], shape[-1:]) == (1024, 1024)
     fn = jax.value_and_grad(_flash_loss, argnums=(0, 1, 2))
     calls = _custom_calls(jax.jit(fn).lower(*_qkv(one_chip, *shape)).compile().as_text())
     assert len(calls) == 3 and all("flash_attention" in c for c in calls), calls
@@ -131,6 +138,10 @@ def _block_args(one_chip):
 
 
 def test_flash_block_fwd_compiles(one_chip):
+    from torchft_tpu.ops.flash_attention import choose_tiles
+
+    # the ring's fold goes through the chooser too: tiles of 1,024 here
+    assert choose_tiles("block", 2048, (64,), kv_len=2048) == (1024, 1024)
     _assert_kernel(_flash_block, *_block_args(one_chip))
 
 
@@ -496,10 +507,15 @@ def test_head_and_loss_is_three_vocabulary_wide_matmuls_in_one_loop_and_fits(
 
 # -- the sdar-raw cell: the flash kernels under the block-diffusion mask -----
 
-# (B, 2L, Hq, Hkv, D, block length, tile) of the cell's attention, and of a
-# block length that is no power of two in tiles of 384 (the mask then takes
-# a remainder where the cell's takes a bitwise and).
-BLOCK_DIFFUSION_SHAPES = [(2, 16384, 32, 4, 128, 4, 512), (1, 1536, 8, 4, 128, 12, 384)]
+# (B, 2L, Hq, Hkv, D, block length, largest tile) of the cell's attention at
+# the tiles it chooses (1,024: eight a stream) and held to 512, of the
+# reference check's sample (one tile a stream), and of a block length that
+# is no power of two in tiles of 384 (the mask then takes a remainder where
+# the cell's takes a bitwise and).
+BLOCK_DIFFUSION_SHAPES = [
+    (2, 16384, 32, 4, 128, 4, 1024), (2, 16384, 32, 4, 128, 4, 512),
+    (1, 2048, 32, 4, 128, 4, 1024), (1, 1536, 8, 4, 128, 12, 384),
+]
 
 
 @pytest.mark.parametrize("shape", BLOCK_DIFFUSION_SHAPES)
@@ -507,9 +523,12 @@ def test_flash_block_diffusion_compiles_under_the_name_the_metrics_match(one_chi
     """Forward, dq and the two dkv kernels (the noisy stream's kv tiles and
     the clean one's) at the cell's widths: four kernels, each named for
     the jit around it, which ``flash_ms``'s pattern finds in a step."""
-    from torchft_tpu.ops.flash_attention import flash_attention_block_diffusion
+    from torchft_tpu.ops.flash_attention import choose_tiles, flash_attention_block_diffusion
 
     *qkv_shape, b, tile = shape
+    assert choose_tiles(
+        "block_diffusion", shape[1] // 2, (shape[4],), tile, tile, block_length=b
+    ) == (tile, tile)
 
     def loss(q, k, v):
         out = flash_attention_block_diffusion(
@@ -526,9 +545,14 @@ def test_flash_block_diffusion_compiles_under_the_name_the_metrics_match(one_chi
 
 # -- the joyai-raw cell: the flash kernels at latent attention's widths -------
 
-# (B, S, heads, rope-free, rotary, value widths) of the cell's attention, and
-# a rotary part of a whole lane tile under other tiles.
-MLA_SHAPES = [(2, 8192, 32, 128, 64, 128, 512), (1, 2048, 4, 128, 128, 256, 256)]
+# (B, S, heads, rope-free, rotary, value widths, largest tile) of the cell's
+# attention at the tiles it chooses (1,024) and held to 512, of the
+# reference check's sample (one tile a sequence), and a rotary part of a
+# whole lane tile under other tiles.
+MLA_SHAPES = [
+    (2, 8192, 32, 128, 64, 128, 1024), (2, 8192, 32, 128, 64, 128, 512),
+    (1, 1024, 32, 128, 64, 128, 1024), (1, 2048, 4, 128, 128, 256, 256),
+]
 
 
 @pytest.mark.parametrize("shape", MLA_SHAPES)
@@ -537,9 +561,10 @@ def test_flash_mla_compiles_under_the_name_the_metrics_match(one_chip, shape):
     contraction a score, 128-wide values, the rotary key one head in HBM):
     three kernels, each named for the jit around it, ``flash_attention_mla``;
     the shared key and its gradient stay [B,1,S,Dr]."""
-    from torchft_tpu.ops.flash_attention import flash_attention_mla
+    from torchft_tpu.ops.flash_attention import choose_tiles, flash_attention_mla
 
     B, S, H, dn, dr, dv, tile = shape
+    assert choose_tiles("mla", S, (dn, dr, dv), tile, tile) == (tile, tile)
 
     def loss(q_nope, q_rope, k_nope, k_rope, v):
         out = flash_attention_mla(

@@ -38,9 +38,12 @@ logger = logging.getLogger(__name__)
 _ATTN_NOTED: set = set()
 
 
-def _note_attention(asked: str, traced: str, seq_len: int) -> None:
+def _note_attention(
+    asked: str, traced: str, seq_len: int, tiles: Optional[tuple] = None
+) -> None:
     """Says once per (asked, traced, seq_len), at trace time, which
-    attention implementation a step really took — 'flash' routes to dense
+    attention implementation a step really took and, of a flash kernel,
+    the tiles it chose (``tiles=1024x1024``) — 'flash' routes to dense
     below ``flash_min_seq`` or on unsupported tilings, and a chip run
     must be able to prove which branch it compiled."""
     key = (asked, traced, seq_len)
@@ -48,7 +51,8 @@ def _note_attention(asked: str, traced: str, seq_len: int) -> None:
         _ATTN_NOTED.add(key)
         logger.log(
             logging.INFO if asked == traced else logging.WARNING,
-            "attention: asked=%s traced=%s seq=%d", asked, traced, seq_len,
+            "attention: asked=%s traced=%s seq=%d%s", asked, traced, seq_len,
+            " tiles=%dx%d" % tiles if tiles else "",
         )
 
 
@@ -76,11 +80,14 @@ class LlamaConfig:
     # v5e crossover; the blocked kernel wins from ~2k and is mandatory past
     # dense's O(S^2) memory wall).
     flash_min_seq: int = 2048
-    # Flash kernel tile sizes (q rows / kv cols per VMEM block). 512x512
-    # is the v5e default; exposed for on-chip grid tuning (smaller block_q
-    # raises grid parallelism, larger block_k amortizes the kv sweep).
-    flash_block_q: int = 512
-    flash_block_k: int = 512
+    # The LARGEST flash kernel tile a call may take (q rows / kv cols per
+    # VMEM block), not the tile: ops/flash_attention.py:choose_tiles picks
+    # under them from the call's own shape (1024 where it divides the
+    # sequence, else 512, else this bound itself; the attention note says
+    # which, ``tiles=``). Set lower only to force small tiles (the CPU
+    # tests' 32; on-chip grid experiments).
+    flash_block_q: int = 1024
+    flash_block_k: int = 1024
     # Mixture of experts: num_experts == 0 -> dense MLP. Experts shard over
     # the 'ep' mesh axis (parallel/sharding.py); dispatch/combine are dense
     # one-hot einsums so XLA derives the all-to-all from the shardings.
@@ -595,8 +602,8 @@ def block_diffusion_mask(stream_len: int, block_length: int) -> jax.Array:
 def block_diffusion_attention(cfg: LlamaConfig, rows: int) -> tuple:
     """(branch, kept share) of the two-stream attention over ``rows`` = 2L
     rows: 'flash' where the kernels take the shape (the share of the score
-    entries in the tiles they run that the mask keeps) or 'dense' (the
-    whole 2L x 2L square is computed)."""
+    entries in the tiles they CHOOSE to run that the mask keeps) or 'dense'
+    (the whole 2L x 2L square is computed)."""
     from torchft_tpu.ops import flash_attention as fa
 
     L, b = rows // 2, cfg.block_length
@@ -605,16 +612,25 @@ def block_diffusion_attention(cfg: LlamaConfig, rows: int) -> tuple:
             f"block diffusion: {rows} rows are not two streams of whole "
             f"blocks of {b}"
         )
-    block = cfg.flash_block_q
-    if (
-        cfg.attn_impl == "flash"
-        and rows >= cfg.flash_min_seq
-        and block == cfg.flash_block_k
-        and fa.supports_block_diffusion(L, b, block)
-    ):
-        kept, run = fa.block_diffusion_tiles(L, b, block)
+    tiles = _block_diffusion_tiles(cfg, rows)
+    if tiles is not None:
+        kept, run = fa.block_diffusion_tiles(L, b, tiles[0])
         return "flash", kept / run
     return "dense", (L * L + L * b) / (rows * rows)
+
+
+def _block_diffusion_tiles(cfg: LlamaConfig, rows: int) -> Optional[tuple]:
+    """The tiles the block-diffusion kernels take for ``rows`` = 2L rows
+    (one square tile, so under both bounds), None where dense runs."""
+    from torchft_tpu.ops import flash_attention as fa
+
+    if cfg.attn_impl != "flash" or rows < cfg.flash_min_seq:
+        return None
+    block = min(cfg.flash_block_q, cfg.flash_block_k)
+    return fa.choose_tiles(
+        "block_diffusion", rows // 2, (cfg.head_dim,), block, block,
+        block_length=cfg.block_length,
+    )
 
 
 class RMSNorm(nn.Module):
@@ -676,16 +692,18 @@ class Attention(nn.Module):
             # The schedule is this layer's, so the share is counted here
             # (read where a step collects what its layers sow).
             self.sow("intermediates", "bd_kept_share", jnp.float32(kept))
+            tiles = _block_diffusion_tiles(cfg, rows)
             _note_attention(
-                f"{cfg.attn_impl}/block_diffusion", f"{traced}/block_diffusion", rows
+                f"{cfg.attn_impl}/block_diffusion", f"{traced}/block_diffusion",
+                rows, tiles,
             )
-            if traced == "flash":
+            if tiles is not None:
                 from torchft_tpu.ops.flash_attention import (
                     flash_attention_block_diffusion,
                 )
 
                 out = flash_attention_block_diffusion(
-                    q, k, v, block_length=cfg.block_length, block=cfg.flash_block_q
+                    q, k, v, block_length=cfg.block_length, block=tiles[0]
                 )
             else:
                 out = dense_attention(
@@ -700,14 +718,16 @@ class Attention(nn.Module):
             out = cfg.attn_fn(q, k, v)
         elif cfg.attn_impl == "flash":
             from torchft_tpu.ops.flash_attention import (
+                choose_tiles,
                 flash_attention,
-                supports,
             )
 
-            if q.shape[1] >= cfg.flash_min_seq and supports(
-                q.shape[1], cfg.flash_block_q, cfg.flash_block_k
-            ):
-                _note_attention("flash", "flash", q.shape[1])
+            tiles = choose_tiles(
+                "causal", q.shape[1], (cfg.head_dim,),
+                cfg.flash_block_q, cfg.flash_block_k,
+            )
+            if q.shape[1] >= cfg.flash_min_seq and tiles is not None:
+                _note_attention("flash", "flash", q.shape[1], tiles)
                 out = flash_attention(
                     q, k, v,
                     block_q=cfg.flash_block_q,

@@ -91,7 +91,7 @@ class LatentAttention(nn.Module):
     def __call__(self, x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
         # llama.py imports this file: its norm and note are taken at call time.
         from torchft_tpu.models.llama import RMSNorm, _note_attention
-        from torchft_tpu.ops.flash_attention import flash_attention_mla, supports_mla
+        from torchft_tpu.ops.flash_attention import choose_tiles, flash_attention_mla
 
         cfg, m = self.cfg, self.cfg.mla
         dn, dr, dv = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
@@ -117,12 +117,11 @@ class LatentAttention(nn.Module):
                 f"latent attention under attn_impl={cfg.attn_impl!r}: it "
                 "exists for 'flash' and 'dense'"
             )
-        if (
-            cfg.attn_impl == "flash"
-            and seq >= cfg.flash_min_seq
-            and supports_mla(seq, dn, dr, dv, cfg.flash_block_q, cfg.flash_block_k)
-        ):
-            _note_attention("flash/mla", "flash/mla", seq)
+        tiles = choose_tiles(
+            "mla", seq, (dn, dr, dv), cfg.flash_block_q, cfg.flash_block_k
+        )
+        if cfg.attn_impl == "flash" and seq >= cfg.flash_min_seq and tiles is not None:
+            _note_attention("flash/mla", "flash/mla", seq, tiles)
             out = flash_attention_mla(
                 q_nope, q_rope, k_nope, k_rope, v,
                 block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,

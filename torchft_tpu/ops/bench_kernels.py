@@ -12,6 +12,7 @@ prints one JSON line.
 
 Run:  python -m torchft_tpu.ops.bench_kernels          # any backend
       python -m torchft_tpu.ops.bench_kernels --chip   # fails off-TPU
+      python -m torchft_tpu.ops.bench_kernels --tiles  # the flash kernels' tile sweep
 """
 
 from __future__ import annotations
@@ -99,6 +100,121 @@ def ssd_section(compiled: bool) -> dict:
     return out
 
 
+# The flash kernels' tile sweep (PERF.md section 6, PR 52): every family at
+# every benchmark cell's shape. (family, cell, (B, S, Hq, Hkv), head widths
+# (qk, ..., v), block length): causal heads are one width; the latent
+# family's are (rope-free, rotary, value); block diffusion's S is both streams.
+SWEEP_SHAPES = [
+    ("causal", "mistral", (4, 4096, 32, 8), (128,), 0),
+    ("causal", "internlm2", (2, 8192, 16, 8), (128,), 0),
+    ("causal", "olmoe", (4, 4096, 16, 16), (128,), 0),
+    ("causal", "nemotron3", (2, 8192, 32, 2), (128,), 0),
+    ("causal", "lfm2", (2, 8192, 32, 8), (64,), 0),
+    ("block_diffusion", "sdar", (2, 16384, 32, 4), (128,), 4),
+    ("mla", "joyai", (2, 8192, 32, 32), (128, 64, 128), 0),
+]
+SWEEP_TILES = [(512, 512), (1024, 512), (512, 1024), (1024, 1024), (2048, 512), (512, 2048)]
+_V5E_BF16_FLOPS = 197e12  # benchmark/peaks.json, "TPU v5 lite"
+
+
+def _sweep_case(family, dims, widths, block_length, tiles, interpret):
+    """(inputs in the kernels' [B,H,S,D] layout, forward, (dq, dkv) given
+    the forward's residuals, forward FLOPs by the benchmark's flops.py
+    convention: 2 FLOP a kept score entry and channel of QK^T and of PV)."""
+    import jax
+    import jax.numpy as jnp
+
+    from torchft_tpu.ops import flash_attention as fa
+
+    B, S, Hq, Hkv = dims
+    bq, bk = tiles
+    rand = lambda i, *shape: jax.random.normal(  # noqa: E731
+        jax.random.PRNGKey(i), shape, jnp.float32
+    ).astype(jnp.bfloat16)
+    if family == "mla":
+        dn, dr, dv = widths
+        args = (rand(0, B, Hq, S, dn), rand(1, B, Hq, S, dr), rand(2, B, Hq, S, dn),
+                rand(3, B, 1, S, dr), rand(4, B, Hq, S, dv))
+        fwd = lambda *a: fa._flash_mla(*a, bq, bk, interpret)  # noqa: E731
+        res = lambda *a: fa._mla_forward_impl(*a, bq, bk, interpret)  # noqa: E731
+        bwd = lambda a, do, out, lse: fa._mla_backward_impl(  # noqa: E731
+            *a, do, lse, fa._row_delta(do, out), bq, bk, interpret
+        )
+        split, entries, width = 2, S * S / 2, dn + dr + dv
+    else:
+        (d,) = widths
+        args = (rand(0, B, Hq, S, d), rand(1, B, Hkv, S, d), rand(2, B, Hkv, S, d))
+        if family == "causal":
+            fwd = lambda *a: fa._flash(*a, True, bq, bk, interpret)  # noqa: E731
+            res = lambda *a: fa._forward_impl(*a, True, bq, bk, interpret)  # noqa: E731
+            bwd = lambda a, do, out, lse: fa._backward_impl(  # noqa: E731
+                *a, do, lse, fa._row_delta(do, out), True, bq, bk, interpret
+            )
+            entries = S * S / 2
+        else:
+            fwd = lambda *a: fa._flash_bd(*a, block_length, bq, interpret)  # noqa: E731
+            res = lambda *a: fa._bd_forward_impl(*a, block_length, bq, interpret)  # noqa: E731
+            bwd = lambda a, do, out, lse: fa._bd_backward_impl(  # noqa: E731
+                *a, do, lse, fa._row_delta(do, out), block_length, bq, interpret
+            )
+            L = S // 2
+            entries = L * L + L * block_length
+        split, width = 1, 2 * d
+    flops = 2.0 * width * entries * Hq * B
+    return args, fwd, res, bwd, split, flops
+
+
+def tile_sweep(shapes=None, tiles=None, reps: int = 8) -> list:
+    """Forward, forward + backward, and dq and dkv alone (each a
+    ``pallas_call`` of its own: the other is dead code to the compiler),
+    ms a call and share of the v5e's bf16 peak, every family at every
+    cell's shape over ``tiles``. The public entries choose their tiles
+    (``flash_attention.choose_tiles``), so the sweep drives the
+    ``custom_vjp`` functions under them, at the tiles it is asked for, on
+    inputs already in the kernels' layout. A tile the compiler refuses
+    (scoped VMEM) is a row with its error."""
+    import jax
+    import jax.numpy as jnp
+
+    compiled = jax.default_backend() == "tpu"
+    rows = []
+    for family, cell, dims, widths, b in shapes or SWEEP_SHAPES:
+        for tile in tiles or SWEEP_TILES:
+            if family == "block_diffusion" and tile[0] != tile[1]:
+                continue  # its kernels take one square tile
+            row = {"family": family, "cell": cell, "shape": list(dims),
+                   "widths": list(widths), "tiles": list(tile)}
+            try:
+                args, fwd, res, bwd, split, flops = _sweep_case(
+                    family, dims, widths, b, tile, not compiled
+                )
+                n = len(args)
+                loss = lambda *a: jnp.sum(fwd(*a).astype(jnp.float32))  # noqa: E731
+                both = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(n))))
+                out, lse = jax.jit(res)(*args)
+                # the output stands in for its cotangent: a time needs no other
+                dq = jax.jit(lambda a, out, lse: bwd(a, out, out, lse)[:split])
+                dkv = jax.jit(lambda a, out, lse: bwd(a, out, out, lse)[split:])
+                ms = {
+                    "fwd": _time_call(jax.jit(fwd), *args, reps=reps),
+                    "fwd_bwd": _time_call(both, *args, reps=reps),
+                    "dq": _time_call(dq, args, out, lse, reps=reps),
+                    "dkv": _time_call(dkv, args, out, lse, reps=reps),
+                }
+                row["ms"] = {k: round(v, 3) for k, v in ms.items()}
+                if compiled:  # a share of the chip's peak is the chip's to give
+                    work = {"fwd": flops, "fwd_bwd": 3 * flops, "dq": flops, "dkv": flops}
+                    row["roofline_pct"] = {
+                        k: round(100 * work[k] / _V5E_BF16_FLOPS / (v / 1e3), 2)
+                        for k, v in ms.items()
+                    }
+            except Exception as e:  # noqa: BLE001 - the compiler's refusal is the finding
+                row["error"] = repr(e)[:240]
+            print(json.dumps(row), flush=True)
+            rows.append(row)
+    return rows
+
+
 def main() -> int:
     import jax
     import jax.numpy as jnp
@@ -119,6 +235,12 @@ def main() -> int:
         fused_reduce_int8,
     )
 
+    if "--tiles" in sys.argv[1:]:
+        # A line a (family, shape, tile); 2,048 a side may not fit the
+        # scoped VMEM (a row with its error), every tile under it has to run.
+        print(json.dumps({"device_kind": jax.devices()[0].device_kind}), flush=True)
+        refused = [r for r in tile_sweep() if "error" in r and max(r["tiles"]) <= 1024]
+        return 1 if refused else 0
     backend = jax.default_backend()
     device_kind = jax.devices()[0].device_kind
     compiled = backend == "tpu"  # off-TPU these run interpreted

@@ -45,6 +45,12 @@ the lane (``_fwd_step``): the row max replicated across 128 lanes, the
 row sum as 128 partial sums reduced once at the sweep's end, so a step
 has one cross-lane reduction (the max) and broadcasts nothing to store.
 
+Tiles: no caller names the tile. ``choose_tiles`` takes it from the shape
+a call is given, 1,024 x 1,024 wherever 1,024 divides the sequence (6 to
+12% under 512 x 512 forward and backward in every family and at both head
+widths on a v5e), else 512, else what the caller's bound admits; the
+entries' ``block_q`` / ``block_k`` are that bound.
+
 Numerics: scores and softmax accumulate in fp32 regardless of input
 dtype; output is cast back to the input dtype. Tested bitwise-free
 against ``dense_attention`` to ≤2e-2 in bf16 and ≤1e-5 in fp32 (the
@@ -71,6 +77,7 @@ __all__ = [
     "flash_attention_block_diffusion",
     "flash_attention_mla",
     "block_diffusion_tiles",
+    "choose_tiles",
     "supports",
     "supports_block_diffusion",
     "supports_mla",
@@ -84,20 +91,85 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def supports(seq_len: int, block_q: int = 512, block_k: int = 512) -> bool:
-    """Whether the kernel path handles this sequence length (the caller
-    falls back to dense attention otherwise)."""
-    bq = min(block_q, seq_len)
-    bk = min(block_k, seq_len)
-    return (
-        seq_len % bq == 0
-        and seq_len % bk == 0
-        # TPU sublane alignment (fp32 tile = 8 rows; bf16 inputs are
-        # upcast in-kernel but blocks still enter VMEM in their own dtype,
-        # so keep the stricter 16-row multiple).
-        and bq % 16 == 0
-        and bk % 16 == 0
-    )
+# The tiles measured good on a v5e, best first, and so the largest a call
+# takes unless its caller names a smaller one (PERF.md section 6, PR 52: the
+# family x shape x tile sweep of ``bench_kernels --tiles``).
+_GOOD_TILES = (1024, 512)
+_MAX_TILE = _GOOD_TILES[0]
+
+
+def _tile(length: int, largest: int, multiple: int = 16) -> Optional[int]:
+    """The tile one side of a sweep over ``length`` positions takes under
+    ``largest``: the first of the measured-good tiles, then ``largest``
+    itself (what the kernels took before they chose), that cuts the length
+    into whole tiles of whole ``multiple`` rows. A length under a tile is
+    one tile of its own; past 512 rows only in whole lane tiles (1,008
+    rows, 63 groups of 16 lanes, do not fit the scoped VMEM). None: no
+    tiling."""
+    for t in (*(t for t in _GOOD_TILES if t <= largest), largest):
+        t = min(t, length)
+        if (
+            t > 0 and length % t == 0 and t % multiple == 0
+            and (t <= _GOOD_TILES[-1] or t % _LANES == 0)
+        ):
+            return t
+    return None
+
+
+def choose_tiles(
+    family: str,
+    seq_len: int,
+    widths: tuple = (),
+    block_q: int = _MAX_TILE,
+    block_k: int = _MAX_TILE,
+    kv_len: Optional[int] = None,
+    block_length: int = 0,
+) -> Optional[tuple]:
+    """The (q rows, kv columns) a call's VMEM tiles hold, from what the
+    call can see of its input, or None where the kernels do not take the
+    shape (the caller then runs dense attention). The one rule of every
+    entry and ``supports*`` predicate below.
+
+    ``family``: 'causal', 'block' (the ring's offset block: ``kv_len``
+    keys against ``seq_len`` queries), 'block_diffusion' (``seq_len`` the
+    length of ONE stream, ``block_length`` its blocks) or 'mla'
+    (``widths`` = rope-free, rotary and value channels; the other
+    families' one head width decides nothing today: 64 and 128 measured
+    best at the same tiles). ``block_q`` and ``block_k`` are the LARGEST
+    tile the caller admits (``LlamaConfig.flash_block_q`` /
+    ``flash_block_k``), not the tile.
+
+    Alignment: tiles of whole 16-row groups (fp32 tiles are 8 rows; bf16
+    blocks enter VMEM in their own dtype, so the stricter multiple).
+    Block diffusion takes one square tile that lies in one stream and cuts
+    no block and, compiled, is whole lane tiles (a stream is half of the
+    array, so the per-row residuals' blocks cannot be the array's own last
+    dimension as a short causal sequence's are). The latent kernels have
+    been compiled for a rope-free part and values of whole lane tiles and
+    a rotary part of half a tile or whole ones; the interpreter takes any.
+    """
+    compiled = not _interpret()
+    if family == "block_diffusion":
+        if block_length <= 0:
+            return None
+        rows = math.lcm(16, block_length, _LANES if compiled else 1)
+        tile = _tile(seq_len, min(block_q, block_k), rows)
+        return None if tile is None else (tile, tile)
+    if family == "mla" and compiled:
+        nope, rope, v_dim = widths
+        if nope % _LANES or v_dim % _LANES or rope % (_LANES // 2):
+            return None
+    bq = _tile(seq_len, block_q)
+    bk = _tile(seq_len if kv_len is None else kv_len, block_k)
+    return None if bq is None or bk is None else (bq, bk)
+
+
+def supports(
+    seq_len: int, block_q: int = _MAX_TILE, block_k: int = _MAX_TILE
+) -> bool:
+    """Whether the kernel path handles this sequence length under these
+    largest tiles (the caller falls back to dense attention otherwise)."""
+    return choose_tiles("causal", seq_len, (), block_q, block_k) is not None
 
 
 # ---------------------------------------------------------------------------
@@ -581,22 +653,23 @@ def flash_attention(
     k: jax.Array,
     v: jax.Array,
     causal: bool = True,
-    block_q: int = 512,
-    block_k: int = 512,
+    block_q: int = _MAX_TILE,
+    block_k: int = _MAX_TILE,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Causal GQA flash attention, differentiable. q: [B,S,Hq,D]; k/v:
-    [B,S,Hkv,D] with Hq % Hkv == 0. Returns [B,S,Hq,D] in q's dtype."""
+    [B,S,Hkv,D] with Hq % Hkv == 0. Returns [B,S,Hq,D] in q's dtype.
+    ``block_q``, ``block_k``: the largest tiles to take (``choose_tiles``)."""
     B, S, Hq, D = q.shape
     _, _, Hkv, _ = k.shape
     assert Hq % Hkv == 0, (Hq, Hkv)
-    block_q = min(block_q, S)
-    block_k = min(block_k, S)
-    if not supports(S, block_q, block_k):
+    tiles = choose_tiles("causal", S, (D,), block_q, block_k)
+    if tiles is None:
         raise ValueError(
             f"flash_attention: seq_len {S} not divisible by blocks "
             f"({block_q},{block_k}); use dense_attention"
         )
+    block_q, block_k = tiles
     itp = _interpret() if interpret is None else interpret
     # [B,S,H,D] -> [B,H,S,D]: S x D blocks are MXU-shaped.
     qt = jnp.swapaxes(q, 1, 2)
@@ -877,8 +950,8 @@ def flash_attention_block(
     v: jax.Array,
     q_offset: jax.Array,
     k_offset: jax.Array,
-    block_q: int = 512,
-    block_k: int = 512,
+    block_q: int = _MAX_TILE,
+    block_k: int = _MAX_TILE,
     interpret: Optional[bool] = None,
 ) -> tuple:
     """One causal-at-global-positions attention block: q [B,Sq,Hq,D]
@@ -889,13 +962,13 @@ def flash_attention_block(
     parallel/ring_attention.py). Differentiable (offsets get no grad)."""
     B, Sq, Hq, D = q.shape
     Skv = k.shape[1]
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Skv)
-    if not (supports(Sq, block_q, block_q) and supports(Skv, block_k, block_k)):
+    tiles = choose_tiles("block", Sq, (D,), block_q, block_k, kv_len=Skv)
+    if tiles is None:
         raise ValueError(
             f"flash_attention_block: shapes (Sq={Sq}, Skv={Skv}) not "
             f"block-divisible; use the dense fold"
         )
+    block_q, block_k = tiles
     qoff = jnp.asarray(q_offset, jnp.int32).reshape(1, 1)
     koff = jnp.asarray(k_offset, jnp.int32).reshape(1, 1)
     itp = _interpret() if interpret is None else interpret
@@ -923,26 +996,34 @@ def flash_attention_block(
 # ---------------------------------------------------------------------------
 
 
-def supports_block_diffusion(stream_len: int, block_length: int, block: int = 512) -> bool:
+def supports_block_diffusion(
+    stream_len: int, block_length: int, block: int = _MAX_TILE
+) -> bool:
     """Whether the block-diffusion kernels handle two streams of
-    ``stream_len`` positions under blocks of ``block_length``: whole
-    tiles a stream, whole blocks a tile and, compiled, tiles of whole
-    lane widths (a stream is half of the array, so the per-row residuals'
-    blocks cannot be the array's own last dimension as a short causal
-    sequence's are)."""
-    blk = min(block, stream_len)
-    return (
-        block_length > 0
-        and supports(stream_len, blk, blk)
-        and blk % block_length == 0
-        and (blk % 128 == 0 or _interpret())
+    ``stream_len`` positions under blocks of ``block_length`` in tiles of
+    at most ``block`` (``choose_tiles`` has the rules)."""
+    return _bd_tile(stream_len, block_length, block) is not None
+
+
+def _bd_tile(stream_len: int, block_length: int, block: int) -> Optional[int]:
+    tiles = choose_tiles(
+        "block_diffusion", stream_len, (), block, block, block_length=block_length
     )
+    return None if tiles is None else tiles[0]
 
 
-def block_diffusion_tiles(stream_len: int, block_length: int, block: int = 512):
+def block_diffusion_tiles(
+    stream_len: int, block_length: int, block: int = _MAX_TILE
+):
     """(kept score entries, score entries of the tiles a sweep runs) a
-    head and sequence, forward; the backward kernels run the same tiles."""
-    blk = min(block, stream_len)
+    head and sequence, forward, at the tile the kernels choose under
+    ``block``; the backward kernels run the same tiles."""
+    blk = _bd_tile(stream_len, block_length, block)
+    if blk is None:
+        raise ValueError(
+            f"block_diffusion_tiles: streams of {stream_len} positions in "
+            f"blocks of {block_length} do not tile under {block}"
+        )
     n = stream_len // blk
     kept = stream_len * stream_len + stream_len * block_length
     return kept, (n * n + 2 * n) * blk * blk
@@ -1213,18 +1294,20 @@ def flash_attention_block_diffusion(
     k: jax.Array,
     v: jax.Array,
     block_length: int,
-    block: int = 512,
+    block: int = _MAX_TILE,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """GQA flash attention under the block-diffusion training mask,
     differentiable. q: [B,2L,Hq,D]; k/v: [B,2L,Hkv,D]: rows 0..L-1 the
     noisy stream, L..2L-1 the clean one. Returns [B,2L,Hq,D] in q's dtype.
+    ``block``: the largest tile to take (``choose_tiles``).
     The kernels' trace names start ``flash_attention``, as the causal
     ones' do (a kernel is named for the jit around it)."""
     B, S, Hq, D = q.shape
     assert Hq % k.shape[2] == 0, (Hq, k.shape[2])
     L = S // 2
-    if S % 2 or not supports_block_diffusion(L, block_length, block):
+    tile = None if S % 2 else _bd_tile(L, block_length, block)
+    if tile is None:
         raise ValueError(
             f"flash_attention_block_diffusion: two streams of {L} positions "
             f"in blocks of {block_length} do not tile by {min(block, L)}"
@@ -1232,7 +1315,7 @@ def flash_attention_block_diffusion(
     itp = _interpret() if interpret is None else interpret
     out = _flash_bd(
         jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
-        block_length, min(block, L), itp,
+        block_length, tile, itp,
     )
     return jnp.swapaxes(out, 1, 2)
 
@@ -1263,14 +1346,11 @@ def flash_attention_block_diffusion(
 
 def supports_mla(
     seq_len: int, nope: int, rope: int, v_dim: int,
-    block_q: int = 512, block_k: int = 512,
+    block_q: int = _MAX_TILE, block_k: int = _MAX_TILE,
 ) -> bool:
-    """Whether the latent kernels handle this sequence and these widths:
-    the causal kernels' tilings and, compiled, a rope-free part and values
-    of whole lane tiles and a rotary part of half a tile or whole ones
-    (what has been compiled for the chip; the interpreter takes any)."""
-    lanes_ok = nope % _LANES == 0 and v_dim % _LANES == 0 and rope % (_LANES // 2) == 0
-    return supports(seq_len, block_q, block_k) and (lanes_ok or _interpret())
+    """Whether the latent kernels handle this sequence and these widths
+    under these largest tiles (``choose_tiles`` has the rules)."""
+    return choose_tiles("mla", seq_len, (nope, rope, v_dim), block_q, block_k) is not None
 
 
 def _mla_fwd_kernel(
@@ -1498,8 +1578,8 @@ def flash_attention_mla(
     k_nope: jax.Array,
     k_rope: jax.Array,
     v: jax.Array,
-    block_q: int = 512,
-    block_k: int = 512,
+    block_q: int = _MAX_TILE,
+    block_k: int = _MAX_TILE,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Causal latent attention in its training form, differentiable.
@@ -1510,13 +1590,13 @@ def flash_attention_mla(
     kernel is named for the jit around it)."""
     B, S, H, dn = q_nope.shape
     dr, dv = q_rope.shape[-1], v.shape[-1]
-    block_q = min(block_q, S)
-    block_k = min(block_k, S)
-    if not supports_mla(S, dn, dr, dv, block_q, block_k):
+    tiles = choose_tiles("mla", S, (dn, dr, dv), block_q, block_k)
+    if tiles is None:
         raise ValueError(
             f"flash_attention_mla: seq_len {S} in blocks ({block_q},{block_k}) "
             f"at widths {dn}+{dr}|{dv}: use latent_dense_attention"
         )
+    block_q, block_k = tiles
     itp = _interpret() if interpret is None else interpret
     to_heads = lambda x: jnp.swapaxes(x, 1, 2)  # noqa: E731
     out = _flash_mla(
