@@ -2,7 +2,11 @@
 is a ``jax.Array``: the buckets form a line (every device-to-host copy
 started at once, one ``pull`` a bucket, a reduced bucket pushed back
 while the next arrives) and device arrays come back, landed, with the
-inputs' shardings. A tree with a numpy leaf in it keeps the kept views.
+inputs' shardings. A bucket comes down as one array (its one leaf's
+copy, or its leaves joined on the device) and that landing is the bucket:
+``pack`` copies nothing, the manager copies into the kept buffer only
+where something writes, a scale of exactly 1 is no pass. A tree with a
+numpy leaf in it keeps the four passes and the kept views.
 
 Beside ``test_ddp_pack_reuse.py`` (the kept buffers) and
 ``test_bucket_schedule.py`` (the order), whose helpers it uses. On the
@@ -44,6 +48,7 @@ PUSH = "torchft::ddp::push"
 PUSH_WAIT = "torchft::ddp::push_wait"
 ISSUE = "torchft::manager::allreduce"
 HOST_COPY = "torchft::manager::host_copy"
+SCALE = "torchft::manager::allreduce_scale"
 
 # float32 leaves of 300, 200 and 60 + 7 values at 1 kB a bucket: three
 # buckets, issued 67, 200, 300 values (layout indices 2, 1, 0).
@@ -99,6 +104,30 @@ def _step(m, ddp, grads, **kwargs):
 
 def _packs(recorded):
     return [s[ATTRS] for s in recorded if s[NAME] == PACK]
+
+
+def _all_landed(packs):
+    """Every bucket is the copy PJRT landed in that call: ``pack`` copied
+    nothing into a kept buffer, and the memory is that step's."""
+    return bool(packs) and all(
+        p["nbytes"] == 0 and p["reused_bytes"] == 0
+        and p["fresh_bytes"] == BUCKET_BYTES[p["bucket"]] for p in packs)
+
+
+def _copied(recorded):
+    """What ``Manager.allreduce`` copied into the kept buffers, a bucket."""
+    return [s[ATTRS]["copied_bytes"] for s in _named(recorded, HOST_COPY)]
+
+
+class _WritingPG(_FailingPG):
+    """A group that reduces into what it is given, as a world of two or
+    the subprocess group does: the manager must hand it writable memory."""
+
+    def allreduce_writes(self, op=None):
+        return True
+
+
+PGS = {"reads-only": _FailingPG, "writes": _WritingPG}
 
 
 def _named(recorded, name):
@@ -197,19 +226,22 @@ def test_three_steps_equal_the_numpy_path_bit_for_bit(world, kwargs):
                 assert d.tobytes() == w.astype(np.float32).tobytes()
 
 
-def test_returned_leaves_alias_no_kept_buffer_and_outlive_the_next_call():
+@pytest.mark.parametrize("pg", sorted(PGS))
+def test_returned_leaves_alias_no_kept_buffer_and_outlive_the_next_call(pg):
     import jax
 
-    (m,) = _managers(1)
+    (m,) = _managers(1, pg=PGS[pg]())
     ddp = DistributedDataParallel(m, bucket_cap_mb=KB)
     try:
-        first, _, _ = _step(m, ddp, _device_tree(0, 0))
+        first, _, recorded = _step(m, ddp, _device_tree(0, 0))
+        assert _copied(recorded) == (
+            [BUCKET_BYTES[b] for b in ORDER] if pg == "writes" else [0, 0, 0])
         kept_then = [np.array(x) for x in _numpy(first)]
         flats = list(ddp._pack_buffers._flats)
         assert len(flats) == 3
         for leaf in jax.tree_util.tree_leaves(first):
             assert not any(np.shares_memory(np.asarray(leaf), f) for f in flats)
-        # the next call packs other values into the same buffers ...
+        # the next call has the same buffers to write, where it writes ...
         second, committed, _ = _step(m, ddp, _device_tree(0, 1))
         assert committed and all(
             a is b for a, b in zip(flats, ddp._pack_buffers._flats))
@@ -266,16 +298,23 @@ def test_the_spans_of_a_step_of_three_buckets():
     assert [p[ATTRS] for p in pulls] == [
         {"bucket": b, "nbytes": BUCKET_BYTES[b]} for b in ORDER]
     assert sum(p[ATTRS]["nbytes"] for p in pulls) == sum(x.nbytes for x in leaves)
-    assert [p[ATTRS]["bucket"] for p in packs] == ORDER
+    # the landing is the bucket: pack ran a bucket and copied nothing
+    # (parent: nbytes == the bucket's bytes, copied into the kept buffer)
+    assert [p[ATTRS] for p in packs] == [
+        {"bucket": b, "nbytes": 0, "fresh_bytes": BUCKET_BYTES[b],
+         "reused_bytes": 0} for b in ORDER]
     assert [u[ATTRS]["bucket"] for u in _named(recorded, UNPACK)] == ORDER
     assert [p[ATTRS] for p in pushes] == [
         {"bucket": b, "nbytes": BUCKET_BYTES[b]} for b in ORDER]
     # Manager.allreduce is called smallest first: each call's own copy span
-    # carries its payload's bytes
+    # carries its payload's bytes, and at a quorum of one copied none
     issues = [s for s in _named(recorded, ISSUE) if s[PARENT] == root[ID]]
     assert len(issues) == 3
-    copies = {s[PARENT]: s[ATTRS]["nbytes"] for s in _named(recorded, HOST_COPY)}
-    assert [copies[s[ID]] for s in issues] == [BUCKET_BYTES[b] for b in ORDER]
+    copies = {s[PARENT]: s[ATTRS] for s in _named(recorded, HOST_COPY)}
+    assert [copies[s[ID]] for s in issues] == [
+        {"nbytes": BUCKET_BYTES[b], "copied_bytes": 0} for b in ORDER]
+    # times exactly 1 is no pass: a span a bucket, each with a count of 0
+    assert [s[ATTRS] for s in _named(recorded, SCALE)] == [{"nbytes": 0}] * 3
     # each bucket's own stages in order, and bucket k goes back before
     # bucket k+1 is pulled: the dummy group's collectives are done at issue
     pull_of, pack_of, push_of = (
@@ -296,7 +335,8 @@ def test_the_spans_of_a_step_of_three_buckets():
     from benchmark.metrics import ar_host_bytes_step, ar_pull_ms
 
     run = {"journal": [{"event": "step_spans", "attrs": {"spans": recorded}}]}
-    assert ar_host_bytes_step.read(run) == 3 * 2268  # pulled + packed + scaled
+    # pulled, and nothing else (parent: 3 * 2268, pulled + packed + scaled)
+    assert ar_host_bytes_step.read(run) == 2268
     assert ar_pull_ms.read(run) == pytest.approx(
         sum(p[T1] - p[T0] for p in pulls) * 1e3)
 
@@ -331,6 +371,179 @@ def test_a_bucket_whose_collective_is_not_done_is_not_waited_for_early():
 
 
 # ---------------------------------------------------------------------------
+# A byte is touched once each way: what is copied, scaled and allocated
+# ---------------------------------------------------------------------------
+
+
+def test_at_a_quorum_of_one_the_kept_buffers_are_never_written():
+    """The wrapper allocates no gradient of host memory of its own in
+    steady state: ``np.empty`` touches no page, and nothing writes one."""
+    (m,) = _managers(1)
+    ddp = DistributedDataParallel(m, bucket_cap_mb=KB)
+    try:
+        _step(m, ddp, _device_tree(0, 0))
+        flats = list(ddp._pack_buffers._flats)
+        for f in flats:
+            f[...] = 777.0
+        for step in (1, 2, 3):
+            out, committed, recorded = _step(m, ddp, _device_tree(0, step))
+            assert committed and _all_landed(_packs(recorded))
+            assert _copied(recorded) == [0, 0, 0]
+            assert all(a is b for a, b in zip(flats, ddp._pack_buffers._flats))
+            for got, want in zip(_numpy(out), _numpy(_device_tree(0, step))):
+                assert got.tobytes() == want.tobytes()
+        assert all(np.all(f == 777.0) for f in flats)
+    finally:
+        m.shutdown()
+
+
+@pytest.mark.timeout(120)
+@pytest.mark.parametrize("kwargs", [dict(), dict(should_quantize=True)],
+                         ids=["fp32", "host-int8"])
+def test_a_world_of_two_copies_into_the_kept_buffers_and_scales(kwargs, tmp_path):
+    """Where something writes (the ring, the scale of 0.5, the quantized
+    collective) the bucket goes into the kept buffer first, once, inside
+    ``Manager.allreduce``, and both passes are counted."""
+    store = TCPStoreServer()
+    managers = _managers(2, store)
+
+    def run(rank):
+        m = managers[rank]
+        ddp = DistributedDataParallel(m, bucket_cap_mb=KB)
+        flats = None
+        for step in range(3):
+            m.start_quorum()
+            ddp.allreduce_grads(_device_tree(rank, step), **kwargs)
+            assert m.should_commit()
+            if flats is None:
+                flats = list(ddp._pack_buffers._flats)
+            assert all(a is b for a, b in zip(flats, ddp._pack_buffers._flats))
+
+    try:
+        with ThreadPoolExecutor(max_workers=2) as pool:
+            for f in [pool.submit(run, r) for r in range(2)]:
+                f.result(timeout=90)
+    finally:
+        for m in managers:
+            m.shutdown()
+        store.shutdown()
+    # Two Managers share the process's span buffer: read the journal.
+    import json
+
+    with open(tmp_path / "journal.jsonl") as f:
+        spans = [s for e in map(json.loads, f) if e["event"] == "step_spans"
+                 for s in e["attrs"]["spans"]]
+    calls = 2 * 3 * 3  # ranks, steps, buckets
+    packs = [s[ATTRS] for s in spans if s[NAME] == PACK]
+    assert len(packs) == calls and _all_landed(packs)
+    copies = [s[ATTRS] for s in spans if s[NAME] == HOST_COPY]
+    assert len(copies) == calls
+    assert all(c["copied_bytes"] == c["nbytes"] for c in copies)
+    assert sum(c["copied_bytes"] for c in copies) == 2 * 3 * 2268
+    scales = [s[ATTRS]["nbytes"] for s in spans if s[NAME] == SCALE]
+    assert sorted(scales) == sorted(
+        BUCKET_BYTES[b] for b in ORDER for _ in range(2 * 3))
+
+
+def test_a_bucket_of_several_leaves_is_joined_by_one_program_a_layout(monkeypatch):
+    """Three calls, one compile; and the device's temporary is gone when
+    the call returns (it would be a bucket of HBM under ``apply_step``)."""
+    import jax
+
+    from torchft_tpu import ddp as ddp_module
+
+    made = []
+    real = ddp_module._join_leaves
+
+    def joining(leaves):
+        out = real(leaves)
+        made.append((weakref.ref(out), [x.shape for x in leaves], out.shape))
+        return out
+
+    monkeypatch.setattr(ddp_module, "_join_leaves", joining)
+    (m,) = _managers(1)
+    ddp = DistributedDataParallel(m, bucket_cap_mb=KB)
+    try:
+        _step(m, ddp, _device_tree(0, 0))
+        compiled = real._cache_size()
+        for step in (1, 2, 3):
+            out, committed, _ = _step(m, ddp, _device_tree(0, step))
+            assert committed
+            for got, want in zip(_numpy(out), _numpy(_device_tree(0, step))):
+                assert got.tobytes() == want.tobytes()
+        assert real._cache_size() == compiled
+    finally:
+        m.shutdown()
+    # only the bucket of two leaves (c, d); a leaf alone needs no program
+    assert [(shapes, flat) for _, shapes, flat in made] == [
+        ([(60,), (7,)], (67,))] * 4
+    del out
+    gc.collect()
+    assert [ref() for ref, _, _ in made] == [None] * 4
+    assert isinstance(real, type(jax.jit(lambda x: x)))
+
+
+def test_a_leaf_alone_comes_down_through_a_handle_only_the_call_holds(monkeypatch):
+    """jax caches a host copy on the array it was asked of: asked of the
+    caller's leaf it would live, a gradient of host memory, until the
+    caller drops its tree. The copy is asked of a second handle on the
+    same device buffer, and that handle is gone at return."""
+    import jax
+
+    handles = []
+    real = jax.make_array_from_single_device_arrays
+
+    def handle(shape, sharding, arrays):
+        out = real(shape, sharding, arrays)
+        (leaf,) = arrays
+        assert out is not leaf and out.shape == leaf.shape
+        assert out.unsafe_buffer_pointer() == leaf.unsafe_buffer_pointer()
+        handles.append((weakref.ref(out), leaf.shape))
+        return out
+
+    monkeypatch.setattr(jax, "make_array_from_single_device_arrays", handle)
+    (m,) = _managers(1)
+    ddp = DistributedDataParallel(m, bucket_cap_mb=KB)
+    try:
+        grads = _device_tree(0, 0)
+        out, committed, _ = _step(m, ddp, grads)
+    finally:
+        m.shutdown()
+    assert committed
+    # the two buckets of one leaf, in issue order: b (200), then a (3 x 100)
+    assert [shape for _, shape in handles] == [(200,), (3, 100)]
+    gc.collect()
+    assert [ref() for ref, _ in handles] == [None, None]
+    for got, want in zip(_numpy(out), _numpy(grads)):
+        assert got.tobytes() == want.tobytes()
+
+
+def test_leaves_on_several_devices_are_packed_on_the_host():
+    """One program cannot join what lies on two devices without moving it:
+    such a bucket keeps the host's pack, into the kept buffer."""
+    import jax
+
+    devices = jax.devices()
+    grads = {"c": jax.device_put(_values(60, 0, 0, 2), devices[0]),
+             "d": jax.device_put(_values(7, 0, 0, 3), devices[1])}
+    (m,) = _managers(1)
+    ddp = DistributedDataParallel(m, bucket_cap_mb=KB)
+    try:
+        for want_fresh in (268, 0):
+            out, committed, recorded = _step(m, ddp, grads)
+            assert committed
+            assert _packs(recorded) == [{
+                "bucket": 0, "nbytes": 268, "fresh_bytes": want_fresh,
+                "reused_bytes": 268 - want_fresh}]
+            assert _copied(recorded) == [0]
+            for k in grads:
+                assert out[k].sharding == grads[k].sharding
+                assert np.asarray(out[k]).tobytes() == np.asarray(grads[k]).tobytes()
+    finally:
+        m.shutdown()
+
+
+# ---------------------------------------------------------------------------
 # Failure is what it was
 # ---------------------------------------------------------------------------
 
@@ -338,15 +551,18 @@ def test_a_bucket_whose_collective_is_not_done_is_not_waited_for_early():
 @pytest.mark.parametrize("failure", ["raises", "late", "latched"],
                          ids=["pg-raises-at-issue", "work-fails-at-wait",
                               "latched-manager-error"])
-def test_a_failed_step_retires_the_buffers_and_pushes_nothing_from_them(failure):
+@pytest.mark.parametrize("writes", sorted(PGS))
+def test_a_failed_step_retires_the_buffers_and_pushes_nothing_from_them(
+        writes, failure):
     import jax
 
-    pg = _FailingPG()
+    pg = PGS[writes]()
     (m,) = _managers(1, pg=pg)
     ddp = DistributedDataParallel(m, bucket_cap_mb=KB)
     try:
         _step(m, ddp, _device_tree(0, 0))
-        assert _all_reused(_packs(_step(m, ddp, _device_tree(0, 1))[2]))
+        # (parent: _all_reused, every pack copied into its kept buffer)
+        assert _all_landed(_packs(_step(m, ddp, _device_tree(0, 1))[2]))
         kept = list(ddp._pack_buffers._flats)
 
         m.start_quorum()
@@ -367,16 +583,25 @@ def test_a_failed_step_retires_the_buffers_and_pushes_nothing_from_them(failure)
         assert all(failed[k] is grads[k] for k in grads)
         assert ddp._pack_buffers._flats == []
 
+        # what the failed collective holds: the kept buffers where the
+        # group writes, else the landed copies, which nobody can write
+        if failure != "latched":
+            assert pg.held and all(
+                any(h is k for k in kept) if writes == "writes"
+                else not h.flags.writeable for h in pg.held)
+
         # start_quorum clears the latch; the next step sizes a new set
         out, committed, recorded = _step(m, ddp, _device_tree(0, 3))
-        assert committed and _all_fresh(_packs(recorded))
+        assert committed and _all_landed(_packs(recorded))
         assert not any(a is b for a in kept for b in ddp._pack_buffers._flats)
-        for old in kept + pg.held:  # the aborted thread writes at last
-            old[...] = 777.0
+        for old in kept + [h for h in pg.held if h.flags.writeable]:
+            old[...] = 777.0  # the aborted thread writes at last
         for got, want in zip(_numpy(out), _numpy(_device_tree(0, 3))):
             assert got.tobytes() == want.tobytes()
         assert all(isinstance(x, jax.Array) for x in out.values())
-        assert _all_reused(_packs(_step(m, ddp, _device_tree(0, 4))[2]))
+        flats = list(ddp._pack_buffers._flats)
+        assert _all_landed(_packs(_step(m, ddp, _device_tree(0, 4))[2]))
+        assert all(a is b for a, b in zip(flats, ddp._pack_buffers._flats))
     finally:
         m.shutdown()
 
@@ -427,7 +652,8 @@ def test_a_call_that_raises_retires_the_buffers():
         recorded = _recorded()
         assert len(_named(recorded, PULL)) == 1 and not _named(recorded, PUSH)
         out, committed, recorded = _step(m, ddp, _device_tree(0, 2))
-        assert committed and _all_fresh(_packs(recorded))
+        assert committed and _all_landed(_packs(recorded))
+        assert len(ddp._pack_buffers._flats) == 3
         assert not any(a is b for a in kept for b in ddp._pack_buffers._flats)
         for got, want in zip(_numpy(out), _numpy(_device_tree(0, 2))):
             assert got.tobytes() == want.tobytes()
@@ -463,6 +689,13 @@ def test_numpy_leaves_in_get_the_kept_views_out(numpy_leaves):
         # valid until the next call, which has written them again
         assert np.shares_memory(first[k], second[k])
         assert first[k].tobytes() == np.asarray(tree(1)[k]).tobytes()
+    # the four passes of before, span for span: every bucket packed into
+    # its kept buffer, fresh on the wrapper's first call, nothing for the
+    # manager to copy
+    assert _all_fresh(_packs(recorded))
+    assert [p["nbytes"] for p in _packs(recorded)] == [BUCKET_BYTES[b] for b in ORDER]
+    assert _copied(recorded) == [0, 0, 0]
+    assert [s[ATTRS] for s in _named(recorded, SCALE)] == [{"nbytes": 0}] * 3
     # pulled whole, in one span that has no bucket; nothing is pushed
     (pull,) = _named(recorded, PULL)
     device_bytes = sum(4 * n for k, n in SIZES.items() if k not in numpy_leaves)

@@ -1,7 +1,9 @@
-"""The DDP wrapper's kept bucket buffers (host path): ``pack`` copies each
-bucket's leaves into flat buffers the wrapper keeps between calls, the
-averaged leaves come back as views of them, and a failed step retires
-the set.
+"""The DDP wrapper's kept bucket buffers (host path, a numpy leaf in the
+tree): ``pack`` copies each bucket's leaves into flat buffers the wrapper
+keeps between calls, the averaged leaves come back as views of them, and
+a failed step retires the set. (Every leaf a ``jax.Array``: the landed
+copy is the bucket and the kept buffer is written only where something
+writes, ``test_ddp_bucket_line.py``.)
 
 Gradients are small multiples of 1/64, so every sum, average and
 quantization below is exact arithmetic on any host and the recorded
@@ -370,7 +372,7 @@ EF_AT_PARENT = {
 }
 
 
-def _ef_rank(m, rank, bits):
+def _ef_rank(m, rank, bits, device=False):
     """Three steps of one rank: (digest, [(pack attrs, hooks run)] a step)."""
     ddp = DistributedDataParallel(
         m, bucket_cap_mb=KB, error_feedback=True, quantize_bits=bits)
@@ -392,6 +394,10 @@ def _ef_rank(m, rank, bits):
     for step in range(3):
         # off the 1/64 grid, so that 8 and 4 bits both drop something
         grads = {k: v * np.float32(1 / 3) for k, v in _other_tree(rank, step).items()}
+        if device:  # the line of buckets: the same values as jax.Arrays
+            import jax.numpy as jnp
+
+            grads = {k: jnp.asarray(v) for k, v in grads.items()}
         m.start_quorum()
         out = ddp.allreduce_grads(grads, should_quantize=True)
         for key, flat, q, s in sorted(seen, key=lambda t: t[0]):
@@ -410,14 +416,15 @@ def _ef_rank(m, rank, bits):
     return digest.hexdigest(), per_step
 
 
-def _ef_digests(bits):
+def _ef_digests(bits, device=False):
     """Two ranks on the host-quantized wire with error feedback on."""
     store = TCPStoreServer()
     managers = _managers(2, store)
     try:
         with ThreadPoolExecutor(max_workers=2) as pool:
             return [f.result(timeout=90) for f in [
-                pool.submit(_ef_rank, managers[r], r, bits) for r in range(2)]]
+                pool.submit(_ef_rank, managers[r], r, bits, device)
+                for r in range(2)]]
     finally:
         for m in managers:
             m.shutdown()
@@ -425,9 +432,11 @@ def _ef_digests(bits):
 
 
 @pytest.mark.timeout(120)
+@pytest.mark.parametrize("leaves", ["numpy", "device"])
 @pytest.mark.parametrize("bits", [8, 4])
-def test_error_feedback_payload_and_residuals_are_the_parents(bits, recorded_spans):
-    results = _ef_digests(bits)
+def test_error_feedback_payload_and_residuals_are_the_parents(
+        bits, leaves, recorded_spans):
+    results = _ef_digests(bits, device=leaves == "device")
     assert [digest for digest, _ in results] == EF_AT_PARENT[bits]
     assert all(hooks == [2, 2, 2] for _, hooks in results)  # a bucket each
     # Two Managers share the process's span buffer, so a gate flushes the
@@ -439,6 +448,16 @@ def test_error_feedback_payload_and_residuals_are_the_parents(bits, recorded_spa
     packs = [s[ATTRS] for e in events if e["event"] == "step_spans"
              for s in e["attrs"]["spans"] if s[NAME] == PACK]
     assert len(packs) == 2 * 3 * 2
+    if leaves == "device":
+        # The landed copy is the bucket (fresh, nothing copied); from the
+        # second step on the compensated copy is the one pass and is
+        # fresh too. The kept buffers are never asked for.
+        first = [p for p in packs if p["nbytes"] == 0]
+        assert len(first) == 2 * 2 and all(p["fresh_bytes"] > 0 for p in first)
+        assert all(p["fresh_bytes"] == 2 * p["nbytes"] for p in packs
+                   if p not in first)
+        assert all(p["reused_bytes"] == 0 for p in packs)
+        return
     first = [p for p in packs if p["reused_bytes"] == 0]
     assert len(first) == 2 * 2 and _all_fresh(first)
     assert all(p["fresh_bytes"] == p["nbytes"] == p["reused_bytes"]

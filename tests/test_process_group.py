@@ -466,6 +466,42 @@ def test_world_size_one_noop():
     pg.shutdown()
 
 
+@pytest.mark.parametrize("group", ["socket-alone", "socket-of-two", "dummy",
+                                   "swallowing", "fake", "base", "baby"])
+def test_a_group_says_whether_its_allreduce_writes_its_inputs(store, group):
+    """``allreduce_writes``: what ``Manager.allreduce`` asks before it
+    copies a read-only input. True unless the group knows better, and a
+    read-only array goes through a group that says False untouched."""
+    from torchft_tpu.baby import ProcessGroupBabySocket
+    from torchft_tpu.process_group import ProcessGroup
+
+    ro = np.arange(6, dtype=np.float32)
+    ro.flags.writeable = False
+    if group == "socket-of-two":
+        groups = _make_group(store, 2, prefix="writes")
+        assert all(g.allreduce_writes(ReduceOp.SUM) for g in groups)
+        for g in groups:
+            g.shutdown()
+        return
+    if group in ("base", "baby"):  # the default; the child copies back
+        pg = ProcessGroup() if group == "base" else ProcessGroupBabySocket()
+        assert pg.allreduce_writes(ReduceOp.SUM) and pg.allreduce_writes()
+        return
+    if group == "socket-alone":
+        pg = ProcessGroupSocket()
+        pg.configure("unused:0/solo-writes", 0, 1)
+        assert pg.allreduce_writes(ReduceOp.AVG)  # it divides in place
+    else:
+        pg = {"dummy": lambda p: p,
+              "swallowing": ErrorSwallowingProcessGroupWrapper,
+              "fake": FakeProcessGroupWrapper}[group](ProcessGroupDummy())
+    assert not pg.allreduce_writes(ReduceOp.SUM)
+    (out,) = pg.allreduce([ro], ReduceOp.SUM).wait(timeout=5)
+    assert out is ro and not out.flags.writeable
+    if group == "socket-alone":
+        pg.shutdown()
+
+
 def test_dummy_pg():
     pg = ProcessGroupDummy()
     arr = np.ones(3)

@@ -309,6 +309,81 @@ def test_host_path_spans_and_hand_worked_bytes(journal):
     assert ar_host_bytes_step.read({"journal": [ev]}) == 6800
 
 
+@pytest.mark.parametrize("case", ["avg-quorum-of-one", "sum-of-two", "avg-of-two"])
+def test_a_scale_of_exactly_one_is_not_a_pass(journal, case):
+    """Times 1.0 changes no bit, so ``_ManagedWork`` does not run it: the
+    span is there with a count of 0. Any other scale is today's pass."""
+    from torchft_tpu.process_group import ReduceOp
+
+    world = 1 if case == "avg-quorum-of-one" else 2
+    op = ReduceOp.SUM if case == "sum-of-two" else ReduceOp.AVG
+    m = make_manager(quorum_result=make_quorum_result(
+        replica_world_size=world, max_world_size=world))
+    values = (np.arange(100, dtype=np.float32) - 48) / 64
+    try:
+        m.start_quorum()
+        given = values.copy()
+        (out,) = m.allreduce(given, reduce_op=op).wait()
+        assert m.should_commit()
+    finally:
+        m.shutdown()
+    scaled = case == "avg-of-two"
+    assert out is given  # reduced in place, as ever
+    assert out.tobytes() == (values * np.float32(0.5) if scaled else values).tobytes()
+    (ev,) = journal("step_spans")
+    (scale,) = _by_name(ev["attrs"]["spans"], "torchft::manager::allreduce_scale")
+    assert scale[ATTRS] == {"nbytes": 400 if scaled else 0}
+
+
+@pytest.mark.parametrize("case", ["nothing-writes", "scaled", "group-writes",
+                                  "quantized", "not-participating"])
+def test_a_read_only_input_with_scratch_is_copied_only_where_something_writes(
+        journal, case):
+    """``Manager.allreduce(..., scratch=)``: the input goes down as it is
+    unless the call writes, and then it is copied into ``scratch``, not
+    into new memory. ``host_copy`` records either."""
+    from torchft_tpu.process_group import ProcessGroupDummy
+
+    class _Writes(ProcessGroupDummy):
+        def allreduce_writes(self, op=None):
+            return True
+
+    from torchft_tpu.manager import WorldSizeMode
+
+    world = 2 if case == "scaled" else 1
+    quorum = make_quorum_result(replica_world_size=world, max_world_size=world)
+    kwargs = {}
+    if case == "not-participating":  # a spare beyond the fixed size: zeros
+        quorum = make_quorum_result(
+            replica_rank=1, max_world_size=2, replica_world_size=2)
+        kwargs = dict(min_replica_size=1,
+                      world_size_mode=WorldSizeMode.FIXED_WITH_SPARES)
+    m = make_manager(pg=_Writes() if case == "group-writes" else None,
+                     quorum_result=quorum, use_async_quorum=False, **kwargs)
+    values = (np.arange(1024, dtype=np.float32) % 97 - 48) / 64
+    given = values.copy()
+    given.flags.writeable = False
+    scratch = np.full(1024, 777.0, np.float32)
+    try:
+        m.start_quorum()
+        (out,) = m.allreduce(
+            given, should_quantize=case == "quantized", scratch=scratch).wait()
+    finally:
+        m.shutdown()
+    spans = telemetry.drain_spans()[0]
+    (copy,) = _by_name(spans, "torchft::manager::host_copy")
+    assert given.tobytes() == values.tobytes()
+    if case == "nothing-writes":
+        assert out is given and np.all(scratch == 777.0)
+        assert copy[ATTRS] == {"nbytes": 4096, "copied_bytes": 0}
+        return
+    assert out is scratch
+    assert copy[ATTRS] == {"nbytes": 4096, "copied_bytes": 4096}
+    want = {"scaled": values * np.float32(0.5),
+            "not-participating": np.zeros_like(values)}.get(case, values)
+    assert out.tobytes() == want.tobytes()
+
+
 def test_a_read_only_bucket_is_a_recorded_copy(journal):
     m = make_manager()
     try:

@@ -5,14 +5,14 @@ Reference: ``torchft/ddp.py:32-105`` routes each gradient bucket through
 data-parallel axis (within a replica group / pod) is a mesh axis whose
 gradient psum is compiled into the step function and rides ICI; this module
 averages the resulting gradients *across replica groups* over DCN, bucketed
-into flat host buffers with async overlap (bucket N+1 transfers while N is
+in flat host buckets with async overlap (bucket N+1 transfers while N is
 in flight — the comm-hook overlap analog).
 """
 
 from __future__ import annotations
 
 import collections
-from typing import Any, Deque, List, Optional, Sequence, Tuple
+from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -37,6 +37,21 @@ def issue_order(arrays: Sequence[Any], buckets: List[List[int]]) -> List[int]:
     return sorted(range(len(buckets)), key=lambda b: (sizes[b], b))
 
 
+@jax.jit
+def _join_leaves(leaves: Sequence[jax.Array]) -> jax.Array:
+    """A bucket's flat payload on the device, as ``pack`` lays it out on
+    the host: the leaves raveled in the order given, in their own dtype.
+    One compiled program a bucket layout (the leaves' shapes and dtype)."""
+    return jax.numpy.concatenate([jax.numpy.ravel(a) for a in leaves])
+
+
+def _one_device(leaves: Sequence[jax.Array]) -> bool:
+    """Whether every leaf lies whole on one and the same device: only then
+    can one program join them without moving a byte between devices."""
+    devices = {d for x in leaves for d in x.devices()}
+    return len(devices) == 1
+
+
 class _PackBuffers:
     """The flat bucket buffers one ``DistributedDataParallel`` keeps
     between calls: a gradient-sized numpy array made every step is
@@ -44,7 +59,10 @@ class _PackBuffers:
     cost ``pack`` more than its copy (the fault ``_WireScratch`` removed
     from the quantized wire stage). A set is valid for one bucket layout,
     ``(dtype, element count)`` per bucket; a call with another layout
-    sizes a new set."""
+    sizes a new set. ``np.empty`` touches no page: a buffer costs memory
+    only once something is written into it, so on the line of buckets,
+    where the landed copy is the bucket, a buffer is paid for only by a
+    step that has to write (more than one rank, a scale, quantization)."""
 
     def __init__(self) -> None:
         self._layout: Optional[Tuple[Tuple[Any, int], ...]] = None
@@ -86,38 +104,62 @@ class DistributedDataParallel:
     ``PureDistributedDataParallel`` return new arrays. No view of a kept
     buffer leaves the wrapper, so its next call may write the buffers
     whether or not the step commits, and the wrapper keeps no reference
-    to a device array past its return. The buckets form a line: every
-    leaf's device-to-host copy starts at once, as soon as the gradients
-    exist (after ``grads_wait``), and per bucket, in issue order, the
-    caller waits for that bucket's copies
-    (``torchft::ddp::pull``), packs and issues it, and before it blocks
-    on the next pull sends back every earlier bucket whose collective is
-    done (``torchft::ddp::push``: ``jax.device_put`` of each leaf's slice
-    of the reduced bucket, asynchronous). Off the TPU a ``device_put`` of
-    a numpy view may alias the host memory rather than copy it, so there
-    the slice is copied first. A bucket whose collective failed is not
-    pushed (its buffer may still be written, below): its input leaves
-    come back in its place, on a step that will not commit.
+    to a device array past its return. The buckets form a line, and a
+    gradient byte is written once on its way down and read once on its
+    way up. A bucket comes down as ONE array: its one leaf, or its
+    leaves joined on the device by one program a layout
+    (``_join_leaves``; leaves that lie on several devices are packed on
+    the host as below). jax caches a host copy on the array it was asked
+    of, so a leaf alone is asked through a second handle on its buffer
+    (``jax.make_array_from_single_device_arrays``, no device copy) that
+    only the call holds: the landing is then freed when its push has
+    read it, not in one gradient-sized piece when the caller drops its
+    tree. Every copy starts at once, as soon as the gradients exist
+    (after ``grads_wait``), and per bucket, in issue order, the caller
+    waits for that bucket's copy (``torchft::ddp::pull``) and hands the
+    landed array to ``Manager.allreduce`` as it is: a read-only array of
+    host memory made for it this step, with the kept buffer as
+    ``scratch``. The manager copies into the kept buffer only where
+    something writes (more than one rank in the process group, a scale
+    other than 1, quantization, a non-participant's zeros:
+    ``torchft::manager::host_copy`` ``copied_bytes``); at a quorum of one
+    nothing does, and the landed copy is what goes back up. Before the
+    caller blocks on the next pull it sends back every earlier bucket
+    whose collective is done (``torchft::ddp::push``: ``jax.device_put``
+    of each leaf's slice of the reduced bucket, asynchronous). Off the
+    TPU a ``device_put`` of a numpy view may alias the host memory rather
+    than copy it, so there the slice is copied first. A bucket whose
+    collective failed is not pushed (its buffer may still be written,
+    below): its input leaves come back in its place, on a step that will
+    not commit.
 
-    *Any leaf a numpy array*: the leaves are views of flat bucket buffers
-    this wrapper owns and writes again in its next call: they are valid
-    until the next ``allreduce_grads`` on the same wrapper, and a caller
-    that keeps one longer copies it (``np.array(leaf)``; off-TPU a
-    ``jnp.asarray(leaf)`` may alias the host memory rather than copy it).
+    *Any leaf a numpy array*: the tree is pulled whole, every bucket is
+    packed into a flat buffer this wrapper owns, and the leaves that come
+    back are views of those buffers, which the next call writes again:
+    they are valid until the next ``allreduce_grads`` on the same
+    wrapper, and a caller that keeps one longer copies it
+    (``np.array(leaf)``; off-TPU a ``jnp.asarray(leaf)`` may alias the
+    host memory rather than copy it).
 
     **A failed step retires the buffers.** If the call raises, or the
     manager holds an error when it ends (a bucket's work failed or timed
     out), the set is dropped and the next call sizes a new one: an aborted
     collective's thread may still hold, and write into, the array it was
-    given (``ProcessGroupSocket.abort`` does not join it). The step after
-    a failure pays for fresh pages once.
+    given (``ProcessGroupSocket.abort`` does not join it). A landed copy
+    it holds instead is read-only and nobody's to write. The step after
+    a failure pays for fresh pages once, if it writes at all.
 
-    ``torchft::ddp::pack`` says which happened, per bucket:
-    ``fresh_bytes`` is host memory the pack had to allocate (a new set:
-    the wrapper's first call, another layout, the call after a failed
-    step; and the compensated copy under error feedback), ``reused_bytes``
-    what it wrote into memory it already had. Steady state is
-    ``fresh_bytes`` 0 and ``reused_bytes`` = ``nbytes``.
+    ``torchft::ddp::pack`` is recorded once a bucket either way and says
+    what the host did: ``nbytes`` is what the pack copied (the bucket,
+    concatenated into its kept buffer; **0** where the landed copy is the
+    bucket; the compensated copy under error feedback on the line),
+    ``fresh_bytes`` is host memory made for the bucket this call (a new
+    set of buffers: the wrapper's first call, another layout, the call
+    after a failed step; the landing itself on the line, every step; the
+    compensated copy under error feedback), ``reused_bytes`` what was
+    written into memory the wrapper already had. Steady state with a
+    numpy leaf is ``fresh_bytes`` 0 and ``reused_bytes`` = ``nbytes``; on
+    the line ``nbytes`` 0, ``fresh_bytes`` the bucket, ``reused_bytes`` 0.
 
     **The order of a step's collectives** is :func:`issue_order` of the
     bucket layout, ascending size, in both branches below, and it is part
@@ -296,14 +338,37 @@ class DistributedDataParallel:
         like: Sequence[Any] = leaves if line else host
         buckets = self._bucketize(like)
         order = issue_order(like, buckets)
+        # A bucket whose leaves lie on one device comes down as ONE array
+        # that only this call holds, {bucket: that array}: several leaves
+        # joined, a leaf alone through a second handle on its buffer (jax
+        # caches the host copy on the array it was asked of: see the class
+        # docstring).
+        staged: Dict[int, jax.Array] = {}
         if line:
-            # Every copy starts now, in the order the buckets will be
-            # wanted, and they run back to back while the host packs,
-            # reduces and pushes the buckets that have arrived. Not before
-            # the gradients exist: copies asked of a program's pending
-            # outputs are not served in the order asked, and the first
-            # bucket then waits for most of the gradient (PERF.md §6, PR 39).
             for b_idx in order:
+                bucket_leaves = [leaves[i] for i in buckets[b_idx]]
+                if not _one_device(bucket_leaves):
+                    continue
+                if len(bucket_leaves) > 1:
+                    staged[b_idx] = _join_leaves(bucket_leaves)
+                else:
+                    (leaf,) = bucket_leaves
+                    staged[b_idx] = jax.make_array_from_single_device_arrays(
+                        leaf.shape, leaf.sharding, [leaf]
+                    )
+            # Copies asked of a program's pending outputs are not served in
+            # the order asked (below): the joins are microseconds of device.
+            jax.block_until_ready(list(staged.values()))
+            # Every copy starts now, in the order the buckets will be
+            # wanted, and they run back to back while the host reduces and
+            # pushes the buckets that have arrived. Not before the
+            # gradients exist: copies asked of a program's pending outputs
+            # are not served in the order asked, and the first bucket then
+            # waits for most of the gradient (PERF.md §6, PR 39).
+            for b_idx in order:
+                if b_idx in staged:
+                    staged[b_idx].copy_to_host_async()
+                    continue
                 for i in buckets[b_idx]:
                     leaves[i].copy_to_host_async()
         # device_put of a numpy view copies on the TPU and may alias off it.
@@ -355,6 +420,9 @@ class DistributedDataParallel:
 
         for b_idx in order:
             idx_list = buckets[b_idx]
+            # The bucket as the one flat, read-only array that landed,
+            # where it came down as one piece.
+            landed: Optional[np.ndarray] = None
             if line:
                 # A finished bucket goes back while this one's copy arrives.
                 while issued and issued[0][1].done():
@@ -364,24 +432,39 @@ class DistributedDataParallel:
                     bucket=b_idx,
                     nbytes=sum(leaves[i].nbytes for i in idx_list),
                 ):
-                    for i in idx_list:
-                        host[i] = np.asarray(leaves[i])
+                    # pop: a join's device temporary goes as it lands
+                    source = staged.pop(b_idx, None)
+                    if source is None and len(idx_list) == 1:
+                        source = leaves[idx_list[0]]  # over several devices
+                    if source is not None:
+                        landed = np.asarray(source).reshape(-1)
+                    else:  # leaves on several devices: packed on the host
+                        for i in idx_list:
+                            host[i] = np.asarray(leaves[i])
             on_quantized = None
             with trace_span("torchft::ddp::pack", bucket=b_idx) as pack:
-                flat = flats[b_idx]
-                np.concatenate(
-                    [host[i].reshape(-1) for i in idx_list], out=flat
-                )
-                reused = flat.nbytes if kept else 0
-                fresh = flat.nbytes - reused
+                flat, scratch = flats[b_idx], None
+                if landed is None:
+                    np.concatenate(
+                        [host[i].reshape(-1) for i in idx_list], out=flat
+                    )
+                    copied = flat.nbytes
+                    reused = flat.nbytes if kept else 0
+                    fresh = flat.nbytes - reused
+                else:
+                    # The landing is the bucket, memory made this step; the
+                    # kept buffer is the manager's to write if it must.
+                    flat, scratch = landed, flat
+                    copied, reused, fresh = 0, 0, landed.nbytes
                 if should_quantize and self._error_feedback:
                     compensated = self._residuals.compensate(b_idx, flat)
                     if compensated is not flat:  # flat + residual: new memory
                         fresh += compensated.nbytes
+                        copied = compensated.nbytes  # the bucket's, again
                         flat = compensated
                     on_quantized = self._residuals.make_hook(b_idx)
                 pack.attrs.update(
-                    nbytes=flat.nbytes, fresh_bytes=fresh, reused_bytes=reused
+                    nbytes=copied, fresh_bytes=fresh, reused_bytes=reused
                 )
             name_next_bucket(b_idx)
             work = self._manager.allreduce(
@@ -389,8 +472,12 @@ class DistributedDataParallel:
                 should_quantize=should_quantize,
                 quantize_bits=quantize_bits,
                 on_local_quantized=on_quantized,
+                # only the line has a kept buffer to lend
+                **({} if scratch is None else {"scratch": scratch}),
             )
             issued.append((b_idx, work, idx_list))
+        # The last landing is the push's to free once it has read it.
+        landed = None
         while issued:
             land()
         if line:

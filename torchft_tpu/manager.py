@@ -891,11 +891,26 @@ class Manager:
         quantize_bits: int = 8,
         on_local_quantized: Any = None,
         reduce_op: ReduceOp = ReduceOp.AVG,
+        scratch: Any = None,
     ) -> Work:
         """Fault-tolerant allreduce across the replica axis (reference:
         manager.py:379-450). Accepts a numpy array, jax array, or list
         thereof. Returns completed-or-failed Work; errors are latched,
         never raised here.
+
+        A numpy input is reduced in place and comes back as the result. A
+        read-only one (a jax array's host view) cannot be: without
+        ``scratch`` it is copied into new memory first, whatever the call
+        goes on to do. With ``scratch`` (a writable array of the same
+        shape and dtype, or a list of them, one an input) it is copied
+        there only if this call writes: a non-participant's zeros, a
+        process group that reduces into its inputs
+        (``ProcessGroup.allreduce_writes``), a scale other than 1,
+        quantization. Where nothing writes (a quorum of one under AVG, or
+        SUM at a world of one) the input goes down and comes back as it
+        is, read-only still, and ``scratch`` is not touched.
+        ``torchft::manager::host_copy`` records either: ``copied_bytes``
+        0 or the bytes copied.
 
         .. warning:: ``reduce_op`` semantics DIVERGE from the reference
            deliberately. The reference's default ``ReduceOp.SUM`` divides
@@ -986,14 +1001,17 @@ class Manager:
                 a = np.array(a)
             return a
 
-        with trace_span("torchft::manager::host_copy") as copy:
-            arrays: List[np.ndarray] = [to_mutable(t) for t in items]
-            copy.attrs["nbytes"] = sum(a.nbytes for a in arrays)
-            # An array that came back as itself was already writable:
-            # "no copy" is a recorded 0, not an absent number.
-            copy.attrs["copied_bytes"] = sum(
-                a.nbytes for a, t in zip(arrays, items) if a is not t
-            )
+        if scratch is None:
+            with trace_span("torchft::manager::host_copy") as copy:
+                arrays: List[np.ndarray] = [to_mutable(t) for t in items]
+                copy.attrs["nbytes"] = sum(a.nbytes for a in arrays)
+                # An array that came back as itself was already writable:
+                # "no copy" is a recorded 0, not an absent number.
+                copy.attrs["copied_bytes"] = sum(
+                    a.nbytes for a, t in zip(arrays, items) if a is not t
+                )
+        else:  # copied below, once it is known whether anything writes
+            arrays = [np.asarray(t) for t in items]
         # Every return path keeps the contract: wait() -> list of arrays.
         # (Quorum first, error check second — see the device path above.)
         try:
@@ -1004,6 +1022,27 @@ class Manager:
             return DummyWork(arrays)
         if self.errored() is not None:
             return DummyWork(arrays)
+        num_participants = max(self.num_participants(), 1)
+        scale = 1.0 / num_participants if reduce_op == ReduceOp.AVG else 1.0
+        if scratch is not None:
+            pg_writes = getattr(self._pg, "allreduce_writes", None)
+            writes = (
+                self._participating_rank is None
+                or should_quantize
+                or scale != 1.0
+                or pg_writes is None
+                or bool(pg_writes(ReduceOp.SUM))
+            )
+            targets = scratch if isinstance(scratch, (list, tuple)) else [scratch]
+            with trace_span("torchft::manager::host_copy") as copy:
+                copied = 0
+                for k, a in enumerate(arrays):
+                    if writes and not a.flags.writeable:
+                        np.copyto(targets[k], a)
+                        arrays[k] = targets[k]
+                        copied += a.nbytes
+                copy.attrs["nbytes"] = sum(a.nbytes for a in arrays)
+                copy.attrs["copied_bytes"] = copied
         # Non-participants (healing/spares) contribute zeros
         # (reference: manager.py:410-411); the collective quantizes the
         # zeroed arrays, so an error-feedback callback observes the zeros
@@ -1013,7 +1052,6 @@ class Manager:
             for a in arrays:
                 a.fill(0)
 
-        num_participants = max(self.num_participants(), 1)
         try:
             if should_quantize:
                 from torchft_tpu.collectives import allreduce_quantized
@@ -1037,16 +1075,7 @@ class Manager:
             quantized=bool(should_quantize),
             quant_path="host" if should_quantize else None,
         )
-        return _ManagedWork(
-            self,
-            work,
-            arrays,
-            scale=(
-                1.0 / num_participants
-                if reduce_op == ReduceOp.AVG
-                else 1.0
-            ),
-        )
+        return _ManagedWork(self, work, arrays, scale=scale)
 
     def note_exposed_comm(self, seconds: float) -> None:
         """Adds caller-thread seconds spent in replica-axis communication
@@ -1815,12 +1844,19 @@ class _ManagedWork(Work):
                         ):
                             result = self._work.wait(t)
                     if self._in_place:
+                        # Times exactly 1 changes no bit (AVG over a quorum
+                        # of one, SUM): not a pass over the gradient, and
+                        # the span says so with a count of 0.
+                        scaled = self._scale != 1.0
                         with trace_span(
                             "torchft::manager::allreduce_scale",
-                            nbytes=sum(a.nbytes for a in self._arrays),
+                            nbytes=sum(a.nbytes for a in self._arrays)
+                            if scaled
+                            else 0,
                         ):
-                            for a in self._arrays:
-                                a *= self._scale
+                            if scaled:
+                                for a in self._arrays:
+                                    a *= self._scale
                     else:
                         self._arrays = list(result)
                 except Exception as e:  # noqa: BLE001

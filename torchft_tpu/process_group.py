@@ -178,6 +178,13 @@ class ProcessGroup:
     def allreduce(self, tensors: Any, op: ReduceOp = ReduceOp.SUM) -> Work:
         raise NotImplementedError
 
+    def allreduce_writes(self, op: ReduceOp = ReduceOp.SUM) -> bool:
+        """Whether ``allreduce(tensors, op)``, as the group is configured
+        now, may write into ``tensors``. A group reduces in place, so
+        yes, unless it says otherwise: a caller that holds a read-only
+        array copies it first only where this is true."""
+        return True
+
     def allgather(self, tensors: Any) -> Work:
         """Result: list over ranks, each a list of arrays."""
         raise NotImplementedError
@@ -774,6 +781,11 @@ class ProcessGroupSocket(ProcessGroup):
             nbytes=sum(a.nbytes for a in arrays),
             tag=tag,
         )
+
+    def allreduce_writes(self, op: ReduceOp = ReduceOp.SUM) -> bool:
+        # ``_allreduce`` below (the native engine's too): alone in its
+        # world a rank has nothing to add, and only AVG divides.
+        return self._world > 1 or op == ReduceOp.AVG
 
     def _allreduce(
         self, arrays: List[np.ndarray], op: ReduceOp, tag: str
@@ -1497,6 +1509,9 @@ class ProcessGroupDummy(ProcessGroup):
 
     def allreduce(self, tensors: Any, op: ReduceOp = ReduceOp.SUM) -> Work:
         return DummyWork(_as_list(tensors))
+
+    def allreduce_writes(self, op: ReduceOp = ReduceOp.SUM) -> bool:
+        return False  # the inputs pass through
 
     def allgather(self, tensors: Any) -> Work:
         return DummyWork([_as_list(tensors)])
