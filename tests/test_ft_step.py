@@ -30,6 +30,7 @@ from torchft_tpu.models import (  # noqa: E402
     llama_debug,
     llama_moe_debug,
     nemotron_h_debug,
+    olmo_hybrid_debug,
     olmoe_1b_7b,
     sdar_moe_debug,
 )
@@ -51,6 +52,7 @@ SMALL = {
     "lfm2_moe_debug": lfm2_moe_debug,
     "sdar_moe_debug": sdar_moe_debug,
     "joyai_flash_debug": joyai_flash_debug,
+    "olmo_hybrid_debug": olmo_hybrid_debug,
     # the published preset cut to test widths, as tests/test_olmoe.py's TINY
     "olmoe_1b_7b": functools.partial(
         olmoe_1b_7b, hidden_size=64, intermediate_size=32, num_layers=2, num_heads=4,

@@ -12,6 +12,7 @@ from torchft_tpu.models.resnet import (  # noqa: F401
     resnet50,
     resnet101,
 )
+from torchft_tpu.models.gated_delta import GatedDeltaConfig  # noqa: F401
 from torchft_tpu.models.mamba2 import Mamba2Config  # noqa: F401
 from torchft_tpu.models.mla import MLAConfig  # noqa: F401
 from torchft_tpu.models.llama import (  # noqa: F401
@@ -27,6 +28,8 @@ from torchft_tpu.models.llama import (  # noqa: F401
     llama_small,
     nemotron3_nano,
     nemotron_h_debug,
+    olmo_hybrid_7b,
+    olmo_hybrid_debug,
     olmoe_1b_7b,
     sdar_30b_a3b,
     sdar_moe_debug,
@@ -43,4 +46,5 @@ PRESETS = {
     "lfm2_moe": lfm2_moe_debug,
     "sdar_moe": sdar_moe_debug,
     "joyai_flash": joyai_flash_debug,
+    "olmo_hybrid": olmo_hybrid_debug,
 }

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from torchft_tpu.models.gated_delta import GatedDeltaConfig, GatedDeltaMixer
 from torchft_tpu.models.mamba2 import Mamba2Config, Mamba2Mixer, conv_kernel_init
 from torchft_tpu.models.mla import LatentAttention, MLAConfig
 
@@ -122,12 +123,20 @@ class LlamaConfig:
     # (``mamba``), 'E' the expert layer, '*' attention (rotary where
     # ``rope``; latent attention where ``mla`` is set, models/mla.py),
     # 'C' a gated short convolution of ``SHORT_CONV_TAPS`` taps,
-    # 'D' a dense SwiGLU feed-forward. A model whose published layer is an
-    # operator and a feed-forward is two characters a layer ("CD", "*E").
+    # 'G' a gated-delta linear attention (``gated_delta``,
+    # models/gated_delta.py), 'D' a dense SwiGLU feed-forward. A model whose
+    # published layer is an operator and a feed-forward is two characters
+    # a layer ("CD", "*E", "GD").
     # None: ``num_layers`` scanned blocks of attention + MLP.
     layer_pattern: Optional[str] = None
     mamba: Optional[Mamba2Config] = None
     mla: Optional[MLAConfig] = None
+    gated_delta: Optional[GatedDeltaConfig] = None
+    # Where a ``layer_pattern`` layer's RMSNorm stands. False: before the
+    # mixer, x + mixer(norm(x)). True: after it, x + norm(mixer(x)), the
+    # residual stream itself feeding the mixer (OLMo 2's layer,
+    # arXiv:2501.00656 section 3).
+    norm_after_mixer: bool = False
     # The dense feed-forward's width where it is not the experts'
     # (``intermediate_size`` is then an expert's). None: one width for both.
     dense_intermediate_size: Optional[int] = None
@@ -513,6 +522,60 @@ def joyai_flash_debug(**overrides: Any) -> LlamaConfig:
         num_experts_per_tok=3,
         experts_held=(0, 4),
         shared_expert_size=48,
+        remat=False,
+    )
+    return dataclasses.replace(cfg, **overrides)
+
+
+def olmo_hybrid_7b(**overrides: Any) -> LlamaConfig:
+    """Olmo-Hybrid-7B (allenai/Olmo-Hybrid-7B config.json, model_type
+    ``olmo_hybrid``; Gated DeltaNet arXiv:2412.06464) at its published
+    sizes: 32 layers of a mixer and an 11008-wide SwiGLU feed-forward each,
+    the mixer a gated-delta linear attention (30 heads, keys of 96 and
+    values of 192, a 4-tap convolution, beta up to 2) three layers in four
+    and a rope-free full attention (30 heads on 30 of width 128, one
+    RMSNorm over the whole query projection and one over the key's) the
+    fourth; every sub-layer's norm AFTER its mixer (OLMo 2's layer,
+    arXiv:2501.00656); an untied 100,352-row head. 7.43B parameters:
+    override ``layer_pattern``, the heads and ``vocab_size`` for what one
+    chip holds. The norm's place, the QK-norm's form and the absent
+    rotary embedding are not keys of the published file
+    (benchmark/configs/olmo-hybrid-7b-l4h15.json, ``assumed``)."""
+    cfg = LlamaConfig(
+        vocab_size=100352,
+        hidden_size=3840,
+        intermediate_size=11008,
+        num_layers=32,
+        # Two characters a published layer: its mixer, its feed-forward.
+        layer_pattern="GDGDGD*D" * 8,
+        num_heads=30,
+        num_kv_heads=30,
+        head_dim=128,
+        max_seq_len=65536,
+        norm_eps=1e-6,
+        qk_norm=True,
+        rope=False,
+        gated_delta=GatedDeltaConfig(),
+        norm_after_mixer=True,
+    )
+    return dataclasses.replace(cfg, **overrides)
+
+
+def olmo_hybrid_debug(**overrides: Any) -> LlamaConfig:
+    """Tiny Olmo-Hybrid (one period: three gated-delta layers and an
+    attention, a feed-forward after each) for tests and
+    ``train_hsdp.py --model olmo_hybrid``."""
+    cfg = olmo_hybrid_7b(
+        vocab_size=256,
+        hidden_size=64,
+        intermediate_size=160,
+        num_layers=4,
+        layer_pattern="GDGDGD*D",
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=16,
+        max_seq_len=128,
+        gated_delta=GatedDeltaConfig(num_heads=4, key_head_dim=8, value_head_dim=16),
         remat=False,
     )
     return dataclasses.replace(cfg, **overrides)
@@ -1252,8 +1315,9 @@ class ShortConvMixer(nn.Module):
 
 
 class MixerLayer(nn.Module):
-    """One layer of a ``layer_pattern`` stack: x + mixer(RMSNorm(x)), the
-    mixer named for its kind so that the sharding rules find it."""
+    """One layer of a ``layer_pattern`` stack: x + mixer(RMSNorm(x)) or,
+    under ``norm_after_mixer``, x + RMSNorm(mixer(x)); the mixer named for
+    its kind so that the sharding rules find it."""
 
     cfg: LlamaConfig
     kind: str
@@ -1264,23 +1328,29 @@ class MixerLayer(nn.Module):
         cos: Optional[jax.Array] = None, sin: Optional[jax.Array] = None,
     ) -> jax.Array:
         cfg = self.cfg
-        h = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="norm")(x)
+        norm = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="norm")
+        h, after = (x, norm) if cfg.norm_after_mixer else (norm(x), lambda y: y)
         if self.kind == "M":
-            return x + Mamba2Mixer(
+            return x + after(Mamba2Mixer(
                 cfg.mamba, cfg.hidden_size, cfg.norm_eps, cfg.residual_init_scale,
                 cfg.dtype, cfg.param_dtype, name="mamba",
-            )(h)
+            )(h))
+        if self.kind == "G":
+            return x + after(GatedDeltaMixer(
+                cfg.gated_delta, cfg.hidden_size, cfg.norm_eps,
+                cfg.dtype, cfg.param_dtype, name="gdn",
+            )(h))
         if self.kind == "E":
-            return x + MoEMLP(cfg, name="mlp")(h)
+            return x + after(MoEMLP(cfg, name="mlp")(h))
         if self.kind == "D":
-            return x + MLP(cfg, name="mlp")(h)
+            return x + after(MLP(cfg, name="mlp")(h))
         if self.kind == "C":
-            return x + ShortConvMixer(cfg, name="conv")(h)
+            return x + after(ShortConvMixer(cfg, name="conv")(h))
         if self.kind == "*":
             mixer = Attention if cfg.mla is None else LatentAttention
-            return x + mixer(cfg, name="attn")(h, cos, sin)
+            return x + after(mixer(cfg, name="attn")(h, cos, sin))
         raise ValueError(
-            f"layer kind {self.kind!r} is none of 'M', 'E', 'D', 'C', '*'"
+            f"layer kind {self.kind!r} is none of 'M', 'G', 'E', 'D', 'C', '*'"
         )
 
 

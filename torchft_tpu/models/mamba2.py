@@ -30,7 +30,7 @@ from __future__ import annotations
 import dataclasses
 import logging
 import math
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -82,14 +82,16 @@ class Mamba2Config:
         return self.d_inner + 2 * self.n_groups * self.state_size
 
 
-def causal_conv1d(x: jax.Array, kernel: jax.Array, bias: jax.Array) -> jax.Array:
+def causal_conv1d(
+    x: jax.Array, kernel: jax.Array, bias: Optional[jax.Array] = None
+) -> jax.Array:
     """Depthwise causal convolution. x: [B, S, C]; kernel: [K, C], its last
     row multiplying the current position; float32 out."""
     k, s = kernel.shape[0], x.shape[1]
     f32 = jnp.float32
     padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))  # in x's type: half the bytes
     taps = [padded[:, i : i + s].astype(f32) * kernel[i].astype(f32) for i in range(k)]
-    return sum(taps) + bias.astype(f32)
+    return sum(taps) if bias is None else sum(taps) + bias.astype(f32)
 
 
 def ssd_chunked(

@@ -57,6 +57,18 @@ _RULES: Dict[Tuple[str, str], Tuple[Any, ...]] = {
     ("shared_down", "kernel"): ("tp", "fsdp"),
     ("in_proj", "kernel"): ("fsdp", "tp"),
     ("out_proj", "kernel"): ("tp", "fsdp"),
+    # The gated-delta mixer (models/gated_delta.py): its five projections
+    # out of the residual stream shard their heads over ``tp`` (a_proj's
+    # and b_proj's [H, heads] one value a head), o_proj like an MLP's down;
+    # the convolution over [q | k | v], A_log, dt_bias and the per-head
+    # norm's vector replicate.
+    ("q_proj", "kernel"): ("fsdp", "tp"),
+    ("k_proj", "kernel"): ("fsdp", "tp"),
+    ("v_proj", "kernel"): ("fsdp", "tp"),
+    ("g_proj", "kernel"): ("fsdp", "tp"),
+    ("a_proj", "kernel"): ("fsdp", "tp"),
+    ("b_proj", "kernel"): ("fsdp", "tp"),
+    ("o_proj", "kernel"): ("tp", "fsdp"),
     # The gated short-convolution mixer (``conv``) has the same two names:
     # its in_proj's [H, 3H] output splits into thirds that GSPMD re-shards
     # where ``tp`` cuts across them; its depthwise ``conv_kernel`` [taps, H]

@@ -172,10 +172,11 @@ def _lm_head_projection(model: Transformer, params):
 
 
 def _apply_with_aux(model: Transformer, params, inputs, **kw):
-    """model.apply + what MoEMLP sows per layer, by name and stacked over
-    the layers ({} for dense models): ``router_aux``, ``router_z``,
-    ``moe_max_load``, ``moe_dropped``."""
-    if model.cfg.num_experts <= 0:
+    """model.apply + what MoEMLP and the gated-delta mixer sow per layer,
+    by name and stacked over the layers ({} for a model with neither):
+    ``router_aux``, ``router_z``, ``moe_max_load``, ``moe_dropped``; the
+    ``gdn_*`` counters."""
+    if model.cfg.num_experts <= 0 and model.cfg.gated_delta is None:
         return model.apply({"params": params}, inputs, **kw), {}
     out, inter = model.apply(
         {"params": params}, inputs, mutable=["intermediates"], **kw
@@ -200,6 +201,13 @@ _SOWN_OVER_LAYERS = (
     # entries it computes, the share its mask keeps
     # (``block_diffusion_attention``).
     ("bd_kept_share", jnp.mean),
+    # From the gated-delta mixers (models/gated_delta.py): the largest
+    # |entry| of a state at a sequence's end (with eigenvalues down to -1
+    # a state that grows is the failure to see), the smallest decay and
+    # the mean beta of the step.
+    ("gdn_state_abs_max", jnp.max),
+    ("gdn_decay_min", jnp.min),
+    ("gdn_beta_mean", jnp.mean),
     # Not a scalar: every expert's assignments, layer after layer in the
     # parameter tree's order, for the selection-bias update of
     # ``make_train_step`` or of a loop around ``make_grad_step`` (sown
@@ -420,7 +428,8 @@ def _loss_and_metrics(model: Transformer, params, inputs, targets, mask):
     model whose step updates its selection biases the vector
     ``moe_load``, under block diffusion ``diffusion_masked_share``
     (masked data positions over data positions) and, sown by its
-    attention, ``bd_kept_share``."""
+    attention, ``bd_kept_share``; from gated-delta mixers
+    ``gdn_state_abs_max``, ``gdn_decay_min`` and ``gdn_beta_mean``."""
     cfg = model.cfg
     B, S = inputs.shape
     C = min(_LOSS_CHUNK, S) if _LOSS_CHUNK > 0 else loss_chunk(
@@ -448,9 +457,11 @@ def _loss_and_metrics(model: Transformer, params, inputs, targets, mask):
             name: over_layers(sown[name])
             for name, over_layers in _SOWN_OVER_LAYERS if name in sown
         }
-        loss = ce + cfg.router_aux_coef * metrics["router_aux"]
-        if cfg.router_z_coef:
-            loss = loss + cfg.router_z_coef * metrics["router_z"]
+        loss = ce
+        if "router_aux" in metrics:  # else: sown counters, no expert layer
+            loss = ce + cfg.router_aux_coef * metrics["router_aux"]
+            if cfg.router_z_coef:
+                loss = loss + cfg.router_z_coef * metrics["router_z"]
         return loss, jax.lax.stop_gradient({**metrics, **extra})
 
     def data_rows(x):
