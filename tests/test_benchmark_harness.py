@@ -23,7 +23,8 @@ MODULES = (
     "test_ar_pack_fresh_bytes", "test_arch", "test_bucket_line_metrics", "test_flops",
     "test_liveness_metrics",
     "test_reference", "test_run_end_to_end", "test_span_metrics",
-    "test_trace_reduce", "test_wire_fresh_bytes", "test_wire_schedule_metrics",
+    "test_trace_reduce", "test_wait_metrics", "test_wire_fresh_bytes",
+    "test_wire_schedule_metrics",
 )
 SUBPROCESS_RUNS = {
     "test_tiny_raw_cell_end_to_end_metrics",
@@ -87,22 +88,26 @@ def test_every_span_metric_is_an_entry_of_the_table_for_its_path_only():
     new = "ar_pack_fresh_bytes_step"
     schedule = {"wire_start_ms", "wire_starved_ms"}  # the wire's, so ft4's alone
     line = {"ar_push_ms", "ar_early_bucket_share"}  # the fp32 line's, so ft1's alone
+    # what the wire's sockets waited for: pg_collective's account, ft4's alone
+    waits = {"wire_send_ms", "wire_peer_wait_ms", "wire_recv_ms", "wire_sock_cpu_ms",
+             "wire_xfer_bytes_step"}
     ten = {m.__name__.rsplit(".", 1)[1] for m in test_span_metrics.ALL}
     entries = {m["name"]: m for m in cells.load_json(REPO_TABLE)["per_layer"]}
-    for name in ten | {new} | schedule | line:
+    for name in ten | {new} | schedule | line | waits:
         assert entries[name]["layer"] == "replica-axis allreduce"
         assert entries[name]["moves"] == "tok_s_chip"
     ft1 = {m["name"] for m in cells.load_cell("mistral-ft1").per_layer}
     ft4 = {m["name"] for m in cells.load_cell("mistral-ft4").per_layer}
     assert {n for n in ft1 | ft4 if n.startswith(("ar_", "wire_"))} - {
-        "wire_ms", "wire_bytes_step", "wire_fresh_bytes_step", new} - schedule - line == ten
+        "wire_ms", "wire_bytes_step", "wire_fresh_bytes_step", new
+    } - schedule - line - waits == ten
     assert "ar_pull_ms" not in ft4 and "wire_busy_ms" not in ft1
     assert new in ft1 - ft4
-    assert schedule <= ft4 - ft1
+    assert schedule | waits <= ft4 - ft1
     assert line <= ft1 - ft4
     for cell in ("mistral-raw", "internlm2-raw", "olmoe-raw"):
         raw = {m["name"] for m in cells.load_cell(cell).per_layer}
-        assert not (ten | {new} | schedule | line) & raw
+        assert not (ten | {new} | schedule | line | waits) & raw
 
 
 def test_the_five_liveness_metrics_are_entries_for_the_ft_cells_only():

@@ -1173,6 +1173,11 @@ class Manager:
         log = get_event_log()
         if log is not None:
             elapsed_s = time.monotonic() - t_gate0
+            # One call a gate. Everything but the peak is cumulative since
+            # the process started: a reader takes the difference between
+            # two consecutive gates over the difference of their ``ts``.
+            # ``ru_majflt`` and ``ru_nvcsw`` have no reader and stay out.
+            ru = resource.getrusage(resource.RUSAGE_SELF)
             log.emit(
                 "commit_gate",
                 step=gated_step,
@@ -1185,9 +1190,11 @@ class Manager:
                 participants=sorted(self._evidence_peers),
                 # Peak resident set of this process so far (Linux counts
                 # ru_maxrss in KiB).
-                rss_peak_bytes=resource.getrusage(
-                    resource.RUSAGE_SELF
-                ).ru_maxrss * 1024,
+                rss_peak_bytes=ru.ru_maxrss * 1024,
+                cpu_user_s=ru.ru_utime,
+                cpu_sys_s=ru.ru_stime,
+                minflt=ru.ru_minflt,
+                nivcsw=ru.ru_nivcsw,
                 **self._gate_cause,
                 **self._read_liveness(gated_step),
             )

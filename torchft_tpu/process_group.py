@@ -41,7 +41,6 @@ from torchft_tpu.telemetry import (
     add_bytes,
     flight_recorder,
     get_event_log,
-    observe_span,
 )
 from torchft_tpu.work import DummyWork, ErrorWork, FutureWork, Work
 
@@ -271,6 +270,50 @@ class _CollectiveAborted(RuntimeError):
     behind one rank's data-plane stall."""
 
 
+@dataclasses.dataclass
+class _WireAccount:
+    """What one collective's socket time and bytes were: ``_PeerConn.send``
+    and ``_PeerConn.recv`` add into the account of the collective running
+    on their thread, and ``pg_collective`` carries the totals. Wall
+    seconds are on the journal's clock (``time.time()``, which the reader
+    threads' stamps share), CPU seconds are ``time.thread_time()``.
+    ``send_s + peer_wait_s + recv_s`` tile the collective's socket time:
+    a receive's wait is ``peer_wait_s`` until the first of its message
+    was here and ``recv_s`` from then on, queue hand-off included.
+    ``peer_wait_s`` is an upper bound on the ranks' skew, not the skew:
+    a peer sends to its peers one after another, so the header for this
+    rank is written only after the peer's ``sendall`` to the ranks before
+    it, and a reader thread that was not running delays the stamp too.
+    ``send_s`` likewise holds the time the peer's reader took to drain."""
+
+    tx_bytes: int = 0
+    rx_bytes: int = 0
+    send_s: float = 0.0
+    send_cpu_s: float = 0.0
+    peer_wait_s: float = 0.0
+    recv_s: float = 0.0
+    recv_cpu_s: float = 0.0
+    messages: int = 0
+
+    def fields(self) -> Dict[str, Any]:
+        """The seven fields ``pg_collective`` carries; none where no
+        message moved over a ``_PeerConn`` (a collective that blocked in
+        the C++ engine, or had no peer), so that a reader gives None and
+        never 0."""
+        if not self.messages:
+            return {}
+        out = dataclasses.asdict(self)
+        del out["messages"]
+        return out
+
+
+# The account of the collective that runs on this thread (``_submit``'s
+# ``guarded`` opens it on pg-exec where a journal is configured). Without
+# one (no journal, or a ``_PeerConn`` driven by hand) a send or receive
+# reads no clock.
+_wire = threading.local()
+
+
 class _PeerConn:
     """One TCP connection to a peer rank with a tag-routing reader thread."""
 
@@ -278,7 +321,6 @@ class _PeerConn:
         self,
         sock: socket.socket,
         peer: int,
-        policy: Optional[LinkPolicy] = None,
     ) -> None:
         # The connect/accept path may leave a short socket timeout armed; the
         # reader must block indefinitely on an IDLE connection (gaps between
@@ -287,7 +329,6 @@ class _PeerConn:
         sock.settimeout(None)
         self.sock = sock
         self.peer = peer
-        self.policy = policy if policy is not None else LinkPolicy()
         self.send_lock = threading.Lock()
         self._queues: Dict[str, queue_mod.Queue] = {}
         self._queues_lock = threading.Lock()
@@ -315,7 +356,14 @@ class _PeerConn:
         try:
             while True:
                 header = _net.recv_json(self.sock)
+                # The first of the message is here; ``recv`` splits a
+                # collective's wait at this stamp (see ``_WireAccount``).
+                # With no journal nothing reads the stamps: none is taken.
+                stamped = get_event_log() is not None
+                t_hdr = time.time() if stamped else 0.0
+                cpu0 = time.thread_time() if stamped else 0.0
                 payload = _net.recv_frame(self.sock)
+                cpu_s = time.thread_time() - cpu0 if stamped else 0.0
                 add_bytes("pg_wire_rx", len(payload))
                 # Put under the lock so recv()'s delete-when-empty can never
                 # strand a message in an unlinked queue.
@@ -353,7 +401,7 @@ class _PeerConn:
                     q = self._queues.get(tag)
                     if q is None:
                         q = self._queues[tag] = queue_mod.Queue()
-                    q.put((header, payload))
+                    q.put((header, payload, t_hdr, cpu_s))
         except Exception as e:  # noqa: BLE001 - propagate to all waiters
             self.dead = e if isinstance(e, Exception) else RuntimeError(str(e))
             with self._queues_lock:
@@ -363,6 +411,9 @@ class _PeerConn:
     def send(self, tag: str, arr: np.ndarray) -> None:
         if self.dead is not None:
             raise RuntimeError(f"connection to rank {self.peer} dead: {self.dead}")
+        acct = getattr(_wire, "account", None)
+        if acct is not None:
+            t0, cpu0 = time.time(), time.thread_time()
         header = {"tag": tag, "dtype": str(arr.dtype), "shape": list(arr.shape)}
         # Zero-copy: sendall consumes the array's buffer directly.
         arr_c = np.ascontiguousarray(arr)
@@ -383,6 +434,11 @@ class _PeerConn:
         # tens of bytes) — what makes the quantized codecs' byte cut
         # measurable on any backend (telemetry.byte_stats).
         add_bytes("pg_wire_tx", data.nbytes)
+        if acct is not None:
+            acct.messages += 1
+            acct.tx_bytes += data.nbytes
+            acct.send_s += time.time() - t0
+            acct.send_cpu_s += time.thread_time() - cpu0
 
     def send_abort(self, tag: str, msg: str) -> None:
         """Best-effort: tell the peer we abandoned collective ``tag`` so its
@@ -399,6 +455,9 @@ class _PeerConn:
             pass  # dead/closing conn: its reader death already fails waits
 
     def recv(self, tag: str, timeout: float) -> np.ndarray:
+        acct = getattr(_wire, "account", None)
+        if acct is not None:
+            t_call = time.time()
         if _chaos._STATE is not None or not _chaos._INITED:
             st = _chaos.active()
             if st is not None:
@@ -456,13 +515,21 @@ class _PeerConn:
             if isinstance(item, _CollectiveAborted):
                 raise item
             raise RuntimeError(f"connection to rank {self.peer} died") from item
-        header, payload = item
+        header, payload, t_hdr, cpu_s = item
         # Tags are single-use per message: drop the drained queue so a long
         # stable-quorum run doesn't accumulate one dead Queue per collective.
         with self._queues_lock:
             q = self._queues.get(tag)
             if q is not None and q.empty():
                 del self._queues[tag]
+        if acct is not None:
+            waited = time.time() - t_call
+            late = min(max(0.0, t_hdr - t_call), waited)
+            acct.messages += 1
+            acct.rx_bytes += len(payload)
+            acct.peer_wait_s += late
+            acct.recv_s += waited - late
+            acct.recv_cpu_s += cpu_s
         # payload is a bytearray (writable buffer): frombuffer is already
         # a mutable array over it, no copy needed.
         return np.frombuffer(payload, dtype=np.dtype(header["dtype"])).reshape(
@@ -584,17 +651,13 @@ class ProcessGroupSocket(ProcessGroup):
                             attempt_timeout=pol.connect_ms / 1000.0,
                         )
                     _net.send_json(sock, {"rank": rank})
-                    peers[peer] = _PeerConn(sock, peer, policy=pol)
+                    peers[peer] = _PeerConn(sock, peer)
                 listener.settimeout(self._timeout)
                 for _ in range(world_size - rank - 1):
                     sock, _ = listener.accept()
                     _net.set_keepalive(sock)
                     hello = _net.recv_json(sock, timeout=self._timeout)
-                    peers[hello["rank"]] = _PeerConn(
-                        sock,
-                        hello["rank"],
-                        policy=self.link_policy(hello["rank"]),
-                    )
+                    peers[hello["rank"]] = _PeerConn(sock, hello["rank"])
             except (OSError, TimeoutError) as e:
                 for c in peers.values():
                     c.close()
@@ -702,12 +765,19 @@ class ProcessGroupSocket(ProcessGroup):
         def guarded() -> Any:
             t0 = time.monotonic()
             queued_s = t0 - t_submit  # behind earlier ops on pg-exec
+            # Every ``_PeerConn.send``/``recv`` that ``fn`` makes on this
+            # thread adds into this collective's account; with no journal
+            # to carry it there is none, and they read no clock.
+            wire = _wire.account = (
+                _WireAccount() if get_event_log() is not None else None
+            )
             try:
                 result = fn()
             except Exception as e:
                 flight_recorder.complete(seq, error=str(e))
                 self._journal_collective(
-                    op, nbytes, tag, time.monotonic() - t0, queued_s, ok=False
+                    op, nbytes, tag, time.monotonic() - t0, queued_s, wire,
+                    ok=False,
                 )
                 # Tell live peers we abandoned this collective so their
                 # pending tag waits fail NOW: one rank wedged on a dead
@@ -724,9 +794,11 @@ class ProcessGroupSocket(ProcessGroup):
                 if self._errored is None:
                     self._errored = e
                 raise
+            finally:
+                _wire.account = None
             flight_recorder.complete(seq)
             self._journal_collective(
-                op, nbytes, tag, time.monotonic() - t0, queued_s, ok=True
+                op, nbytes, tag, time.monotonic() - t0, queued_s, wire, ok=True
             )
             return result
 
@@ -748,14 +820,16 @@ class ProcessGroupSocket(ProcessGroup):
         tag: Optional[str],
         dt: float,
         queued_s: float,
+        wire: Optional[_WireAccount],
         ok: bool,
     ) -> None:
-        """One journal line + one span sample per completed collective,
-        IDENTICAL across backends (socket and native both route through
-        _submit), so journals from differently-configured replicas can be
-        diffed byte-for-byte per tag. No-ops beyond a span add unless the
-        journal is enabled."""
-        observe_span(f"pg::{self.getBackendName()}::{op}", dt)
+        """One journal line per completed collective, IDENTICAL across
+        backends (socket and native both route through _submit) in
+        ``(op, tag, nbytes, ok)``, so journals from differently-configured
+        replicas can be diffed per tag. A collective that moved messages
+        over the Python sockets carries its ``_WireAccount`` too; one that
+        blocked in the C++ engine, or had no peer, carries none of those
+        fields (never zeros). A no-op unless the journal is enabled."""
         log = get_event_log()
         if log is not None:
             log.emit(
@@ -768,6 +842,7 @@ class ProcessGroupSocket(ProcessGroup):
                 elapsed_s=dt,
                 queued_s=queued_s,
                 ok=ok,
+                **(wire.fields() if wire is not None else {}),
             )
 
     # -- collectives -------------------------------------------------------
@@ -929,7 +1004,9 @@ class ProcessGroupSocket(ProcessGroup):
 
         # No ``nbytes``: the journal's ``pg_collective.nbytes`` of a step
         # has always been the allgathers' alone, and the benchmark's
-        # ``wire_bytes_step`` reads it so.
+        # ``wire_bytes_step`` reads it so. What the sockets really moved
+        # is the event's ``tx_bytes`` and ``rx_bytes`` (``_WireAccount``),
+        # which ``wire_xfer_bytes_step`` reads.
         return self._submit(run, op="alltoall", tag=tag)
 
     def barrier(self) -> Work:

@@ -583,7 +583,9 @@ EVENT_KINDS: Dict[str, str] = {
     "commit_gate": "should_commit verdict for the step window: committed, "
                    "local_vote, cause (manager.GATE_CAUSES), quorum_id, "
                    "participants, hb_rounds/hb_gap_max_ms/hb_rtt_max_ms/"
-                   "hb_late since the last gate, rss_peak_bytes",
+                   "hb_late since the last gate, rss_peak_bytes and the "
+                   "same getrusage's cumulative cpu_user_s/cpu_sys_s/"
+                   "minflt/nivcsw",
     "goodput": "per-commit goodput/step-rate sample",
     # -- healing / checkpoint (manager.py, checkpointing/*) ------------
     "heal_start": "this replica starts healing from a live peer",
@@ -602,7 +604,10 @@ EVENT_KINDS: Dict[str, str] = {
     # -- process group / native engine (process_group.py) --------------
     "pg_configure": "process group (re)configured for a new quorum",
     "pg_configure_failed": "process group configure attempt failed",
-    "pg_collective": "socket-PG collective issued (debug-level cadence)",
+    "pg_collective": "process-group collective completed: op, nbytes, tag, "
+                     "elapsed_s, queued_s, ok; over the Python sockets also "
+                     "tx_bytes/rx_bytes and send_s/peer_wait_s/recv_s with "
+                     "send_cpu_s/recv_cpu_s",
     "pg_abort": "process group aborted in-flight collectives",
     "pg_native_mesh": "native engine mesh established (peers, streams)",
     "native_collective": "native-engine flight-recorder record drained",
