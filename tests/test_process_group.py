@@ -2,6 +2,7 @@
 process_group_test.py MultiPgBaseTest:863-1020), full collective surface,
 crash-and-reconfigure resiliency, and the wrapper zoo."""
 
+import struct
 import threading
 from concurrent.futures import ThreadPoolExecutor
 
@@ -1101,6 +1102,7 @@ def _small_pieces(monkeypatch):
 
     monkeypatch.setattr(C, "_PIECE_BLOCKS", 3)
     monkeypatch.setattr(C, "_BLOCKS_PER_TASK", 8)
+    monkeypatch.setattr(C, "_NATIVE_BLOCKS_PER_TASK", 5)
 
 
 def _wire_data(ws, n, seed):
@@ -1428,3 +1430,639 @@ def test_quantized_wire_many_buckets_in_flight_keep_their_own_results(
                 np.testing.assert_array_equal(got.view(np.uint32), w.view(np.uint32))
     for g in groups:
         g.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# Kept receive buffers (process_group._RecvBuffers)
+# ---------------------------------------------------------------------------
+
+_LARGE = 1 << 16  # _net.LARGE_FRAME: from here on a payload lands in a kept buffer
+
+
+@pytest.fixture
+def pg_journal(tmp_path, monkeypatch):
+    """A configured journal; yields a reader of its ``pg_collective``
+    attributes, one dict a collective, in file order."""
+    import json
+
+    from torchft_tpu import telemetry
+
+    path = str(tmp_path / "journal.jsonl")
+    monkeypatch.setenv("TORCHFT_JOURNAL_FILE", path)
+    monkeypatch.delenv("TORCHFT_JOURNAL_DIR", raising=False)
+    telemetry.reset_event_log()
+
+    def collectives():
+        with open(path) as f:
+            evs = [json.loads(line) for line in f]
+        return [e["attrs"] for e in evs if e["event"] == "pg_collective"]
+
+    yield collectives
+    telemetry.reset_event_log()
+
+
+def _mesh_groups(backend, store, ws, prefix):
+    """``ws`` ranks of the socket group, or of the native group, whose
+    alltoall, reduce_scatter, send and recv ride the same Python mesh."""
+    if backend == "socket":
+        return _make_group(store, ws, prefix=prefix, timeout=30.0)
+    from torchft_tpu import _native
+    from torchft_tpu.process_group import ProcessGroupNative
+
+    if not _native.is_available():
+        pytest.skip("native engine unavailable")
+    groups = [ProcessGroupNative(timeout=30.0) for _ in range(ws)]
+    _run_parallel([
+        lambda r=r: groups[r].configure(f"{store.address()}/{prefix}", r, ws)
+        for r in range(ws)
+    ])
+    return groups
+
+
+def _free_sizes(pg):
+    return sorted(b.size for c in pg._peers.values() for b in c.buffers._free)
+
+
+MESHES = pytest.mark.parametrize("backend", ["socket", "native"])
+
+
+@MESHES
+def test_wire_turns_land_in_kept_buffers_from_the_second_on(store, pg_journal, backend):
+    """Four ranks, six wire turns of one size: the first turn's messages
+    make their buffers (two a size and connection: a peer may be one
+    message ahead), every later turn reads ``rx_fresh_bytes`` 0 on every
+    collective that moved a payload over the Python mesh, and the result
+    is the plain oracle's every time."""
+    import torchft_tpu.collectives as C
+
+    ws, turns = 4, 6
+    chunk = _LARGE  # bytes of int8 payload a rank and chunk: just large
+    n = ws * chunk
+    groups = _mesh_groups(backend, store, ws, f"kept-{backend}")
+    data = [_wire_data(ws, n, seed=60 + t) for t in range(turns)]
+
+    def run(rank):
+        outs = []
+        for t in range(turns):
+            q, s = C.quantize_blockwise(data[t][rank])
+            q_f, s_f = C._quantized_wire_pipeline(groups[rank], q, s, n)
+            outs.append((q_f.copy(), s_f.copy()))
+        return outs
+
+    try:
+        results = _run_parallel([lambda r=r: run(r) for r in range(ws)])
+        for t in range(turns):
+            want_q, want_s, _ = _oracle_wire(data[t], 8)
+            for outs in results:
+                np.testing.assert_array_equal(outs[t][0], want_q)
+                np.testing.assert_array_equal(outs[t][1], want_s)
+        fresh = [e["rx_fresh_bytes"] for e in pg_journal()
+                 if e["op"] in ("alltoall", "allgather") and "rx_fresh_bytes" in e]
+        # per rank and turn one alltoall on the mesh, and on the socket
+        # group one allgather beside it (the native group's rides its engine)
+        per_turn = ws * (2 if backend == "socket" else 1)
+        assert len(fresh) == per_turn * turns
+        made = sorted(fresh, reverse=True)[: per_turn]
+        # the first messages of the one size: its own buffer and a spare,
+        # from each of three peers, on each rank
+        assert sum(made) == ws * (ws - 1) * 2 * chunk
+        assert sum(fresh) == sum(made)  # nothing after them
+        # every rank's list holds exactly what those turns made, all free
+        for g in groups:
+            assert _free_sizes(g) == [chunk] * (2 * (ws - 1))
+    finally:
+        for g in groups:
+            g.shutdown()
+
+
+@MESHES
+def test_a_received_array_that_is_alive_is_never_handed_out_again(store, backend):
+    """Hold what one turn received, and a view of what another did,
+    across ten more turns of the same sizes: their bytes stay, because a
+    buffer somebody still sees is not on the free list; the turns
+    meanwhile make new buffers instead, and once the holders let go the
+    held buffers come back."""
+    ws, n = 2, _LARGE // 4 + 512  # fp32: just over the threshold
+    groups = _mesh_groups(backend, store, ws, f"held-{backend}")
+
+    def turn(rank, t):
+        mine = [np.full(n, 100 * t + 10 * rank + j, np.float32) for j in range(ws)]
+        return groups[rank].alltoall(mine).wait(timeout=30)[1 - rank]
+
+    def run(rank):
+        whole = turn(rank, 0)
+        view = turn(rank, 1)[7:99]
+        kept = whole.copy(), view.copy()
+        seen = []
+        for t in range(2, 12):
+            got = turn(rank, t)
+            assert not np.shares_memory(got, whole)
+            assert not np.shares_memory(got, view)
+            seen.append(float(got[0]))
+        np.testing.assert_array_equal(whole, kept[0])
+        np.testing.assert_array_equal(view, kept[1])
+        assert whole[0] == 10 * (1 - rank) + rank
+        return seen
+
+    try:
+        results = _run_parallel([lambda r=r: run(r) for r in range(ws)])
+        for rank, seen in enumerate(results):
+            assert seen == [100.0 * t + 10 * (1 - rank) + rank for t in range(2, 12)]
+        # the two held buffers and the two or three the later turns went
+        # round in (a turn's result lives until the next one's replaces
+        # it, and the peer may be a message ahead), all back now that the
+        # holders are gone
+        for g in groups:
+            sizes = _free_sizes(g)
+            assert set(sizes) == {4 * n} and 4 <= len(sizes) <= 5
+    finally:
+        for g in groups:
+            g.shutdown()
+
+
+@MESHES
+def test_a_message_larger_than_the_turns_goes_with_the_wire_scratch(store, backend):
+    """A heal-sized message over the group leaves its buffers on the
+    connection's list once the receiver lets the array go, and
+    ``_drop_wire_scratch`` (reconfigure, abort, shutdown) lets them go:
+    nothing of it is retained, and the next message makes its own."""
+    ws, big = 2, 1 << 20
+    groups = _mesh_groups(backend, store, ws, f"heal-{backend}")
+
+    def run(rank):
+        pg = groups[rank]
+        if rank == 0:
+            pg.send([np.arange(big, dtype=np.uint8)], dst=1, tag="heal").wait(timeout=30)
+            return None
+        (got,) = pg.recv(src=0, tag="heal").wait(timeout=30)
+        assert got[-1] == (big - 1) % 256
+        del got
+        return _free_sizes(pg)
+
+    try:
+        results = _run_parallel([lambda r=r: run(r) for r in range(ws)])
+        assert results[1] == [big, big]
+        conn = groups[1]._peers[0]
+        groups[1]._drop_wire_scratch()
+        assert _free_sizes(groups[1]) == [] and conn.buffers._seen == set()
+        groups[1].abort()
+        assert conn.buffers._free == []
+    finally:
+        for g in groups:
+            g.shutdown()
+
+
+def _torn_frame_conn(n):
+    """A hand-made connection that has received one whole message of
+    ``n`` bytes (so its list holds that size's two buffers, painted 0xAB)
+    and is now half way through a second one: (conn, the peer's socket,
+    the painted buffers)."""
+    import socket as socket_mod
+    import time
+
+    from torchft_tpu import _net
+    from torchft_tpu.process_group import _PeerConn
+
+    a, b = socket_mod.socketpair()
+    conn = _PeerConn(a, peer=1)
+    header = {"tag": "t", "dtype": "uint8", "shape": [n]}
+    _net.send_json(b, header)
+    _net.send_frame(b, bytes(n))
+    got = conn.recv("t", timeout=5.0)
+    del got
+    painted = list(conn.buffers._free)
+    assert [p.size for p in painted] == [n, n]
+    for p in painted:
+        p[:] = 0xAB
+    _net.send_json(b, header)
+    b.sendall(struct.pack(">I", n) + bytes(n // 2))  # ... and no more
+    deadline = time.monotonic() + 5
+    while len(conn.buffers._free) == 2 and time.monotonic() < deadline:
+        time.sleep(0.005)
+    return conn, b, painted
+
+
+@pytest.mark.parametrize("how", ["peer_death", "abort"])
+def test_a_reader_that_stops_mid_frame_leaves_no_buffer_it_can_write_on_the_list(how):
+    """The reader thread is half way into a kept buffer when the peer
+    dies, or when this side aborts: that buffer is on no list anybody
+    lends from (what still refers to it is the dead reader's own frame),
+    and the one that stayed on the list was never written."""
+    n = 4 * _LARGE
+    conn, b, painted = _torn_frame_conn(n)
+    try:
+        assert len(conn.buffers._free) == 1  # the other is being filled
+        (idle,) = conn.buffers._free
+        if how == "peer_death":
+            b.close()
+        else:
+            conn.close()
+            assert conn.buffers._free == []  # close lets the list go
+        conn._reader.join(timeout=5)
+        assert not conn._reader.is_alive() and conn.dead is not None
+        filling = next(p for p in painted if p is not idle)
+        assert (idle == 0xAB).all()  # never lent, never written
+        assert (filling[: n // 2] == 0).all()  # the half that arrived
+        assert all(f is not filling for f in conn.buffers._free)
+        with pytest.raises(RuntimeError, match="died"):
+            conn.recv("t", timeout=5.0)
+        assert all(f is not filling for f in conn.buffers._free)
+    finally:
+        conn.close()
+        b.close()
+
+
+@pytest.mark.timeout(120)
+def test_kept_buffers_under_readers_that_let_go_on_other_threads():
+    """More threads than cores and a short switch interval: one thread
+    receives 400 messages of two sizes, six others check each array some
+    messages later and drop it there, so buffers come back to the
+    connection's list (a finalizer, on whichever thread held the last
+    view) while its reader lends from it. A buffer handed out while an
+    array still saw it would show as another message's bytes."""
+    import queue
+    import socket as socket_mod
+    import sys
+
+    from torchft_tpu.process_group import _PeerConn
+
+    a, b = socket_mod.socketpair()
+    left, right = _PeerConn(a, peer=1), _PeerConn(b, peer=0)
+    sizes = (_LARGE, 3 * _LARGE)
+    total, checkers = 400, 6
+    held: "queue.Queue" = queue.Queue(maxsize=12)
+    bad, seen = [], []
+
+    def check():
+        while True:
+            item = held.get()
+            if item is None:
+                return
+            i, arr = item
+            if not (arr == i % 251).all() or arr.size != sizes[i % 2]:
+                bad.append(i)
+            seen.append(i)
+
+    def send():
+        for i in range(total):
+            left.send(f"t{i}", np.full(sizes[i % 2], i % 251, np.uint8))
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    threads = [threading.Thread(target=check) for _ in range(checkers)]
+    threads.append(threading.Thread(target=send))
+    try:
+        for t in threads:
+            t.start()
+        for i in range(total):
+            held.put((i, right.recv(f"t{i}", timeout=30.0)))
+        for _ in range(checkers):
+            held.put(None)
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+        left.close()
+        right.close()
+    assert bad == [] and sorted(seen) == list(range(total))
+
+
+def test_receive_buffers_are_found_by_index_and_size_never_by_value(monkeypatch):
+    """``_RecvBuffers.lend`` never compares arrays (``list.remove`` on a
+    list of arrays did, elementwise, and cost PR 24 its chip time): with
+    every comparison of two buffers forbidden it still lends, exact size
+    first and the newest of a size; a size's first message makes its
+    spare, a small frame gets nothing; and the list keeps ``KEEP_BYTES``
+    at most, the oldest going first."""
+    from torchft_tpu.process_group import _RecvBuffers
+
+    class Buf(np.ndarray):
+        def __eq__(self, other):
+            raise AssertionError("buffers compared by value")
+
+        __ne__ = __eq__
+
+    real_empty = np.empty
+    monkeypatch.setattr(
+        "torchft_tpu.process_group.np.empty",
+        lambda *a, **k: real_empty(*a, **k).view(Buf),
+    )
+    bufs = _RecvBuffers()
+    assert bufs.lend(_LARGE - 1) == (None, 0)
+    a, fresh = bufs.lend(_LARGE)
+    assert (a.size, a.dtype, fresh) == (_LARGE, np.uint8, 2 * _LARGE)
+    b, fresh = bufs.lend(_LARGE)  # the spare
+    assert fresh == 0 and not np.shares_memory(a, b)
+    c, fresh = bufs.lend(_LARGE)  # both are out: one more, no second spare
+    assert fresh == _LARGE and bufs._free == []
+    big, fresh = bufs.lend(3 * _LARGE)
+    assert fresh == 6 * _LARGE and [f.size for f in bufs._free] == [3 * _LARGE]
+    a[:] = 1
+    b[:] = 2
+    del a
+    del b  # came back last: lent first
+    again, fresh = bufs.lend(_LARGE)
+    assert fresh == 0 and again[0] == 2
+    del again, c, big
+    assert sorted(f.size for f in bufs._free) == [_LARGE] * 3 + [3 * _LARGE] * 2
+
+    monkeypatch.setattr(_RecvBuffers, "KEEP_BYTES", 5 * _LARGE)
+    x, _ = bufs.lend(_LARGE)  # 3 x 64K + 2 x 192K on the list: over the cap
+    # the oldest go until the rest fits: the big spare (made first of
+    # those still there), then the small ones in the order they came back
+    assert sum(f.size for f in bufs._free) <= 5 * _LARGE
+    assert 3 * _LARGE in [f.size for f in bufs._free]
+    bufs.drop()
+    assert bufs._free == [] and bufs._seen == set()
+    del x  # returns to the list that was, not to this one
+    assert bufs._free == []
+
+
+# ---------------------------------------------------------------------------
+# The wire turn's codec: the library's one pass a block against numpy's passes
+# ---------------------------------------------------------------------------
+
+
+def _needs_codec():
+    from torchft_tpu import _native
+
+    if not _native.is_available():
+        pytest.skip("native library unavailable")
+    return _native
+
+
+def _both_codecs(peers, blocks):
+    """(payload, scales, fp32 sum) of ``peers``' chunk of ``blocks``
+    blocks by the numpy passes and by the library."""
+    import torchft_tpu.collectives as C
+
+    native = _needs_codec()
+    n = blocks * _B
+    acc, rq, rs = np.empty(n, np.float32), np.empty(n, np.int8), np.empty(blocks, np.float32)
+    tmp = np.empty(C._task_tmp_shape(blocks), np.float32)
+    C._dequantize_sum(acc, peers, 8, tmp)
+    C._quantize_into(acc, 8, rq, rs, tmp)
+    assert C._native_codec(peers, blocks, 8) is native
+    acc2, rq2, rs2 = np.full(n, 7.0, np.float32), np.full(n, 7, np.int8), np.full(blocks, 7.0, np.float32)
+    C._parallel_over_blocks(
+        blocks, native.q8_reducer(peers, acc2, rq2, rs2), C._NATIVE_BLOCKS_PER_TASK
+    )
+    # ... and the form the allreduce runs, which writes no sum
+    rq3, rs3 = np.full(n, 7, np.int8), np.full(blocks, 7.0, np.float32)
+    C._parallel_over_blocks(
+        blocks, native.q8_reducer(peers, None, rq3, rs3), C._NATIVE_BLOCKS_PER_TASK
+    )
+    np.testing.assert_array_equal(rq2, rq3)
+    np.testing.assert_array_equal(rs2.view(np.uint32), rs3.view(np.uint32))
+    return (rq, rs, acc), (rq2, rs2, acc2)
+
+
+def _assert_same_bits(numpy_out, native_out):
+    (rq, rs, acc), (rq2, rs2, acc2) = numpy_out, native_out
+    np.testing.assert_array_equal(rq, rq2)
+    np.testing.assert_array_equal(rs.view(np.uint32), rs2.view(np.uint32))
+    # the sums bit for bit, the sign of a zero aside (it requantizes to
+    # the same byte): -0.0 == 0.0 here, and everything else by its bits
+    np.testing.assert_array_equal(acc, acc2)
+    nonzero = acc != 0
+    np.testing.assert_array_equal(
+        acc.view(np.uint32)[nonzero], acc2.view(np.uint32)[nonzero]
+    )
+
+
+@pytest.mark.parametrize("tasks", ["one_task", "several_tasks"])
+@pytest.mark.parametrize("n_peers", [1, 2, 3, 4])
+def test_native_codec_equals_numpy_bit_for_bit_on_random_chunks(
+    monkeypatch, n_peers, tasks
+):
+    """Heavy-tailed random payloads (every block's largest lands on
+    +-127), an all-zero block, a zero-padded last block and peers whose
+    scales differ by orders of magnitude: the same bytes, scale bits and
+    sums, in one task and in several ragged ones."""
+    import torchft_tpu.collectives as C
+
+    if tasks == "several_tasks":
+        _small_pieces(monkeypatch)
+    blocks = 23
+    rng = np.random.default_rng(100 + n_peers)
+    peers = []
+    for p in range(n_peers):
+        x = (rng.standard_normal(blocks * _B) * rng.choice([1e-4, 1.0, 300.0], blocks * _B)
+             * 10.0 ** (3 * p)).astype(np.float32)
+        x[_B : 2 * _B] = 0.0  # all zero
+        x[-77:] = 0.0  # a short last block, padded
+        peers.append(C.quantize_blockwise(x))
+    assert all(np.abs(q).max() == 127 for q, _ in peers)
+    _assert_same_bits(*_both_codecs(peers, blocks))
+
+
+def test_native_codec_equals_numpy_on_ties_zero_sums_and_underflow():
+    """Quotients that tie at .5 round to even (0.5 -> 0, 1.5 -> 2, 2.5 ->
+    2, and their negatives); sums that cancel to +0.0 and a sum of
+    -0.0s; a block whose largest is 127 scales exactly; a block of
+    denormals whose scale underflows to 0 takes scale 1; and a received
+    scale of 0, which no quantizer sends, still agrees."""
+    blocks = 6
+    q0 = np.zeros(blocks * _B, np.int8)
+    q1 = np.zeros(blocks * _B, np.int8)
+    s0 = np.ones(blocks, np.float32)
+    s1 = np.ones(blocks, np.float32)
+    # block 0: peer 0 pins the scale at c = 8 (127 * 8 / 127), peer 1
+    # adds odd halves of it: quotients k + 0.5
+    q0[0], s0[0] = 127, 8.0
+    odd = np.array([1, 3, 5, 7, -1, -3, -5, -7, 251 - 256, 253], np.int64)
+    q1[1 : 1 + odd.size] = odd.astype(np.int8)
+    s1[0] = 4.0
+    # block 1: cancels to +0.0 everywhere
+    q0[_B : 2 * _B], q1[_B : 2 * _B] = 5, -5
+    # block 2: zeros times a negative scale: a sum of -0.0s
+    s0[2] = s1[2] = -1.0
+    # block 3: clips and exact +-127
+    q0[3 * _B : 4 * _B] = np.resize(np.array([127, -127, 64, -64, 1], np.int8), _B)
+    q1[3 * _B : 4 * _B] = np.resize(np.array([127, -127, 63, -1, 0], np.int8), _B)
+    s0[3], s1[3] = 0.75, 0.5
+    # block 4: denormal sums, absmax / 127 underflows to 0
+    q0[4 * _B : 5 * _B] = np.resize(np.array([1, -1, 0], np.int8), _B)
+    s0[4] = np.float32(1e-45)
+    s1[4] = np.float32(1e-45)
+    # block 5: a zero scale from the wire
+    q0[5 * _B :], q1[5 * _B :] = 9, 100
+    s0[5], s1[5] = 0.0, 0.25
+    peers = [(q0, s0), (q1, s1)]
+    numpy_out, native_out = _both_codecs(peers, blocks)
+    _assert_same_bits(numpy_out, native_out)
+    rq, rs, acc = native_out
+    assert rs[0] == 8.0 and rq[0] == 127
+    np.testing.assert_array_equal(rq[1:9], [0, 2, 2, 4, 0, -2, -2, -4])
+    assert (acc[_B : 2 * _B] == 0).all() and (rq[_B : 3 * _B] == 0).all()
+    assert np.signbit(acc[2 * _B : 3 * _B]).all() and rs[1] == rs[2] == 1.0
+    assert rq[3 * _B] == 127 and rq[3 * _B + 1] == -127
+    assert rs[4] == 1.0 and (rq[4 * _B : 5 * _B] == 0).all()
+
+
+def _wire_spans(drained):
+    return [s[6] for s in drained if s[0].endswith("::wire_reduce") and "numpy_blocks" in s[6]]
+
+
+@pytest.mark.parametrize("case", ["int8", "int4", "tiny", "no_library"])
+def test_which_codec_reduces_is_read_from_the_payload_and_the_process(
+    store, pg_journal, monkeypatch, case
+):
+    """8-bit whole blocks in a process with the library: every block by
+    the native pass. 4-bit payloads, a payload of fewer blocks than
+    ranks, and a process without the library: the numpy passes
+    (``native_blocks`` 0), to the oracle's bytes."""
+    from torchft_tpu import _native, telemetry
+
+    native = _needs_codec()
+    ws = 3
+    bits = 4 if case == "int4" else 8
+    n = 2 * _B - 5 if case == "tiny" else _B * ws * 9 + 13
+    if case == "no_library":
+        monkeypatch.setattr(_native, "is_available", lambda: False)
+        monkeypatch.setattr(native, "q8_reducer", lambda *a: pytest.fail("called"))
+    groups = _make_group(store, ws, prefix=f"which-{case}")
+    data = _wire_data(ws, n, seed=70)
+    telemetry.drain_spans()
+    try:
+        got = _allreduce_quantized_all(groups, data, bits=bits)
+    finally:
+        for g in groups:
+            g.shutdown()
+    if case == "tiny":
+        want = sum(_oracle_dequantize(*_oracle_quantize(d, bits), n, bits) for d in data)
+        # the fallback sums every rank's dequantized payload in fp32
+        for g in got:
+            np.testing.assert_allclose(g, want, rtol=1e-6)
+    else:
+        for g in got:
+            np.testing.assert_array_equal(g, _oracle_wire(data, bits)[2])
+    spans = _wire_spans(telemetry.drain_spans()[0])
+    assert len(spans) == ws
+    blocks = -(-n // _B)
+    if case == "int8":
+        assert sum(a["native_blocks"] for a in spans) == blocks
+        assert all(a["numpy_blocks"] == 0 for a in spans)
+    else:
+        assert all(a["native_blocks"] == 0 for a in spans)
+        assert sum(a["numpy_blocks"] for a in spans) == (
+            blocks * ws if case == "tiny" else blocks
+        )
+
+
+def test_a_group_that_mixes_the_two_codecs_agrees_on_every_rank(store, monkeypatch):
+    """One rank without the library among three with it: replicas that
+    differ in what they can run still hold the same payload and scales,
+    bit for bit, the oracle's."""
+    import torchft_tpu.collectives as C
+
+    _needs_codec()
+    _small_pieces(monkeypatch)
+    ws = 4
+    n = _B * ws * 11 + 301
+    groups = _make_group(store, ws, prefix="mixed-codec")
+    data = _wire_data(ws, n, seed=71)
+    numpy_rank = {}
+    real = C._native_codec
+    took = []
+
+    def codec(peers, blocks, bits):
+        chosen = None if threading.get_ident() in numpy_rank else real(peers, blocks, bits)
+        took.append(chosen is not None)
+        return chosen
+
+    monkeypatch.setattr(C, "_native_codec", codec)
+
+    def run(rank):
+        if rank == 0:
+            numpy_rank[threading.get_ident()] = True
+        q, s = C.quantize_blockwise(data[rank])
+        q_f, s_f = C._quantized_wire_pipeline(groups[rank], q, s, n)
+        return q_f.copy(), s_f.copy()
+
+    try:
+        results = _run_parallel([lambda r=r: run(r) for r in range(ws)])
+    finally:
+        for g in groups:
+            g.shutdown()
+    assert sorted(took) == [False, True, True, True]
+    want_q, want_s, _ = _oracle_wire(data, 8)
+    for q_f, s_f in results:
+        np.testing.assert_array_equal(q_f, want_q)
+        np.testing.assert_array_equal(s_f.view(np.uint32), want_s.view(np.uint32))
+
+
+def test_error_feedbacks_hook_sees_this_ranks_own_payload_as_before(store):
+    """``on_local_quantized(flat, q, s)``: the rank's own quantized
+    payload, what ``quantize_blockwise`` gives, and nothing the wire turn
+    does afterwards (its own chunk now travels round the alltoall as a
+    view of ``q``, not a copy) writes into it."""
+    from torchft_tpu.collectives import allreduce_quantized, quantize_blockwise
+
+    ws = 2
+    n = _B * ws * 5 + 40
+    groups = _make_group(store, ws, prefix="ef-hook")
+    data = _wire_data(ws, n, seed=72)
+    seen = {}
+
+    def run(rank):
+        def hook(flat, q, s):
+            seen[rank] = (flat.copy(), q, s, q.copy(), s.copy())
+
+        arr = data[rank].copy()
+        allreduce_quantized(groups[rank], [arr], on_local_quantized=hook).wait(timeout=60)
+        return arr
+
+    try:
+        got = _run_parallel([lambda r=r: run(r) for r in range(ws)])
+    finally:
+        for g in groups:
+            g.shutdown()
+    for rank in range(ws):
+        flat, q, s, q_then, s_then = seen[rank]
+        np.testing.assert_array_equal(flat, data[rank])
+        want_q, want_s = quantize_blockwise(data[rank])
+        np.testing.assert_array_equal(q_then, want_q)
+        np.testing.assert_array_equal(s_then, want_s)
+        np.testing.assert_array_equal(q, q_then)  # still, after the turn
+        np.testing.assert_array_equal(s, s_then)
+        np.testing.assert_array_equal(got[rank], _oracle_wire(data, 8)[2])
+
+
+def test_reduce_scatter_quantized_is_its_numpy_form_bit_for_bit(store, monkeypatch):
+    """The collective that hands the fp32 sum out keeps a form that
+    writes it: the shard by the library equals the shard by numpy."""
+    from torchft_tpu import _native
+    from torchft_tpu.collectives import reduce_scatter_quantized
+
+    _needs_codec()
+    _small_pieces(monkeypatch)
+    ws = 3
+    n = _B * ws * 7 + 99
+    data = _wire_data(ws, n, seed=73)
+
+    def shards(prefix):
+        groups = _make_group(store, ws, prefix=prefix)
+        try:
+            return _run_parallel([
+                lambda r=r: reduce_scatter_quantized(
+                    groups[r], [data[r].copy()]
+                ).wait(timeout=60)
+                for r in range(ws)
+            ])
+        finally:
+            for g in groups:
+                g.shutdown()
+
+    native = shards("rs-native")
+    monkeypatch.setattr(_native, "is_available", lambda: False)
+    plain = shards("rs-numpy")
+    covered = 0
+    for (a, a_range), (b, b_range) in zip(native, plain):
+        assert a_range == b_range
+        np.testing.assert_array_equal(a, b)
+        nz = a != 0
+        np.testing.assert_array_equal(a.view(np.uint32)[nz], b.view(np.uint32)[nz])
+        covered += a.size
+    assert covered == n

@@ -550,11 +550,12 @@ def test_quantized_tree_two_ranks_three_buckets(journal):
         assert all(a[1] <= b[0] for a, b in zip(wires, wires[1:]))
         for wire in _by_name(tree, "torchft::collectives::wire"):
             kids = [s[NAME].rsplit("::", 1)[1] for s in tree if s[PARENT] == wire[ID]]
-            # numpy between and after the socket operations: accumulate,
-            # requantize, join the gathered chunks
+            # host arithmetic between and after the socket operations:
+            # accumulate and requantize (one pass a block where the native
+            # codec runs, so one span), then join the gathered chunks
             assert sorted(kids) == [
                 "wire_allgather", "wire_alltoall",
-                "wire_reduce", "wire_reduce", "wire_reduce",
+                "wire_reduce", "wire_reduce",
             ]
         # stage spans run on the buckets' own threads, not the caller's
         caller = next(s for s in spans if s[ID] == roots[rank])[THREAD]
@@ -603,14 +604,18 @@ def test_wire_reduce_spans_count_fresh_and_reused_bytes(journal):
          if s[PARENT] == w[ID] and s[NAME].endswith("::wire_reduce")]
         for w in wires
     ]
-    assert all(len(attrs) == 3 for attrs in stages)
+    assert all(len(attrs) == 2 for attrs in stages)
     # the two ranks' first collectives grow the scratch ...
     for attrs in stages[:2]:
         assert all(a["fresh_bytes"] > 0 for a in attrs)
     # ... and nothing after them allocates. A chunk of 6 blocks: the
-    # requantized 3,072 B + 24 B of scales (and a piece per task), the
-    # joined payload 6,144 B + 48 B
+    # requantized 3,072 B + 24 B of scales (where the numpy passes run,
+    # the fp32 sum and a piece per task as well), the joined payload
+    # 6,144 B + 48 B
     for attrs in stages[2:]:
-        assert [a["fresh_bytes"] for a in attrs] == [0, 0, 0]
+        assert [a["fresh_bytes"] for a in attrs] == [0, 0]
         assert all(a["reused_bytes"] > 0 for a in attrs)
-        assert attrs[2]["reused_bytes"] == 6144 + 48
+        assert attrs[1]["reused_bytes"] == 6144 + 48
+        # the first span says which codec reduced its blocks, the join none
+        assert attrs[0]["native_blocks"] + attrs[0]["numpy_blocks"] == 6
+        assert "native_blocks" not in attrs[1]

@@ -25,7 +25,7 @@ from torchft_tpu.process_group import (
 from torchft_tpu.store import TCPStoreServer
 
 WIRE_FIELDS = ("tx_bytes", "rx_bytes", "send_s", "send_cpu_s", "peer_wait_s",
-               "recv_s", "recv_cpu_s")
+               "recv_s", "recv_cpu_s", "rx_fresh_bytes")
 RUSAGE_FIELDS = ("cpu_user_s", "cpu_sys_s", "minflt", "nivcsw")
 # The three waits are on time.time(), elapsed_s on time.monotonic(): two
 # clocks may disagree by what the system clock was slewed meanwhile.
@@ -257,7 +257,7 @@ def test_a_bare_connection_stamps_its_messages_and_needs_no_account(journal):
     left, right = _PeerConn(a, peer=1), _PeerConn(b, peer=0)
     try:
         t0 = time.time()
-        (_, payload, t_hdr, cpu_s), got = _one_message(left, right)
+        (_, payload, t_hdr, cpu_s, _fresh), got = _one_message(left, right)
         assert len(payload) == N * 4 and t0 <= t_hdr <= time.time() and cpu_s >= 0.0
         np.testing.assert_array_equal(got, np.arange(N, dtype=np.float32))
     finally:
@@ -271,7 +271,7 @@ def test_with_no_journal_the_reader_takes_no_stamp(no_journal, monkeypatch):
     monkeypatch.setattr(process_group.time, "thread_time",
                         lambda: pytest.fail("a clock was read with tracing off"))
     try:
-        (_, payload, t_hdr, cpu_s), got = _one_message(left, right)
+        (_, payload, t_hdr, cpu_s, _fresh), got = _one_message(left, right)
         assert len(payload) == N * 4 and (t_hdr, cpu_s) == (0.0, 0.0)
         np.testing.assert_array_equal(got, np.arange(N, dtype=np.float32))
     finally:

@@ -75,6 +75,73 @@ void q8_accumulate(float* acc, const int8_t* q, const float* scales,
   }
 }
 
+// One pass a block of the Python wire turn's host arithmetic
+// (collectives.py `_dequantize_sum` then `_quantize_into`, bits=8): the fp32
+// sum over `n_peers` of float(q) * scale in the order given, the first peer
+// written and not added, then (where `q_out` is given) absmax, scale,
+// divide, round-half-even, clip, store. The sum lives in `sum`, which is a
+// block of the caller's `acc` where the caller wants the sum and a block on
+// the stack where it wants the requantized bytes only.
+//
+// Every operation is the numpy path's, in its order and in fp32, so payload
+// bytes, scales and sums agree with it bit for bit (the Makefile keeps the
+// compiler from contracting a multiply and an add into an FMA). Written so
+// that the loops vectorise under strict IEEE semantics, since 484 MB of fp32
+// a rank and step pass through here in mistral-ft4:
+// * absmax over the values' bit patterns with the sign cleared, as integers:
+//   for floats that is the order of their magnitudes, a NaN is the largest
+//   (np.max hands a NaN on, too), and an integer max may be reordered where
+//   a float max may not;
+// * round by adding and taking away 1.5 * 2^23: |t| is 127 and a rounding
+//   at most (the scale is the block's own absmax / 127), so the sum lies in
+//   [2^23, 2^24), whose floats are the integers: the add rounds to the
+//   nearest even one, as np.rint does, and the subtraction is exact. -0.3
+//   comes out +0.0 where rint gives -0.0: the same byte. The clip is on the
+//   integer, which float compares under trapping math would keep scalar.
+// * scale == 0 -> 1 is tested on the QUOTIENT, as numpy does: a denormal
+//   absmax whose 127th underflows takes scale 1 as well.
+void q8_reduce_block(const int8_t* const* qs, const float* const* ss,
+                     int32_t n_peers, uint64_t b, float* __restrict sum,
+                     int8_t* __restrict q_out, float* s_out) {
+  const uint64_t lo = b * kQBlock;
+  {
+    const int8_t* __restrict q = qs[0] + lo;
+    const float s = ss[0][b];
+    for (uint64_t j = 0; j < kQBlock; ++j)
+      sum[j] = static_cast<float>(q[j]) * s;
+  }
+  for (int32_t p = 1; p < n_peers; ++p) {
+    const int8_t* __restrict q = qs[p] + lo;
+    const float s = ss[p][b];
+    for (uint64_t j = 0; j < kQBlock; ++j) {
+      const float t = static_cast<float>(q[j]) * s;
+      sum[j] += t;
+    }
+  }
+  if (q_out == nullptr) return;
+  uint32_t top = 0;
+  for (uint64_t j = 0; j < kQBlock; ++j) {
+    uint32_t bits;
+    memcpy(&bits, &sum[j], sizeof(bits));
+    bits &= 0x7fffffffu;
+    top = bits > top ? bits : top;
+  }
+  float absmax;
+  memcpy(&absmax, &top, sizeof(absmax));
+  float s = absmax / 127.0f;
+  if (s == 0.f) s = 1.0f;
+  s_out[b] = s;
+  constexpr float kRound = 12582912.0f;  // 1.5 * 2^23
+  int8_t* __restrict q = q_out + lo;
+  for (uint64_t j = 0; j < kQBlock; ++j) {
+    const float t = (sum[j] / s + kRound) - kRound;
+    int32_t i = static_cast<int32_t>(t);
+    i = i > 127 ? 127 : i;
+    i = i < -127 ? -127 : i;
+    q[j] = static_cast<int8_t>(i);
+  }
+}
+
 template <typename T>
 void reduce_into(T* dst, const T* src, uint64_t n, int32_t op) {
   if (op == TFT_OP_SUM) {
@@ -1801,5 +1868,16 @@ int64_t tft_coll_fr_snapshot(void* h, uint64_t since_seq, char* out,
   }
   return static_cast<int64_t>(snap.size());
 }
+
+void tft_q8_reduce_blocks(const int8_t* const* qs, const float* const* ss,
+                          int32_t n_peers, uint64_t b0, uint64_t b1,
+                          float* acc, int8_t* q_out, float* s_out) {
+  alignas(64) float local[tft::kQBlock];
+  for (uint64_t b = b0; b < b1; ++b) {
+    float* sum = acc != nullptr ? acc + b * tft::kQBlock : local;
+    tft::q8_reduce_block(qs, ss, n_peers, b, sum, q_out, s_out);
+  }
+}
+
 
 }  // extern "C"

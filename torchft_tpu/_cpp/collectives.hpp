@@ -453,4 +453,13 @@ uint64_t tft_coll_fr_seq(void* h);
 // the return value >= cap. Safe concurrently with an in-flight collective.
 int64_t tft_coll_fr_snapshot(void* h, uint64_t since_seq, char* out,
                              int64_t cap);
+// The int8 blockwise codec alone, no engine: blocks [b0, b1) of the Python
+// wire turn's chunk, one pass a block. qs[p] / ss[p] are peer p's payload and
+// scales of the WHOLE chunk (b1 blocks or more each); the fp32 sum over the
+// peers, in that order, is written to acc (whole chunk, fp32) where acc is
+// given, and requantized into q_out / s_out (whole chunk) where q_out is
+// given. Bit for bit what collectives.py computes in numpy.
+void tft_q8_reduce_blocks(const int8_t* const* qs, const float* const* ss,
+                          int32_t n_peers, uint64_t b0, uint64_t b1,
+                          float* acc, int8_t* q_out, float* s_out);
 }

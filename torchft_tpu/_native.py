@@ -82,6 +82,8 @@ def _declare(lib: ctypes.CDLL) -> None:
     lib.tft_coll_fr_seq.argtypes = [P]
     lib.tft_coll_fr_snapshot.restype = I64
     lib.tft_coll_fr_snapshot.argtypes = [P, U64, P, I64]
+    lib.tft_q8_reduce_blocks.restype = None
+    lib.tft_q8_reduce_blocks.argtypes = [P, P, I32, U64, U64, P, P, P]
     lib.tft_chaos_init.restype = I32
     lib.tft_chaos_init.argtypes = [CP]
     lib.tft_chaos_armed.restype = I32
@@ -190,6 +192,33 @@ def is_available() -> bool:
         return True
     except RuntimeError:
         return False
+
+
+def q8_reducer(
+    peers: "List[Tuple[np.ndarray, np.ndarray]]",
+    acc: Optional[np.ndarray],
+    q_out: Optional[np.ndarray],
+    s_out: Optional[np.ndarray],
+):
+    """``fn(b0, b1)`` that reduces blocks ``[b0, b1)`` of one wire turn's
+    chunk in the library's one pass a block (``tft_q8_reduce_blocks``): the
+    fp32 sum over ``peers`` — (int8 payload, fp32 scales) of the whole
+    chunk each, summed in the order given — written to ``acc`` where it is
+    given and requantized into ``q_out`` / ``s_out`` where they are. The
+    call releases the GIL, so tasks over disjoint ranges run side by side.
+
+    The caller has checked what C cannot: every array is C-contiguous, of
+    its dtype, and holds the whole chunk. The closure keeps them alive."""
+    lib = _load()
+    n = len(peers)
+    qs = (ctypes.c_void_p * n)(*[q.ctypes.data for q, _ in peers])
+    ss = (ctypes.c_void_p * n)(*[s.ctypes.data for _, s in peers])
+    outs = [None if a is None else a.ctypes.data for a in (acc, q_out, s_out)]
+
+    def reduce_blocks(b0: int, b1: int, _held=(peers, acc, q_out, s_out)) -> None:
+        lib.tft_q8_reduce_blocks(qs, ss, n, b0, b1, *outs)
+
+    return reduce_blocks
 
 
 class NativeEngine:

@@ -12,11 +12,15 @@ import json
 import socket
 import struct
 import time
-from typing import Any, Optional
+from typing import Any, Callable, Optional
 
 from . import chaos as _chaos
 
 MAX_FRAME = 1 << 30  # 1 GiB sanity cap, matches net.cc
+# From here on a frame is a tensor payload: sent without a copy
+# (``send_frame``) and, on a process group's connections, received into a
+# buffer the connection keeps (``process_group._RecvBuffers``).
+LARGE_FRAME = 1 << 16
 
 
 def _chaos_armed() -> bool:
@@ -186,7 +190,7 @@ def send_frame(
     if timeout is not None:
         sock.settimeout(timeout)
     n = len(payload)
-    if n < 1 << 16:
+    if n < LARGE_FRAME:
         # Small frame: one syscall, one small copy.
         sock.sendall(struct.pack(">I", n) + bytes(payload))
     else:
@@ -195,13 +199,15 @@ def send_frame(
         sock.sendall(payload)
 
 
-def _recv_exact(sock: socket.socket, n: int, deadline: Optional[float]) -> bytearray:
+def _recv_exact(sock: socket.socket, n: int, deadline: Optional[float], buf=None):
     # Preallocated recv_into: no per-chunk allocations, no final copy. The
     # returned bytearray doubles as a WRITABLE numpy buffer downstream
     # (np.frombuffer(bytearray) is mutable), so tensor receives are
-    # zero-copy end to end.
-    buf = bytearray(n)
-    view = memoryview(buf)
+    # zero-copy end to end. ``buf`` is a writable buffer of exactly ``n``
+    # bytes that the caller keeps, filled and returned instead of a new one.
+    if buf is None:
+        buf = bytearray(n)
+    view = memoryview(buf).cast("B")
     got = 0
     while got < n:
         if deadline is not None:
@@ -216,7 +222,16 @@ def _recv_exact(sock: socket.socket, n: int, deadline: Optional[float]) -> bytea
     return buf
 
 
-def recv_frame(sock: socket.socket, timeout: Optional[float] = None) -> bytearray:
+def recv_frame(
+    sock: socket.socket,
+    timeout: Optional[float] = None,
+    dest: Optional[Callable[[int], Any]] = None,
+):
+    """The next frame's payload, in a new ``bytearray``. A caller that
+    keeps receive buffers passes ``dest``: once the frame's length is
+    known, ``dest(length)`` may hand back a writable buffer of exactly
+    that many bytes, which is then filled and returned in the bytearray's
+    place (None: a new bytearray after all)."""
     if _chaos_armed():
         _chaos_io(sock, "recv")
     deadline = None if timeout is None else time.monotonic() + timeout
@@ -235,7 +250,8 @@ def recv_frame(sock: socket.socket, timeout: Optional[float] = None) -> bytearra
             )
             if delay > 0.0:
                 time.sleep(delay)
-    return _recv_exact(sock, length, deadline)
+    buf = dest(length) if dest is not None else None
+    return _recv_exact(sock, length, deadline, buf)
 
 
 def send_json(sock: socket.socket, obj: Any, timeout: Optional[float] = None) -> None:

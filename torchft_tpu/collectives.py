@@ -286,6 +286,12 @@ def _spawn_collective(
 # needing the GIL) and 0.51 at 8M (out of cache); tasks of 2M values, 0.50.
 _BLOCKS_PER_TASK = 8 * 1024 * 1024 // BLOCK  # 8M values per parallel task
 _PIECE_BLOCKS = 2048  # 1M values a pass
+# The native codec's tasks (``_native.q8_reducer``) hold no GIL and make no
+# numpy calls, so they can be as small as a numpy piece: on a 13-core host
+# with four ranks at once, mistral-ft4's chunks took 0.21 s a step in tasks
+# of 8M values (a chunk of 1-15M values is one or two of them), 0.13 s at
+# 2M and 0.12-0.13 s at 1M (PR 57's chip runs, the wire stage alone).
+_NATIVE_BLOCKS_PER_TASK = _PIECE_BLOCKS
 _host_pool = None
 _host_pool_lock = threading.Lock()
 
@@ -303,16 +309,32 @@ def _pool():
         return _host_pool
 
 
-def _parallel_over_blocks(n_blocks: int, fn) -> None:
-    """Runs fn(block_start, block_end) over block ranges in parallel."""
-    if n_blocks <= _BLOCKS_PER_TASK:
+def _parallel_over_blocks(
+    n_blocks: int, fn, per_task: "int | None" = None
+) -> None:
+    """Runs fn(block_start, block_end) over block ranges in parallel, of
+    ``per_task`` blocks each (``_BLOCKS_PER_TASK`` unless given)."""
+    per_task = per_task or _BLOCKS_PER_TASK
+    if n_blocks <= per_task:
         fn(0, n_blocks)
         return
     tasks = []
-    for start in range(0, n_blocks, _BLOCKS_PER_TASK):
-        tasks.append(
-            _pool().submit(fn, start, min(start + _BLOCKS_PER_TASK, n_blocks))
-        )
+    for start in range(0, n_blocks, per_task):
+        tasks.append(_pool().submit(fn, start, min(start + per_task, n_blocks)))
+    for t in tasks:
+        t.result()
+
+
+def _join(parts: "Sequence[np.ndarray]", out: np.ndarray) -> None:
+    """``out[:]`` = the 1-D ``parts`` end to end. A payload's worth is a
+    copy a part, side by side (a plain copy holds no GIL)."""
+    if out.size <= _BLOCKS_PER_TASK * BLOCK:
+        np.concatenate(parts, out=out)
+        return
+    tasks, off = [], 0
+    for part in parts:
+        tasks.append(_pool().submit(np.copyto, out[off : off + part.size], part))
+        off += part.size
     for t in tasks:
         t.result()
 
@@ -715,7 +737,8 @@ def reduce_scatter_quantized(
                 shard = acc[start:end]
             else:
                 acc = _alltoall_chunk_reduce(
-                    pg, q_host, s_host, counts, bits, _wire_scratch(pg)
+                    pg, q_host, s_host, counts, bits, _wire_scratch(pg),
+                    requantize=False,
                 )
                 # ``acc`` is the next wire turn's to overwrite: what the
                 # caller keeps is a copy, made inside this turn.
@@ -809,6 +832,34 @@ class ErrorFeedback:
         return bool(self._residuals)
 
 
+def _native_codec(
+    peers: "Sequence[Tuple[np.ndarray, np.ndarray]]", blocks: int, bits: int
+):
+    """``_native`` where the library's one pass a block can reduce this
+    turn's chunk, else None and the numpy passes run. Decided by what is
+    there to see, never by a caller: 8-bit payloads, every peer's payload
+    and scales of exactly ``blocks`` whole blocks, of their dtypes and
+    contiguous (C reads what it is pointed at: a short message has to
+    fail in numpy's reshape, as it always has), and a process that has
+    the library. The two paths agree to the byte, so replicas that differ
+    here still agree."""
+    if bits != 8:
+        return None
+    for q, s in peers:
+        if not (
+            q.dtype == np.int8
+            and s.dtype == np.float32
+            and q.size == blocks * BLOCK
+            and s.size == blocks
+            and q.flags.c_contiguous
+            and s.flags.c_contiguous
+        ):
+            return None
+    from torchft_tpu import _native
+
+    return _native if _native.is_available() else None
+
+
 def _alltoall_chunk_reduce(
     pg: ProcessGroup,
     q_host: np.ndarray,
@@ -816,12 +867,18 @@ def _alltoall_chunk_reduce(
     counts: "List[int]",
     bits: int,
     scratch: _WireScratch,
-) -> np.ndarray:
+    requantize: bool,
+):
     """Shared wire step of both quantized collectives: split the payload
     into per-rank block-aligned chunks, alltoall, and dequantize-accumulate
-    every peer's contribution for MY chunk in fp32. Returns the fp32 sum of
-    this rank's chunk (counts[rank] * BLOCK values, padded) in a buffer of
-    ``scratch`` that the next wire turn overwrites."""
+    every peer's contribution for MY chunk in fp32 (counts[rank] * BLOCK
+    values, padded). With ``requantize`` the result is that sum's
+    (payload, scales), else the fp32 sum itself; either way in buffers of
+    ``scratch`` that the next wire turn overwrites.
+
+    Where :func:`_native_codec` allows, a block is summed and requantized
+    in one pass of the library, and a sum nobody asked for is never
+    written to memory; else the numpy passes run, to the same bytes."""
     bpb = BLOCK // (8 // bits)  # payload bytes per block
     q_chunks, s_chunks = [], []
     off = 0
@@ -830,16 +887,38 @@ def _alltoall_chunk_reduce(
         s_chunks.append(s_host[off : off + c])
         off += c
     with trace_span("torchft::collectives::wire_alltoall"):
+        # This rank's own chunk comes back as the views handed in
+        # (``ProcessGroup.alltoall``): read inside the turn only.
         peers = pg.alltoall(
             [[q, s] for q, s in zip(q_chunks, s_chunks)]
         ).wait()
     mine = counts[pg.rank()]
     with trace_span("torchft::collectives::wire_reduce") as span:
-        acc = scratch.turn("acc", np.float32, mine * BLOCK)
-        tmp = scratch.turn("tmp", np.float32, _task_tmp_shape(mine))
-        _dequantize_sum(acc, peers, bits, tmp)
-        span.attrs.update(scratch.counts())
-    return acc
+        acc = rq = rs = None
+        if requantize:
+            rq = scratch.turn("rq", np.int8, mine * bpb)
+            rs = scratch.turn("rs", np.float32, mine)
+        codec = _native_codec(peers, mine, bits)
+        if codec is not None:
+            if not requantize:
+                acc = scratch.turn("acc", np.float32, mine * BLOCK)
+            _parallel_over_blocks(
+                mine,
+                codec.q8_reducer(peers, acc, rq, rs),
+                _NATIVE_BLOCKS_PER_TASK,
+            )
+        else:
+            acc = scratch.turn("acc", np.float32, mine * BLOCK)
+            tmp = scratch.turn("tmp", np.float32, _task_tmp_shape(mine))
+            _dequantize_sum(acc, peers, bits, tmp)
+            if requantize:
+                _quantize_into(acc, bits, rq, rs, tmp)
+        span.attrs.update(
+            scratch.counts(),
+            native_blocks=mine if codec is not None else 0,
+            numpy_blocks=0 if codec is not None else mine,
+        )
+    return (rq, rs) if requantize else acc
 
 
 def _quantized_wire_pipeline(
@@ -867,32 +946,30 @@ def _quantized_wire_pipeline(
     if blocks < ws:
         with trace_span("torchft::collectives::wire_allgather"):
             gathered = pg.allgather([q_host, s_host]).wait()
-        with trace_span("torchft::collectives::wire_reduce"):
+        with trace_span("torchft::collectives::wire_reduce") as span:
             acc = np.zeros(n, np.float32)
             for g_q, g_s in gathered:
                 acc += dequantize_blockwise(g_q, g_s, n, bits)
+            span.attrs.update(native_blocks=0, numpy_blocks=blocks)
         return acc
     # Contiguous block-aligned chunks so each chunk owns whole scales;
     # alltoall -> rank r reduces everyone's r-th chunk.
     counts = [len(c) for c in np.array_split(np.arange(blocks), ws)]
     scratch = _wire_scratch(pg)
-    acc = _alltoall_chunk_reduce(pg, q_host, s_host, counts, bits, scratch)
+    rq, rs = _alltoall_chunk_reduce(
+        pg, q_host, s_host, counts, bits, scratch, requantize=True
+    )
     bpb = BLOCK // (8 // bits)  # payload bytes per block
-    mine = acc.size // BLOCK
-    with trace_span("torchft::collectives::wire_reduce") as span:
-        rq = scratch.turn("rq", np.int8, mine * bpb)
-        rs = scratch.turn("rs", np.float32, mine)
-        tmp = scratch.turn("tmp", np.float32, _task_tmp_shape(mine))
-        _quantize_into(acc, bits, rq, rs, tmp)
-        span.attrs.update(scratch.counts())
     with trace_span("torchft::collectives::wire_allgather"):
+        # ``rq`` and ``rs`` come back as this rank's own entry: the
+        # turn's buffers, read by the join below and by nothing later.
         gathered = pg.allgather([rq, rs]).wait()
     # Joining the ranks' chunks is a host copy of the whole payload, not
     # socket time: booked with the other numpy work of the stage.
     with trace_span("torchft::collectives::wire_reduce") as span:
         q_final, s_final = scratch.result(blocks * bpb, blocks)
-        np.concatenate([g[0] for g in gathered], out=q_final)
-        np.concatenate([g[1] for g in gathered], out=s_final)
+        _join([g[0] for g in gathered], q_final)
+        _join([g[1] for g in gathered], s_final)
         span.attrs.update(scratch.counts())
     return q_final, s_final
 

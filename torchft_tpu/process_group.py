@@ -28,6 +28,7 @@ import socket
 import struct
 import threading
 import time
+import weakref
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -185,7 +186,10 @@ class ProcessGroup:
         return True
 
     def allgather(self, tensors: Any) -> Work:
-        """Result: list over ranks, each a list of arrays."""
+        """Result: list over ranks, each a list of arrays. This rank's own
+        entry is the arrays handed in, not a copy of them (as ``broadcast``
+        hands the root its own arrays and ``allreduce`` reduces in place):
+        a caller that goes on writing its input copies it first."""
         raise NotImplementedError
 
     def broadcast(self, tensors: Any, root: int = 0) -> Work:
@@ -199,7 +203,8 @@ class ProcessGroup:
     def alltoall(self, inputs: Sequence[Any]) -> Work:
         """``inputs``: per destination rank one array, or a list of arrays
         (as many for every rank) that travel in the one collective.
-        Result: per source rank what it sent here, in the same form."""
+        Result: per source rank what it sent here, in the same form; from
+        this rank itself the arrays handed in, not a copy of them."""
         raise NotImplementedError
 
     def barrier(self) -> Work:
@@ -284,7 +289,13 @@ class _WireAccount:
     a peer sends to its peers one after another, so the header for this
     rank is written only after the peer's ``sendall`` to the ranks before
     it, and a reader thread that was not running delays the stamp too.
-    ``send_s`` likewise holds the time the peer's reader took to drain."""
+    ``send_s`` likewise holds the time the peer's reader took to drain.
+    ``rx_fresh_bytes`` are the bytes of the buffers that had to be made to
+    receive this collective's large payloads (``_net.LARGE_FRAME`` and up),
+    because the connection's ``_RecvBuffers`` had no free buffer of the
+    size (a size's first message makes two): 0 once the payloads seen
+    have sized the buffers. A smaller frame is a new bytearray every time
+    and is not counted."""
 
     tx_bytes: int = 0
     rx_bytes: int = 0
@@ -293,10 +304,11 @@ class _WireAccount:
     peer_wait_s: float = 0.0
     recv_s: float = 0.0
     recv_cpu_s: float = 0.0
+    rx_fresh_bytes: int = 0
     messages: int = 0
 
     def fields(self) -> Dict[str, Any]:
-        """The seven fields ``pg_collective`` carries; none where no
+        """The eight fields ``pg_collective`` carries; none where no
         message moved over a ``_PeerConn`` (a collective that blocked in
         the C++ engine, or had no peer), so that a reader gives None and
         never 0."""
@@ -314,14 +326,102 @@ class _WireAccount:
 _wire = threading.local()
 
 
+class _RecvBuffers:
+    """Receive buffers of one connection, kept across collectives: a
+    payload-sized ``bytearray`` is mapped, touched for the first time page
+    by page and unmapped again for every message, which on the chip's host
+    costs the reader thread more than the copy out of the socket (a copy
+    into memory made for it ran at 0.9 GB/s there, into a kept buffer at
+    19; PERF.md section 6, PR 28).
+
+    Who may write what, and when (the rule of ``collectives._WireScratch
+    .result``): a buffer on the free list is nobody's. ``lend`` (the
+    connection's reader thread, nobody else) takes it off the list and
+    hands it out through an array of its own, the loan, which the reader
+    fills and ``_PeerConn.recv`` gives to its caller as a view. The buffer
+    comes back to the list only when the loan and every view of it are
+    gone (a ``weakref.finalize`` on the loan), so a buffer anyone can
+    still see, the reader that died mid-frame included (its memoryview
+    holds the loan), is never handed out again: a new one is made
+    instead, and counted (``_WireAccount.rx_fresh_bytes``).
+
+    Sizes come from the payloads seen: a message takes a free buffer of
+    exactly its size, the one that came back last, else a new one. The
+    first message of a size makes two, its own and a spare, because a
+    peer may be one message ahead of its reader's consumer (in a wire
+    turn the bucket's allgather chunk lands while the alltoall chunk is
+    still being summed, or the next bucket's while this one's is being
+    joined): so a training step, which sends the same few sizes every
+    time, finds every buffer it needs from each size's second message
+    on, and a small message never holds a large one's buffer. Bounded:
+    the list keeps ``KEEP_BYTES`` at most, letting the buffers that came
+    back longest ago go first, which a size nothing asks for again (a
+    healed state's leaves, a parameter server's reply) soon is; arrays a
+    caller keeps keep their buffers, which then simply never come back;
+    and ``drop`` lets go of the whole list (what is lent out at that
+    moment is freed with its loan): the group calls it wherever it drops
+    its wire scratch (reconfigure, abort, shutdown), so nothing outlives
+    the generation that received it."""
+
+    # A connection of mistral-ft4 keeps 0.11 GB (six sizes of up to 33
+    # MB, two of each); a step whose buckets need more than this goes on
+    # making some of its memory, as every step did before.
+    KEEP_BYTES = 1 << 29
+
+    def __init__(self) -> None:
+        self._free: List[np.ndarray] = []  # in the order they came back
+        self._seen: set = set()  # sizes that have had their spare
+
+    def lend(self, n: int) -> Tuple[Optional[np.ndarray], int]:
+        """(a writable uint8 array of exactly ``n`` bytes, the bytes of
+        the buffers that had to be made for it); (None, 0) for a frame
+        under ``_net.LARGE_FRAME``, which stays a bytearray of its own."""
+        if n < _net.LARGE_FRAME:
+            return None, 0
+        # By index and by size, never by value: ``list.remove`` would
+        # compare arrays. A buffer that comes back meanwhile is appended
+        # (a finalizer on whatever thread dropped the last view), so an
+        # index taken here stays good.
+        free = self._free
+        fit = next(
+            (i for i in reversed(range(len(free))) if free[i].size == n), None
+        )
+        if fit is not None:
+            owner, fresh = free.pop(fit), 0
+        else:
+            owner, fresh = np.empty(n, dtype=np.uint8), n
+            if n not in self._seen:
+                self._seen.add(n)
+                free.append(np.empty(n, dtype=np.uint8))
+                fresh += n
+        kept = sum(b.size for b in free)
+        while kept > self.KEEP_BYTES:
+            kept -= free.pop(0).size
+        # Views of ``loan`` name ``loan`` as their base, not ``owner``
+        # (numpy follows views down to the first array whose base is not an
+        # array), so ``loan`` dies with the last of them.
+        loan = np.frombuffer(owner.data, dtype=np.uint8)
+        weakref.finalize(loan, free.append, owner)
+        return loan, fresh
+
+    def drop(self) -> None:
+        """Forgets every free buffer and every size. Loans still out
+        return to the list that was, which nobody reads again."""
+        self._free = []
+        self._seen = set()
+
+
 class _PeerConn:
-    """One TCP connection to a peer rank with a tag-routing reader thread."""
+    """One TCP connection to a peer rank with a tag-routing reader thread.
+    Large payloads land in buffers the connection keeps (``buffers``)."""
 
     def __init__(
         self,
         sock: socket.socket,
         peer: int,
     ) -> None:
+        self.buffers = _RecvBuffers()
+        self._lent_fresh = 0
         # The connect/accept path may leave a short socket timeout armed; the
         # reader must block indefinitely on an IDLE connection (gaps between
         # collectives are unbounded, e.g. DiLoCo inner steps). Stall/death
@@ -352,6 +452,13 @@ class _PeerConn:
                 q = self._queues[tag] = queue_mod.Queue()
             return q
 
+    def _lend(self, n: int) -> Optional[np.ndarray]:
+        """Where the reader thread's next payload of ``n`` bytes lands
+        (``_net.recv_frame``'s ``dest``), and in ``_lent_fresh`` how many
+        of those bytes had to be made for it."""
+        buf, self._lent_fresh = self.buffers.lend(n)
+        return buf
+
     def _read_loop(self) -> None:
         try:
             while True:
@@ -362,7 +469,8 @@ class _PeerConn:
                 stamped = get_event_log() is not None
                 t_hdr = time.time() if stamped else 0.0
                 cpu0 = time.thread_time() if stamped else 0.0
-                payload = _net.recv_frame(self.sock)
+                payload = _net.recv_frame(self.sock, dest=self._lend)
+                fresh = self._lent_fresh
                 cpu_s = time.thread_time() - cpu0 if stamped else 0.0
                 add_bytes("pg_wire_rx", len(payload))
                 # Put under the lock so recv()'s delete-when-empty can never
@@ -401,7 +509,11 @@ class _PeerConn:
                     q = self._queues.get(tag)
                     if q is None:
                         q = self._queues[tag] = queue_mod.Queue()
-                    q.put((header, payload, t_hdr, cpu_s))
+                    q.put((header, payload, t_hdr, cpu_s, fresh))
+                # Nothing of the message stays with the reader: a loan it
+                # held while it blocked on the next header would keep the
+                # buffer from ever coming back.
+                del payload
         except Exception as e:  # noqa: BLE001 - propagate to all waiters
             self.dead = e if isinstance(e, Exception) else RuntimeError(str(e))
             with self._queues_lock:
@@ -515,7 +627,8 @@ class _PeerConn:
             if isinstance(item, _CollectiveAborted):
                 raise item
             raise RuntimeError(f"connection to rank {self.peer} died") from item
-        header, payload, t_hdr, cpu_s = item
+        header, payload, t_hdr, cpu_s, fresh = item
+        del item
         # Tags are single-use per message: drop the drained queue so a long
         # stable-quorum run doesn't accumulate one dead Queue per collective.
         with self._queues_lock:
@@ -530,13 +643,18 @@ class _PeerConn:
             acct.peer_wait_s += late
             acct.recv_s += waited - late
             acct.recv_cpu_s += cpu_s
-        # payload is a bytearray (writable buffer): frombuffer is already
-        # a mutable array over it, no copy needed.
+            acct.rx_fresh_bytes += fresh
+        # payload is a writable buffer: frombuffer is already a mutable
+        # array over it, no copy needed. A small one is a bytearray of its
+        # own; a large one is a loan of the connection's ``_RecvBuffers``,
+        # which the result keeps alive: the buffer is the caller's until
+        # the result and every view of it are gone.
         return np.frombuffer(payload, dtype=np.dtype(header["dtype"])).reshape(
             header["shape"]
         )
 
     def close(self) -> None:
+        self.buffers.drop()
         try:
             self.sock.shutdown(socket.SHUT_RDWR)
         except OSError:
@@ -722,6 +840,13 @@ class ProcessGroupSocket(ProcessGroup):
     def shutdown(self) -> None:
         self.abort(_dump=False)
 
+    def _drop_wire_scratch(self) -> None:
+        # The connections' kept receive buffers go with the wire scratch:
+        # the same memory for the same reason, let go at the same places.
+        super()._drop_wire_scratch()
+        for conn in list(self._peers.values()):
+            conn.buffers.drop()
+
     def errored(self) -> Optional[Exception]:
         return self._errored
 
@@ -905,7 +1030,7 @@ class ProcessGroupSocket(ProcessGroup):
 
         def run() -> List[List[np.ndarray]]:
             out: List[Optional[List[np.ndarray]]] = [None] * self._world
-            out[self._rank] = [a.copy() for a in arrays]
+            out[self._rank] = arrays  # the caller's own, not a copy
             for peer, conn in self._peers.items():
                 for i, a in enumerate(arrays):
                     conn.send(f"{tag}.{i}", a)
@@ -994,7 +1119,7 @@ class ProcessGroupSocket(ProcessGroup):
             # allgather tags its arrays.
             tags = [f"{tag}.{i}" for i in range(parts)] if nested else [tag]
             out: List[Optional[List[np.ndarray]]] = [None] * self._world
-            out[self._rank] = [a.copy() for a in per_rank[self._rank]]
+            out[self._rank] = per_rank[self._rank]  # the caller's own
             for peer, conn in self._peers.items():
                 for t, a in zip(tags, per_rank[peer]):
                     conn.send(t, a)
@@ -1502,7 +1627,7 @@ class ProcessGroupNative(ProcessGroupSocket):
             finally:
                 self._drain_flight_records(engine)
             out: List[Optional[List[np.ndarray]]] = [None] * self._world
-            out[self._rank] = [a.copy() for a in arrays]
+            out[self._rank] = arrays  # the caller's own, as the socket group's
             for p in range(self._world):
                 if p == self._rank:
                     continue

@@ -606,8 +606,9 @@ EVENT_KINDS: Dict[str, str] = {
     "pg_configure_failed": "process group configure attempt failed",
     "pg_collective": "process-group collective completed: op, nbytes, tag, "
                      "elapsed_s, queued_s, ok; over the Python sockets also "
-                     "tx_bytes/rx_bytes and send_s/peer_wait_s/recv_s with "
-                     "send_cpu_s/recv_cpu_s",
+                     "tx_bytes/rx_bytes, send_s/peer_wait_s/recv_s with "
+                     "send_cpu_s/recv_cpu_s, and rx_fresh_bytes (large "
+                     "payloads received into memory made for them)",
     "pg_abort": "process group aborted in-flight collectives",
     "pg_native_mesh": "native engine mesh established (peers, streams)",
     "native_collective": "native-engine flight-recorder record drained",
