@@ -33,6 +33,7 @@ from torchft_tpu.models import (  # noqa: E402
     olmo_hybrid_debug,
     olmoe_1b_7b,
     sdar_moe_debug,
+    solar_open2_debug,
 )
 from torchft_tpu.parallel import auto_mesh  # noqa: E402
 from torchft_tpu.parallel.train import (  # noqa: E402
@@ -53,6 +54,7 @@ SMALL = {
     "sdar_moe_debug": sdar_moe_debug,
     "joyai_flash_debug": joyai_flash_debug,
     "olmo_hybrid_debug": olmo_hybrid_debug,
+    "solar_open2_debug": solar_open2_debug,
     # the published preset cut to test widths, as tests/test_olmoe.py's TINY
     "olmoe_1b_7b": functools.partial(
         olmoe_1b_7b, hidden_size=64, intermediate_size=32, num_layers=2, num_heads=4,

@@ -12,7 +12,7 @@ from torchft_tpu.models.resnet import (  # noqa: F401
     resnet50,
     resnet101,
 )
-from torchft_tpu.models.gated_delta import GatedDeltaConfig  # noqa: F401
+from torchft_tpu.models.gated_delta import GatedDeltaConfig, KDAConfig  # noqa: F401
 from torchft_tpu.models.mamba2 import Mamba2Config  # noqa: F401
 from torchft_tpu.models.mla import MLAConfig  # noqa: F401
 from torchft_tpu.models.llama import (  # noqa: F401
@@ -33,6 +33,8 @@ from torchft_tpu.models.llama import (  # noqa: F401
     olmoe_1b_7b,
     sdar_30b_a3b,
     sdar_moe_debug,
+    solar_open2_250b,
+    solar_open2_debug,
 )
 
 # What ``train_hsdp.py --model`` names: each architecture's small preset, the 125M
@@ -47,4 +49,6 @@ PRESETS = {
     "sdar_moe": sdar_moe_debug,
     "joyai_flash": joyai_flash_debug,
     "olmo_hybrid": olmo_hybrid_debug,
+    "solar_open2_250b": solar_open2_250b,
+    "solar_open2_debug": solar_open2_debug,
 }

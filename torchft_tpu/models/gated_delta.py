@@ -32,6 +32,33 @@ and over the chunks, the state carried in float32::
 Every exponential is of a difference <= 0 in float32; the matrix
 multiplications take operands in the compute type and accumulate in
 float32. T is float32 throughout (``unit_lower_inverse``).
+
+Kimi Delta Attention (Kimi Linear arXiv:2510.26692; flash-linear-attention's
+``KimiDeltaAttention``, whose ``linear_attn_config`` / ``kda_*`` keys
+Solar-Open2's config.json carries) is the same rule with a decay for every
+KEY CHANNEL, alpha_t in (0,1)^d a head::
+
+    S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T
+
+and another mixer around it (``KimiDeltaMixer``: low-rank decay and gate
+projections, a sigmoid-gated norm, keys and values of one width).
+``kda_chunked`` is its chunked form in plain XLA, the contract a fused
+kernel would have to meet. With G [C, d] the cumulative log-decays of a
+chunk, Gamma no longer factors out of the products::
+
+    A_ij = beta_i sum_c k_ic k_jc exp(G_ic - G_jc)        i > j
+    P_ij =        sum_c q_ic k_jc exp(G_ic - G_jc)        i >= j
+    W = T (beta K * e^G),  U = T (beta V),  V' = U - W S
+    O = (Q * e^G) S + P V';   S <- Diag(e^{G_C}) S + (K * e^{G_C - G})^T V'
+
+and (k_i e^{G_i}) . (k_j e^{-G_j}) overflows float32 inside one chunk (64
+steps of a log-decay of -1.6, the mixer's own strongest initial value, are
+-102), so the chunk is cut into sub-blocks of ``SUB`` = 16 rows
+(flash-linear-attention's cut): a sub-block's rows against EARLIER
+sub-blocks' columns are a matmul of (x_i e^{G_i - G_r}) with
+(k_j e^{G_r - G_j}), r the sub-block's first row, both exponents <= 0; the
+16 x 16 diagonal sub-blocks take exp(G_ic - G_jc) channel by channel, no
+matmul. Precisions as above.
 """
 
 from __future__ import annotations
@@ -103,6 +130,30 @@ class GatedDeltaConfig:
         return 2 * self.key_dim + self.value_dim
 
 
+@dataclasses.dataclass(frozen=True)
+class KDAConfig:
+    """Kimi Delta Attention's sizes. ``num_heads`` are the heads HELD (as
+    ``GatedDeltaConfig``'s); keys and values are ``head_dim`` wide, and so
+    is the bottleneck of the two low-rank projections."""
+
+    num_heads: int = 64
+    head_dim: int = 128
+    conv_kernel: int = 4
+    allow_neg_eigval: bool = True
+    # dt_bias (a key channel) and A_log (a head) start as the Mamba-2 mixer's.
+    dt_min: float = 0.001
+    dt_max: float = 0.1
+    dt_floor: float = 1e-4
+
+    @property
+    def key_dim(self) -> int:
+        return self.num_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return 3 * self.key_dim
+
+
 @jax.custom_vjp
 def unit_lower_inverse(a: jax.Array) -> jax.Array:
     """(I + a)^-1 for strictly lower-triangular ``a`` [..., C, C], float32:
@@ -137,6 +188,44 @@ def _inverse_bwd(t, dt):
 unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
 
 
+def _states_and_outputs(w, u, k_end, decay, q_in, scores, dtype: Dtype):
+    """What both chunked rules do once a chunk's algebra is done. The
+    recurrence over the chunk states, the state carried in float32: what
+    enters each chunk and the chunk's corrected values V' = U - W S,
+    S <- decay * S + K_end^T V'; then the outputs of all chunks at once,
+    O = Q_in S + scores V'. w, k_end, q_in: [B, nc, C, H, d_k] and scores
+    [B, nc, H, C, C] in the compute type; u [B, nc, C, H, d_v] float32;
+    ``decay`` [B, nc, H, 1 | d_k] float32, the factor of a state's rows
+    over a whole chunk. Returns (o [B, nc, C, H, d_v] float32, the last
+    state [B, H, d_k, d_v])."""
+    f32 = jnp.float32
+    bsz, _, _, heads, dk = w.shape
+
+    def carry_state(state, inputs):
+        w_c, u_c, k_c, decay_c = inputs
+        v_new = u_c - jnp.einsum(
+            "bihd,bhde->bihe", w_c, state.astype(dtype), preferred_element_type=f32
+        )
+        new = decay_c[..., None] * state + jnp.einsum(
+            "bihd,bihe->bhde", k_c, v_new.astype(dtype), preferred_element_type=f32
+        )
+        return new, (state.astype(dtype), v_new.astype(dtype))
+
+    chunks_first = lambda x: jnp.moveaxis(x, 1, 0)  # noqa: E731
+    last, (entering, v_new) = jax.lax.scan(
+        carry_state, jnp.zeros((bsz, heads, dk, u.shape[-1]), f32),
+        (chunks_first(w), chunks_first(u), chunks_first(k_end), chunks_first(decay)),
+    )
+    entering = jnp.moveaxis(entering, 0, 1)  # [B, nc, H, d_k, d_v]
+    v_new = jnp.moveaxis(v_new, 0, 1)  # [B, nc, C, H, d_v]
+    o = jnp.einsum(
+        "bchij,bcjhe->bcihe", scores, v_new, preferred_element_type=f32,
+    ) + jnp.einsum(
+        "bcihd,bchde->bcihe", q_in, entering, preferred_element_type=f32,
+    )
+    return o, last
+
+
 def gated_delta_chunked(
     q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array,
     chunk: int, dtype: Dtype,
@@ -147,7 +236,7 @@ def gated_delta_chunked(
     the state after the last position [B, H, d_k, d_v]. A sequence that is
     no multiple of ``chunk`` is padded with steps of beta = 0, g = 0, which
     neither decay nor write the state."""
-    bsz, seq, heads, dk = q.shape
+    bsz, seq, heads, _ = q.shape
     dv = v.shape[-1]
     pad = -seq % chunk
     if pad:
@@ -185,37 +274,128 @@ def gated_delta_chunked(
     whole = cum[:, :, -1]  # G_C: [B, nc, H]
     k_end = (kc * jnp.exp(whole[:, :, None] - cum)[..., None]).astype(dtype)
 
-    # The recurrence over the chunk states: what enters each chunk, and
-    # the chunk's corrected values V' = U - W S.
-    def carry_state(state, inputs):
-        w_c, u_c, k_c, decay_c = inputs
-        v_new = u_c - jnp.einsum(
-            "bihd,bhde->bihe", w_c, state.astype(dtype), preferred_element_type=f32
-        )
-        new = decay_c[..., None, None] * state + jnp.einsum(
-            "bihd,bihe->bhde", k_c, v_new.astype(dtype), preferred_element_type=f32
-        )
-        return new, (state.astype(dtype), v_new.astype(dtype))
-
-    chunks_first = lambda x: jnp.moveaxis(x, 1, 0)  # noqa: E731
-    last, (entering, v_new) = jax.lax.scan(
-        carry_state, jnp.zeros((bsz, heads, dk, dv), f32),
-        (chunks_first(w.astype(dtype)), chunks_first(u), chunks_first(k_end),
-         chunks_first(jnp.exp(whole))),
+    qk = jnp.einsum(
+        "bcihd,bcjhd->bchij", qc.astype(dtype), kd, preferred_element_type=f32
     )
-    entering = jnp.moveaxis(entering, 0, 1)  # [B, nc, H, d_k, d_v]
-    v_new = jnp.moveaxis(v_new, 0, 1)  # [B, nc, C, H, d_v]
-
-    qd = qc.astype(dtype)
-    qk = jnp.einsum("bcihd,bcjhd->bchij", qd, kd, preferred_element_type=f32)
-    o = jnp.einsum(
-        "bchij,bcjhe->bcihe", (qk * gamma).astype(dtype), v_new,
-        preferred_element_type=f32,
-    ) + jnp.einsum(
-        "bcihd,bchde->bcihe", (qc * into).astype(dtype), entering,
-        preferred_element_type=f32,
+    o, last = _states_and_outputs(
+        w.astype(dtype), u, k_end, jnp.exp(whole)[..., None],
+        (qc * into).astype(dtype), (qk * gamma).astype(dtype), dtype,
     )
     return o.reshape(bsz, nc * chunk, heads, dv)[:, :seq], last
+
+
+# Kimi Delta Attention's sub-block inside a chunk (flash-linear-attention's):
+# one value is in use, so it is a constant and no field of the configuration.
+SUB = 16
+
+
+def _channel_decayed_products(xs, k, cum, sub: int, dtype: Dtype):
+    """For each x of ``xs``: P_ij = sum_c x_ic k_jc exp(G_ic - G_jc) where
+    i >= j and 0 above the diagonal, [B, nc, H, C, C] float32. x, k and the
+    cumulative log-decays ``cum`` (G) are [B, nc, C, H, d] float32. Every
+    exponential is of a difference <= 0: the module's text says how the
+    sub-blocks of ``sub`` rows arrange that."""
+    bsz, nc, chunk, heads, d = k.shape
+    ns, f32 = chunk // sub, jnp.float32
+    blocks = lambda t: t.reshape(bsz, nc, ns, sub, heads, d)  # noqa: E731
+    ks, gs = blocks(k), blocks(cum)
+    keep = jnp.tril(jnp.ones((sub, sub), bool))[:, :, None, None]
+    within = jnp.exp(jnp.where(
+        keep, gs[:, :, :, :, None] - gs[:, :, :, None, :], -jnp.inf
+    ))  # [B, nc, ns, i, j, H, d]: the diagonal sub-blocks' own decays
+    first = gs[:, :, :, 0]  # G at each sub-block's first row: [B, nc, ns, H, d]
+    to_first = jnp.exp(gs - first[:, :, :, None])  # rows decayed back to it
+    # Columns before sub-block I, decayed up to I's first row.
+    earlier = [
+        (k[:, :, : i * sub] * jnp.exp(first[:, :, i, None] - cum[:, :, : i * sub]))
+        .astype(dtype)
+        for i in range(1, ns)
+    ]
+    out = []
+    for x in xs:
+        xb = blocks(x)
+        diagonal = jnp.moveaxis(
+            jnp.sum(xb[:, :, :, :, None] * ks[:, :, :, None, :] * within, axis=-1),
+            -1, 2,
+        )  # [B, nc, H, ns, i, j]
+        rows_rel = (xb * to_first).astype(dtype)
+        rows = []
+        for i in range(ns):
+            parts = [diagonal[:, :, :, i]]
+            if i:
+                parts.insert(0, jnp.einsum(
+                    "bcihd,bcjhd->bchij", rows_rel[:, :, i], earlier[i - 1],
+                    preferred_element_type=f32,
+                ))
+            if i < ns - 1:
+                parts.append(
+                    jnp.zeros((bsz, nc, heads, sub, chunk - (i + 1) * sub), f32)
+                )
+            rows.append(jnp.concatenate(parts, axis=-1))
+        out.append(jnp.concatenate(rows, axis=-2))
+    return out
+
+
+def kda_chunked(
+    q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array,
+    chunk: int, dtype: Dtype,
+) -> Tuple[jax.Array, jax.Array]:
+    """The delta rule with a decay a key channel, chunked. q, k:
+    [B, S, H, d_k], normalised (q scaled); v: [B, S, H, d_v]; g:
+    [B, S, H, d_k] the log-decays (<= 0) and beta: [B, S, H], both float32.
+    Returns o [B, S, H, d_v] in float32 and the state after the last
+    position [B, H, d_k, d_v]. ``chunk`` is a multiple of ``SUB`` or less
+    than it; a sequence that is no multiple of ``chunk`` is padded with
+    steps of beta = 0, g = 0, which neither decay nor write the state."""
+    bsz, seq, heads, _ = q.shape
+    dv = v.shape[-1]
+    sub = min(SUB, chunk)
+    if g.shape != q.shape or chunk % sub:
+        raise ValueError(
+            f"kda_chunked: a decay of shape {g.shape} for keys {q.shape} in "
+            f"chunks of {chunk} (sub-blocks of {SUB})"
+        )
+    pad = -seq % chunk
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in (q, k, v, g, beta)
+        )
+    nc = (seq + pad) // chunk
+    f32 = jnp.float32
+    by_chunk = lambda t: t.reshape(bsz, nc, chunk, heads, *t.shape[3:])  # noqa: E731
+    qc, kc, vc = (by_chunk(t).astype(f32) for t in (q, k, v))
+    beta = by_chunk(beta.astype(f32))
+    cum = jnp.cumsum(by_chunk(g.astype(f32)), axis=2)  # G: [B, nc, C, H, d_k]
+    kk, qk = _channel_decayed_products((kc, qc), kc, cum, sub, dtype)
+    strictly_lower = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+    a = jnp.where(strictly_lower, jnp.moveaxis(beta, 2, -1)[..., None] * kk, 0.0)
+    t = unit_lower_inverse(a).astype(dtype)  # [B, nc, H, C, C]
+    into = jnp.exp(cum)  # e^G: the entering state's decay to row i, a channel
+    w = jnp.einsum(
+        "bchij,bcjhd->bcihd", t, (beta[..., None] * into * kc).astype(dtype),
+        preferred_element_type=f32,
+    )
+    u = jnp.einsum(
+        "bchij,bcjhd->bcihd", t, (beta[..., None] * vc).astype(dtype),
+        preferred_element_type=f32,
+    )
+    whole = cum[:, :, -1]  # G_C: [B, nc, H, d_k]
+    k_end = (kc * jnp.exp(whole[:, :, None] - cum)).astype(dtype)
+
+    # The gated-delta rule's scan, the state's ROWS decayed each by its own
+    # channel's e^{G_C}.
+    o, last = _states_and_outputs(
+        w.astype(dtype), u, k_end, jnp.exp(whole), (qc * into).astype(dtype),
+        qk.astype(dtype), dtype,
+    )
+    return o.reshape(bsz, nc * chunk, heads, dv)[:, :seq], last
+
+
+def _unit(t: jax.Array) -> jax.Array:
+    """A head's vector [..., d] over its length, in float32."""
+    t = t.astype(jnp.float32)
+    return t * jax.lax.rsqrt(jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
 
 
 class GatedDeltaMixer(nn.Module):
@@ -249,13 +429,8 @@ class GatedDeltaMixer(nn.Module):
         a_log = self.param("A_log", _a_log_init, (heads,), self.param_dtype)
         dt_bias = self.param("dt_bias", _dt_bias_init(m), (heads,), self.param_dtype)
         with jax.named_scope("gated_delta/rule"):
-            def unit(t):  # a head's vector over its length, in float32
-                t = t.reshape(bsz, seq, heads, dk).astype(f32)
-                return t * jax.lax.rsqrt(
-                    jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6
-                )
-
-            q, k = unit(q) * dk ** -0.5, unit(k)
+            by_head = lambda t: t.reshape(bsz, seq, heads, dk)  # noqa: E731
+            q, k = _unit(by_head(q)) * dk ** -0.5, _unit(by_head(k))
             beta = jax.nn.sigmoid(dense(heads, "b_proj")(x).astype(f32))
             if m.allow_neg_eigval:
                 beta = 2.0 * beta
@@ -284,3 +459,82 @@ class GatedDeltaMixer(nn.Module):
             )
             y = (o * weight.astype(f32) * nn.silu(gate)).astype(self.dtype)
         return dense(self.hidden_size, "o_proj")(y.reshape(bsz, seq, m.value_dim))
+
+
+class KimiDeltaMixer(nn.Module):
+    """Kimi Delta Attention (flash-linear-attention's ``KimiDeltaAttention``)
+    for x [B, S, hidden], H held heads of d = ``head_dim``::
+
+        [q | k | v] = silu(conv1d_causal_depthwise([x W_q | x W_k | x W_v]))
+        q = q / |q|_2 / sqrt(d),  k = k / |k|_2                       a head
+        g = -exp(A_log_h) softplus(W_f_b W_f_a x + dt_bias)   in R^d, <= 0
+        beta = sigmoid(x W_b)             (x 2 where ``allow_neg_eigval``)
+        o = the delta rule over (q, k, v, g, beta)           ``kda_chunked``
+        y = W_o concat_h[RMSNorm_d(o) * w * sigmoid(W_g_b W_g_a x + b_g)]
+
+    W_f_a and W_g_a go down to d channels (one bottleneck for all heads),
+    W_f_b and W_g_b up to H d; dt_bias is a key channel's, A_log a head's."""
+
+    m: KDAConfig
+    hidden_size: int
+    norm_eps: float = 1e-5
+    dtype: Dtype = jnp.bfloat16
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        m, f32 = self.m, jnp.float32
+        bsz, seq, _ = x.shape
+        heads, d = m.num_heads, m.head_dim
+        dense = lambda f, name, bias=False: nn.Dense(  # noqa: E731
+            f, use_bias=bias, dtype=self.dtype, param_dtype=self.param_dtype,
+            name=name,
+        )
+        by_head = lambda t: t.reshape(bsz, seq, heads, d)  # noqa: E731
+        qkv = jnp.concatenate(
+            [dense(m.key_dim, name)(x) for name in ("q_proj", "k_proj", "v_proj")],
+            axis=-1,
+        )
+        with jax.named_scope("kda/conv"):
+            kernel = self.param(
+                "conv_kernel", conv_kernel_init(m.conv_kernel),
+                (m.conv_kernel, m.conv_dim), self.param_dtype,
+            )
+            qkv = nn.silu(causal_conv1d(qkv, kernel)).astype(self.dtype)
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+
+        a_log = self.param("A_log", _a_log_init, (heads,), self.param_dtype)
+        dt_bias = self.param(
+            "dt_bias", _dt_bias_init(m), (m.key_dim,), self.param_dtype
+        )
+        with jax.named_scope("kda/gates"):
+            step = by_head(
+                dense(m.key_dim, "f_b_proj")(dense(d, "f_a_proj")(x)).astype(f32)
+                + dt_bias.astype(f32)
+            )
+            g = -jnp.exp(a_log.astype(f32))[:, None] * jax.nn.softplus(step)
+            beta = jax.nn.sigmoid(dense(heads, "b_proj")(x).astype(f32))
+            if m.allow_neg_eigval:
+                beta = 2.0 * beta
+        with jax.named_scope("kda/rule"):
+            _note("kda-xla", CHUNK, seq)
+            o, state = kda_chunked(
+                _unit(by_head(q)) * d ** -0.5, _unit(by_head(k)), by_head(v),
+                g, beta, CHUNK, self.dtype,
+            )
+            self.sow("intermediates", "kda_state_abs_max", jnp.max(jnp.abs(state)))
+            self.sow("intermediates", "kda_decay_min", jnp.exp(jnp.min(g)))
+            self.sow("intermediates", "kda_beta_mean", jnp.mean(beta))
+
+        with jax.named_scope("kda/gated_norm"):
+            weight = self.param(
+                "norm_scale", nn.initializers.ones, (d,), self.param_dtype
+            )
+            gate = by_head(
+                dense(m.key_dim, "g_b_proj", bias=True)(dense(d, "g_a_proj")(x))
+            ).astype(f32)
+            o = o * jax.lax.rsqrt(
+                jnp.mean(jnp.square(o), axis=-1, keepdims=True) + self.norm_eps
+            )
+            y = (o * weight.astype(f32) * jax.nn.sigmoid(gate)).astype(self.dtype)
+        return dense(self.hidden_size, "o_proj")(y.reshape(bsz, seq, m.key_dim))

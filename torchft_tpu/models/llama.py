@@ -29,7 +29,12 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from torchft_tpu.models.gated_delta import GatedDeltaConfig, GatedDeltaMixer
+from torchft_tpu.models.gated_delta import (
+    GatedDeltaConfig,
+    GatedDeltaMixer,
+    KDAConfig,
+    KimiDeltaMixer,
+)
 from torchft_tpu.models.mamba2 import Mamba2Config, Mamba2Mixer, conv_kernel_init
 from torchft_tpu.models.mla import LatentAttention, MLAConfig
 
@@ -118,20 +123,27 @@ class LlamaConfig:
     # False: attention without rotary embeddings (a hybrid whose
     # state-space layers carry the order, Nemotron-H).
     rope: bool = True
+    # True: the attention's output is gated elementwise before W_o,
+    # W_o(Y * sigmoid(x W_gate)), x the layer's (normed) input and W_gate
+    # as wide as W_q (arXiv:2505.06708's head-specific elementwise gate;
+    # Solar-Open2's ``use_gqa_gate``).
+    attn_gate: bool = False
     # A stack of unlike layers, one character a layer, each layer ONE mixer
     # between a pre-norm and the residual add: 'M' a Mamba-2 mixer
     # (``mamba``), 'E' the expert layer, '*' attention (rotary where
     # ``rope``; latent attention where ``mla`` is set, models/mla.py),
     # 'C' a gated short convolution of ``SHORT_CONV_TAPS`` taps,
     # 'G' a gated-delta linear attention (``gated_delta``,
-    # models/gated_delta.py), 'D' a dense SwiGLU feed-forward. A model whose
-    # published layer is an operator and a feed-forward is two characters
-    # a layer ("CD", "*E", "GD").
+    # models/gated_delta.py), 'K' a Kimi delta attention (``kda``, the same
+    # file: a decay a key channel), 'D' a dense SwiGLU feed-forward. A model
+    # whose published layer is an operator and a feed-forward is two
+    # characters a layer ("CD", "*E", "GD", "KE").
     # None: ``num_layers`` scanned blocks of attention + MLP.
     layer_pattern: Optional[str] = None
     mamba: Optional[Mamba2Config] = None
     mla: Optional[MLAConfig] = None
     gated_delta: Optional[GatedDeltaConfig] = None
+    kda: Optional[KDAConfig] = None
     # Where a ``layer_pattern`` layer's RMSNorm stands. False: before the
     # mixer, x + mixer(norm(x)). True: after it, x + norm(mixer(x)), the
     # residual stream itself feeding the mixer (OLMo 2's layer,
@@ -581,6 +593,73 @@ def olmo_hybrid_debug(**overrides: Any) -> LlamaConfig:
     return dataclasses.replace(cfg, **overrides)
 
 
+def solar_open2_250b(**overrides: Any) -> LlamaConfig:
+    """Solar-Open2-250B (upstage/Solar-Open2-250B config.json, model_type
+    ``solar_open2``, 250B-A15B; Kimi Delta Attention arXiv:2510.26692, the
+    attention gate arXiv:2505.06708) at its published sizes: 48 layers of a
+    mixer and an expert layer each, every sub-layer pre-normed; the mixer a
+    rope-free GQA attention (64 heads on 8 of width 128) with an elementwise
+    sigmoid output gate at layers 0, 4, 8, ... and a Kimi delta attention
+    (64 heads, keys and values of 128, a 4-tap convolution, a decay a key
+    channel, beta up to 2) at the other three of every four; 320 SiLU-gated
+    experts of width 1280, 8 a token by sigmoid scores plus a selection
+    bias that nothing moves (the published file names no rate), gates
+    renormalised, one shared expert; an untied 196,608-row head. Override
+    ``layer_pattern``, the heads, ``experts_held`` and ``vocab_size`` for
+    what one chip holds. The router's form, the gate's and the mixer's
+    low-rank projections are not keys of the published file
+    (benchmark/configs/solar-open2-250b-l4h8e8.json, ``assumed``)."""
+    cfg = LlamaConfig(
+        vocab_size=196608,
+        hidden_size=4096,
+        intermediate_size=1280,
+        num_layers=48,
+        # Two characters a published layer: its mixer, its expert layer.
+        layer_pattern="*EKEKEKE" * 12,
+        num_heads=64,
+        num_kv_heads=8,
+        head_dim=128,
+        max_seq_len=1048576,
+        norm_eps=1e-5,
+        rope=False,
+        attn_gate=True,
+        kda=KDAConfig(),
+        num_experts=320,
+        num_experts_per_tok=8,
+        expert_capacity_factor=None,
+        router_score="sigmoid",
+        routed_scaling=1.0,
+        shared_expert_size=1280,
+        router_aux_coef=0.0,
+        router_z_coef=0.0,
+    )
+    return dataclasses.replace(cfg, **overrides)
+
+
+def solar_open2_debug(**overrides: Any) -> LlamaConfig:
+    """Tiny Solar-Open2 (one period: a gated attention and three Kimi delta
+    attentions, an expert layer after each; 16 experts of which 4 are held)
+    for tests and ``train_hsdp.py --model solar_open2_debug``."""
+    cfg = solar_open2_250b(
+        vocab_size=256,
+        hidden_size=64,
+        intermediate_size=48,
+        num_layers=4,
+        layer_pattern="*EKEKEKE",
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        max_seq_len=128,
+        kda=KDAConfig(num_heads=4, head_dim=16),
+        num_experts=16,
+        num_experts_per_tok=3,
+        experts_held=(0, 4),
+        shared_expert_size=48,
+        remat=False,
+    )
+    return dataclasses.replace(cfg, **overrides)
+
+
 def llama_moe_debug(**overrides: Any) -> LlamaConfig:
     """Tiny MoE config (4 experts, top-2) for tests and the ep dryrun."""
     cfg = llama_debug(num_experts=4, num_experts_per_tok=2)
@@ -802,6 +881,10 @@ class Attention(nn.Module):
         else:
             _note_attention(cfg.attn_impl, "dense", q.shape[1])
             out = dense_attention(q, k, v)
+        if cfg.attn_gate:
+            with jax.named_scope("attention/gate"):
+                gate = dense(cfg.num_heads, "wg")(x).astype(jnp.float32)
+                out = (out.astype(jnp.float32) * jax.nn.sigmoid(gate)).astype(cfg.dtype)
         return nn.DenseGeneral(
             features=cfg.hidden_size,
             axis=(-2, -1),
@@ -935,15 +1018,25 @@ _combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
 # The held dispatch's static row buffer, as a multiple of the rows a
 # uniform router sends to the experts held (MoEMLP._sorted_held says why 4).
 HELD_ROW_FACTOR = 4.0
+# From this many rows up the buffer is a whole number of tiles of as many:
+# XLA's grouped matmul takes its row tile from the divisors of the buffer's
+# length. Over 13,112 rows (8 x 11 x 149) it ran at 4 TFLOP/s on a v5e, over
+# 13,312 (26 x 512) at 90, the same 8 groups of [4096, 1280] weights (my chip
+# run, PR 58). The buffers of the cells before it are multiples already.
+HELD_ROW_TILE = 512
 
 
 def held_buffer_rows(cfg: LlamaConfig, tokens: int) -> int:
     """Rows of the held dispatch's static buffer for ``tokens`` tokens a
     step: ``HELD_ROW_FACTOR`` times the uniform router's share of the
-    T*K assignments, a multiple of 8, T*K at most."""
+    T*K assignments, a multiple of 8 and, from ``HELD_ROW_TILE`` rows up, of
+    that; T*K at most."""
     assignments = tokens * cfg.num_experts_per_tok
     share = -(-assignments * cfg.experts_held[1] // cfg.num_experts)
-    return min(assignments, -(-int(HELD_ROW_FACTOR * share) // 8) * 8)
+    rows = -(-int(HELD_ROW_FACTOR * share) // 8) * 8
+    if rows >= HELD_ROW_TILE:
+        rows = -(-rows // HELD_ROW_TILE) * HELD_ROW_TILE
+    return min(assignments, rows)
 
 
 class MoEMLP(nn.Module):
@@ -1148,7 +1241,8 @@ class MoEMLP(nn.Module):
         have added is left out.
 
         R is static: a uniform router sends T*K*count/E rows here, and R is
-        ``HELD_ROW_FACTOR`` (4) times that, T*K at most. A full T*K
+        ``HELD_ROW_FACTOR`` (4) times that in whole row tiles
+        (``held_buffer_rows``), T*K at most. A full T*K
         buffer would make every grouped matmul E/count (16) times longer
         for rows that are never filled; four times the uniform share is
         far above what a router under its balance term sends (the sown
@@ -1340,6 +1434,11 @@ class MixerLayer(nn.Module):
                 cfg.gated_delta, cfg.hidden_size, cfg.norm_eps,
                 cfg.dtype, cfg.param_dtype, name="gdn",
             )(h))
+        if self.kind == "K":
+            return x + after(KimiDeltaMixer(
+                cfg.kda, cfg.hidden_size, cfg.norm_eps,
+                cfg.dtype, cfg.param_dtype, name="kda",
+            )(h))
         if self.kind == "E":
             return x + after(MoEMLP(cfg, name="mlp")(h))
         if self.kind == "D":
@@ -1350,7 +1449,7 @@ class MixerLayer(nn.Module):
             mixer = Attention if cfg.mla is None else LatentAttention
             return x + after(mixer(cfg, name="attn")(h, cos, sin))
         raise ValueError(
-            f"layer kind {self.kind!r} is none of 'M', 'G', 'E', 'D', 'C', '*'"
+            f"layer kind {self.kind!r} is none of 'M', 'G', 'K', 'E', 'D', 'C', '*'"
         )
 
 

@@ -93,15 +93,25 @@ def meant_for(d_k: int, d_v: int) -> bool:
     return d_k % 16 == 0 and d_v % 16 == 0
 
 
-def supports(chunk: int, d_k: int, d_v: int, heads: int, seq: int) -> bool:
+def supports(
+    chunk: int, d_k: int, d_v: int, heads: int, seq: int,
+    channel_decay: bool = False,
+) -> bool:
     """Whether the kernels take these shapes (the caller runs
     ``gated_delta_chunked`` otherwise): the chunk of 64 that halves a lane
     tile, widths they are ``meant_for``, and a head's state, its gradient
     and their second orientation within what Mosaic has compiled for a v5e
     (``tests/test_tpu_compile.py``: 16 / 16, 96 / 192 and 128 / 256). Any
-    number of heads and any sequence: a ragged end is padded."""
+    number of heads and any sequence: a ragged end is padded. ONE decay a
+    head and step: the kernels build exp(G_i - G_j) as one C x C matrix a
+    head, which a decay a key channel (``channel_decay``: Kimi Delta
+    Attention's g [B, S, H, d_k]) does not factor into, so that is refused
+    whatever the widths (the caller runs ``kda_chunked``)."""
     del heads, seq
-    return chunk == CHUNK and meant_for(d_k, d_v) and d_k <= 128 and d_v <= 256
+    return (
+        not channel_decay and chunk == CHUNK and meant_for(d_k, d_v)
+        and d_k <= 128 and d_v <= 256
+    )
 
 
 def _dot(a, b, dims=(((1,), (0,)), ((), ())), precision=None):
@@ -607,10 +617,11 @@ def gated_delta(
     inputs. The caller has asked ``supports``."""
     bsz, seq, heads, dk = q.shape
     dv = v.shape[-1]
-    if not supports(chunk, dk, dv, heads, seq):
+    if not supports(chunk, dk, dv, heads, seq, channel_decay=g.ndim != 3):
         raise ValueError(
-            f"gated_delta: chunk {chunk}, keys of {dk}, values of {dv} are "
-            "not the kernels' shapes; use gated_delta_chunked"
+            f"gated_delta: chunk {chunk}, keys of {dk}, values of {dv}, a "
+            f"decay of shape {g.shape} are not the kernels' shapes; use "
+            "gated_delta_chunked (kda_chunked for a decay a key channel)"
         )
     pad = -seq % _PAIR
     if pad:

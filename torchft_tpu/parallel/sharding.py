@@ -69,6 +69,16 @@ _RULES: Dict[Tuple[str, str], Tuple[Any, ...]] = {
     ("a_proj", "kernel"): ("fsdp", "tp"),
     ("b_proj", "kernel"): ("fsdp", "tp"),
     ("o_proj", "kernel"): ("tp", "fsdp"),
+    # The Kimi delta mixer (same file) has those names for its q, k, v,
+    # beta and output projections; its two low-rank pairs go down to one
+    # bottleneck for all heads (whole, like latent attention's) and up to
+    # the heads over ``tp``; g_b_proj's bias, dt_bias (a key channel) and
+    # the rest replicate. The attention's output gate shards as wq does.
+    ("f_a_proj", "kernel"): ("fsdp", None),
+    ("g_a_proj", "kernel"): ("fsdp", None),
+    ("f_b_proj", "kernel"): (None, "tp"),
+    ("g_b_proj", "kernel"): (None, "tp"),
+    ("wg", "kernel"): ("fsdp", "tp", None),
     # The gated short-convolution mixer (``conv``) has the same two names:
     # its in_proj's [H, 3H] output splits into thirds that GSPMD re-shards
     # where ``tp`` cuts across them; its depthwise ``conv_kernel`` [taps, H]

@@ -172,11 +172,12 @@ def _lm_head_projection(model: Transformer, params):
 
 
 def _apply_with_aux(model: Transformer, params, inputs, **kw):
-    """model.apply + what MoEMLP and the gated-delta mixer sow per layer,
-    by name and stacked over the layers ({} for a model with neither):
-    ``router_aux``, ``router_z``, ``moe_max_load``, ``moe_dropped``; the
-    ``gdn_*`` counters."""
-    if model.cfg.num_experts <= 0 and model.cfg.gated_delta is None:
+    """model.apply + what MoEMLP and the delta-rule mixers sow per layer,
+    by name and stacked over the layers ({} for a model with none of
+    them): ``router_aux``, ``router_z``, ``moe_max_load``, ``moe_dropped``;
+    the ``gdn_*`` and ``kda_*`` counters."""
+    cfg = model.cfg
+    if cfg.num_experts <= 0 and cfg.gated_delta is None and cfg.kda is None:
         return model.apply({"params": params}, inputs, **kw), {}
     out, inter = model.apply(
         {"params": params}, inputs, mutable=["intermediates"], **kw
@@ -208,6 +209,11 @@ _SOWN_OVER_LAYERS = (
     ("gdn_state_abs_max", jnp.max),
     ("gdn_decay_min", jnp.min),
     ("gdn_beta_mean", jnp.mean),
+    # The same three from the Kimi delta mixers, whose decay is a key
+    # channel's: the smallest is over every channel of the step.
+    ("kda_state_abs_max", jnp.max),
+    ("kda_decay_min", jnp.min),
+    ("kda_beta_mean", jnp.mean),
     # Not a scalar: every expert's assignments, layer after layer in the
     # parameter tree's order, for the selection-bias update of
     # ``make_train_step`` or of a loop around ``make_grad_step`` (sown
@@ -429,7 +435,8 @@ def _loss_and_metrics(model: Transformer, params, inputs, targets, mask):
     ``moe_load``, under block diffusion ``diffusion_masked_share``
     (masked data positions over data positions) and, sown by its
     attention, ``bd_kept_share``; from gated-delta mixers
-    ``gdn_state_abs_max``, ``gdn_decay_min`` and ``gdn_beta_mean``."""
+    ``gdn_state_abs_max``, ``gdn_decay_min`` and ``gdn_beta_mean``, from
+    Kimi delta mixers the same three as ``kda_*``."""
     cfg = model.cfg
     B, S = inputs.shape
     C = min(_LOSS_CHUNK, S) if _LOSS_CHUNK > 0 else loss_chunk(
