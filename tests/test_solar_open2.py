@@ -32,6 +32,7 @@ from torchft_tpu.models.llama import (
     solar_open2_debug,
 )
 from torchft_tpu.ops import gated_delta as gdn_kernel
+from torchft_tpu.ops import kda as kda_kernel
 from torchft_tpu.parallel import auto_mesh, make_mesh
 from torchft_tpu.parallel.sharding import param_specs
 from torchft_tpu.parallel.train import (
@@ -135,13 +136,16 @@ def test_the_chunked_kda_refuses_shapes_it_does_not_compute():
         kda_chunked(q, key, v, g, beta, 24, jnp.float32)  # no whole sub-blocks
 
 
-@pytest.mark.parametrize("dk,dv", [(16, 16), (96, 192), (128, 128), (128, 256)])
-def test_the_scalar_decay_kernels_refuse_a_decay_a_channel(dk, dv):
-    """At every width the kernels take, ``supports`` says no to a decay a
-    key channel, and the kernels' entry point raises for a g [B, S, H, d]
-    instead of reading it as one decay a head."""
+@pytest.mark.parametrize("dk,dv,channel", [
+    (16, 16, True), (96, 192, False), (128, 128, True), (128, 256, False),
+])
+def test_the_scalar_decay_kernels_refuse_a_decay_a_channel(dk, dv, channel):
+    """At every width the scalar-decay kernels take, ``supports`` answers a
+    decay a key channel by width (``ops/kda.py``'s kernels hold values no
+    wider than the keys' tile), and the scalar kernels' entry point still
+    raises for a g [B, S, H, d] instead of reading it as one decay a head."""
     assert gdn_kernel.supports(64, dk, dv, 8, 8192)
-    assert not gdn_kernel.supports(64, dk, dv, 8, 8192, channel_decay=True)
+    assert gdn_kernel.supports(64, dk, dv, 8, 8192, channel_decay=True) is channel
     if dk == 16:
         (q, key, v, g, beta), _ = _rule_inputs(64, "slow", heads=2, dk=16, dv=16)
         assert gdn_kernel.gated_delta(q, key, v, g[..., 0], beta, 64, jnp.float32,
@@ -216,14 +220,16 @@ def test_a_lower_precision_or_a_dropped_term_fails_the_comparison(what, monkeypa
     c = tiny()
     bf16 = lambda t: t.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
     drop = None
-    if what == "bf16_decays":
-        real = gated_delta.kda_chunked
+    if what == "bf16_decays":  # head width 16: the program runs the kernels
+        real = kda_kernel.kda
         monkeypatch.setattr(
-            gated_delta, "kda_chunked",
+            kda_kernel, "kda",
             lambda q, k, v, g, beta, *rest: real(q, k, v, bf16(g), beta, *rest))
     elif what == "bf16_inverse":
-        real = gated_delta.unit_lower_inverse
-        monkeypatch.setattr(gated_delta, "unit_lower_inverse", lambda a: bf16(real(a)))
+        real = kda_kernel._unit_lower_inverse
+        monkeypatch.setattr(
+            kda_kernel, "_unit_lower_inverse", lambda *a: bf16(real(*a)))
+        jax.clear_caches()  # ``kda_fwd`` keeps what it traced for these shapes
     elif what == "bf16_gate":
         monkeypatch.setattr(jax.nn, "sigmoid", lambda x, real=jax.nn.sigmoid: bf16(real(x)))
     elif what == "no_beta_doubling":
@@ -232,6 +238,8 @@ def test_a_lower_precision_or_a_dropped_term_fails_the_comparison(what, monkeypa
         drop = what[3:]
     params, data, loss, grads = _system(c, 64)
     monkeypatch.undo()
+    if what == "bf16_inverse":
+        jax.clear_caches()  # and must not keep the rounded one
     loss_ref, grads_ref = jax.jit(
         lambda p, b: reference.loss_and_grads(p, b, c, drop=drop))(params, data)
     worst = _worst(_leaf_errors(grads, grads_ref))
@@ -416,7 +424,8 @@ def test_the_step_hands_on_the_mixers_and_the_experts_counters(caplog):
     with caplog.at_level(logging.INFO, logger="torchft_tpu.models.gated_delta"):
         new, metrics = make_train_step(model, mesh, sh, donate=False)(
             state, _data(cfg.vocab_size, 2, 64))
-    assert "gated_delta: traced=kda-xla chunk=64 seq=64" in caplog.text
+    # head width 16: the kernels, through the interpreter off the chip
+    assert "gated_delta: traced=kda-kernel chunk=64 seq=64" in caplog.text
     assert "WARNING" not in [r.levelname for r in caplog.records]
     assert set(metrics) == {
         "loss", "grad_norm", "kda_state_abs_max", "kda_decay_min", "kda_beta_mean",
@@ -652,5 +661,5 @@ def test_train_hsdp_runs_the_small_preset(tmp_path):
     assert proc.returncode == 0, proc.stderr[-3000:]
     steps = [line for line in proc.stderr.splitlines() if " loss " in line]
     assert len(steps) == 3, steps
-    assert "gated_delta: traced=kda-xla chunk=64 seq=32" in proc.stderr
+    assert "gated_delta: traced=kda-kernel chunk=64 seq=32" in proc.stderr
     assert cells.load_json(str(tmp_path / "out" / "group0.json"))["final_step"] == 3

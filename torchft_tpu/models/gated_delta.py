@@ -42,9 +42,11 @@ KEY CHANNEL, alpha_t in (0,1)^d a head::
 
 and another mixer around it (``KimiDeltaMixer``: low-rank decay and gate
 projections, a sigmoid-gated norm, keys and values of one width).
-``kda_chunked`` is its chunked form in plain XLA, the contract a fused
-kernel would have to meet. With G [C, d] the cumulative log-decays of a
-chunk, Gamma no longer factors out of the products::
+``kda_chunked`` is its chunked form in plain XLA: the contract, the
+fallback and the tests' oracle of the fused kernels (``ops/kda.py``, which
+the mixer takes where ``supports(..., channel_decay=True)`` says the shapes
+fit). With G [C, d] the cumulative log-decays of a chunk, Gamma no longer
+factors out of the products::
 
     A_ij = beta_i sum_c k_ic k_jc exp(G_ic - G_jc)        i > j
     P_ij =        sum_c q_ic k_jc exp(G_ic - G_jc)        i >= j
@@ -72,6 +74,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from torchft_tpu.ops import gated_delta as gdn_kernel
+from torchft_tpu.ops import kda as kda_kernel
 from torchft_tpu.models.mamba2 import (
     _a_log_init,
     _dt_bias_init,
@@ -92,12 +95,13 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 
 def _note(form: str, chunk: int, seq: int, meant: bool = False) -> None:
     """Says once per (form, chunk, seq), at trace time, which form of the
-    delta rule a step took; at WARNING where widths the kernel is ``meant``
-    for fell back to plain XLA."""
+    delta rule a step took ("kernel" / "xla", "kda-kernel" / "kda-xla"); at
+    WARNING where widths the kernels are ``meant`` for fell back to plain
+    XLA."""
     if (form, chunk, seq) not in _NOTED:
         _NOTED.add((form, chunk, seq))
         logger.log(
-            logging.WARNING if meant and form == "xla" else logging.INFO,
+            logging.WARNING if meant and form.endswith("xla") else logging.INFO,
             "gated_delta: traced=%s chunk=%d seq=%d", form, chunk, seq,
         )
 
@@ -469,7 +473,8 @@ class KimiDeltaMixer(nn.Module):
         q = q / |q|_2 / sqrt(d),  k = k / |k|_2                       a head
         g = -exp(A_log_h) softplus(W_f_b W_f_a x + dt_bias)   in R^d, <= 0
         beta = sigmoid(x W_b)             (x 2 where ``allow_neg_eigval``)
-        o = the delta rule over (q, k, v, g, beta)           ``kda_chunked``
+        o = the delta rule over (q, k, v, g, beta)    ``ops/kda.py`` where
+                   ``supports`` admits the widths, ``kda_chunked`` otherwise
         y = W_o concat_h[RMSNorm_d(o) * w * sigmoid(W_g_b W_g_a x + b_g)]
 
     W_f_a and W_g_a go down to d channels (one bottleneck for all heads),
@@ -517,8 +522,12 @@ class KimiDeltaMixer(nn.Module):
             if m.allow_neg_eigval:
                 beta = 2.0 * beta
         with jax.named_scope("kda/rule"):
-            _note("kda-xla", CHUNK, seq)
-            o, state = kda_chunked(
+            kernel = gdn_kernel.supports(CHUNK, d, d, heads, seq, channel_decay=True)
+            _note(
+                "kda-kernel" if kernel else "kda-xla", CHUNK, seq,
+                meant=gdn_kernel.meant_for(d, d),
+            )
+            o, state = (kda_kernel.kda if kernel else kda_chunked)(
                 _unit(by_head(q)) * d ** -0.5, _unit(by_head(k)), by_head(v),
                 g, beta, CHUNK, self.dtype,
             )

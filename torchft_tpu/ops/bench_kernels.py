@@ -14,6 +14,7 @@ Run:  python -m torchft_tpu.ops.bench_kernels          # any backend
       python -m torchft_tpu.ops.bench_kernels --chip   # fails off-TPU
       python -m torchft_tpu.ops.bench_kernels --tiles  # the flash kernels' tile sweep
       python -m torchft_tpu.ops.bench_kernels --gdn    # the gated delta rule alone
+      python -m torchft_tpu.ops.bench_kernels --kda    # the rule with a decay a key channel
 """
 
 from __future__ import annotations
@@ -108,36 +109,24 @@ def ssd_section(compiled: bool) -> dict:
 _V5E_HBM_BYTES = 819e9  # benchmark/peaks.json, "TPU v5 lite"
 
 
-def gdn_section(compiled: bool) -> dict:
-    """The chunked gated delta rule alone, ``ops/gated_delta.py``'s kernels
-    against the plain ``gated_delta_chunked``: parity of o, of the last
-    state and of the five gradients, milliseconds forward and forward plus
-    backward, and the share of the least time a v5e needs for the same rule
-    (the bytes of q, k, v in bf16, g and beta in float32 and o in bf16 once
-    forward, those, o's gradient and the five gradients backward, as the
-    benchmark's ``gdn_bytes_per_step`` counts them; memory-bound).
-    Compiled: the shapes of the ``olmo-hybrid-raw`` cell (2 x 8192, 15 heads,
-    keys of 96, values of 192, bf16). Interpreted: the smallest the
-    kernels take."""
+def _rule_section(compiled, args, dv, dtype, kernel, plain, alone, token_bytes) -> dict:
+    """A chunked delta rule alone, kernels against the plain form, both as
+    (q, k, v, g, beta, chunk, dtype) -> (o, last state): parity of o, of the
+    last state and of the five gradients, milliseconds forward and forward
+    plus backward, the forward kernel ``alone(save)`` in its own layouts
+    without and with its residuals written out, and on a chip the share of
+    the least time a v5e needs for the same rule (memory-bound:
+    ``token_bytes`` = a token's inputs and its o, as the benchmark's
+    ``*_bytes_per_step`` count them)."""
     import jax
     import jax.numpy as jnp
 
-    from torchft_tpu.models.gated_delta import CHUNK, gated_delta_chunked
-    from torchft_tpu.ops import gated_delta as gdn_kernel
+    from torchft_tpu.models.gated_delta import CHUNK
 
-    b, s, h, dk, dv = (2, 8192, 15, 96, 192) if compiled else (1, 256, 2, 16, 32)
-    dtype = jnp.bfloat16
-    ks = jax.random.split(jax.random.PRNGKey(0), 7)
-    unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)  # noqa: E731
-    args = (
-        unit(jax.random.normal(ks[0], (b, s, h, dk))) * dk ** -0.5,
-        unit(jax.random.normal(ks[1], (b, s, h, dk))),
-        jax.random.normal(ks[2], (b, s, h, dv)).astype(dtype),
-        -0.1 * jax.nn.softplus(jax.random.normal(ks[3], (b, s, h))),
-        2.0 * jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, h))),
-    )
-    weight = jax.random.normal(ks[5], (b, s, h, dv))
-    state_weight = jax.random.normal(ks[6], (b, h, dk, dv))
+    b, s, h, dk = args[0].shape
+    ks = jax.random.split(jax.random.PRNGKey(1), 2)
+    weight = jax.random.normal(ks[0], (b, s, h, dv))
+    state_weight = jax.random.normal(ks[1], (b, h, dk, dv))
 
     def both(form):
         fwd = jax.jit(lambda *a: form(*a, CHUNK, dtype))
@@ -148,11 +137,8 @@ def gdn_section(compiled: bool) -> dict:
 
         return fwd, jax.jit(jax.value_and_grad(scalar, argnums=(0, 1, 2, 3, 4)))
 
-    interpret = not compiled
-    k_fwd, k_grad = both(
-        lambda *a: gdn_kernel.gated_delta(*a, interpret=interpret)
-    )
-    x_fwd, x_grad = both(gated_delta_chunked)
+    k_fwd, k_grad = both(kernel)
+    x_fwd, x_grad = both(plain)
     (o, last), (o_x, last_x) = k_fwd(*args), x_fwd(*args)
     out = {
         "shape": [b, s, h, dk, dv], "chunk": CHUNK,
@@ -165,19 +151,14 @@ def gdn_section(compiled: bool) -> dict:
     reps = 20 if compiled else 1
     out["kernel_fwd_ms"] = round(_time_call(k_fwd, *args, reps=reps), 3)
     out["kernel_fwd_bwd_ms"] = round(_time_call(k_grad, *args, reps=reps), 3)
-    # What the residuals cost: the forward kernel alone, in its own layouts,
-    # without and with the entering states and T written out.
-    laid = gdn_kernel.kernel_layout(*args)
     for name, save in (("kernel_alone_fwd_ms", False), ("kernel_alone_fwd_saving_ms", True)):
-        call = lambda *t, save=save: gdn_kernel.gdn_fwd(  # noqa: E731
-            *t, jnp.dtype(dtype), save, interpret
-        )
+        call, laid = alone(save)
         out[name] = round(_time_call(call, *laid, reps=reps), 3)
     out["xla_fwd_ms"] = round(_time_call(x_fwd, *args, reps=reps), 3)
     out["xla_fwd_bwd_ms"] = round(_time_call(x_grad, *args, reps=reps), 3)
     if compiled:  # a share of a roofline comes from a chip run only
-        ins = 2 * h * (2 * dk + dv) + 2 * 4 * h  # a token's q, k, v, g, beta
-        fwd_bytes = (ins + 2 * h * dv) * b * s
+        ins, o_bytes = token_bytes
+        fwd_bytes = (ins + o_bytes) * b * s
         least = {"fwd": fwd_bytes, "fwd_bwd": 2 * fwd_bytes + ins * b * s}
         for name, nbytes in least.items():
             ms = 1e3 * nbytes / _V5E_HBM_BYTES
@@ -187,6 +168,85 @@ def gdn_section(compiled: bool) -> dict:
                     100 * ms / out[f"{form}_{name}_ms"], 2
                 )
     return out
+
+
+def _rule_inputs(b, s, h, dk, dv, dtype, channel_decay):
+    """Unit keys, scaled unit queries, values in the compute type, a
+    log-decay a head (or a key channel) and beta over (0, 2)."""
+    import jax
+    import jax.numpy as jnp
+
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)  # noqa: E731
+    return (
+        unit(jax.random.normal(ks[0], (b, s, h, dk))) * dk ** -0.5,
+        unit(jax.random.normal(ks[1], (b, s, h, dk))),
+        jax.random.normal(ks[2], (b, s, h, dv)).astype(dtype),
+        -0.1 * jax.nn.softplus(
+            jax.random.normal(ks[3], (b, s, h, dk) if channel_decay else (b, s, h))
+        ),
+        2.0 * jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, h))),
+    )
+
+
+def gdn_section(compiled: bool) -> dict:
+    """The chunked gated delta rule alone, ``ops/gated_delta.py``'s kernels
+    against the plain ``gated_delta_chunked`` (``_rule_section``); the least
+    time from the bytes of q, k, v in bf16, g and beta in float32 and o in
+    bf16 once forward, those, o's gradient and the five gradients backward,
+    as the benchmark's ``gdn_bytes_per_step`` counts them.
+    Compiled: the shapes of the ``olmo-hybrid-raw`` cell (2 x 8192, 15 heads,
+    keys of 96, values of 192, bf16). Interpreted: the smallest the
+    kernels take."""
+    import jax.numpy as jnp
+
+    from torchft_tpu.models.gated_delta import gated_delta_chunked
+    from torchft_tpu.ops import gated_delta as gdn_kernel
+
+    b, s, h, dk, dv = (2, 8192, 15, 96, 192) if compiled else (1, 256, 2, 16, 32)
+    dtype, interpret = jnp.bfloat16, not compiled
+    args = _rule_inputs(b, s, h, dk, dv, dtype, channel_decay=False)
+
+    def alone(save):
+        return (
+            lambda *t: gdn_kernel.gdn_fwd(*t, jnp.dtype(dtype), save, interpret),
+            gdn_kernel.kernel_layout(*args),
+        )
+
+    return _rule_section(
+        compiled, args, dv, dtype,
+        lambda *a: gdn_kernel.gated_delta(*a, interpret=interpret), gated_delta_chunked,
+        alone, (2 * h * (2 * dk + dv) + 2 * 4 * h, 2 * h * dv),
+    )
+
+
+def kda_section(compiled: bool) -> dict:
+    """The chunked delta rule with a decay a key channel alone,
+    ``ops/kda.py``'s kernels against the plain ``kda_chunked``
+    (``_rule_section``); the least time as the benchmark's
+    ``kda_bytes_per_step`` counts it (the log-decay is float32 a channel).
+    Compiled: the shapes of the ``solar-open2-raw`` cell (2 x 8192, 8 heads
+    of 128, bf16). Interpreted: the smallest the kernels take."""
+    import jax.numpy as jnp
+
+    from torchft_tpu.models.gated_delta import kda_chunked
+    from torchft_tpu.ops import kda as kda_kernel
+
+    b, s, h, d = (2, 8192, 8, 128) if compiled else (1, 256, 2, 16)
+    dtype, interpret = jnp.bfloat16, not compiled
+    args = _rule_inputs(b, s, h, d, d, dtype, channel_decay=True)
+
+    def alone(save):
+        return (
+            lambda *t: kda_kernel.kda_fwd(*t, jnp.dtype(dtype), save, interpret),
+            kda_kernel.kernel_layout(*args),
+        )
+
+    return _rule_section(
+        compiled, args, d, dtype,
+        lambda *a: kda_kernel.kda(*a, interpret=interpret), kda_chunked,
+        alone, (2 * 3 * h * d + 4 * h * d + 4 * h, 2 * h * d),
+    )
 
 
 # The flash kernels' tile sweep (PERF.md section 6, PR 52): every family at
@@ -340,9 +400,10 @@ def main() -> int:
             file=sys.stderr,
         )
         return 2
-    if "--gdn" in sys.argv[1:]:  # the delta rule's section alone
-        print(json.dumps({"device_kind": device_kind, "gdn": gdn_section(compiled)}), flush=True)
-        return 0
+    for flag, section in (("gdn", gdn_section), ("kda", kda_section)):
+        if f"--{flag}" in sys.argv[1:]:  # one delta rule's section alone
+            print(json.dumps({"device_kind": device_kind, flag: section(compiled)}), flush=True)
+            return 0
     result: dict = {
         "backend": backend,
         "device_kind": device_kind,
@@ -543,6 +604,7 @@ def main() -> int:
 
     # ---- chunked gated delta rule: kernels vs plain XLA -------------------
     result["gdn"] = gdn_section(compiled)
+    result["kda"] = kda_section(compiled)
 
     ok = (
         result["quantize"]["dequantize_bit_exact"]
@@ -560,8 +622,11 @@ def main() -> int:
         # two roundings of bf16 operands apart; dt and a sum many such terms
         and result["ssd"]["y_rel_vs_xla"] < 0.01
         and max(result["ssd"]["grad_rel_vs_xla"].values()) < 0.08
-        and max(result["gdn"]["o_rel_vs_xla"], result["gdn"]["state_rel_vs_xla"]) < 0.01
-        and max(result["gdn"]["grad_rel_vs_xla"].values()) < 0.08
+        and all(
+            max(result[rule]["o_rel_vs_xla"], result[rule]["state_rel_vs_xla"]) < 0.01
+            and max(result[rule]["grad_rel_vs_xla"].values()) < 0.08
+            for rule in ("gdn", "kda")
+        )
     )
     result["ok"] = bool(ok)
     print(json.dumps(result), flush=True)

@@ -97,21 +97,20 @@ def supports(
     chunk: int, d_k: int, d_v: int, heads: int, seq: int,
     channel_decay: bool = False,
 ) -> bool:
-    """Whether the kernels take these shapes (the caller runs
-    ``gated_delta_chunked`` otherwise): the chunk of 64 that halves a lane
-    tile, widths they are ``meant_for``, and a head's state, its gradient
-    and their second orientation within what Mosaic has compiled for a v5e
+    """Whether the kernels take these shapes (the caller runs the plain
+    chunked form otherwise): the chunk of 64 that halves a lane tile, widths
+    they are ``meant_for``, and a head's state, its gradient and their second
+    orientation within what Mosaic has compiled for a v5e
     (``tests/test_tpu_compile.py``: 16 / 16, 96 / 192 and 128 / 256). Any
     number of heads and any sequence: a ragged end is padded. ONE decay a
-    head and step: the kernels build exp(G_i - G_j) as one C x C matrix a
+    head and step: these kernels build exp(G_i - G_j) as one C x C matrix a
     head, which a decay a key channel (``channel_decay``: Kimi Delta
-    Attention's g [B, S, H, d_k]) does not factor into, so that is refused
-    whatever the widths (the caller runs ``kda_chunked``)."""
+    Attention's g [B, S, H, d_k]) does not factor into. That rule's kernels
+    are ``ops/kda.py``'s, which hold G as a [d_k, 128] tile and the state one
+    way round, and were compiled at 16 / 16 and 128 / 128."""
     del heads, seq
-    return (
-        not channel_decay and chunk == CHUNK and meant_for(d_k, d_v)
-        and d_k <= 128 and d_v <= 256
-    )
+    widest = 128 if channel_decay else 256
+    return chunk == CHUNK and meant_for(d_k, d_v) and d_k <= 128 and d_v <= widest
 
 
 def _dot(a, b, dims=(((1,), (0,)), ((), ())), precision=None):
@@ -602,7 +601,9 @@ _gated_delta.defvjp(_gated_delta_fwd, _gated_delta_bwd)
 def kernel_layout(q, k, v, g, beta):
     """The model's q, k [B, S, H, d_k], v [B, S, H, d_v], g and beta
     [B, S, H] as ``gdn_fwd`` and ``gdn_bwd`` take them, positions last:
-    q, k [B, H d_k, S], v [B, H d_v, S], g and beta [B, H, S] in float32."""
+    q, k [B, H d_k, S], v [B, H d_v, S], g and beta [B, H, S] in float32
+    (a g [B, S, H, d_k], a decay a key channel, becomes [B, H d_k, S] as
+    ``ops/kda.py``'s kernels take it)."""
     bsz, seq, heads, _ = q.shape
     last = lambda t: jnp.swapaxes(t.reshape(bsz, seq, -1), 1, 2)  # noqa: E731
     f32 = jnp.float32
@@ -617,11 +618,11 @@ def gated_delta(
     inputs. The caller has asked ``supports``."""
     bsz, seq, heads, dk = q.shape
     dv = v.shape[-1]
-    if not supports(chunk, dk, dv, heads, seq, channel_decay=g.ndim != 3):
+    if g.ndim != 3 or not supports(chunk, dk, dv, heads, seq):
         raise ValueError(
             f"gated_delta: chunk {chunk}, keys of {dk}, values of {dv}, a "
             f"decay of shape {g.shape} are not the kernels' shapes; use "
-            "gated_delta_chunked (kda_chunked for a decay a key channel)"
+            "gated_delta_chunked (kda_chunked or ops/kda.py for a decay a key channel)"
         )
     pad = -seq % _PAIR
     if pad:
