@@ -634,6 +634,48 @@ def test_flash_block_diffusion_compiles_under_the_name_the_metrics_match(one_chi
     assert len(calls) == 4 and all("flash_attention_block_diffusion" in c for c in calls), calls
 
 
+# -- the smallthinker-raw cell: the flash kernels under a sliding window -----
+
+# (B, S, Hq, Hkv, D, window, largest tile) of the cell's windowed attention at
+# the tiles it chooses (1,024: a sweep of 5), held to 512 (a sweep of 9), of a
+# window that is no multiple of the tile, of a window of at least the sequence
+# (the causal kernels under this name) and of the reference check's sample
+# (``smallthinker/adapter.py`` ``sample_config``: 1,024 tokens under a window
+# of 256 at tiles of 128, a sweep of 3; tiles of 64 the compiler refuses).
+WINDOW_SHAPES = [
+    (1, 16384, 28, 4, 128, 4096, 1024), (1, 16384, 28, 4, 128, 4096, 512),
+    (1, 8192, 28, 4, 128, 1000, 1024), (1, 1024, 28, 4, 128, 4096, 1024),
+    (1, 1024, 28, 4, 128, 256, 128),
+]
+
+
+@pytest.mark.parametrize("shape", WINDOW_SHAPES)
+def test_flash_window_compiles_under_the_name_the_metrics_match(one_chip, shape):
+    """Forward, dq and dkv at the cell's widths, seven query heads a
+    key/value head: three kernels, each named for the jit around it,
+    ``flash_attention_window``, which ``swa_ms`` tells from the causal
+    family's by and ``flash_ms`` counts with them; the grids' innermost
+    dimension is the band's sweep, not the causal one."""
+    from torchft_tpu.ops.flash_attention import choose_tiles, flash_attention_window
+
+    *qkv_shape, window, tile = shape
+    S = shape[1]
+    assert choose_tiles("window", S, (shape[4],), tile, tile) == (min(tile, S),) * 2
+
+    def loss(q, k, v):
+        out = flash_attention_window(
+            q, k, v, window=window, block_q=tile, block_k=tile, interpret=False
+        )
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        *_qkv(one_chip, *qkv_shape)
+    ).compile().as_text()
+    calls = _custom_calls(text)
+    assert len(calls) == 3 and all("flash_attention_window" in c for c in calls), calls
+    assert f"f32[{S},{S}]" not in text and f"bf16[{S},{S}]" not in text
+
+
 # -- the joyai-raw cell: the flash kernels at latent attention's widths -------
 
 # (B, S, heads, rope-free, rotary, value widths, largest tile) of the cell's
@@ -890,3 +932,47 @@ def test_the_solar_open2_cells_step_fits_and_leads_with_the_shapes_the_metrics_m
         r"\[(?:2,8192,4096|2,8192,1280|2,8192,8,128|2,8,8192,128|2,1,8192,128|16384,|13312,"
         r"|\d+,24576|8,4096,1280|8,1280,4096|2,8192,320|2,8192,8\])")
     assert not [i for i in scan + rest if other.search(i) or i.startswith(("flash_", "ragged"))]
+
+
+@pytest.mark.timeout(900)
+def test_the_smallthinker_cells_step_fits_and_holds_no_square_of_the_sequence(
+    topo, monkeypatch
+):
+    """The fused step of ``smallthinker-raw`` (one sequence of 16,384 tokens
+    through two periods of a global and three windowed attentions and eight
+    expert layers of 8 held experts): it fits the chip; the six windowed
+    layers are banded kernel calls under the name ``swa_ms`` tells from the
+    two global layers' causal ones by (forward, remat's forward, dq and dkv a
+    layer), all of them among what ``flash_ms`` finds; no tensor of the
+    program is a square of the sequence; the grouped matmuls run over the
+    49,152-row buffer; and every sub-layer's router logits leave their
+    attention sub-layer as float32 [1, 16384, 64]."""
+    import re
+
+    from benchmark import cells
+    from benchmark.metrics import flash_ms, moe_gmm_ms, swa_ms
+    from benchmark.tests.test_v5e_compile import _programs
+    from torchft_tpu.ops import flash_attention
+
+    monkeypatch.setattr(flash_attention, "_interpret", lambda: False)
+    cell = cells.load_cell("smallthinker-raw")
+    programs, resident = _programs(cell, topo)
+    prog, args = programs["step"]
+    compiled = prog.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    print(f"smallthinker-raw/step needs {need / 2**30:.2f} GiB, resident {resident / 2**30:.2f}")
+    assert resident == 12 * 643_852_800 + 8  # weights, two moments, two counters
+    assert resident < need < ALLOCATOR_BYTES, need
+    text = compiled.as_text()
+    calls = _custom_calls(text)
+    flash = [c for c in calls if re.search(flash_ms.PATTERN, c)]
+    banded = [c for c in flash if re.search(swa_ms.PATTERN, c)]
+    assert len(banded) == 6 * 4 and len(flash) == 8 * 4, (len(banded), len(flash))
+    assert all("16384,128]" in c for c in flash), flash
+    assert not re.search(r"\[(?:\d+,)*16384,16384\]", text)
+    gmm = [c for c in calls if re.search(moe_gmm_ms.PATTERN, c)]
+    rows = [c for c in gmm if "ragged-dot-none" in c]
+    assert len(rows) == 8 * 12 and all("[49152," in c or "[8," in c for c in rows), rows
+    assert "f32[1,16384,64]" in text

@@ -1,0 +1,161 @@
+"""A cell's model against its architecture's plain reference at a length of
+the builder's choosing: loss and every gradient leaf, as
+``benchmark/worker.py`` ``reference_check`` compares them on its 1,024-token
+sample, but on one seeded sequence of ``--seq`` tokens (the cell's own by
+default) through the cell's own model configuration, kernels and all.
+
+Why it exists: the harness's sample is shorter than SmallThinker's window
+of 4,096, so its check never sees the band's edge (PERF.md section 7);
+this does, at 16,384 tokens, with the reference in blocks of
+``--query-block`` query rows so that it fits the chip
+(``reference.loss_and_grads(..., query_block=)``; an architecture whose
+reference has no such option is compared whole).
+
+``--operand-dtype float8_e4m3fn`` hands the comparison the REFERENCE with
+its matmul operands rounded to that type in the system's place: the next
+precision down, which a tolerance has to refuse. ``--program-window N``
+gives the PROGRAM's windowed layers a window of N positions and leaves the
+reference its own: a band that is misplaced or missing, which a tolerance
+has to refuse too.
+
+    python tools/reference_compare.py --workload smallthinker-raw \\
+        --seeds 3000000001,2999999877 --query-block 256 --out chiprun_out/compare.jsonl
+
+One JSON line a seed; exit code 1 where a tolerance is passed. Through the chip
+tool at the published widths; on the CPU only at a test's size
+(``compare`` is what the tests call).
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import inspect
+import json
+import os
+import sys
+from typing import Any, Dict, Iterator, Optional, Sequence
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+
+def comparisons(
+    cell: Any, seq: int, seeds: Sequence[int], query_block: Optional[int] = None,
+    operand_dtype: Optional[str] = None, program_window: Optional[int] = None,
+) -> Iterator[Dict[str, Any]]:
+    """One comparison a seed (weights and tokens from it), its readings
+    beside the reference's tolerances; the programs are compiled once."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from torchft_tpu.parallel import auto_mesh
+    from torchft_tpu.parallel.train import build_model, make_grad_step, state_shardings
+
+    config, reference = cell.config, cell.reference
+    # As the harness's check builds its sample's model: the kernels are
+    # taken wherever the cell takes them, also at a shorter length.
+    cfg = cell.adapter.sample_config(cell.adapter.model_config(config, seq), seq)
+    if program_window is not None:
+        cfg = dataclasses.replace(cfg, sliding_window=program_window)
+    mesh = auto_mesh(1, devices=jax.devices()[:1])
+    model = build_model(cfg, mesh)
+    shardings = state_shardings(model, mesh, (1, seq))
+    options = {}
+    if query_block and "query_block" in inspect.signature(reference.loss_and_grads).parameters:
+        options["query_block"] = query_block
+    init = jax.jit(
+        lambda rng, tokens: model.init(rng, tokens)["params"],
+        out_shardings=shardings.params,
+    )
+    ref = jax.jit(lambda p, b: reference.loss_and_grads(p, b, config, **options))
+    if operand_dtype is None:
+        system = make_grad_step(model, mesh, shardings)
+    else:
+        system = jax.jit(lambda p, b: reference.loss_and_grads(
+            p, b, config, operand_dtype=jnp.dtype(operand_dtype), **options))
+    rel = jax.jit(lambda a, b: jnp.linalg.norm((a - b).ravel()) / jnp.linalg.norm(b.ravel()))
+    for seed in seeds:
+        toks = jax.random.randint(
+            jax.random.fold_in(jax.random.PRNGKey(seed), 0x5EED), (1, seq + 1), 0,
+            cfg.vocab_size,
+        )
+        sample = {"inputs": toks[:, :-1], "targets": toks[:, 1:],
+                  "mask": jnp.ones((1, seq), jnp.int32)}
+        params = init(jax.random.PRNGKey(seed), sample["inputs"])
+        loss_sys, g_sys = system(params, sample)
+        # To the host: the two gradient trees never share the device's memory.
+        loss_sys, g_sys = float(loss_sys), jax.tree_util.tree_map(np.asarray, g_sys)
+        loss_ref, g_ref = ref(params, sample)
+        del params
+        errs = {
+            jax.tree_util.keystr(path): float(rel(a, b))
+            for (path, a), b in zip(
+                jax.tree_util.tree_leaves_with_path(g_sys), jax.tree_util.tree_leaves(g_ref))
+        }
+        del g_sys, g_ref
+        worst = max(errs, key=lambda k: errs[k] if errs[k] == errs[k] else -1.0)
+        loss_rel = abs(loss_sys - float(loss_ref)) / abs(float(loss_ref))
+        ranked = sorted(v for v in errs.values() if v == v)
+        yield {
+            "tokens": seq,
+            "seed": seed,
+            "compared": (
+                f"reference in {operand_dtype}" if operand_dtype is not None
+                else "system" if program_window is None
+                else f"system under a window of {program_window}"
+            ),
+            "query_block": options.get("query_block"),
+            "loss_system": loss_sys,
+            "loss_reference": float(loss_ref),
+            "loss_rel_diff": loss_rel,
+            "grad_rel_l2_worst": errs[worst],
+            "grad_rel_l2_worst_leaf": worst,
+            "grad_rel_l2_second": ranked[-2] if len(ranked) > 1 else None,
+            "grad_rel_l2_median": ranked[len(ranked) // 2],
+            "leaves": len(errs),
+            "loss_rel_tol": reference.LOSS_REL_TOL,
+            "grad_rel_l2_tol": reference.GRAD_REL_L2_TOL,
+            "ok": bool(loss_rel <= reference.LOSS_REL_TOL
+                       and errs[worst] <= reference.GRAD_REL_L2_TOL),
+            "device": jax.devices()[0].device_kind,
+        }
+
+
+def compare(cell: Any, seq: int, seed: int, **options: Any) -> Dict[str, Any]:
+    """``comparisons`` for one seed."""
+    return next(comparisons(cell, seq, [seed], **options))
+
+
+def main() -> int:
+    from benchmark import cells
+
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seq", type=int, default=0, help="0: the cell's own")
+    ap.add_argument("--seeds", default="0", help="comma-separated")
+    ap.add_argument("--query-block", type=int, default=0)
+    ap.add_argument("--operand-dtype", default=None)
+    ap.add_argument("--program-window", type=int, default=0)
+    ap.add_argument("--out", default="")
+    args = ap.parse_args()
+    cell = cells.load_cell(args.workload)
+    ok = True
+    for out in comparisons(
+        cell, args.seq or int(cell.mix["seq"]), [int(x) for x in args.seeds.split(",")],
+        args.query_block or None, args.operand_dtype, args.program_window or None,
+    ):
+        line = json.dumps(out)
+        print(line, flush=True)
+        if args.out:
+            os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+            with open(args.out, "a") as f:
+                f.write(line + "\n")
+        ok = ok and out["ok"]
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

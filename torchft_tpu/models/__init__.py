@@ -33,6 +33,8 @@ from torchft_tpu.models.llama import (  # noqa: F401
     olmoe_1b_7b,
     sdar_30b_a3b,
     sdar_moe_debug,
+    smallthinker_21b,
+    smallthinker_debug,
     solar_open2_250b,
     solar_open2_debug,
 )
@@ -51,4 +53,6 @@ PRESETS = {
     "olmo_hybrid": olmo_hybrid_debug,
     "solar_open2_250b": solar_open2_250b,
     "solar_open2_debug": solar_open2_debug,
+    "smallthinker_21b": smallthinker_21b,
+    "smallthinker_debug": smallthinker_debug,
 }

@@ -45,19 +45,22 @@ _ATTN_NOTED: set = set()
 
 
 def _note_attention(
-    asked: str, traced: str, seq_len: int, tiles: Optional[tuple] = None
+    asked: str, traced: str, seq_len: int, tiles: Optional[tuple] = None,
+    window: Optional[int] = None,
 ) -> None:
     """Says once per (asked, traced, seq_len), at trace time, which
-    attention implementation a step really took and, of a flash kernel,
-    the tiles it chose (``tiles=1024x1024``) — 'flash' routes to dense
-    below ``flash_min_seq`` or on unsupported tilings, and a chip run
-    must be able to prove which branch it compiled."""
+    attention implementation a step really took, of a windowed layer its
+    window (``window=4096``) and, of a flash kernel, the tiles it chose
+    (``tiles=1024x1024``) — 'flash' routes to dense below ``flash_min_seq``
+    or on unsupported tilings, and a chip run must be able to prove which
+    branch it compiled."""
     key = (asked, traced, seq_len)
     if key not in _ATTN_NOTED:
         _ATTN_NOTED.add(key)
         logger.log(
             logging.INFO if asked == traced else logging.WARNING,
-            "attention: asked=%s traced=%s seq=%d%s", asked, traced, seq_len,
+            "attention: asked=%s traced=%s seq=%d%s%s", asked, traced, seq_len,
+            " window=%d" % window if window else "",
             " tiles=%dx%d" % tiles if tiles else "",
         )
 
@@ -77,6 +80,14 @@ class LlamaConfig:
     dtype: Dtype = jnp.bfloat16
     param_dtype: Dtype = jnp.float32
     tie_embeddings: bool = False
+    # The embedding table's initial standard deviation. None: flax's
+    # default, variance 1/hidden_size, under which the first block's
+    # residual stream is its sub-layers' outputs and hardly the token's own
+    # row. 1.0 (T5's and PaLM's unit-variance table) keeps the token's row
+    # the larger part of the stream through a shallow stack: a stack that
+    # holds a share of each layer's experts (``experts_held``) needs it to
+    # keep its routing spread under random weights (PERF.md section 6, PR 60).
+    embed_init_std: Optional[float] = None
     remat: bool = True
     # 'dense' | 'flash' | 'ring' | 'ulysses'. flash = Pallas on-chip blocked attention
     # (ops/flash_attention.py, dense fallback for odd seq lens); ring
@@ -121,8 +132,14 @@ class LlamaConfig:
     # heads, before RoPE (LFM2's form).
     qk_norm: Any = False
     # False: attention without rotary embeddings (a hybrid whose
-    # state-space layers carry the order, Nemotron-H).
+    # state-space layers carry the order, Nemotron-H). The flag is the
+    # global kind's ('*' of a ``layer_pattern``, and the scanned block's);
+    # the windowed kind 'W' carries its own and is always rotated.
     rope: bool = True
+    # The windowed kind's window: a 'W' layer's row i keeps the keys j <= i
+    # with i - j < ``sliding_window``, the position itself counted
+    # (SmallThinker's ``sliding_window_size``).
+    sliding_window: Optional[int] = None
     # True: the attention's output is gated elementwise before W_o,
     # W_o(Y * sigmoid(x W_gate)), x the layer's (normed) input and W_gate
     # as wide as W_q (arXiv:2505.06708's head-specific elementwise gate;
@@ -132,12 +149,14 @@ class LlamaConfig:
     # between a pre-norm and the residual add: 'M' a Mamba-2 mixer
     # (``mamba``), 'E' the expert layer, '*' attention (rotary where
     # ``rope``; latent attention where ``mla`` is set, models/mla.py),
+    # 'W' attention under a sliding window (``sliding_window``; rotary
+    # whatever ``rope`` says, never latent),
     # 'C' a gated short convolution of ``SHORT_CONV_TAPS`` taps,
     # 'G' a gated-delta linear attention (``gated_delta``,
     # models/gated_delta.py), 'K' a Kimi delta attention (``kda``, the same
     # file: a decay a key channel), 'D' a dense SwiGLU feed-forward. A model
     # whose published layer is an operator and a feed-forward is two
-    # characters a layer ("CD", "*E", "GD", "KE").
+    # characters a layer ("CD", "*E", "GD", "KE", "WE").
     # None: ``num_layers`` scanned blocks of attention + MLP.
     layer_pattern: Optional[str] = None
     mamba: Optional[Mamba2Config] = None
@@ -157,8 +176,10 @@ class LlamaConfig:
     # a selection bias (a parameter that gets no gradient), the gates those
     # sigmoids without the bias, divided by their sum and times
     # ``routed_scaling``. 'relu2': down(relu(up(x))^2), no gate
-    # matrix. ``shared_expert_size`` > 0: one such expert of that width
-    # every token passes through, added to the routed result.
+    # matrix. 'reglu': down(relu(gate(x)) * up(x)), SwiGLU's three matrices
+    # under a ReLU (SmallThinker's sparse experts). ``shared_expert_size`` >
+    # 0: one such expert of that width every token passes through, added to
+    # the routed result.
     router_score: str = "softmax"
     routed_scaling: float = 1.0
     # What the sigmoid router adds to the chosen scores' sum before it
@@ -172,6 +193,16 @@ class LlamaConfig:
     router_bias_update_rate: float = 0.0
     expert_act: str = "swiglu"
     shared_expert_size: int = 0
+    # True: an expert layer's router reads the normed input of the
+    # attention sub-layer BEFORE it (SmallThinker's pre-attention router:
+    # a deployment fetches the chosen experts while the attention runs),
+    # its experts their own normed input. The router's kernel is then a
+    # parameter of that attention sub-layer (``layers_<i>/router``), which
+    # hands its float32 logits [B, S, E] on to the 'E' that follows; under
+    # per-sub-layer remat they are the attention sub-layer's second output
+    # and so SAVED for the backward pass, 4 E bytes a token and layer, not
+    # recomputed. A ``layer_pattern`` stack's, every 'E' after a '*' or 'W'.
+    router_ahead: bool = False
     # (first, count): this layer HOLDS experts first .. first+count-1 of
     # ``num_experts`` (a chip's share under expert parallelism). It routes
     # over all of them, computes the assignments that land on its own, and
@@ -660,6 +691,73 @@ def solar_open2_debug(**overrides: Any) -> LlamaConfig:
     return dataclasses.replace(cfg, **overrides)
 
 
+def smallthinker_21b(**overrides: Any) -> LlamaConfig:
+    """SmallThinker-21BA3B-Instruct (PowerInfer/SmallThinker-21BA3B-Instruct
+    config.json; arXiv:2507.20984) at its published sizes: 52 layers of an
+    attention (28 heads on 4 of width 128, no bias, no QK norm) and an
+    expert layer each; one layer in four (``sliding_window_layout`` and
+    ``rope_layout`` 0: layers 0, 4, 8, ...) a global attention WITHOUT
+    rotary embedding, the other three a sliding window of 4,096 with it
+    (theta 1.5e6); 64 ReGLU experts of width 768, 6 a token by the top
+    logits and a softmax over those six, no shared expert, the router
+    reading the layer's normed input BEFORE its attention; an untied
+    151,936-row head. 21.5B parameters, 3.3B active: override
+    ``layer_pattern``, ``experts_held`` and ``vocab_size`` for what one
+    chip holds. The balance term's form and coefficient are not keys of
+    the published file (benchmark/configs/smallthinker-21b-l8e8.json,
+    ``assumed``)."""
+    cfg = LlamaConfig(
+        vocab_size=151936,
+        hidden_size=2560,
+        intermediate_size=768,
+        num_layers=52,
+        # Two characters a published layer: its attention ('*' global and
+        # rope-free, 'W' windowed and rotary), its expert layer.
+        layer_pattern="*EWEWEWE" * 13,
+        num_heads=28,
+        num_kv_heads=4,
+        head_dim=128,
+        max_seq_len=16384,
+        rope_theta=1.5e6,
+        norm_eps=1e-6,
+        rope=False,
+        sliding_window=4096,
+        router_ahead=True,
+        num_experts=64,
+        num_experts_per_tok=6,
+        expert_capacity_factor=None,
+        norm_topk_prob=True,
+        expert_act="reglu",
+        router_aux_coef=0.001,
+        router_z_coef=0.0,
+    )
+    return dataclasses.replace(cfg, **overrides)
+
+
+def smallthinker_debug(**overrides: Any) -> LlamaConfig:
+    """Tiny SmallThinker (one period: a global rope-free attention and
+    three windowed rotary ones over 16 positions, an expert layer after
+    each; 16 experts of which 4 are held) for tests and
+    ``train_hsdp.py --model smallthinker_debug``."""
+    cfg = smallthinker_21b(
+        vocab_size=256,
+        hidden_size=64,
+        intermediate_size=48,
+        num_layers=4,
+        layer_pattern="*EWEWEWE",
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        max_seq_len=128,
+        sliding_window=16,
+        num_experts=16,
+        num_experts_per_tok=3,
+        experts_held=(0, 4),
+        remat=False,
+    )
+    return dataclasses.replace(cfg, **overrides)
+
+
 def llama_moe_debug(**overrides: Any) -> LlamaConfig:
     """Tiny MoE config (4 experts, top-2) for tests and the ep dryrun."""
     cfg = llama_debug(num_experts=4, num_experts_per_tok=2)
@@ -775,6 +873,37 @@ def _block_diffusion_tiles(cfg: LlamaConfig, rows: int) -> Optional[tuple]:
     )
 
 
+def window_mask(seq_len: int, window: int) -> jax.Array:
+    """[S,S] boolean, True where a query row sees a key column under a
+    sliding window: j <= i and i - j < ``window``."""
+    back = jnp.arange(seq_len)[:, None] - jnp.arange(seq_len)[None, :]
+    return (back >= 0) & (back < window)
+
+
+def window_attention(cfg: LlamaConfig, seq_len: int) -> tuple:
+    """(tiles, kept share) of a windowed layer's attention over
+    ``seq_len`` rows: the tiles the banded flash kernels choose and the
+    share of the score entries in the tiles they run that the band keeps,
+    or (None, share of the whole S x S square) where dense attention under
+    ``window_mask`` runs."""
+    from torchft_tpu.ops import flash_attention as fa
+
+    window = cfg.sliding_window
+    if window is None or window < 1:
+        raise ValueError(f"a windowed layer needs sliding_window, not {window!r}")
+    tiles = None
+    if cfg.attn_impl == "flash" and seq_len >= cfg.flash_min_seq:
+        tiles = fa.choose_tiles(
+            "window", seq_len, (cfg.head_dim,), cfg.flash_block_q, cfg.flash_block_k
+        )
+    if tiles is not None:
+        kept, run = fa.window_tiles(
+            seq_len, window, cfg.flash_block_q, cfg.flash_block_k
+        )
+        return tiles, kept / run
+    return None, fa.window_kept(seq_len, window) / (seq_len * seq_len)
+
+
 class RMSNorm(nn.Module):
     eps: float = 1e-5
     param_dtype: Dtype = jnp.float32
@@ -793,7 +922,12 @@ class RMSNorm(nn.Module):
 
 
 class Attention(nn.Module):
+    """GQA attention of one of two kinds: the global one (causal; rotary
+    where ``cfg.rope``) or, ``window=True``, the windowed one (causal
+    within ``cfg.sliding_window`` positions; always rotary)."""
+
     cfg: LlamaConfig
+    window: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -819,10 +953,40 @@ class Attention(nn.Module):
                 cfg.norm_eps, cfg.param_dtype, name=name
             )(t.reshape(*t.shape[:2], -1)).reshape(t.shape)
             q, k = whole(q, "q_norm"), whole(k, "k_norm")
-        if cfg.rope:
+        if cfg.rope or self.window:
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
-        if cfg.objective == "block_diffusion":
+        if self.window:
+            if cfg.attn_impl not in ("flash", "dense") or cfg.objective != "next_token":
+                raise ValueError(
+                    f"windowed attention under attn_impl={cfg.attn_impl!r}, "
+                    f"objective={cfg.objective!r}: the band mask exists for "
+                    "'flash' and 'dense' under 'next_token' (the ring's hops "
+                    "and the all-to-all know no window)"
+                )
+            seq = q.shape[1]
+            tiles, kept = window_attention(cfg, seq)
+            # The schedule is this layer's, so the share is counted here.
+            self.sow("intermediates", "swa_kept_share", jnp.float32(kept))
+            _note_attention(
+                f"{cfg.attn_impl}/window", f"{'flash' if tiles else 'dense'}/window",
+                seq, tiles, cfg.sliding_window,
+            )
+            with jax.named_scope("attention/window"):
+                if tiles is not None:
+                    from torchft_tpu.ops.flash_attention import (
+                        flash_attention_window,
+                    )
+
+                    out = flash_attention_window(
+                        q, k, v, window=cfg.sliding_window,
+                        block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
+                    )
+                else:
+                    out = dense_attention(
+                        q, k, v, mask=window_mask(seq, cfg.sliding_window)
+                    )
+        elif cfg.objective == "block_diffusion":
             # x holds [x_t | x_0]: two streams, one mask over both.
             if cfg.attn_impl not in ("flash", "dense"):
                 raise ValueError(
@@ -912,6 +1076,18 @@ class MLP(nn.Module):
         gate = proj(width, "gate")(x)
         up = proj(width, "up")(x)
         return proj(cfg.hidden_size, "down")(nn.silu(gate) * up)
+
+
+def router_dense(cfg: LlamaConfig) -> nn.Dense:
+    """An expert layer's router, in fp32 for a numerically stable softmax
+    and top-k: a child ``router`` of the module that calls it."""
+    return nn.Dense(
+        cfg.num_experts,
+        use_bias=False,
+        dtype=jnp.float32,
+        param_dtype=cfg.param_dtype,
+        name="router",
+    )
 
 
 @jax.custom_vjp
@@ -1015,6 +1191,9 @@ def _combine_held_bwd(res, g):
 _combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
 
 
+# The three-matrix experts' activations, by ``LlamaConfig.expert_act``.
+GATED_EXPERT_ACTS = {"swiglu": nn.silu, "reglu": nn.relu}
+
 # The held dispatch's static row buffer, as a multiple of the rows a
 # uniform router sends to the experts held (MoEMLP._sorted_held says why 4).
 HELD_ROW_FACTOR = 4.0
@@ -1060,7 +1239,9 @@ class MoEMLP(nn.Module):
 
     Variants of the dropless form (the LlamaConfig fields say what each
     computes): ``router_score="sigmoid"`` (a selection bias chooses, the
-    sigmoids weigh), ``expert_act="relu2"`` (two matrices an expert),
+    sigmoids weigh), ``expert_act="relu2"`` (two matrices an expert) or
+    ``"reglu"`` (SwiGLU's three under a ReLU), ``router_ahead`` (the logits
+    are handed in),
     ``shared_expert_size`` (one expert every token passes through) and
     ``experts_held`` (the layer holds a share of the experts, routes over
     all of them and returns its own part: ``_sorted_held``).
@@ -1078,7 +1259,13 @@ class MoEMLP(nn.Module):
     cfg: LlamaConfig
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(
+        self, x: jax.Array, router_logits: Optional[jax.Array] = None
+    ) -> jax.Array:
+        """``router_logits`` [B,S,E] float32: logits computed ahead of this
+        layer, by the attention sub-layer before it
+        (``LlamaConfig.router_ahead``); None: the layer's own router reads
+        ``x``."""
         cfg = self.cfg
         E = cfg.num_experts
         K = cfg.num_experts_per_tok
@@ -1089,14 +1276,8 @@ class MoEMLP(nn.Module):
         H = x.shape[-1]
         held = E if cfg.experts_held is None else cfg.experts_held[1]
 
-        # Router in fp32 for numerically stable softmax/top-k.
-        router_logits = nn.Dense(
-            E,
-            use_bias=False,
-            dtype=jnp.float32,
-            param_dtype=cfg.param_dtype,
-            name="router",
-        )(x.astype(jnp.float32))  # [B,S,E]
+        if router_logits is None:
+            router_logits = router_dense(cfg)(x.astype(jnp.float32))  # [B,S,E]
         if cfg.router_score == "sigmoid":
             with jax.named_scope("moe/route"):
                 scores = jax.nn.sigmoid(router_logits)
@@ -1132,7 +1313,7 @@ class MoEMLP(nn.Module):
             ),
             shape, cfg.param_dtype,
         ).astype(cfg.dtype)
-        gated = cfg.expert_act == "swiglu"
+        gated = cfg.expert_act in GATED_EXPERT_ACTS
         if not gated and cfg.expert_act != "relu2":
             raise ValueError(f"expert_act {cfg.expert_act!r}")
         weights = (
@@ -1151,7 +1332,7 @@ class MoEMLP(nn.Module):
             out = self._sorted_held(x, probs, gate_vals, gate_idx, weights)
         elif cfg.expert_capacity_factor is None:
             out = self._sorted(x, probs, gate_vals, gate_idx, weights)
-        elif gated:
+        elif cfg.expert_act == "swiglu":
             out = self._capacity(x, probs, gate_vals, gate_idx, *weights)
         else:
             raise ValueError("the capacity dispatch computes SwiGLU experts only")
@@ -1162,10 +1343,12 @@ class MoEMLP(nn.Module):
 
     def _ffn(self, xs, weights, gmm):
         """One expert's feed-forward over its rows, ``gmm`` the (grouped)
-        matmul: SwiGLU over (gate, up, down) or relu^2 over (up, down)."""
+        matmul: SwiGLU or ReGLU over (gate, up, down) or relu^2 over (up,
+        down)."""
         if len(weights) == 3:
             w_gate, w_up, w_down = weights
-            return gmm(nn.silu(gmm(xs, w_gate)) * gmm(xs, w_up), w_down)
+            act = GATED_EXPERT_ACTS[self.cfg.expert_act]
+            return gmm(act(gmm(xs, w_gate)) * gmm(xs, w_up), w_down)
         w_up, w_down = weights
         return gmm(jnp.square(nn.relu(gmm(xs, w_up))), w_down)
 
@@ -1180,7 +1363,10 @@ class MoEMLP(nn.Module):
             ),
             name=name,
         )
-        names = ("shared_gate", "shared_up") if cfg.expert_act == "swiglu" else ("shared_up",)
+        names = (
+            ("shared_gate", "shared_up") if cfg.expert_act in GATED_EXPERT_ACTS
+            else ("shared_up",)
+        )
         weights = tuple(proj(cfg.shared_expert_size, n) for n in names) + (
             proj(x.shape[-1], "shared_down", cfg.residual_init_scale),
         )
@@ -1411,7 +1597,11 @@ class ShortConvMixer(nn.Module):
 class MixerLayer(nn.Module):
     """One layer of a ``layer_pattern`` stack: x + mixer(RMSNorm(x)) or,
     under ``norm_after_mixer``, x + RMSNorm(mixer(x)); the mixer named for
-    its kind so that the sharding rules find it."""
+    its kind so that the sharding rules find it. Under
+    ``cfg.router_ahead`` an attention layer ('*', 'W') also holds the
+    router of the expert layer after it and returns (x, that router's
+    float32 logits from its own normed input), and an expert layer is
+    handed them as ``router_logits``."""
 
     cfg: LlamaConfig
     kind: str
@@ -1420,7 +1610,8 @@ class MixerLayer(nn.Module):
     def __call__(
         self, x: jax.Array,
         cos: Optional[jax.Array] = None, sin: Optional[jax.Array] = None,
-    ) -> jax.Array:
+        router_logits: Optional[jax.Array] = None,
+    ) -> Any:
         cfg = self.cfg
         norm = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="norm")
         h, after = (x, norm) if cfg.norm_after_mixer else (norm(x), lambda y: y)
@@ -1440,17 +1631,74 @@ class MixerLayer(nn.Module):
                 cfg.dtype, cfg.param_dtype, name="kda",
             )(h))
         if self.kind == "E":
-            return x + after(MoEMLP(cfg, name="mlp")(h))
+            return x + after(MoEMLP(cfg, name="mlp")(h, router_logits))
         if self.kind == "D":
             return x + after(MLP(cfg, name="mlp")(h))
         if self.kind == "C":
             return x + after(ShortConvMixer(cfg, name="conv")(h))
-        if self.kind == "*":
-            mixer = Attention if cfg.mla is None else LatentAttention
-            return x + after(mixer(cfg, name="attn")(h, cos, sin))
+        if self.kind in ATTENTION_KINDS:
+            if self.kind == "W":
+                mixer = Attention(cfg, window=True, name="attn")
+            else:
+                mixer = (Attention if cfg.mla is None else LatentAttention)(cfg, name="attn")
+            out = x + after(mixer(h, cos, sin))
+            if not cfg.router_ahead:
+                return out
+            with jax.named_scope("moe/router_ahead"):
+                return out, router_dense(cfg)(h.astype(jnp.float32))
         raise ValueError(
-            f"layer kind {self.kind!r} is none of 'M', 'G', 'K', 'E', 'D', 'C', '*'"
+            f"layer kind {self.kind!r} is none of 'M', 'G', 'K', 'E', 'D', 'C', "
+            "'*', 'W'"
         )
+
+
+# The kinds of a ``layer_pattern`` that are attention: the global one and
+# the windowed one. They are handed the rotary tables (where any layer of
+# theirs is rotary) and, under ``router_ahead``, hold the next 'E's router.
+ATTENTION_KINDS = "*W"
+
+
+def _stack_layers(cfg: LlamaConfig, pattern: str, x: jax.Array, rotary: tuple,
+                  name: Callable[[int], str]) -> jax.Array:
+    """``x`` through the layers of ``pattern``, ``layers_<i>`` of the
+    module that calls it, each remat'd alone. Only an attention layer is
+    handed the rotary tables: the other kinds' calls (and a rope-free
+    stack's) stay as they were. Under ``router_ahead`` an attention
+    layer's second output is carried to the expert layer after it."""
+    # Unlike layers cannot share one scanned body: each is its own module,
+    # remat'd alone (outside a scan XLA would otherwise merge the
+    # recomputation with the forward pass and keep every layer's
+    # activations: prevent_cse stays on).
+    layer = nn.remat(MixerLayer) if cfg.remat else MixerLayer
+    ahead = None
+    for i, kind in enumerate(pattern):
+        mod = layer(cfg, kind, name=name(i))
+        if kind in ATTENTION_KINDS:
+            x = mod(x, *rotary)
+            if cfg.router_ahead:
+                x, ahead = x
+        elif kind == "E" and cfg.router_ahead:
+            if ahead is None:
+                raise ValueError(
+                    f"router_ahead: layer {i} of {pattern!r} is an expert layer "
+                    "with no attention layer before it to hold its router"
+                )
+            x, ahead = mod(x, None, None, ahead), None
+        else:
+            x = mod(x)
+        # A layer's output is the tensor its successors read, which
+        # per-sub-layer remat saves anyway. Without the barrier XLA fuses the
+        # residual adds ACROSS layers wherever [B, S, H] and [B S, H] are one
+        # layout (one sequence a device): the final hidden states become one
+        # fusion over every expert layer's K gathered [S, H] parts, all
+        # alive until the forward pass ends (1.2 GiB a layer by the v5e
+        # compiler's account at 16,384 x 2560: 19.5 GiB for eight layers on
+        # a chip that hands out 15.75, 10.4 with the barrier). Where the
+        # compiler does not fuse past the reshape (two sequences a device
+        # and more) the barrier moves the compiler's account of the step by
+        # at most 0.3% (PERF.md section 6, PR 60).
+        x = jax.lax.optimization_barrier(x)
+    return x
 
 
 # A prediction module's block, as a ``layer_pattern``: attention, then the
@@ -1481,12 +1729,7 @@ class MTPModule(nn.Module):
             cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name="eh_proj",
         )(jnp.concatenate([norm("hnorm")(h), norm("enorm")(emb)], axis=-1))
-        layer = nn.remat(MixerLayer) if cfg.remat else MixerLayer
-        for i, kind in enumerate(MTP_BLOCK):
-            x = layer(cfg, kind, name=f"layers_{i}")(
-                x, *(rotary if kind == "*" else ())
-            )
-        return x
+        return _stack_layers(cfg, MTP_BLOCK, x, rotary, "layers_{}".format)
 
 
 class _ScanBlock(Block):
@@ -1539,26 +1782,24 @@ class Transformer(nn.Module):
             cfg.hidden_size,
             dtype=cfg.dtype,
             param_dtype=cfg.param_dtype,
+            embedding_init=(
+                nn.linear.default_embed_init if cfg.embed_init_std is None
+                else nn.initializers.normal(cfg.embed_init_std)
+            ),
             name="embed",
         )
         x = embed(tokens)
+        if cfg.router_ahead and (cfg.layer_pattern is None or cfg.norm_after_mixer):
+            raise ValueError(
+                "router_ahead is a layer_pattern stack's, of pre-normed layers"
+            )
         if cfg.layer_pattern is not None:
-            # Unlike layers cannot share one scanned body: each is its own
-            # module, ``layers_<i>``, remat'd alone (outside a scan XLA would
-            # otherwise merge the recomputation with the forward pass and
-            # keep every layer's activations: prevent_cse stays on).
-            layer = nn.remat(MixerLayer) if cfg.remat else MixerLayer
-            # Only a rotary attention layer is handed the tables: the
-            # other kinds' calls (and a rope-free stack's) stay as they were.
             rotary_dim = cfg.head_dim if cfg.mla is None else cfg.mla.qk_rope_head_dim
             rotary = (
                 rope_table(positions, rotary_dim, cfg.rope_theta, cfg.dtype)
-                if cfg.rope else ()
+                if cfg.rope or "W" in cfg.layer_pattern else ()
             )
-            for i, kind in enumerate(cfg.layer_pattern):
-                x = layer(cfg, kind, name=f"layers_{i}")(
-                    x, *(rotary if kind == "*" else ())
-                )
+            x = _stack_layers(cfg, cfg.layer_pattern, x, rotary, "layers_{}".format)
             predicted = []
             # The modules serve the training loss alone (and ``init``,
             # which has to meet their parameters).

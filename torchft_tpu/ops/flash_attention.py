@@ -1,4 +1,4 @@
-"""Pallas TPU flash attention (GQA, forward and backward, three masks;
+"""Pallas TPU flash attention (GQA, forward and backward, four masks;
 latent attention's two-part heads under the causal one).
 
 The reference has no attention kernel of its own (it delegates compute to
@@ -9,8 +9,9 @@ K/V blocks through VMEM with an online softmax so scores never leave
 the chip (reference for the FLOPs budget: SURVEY.md §6; technique:
 Dao et al. 2022, standard TPU formulation as in jax's pallas examples).
 
-Three masks, each a mask closure and a skip predicate around the one
-step math (``_fwd_step``, ``_bwd_dq_step``, ``_bwd_dkv_step``):
+Five families of kernels in this file, four masks, each a mask closure
+and a skip predicate (or a sweep of its own) around the one step math
+(``_fwd_step``, ``_bwd_dq_step``, ``_bwd_dkv_step``):
 
 - ``flash_attention``: causal (or none) over one sequence, static.
 - ``flash_attention_block``: causal at GLOBAL offsets that are dynamic
@@ -22,10 +23,13 @@ step math (``_fwd_step``, ``_bwd_dq_step``, ``_bwd_dkv_step``):
   query the clean keys of strictly earlier blocks and the noisy keys of
   its own block, and nothing sees otherwise. L^2 + L*b score entries are
   kept of the 4 L^2 of the square; the sweeps visit the kept tiles only.
-
-``flash_attention_mla`` is the causal mask again over heads of another
-shape: a query and key of two parts (rope-free and rotary, the rotary key
-one a position for all heads) and values of a width of their own.
+- ``flash_attention_window``: causal with a band, row i keeping the
+  ``window`` columns j <= i with i - j < window, static; the sweeps visit
+  only the tiles the band touches (70 of the causal triangle's 136 a head
+  at 16,384 positions, a window of 4,096 and tiles of 1,024).
+- ``flash_attention_mla``: the causal mask again over heads of another
+  shape: a query and key of two parts (rope-free and rotary, the rotary
+  key one a position for all heads) and values of a width of their own.
 
 Layout: model-native [B, S, H, D] in/out (matching
 ``models/llama.py:dense_attention``); internally transposed to
@@ -76,11 +80,15 @@ __all__ = [
     "flash_attention_block",
     "flash_attention_block_diffusion",
     "flash_attention_mla",
+    "flash_attention_window",
     "block_diffusion_tiles",
+    "window_kept",
+    "window_tiles",
     "choose_tiles",
     "supports",
     "supports_block_diffusion",
     "supports_mla",
+    "supports_window",
 ]
 
 _NEG_INF = -1e30
@@ -130,7 +138,9 @@ def choose_tiles(
     shape (the caller then runs dense attention). The one rule of every
     entry and ``supports*`` predicate below.
 
-    ``family``: 'causal', 'block' (the ring's offset block: ``kv_len``
+    ``family``: 'causal', 'window' (the band's tiles are the causal
+    family's: the window decides the sweep, not the tile), 'block' (the
+    ring's offset block: ``kv_len``
     keys against ``seq_len`` queries), 'block_diffusion' (``seq_len`` the
     length of ONE stream, ``block_length`` its blocks) or 'mla'
     (``widths`` = rope-free, rotary and value channels; the other
@@ -1603,4 +1613,336 @@ def flash_attention_mla(
         to_heads(q_nope), to_heads(q_rope), to_heads(k_nope), k_rope[:, None],
         to_heads(v), block_q, block_k, itp,
     )
+    return jnp.swapaxes(out, 1, 2)
+
+
+# ---------------------------------------------------------------------------
+# Banded variant (models/llama.py:Attention of the windowed kind 'W'): causal
+# with a sliding window, row i keeps the columns j <= i with i - j < window,
+# the position itself counted (``window`` keys at most). Static, over one
+# sequence. No kernel's grid walks the causal triangle: a q tile sweeps the
+# kv tiles from the one that holds its first row's oldest key to its
+# diagonal, a kv tile the q tiles from its diagonal to the one that holds the
+# last row that still sees its last column (the mirrored sweep), and the
+# grid's innermost dimension is the LONGEST such sweep (ceil((window - 1) /
+# tile) + 1 steps where the tiles are square). A step past the end of a
+# sweep repeats the sweep's last tile, so nothing is fetched for it. The
+# first tile of a q tile's sweep can hold rows that keep none of it: their
+# running max stays at the mask's value and what they accumulate there is
+# scaled to exactly 0 by the diagonal tile's first finite max.
+# ---------------------------------------------------------------------------
+
+
+def supports_window(
+    seq_len: int, window: int, block_q: int = _MAX_TILE, block_k: int = _MAX_TILE
+) -> bool:
+    """Whether the banded kernels handle this sequence under a window of
+    ``window`` positions and these largest tiles (by shape alone; the
+    caller falls back to dense attention under the band mask otherwise)."""
+    return window >= 1 and choose_tiles("window", seq_len, (), block_q, block_k) is not None
+
+
+def _band_span(i, a, c, back, ahead, n, lo=jnp.maximum, hi=jnp.minimum):
+    """(first, last) of the ``n`` tiles of ``c`` positions that hold any of
+    the positions i*a - back .. i*a + a - 1 + ahead: the kv tiles of q tile
+    ``i`` (``back`` = window - 1, ``ahead`` = 0) or the q tiles of kv tile
+    ``i`` (the mirror). On traced indices, or on Python ints with
+    ``lo=max, hi=min``."""
+    return lo(i * a - back, 0) // c, hi((i * a + a - 1 + ahead) // c, n - 1)
+
+
+def _band_sweeps(seq_len: int, window: int, block_q: int, block_k: int):
+    """Per q tile the kv tiles its sweep runs, per kv tile the q tiles
+    (two lists of Python ints): what the grids are sized from."""
+    nq, nk = seq_len // block_q, seq_len // block_k
+    span = lambda *a: _band_span(*a, lo=max, hi=min)  # noqa: E731
+    kv = [span(i, block_q, block_k, window - 1, 0, nk) for i in range(nq)]
+    q = [span(i, block_k, block_q, 0, window - 1, nq) for i in range(nk)]
+    return [b - a + 1 for a, b in kv], [b - a + 1 for a, b in q]
+
+
+def window_kept(seq_len: int, window: int) -> int:
+    """Score entries the band keeps a head and sequence: row i keeps
+    min(i + 1, window) keys."""
+    w = min(window, seq_len)
+    return w * (w + 1) // 2 + (seq_len - w) * w
+
+
+def window_tiles(
+    seq_len: int, window: int, block_q: int = _MAX_TILE, block_k: int = _MAX_TILE
+):
+    """(kept score entries, score entries of the tiles a sweep runs) a head
+    and sequence, forward, at the tiles the kernels choose under the
+    bounds; the backward kernels run the same tiles. A window of at least
+    the sequence runs the causal schedule."""
+    tiles = choose_tiles("window", seq_len, (), block_q, block_k)
+    if tiles is None:
+        raise ValueError(
+            f"window_tiles: {seq_len} positions do not tile under ({block_q},{block_k})"
+        )
+    sweeps = _band_sweeps(seq_len, min(window, seq_len), *tiles)[0]
+    return window_kept(seq_len, window), sum(sweeps) * tiles[0] * tiles[1]
+
+
+def _window_mask(window, q_start, k_start):
+    def mask_fn(s):
+        rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + q_start
+        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + k_start
+        back = rows - cols
+        return jnp.where((back >= 0) & (back < window), s, _NEG_INF)
+
+    return mask_fn
+
+
+def _flash_window_kernel(
+    q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
+    *, scale: float, window: int, block_q: int, block_k: int, nk: int,
+):
+    iq = pl.program_id(2)
+    j = pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    first, last = _band_span(iq, block_q, block_k, window - 1, 0, nk)
+    ik = first + j
+
+    @pl.when(ik <= last)
+    def _step():
+        _fwd_step(
+            q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, scale,
+            _window_mask(window, iq * block_q, ik * block_k),
+        )
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _finish():
+        _fwd_finish(o_ref, lse_ref, acc_ref, m_ref, l_ref)
+
+
+def _flash_window_bwd_dq_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
+    *, scale: float, window: int, block_q: int, block_k: int, nk: int,
+):
+    iq = pl.program_id(2)
+    j = pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    first, last = _band_span(iq, block_q, block_k, window - 1, 0, nk)
+    ik = first + j
+
+    @pl.when(ik <= last)
+    def _step():
+        _bwd_dq_step(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, None,
+            dq_acc, scale, _window_mask(window, iq * block_q, ik * block_k),
+        )
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _finish():
+        dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
+
+
+def _flash_window_bwd_dkv_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+    dk_acc, dv_acc,
+    *, scale: float, window: int, block_q: int, block_k: int, nq: int, sweep: int,
+):
+    # Grid = (B, Hkv, nk, q_per_kv * sweep): the q-head group and each
+    # head's sweep over the q tiles the band pairs with THIS kv tile are the
+    # one innermost dimension, as in the causal dkv kernel.
+    ik = pl.program_id(2)
+    inner = pl.program_id(3)
+
+    @pl.when(inner == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    first, last = _band_span(ik, block_k, block_q, 0, window - 1, nq)
+    iq = first + inner % sweep
+
+    @pl.when(iq <= last)
+    def _step():
+        _bwd_dkv_step(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, None,
+            dk_acc, dv_acc, scale,
+            _window_mask(window, iq * block_q, ik * block_k),
+        )
+
+    @pl.when(inner == pl.num_programs(3) - 1)
+    def _finish():
+        dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _window_q_sweep_specs(window, q_per_kv, block_q, block_k, nk, D):
+    """Block specs of the kernels whose grid is (B, Hq, q tiles, sweep
+    steps), forward and dq: a q tile's own block (q, o, do, dq), the kv
+    tile its sweep is at (GQA: q head h reads kv head h // q_per_kv), its
+    rows' residuals (lse, delta)."""
+
+    def kv_idx(b, h, iq, j):
+        first, last = _band_span(iq, block_q, block_k, window - 1, 0, nk)
+        return (b, h // q_per_kv, jnp.minimum(first + j, last), 0)
+
+    q_spec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, j: (b, h, iq, 0))
+    kv_spec = pl.BlockSpec((1, 1, block_k, D), kv_idx)
+    row_spec = pl.BlockSpec((1, 1, 8, block_q), lambda b, h, iq, j: (b, h, 0, iq))
+    return q_spec, kv_spec, row_spec
+
+
+def _window_forward_impl(qt, kt, vt, window, block_q, block_k, interpret):
+    B, Hq, S, D = qt.shape
+    nk = S // block_k
+    steps = max(_band_sweeps(S, window, block_q, block_k)[0])
+    q_spec, kv_spec, row_spec = _window_q_sweep_specs(
+        window, Hq // kt.shape[1], block_q, block_k, nk, D
+    )
+    return pl.pallas_call(
+        functools.partial(
+            _flash_window_kernel, scale=1.0 / math.sqrt(D), window=window,
+            block_q=block_q, block_k=block_k, nk=nk,
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, Hq, S, D), qt.dtype),
+            jax.ShapeDtypeStruct((B, Hq, 8, S), jnp.float32),
+        ],
+        grid=(B, Hq, S // block_q, steps),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, row_spec],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, 128), jnp.float32),
+        ],
+        interpret=interpret,
+    )(qt, kt, vt)
+
+
+def _window_backward_impl(
+    qt, kt, vt, do, lse, delta, window, block_q, block_k, interpret
+):
+    B, Hq, S, D = qt.shape
+    Hkv = kt.shape[1]
+    q_per_kv = Hq // Hkv
+    nq, nk = S // block_q, S // block_k
+    kv_sweeps, q_sweeps = _band_sweeps(S, window, block_q, block_k)
+    static = dict(
+        scale=1.0 / math.sqrt(D), window=window, block_q=block_q, block_k=block_k
+    )
+
+    q_spec, kv_spec, row_spec = _window_q_sweep_specs(
+        window, q_per_kv, block_q, block_k, nk, D
+    )
+    dq = pl.pallas_call(
+        functools.partial(_flash_window_bwd_dq_kernel, nk=nk, **static),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, S, D), qt.dtype),
+        grid=(B, Hq, nq, max(kv_sweeps)),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=q_spec,
+        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        interpret=interpret,
+    )(qt, kt, vt, do, lse, delta)
+
+    sweep = max(q_sweeps)
+
+    def q_at(hk, ik, inner):
+        first, last = _band_span(ik, block_k, block_q, 0, window - 1, nq)
+        return hk * q_per_kv + inner // sweep, jnp.minimum(first + inner % sweep, last)
+
+    def q_idx(b, hk, ik, inner):
+        head, iq = q_at(hk, ik, inner)
+        return b, head, iq, 0
+
+    def row_idx(b, hk, ik, inner):
+        head, iq = q_at(hk, ik, inner)
+        return b, head, 0, iq
+
+    q_spec2 = pl.BlockSpec((1, 1, block_q, D), q_idx)
+    row_spec2 = pl.BlockSpec((1, 1, 8, block_q), row_idx)
+    kv_spec2 = pl.BlockSpec(
+        (1, 1, block_k, D), lambda b, hk, ik, inner: (b, hk, ik, 0)
+    )
+    dk, dv = pl.pallas_call(
+        functools.partial(
+            _flash_window_bwd_dkv_kernel, nq=nq, sweep=sweep, **static
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, Hkv, S, D), kt.dtype),
+            jax.ShapeDtypeStruct((B, Hkv, S, D), vt.dtype),
+        ],
+        grid=(B, Hkv, nk, q_per_kv * sweep),
+        in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2, row_spec2],
+        out_specs=[kv_spec2, kv_spec2],
+        scratch_shapes=[
+            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, D), jnp.float32),
+        ],
+        interpret=interpret,
+    )(qt, kt, vt, do, lse, delta)
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_window(qt, kt, vt, window, block_q, block_k, interpret):
+    return _window_forward_impl(qt, kt, vt, window, block_q, block_k, interpret)[0]
+
+
+def _flash_window_fwd(qt, kt, vt, window, block_q, block_k, interpret):
+    out, lse = _window_forward_impl(qt, kt, vt, window, block_q, block_k, interpret)
+    return out, (qt, kt, vt, out, lse)
+
+
+def _flash_window_bwd(window, block_q, block_k, interpret, res, do):
+    qt, kt, vt, out, lse = res
+    return _window_backward_impl(
+        qt, kt, vt, do, lse, _row_delta(do, out), window, block_q, block_k,
+        interpret,
+    )
+
+
+_flash_window.defvjp(_flash_window_fwd, _flash_window_bwd)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("window", "block_q", "block_k", "interpret")
+)
+def flash_attention_window(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    window: int,
+    block_q: int = _MAX_TILE,
+    block_k: int = _MAX_TILE,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Causal GQA flash attention under a sliding window, differentiable:
+    row i keeps the columns j <= i with i - j < ``window``. q: [B,S,Hq,D];
+    k/v: [B,S,Hkv,D] with Hq % Hkv == 0. Returns [B,S,Hq,D] in q's dtype.
+    A window of at least the sequence is the causal mask, and runs the
+    causal kernels. ``block_q``, ``block_k``: the largest tiles to take
+    (``choose_tiles``). The kernels' trace names start
+    ``flash_attention_window`` (a kernel is named for the jit around it)."""
+    B, S, Hq, D = q.shape
+    assert Hq % k.shape[2] == 0, (Hq, k.shape[2])
+    if window < 1:
+        raise ValueError(f"flash_attention_window: window {window} keeps nothing")
+    tiles = choose_tiles("window", S, (D,), block_q, block_k)
+    if tiles is None:
+        raise ValueError(
+            f"flash_attention_window: seq_len {S} not divisible by blocks "
+            f"({block_q},{block_k}); use dense_attention under the band mask"
+        )
+    itp = _interpret() if interpret is None else interpret
+    qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
+    if window >= S:
+        out = _flash(qt, kt, vt, True, *tiles, itp)
+    else:
+        out = _flash_window(qt, kt, vt, window, *tiles, itp)
     return jnp.swapaxes(out, 1, 2)

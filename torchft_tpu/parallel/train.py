@@ -175,9 +175,13 @@ def _apply_with_aux(model: Transformer, params, inputs, **kw):
     """model.apply + what MoEMLP and the delta-rule mixers sow per layer,
     by name and stacked over the layers ({} for a model with none of
     them): ``router_aux``, ``router_z``, ``moe_max_load``, ``moe_dropped``;
-    the ``gdn_*`` and ``kda_*`` counters."""
+    the ``gdn_*`` and ``kda_*`` counters; a windowed attention layer's
+    ``swa_kept_share``."""
     cfg = model.cfg
-    if cfg.num_experts <= 0 and cfg.gated_delta is None and cfg.kda is None:
+    if (
+        cfg.num_experts <= 0 and cfg.gated_delta is None and cfg.kda is None
+        and "W" not in (cfg.layer_pattern or "")
+    ):
         return model.apply({"params": params}, inputs, **kw), {}
     out, inter = model.apply(
         {"params": params}, inputs, mutable=["intermediates"], **kw
@@ -202,6 +206,9 @@ _SOWN_OVER_LAYERS = (
     # entries it computes, the share its mask keeps
     # (``block_diffusion_attention``).
     ("bd_kept_share", jnp.mean),
+    # From the windowed attention layers: of the score entries the tiles
+    # they run hold, the share the band keeps (``window_attention``).
+    ("swa_kept_share", jnp.mean),
     # From the gated-delta mixers (models/gated_delta.py): the largest
     # |entry| of a state at a sequence's end (with eigenvalues down to -1
     # a state that grows is the failure to see), the smallest decay and
@@ -434,7 +441,8 @@ def _loss_and_metrics(model: Transformer, params, inputs, targets, mask):
     model whose step updates its selection biases the vector
     ``moe_load``, under block diffusion ``diffusion_masked_share``
     (masked data positions over data positions) and, sown by its
-    attention, ``bd_kept_share``; from gated-delta mixers
+    attention, ``bd_kept_share``; from windowed attention layers
+    ``swa_kept_share``; from gated-delta mixers
     ``gdn_state_abs_max``, ``gdn_decay_min`` and ``gdn_beta_mean``, from
     Kimi delta mixers the same three as ``kda_*``."""
     cfg = model.cfg
