@@ -2,8 +2,8 @@
 rotary part, one rotary key a position for all heads, values of a width of
 their own) and a multi-token-prediction module in the program, against the
 benchmark's plain reference at a small size on the CPU, in float32 with
-seeded weights; the latent Pallas kernels, interpreted, against the dense
-path; the two rotary pairings; the shares of an expert layer against the
+seeded weights (the latent Pallas kernels' own tests are in
+tests/test_flash_attention.py); the two rotary pairings; the shares of an expert layer against the
 whole layer; a sharded mesh; two replicas under Managers; the presets and
 ``train_hsdp.py --model joyai_flash``."""
 
@@ -26,9 +26,7 @@ from torchft_tpu.models.llama import MoEMLP, apply_rope, rope_table
 from torchft_tpu.models.mla import (
     LatentAttention,
     apply_rope_interleaved,
-    latent_dense_attention,
 )
-from torchft_tpu.ops.flash_attention import flash_attention_mla, supports_mla
 from torchft_tpu.parallel import auto_mesh, make_mesh
 from torchft_tpu.parallel.sharding import param_specs
 from torchft_tpu.parallel.train import (
@@ -104,75 +102,6 @@ def _setup(c, seq, batch=2, seed=0, **cfg_overrides):
     data = _data(c["vocab_size"], batch, seq, seed + 1)
     params = model.init(jax.random.PRNGKey(seed), data["inputs"])["params"]
     return model, mesh, params, data
-
-
-# -- (a) the kernels ---------------------------------------------------------
-
-
-def _per_head_dense(q_nope, q_rope, k_nope, k_rope_heads, v):
-    """Dense causal attention over 192-wide queries and keys joined, the
-    rotary key given for EVERY head ([B,S,H,Dr])."""
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    k = jnp.concatenate([k_nope, k_rope_heads], axis=-1)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
-    keep = jnp.tril(jnp.ones((q.shape[1],) * 2, dtype=bool))
-    p = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
-
-
-@pytest.mark.parametrize("seq,heads,widths,blocks", [
-    (128, 3, (32, 16, 32), (32, 32)),   # 4 x 4 tiles: the skipped tiles' clamped index maps
-    (96, 2, (48, 16, 32), (48, 32)),    # unlike tiles, the cell's ratio of widths 192 | 128
-    (64, 4, (16, 8, 24), (64, 64)),     # one tile
-    (128, 1, (128, 64, 128), (64, 64)),  # the published widths, one head
-])
-def test_the_latent_kernels_are_the_dense_path_at_unlike_widths(seq, heads, widths, blocks):
-    """Interpreted, float32: the output and the gradients of both parts of
-    the queries, both parts of the keys and the values against the dense
-    path, and against dense attention over joined 192-wide heads in which
-    every head has a rotary key of its own: the shared key's gradient is
-    the sum of those over the heads."""
-    dn, dr, dv = widths
-    keys = jax.random.split(jax.random.PRNGKey(seq + heads), 6)
-    q_nope, k_nope = (jax.random.normal(k, (2, seq, heads, dn)) for k in keys[:2])
-    q_rope = jax.random.normal(keys[2], (2, seq, heads, dr))
-    k_rope = jax.random.normal(keys[3], (2, seq, dr))
-    v, w = (jax.random.normal(k, (2, seq, heads, dv)) for k in keys[4:])
-    assert supports_mla(seq, dn, dr, dv, *blocks)
-
-    def flash(*a):
-        return flash_attention_mla(*a, block_q=blocks[0], block_k=blocks[1])
-
-    args = (q_nope, q_rope, k_nope, k_rope, v)
-    assert flash(*args).shape == (2, seq, heads, dv)
-    assert jnp.allclose(flash(*args), latent_dense_attention(*args), atol=2e-5)
-    grads = lambda f, *a: jax.grad(  # noqa: E731
-        lambda *b: (f(*b) * w).sum(), argnums=(0, 1, 2, 3, 4))(*a)
-    got, want = grads(flash, *args), grads(latent_dense_attention, *args)
-    for g, r, name in zip(got, want, ("dq_nope", "dq_rope", "dk_nope", "dk_rope", "dv")):
-        assert g.shape == r.shape and jnp.allclose(g, r, atol=1e-4), (
-            name, float(jnp.abs(g - r).max()))
-    a_head = jnp.broadcast_to(k_rope[:, :, None], (2, seq, heads, dr))
-    per_head = grads(_per_head_dense, q_nope, q_rope, k_nope, a_head, v)
-    assert jnp.allclose(got[3], per_head[3].sum(axis=2), atol=1e-4)
-    assert float(jnp.abs(per_head[3][:, :, 0] - got[3]).max()) > 1e-2 or heads == 1
-    for g, r in zip(got[:3] + got[4:], per_head[:3] + per_head[4:]):
-        assert jnp.allclose(g, r, atol=1e-4)
-
-
-def test_the_latent_family_refuses_what_it_does_not_compute(monkeypatch):
-    from torchft_tpu.ops import flash_attention
-
-    assert not supports_mla(100, 128, 64, 128)  # no whole tiles
-    monkeypatch.setattr(flash_attention, "_interpret", lambda: False)
-    assert supports_mla(8192, 128, 64, 128) and supports_mla(8192, 128, 128, 256)
-    assert not supports_mla(8192, 96, 64, 128) and not supports_mla(8192, 128, 64, 64)
-    assert not supports_mla(8192, 128, 48, 128)
-    with pytest.raises(ValueError, match="latent_dense_attention"):
-        flash_attention_mla(
-            jnp.zeros((1, 128, 2, 96)), jnp.zeros((1, 128, 2, 64)),
-            jnp.zeros((1, 128, 2, 96)), jnp.zeros((1, 128, 64)),
-            jnp.zeros((1, 128, 2, 128)))
 
 
 # -- (b) the rotary pairing ---------------------------------------------------

@@ -3,8 +3,8 @@ a clean stream through one trunk under the block-diffusion mask, a 1/t
 weighted loss over masked positions from noise that is a pure function of
 the batch, a renormalised softmax router over experts of which a share is
 held) against the benchmark's plain reference at a small size on the CPU,
-in float32 with seeded weights; the Pallas kernels, interpreted, against
-dense masked attention; what each stream may and may not see; the shares
+in float32 with seeded weights (the Pallas kernels' own tests are in
+tests/test_flash_attention.py); what each stream may and may not see; the shares
 of an expert layer against the whole layer; two replicas under Managers;
 the presets and ``train_hsdp.py --model sdar_moe``."""
 
@@ -23,12 +23,7 @@ from benchmark import cells
 from benchmark.tests import test_sdar_reference as _reference_tests
 from torchft_tpu.coordination import LighthouseServer
 from torchft_tpu.models import llama, sdar_30b_a3b, sdar_moe_debug
-from torchft_tpu.models.llama import MoEMLP, block_diffusion_attention, block_diffusion_mask
-from torchft_tpu.ops.flash_attention import (
-    block_diffusion_tiles,
-    flash_attention_block_diffusion,
-    supports_block_diffusion,
-)
+from torchft_tpu.models.llama import MoEMLP, block_diffusion_attention
 from torchft_tpu.parallel import auto_mesh
 from torchft_tpu.parallel.train import (
     TrainState,
@@ -90,58 +85,6 @@ def _leaf_errors(got, want):
         lambda a, b: float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b)), got, want
     )
     return {jax.tree_util.keystr(k): v for k, v in jax.tree_util.tree_leaves_with_path(errs)}
-
-
-def _dense_masked(q, k, v, see):
-    g = q.shape[2] // k.shape[2]
-    k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
-    p = jax.nn.softmax(jnp.where(see[None, None], s, -jnp.inf), axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
-
-
-# -- (a) the kernels ---------------------------------------------------------
-
-
-@pytest.mark.parametrize("b,length,block", [(4, 64, 32), (32, 128, 32), (4, 48, 16), (12, 96, 48)])
-def test_the_kernels_are_dense_attention_under_the_reference_mask(b, length, block):
-    """Interpreted, float32, four query heads on two key/value heads, two
-    to four tiles a stream: outputs and all three gradients against dense
-    attention over [x_t | x_0] under the REFERENCE's mask (the program's
-    own dense mask is held to it too)."""
-    keys = jax.random.split(jax.random.PRNGKey(b), 4)
-    q = jax.random.normal(keys[0], (2, 2 * length, 4, 16))
-    k, v = (jax.random.normal(key, (2, 2 * length, 2, 16)) for key in keys[1:3])
-    w = jax.random.normal(keys[3], q.shape)
-    see = reference.visible(length, b)
-    assert jnp.array_equal(block_diffusion_mask(length, b), see)
-    assert supports_block_diffusion(length, b, block) and length // block >= 2
-
-    def flash(q, k, v):
-        return flash_attention_block_diffusion(q, k, v, block_length=b, block=block)
-
-    assert jnp.allclose(flash(q, k, v), _dense_masked(q, k, v, see), atol=2e-5)
-    got = jax.grad(lambda *a: (flash(*a) * w).sum(), argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: (_dense_masked(*a, see) * w).sum(), argnums=(0, 1, 2))(q, k, v)
-    for g, r in zip(got, want):
-        assert jnp.allclose(g, r, atol=5e-5), float(jnp.abs(g - r).max())
-
-
-def test_the_tile_schedule_counts_what_the_sweeps_run():
-    # n^2 + 2n tiles of the 4 n^2: 80 of 256 at the 8 tiles a stream the
-    # kernels choose, 288 of 1,024 at 16 where the tiles are held to 512
-    assert block_diffusion_tiles(8192, 4) == (8192 * 8192 + 8192 * 4, 80 * 1024 * 1024)
-    assert block_diffusion_tiles(8192, 4, 512) == (8192 * 8192 + 8192 * 4, 288 * 512 * 512)
-    assert block_diffusion_tiles(64, 4, 32) == (64 * 64 + 64 * 4, 8 * 32 * 32)
-    assert not supports_block_diffusion(1024, 24)  # a tile would cut a block
-    assert not supports_block_diffusion(1000, 4) and not supports_block_diffusion(64, 0)
-    with pytest.raises(ValueError, match="do not tile"):
-        flash_attention_block_diffusion(*(jnp.zeros((1, 96, 2, 16)),) * 3, block_length=5)
-    cfg = sdar_moe_debug(attn_impl="flash", flash_min_seq=64, flash_block_q=32, flash_block_k=32)
-    assert block_diffusion_attention(cfg, 128) == ("flash", pytest.approx(4352 / 8192))
-    assert block_diffusion_attention(cfg, 32) == ("dense", pytest.approx((256 + 64) / 1024))
-    with pytest.raises(ValueError, match="whole blocks"):
-        block_diffusion_attention(cfg, 36)  # 18 positions a stream: no whole blocks of 4
 
 
 # -- (b) the system against the reference -------------------------------------

@@ -1,7 +1,5 @@
-"""SmallThinker-21BA3B: the banded (sliding-window) flash kernels against
-plain attention under the band mask, values and gradients, through the
-interpreter, and the tiles their grids run against the band's own count;
-the program's stack (a global rope-free attention and three windowed
+"""SmallThinker-21BA3B (its banded flash kernels' own tests are in
+tests/test_flash_attention.py): the program's stack (a global rope-free attention and three windowed
 rotary ones, an expert layer of ReGLU experts after each, its router
 reading the attention's input) against the benchmark's plain reference at
 a small size on the CPU, in float32 with seeded weights; the expert shares
@@ -26,11 +24,9 @@ from torchft_tpu.models import PRESETS, llama
 from torchft_tpu.models.llama import (
     Attention,
     MoEMLP,
-    dense_attention,
     smallthinker_21b,
     smallthinker_debug,
     window_attention,
-    window_mask,
 )
 from torchft_tpu.ops import flash_attention as fa
 from torchft_tpu.parallel import auto_mesh, make_mesh
@@ -55,108 +51,6 @@ tiny, PUBLISHED = _reference_tests.tiny, _reference_tests.PUBLISHED
 for _name, _obj in vars(_reference_tests).items():
     if _name.startswith("test_") and callable(_obj):
         globals()[_name] = _obj
-
-
-# -- (a) the banded kernels are attention under the band mask -----------------------
-
-
-def _qkv(seq, hq=7, hkv=1, d=16, batch=2, seed=0):
-    k = jax.random.split(jax.random.PRNGKey(seed), 4)
-    return (jax.random.normal(k[0], (batch, seq, hq, d)),
-            jax.random.normal(k[1], (batch, seq, hkv, d)),
-            jax.random.normal(k[2], (batch, seq, hkv, d)),
-            jax.random.normal(k[3], (batch, seq, hq, d)))
-
-
-# (sequence, window, largest q tile, largest kv tile): a window below the
-# sequence in whole tiles, one that is no multiple of the tile, of one tile
-# and a row more, of one position, equal to the sequence, above it, and
-# unequal tiles either way.
-BANDS = [(128, 64, 32, 32), (128, 48, 32, 32), (128, 33, 32, 32), (128, 1, 32, 32),
-         (128, 128, 32, 32), (128, 500, 32, 32), (128, 40, 64, 32), (128, 40, 32, 64),
-         (96, 50, 32, 32)]
-
-
-@pytest.mark.parametrize("seq,window,bq,bk", BANDS)
-def test_the_banded_kernels_are_attention_under_the_band_mask(seq, window, bq, bk):
-    """Forward, dq, dk and dv, seven query heads a key/value head."""
-    q, k, v, do = _qkv(seq)
-    flash = lambda q, k, v: fa.flash_attention_window(  # noqa: E731
-        q, k, v, window=window, block_q=bq, block_k=bk)
-    plain = lambda q, k, v: dense_attention(q, k, v, mask=window_mask(seq, window))  # noqa: E731
-    assert jnp.allclose(flash(q, k, v), plain(q, k, v), atol=1e-5)
-    got = jax.grad(lambda *a: jnp.sum(flash(*a) * do), (0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(plain(*a) * do), (0, 1, 2))(q, k, v)
-    for name, a, b in zip(("dq", "dk", "dv"), got, want):
-        assert jnp.allclose(a, b, atol=2e-5), name
-
-
-@pytest.mark.parametrize("window", [128, 129, 4096])
-def test_a_window_of_at_least_the_sequence_is_the_causal_family_bit_for_bit(window):
-    q, k, v, do = _qkv(128, hq=4, hkv=2)
-    banded = lambda *a: fa.flash_attention_window(*a, window=window, block_q=32, block_k=32)  # noqa: E731
-    causal = lambda *a: fa.flash_attention(*a, block_q=32, block_k=32)  # noqa: E731
-    assert np.array_equal(banded(q, k, v), causal(q, k, v))
-    got = jax.grad(lambda *a: jnp.sum(banded(*a) * do), (0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(causal(*a) * do), (0, 1, 2))(q, k, v)
-    assert all(np.array_equal(a, b) for a, b in zip(got, want))
-    # one position short of it is not
-    short = fa.flash_attention_window(q, k, v, window=127, block_q=32, block_k=32)
-    assert not np.array_equal(short, causal(q, k, v))
-
-
-@pytest.mark.parametrize("seq,window,bq,bk", BANDS + [(16384, 4096, 1024, 1024),
-                                                       (16384, 4096, 512, 512),
-                                                       (8192, 1000, 1024, 1024)])
-def test_the_grids_run_the_tiles_the_band_touches_and_no_others(seq, window, bq, bk):
-    """Both sweeps (a q tile's kv tiles, forward and dq; a kv tile's q
-    tiles, dkv) hold exactly the tile pairs with a kept entry, counted here
-    pair by pair; the grid's innermost dimension is the longest sweep."""
-    w = min(window, seq)
-    nq, nk = seq // bq, seq // bk
-
-    def touched(iq, ik):  # any row i of the q tile with a kept column j of the kv tile
-        lo_i, hi_i, lo_j, hi_j = iq * bq, iq * bq + bq - 1, ik * bk, ik * bk + bk - 1
-        return lo_j <= hi_i and hi_j >= lo_i - (w - 1)
-
-    pairs = {(iq, ik) for iq in range(nq) for ik in range(nk) if touched(iq, ik)}
-    kv_sweeps, q_sweeps = fa._band_sweeps(seq, w, bq, bk)
-    assert kv_sweeps == [sum((iq, ik) in pairs for ik in range(nk)) for iq in range(nq)]
-    assert q_sweeps == [sum((iq, ik) in pairs for iq in range(nq)) for ik in range(nk)]
-    assert sum(kv_sweeps) == sum(q_sweeps) == len(pairs)
-    if bq == bk:
-        assert max(kv_sweeps) == max(q_sweeps) == min(-(-(w - 1) // bq) + 1, nq)
-    kept, run = fa.window_tiles(seq, window, bq, bk)
-    assert run == len(pairs) * bq * bk
-    assert kept == sum(min(i + 1, w) for i in range(seq))
-    assert kept == flops.window_kept_entries(dict(PUBLISHED, sliding_window_size=window), seq)
-
-
-def test_the_cells_schedule_is_seventy_tiles_of_the_causal_136():
-    kept, run = fa.window_tiles(16384, 4096)
-    assert (kept, run) == (58_722_304, 70 * 1024 * 1024)
-    assert kept / run == pytest.approx(0.800, abs=5e-4)
-    assert sum(fa._band_sweeps(16384, 16384, 1024, 1024)[0]) == 136
-    assert fa.window_tiles(16384, 4096, 512, 512)[1] == 252 * 512 * 512
-    assert fa.supports_window(16384, 4096) and fa.supports_window(1024, 4096)
-    assert not fa.supports_window(16384 + 8, 4096) and not fa.supports_window(16384, 0)
-    with pytest.raises(ValueError, match="window"):
-        fa.flash_attention_window(*_qkv(64)[:3], window=0)
-    with pytest.raises(ValueError, match="not divisible"):
-        fa.flash_attention_window(*_qkv(1000)[:3], window=100, block_q=48, block_k=48)
-
-
-def test_the_other_families_programs_are_what_they_were():
-    """The band is a mask closure and a sweep of its own: the four older
-    entries trace to the jaxprs they traced to without this file's fifth
-    family (their text names no window and their grids are the old)."""
-    q, k, v, _ = _qkv(128, hq=4, hkv=2)
-    text = str(jax.make_jaxpr(
-        lambda *a: fa.flash_attention(*a, block_q=32, block_k=32))(q, k, v))
-    assert "grid=(2, 4, 4, 4)" in text and "window" not in text
-    banded = str(jax.make_jaxpr(
-        lambda *a: fa.flash_attention_window(*a, window=40, block_q=32, block_k=32))(q, k, v))
-    assert "grid=(2, 4, 4, 3)" in banded and "flash_attention_window" in banded
 
 
 # -- (b) the program against the reference -----------------------------------------

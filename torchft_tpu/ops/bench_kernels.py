@@ -280,35 +280,33 @@ def _sweep_case(family, dims, widths, block_length, tiles, interpret):
     rand = lambda i, *shape: jax.random.normal(  # noqa: E731
         jax.random.PRNGKey(i), shape, jnp.float32
     ).astype(jnp.bfloat16)
+    # One name a stage for every family: the ``custom_vjp``, the forward that
+    # keeps its residuals, the backward given them; a family is its value.
     if family == "mla":
         dn, dr, dv = widths
         args = (rand(0, B, Hq, S, dn), rand(1, B, Hq, S, dr), rand(2, B, Hq, S, dn),
                 rand(3, B, 1, S, dr), rand(4, B, Hq, S, dv))
-        fwd = lambda *a: fa._flash_mla(*a, bq, bk, interpret)  # noqa: E731
-        res = lambda *a: fa._mla_forward_impl(*a, bq, bk, interpret)  # noqa: E731
-        bwd = lambda a, do, out, lse: fa._mla_backward_impl(  # noqa: E731
-            *a, do, lse, fa._row_delta(do, out), bq, bk, interpret
-        )
+        value = fa._Causal(S, S, bq, bk)
         split, entries, width = 2, S * S / 2, dn + dr + dv
     else:
         (d,) = widths
         args = (rand(0, B, Hq, S, d), rand(1, B, Hkv, S, d), rand(2, B, Hkv, S, d))
         if family == "causal":
-            fwd = lambda *a: fa._flash(*a, True, bq, bk, interpret)  # noqa: E731
-            res = lambda *a: fa._forward_impl(*a, True, bq, bk, interpret)  # noqa: E731
-            bwd = lambda a, do, out, lse: fa._backward_impl(  # noqa: E731
-                *a, do, lse, fa._row_delta(do, out), True, bq, bk, interpret
-            )
-            entries = S * S / 2
+            value, entries = fa._Causal(S, S, bq, bk), S * S / 2
         else:
-            fwd = lambda *a: fa._flash_bd(*a, block_length, bq, interpret)  # noqa: E731
-            res = lambda *a: fa._bd_forward_impl(*a, block_length, bq, interpret)  # noqa: E731
-            bwd = lambda a, do, out, lse: fa._bd_backward_impl(  # noqa: E731
-                *a, do, lse, fa._row_delta(do, out), block_length, bq, interpret
-            )
             L = S // 2
-            entries = L * L + L * block_length
+            value, entries = fa._BlockDiffusion(S, S, bq, bq, block_length), L * L + L * block_length
         split, width = 1, 2 * d
+    parts = lambda a: (a[:split], a[split:-1], a[-1])  # noqa: E731 - q's parts, k's, v
+    fwd = lambda *a: fa._flash(value, (), *parts(a), interpret)  # noqa: E731
+    res = lambda *a: fa._forward_impl(value, (), *parts(a), interpret)  # noqa: E731
+
+    def bwd(a, do, out, lse):
+        dq, dk, dv_ = fa._backward_impl(
+            value, (), *parts(a), do, lse, fa._row_delta(do, out), None, interpret
+        )
+        return (*dq, *dk, dv_)
+
     flops = 2.0 * width * entries * Hq * B
     return args, fwd, res, bwd, split, flops
 

@@ -9,27 +9,52 @@ K/V blocks through VMEM with an online softmax so scores never leave
 the chip (reference for the FLOPs budget: SURVEY.md §6; technique:
 Dao et al. 2022, standard TPU formulation as in jax's pallas examples).
 
-Five families of kernels in this file, four masks, each a mask closure
-and a skip predicate (or a sweep of its own) around the one step math
-(``_fwd_step``, ``_bwd_dq_step``, ``_bwd_dkv_step``):
+One scaffold, five families. The step math (``_fwd_step``,
+``_bwd_dq_step``, ``_bwd_dkv_step``), the three kernel bodies around it
+(``_fwd_kernel``, ``_dq_kernel``, ``_dkv_kernel``: init at a sweep's first
+step, the step under ``pl.when(run)``, finish at its last), the two
+drivers that build block specs, out shapes and scratch (``_forward_impl``,
+``_backward_impl``) and the ``custom_vjp`` (``_flash``) are written once. A
+family is a small frozen value (``_Family``) that answers four questions,
+and is the only thing that answers them:
 
-- ``flash_attention``: causal (or none) over one sequence, static.
-- ``flash_attention_block``: causal at GLOBAL offsets that are dynamic
-  scalars, for the ring (one streamed k/v block a call, merged by lse).
-- ``flash_attention_block_diffusion``: the training mask of block
-  diffusion (arXiv:2503.09573) over two streams of L positions laid end
-  to end, a noisy x_t then the clean x_0, with blocks of ``b`` positions:
-  a clean query sees the clean keys of its own and earlier blocks, a noisy
-  query the clean keys of strictly earlier blocks and the noisy keys of
-  its own block, and nothing sees otherwise. L^2 + L*b score entries are
-  kept of the 4 L^2 of the square; the sweeps visit the kept tiles only.
-- ``flash_attention_window``: causal with a band, row i keeping the
-  ``window`` columns j <= i with i - j < window, static; the sweeps visit
-  only the tiles the band touches (70 of the causal triangle's 136 a head
-  at 16,384 positions, a window of 4,096 and tiles of 1,024).
-- ``flash_attention_mla``: the causal mask again over heads of another
-  shape: a query and key of two parts (rope-free and rotary, the rotary
-  key one a position for all heads) and values of a width of their own.
+- the q-sweep (forward and dq): a q tile's span (``q_span``: what the
+  steps of its sweep share), then for step ``j`` the kv tile and whether
+  the step runs (``q_sweep``) or the tile the index map names, which
+  repeats a tile the sweep holds where the step does not run, so nothing
+  is fetched for it (``q_fetch``); ``q_steps`` sizes the grid;
+- the kv-sweep (dkv), the mirror: ``kv_span``, ``kv_sweep``, ``kv_fetch``,
+  and ``kv_sweeps``, the runs of kv tiles that are one call each;
+- the mask closure of a (q tile, kv tile) pair (``mask``);
+- its operands: how many SMEM scalars lead them (``scalars``) and whether
+  ``lse`` is an output with a cotangent (``lse_cotangent``). A tensor is a
+  tuple of parts (the latent family's query and key are two).
+
+A sixth family is a sixth subclass beside these and an entry that builds
+its value; nothing in the frame names a family.
+
+- ``flash_attention`` (``_Causal``): causal (or none) over one sequence,
+  static.
+- ``flash_attention_block`` (``_Offset``): causal at GLOBAL offsets that
+  are dynamic scalars, for the ring (one streamed k/v block a call, merged
+  by lse).
+- ``flash_attention_block_diffusion`` (``_BlockDiffusion``): the training
+  mask of block diffusion (arXiv:2503.09573) over two streams of L
+  positions laid end to end, a noisy x_t then the clean x_0, with blocks
+  of ``b`` positions: a clean query sees the clean keys of its own and
+  earlier blocks, a noisy query the clean keys of strictly earlier blocks
+  and the noisy keys of its own block, and nothing sees otherwise.
+  L^2 + L*b score entries are kept of the 4 L^2 of the square; the sweeps
+  visit the kept tiles only.
+- ``flash_attention_window`` (``_Window``): causal with a band, row i
+  keeping the ``window`` columns j <= i with i - j < window, static; the
+  sweeps visit only the tiles the band touches (70 of the causal
+  triangle's 136 a head at 16,384 positions, a window of 4,096 and tiles
+  of 1,024).
+- ``flash_attention_mla`` (``_Causal`` again): the causal mask over heads
+  of another shape: a query and key of two parts (rope-free and rotary,
+  the rotary key one a position for all heads) and values of a width of
+  their own.
 
 Layout: model-native [B, S, H, D] in/out (matching
 ``models/llama.py:dense_attention``); internally transposed to
@@ -66,9 +91,10 @@ Pallas interpreter (same gating as ``ops/quantization.py``).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -183,29 +209,24 @@ def supports(
 
 
 # ---------------------------------------------------------------------------
-# Shared per-block step math. Every kernel below (causal and offset-block,
-# forward and backward) delegates here so the numerics live in exactly one
-# place; kernels differ only in their mask closure and skip predicate.
+# Shared per-block step math. Every kernel below (forward and backward, every
+# family) delegates here so the numerics live in exactly one place; a family
+# differs only in the mask closure it hands over. A tensor arrives as a TUPLE
+# of refs, a part each: the latent family's score is a sum of two
+# contractions (a rope-free and a rotary part of each query and key), every
+# other family's tensors are tuples of one.
 # All matmuls run in the INPUT dtype (bf16 hits the MXU at full rate; fp32
 # would be emulated) with fp32 accumulation; softmax math stays fp32.
 # ---------------------------------------------------------------------------
 
 
-def _parts(x) -> tuple:
-    """The latent family's score is a sum of two contractions (a rope-free
-    and a rotary part of each query and key), so its kernels hand the step
-    math TUPLES of refs and of accumulators, a part each; every other
-    family hands one ref, which is a tuple of one."""
-    return x if isinstance(x, tuple) else (x,)
-
-
-def _scores(q_ref, k_ref, scale, mask_fn):
+def _scores(q, k, scale, mask_fn):
     s = functools.reduce(jnp.add, [
         jax.lax.dot_general(
-            q[0, 0], k[0, 0], (((1,), (1,)), ((), ())),
+            q_part[0, 0], k_part[0, 0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        for q, k in zip(_parts(q_ref), _parts(k_ref))
+        for q_part, k_part in zip(q, k)
     ]) * scale  # [block_q, block_k] fp32
     return mask_fn(s)
 
@@ -217,7 +238,7 @@ def _lanes_to(x, width: int):
     return jnp.broadcast_to(x[:, :1], (x.shape[0], width))
 
 
-def _fwd_step(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, scale, mask_fn):
+def _fwd_step(q, k, v_ref, acc_ref, m_ref, l_ref, scale, mask_fn):
     """One online-softmax accumulation of a kv block into the scratch.
     The softmax state is kept by the lane: ``m_ref`` holds the running row
     max replicated across its 128 lanes, ``l_ref`` PARTIAL row sums a row,
@@ -227,7 +248,7 @@ def _fwd_step(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, scale, mask_fn):
     ``_fwd_finish`` reduces them across the lanes once. alpha is computed
     on the replicated form, so the max is the one cross-lane reduction a
     tile and nothing is broadcast to be stored."""
-    s = _scores(q_ref, k_ref, scale, mask_fn)
+    s = _scores(q, k, scale, mask_fn)
     block_k = s.shape[1]
     w = math.gcd(block_k, _LANES)
     v = v_ref[0, 0]
@@ -265,13 +286,12 @@ def _fwd_finish(o_ref, lse_ref, acc_ref, m_ref, l_ref):
     lse_ref[0, 0] = jnp.broadcast_to(lse[None, :], (8, lse.shape[0]))
 
 
-def _bwd_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref,
-            scale, mask_fn):
+def _bwd_ds(q, k, v_ref, do_ref, lse_ref, delta_ref, dlse_ref, scale, mask_fn):
     """Recomputes P and the softmax-jacobian term dS for a block.
-    ``dlse_ref`` is None when the caller's lse output carries no cotangent
-    (plain flash_attention returns only out); for the block variant
+    ``dlse_ref`` is None when the family's lse output carries no cotangent
+    (plain flash_attention returns only out); for the offset block
     d lse_i / d s_ij = p_ij folds the lse cotangent straight into dS."""
-    s = _scores(q_ref, k_ref, scale, mask_fn)
+    s = _scores(q, k, scale, mask_fn)
     lse = lse_ref[0, 0, 0][:, None]  # [block_q, 1]
     delta = delta_ref[0, 0, 0][:, None]
     p = jnp.exp(s - lse)  # [block_q, block_k] fp32 (normalized)
@@ -287,340 +307,726 @@ def _bwd_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref,
     return p, p * dsum
 
 
-def _bwd_dq_step(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref,
-                 dq_acc, scale, mask_fn):
-    _, ds = _bwd_ds(
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref,
-        scale, mask_fn,
-    )
-    for k_part, acc in zip(_parts(k_ref), _parts(dq_acc)):
-        k = k_part[0, 0]
+def _bwd_dq_step(q, k, residuals, dq_acc, scale, mask_fn):
+    _, ds = _bwd_ds(q, k, *residuals, scale, mask_fn)
+    for k_part, acc in zip(k, dq_acc):
+        kk = k_part[0, 0]
         acc[:] = acc[:] + jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            ds.astype(kk.dtype), kk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
 
 
-def _bwd_dkv_step(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref,
-                  dk_acc, dv_acc, scale, mask_fn):
-    p, ds = _bwd_ds(
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref,
-        scale, mask_fn,
-    )
-    qs = [q[0, 0] for q in _parts(q_ref)]
-    do = do_ref[0, 0]
+def _bwd_dkv_step(q, k, residuals, dk_acc, dv_acc, scale, mask_fn):
+    p, ds = _bwd_ds(q, k, *residuals, scale, mask_fn)
+    qs = [q_part[0, 0] for q_part in q]
+    do = residuals[1][0, 0]
     # dv += P^T @ dO
     dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
         p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
     # dk += dS^T @ Q * scale, a part of the key each
-    for q, acc in zip(qs, _parts(dk_acc)):
+    for qq, acc in zip(qs, dk_acc):
         acc[:] = acc[:] + jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            ds.astype(qq.dtype), qq, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
 
 
-def _static_mask(causal, q_start, k_start):
-    def mask_fn(s):
-        if not causal:
+# ---------------------------------------------------------------------------
+# The families. Tile indices are traced scalars inside a kernel or an index
+# map and plain ints in the tests, which walk the sweeps as the grids do.
+# ---------------------------------------------------------------------------
+
+
+class _Sweep(NamedTuple):
+    """One dkv call's kv tiles: ``tiles`` of them from tile ``first`` on,
+    each swept in ``steps`` steps a q head."""
+    first: int
+    tiles: int
+    steps: int
+
+
+@dataclasses.dataclass(frozen=True)
+class _Family:
+    """What the frame asks of a family (the module's docstring has the four
+    questions). Hashable and of static ints: it is a non-differentiable
+    argument of the ``custom_vjp``. A sweep is asked in two steps: a tile's
+    span (``q_span``, ``kv_span``: what its steps share, by default the
+    tile itself), then step by step the tile the sweep is at and whether
+    it runs (``q_sweep``, ``kv_sweep``) or the tile to fetch (``q_fetch``,
+    ``kv_fetch``). ``scalars`` SMEM scalars lead a call's operands and are
+    handed to ``q_sweep``, ``kv_sweep`` and ``mask`` after the tiles; the
+    index maps do not see them. This base is the full rectangle of tiles,
+    every step run."""
+    q_len: int
+    kv_len: int
+    block_q: int
+    block_k: int
+
+    scalars = 0
+    lse_cotangent = False
+
+    @property
+    def nq(self) -> int:
+        return self.q_len // self.block_q
+
+    @property
+    def nk(self) -> int:
+        return self.kv_len // self.block_k
+
+    @property
+    def q_steps(self) -> int:
+        return self.nk
+
+    @property
+    def kv_sweeps(self) -> tuple:
+        return (_Sweep(0, self.nk, self.nq),)
+
+    def q_span(self, iq):
+        return iq
+
+    def q_sweep(self, span, j, *scalars):
+        return j, True
+
+    def q_fetch(self, span, j):
+        return j
+
+    def kv_span(self, sweep, ik):
+        return ik
+
+    def kv_sweep(self, span, s, *scalars):
+        return s, True
+
+    def kv_fetch(self, span, s):
+        return s
+
+    def mask(self, iq, ikv, *scalars):
+        return lambda s: s
+
+    def _starts(self, iq, ikv):
+        return iq * self.block_q, ikv * self.block_k
+
+
+def _keep(s, kept):
+    return jnp.where(kept, s, _NEG_INF)
+
+
+def _positions(s, q_start, k_start):
+    """(row, column) positions of a score tile that starts at these."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + q_start
+    cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + k_start
+    return rows, cols
+
+
+@dataclasses.dataclass(frozen=True)
+class _Causal(_Family):
+    """Causal over one sequence, the grids the whole (nq, nk) rectangle: a
+    tile strictly above the diagonal is skipped (no q row attends into it)
+    and its index CLAMPED to the diagonal tile's, so the index map repeats
+    and the (otherwise wasted) DMA is elided. ``causal=False``: the base."""
+    causal: bool = True
+
+    def _runs(self, iq, ikv):
+        q_start, k_start = self._starts(iq, ikv)
+        return (not self.causal) or (k_start <= q_start + self.block_q - 1)
+
+    def q_span(self, iq):
+        if not self.causal:
+            return iq, None
+        return iq, (iq * self.block_q + self.block_q - 1) // self.block_k
+
+    def q_sweep(self, span, j):
+        # On positions, as the kernels have always traced it (j <= the
+        # span's last tile says the same; the compiler drops what is unused).
+        return j, self._runs(span[0], j)
+
+    def q_fetch(self, span, j):
+        return j if span[1] is None else jnp.minimum(j, span[1])
+
+    def kv_sweep(self, ik, s):
+        return s, self._runs(s, ik)
+
+    def kv_fetch(self, ik, s):
+        # The first q tile of the sweep is traced here and not in a span: an
+        # index map then computes it after the head's index, as it always has.
+        if not self.causal:
             return s
-        rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + q_start
-        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + k_start
-        return jnp.where(rows >= cols, s, _NEG_INF)
+        return jnp.maximum(s, (ik * self.block_k) // self.block_q)
 
-    return mask_fn
+    def mask(self, iq, ikv):
+        if not self.causal:
+            return lambda s: s
+        starts = self._starts(iq, ikv)
 
+        def mask_fn(s):
+            rows, cols = _positions(s, *starts)
+            return _keep(s, rows >= cols)
 
-def _dynamic_mask(q_start, k_start, qoff, koff):
-    def mask_fn(s):
-        return _offset_mask(s, q_start, k_start, qoff, koff)
-
-    return mask_fn
-
-
-def _offset_mask(s, q_start, k_start, qoff, koff):
-    rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + q_start + qoff
-    cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + k_start + koff
-    return jnp.where(rows >= cols, s, _NEG_INF)
+        return mask_fn
 
 
-def _flash_kernel(
-    q_ref,  # [1, 1, block_q, D]
-    k_ref,  # [1, 1, block_k, D]
-    v_ref,  # [1, 1, block_k, D]
-    o_ref,  # [1, 1, block_q, D]
-    lse_ref,  # [1, 1, 8, block_q] f32 (logsumexp residual)
-    acc_ref,  # VMEM [block_q, D] f32
-    m_ref,  # VMEM [block_q, 128] f32 (row max, lane-broadcast)
-    l_ref,  # VMEM [block_q, 128] f32 (partial row sums by the lane)
-    *,
-    scale: float,
-    causal: bool,
-    block_q: int,
-    block_k: int,
-):
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
-    nk = pl.num_programs(3)
+@dataclasses.dataclass(frozen=True)
+class _Offset(_Family):
+    """The ring's fold (parallel/ring_attention.py): full attention of a
+    local q shard against one streamed k/v block, with the causal mask
+    evaluated at GLOBAL positions (``qoff``, ``koff`` are dynamic SMEM
+    scalars: each ring step sees a different source block). A skip decided
+    by a dynamic scalar still fetches the tile it skips. ``lse`` is an
+    output, so the caller can merge blocks with the standard online-softmax
+    combination, and carries a cotangent."""
+    scalars = 2
+    lse_cotangent = True
 
-    @pl.when(ik == 0)
+    def _runs(self, iq, ikv, qoff, koff):
+        q_start, k_start = self._starts(iq, ikv)
+        # False: this kv block is entirely in this q block's future.
+        return (k_start + koff) <= (q_start + qoff + self.block_q - 1)
+
+    def q_sweep(self, iq, j, qoff, koff):
+        return j, self._runs(iq, j, qoff, koff)
+
+    def kv_sweep(self, ik, s, qoff, koff):
+        return s, self._runs(s, ik, qoff, koff)
+
+    def mask(self, iq, ikv, qoff, koff):
+        q_start, k_start = self._starts(iq, ikv)
+
+        def mask_fn(s):  # a tile's start, then the offset: two adds a side, as ever
+            rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + q_start + qoff
+            cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + k_start + koff
+            return _keep(s, rows >= cols)
+
+        return mask_fn
+
+
+@dataclasses.dataclass(frozen=True)
+class _BlockDiffusion(_Family):
+    """models/llama.py:Attention under ``objective="block_diffusion"``:
+    q/k/v hold two streams of L positions end to end, rows 0..L-1 the noisy
+    x_t and L..2L-1 the clean x_0, both at positions 0..L-1, in square
+    tiles (a multiple of the block length ``b``, so a tile lies in one
+    stream and cuts no block). With n = L/tile the 2n x 2n tiles hold
+    n(n+1)/2 kept clean-on-clean tiles, as many noisy-on-clean and n
+    noisy-on-noisy: n^2 + 2n of 4n^2. No kernel's grid walks the square: a
+    q tile sweeps its own kept kv tiles (n + 1 steps at most, as a causal
+    sweep over L would take n), a clean kv tile the q tiles of both streams
+    from its own on, a noisy kv tile its one q tile (two dkv calls, a
+    stream's kv tiles each). A step past the end of a sweep repeats the
+    sweep's last tile, so nothing is fetched for it. Everything is static:
+    no scalar reaches the kernels."""
+    b: int = 0
+
+    @property
+    def n(self) -> int:
+        return self.nq // 2
+
+    @property
+    def q_steps(self) -> int:
+        return self.n + 1
+
+    @property
+    def kv_sweeps(self) -> tuple:
+        return (_Sweep(0, self.n, 1), _Sweep(self.n, self.n, 2 * self.n))
+
+    def q_sweep(self, iq, j):
+        """A noisy tile i starts on the noisy tile i, which holds every
+        row's own block (so the running max is finite from the first step
+        on), then takes the clean tiles 0..i; a clean tile i takes the
+        clean tiles 0..i."""
+        n = self.n
+        noisy = iq < n
+        i = jnp.where(noisy, iq, iq - n)
+        last = jnp.where(noisy, i + 1, i)
+        jj = jnp.minimum(j, last)
+        clean_tile = n + jnp.where(noisy, jj - 1, jj)
+        return jnp.where(noisy & (jj == 0), i, clean_tile), j <= last
+
+    def q_fetch(self, iq, j):
+        return self.q_sweep(iq, j)[0]
+
+    def kv_span(self, sweep, ik):
+        return sweep, ik
+
+    def kv_sweep(self, span, s):
+        """A noisy kv tile: its one q tile. The clean kv tile ``ik`` of the
+        stream: the noisy q tiles ik..n-1 (steps ik..n-1), then the clean
+        ones (steps n+ik..2n-1); a step before them repeats the first."""
+        sweep, ik = span
+        if sweep.first == 0:
+            return ik, True
+        n = self.n
+        i = s % n
+        return jnp.where(s >= n, n, 0) + jnp.maximum(i, ik), i >= ik
+
+    def kv_fetch(self, span, s):
+        return self.kv_sweep(span, s)[0]
+
+    def mask(self, iq, ikv):
+        """A row at position p of block first(p)..first(p)+b-1 keeps the
+        columns in [lo, hi), by the two tiles' streams. A sweep never pairs
+        a clean q tile with a noisy kv tile."""
+        n, b, block = self.n, self.b, self.block_q
+
+        def mask_fn(s):
+            q_noisy, k_noisy = iq < n, ikv < n
+            rows = jax.lax.broadcasted_iota(jnp.int32, (s.shape[0], 1), 0)
+            qpos = rows + (iq - jnp.where(q_noisy, 0, n)) * block
+            cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            kpos = cols + (ikv - jnp.where(k_noisy, 0, n)) * block
+            # b a power of two: a bitwise and (the VPU has no integer divide).
+            first = (qpos & -b) if b & (b - 1) == 0 else qpos - qpos % b
+            lo = first * k_noisy.astype(jnp.int32)  # noisy keys: the own block only
+            # Noisy on clean: strictly earlier blocks. Else up to the own block's end.
+            hi = first + jnp.where(q_noisy & jnp.logical_not(k_noisy), 0, b)
+            return _keep(s, (kpos >= lo) & (kpos < hi))
+
+        return mask_fn
+
+
+def _band_span(i, a, c, back, ahead, n, lo=jnp.maximum, hi=jnp.minimum):
+    """(first, last) of the ``n`` tiles of ``c`` positions that hold any of
+    the positions i*a - back .. i*a + a - 1 + ahead: the kv tiles of q tile
+    ``i`` (``back`` = window - 1, ``ahead`` = 0) or the q tiles of kv tile
+    ``i`` (the mirror). On traced indices, or on Python ints with
+    ``lo=max, hi=min``."""
+    return lo(i * a - back, 0) // c, hi((i * a + a - 1 + ahead) // c, n - 1)
+
+
+def _band_sweeps(seq_len: int, window: int, block_q: int, block_k: int):
+    """Per q tile the kv tiles its sweep runs, per kv tile the q tiles
+    (two lists of Python ints): what the grids are sized from."""
+    nq, nk = seq_len // block_q, seq_len // block_k
+    span = lambda *a: _band_span(*a, lo=max, hi=min)  # noqa: E731
+    kv = [span(i, block_q, block_k, window - 1, 0, nk) for i in range(nq)]
+    q = [span(i, block_k, block_q, 0, window - 1, nq) for i in range(nk)]
+    return [b - a + 1 for a, b in kv], [b - a + 1 for a, b in q]
+
+
+@dataclasses.dataclass(frozen=True)
+class _Window(_Family):
+    """models/llama.py:Attention of the windowed kind 'W': causal with a
+    sliding window, row i keeps the columns j <= i with i - j < window, the
+    position itself counted (``window`` keys at most). Static, over one
+    sequence. No kernel's grid walks the causal triangle: a q tile sweeps
+    the kv tiles from the one that holds its first row's oldest key to its
+    diagonal, a kv tile the q tiles from its diagonal to the one that holds
+    the last row that still sees its last column (the mirrored sweep), and
+    the grid's innermost dimension is the LONGEST such sweep
+    (ceil((window - 1) / tile) + 1 steps where the tiles are square). A
+    step past the end of a sweep repeats the sweep's last tile, so nothing
+    is fetched for it. The first tile of a q tile's sweep can hold rows
+    that keep none of it: their running max stays at the mask's value and
+    what they accumulate there is scaled to exactly 0 by the diagonal
+    tile's first finite max."""
+    window: int = 0
+
+    def _longest(self, side: int) -> int:
+        return max(_band_sweeps(self.q_len, self.window, self.block_q, self.block_k)[side])
+
+    @property
+    def q_steps(self) -> int:
+        return self._longest(0)
+
+    @property
+    def kv_sweeps(self) -> tuple:
+        return (_Sweep(0, self.nk, self._longest(1)),)
+
+    def q_span(self, iq):
+        return _band_span(iq, self.block_q, self.block_k, self.window - 1, 0, self.nk)
+
+    def q_sweep(self, span, j):
+        ik = span[0] + j
+        return ik, ik <= span[1]
+
+    def q_fetch(self, span, j):
+        return jnp.minimum(span[0] + j, span[1])
+
+    def kv_span(self, sweep, ik):
+        return _band_span(ik, self.block_k, self.block_q, 0, self.window - 1, self.nq)
+
+    def kv_sweep(self, span, s):
+        iq = span[0] + s
+        return iq, iq <= span[1]
+
+    def kv_fetch(self, span, s):
+        return jnp.minimum(span[0] + s, span[1])
+
+    def mask(self, iq, ikv):
+        starts = self._starts(iq, ikv)
+
+        def mask_fn(s):
+            rows, cols = _positions(s, *starts)
+            back = rows - cols
+            return _keep(s, (back >= 0) & (back < self.window))
+
+        return mask_fn
+
+
+# ---------------------------------------------------------------------------
+# The frame: three kernel bodies (and the one for a key part that all heads
+# share), two drivers ([B,H,S,D] layout) and the custom_vjp.
+#
+# Forward and dq: grid = (B, Hq, q tiles, family.q_steps). dkv: grid =
+# (B, Hkv, a sweep's kv tiles, q_per_kv * its steps): everything that
+# accumulates into THIS kv block, the q-head group and each head's sweep
+# over the q tiles, is the single innermost dimension, so the output
+# block's VMEM residency is one consecutive run and the scratch init/flush
+# brackets exactly it. A kernel's refs are its operands in the order the
+# drivers pass them: the family's SMEM scalars, q's parts, k's parts, v,
+# (backward: do, lse, delta and, where lse has a cotangent, dlse), the
+# outputs, the scratch.
+# ---------------------------------------------------------------------------
+
+
+def _cut(refs, *counts):
+    """``refs`` as tuples of ``counts`` refs each, then what is left."""
+    cuts, at = [], 0
+    for n in counts:
+        cuts.append(tuple(refs[at:at + n]))
+        at += n
+    return (*cuts, tuple(refs[at:]))
+
+
+def _zero(*accs):
+    for acc in accs:
+        acc[:] = jnp.zeros_like(acc)
+
+
+def _flush(outs, accs):
+    for out, acc in zip(outs, accs):
+        out[0, 0] = acc[:].astype(out.dtype)
+
+
+def _fwd_kernel(*refs, family, parts, scale):
+    # q [1,1,block_q,D], k/v [1,1,block_k,D] a part; o [1,1,block_q,Dv];
+    # lse [1,1,8,block_q] f32 (logsumexp residual); VMEM acc [block_q,Dv],
+    # m and l [block_q,128] f32 (row max lane-broadcast, partial row sums).
+    scalars, q, k, (v, o, lse, acc, m, l) = _cut(refs, family.scalars, parts, parts)
+    iq, j = pl.program_id(2), pl.program_id(3)
+    at = [ref[0, 0] for ref in scalars]
+
+    @pl.when(j == 0)
     def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+        _zero(acc)
+        m[:] = jnp.full_like(m, _NEG_INF)
+        _zero(l)
 
-    # Causal: skip blocks strictly above the diagonal (no q row attends
-    # into them; their DMA is elided by the clamped index maps).
-    q_start = iq * block_q
-    k_start = ik * block_k
-    run = (not causal) or (k_start <= q_start + block_q - 1)
+    ikv, run = family.q_sweep(family.q_span(iq), j, *at)
 
     @pl.when(run)
     def _step():
-        _fwd_step(
-            q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, scale,
-            _static_mask(causal, q_start, k_start),
-        )
+        _fwd_step(q, k, v, acc, m, l, scale, family.mask(iq, ikv, *at))
 
-    @pl.when(ik == nk - 1)
+    @pl.when(j == pl.num_programs(3) - 1)
     def _finish():
-        _fwd_finish(o_ref, lse_ref, acc_ref, m_ref, l_ref)
+        _fwd_finish(o, lse, acc, m, l)
 
 
-def _flash_bwd_dq_kernel(
-    q_ref,  # [1, 1, block_q, D]
-    k_ref,  # [1, 1, block_k, D]
-    v_ref,  # [1, 1, block_k, D]
-    do_ref,  # [1, 1, block_q, D]
-    lse_ref,  # [1, 1, 8, block_q] (sublane-broadcast)
-    delta_ref,  # [1, 1, 8, block_q]
-    dq_ref,  # out [1, 1, block_q, D]
-    dq_acc,  # VMEM [block_q, D] f32
-    *,
-    scale: float,
-    causal: bool,
-    block_q: int,
-    block_k: int,
-):
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
-    nk = pl.num_programs(3)
+def _backward_refs(refs, family, parts):
+    """A backward kernel's refs: (scalars' values, q, k, (v, do, lse,
+    delta, dlse or None), the rest: outputs then scratch)."""
+    scalars, q, k, residuals, rest = _cut(
+        refs, family.scalars, parts, parts, 4 + family.lse_cotangent
+    )
+    return [ref[0, 0] for ref in scalars], q, k, (*residuals, None)[:5], rest
 
-    @pl.when(ik == 0)
+
+def _dq_kernel(*refs, family, parts, scale):
+    iq, j = pl.program_id(2), pl.program_id(3)
+    at, q, k, residuals, rest = _backward_refs(refs, family, parts)
+    dq, dq_acc = rest[:parts], rest[parts:]
+
+    @pl.when(j == 0)
     def _init():
-        for acc in _parts(dq_acc):
-            acc[:] = jnp.zeros_like(acc)
+        _zero(*dq_acc)
 
-    q_start = iq * block_q
-    k_start = ik * block_k
-    run = (not causal) or (k_start <= q_start + block_q - 1)
+    ikv, run = family.q_sweep(family.q_span(iq), j, *at)
 
     @pl.when(run)
     def _step():
-        _bwd_dq_step(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, None,
-            dq_acc, scale, _static_mask(causal, q_start, k_start),
-        )
+        _bwd_dq_step(q, k, residuals, dq_acc, scale, family.mask(iq, ikv, *at))
 
-    @pl.when(ik == nk - 1)
+    @pl.when(j == pl.num_programs(3) - 1)
     def _finish():
-        for out, acc in zip(_parts(dq_ref), _parts(dq_acc)):
-            out[0, 0] = acc[:].astype(out.dtype)
+        _flush(dq, dq_acc)
 
 
-def _flash_bwd_dkv_kernel(
-    q_ref,  # [1, 1, block_q, D]
-    k_ref,  # [1, 1, block_k, D]
-    v_ref,  # [1, 1, block_k, D]
-    do_ref,  # [1, 1, block_q, D]
-    lse_ref,  # [1, 1, 8, block_q] (sublane-broadcast)
-    delta_ref,  # [1, 1, 8, block_q]
-    dk_ref,  # out [1, 1, block_k, D] (kv-head indexed)
-    dv_ref,  # out [1, 1, block_k, D]
-    dk_acc,  # VMEM [block_k, D] f32
-    dv_acc,  # VMEM [block_k, D] f32
-    *,
-    scale: float,
-    causal: bool,
-    block_q: int,
-    block_k: int,
-    nq: int,
-    q_per_kv: int,
-):
-    # Grid = (B, Hkv, nk, q_per_kv * nq): everything that accumulates into
-    # THIS kv block — the q-head group and the q-block sweep — is the
-    # single innermost dimension, so the output block's VMEM residency is
-    # one consecutive run and the scratch init/flush brackets exactly it.
-    ik = pl.program_id(2)
-    inner = pl.program_id(3)
-    n_inner = pl.num_programs(3)
-    iq = inner % nq
+def _dkv_kernel(*refs, family, sweep, parts, scale):
+    ik, inner = pl.program_id(2), pl.program_id(3)
+    s = inner % sweep.steps
+    at, q, k, residuals, rest = _backward_refs(refs, family, parts)
+    outs, accs = rest[:parts + 1], rest[parts + 1:]  # dk's parts, then dv
 
     @pl.when(inner == 0)
     def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+        _zero(*accs)
 
-    q_start = iq * block_q
-    k_start = ik * block_k
-    run = (not causal) or (k_start <= q_start + block_q - 1)
+    ikv = sweep.first + ik
+    iq, run = family.kv_sweep(family.kv_span(sweep, ik), s, *at)
 
     @pl.when(run)
     def _step():
         _bwd_dkv_step(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, None,
-            dk_acc, dv_acc, scale, _static_mask(causal, q_start, k_start),
+            q, k, residuals, accs[:parts], accs[parts], scale,
+            family.mask(iq, ikv, *at),
         )
 
-    @pl.when(inner == n_inner - 1)
+    @pl.when(inner == pl.num_programs(3) - 1)
     def _finish():
-        dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
+        _flush(outs, accs)
 
 
-# ---------------------------------------------------------------------------
-# pallas_call drivers ([B,H,S,D] layout) + custom_vjp plumbing
-# ---------------------------------------------------------------------------
+def _shared_dkv_kernel(*refs, family, sweep, shared, scale):
+    # The dkv body for a key with a part that ALL heads share (the latent
+    # family's rotary key). A body of its own because it has no kv-head
+    # grid axis and two brackets: grid = (B, kv tiles, H * steps), head
+    # after head innermost; a head's own parts (dk_nope, dv) are bracketed
+    # by its own steps (their output blocks move on with the head), the
+    # shared part's gradient by the whole sweep. Folded into ``_dkv_kernel``
+    # it would be a grid of another rank there, chosen by a flag.
+    parts = len(shared) - 1  # ``shared``: of dk's parts and dv, which all heads share
+    ik, inner = pl.program_id(1), pl.program_id(2)
+    s = inner % sweep.steps
+    at, q, k, residuals, rest = _backward_refs(refs, family, parts)
+    outs, accs = rest[:parts + 1], rest[parts + 1:]  # dk's parts, then dv
+    held = [pair for pair, one in zip(zip(outs, accs), shared) if one]
+    own = [pair for pair, one in zip(zip(outs, accs), shared) if not one]
+
+    @pl.when(inner == 0)
+    def _init_shared():
+        _zero(*(acc for _, acc in held))
+
+    @pl.when(s == 0)
+    def _init_head():
+        _zero(*(acc for _, acc in own))
+
+    iq, run = family.kv_sweep(family.kv_span(sweep, ik), s, *at)
+
+    @pl.when(run)
+    def _step():
+        _bwd_dkv_step(
+            q, k, residuals, accs[:parts], accs[parts], scale,
+            family.mask(iq, sweep.first + ik, *at),
+        )
+
+    @pl.when(s == sweep.steps - 1)
+    def _finish_head():
+        _flush(*zip(*own))
+
+    @pl.when(inner == pl.num_programs(2) - 1)
+    def _finish_shared():
+        _flush(*zip(*held))
 
 
-def _forward_impl(qt, kt, vt, causal, block_q, block_k, interpret):
-    B, Hq, S, D = qt.shape
-    Hkv = kt.shape[1]
-    q_per_kv = Hq // Hkv
-    scale = 1.0 / math.sqrt(D)
-    grid = (B, Hq, S // block_q, S // block_k)
-
-    if causal:
-        # Blocks strictly above the causal diagonal are pl.when-skipped in
-        # the kernel; CLAMP their kv index to the diagonal block so the
-        # index map repeats and pallas elides the (otherwise wasted) DMA.
-        def kv_idx(b, h, iq, ik):
-            lim = (iq * block_q + block_q - 1) // block_k
-            return (b, h // q_per_kv, jnp.minimum(ik, lim), 0)
-    else:
-        def kv_idx(b, h, iq, ik):
-            return (b, h // q_per_kv, ik, 0)
-
-    out, lse = pl.pallas_call(
-        functools.partial(
-            _flash_kernel,
-            scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hq, S, D), qt.dtype),
-            jax.ShapeDtypeStruct((B, Hq, 8, S), jnp.float32),
-        ],
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-            # GQA: q head h reads kv head h // q_per_kv.
-            pl.BlockSpec((1, 1, block_k, D), kv_idx),
-            pl.BlockSpec((1, 1, block_k, D), kv_idx),
-        ],
-        # Constant in ik: blocks stay resident in VMEM across the kv sweep
-        # and are flushed once.
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, 8, block_q), lambda b, h, iq, ik: (b, h, 0, iq)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qt, kt, vt)
-    return out, lse
-
-
-def _backward_impl(qt, kt, vt, do, lse, delta, causal, block_q, block_k,
-                   interpret):
-    B, Hq, S, D = qt.shape
-    Hkv = kt.shape[1]
-    q_per_kv = Hq // Hkv
-    scale = 1.0 / math.sqrt(D)
-
-    q_spec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0))
-    if causal:
-        def kv_idx(b, h, iq, ik):
-            lim = (iq * block_q + block_q - 1) // block_k
-            return (b, h // q_per_kv, jnp.minimum(ik, lim), 0)
-    else:
-        def kv_idx(b, h, iq, ik):
-            return (b, h // q_per_kv, ik, 0)
-    kv_spec = pl.BlockSpec((1, 1, block_k, D), kv_idx)
-    row_spec = pl.BlockSpec(
-        (1, 1, 8, block_q), lambda b, h, iq, ik: (b, h, 0, iq)
+def _smem_spec():
+    return pl.BlockSpec(
+        (1, 1), lambda *_: (0, 0), memory_space=pltpu.SMEM
     )
 
+
+def _blocks(rows: int, idx, xs) -> list:
+    return [pl.BlockSpec((1, 1, rows, x.shape[-1]), idx) for x in xs]
+
+
+def _shared(k) -> tuple:
+    """Which of a key's parts all heads share: one of ONE head beside a
+    first part of more (the latent family's rotary key, [B,1,S,Dr]; it
+    stays one head in HBM, every head's index map names that head, and its
+    gradient is accumulated over the heads inside the dkv kernel)."""
+    return tuple(part.shape[1] != k[0].shape[1] for part in k)
+
+
+def _q_sweep_specs(family, q, k, v):
+    """Block specs of the calls whose grid is (B, Hq, q tiles, sweep
+    steps), forward and dq: q's parts, k's parts, v, a q tile's own block
+    of values' width (o, do), its rows' residuals (lse, delta, dlse). GQA
+    folds into the index maps: q head h reads kv head h // q_per_kv."""
+    bq, bk = family.block_q, family.block_k
+    q_per_kv = q[0].shape[1] // k[0].shape[1]
+
+    def q_idx(b, h, iq, j):
+        return (b, h, iq, 0)
+
+    def kv_idx(b, h, iq, j):
+        span = family.q_span(iq)
+        return (b, h // q_per_kv, family.q_fetch(span, j), 0)
+
+    def shared_idx(b, h, iq, j):
+        return (b, 0, family.q_fetch(family.q_span(iq), j), 0)
+
+    return (
+        _blocks(bq, q_idx, q),
+        [
+            pl.BlockSpec((1, 1, bk, x.shape[-1]), shared_idx if one else kv_idx)
+            for x, one in zip((*k, v), (*_shared(k), False))
+        ],
+        *_blocks(bq, q_idx, [v]),
+        pl.BlockSpec((1, 1, 8, bq), lambda b, h, iq, j: (b, h, 0, iq)),
+    )
+
+
+def _scale(q) -> float:
+    return 1.0 / math.sqrt(sum(part.shape[-1] for part in q))
+
+
+def _forward_impl(family, scalars, q, k, v, interpret):
+    """(out [B,Hq,Sq,Dv] in q's dtype, lse [B,Hq,8,Sq] f32) of one family
+    value: ``scalars`` its SMEM scalars ([1,1] i32 each), ``q`` and ``k``
+    tuples of parts [B,H,S,D], ``v`` one array."""
+    B, Hq = q[0].shape[:2]
+    bq, dv = family.block_q, v.shape[-1]
+    q_specs, kv_specs, o_spec, row_spec = _q_sweep_specs(family, q, k, v)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, family=family, parts=len(q), scale=_scale(q)),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, Hq, family.q_len, dv), q[0].dtype),
+            jax.ShapeDtypeStruct((B, Hq, 8, family.q_len), jnp.float32),
+        ],
+        grid=(B, Hq, family.nq, family.q_steps),
+        in_specs=[*[_smem_spec()] * len(scalars), *q_specs, *kv_specs],
+        # Constant in the sweep's step: blocks stay resident in VMEM across
+        # the kv sweep and are flushed once.
+        out_specs=[o_spec, row_spec],
+        scratch_shapes=[
+            pltpu.VMEM((bq, dv), jnp.float32),
+            pltpu.VMEM((bq, _LANES), jnp.float32),
+            pltpu.VMEM((bq, _LANES), jnp.float32),
+        ],
+        interpret=interpret,
+    )(*scalars, *q, *k, v)
+
+
+def _backward_impl(family, scalars, q, k, v, do, lse, delta, dlse, interpret):
+    """(dq's parts, dk's parts, dv) given the forward's residuals; ``dlse``
+    is None unless the family's lse carries a cotangent. A run of kv tiles
+    (``family.kv_sweeps``) is a dkv call of its own."""
+    B, Hq = q[0].shape[:2]
+    bq = family.block_q
+    rows = (lse, delta) if dlse is None else (lse, delta, dlse)
+    operands = (*scalars, *q, *k, v, do, *rows)
+    q_specs, kv_specs, o_spec, row_spec = _q_sweep_specs(family, q, k, v)
     dq = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dq_kernel,
-            scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, S, D), qt.dtype),
-        grid=(B, Hq, S // block_q, S // block_k),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=pl.BlockSpec(
-            (1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)
-        ),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        functools.partial(_dq_kernel, family=family, parts=len(q), scale=_scale(q)),
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in q],
+        grid=(B, Hq, family.nq, family.q_steps),
+        in_specs=[
+            *[_smem_spec()] * len(scalars), *q_specs, *kv_specs, o_spec,
+            *[row_spec] * len(rows),
+        ],
+        out_specs=q_specs,
+        scratch_shapes=[pltpu.VMEM((bq, x.shape[-1]), jnp.float32) for x in q],
         interpret=interpret,
-    )(qt, kt, vt, do, lse, delta)
+    )(*operands)
 
-    # dk/dv: one kv block per (b, hkv, ik); its full accumulation sweep
-    # (q heads in the GQA group x q blocks) is the innermost grid dim.
-    nq = S // block_q
+    dkv_call = _shared_dkv_call if any(_shared(k)) else _dkv_call
+    runs = [
+        dkv_call(family, sweep, len(scalars), q, k, v, len(rows), interpret)(*operands)
+        for sweep in family.kv_sweeps
+    ]
+    *dk, dv = runs[0] if len(runs) == 1 else [
+        jnp.concatenate(pieces, axis=2) for pieces in zip(*runs)
+    ]
+    return tuple(dq), tuple(dk), dv
 
-    def q_blk(ik, inner):
-        iq = inner % nq
-        if not causal:
-            return iq
-        # q blocks fully above the diagonal contribute nothing; clamp to
-        # the diagonal block so the repeated index elides their DMA.
-        lo = (ik * block_k) // block_q
-        return jnp.maximum(iq, lo)
 
-    q_spec2 = pl.BlockSpec(
-        (1, 1, block_q, D),
-        lambda b, hk, ik, inner: (
-            b, hk * q_per_kv + inner // nq, q_blk(ik, inner), 0
-        ),
-    )
-    kv_spec2 = pl.BlockSpec(
-        (1, 1, block_k, D), lambda b, hk, ik, inner: (b, hk, ik, 0)
-    )
-    row_spec2 = pl.BlockSpec(
-        (1, 1, 8, block_q),
-        lambda b, hk, ik, inner: (
-            b, hk * q_per_kv + inner // nq, 0, q_blk(ik, inner)
-        ),
-    )
-    dkv_out = pl.BlockSpec(
-        (1, 1, block_k, D), lambda b, hk, ik, inner: (b, hk, ik, 0)
-    )
-    dk, dv = pl.pallas_call(
+def _dkv_call(family, sweep, n_scalars, q, k, v, n_rows, interpret):
+    """The dkv call of one run of kv tiles: dk's parts and dv, [B, Hkv, the
+    run's length, D] each, from ``n_scalars`` SMEM scalars, q, k, v, do and
+    ``n_rows`` per-row residuals."""
+    B, Hq = q[0].shape[:2]
+    Hkv = k[0].shape[1]
+    q_per_kv, steps = Hq // Hkv, sweep.steps
+    bq, bk = family.block_q, family.block_k
+
+    def q_at(hk, ik, inner):
+        # A q tile the mask empties adds nothing: its index repeats a tile
+        # the sweep holds (``kv_fetch``), so nothing is fetched for it.
+        span = family.kv_span(sweep, ik)
+        return hk * q_per_kv + inner // steps, family.kv_fetch(span, inner % steps)
+
+    def q_idx(b, hk, ik, inner):
+        head, iq = q_at(hk, ik, inner)
+        return b, head, iq, 0
+
+    def row_idx(b, hk, ik, inner):
+        head, iq = q_at(hk, ik, inner)
+        return b, head, 0, iq
+
+    return pl.pallas_call(
         functools.partial(
-            _flash_bwd_dkv_kernel,
-            scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-            nq=nq, q_per_kv=q_per_kv,
+            _dkv_kernel, family=family, sweep=sweep, parts=len(k), scale=_scale(q)
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, S, D), kt.dtype),
-            jax.ShapeDtypeStruct((B, Hkv, S, D), vt.dtype),
+            jax.ShapeDtypeStruct((B, Hkv, sweep.tiles * bk, x.shape[-1]), x.dtype)
+            for x in (*k, v)
         ],
-        grid=(B, Hkv, S // block_k, q_per_kv * nq),
-        in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2, row_spec2],
-        out_specs=[dkv_out, dkv_out],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+        grid=(B, Hkv, sweep.tiles, q_per_kv * steps),
+        in_specs=[
+            *[_smem_spec()] * n_scalars,
+            *_blocks(bq, q_idx, q),
+            *_blocks(bk, lambda b, hk, ik, inner: (b, hk, sweep.first + ik, 0), (*k, v)),
+            *_blocks(bq, q_idx, [v]),
+            *[pl.BlockSpec((1, 1, 8, bq), row_idx)] * n_rows,
         ],
+        out_specs=_blocks(bk, lambda b, hk, ik, inner: (b, hk, ik, 0), (*k, v)),
+        scratch_shapes=[pltpu.VMEM((bk, x.shape[-1]), jnp.float32) for x in (*k, v)],
         interpret=interpret,
-    )(qt, kt, vt, do, lse, delta)
-    return dq, dk, dv
+    )
+
+
+def _shared_dkv_call(family, sweep, n_scalars, q, k, v, n_rows, interpret):
+    """``_dkv_call`` where all heads share a part of the key: that part's
+    gradient [B, 1, the run's length, D], summed over the heads."""
+    B, H = q[0].shape[:2]
+    steps = sweep.steps
+    bq, bk = family.block_q, family.block_k
+    shared = (*_shared(k), False)
+
+    def q_at(ik, inner):
+        span = family.kv_span(sweep, ik)
+        return inner // steps, family.kv_fetch(span, inner % steps)
+
+    def q_idx(b, ik, inner):
+        head, iq = q_at(ik, inner)
+        return b, head, iq, 0
+
+    def row_idx(b, ik, inner):
+        head, iq = q_at(ik, inner)
+        return b, head, 0, iq
+
+    def kv_specs(first):
+        return [
+            pl.BlockSpec(
+                (1, 1, bk, x.shape[-1]),
+                (lambda b, ik, inner: (b, 0, first + ik, 0)) if one
+                else (lambda b, ik, inner: (b, inner // steps, first + ik, 0)),
+            )
+            for x, one in zip((*k, v), shared)
+        ]
+
+    return pl.pallas_call(
+        functools.partial(
+            _shared_dkv_kernel, family=family, sweep=sweep, shared=shared,
+            scale=_scale(q),
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((*x.shape[:2], sweep.tiles * bk, x.shape[-1]), x.dtype)
+            for x in (*k, v)
+        ],
+        grid=(B, sweep.tiles, H * steps),
+        in_specs=[
+            *[_smem_spec()] * n_scalars,
+            *_blocks(bq, q_idx, q),
+            *kv_specs(sweep.first),
+            *_blocks(bq, q_idx, [v]),
+            *[pl.BlockSpec((1, 1, 8, bq), row_idx)] * n_rows,
+        ],
+        out_specs=kv_specs(0),
+        scratch_shapes=[pltpu.VMEM((bk, x.shape[-1]), jnp.float32) for x in (*k, v)],
+        interpret=interpret,
+    )
 
 
 def _row_delta(do, out):
@@ -633,26 +1039,40 @@ def _row_delta(do, out):
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(qt, kt, vt, causal, block_q, block_k, interpret):
-    out, _ = _forward_impl(qt, kt, vt, causal, block_q, block_k, interpret)
-    return out
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 5))
+def _flash(family, scalars, q, k, v, interpret):
+    """The attention of one family value, differentiable in q's parts, k's
+    parts and v: out, or (out, lse) where the family's lse is an output."""
+    return _flash_fwd(family, scalars, q, k, v, interpret)[0]
 
 
-def _flash_fwd(qt, kt, vt, causal, block_q, block_k, interpret):
-    out, lse = _forward_impl(qt, kt, vt, causal, block_q, block_k, interpret)
-    return out, (qt, kt, vt, out, lse)
+def _flash_fwd(family, scalars, q, k, v, interpret):
+    out, lse = _forward_impl(family, scalars, q, k, v, interpret)
+    primal = (out, lse) if family.lse_cotangent else out
+    return primal, (scalars, q, k, v, out, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, res, do):
-    qt, kt, vt, out, lse = res
-    return _backward_impl(
-        qt, kt, vt, do, lse, _row_delta(do, out), causal, block_q, block_k,
-        interpret,
+def _flash_bwd(family, interpret, res, ct):
+    scalars, q, k, v, out, lse = res
+    # Where lse is an output BOTH carry cotangents (the ring merge uses
+    # lse). dlse is already in the raw [B,Hq,8,S] kernel layout (the
+    # sublane slice happens in the public wrapper, outside this vjp); the
+    # kernels read sublane 0, which is exactly where the slice cotangent
+    # lands.
+    do, dlse = ct if family.lse_cotangent else (ct, None)
+    dq, dk, dv = _backward_impl(
+        family, scalars, q, k, v, do, lse, _row_delta(do, out),
+        None if dlse is None else dlse.astype(jnp.float32), interpret,
     )
+    return tuple(None for _ in scalars), dq, dk, dv
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def _heads_first(*xs):
+    """[B,S,H,D] -> [B,H,S,D]: S x D blocks are MXU-shaped."""
+    return [jnp.swapaxes(x, 1, 2) for x in xs]
 
 
 @functools.partial(
@@ -679,279 +1099,10 @@ def flash_attention(
             f"flash_attention: seq_len {S} not divisible by blocks "
             f"({block_q},{block_k}); use dense_attention"
         )
-    block_q, block_k = tiles
     itp = _interpret() if interpret is None else interpret
-    # [B,S,H,D] -> [B,H,S,D]: S x D blocks are MXU-shaped.
-    qt = jnp.swapaxes(q, 1, 2)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
-    out = _flash(qt, kt, vt, causal, block_q, block_k, itp)
+    qt, kt, vt = _heads_first(q, k, v)
+    out = _flash(_Causal(S, S, *tiles, causal), (), (qt,), (kt,), vt, itp)
     return jnp.swapaxes(out, 1, 2)
-
-
-# ---------------------------------------------------------------------------
-# Offset-aware block variant for ring attention (parallel/ring_attention.py):
-# full attention of a local q shard against one streamed k/v block, with the
-# causal mask evaluated at GLOBAL positions (q_offset / k_offset are dynamic
-# SMEM scalars — each ring step sees a different source block). Returns
-# (out, lse) so the caller can merge blocks with the standard online-softmax
-# combination.
-# ---------------------------------------------------------------------------
-
-
-def _flash_block_fwd_kernel(
-    qoff_ref,  # SMEM [1, 1] i32
-    koff_ref,  # SMEM [1, 1] i32
-    q_ref, k_ref, v_ref,  # [1, 1, block, D]
-    o_ref,  # [1, 1, block_q, D]
-    lse_ref,  # [1, 1, 8, block_q]
-    acc_ref, m_ref, l_ref,  # VMEM scratch
-    *, scale: float, block_q: int, block_k: int,
-):
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
-    nk = pl.num_programs(3)
-    qoff = qoff_ref[0, 0]
-    koff = koff_ref[0, 0]
-
-    @pl.when(ik == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    q_start = iq * block_q
-    k_start = ik * block_k
-    # Dynamic skip: this kv block is entirely in this q block's future.
-    run = (k_start + koff) <= (q_start + qoff + block_q - 1)
-
-    @pl.when(run)
-    def _step():
-        _fwd_step(
-            q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, scale,
-            _dynamic_mask(q_start, k_start, qoff, koff),
-        )
-
-    @pl.when(ik == nk - 1)
-    def _finish():
-        _fwd_finish(o_ref, lse_ref, acc_ref, m_ref, l_ref)
-
-
-def _flash_block_bwd_dq_kernel(
-    qoff_ref, koff_ref,
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref,
-    dq_ref,
-    dq_acc,
-    *, scale: float, block_q: int, block_k: int,
-):
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
-    nk = pl.num_programs(3)
-    qoff = qoff_ref[0, 0]
-    koff = koff_ref[0, 0]
-
-    @pl.when(ik == 0)
-    def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    q_start = iq * block_q
-    k_start = ik * block_k
-    run = (k_start + koff) <= (q_start + qoff + block_q - 1)
-
-    @pl.when(run)
-    def _step():
-        _bwd_dq_step(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref,
-            dq_acc, scale, _dynamic_mask(q_start, k_start, qoff, koff),
-        )
-
-    @pl.when(ik == nk - 1)
-    def _finish():
-        dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
-
-
-def _flash_block_bwd_dkv_kernel(
-    qoff_ref, koff_ref,
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref,
-    dk_ref, dv_ref,
-    dk_acc, dv_acc,
-    *, scale: float, block_q: int, block_k: int, nq: int, q_per_kv: int,
-):
-    ik = pl.program_id(2)
-    inner = pl.program_id(3)
-    n_inner = pl.num_programs(3)
-    iq = inner % nq
-    qoff = qoff_ref[0, 0]
-    koff = koff_ref[0, 0]
-
-    @pl.when(inner == 0)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
-
-    q_start = iq * block_q
-    k_start = ik * block_k
-    run = (k_start + koff) <= (q_start + qoff + block_q - 1)
-
-    @pl.when(run)
-    def _step():
-        _bwd_dkv_step(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref,
-            dk_acc, dv_acc, scale,
-            _dynamic_mask(q_start, k_start, qoff, koff),
-        )
-
-    @pl.when(inner == n_inner - 1)
-    def _finish():
-        dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
-
-
-
-def _smem_spec():
-    return pl.BlockSpec(
-        (1, 1), lambda *_: (0, 0), memory_space=pltpu.SMEM
-    )
-
-
-def _block_forward_impl(qt, kt, vt, qoff, koff, block_q, block_k, interpret):
-    B, Hq, Sq, D = qt.shape
-    Hkv, Skv = kt.shape[1], kt.shape[2]
-    q_per_kv = Hq // Hkv
-    scale = 1.0 / math.sqrt(D)
-    grid = (B, Hq, Sq // block_q, Skv // block_k)
-    kv_idx = lambda b, h, iq, ik: (b, h // q_per_kv, ik, 0)  # noqa: E731
-    out, lse = pl.pallas_call(
-        functools.partial(
-            _flash_block_fwd_kernel,
-            scale=scale, block_q=block_q, block_k=block_k,
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hq, Sq, D), qt.dtype),
-            jax.ShapeDtypeStruct((B, Hq, 8, Sq), jnp.float32),
-        ],
-        grid=grid,
-        in_specs=[
-            _smem_spec(),
-            _smem_spec(),
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_k, D), kv_idx),
-            pl.BlockSpec((1, 1, block_k, D), kv_idx),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, 8, block_q), lambda b, h, iq, ik: (b, h, 0, iq)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qoff, koff, qt, kt, vt)
-    return out, lse
-
-
-def _block_backward_impl(
-    qt, kt, vt, qoff, koff, do, lse, delta, dlse, block_q, block_k, interpret
-):
-    B, Hq, Sq, D = qt.shape
-    Hkv, Skv = kt.shape[1], kt.shape[2]
-    q_per_kv = Hq // Hkv
-    scale = 1.0 / math.sqrt(D)
-
-    q_spec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0))
-    kv_spec = pl.BlockSpec(
-        (1, 1, block_k, D), lambda b, h, iq, ik: (b, h // q_per_kv, ik, 0)
-    )
-    row_spec = pl.BlockSpec(
-        (1, 1, 8, block_q), lambda b, h, iq, ik: (b, h, 0, iq)
-    )
-    dq = pl.pallas_call(
-        functools.partial(
-            _flash_block_bwd_dq_kernel,
-            scale=scale, block_q=block_q, block_k=block_k,
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, D), qt.dtype),
-        grid=(B, Hq, Sq // block_q, Skv // block_k),
-        in_specs=[_smem_spec(), _smem_spec(),
-                  q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec,
-                  row_spec],
-        out_specs=pl.BlockSpec(
-            (1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)
-        ),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        interpret=interpret,
-    )(qoff, koff, qt, kt, vt, do, lse, delta, dlse)
-
-    nq = Sq // block_q
-    q_spec2 = pl.BlockSpec(
-        (1, 1, block_q, D),
-        lambda b, hk, ik, inner: (b, hk * q_per_kv + inner // nq, inner % nq, 0),
-    )
-    kv_spec2 = pl.BlockSpec(
-        (1, 1, block_k, D), lambda b, hk, ik, inner: (b, hk, ik, 0)
-    )
-    row_spec2 = pl.BlockSpec(
-        (1, 1, 8, block_q),
-        lambda b, hk, ik, inner: (b, hk * q_per_kv + inner // nq, 0, inner % nq),
-    )
-    dkv_out = pl.BlockSpec(
-        (1, 1, block_k, D), lambda b, hk, ik, inner: (b, hk, ik, 0)
-    )
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _flash_block_bwd_dkv_kernel,
-            scale=scale, block_q=block_q, block_k=block_k,
-            nq=nq, q_per_kv=q_per_kv,
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, Skv, D), kt.dtype),
-            jax.ShapeDtypeStruct((B, Hkv, Skv, D), vt.dtype),
-        ],
-        grid=(B, Hkv, Skv // block_k, q_per_kv * nq),
-        in_specs=[_smem_spec(), _smem_spec(),
-                  q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2, row_spec2,
-                  row_spec2],
-        out_specs=[dkv_out, dkv_out],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qoff, koff, qt, kt, vt, do, lse, delta, dlse)
-    return dq, dk, dv
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _flash_block(qt, kt, vt, qoff, koff, block_q, block_k, interpret):
-    return _block_forward_impl(
-        qt, kt, vt, qoff, koff, block_q, block_k, interpret
-    )
-
-
-def _flash_block_fwd(qt, kt, vt, qoff, koff, block_q, block_k, interpret):
-    out, lse = _block_forward_impl(
-        qt, kt, vt, qoff, koff, block_q, block_k, interpret
-    )
-    return (out, lse), (qt, kt, vt, qoff, koff, out, lse)
-
-
-def _flash_block_bwd(block_q, block_k, interpret, res, cts):
-    qt, kt, vt, qoff, koff, out, lse = res
-    do, dlse = cts  # BOTH outputs carry cotangents (the ring merge uses lse)
-    delta = _row_delta(do, out)
-    # dlse is already in the raw [B,Hq,8,S] kernel layout (the sublane
-    # slice happens in the public wrapper, outside this vjp); the kernels
-    # read sublane 0, which is exactly where the slice cotangent lands.
-    dq, dk, dv = _block_backward_impl(
-        qt, kt, vt, qoff, koff, do, lse, delta,
-        dlse.astype(jnp.float32), block_q, block_k, interpret,
-    )
-    return dq, dk, dv, None, None
-
-
-_flash_block.defvjp(_flash_block_fwd, _flash_block_bwd)
 
 
 def flash_attention_block(
@@ -978,32 +1129,15 @@ def flash_attention_block(
             f"flash_attention_block: shapes (Sq={Sq}, Skv={Skv}) not "
             f"block-divisible; use the dense fold"
         )
-    block_q, block_k = tiles
     qoff = jnp.asarray(q_offset, jnp.int32).reshape(1, 1)
     koff = jnp.asarray(k_offset, jnp.int32).reshape(1, 1)
     itp = _interpret() if interpret is None else interpret
-    qt = jnp.swapaxes(q, 1, 2)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
-    out, lse = _flash_block(qt, kt, vt, qoff, koff, block_q, block_k, itp)
+    qt, kt, vt = _heads_first(q, k, v)
+    out, lse = _flash(
+        _Offset(Sq, Skv, *tiles), (qoff, koff), (qt,), (kt,), vt, itp
+    )
     # lse is sublane-broadcast [B,Hq,8,Sq]; take one sublane.
     return jnp.swapaxes(out, 1, 2), lse[:, :, 0, :]
-
-
-# ---------------------------------------------------------------------------
-# Block-diffusion variant (models/llama.py:Attention under
-# ``objective="block_diffusion"``): q/k/v hold two streams of L positions
-# end to end, rows 0..L-1 the noisy x_t and L..2L-1 the clean x_0, both at
-# positions 0..L-1, in tiles of ``block`` (a multiple of the block length
-# ``b``, so a tile lies in one stream and cuts no block). With n = L/block
-# the 2n x 2n tiles hold n(n+1)/2 kept clean-on-clean tiles, as many
-# noisy-on-clean and n noisy-on-noisy: n^2 + 2n of 4n^2. No kernel's grid
-# walks the square: a q tile sweeps its own kept kv tiles (n + 1 steps at
-# most, as a causal sweep over L would take n), a clean kv tile the q tiles
-# of both streams from its own on, a noisy kv tile its one q tile. A step
-# past the end of a sweep repeats the sweep's last tile, so nothing is
-# fetched for it. Everything is static: no scalar reaches the kernels.
-# ---------------------------------------------------------------------------
 
 
 def supports_block_diffusion(
@@ -1039,263 +1173,6 @@ def block_diffusion_tiles(
     return kept, (n * n + 2 * n) * blk * blk
 
 
-def _bd_kv_sweep(n, iq, j):
-    """Step ``j`` of q tile ``iq``'s sweep over the kv tiles: (tile,
-    whether it runs). A noisy tile i starts on the noisy tile i, which
-    holds every row's own block (so the running max is finite from the
-    first step on), then takes the clean tiles 0..i; a clean tile i takes
-    the clean tiles 0..i."""
-    noisy = iq < n
-    i = jnp.where(noisy, iq, iq - n)
-    last = jnp.where(noisy, i + 1, i)
-    jj = jnp.minimum(j, last)
-    clean_tile = n + jnp.where(noisy, jj - 1, jj)
-    return jnp.where(noisy & (jj == 0), i, clean_tile), j <= last
-
-
-def _bd_q_sweep(n, ik, s):
-    """Step ``s`` of the clean kv tile ``ik``'s sweep over the q tiles:
-    the noisy tiles ik..n-1 (steps ik..n-1), then the clean ones (steps
-    n+ik..2n-1); a step before them repeats the first."""
-    i = s % n
-    return jnp.where(s >= n, n, 0) + jnp.maximum(i, ik), i >= ik
-
-
-def _bd_mask(n, b, block, iq, ikv):
-    """The mask of q tile ``iq`` on kv tile ``ikv`` (tiles of the 2n): a
-    row at position p of block first(p)..first(p)+b-1 keeps the columns in
-    [lo, hi), by the two tiles' streams. A sweep never pairs a clean q
-    tile with a noisy kv tile."""
-
-    def mask_fn(s):
-        q_noisy, k_noisy = iq < n, ikv < n
-        rows = jax.lax.broadcasted_iota(jnp.int32, (s.shape[0], 1), 0)
-        qpos = rows + (iq - jnp.where(q_noisy, 0, n)) * block
-        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        kpos = cols + (ikv - jnp.where(k_noisy, 0, n)) * block
-        # b a power of two: a bitwise and (the VPU has no integer divide).
-        first = (qpos & -b) if b & (b - 1) == 0 else qpos - qpos % b
-        lo = first * k_noisy.astype(jnp.int32)  # noisy keys: the own block only
-        # Noisy on clean: strictly earlier blocks. Else up to the own block's end.
-        hi = first + jnp.where(q_noisy & jnp.logical_not(k_noisy), 0, b)
-        return jnp.where((kpos >= lo) & (kpos < hi), s, _NEG_INF)
-
-    return mask_fn
-
-
-def _flash_bd_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-    *, scale: float, n: int, b: int, block: int,
-):
-    iq = pl.program_id(2)
-    j = pl.program_id(3)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    ikv, run = _bd_kv_sweep(n, iq, j)
-
-    @pl.when(run)
-    def _step():
-        _fwd_step(
-            q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, scale,
-            _bd_mask(n, b, block, iq, ikv),
-        )
-
-    @pl.when(j == pl.num_programs(3) - 1)
-    def _finish():
-        _fwd_finish(o_ref, lse_ref, acc_ref, m_ref, l_ref)
-
-
-def _flash_bd_bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
-    *, scale: float, n: int, b: int, block: int,
-):
-    iq = pl.program_id(2)
-    j = pl.program_id(3)
-
-    @pl.when(j == 0)
-    def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    ikv, run = _bd_kv_sweep(n, iq, j)
-
-    @pl.when(run)
-    def _step():
-        _bwd_dq_step(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, None,
-            dq_acc, scale, _bd_mask(n, b, block, iq, ikv),
-        )
-
-    @pl.when(j == pl.num_programs(3) - 1)
-    def _finish():
-        dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
-
-
-def _flash_bd_bwd_dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    dk_acc, dv_acc,
-    *, scale: float, n: int, b: int, block: int, clean: bool, sweep: int,
-):
-    # Grid = (B, Hkv, n, q_per_kv * sweep) over the kv tiles of ONE stream:
-    # the clean ones (``sweep`` = 2n q tiles a head) or the noisy ones (1).
-    ik = pl.program_id(2)
-    inner = pl.program_id(3)
-
-    @pl.when(inner == 0)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
-
-    if clean:
-        ikv = n + ik
-        iq, run = _bd_q_sweep(n, ik, inner % sweep)
-    else:
-        ikv, iq, run = ik, ik, True
-
-    @pl.when(run)
-    def _step():
-        _bwd_dkv_step(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, None,
-            dk_acc, dv_acc, scale, _bd_mask(n, b, block, iq, ikv),
-        )
-
-    @pl.when(inner == pl.num_programs(3) - 1)
-    def _finish():
-        dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
-
-
-def _bd_q_sweep_specs(n, q_per_kv, block, D):
-    """Block specs of the kernels whose grid is (B, Hq, 2n q tiles, n + 1
-    sweep steps), forward and dq: a q tile's own block (q, o, do, dq), the
-    kv tile its sweep is at (GQA: q head h reads kv head h // q_per_kv),
-    its rows' residuals (lse, delta)."""
-    q_spec = pl.BlockSpec((1, 1, block, D), lambda bb, h, iq, j: (bb, h, iq, 0))
-    kv_spec = pl.BlockSpec(
-        (1, 1, block, D),
-        lambda bb, h, iq, j: (bb, h // q_per_kv, _bd_kv_sweep(n, iq, j)[0], 0),
-    )
-    row_spec = pl.BlockSpec((1, 1, 8, block), lambda bb, h, iq, j: (bb, h, 0, iq))
-    return q_spec, kv_spec, row_spec
-
-
-def _bd_forward_impl(qt, kt, vt, b, block, interpret):
-    B, Hq, S, D = qt.shape
-    n = S // 2 // block
-    q_spec, kv_spec, row_spec = _bd_q_sweep_specs(n, Hq // kt.shape[1], block, D)
-    return pl.pallas_call(
-        functools.partial(
-            _flash_bd_kernel, scale=1.0 / math.sqrt(D), n=n, b=b, block=block
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hq, S, D), qt.dtype),
-            jax.ShapeDtypeStruct((B, Hq, 8, S), jnp.float32),
-        ],
-        grid=(B, Hq, 2 * n, n + 1),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[q_spec, row_spec],
-        scratch_shapes=[
-            pltpu.VMEM((block, D), jnp.float32),
-            pltpu.VMEM((block, 128), jnp.float32),
-            pltpu.VMEM((block, 128), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qt, kt, vt)
-
-
-def _bd_backward_impl(qt, kt, vt, do, lse, delta, b, block, interpret):
-    B, Hq, S, D = qt.shape
-    Hkv = kt.shape[1]
-    q_per_kv = Hq // Hkv
-    n = S // 2 // block
-    static = dict(scale=1.0 / math.sqrt(D), n=n, b=b, block=block)
-
-    q_spec, kv_spec, row_spec = _bd_q_sweep_specs(n, q_per_kv, block, D)
-    dq = pl.pallas_call(
-        functools.partial(_flash_bd_bwd_dq_kernel, **static),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, S, D), qt.dtype),
-        grid=(B, Hq, 2 * n, n + 1),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
-        scratch_shapes=[pltpu.VMEM((block, D), jnp.float32)],
-        interpret=interpret,
-    )(qt, kt, vt, do, lse, delta)
-
-    def dkv(clean: bool):
-        """dk, dv of one stream's kv tiles, [B, Hkv, L, D] each."""
-        sweep = 2 * n if clean else 1
-
-        def q_at(hk, ik, inner):
-            iq = _bd_q_sweep(n, ik, inner % sweep)[0] if clean else ik
-            return hk * q_per_kv + inner // sweep, iq
-
-        def q_idx(bb, hk, ik, inner):
-            head, iq = q_at(hk, ik, inner)
-            return bb, head, iq, 0
-
-        def row_idx(bb, hk, ik, inner):
-            head, iq = q_at(hk, ik, inner)
-            return bb, head, 0, iq
-
-        first = n if clean else 0
-        q_spec2 = pl.BlockSpec((1, 1, block, D), q_idx)
-        row_spec2 = pl.BlockSpec((1, 1, 8, block), row_idx)
-        kv_spec2 = pl.BlockSpec(
-            (1, 1, block, D), lambda bb, hk, ik, inner: (bb, hk, first + ik, 0)
-        )
-        out_spec = pl.BlockSpec(
-            (1, 1, block, D), lambda bb, hk, ik, inner: (bb, hk, ik, 0)
-        )
-        return pl.pallas_call(
-            functools.partial(
-                _flash_bd_bwd_dkv_kernel, clean=clean, sweep=sweep, **static
-            ),
-            out_shape=[
-                jax.ShapeDtypeStruct((B, Hkv, S // 2, D), kt.dtype),
-                jax.ShapeDtypeStruct((B, Hkv, S // 2, D), vt.dtype),
-            ],
-            grid=(B, Hkv, n, q_per_kv * sweep),
-            in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2, row_spec2],
-            out_specs=[out_spec, out_spec],
-            scratch_shapes=[
-                pltpu.VMEM((block, D), jnp.float32),
-                pltpu.VMEM((block, D), jnp.float32),
-            ],
-            interpret=interpret,
-        )(qt, kt, vt, do, lse, delta)
-
-    (dk_noisy, dv_noisy), (dk_clean, dv_clean) = dkv(False), dkv(True)
-    return (
-        dq,
-        jnp.concatenate([dk_noisy, dk_clean], axis=2),
-        jnp.concatenate([dv_noisy, dv_clean], axis=2),
-    )
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_bd(qt, kt, vt, b, block, interpret):
-    return _bd_forward_impl(qt, kt, vt, b, block, interpret)[0]
-
-
-def _flash_bd_fwd(qt, kt, vt, b, block, interpret):
-    out, lse = _bd_forward_impl(qt, kt, vt, b, block, interpret)
-    return out, (qt, kt, vt, out, lse)
-
-
-def _flash_bd_bwd(b, block, interpret, res, do):
-    qt, kt, vt, out, lse = res
-    return _bd_backward_impl(
-        qt, kt, vt, do, lse, _row_delta(do, out), b, block, interpret
-    )
-
-
-_flash_bd.defvjp(_flash_bd_fwd, _flash_bd_bwd)
-
-
 @functools.partial(
     jax.jit, static_argnames=("block_length", "block", "interpret")
 )
@@ -1323,23 +1200,19 @@ def flash_attention_block_diffusion(
             f"in blocks of {block_length} do not tile by {min(block, L)}"
         )
     itp = _interpret() if interpret is None else interpret
-    out = _flash_bd(
-        jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
-        block_length, tile, itp,
-    )
-    return jnp.swapaxes(out, 1, 2)
+    qt, kt, vt = _heads_first(q, k, v)
+    family = _BlockDiffusion(S, S, tile, tile, block_length)
+    return jnp.swapaxes(_flash(family, (), (qt,), (kt,), vt, itp), 1, 2)
 
 
 # ---------------------------------------------------------------------------
-# Latent-attention variant (models/mla.py, DeepSeek-V3 arXiv:2412.19437
-# section 2.1.1 in its training form): a head's query and key are a
-# rope-free part of ``Dn`` channels and a rotary part of ``Dr``, the score
+# Latent attention (models/mla.py, DeepSeek-V3 arXiv:2412.19437 section
+# 2.1.1 in its training form): a head's query and key are a rope-free part
+# of ``Dn`` channels and a rotary part of ``Dr``, the score
 # (q_nope . k_nope + q_rope . k_rope) / sqrt(Dn + Dr), the values ``Dv``
-# wide, and ONE rotary key a position serves every head. The rotary key
-# stays one head in HBM, [B, 1, S, Dr]: every head's index map names that
-# head, and its gradient is accumulated over the heads inside the dkv
-# kernel. Causal over one sequence, static; the causal kernels' bodies,
-# their refs and accumulators handed in parts (``_parts``).
+# wide, and ONE rotary key a position serves every head (``_shared``).
+# Causal over one sequence, static: the causal family over tensors of two
+# parts.
 #
 # The score is TWO contractions a tile, Dn deep and Dr deep, summed. The
 # other form, one contraction over the parts joined in VMEM (Dn + Dr = 192
@@ -1363,224 +1236,6 @@ def supports_mla(
     return choose_tiles("mla", seq_len, (nope, rope, v_dim), block_q, block_k) is not None
 
 
-def _mla_fwd_kernel(
-    qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-    **static,
-):
-    _flash_kernel(
-        (qn_ref, qr_ref), (kn_ref, kr_ref), v_ref, o_ref, lse_ref,
-        acc_ref, m_ref, l_ref, causal=True, **static,
-    )
-
-
-def _mla_bwd_dq_kernel(
-    qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref, delta_ref,
-    dqn_ref, dqr_ref, dqn_acc, dqr_acc, **static,
-):
-    _flash_bwd_dq_kernel(
-        (qn_ref, qr_ref), (kn_ref, kr_ref), v_ref, do_ref, lse_ref, delta_ref,
-        (dqn_ref, dqr_ref), (dqn_acc, dqr_acc), causal=True, **static,
-    )
-
-
-def _mla_bwd_dkv_kernel(
-    qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref, delta_ref,
-    dkn_ref, dkr_ref, dv_ref,  # out: [1,1,block_k,Dn], [1,1,block_k,Dr] (head 0), [1,1,block_k,Dv]
-    dkn_acc, dkr_acc, dv_acc,
-    *, scale: float, block_q: int, block_k: int, nq: int,
-):
-    # Grid = (B, nk, H * nq): everything that accumulates into THIS kv tile
-    # is the innermost dimension, head after head. A head's dk_nope and dv
-    # are bracketed by its own nq steps (their output blocks move on with
-    # the head); the shared rotary key's gradient by the whole sweep.
-    ik = pl.program_id(1)
-    inner = pl.program_id(2)
-    iq = inner % nq
-
-    @pl.when(inner == 0)
-    def _init_shared():
-        dkr_acc[:] = jnp.zeros_like(dkr_acc)
-
-    @pl.when(iq == 0)
-    def _init_head():
-        dkn_acc[:] = jnp.zeros_like(dkn_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
-
-    q_start = iq * block_q
-    k_start = ik * block_k
-
-    @pl.when(k_start <= q_start + block_q - 1)
-    def _step():
-        _bwd_dkv_step(
-            (qn_ref, qr_ref), (kn_ref, kr_ref), v_ref, do_ref, lse_ref,
-            delta_ref, None, (dkn_acc, dkr_acc), dv_acc, scale,
-            _static_mask(True, q_start, k_start),
-        )
-
-    @pl.when(iq == nq - 1)
-    def _finish_head():
-        dkn_ref[0, 0] = dkn_acc[:].astype(dkn_ref.dtype)
-        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
-
-    @pl.when(inner == pl.num_programs(2) - 1)
-    def _finish_shared():
-        dkr_ref[0, 0] = dkr_acc[:].astype(dkr_ref.dtype)
-
-
-def _mla_q_sweep_specs(dims, block_q, block_k):
-    """Block specs of the kernels whose grid is (B, H, q tiles, kv tiles),
-    forward and dq: (q_nope, q_rope, k_nope, k_rope, v, out/do, rows). A
-    kv tile above the diagonal is skipped and its index clamped to the
-    diagonal's, as in the causal kernels; the rotary key is head 0's."""
-    dn, dr, dv = dims
-
-    def q_idx(b, h, iq, ik):
-        return (b, h, iq, 0)
-
-    def kv_at(iq, ik):
-        return jnp.minimum(ik, (iq * block_q + block_q - 1) // block_k)
-
-    def kv_idx(b, h, iq, ik):
-        return (b, h, kv_at(iq, ik), 0)
-
-    def shared_idx(b, h, iq, ik):
-        return (b, 0, kv_at(iq, ik), 0)
-
-    return (
-        pl.BlockSpec((1, 1, block_q, dn), q_idx),
-        pl.BlockSpec((1, 1, block_q, dr), q_idx),
-        pl.BlockSpec((1, 1, block_k, dn), kv_idx),
-        pl.BlockSpec((1, 1, block_k, dr), shared_idx),
-        pl.BlockSpec((1, 1, block_k, dv), kv_idx),
-        pl.BlockSpec((1, 1, block_q, dv), q_idx),
-        pl.BlockSpec((1, 1, 8, block_q), lambda b, h, iq, ik: (b, h, 0, iq)),
-    )
-
-
-def _mla_forward_impl(qn, qr, kn, kr, vt, block_q, block_k, interpret):
-    B, H, S, dn = qn.shape
-    dr, dv = qr.shape[-1], vt.shape[-1]
-    qn_spec, qr_spec, kn_spec, kr_spec, v_spec, o_spec, row_spec = _mla_q_sweep_specs(
-        (dn, dr, dv), block_q, block_k
-    )
-    return pl.pallas_call(
-        functools.partial(
-            _mla_fwd_kernel, scale=1.0 / math.sqrt(dn + dr),
-            block_q=block_q, block_k=block_k,
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, S, dv), vt.dtype),
-            jax.ShapeDtypeStruct((B, H, 8, S), jnp.float32),
-        ],
-        grid=(B, H, S // block_q, S // block_k),
-        in_specs=[qn_spec, qr_spec, kn_spec, kr_spec, v_spec],
-        out_specs=[o_spec, row_spec],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, dv), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qn, qr, kn, kr, vt)
-
-
-def _mla_backward_impl(qn, qr, kn, kr, vt, do, lse, delta, block_q, block_k, interpret):
-    B, H, S, dn = qn.shape
-    dr, dv = qr.shape[-1], vt.shape[-1]
-    scale = 1.0 / math.sqrt(dn + dr)
-    qn_spec, qr_spec, kn_spec, kr_spec, v_spec, o_spec, row_spec = _mla_q_sweep_specs(
-        (dn, dr, dv), block_q, block_k
-    )
-    dqn, dqr = pl.pallas_call(
-        functools.partial(
-            _mla_bwd_dq_kernel, scale=scale, block_q=block_q, block_k=block_k
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct(qn.shape, qn.dtype),
-            jax.ShapeDtypeStruct(qr.shape, qr.dtype),
-        ],
-        grid=(B, H, S // block_q, S // block_k),
-        in_specs=[qn_spec, qr_spec, kn_spec, kr_spec, v_spec, o_spec, row_spec, row_spec],
-        out_specs=[qn_spec, qr_spec],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, dn), jnp.float32),
-            pltpu.VMEM((block_q, dr), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qn, qr, kn, kr, vt, do, lse, delta)
-
-    nq = S // block_q
-
-    def q_at(ik, inner):
-        # q tiles wholly above the diagonal add nothing: clamp to the
-        # diagonal's so that the repeated index fetches nothing.
-        return inner // nq, jnp.maximum(inner % nq, (ik * block_k) // block_q)
-
-    def q_idx(b, ik, inner):
-        head, iq = q_at(ik, inner)
-        return (b, head, iq, 0)
-
-    def row_idx(b, ik, inner):
-        head, iq = q_at(ik, inner)
-        return (b, head, 0, iq)
-
-    def kv_idx(b, ik, inner):
-        return (b, inner // nq, ik, 0)
-
-    def shared_idx(b, ik, inner):
-        return (b, 0, ik, 0)
-
-    q_specs = [pl.BlockSpec((1, 1, block_q, d), q_idx) for d in (dn, dr)]
-    kv_specs = [
-        pl.BlockSpec((1, 1, block_k, dn), kv_idx),
-        pl.BlockSpec((1, 1, block_k, dr), shared_idx),
-        pl.BlockSpec((1, 1, block_k, dv), kv_idx),
-    ]
-    rows = pl.BlockSpec((1, 1, 8, block_q), row_idx)
-    dkn, dkr, dvt = pl.pallas_call(
-        functools.partial(
-            _mla_bwd_dkv_kernel, scale=scale, block_q=block_q, block_k=block_k, nq=nq
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct(kn.shape, kn.dtype),
-            jax.ShapeDtypeStruct(kr.shape, kr.dtype),
-            jax.ShapeDtypeStruct(vt.shape, vt.dtype),
-        ],
-        grid=(B, S // block_k, H * nq),
-        in_specs=[
-            *q_specs, *kv_specs, pl.BlockSpec((1, 1, block_q, dv), q_idx), rows, rows,
-        ],
-        out_specs=kv_specs,
-        scratch_shapes=[
-            pltpu.VMEM((block_k, dn), jnp.float32),
-            pltpu.VMEM((block_k, dr), jnp.float32),
-            pltpu.VMEM((block_k, dv), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qn, qr, kn, kr, vt, do, lse, delta)
-    return dqn, dqr, dkn, dkr, dvt
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _flash_mla(qn, qr, kn, kr, vt, block_q, block_k, interpret):
-    return _mla_forward_impl(qn, qr, kn, kr, vt, block_q, block_k, interpret)[0]
-
-
-def _flash_mla_fwd(qn, qr, kn, kr, vt, block_q, block_k, interpret):
-    out, lse = _mla_forward_impl(qn, qr, kn, kr, vt, block_q, block_k, interpret)
-    return out, (qn, qr, kn, kr, vt, out, lse)
-
-
-def _flash_mla_bwd(block_q, block_k, interpret, res, do):
-    qn, qr, kn, kr, vt, out, lse = res
-    return _mla_backward_impl(
-        qn, qr, kn, kr, vt, do, lse, _row_delta(do, out), block_q, block_k, interpret
-    )
-
-
-_flash_mla.defvjp(_flash_mla_fwd, _flash_mla_bwd)
-
-
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k", "interpret"))
 def flash_attention_mla(
     q_nope: jax.Array,
@@ -1595,7 +1250,7 @@ def flash_attention_mla(
     """Causal latent attention in its training form, differentiable.
     q_nope, k_nope: [B,S,H,Dn]; q_rope: [B,S,H,Dr]; k_rope: [B,S,Dr], the
     one rotary key a position that every head reads; v: [B,S,H,Dv].
-    Returns [B,S,H,Dv] in v's dtype. k_rope's gradient is the sum over
+    Returns [B,S,H,Dv] in q_nope's dtype. k_rope's gradient is the sum over
     the heads. The kernels' trace names start ``flash_attention_mla`` (a
     kernel is named for the jit around it)."""
     B, S, H, dn = q_nope.shape
@@ -1606,31 +1261,11 @@ def flash_attention_mla(
             f"flash_attention_mla: seq_len {S} in blocks ({block_q},{block_k}) "
             f"at widths {dn}+{dr}|{dv}: use latent_dense_attention"
         )
-    block_q, block_k = tiles
     itp = _interpret() if interpret is None else interpret
-    to_heads = lambda x: jnp.swapaxes(x, 1, 2)  # noqa: E731
-    out = _flash_mla(
-        to_heads(q_nope), to_heads(q_rope), to_heads(k_nope), k_rope[:, None],
-        to_heads(v), block_q, block_k, itp,
-    )
+    qn, qr, kn = _heads_first(q_nope, q_rope, k_nope)
+    k = (kn, k_rope[:, None])
+    out = _flash(_Causal(S, S, *tiles), (), (qn, qr), k, *_heads_first(v), itp)
     return jnp.swapaxes(out, 1, 2)
-
-
-# ---------------------------------------------------------------------------
-# Banded variant (models/llama.py:Attention of the windowed kind 'W'): causal
-# with a sliding window, row i keeps the columns j <= i with i - j < window,
-# the position itself counted (``window`` keys at most). Static, over one
-# sequence. No kernel's grid walks the causal triangle: a q tile sweeps the
-# kv tiles from the one that holds its first row's oldest key to its
-# diagonal, a kv tile the q tiles from its diagonal to the one that holds the
-# last row that still sees its last column (the mirrored sweep), and the
-# grid's innermost dimension is the LONGEST such sweep (ceil((window - 1) /
-# tile) + 1 steps where the tiles are square). A step past the end of a
-# sweep repeats the sweep's last tile, so nothing is fetched for it. The
-# first tile of a q tile's sweep can hold rows that keep none of it: their
-# running max stays at the mask's value and what they accumulate there is
-# scaled to exactly 0 by the diagonal tile's first finite max.
-# ---------------------------------------------------------------------------
 
 
 def supports_window(
@@ -1640,25 +1275,6 @@ def supports_window(
     ``window`` positions and these largest tiles (by shape alone; the
     caller falls back to dense attention under the band mask otherwise)."""
     return window >= 1 and choose_tiles("window", seq_len, (), block_q, block_k) is not None
-
-
-def _band_span(i, a, c, back, ahead, n, lo=jnp.maximum, hi=jnp.minimum):
-    """(first, last) of the ``n`` tiles of ``c`` positions that hold any of
-    the positions i*a - back .. i*a + a - 1 + ahead: the kv tiles of q tile
-    ``i`` (``back`` = window - 1, ``ahead`` = 0) or the q tiles of kv tile
-    ``i`` (the mirror). On traced indices, or on Python ints with
-    ``lo=max, hi=min``."""
-    return lo(i * a - back, 0) // c, hi((i * a + a - 1 + ahead) // c, n - 1)
-
-
-def _band_sweeps(seq_len: int, window: int, block_q: int, block_k: int):
-    """Per q tile the kv tiles its sweep runs, per kv tile the q tiles
-    (two lists of Python ints): what the grids are sized from."""
-    nq, nk = seq_len // block_q, seq_len // block_k
-    span = lambda *a: _band_span(*a, lo=max, hi=min)  # noqa: E731
-    kv = [span(i, block_q, block_k, window - 1, 0, nk) for i in range(nq)]
-    q = [span(i, block_k, block_q, 0, window - 1, nq) for i in range(nk)]
-    return [b - a + 1 for a, b in kv], [b - a + 1 for a, b in q]
 
 
 def window_kept(seq_len: int, window: int) -> int:
@@ -1684,232 +1300,6 @@ def window_tiles(
     return window_kept(seq_len, window), sum(sweeps) * tiles[0] * tiles[1]
 
 
-def _window_mask(window, q_start, k_start):
-    def mask_fn(s):
-        rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + q_start
-        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + k_start
-        back = rows - cols
-        return jnp.where((back >= 0) & (back < window), s, _NEG_INF)
-
-    return mask_fn
-
-
-def _flash_window_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-    *, scale: float, window: int, block_q: int, block_k: int, nk: int,
-):
-    iq = pl.program_id(2)
-    j = pl.program_id(3)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    first, last = _band_span(iq, block_q, block_k, window - 1, 0, nk)
-    ik = first + j
-
-    @pl.when(ik <= last)
-    def _step():
-        _fwd_step(
-            q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, scale,
-            _window_mask(window, iq * block_q, ik * block_k),
-        )
-
-    @pl.when(j == pl.num_programs(3) - 1)
-    def _finish():
-        _fwd_finish(o_ref, lse_ref, acc_ref, m_ref, l_ref)
-
-
-def _flash_window_bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
-    *, scale: float, window: int, block_q: int, block_k: int, nk: int,
-):
-    iq = pl.program_id(2)
-    j = pl.program_id(3)
-
-    @pl.when(j == 0)
-    def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    first, last = _band_span(iq, block_q, block_k, window - 1, 0, nk)
-    ik = first + j
-
-    @pl.when(ik <= last)
-    def _step():
-        _bwd_dq_step(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, None,
-            dq_acc, scale, _window_mask(window, iq * block_q, ik * block_k),
-        )
-
-    @pl.when(j == pl.num_programs(3) - 1)
-    def _finish():
-        dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
-
-
-def _flash_window_bwd_dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    dk_acc, dv_acc,
-    *, scale: float, window: int, block_q: int, block_k: int, nq: int, sweep: int,
-):
-    # Grid = (B, Hkv, nk, q_per_kv * sweep): the q-head group and each
-    # head's sweep over the q tiles the band pairs with THIS kv tile are the
-    # one innermost dimension, as in the causal dkv kernel.
-    ik = pl.program_id(2)
-    inner = pl.program_id(3)
-
-    @pl.when(inner == 0)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
-
-    first, last = _band_span(ik, block_k, block_q, 0, window - 1, nq)
-    iq = first + inner % sweep
-
-    @pl.when(iq <= last)
-    def _step():
-        _bwd_dkv_step(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, None,
-            dk_acc, dv_acc, scale,
-            _window_mask(window, iq * block_q, ik * block_k),
-        )
-
-    @pl.when(inner == pl.num_programs(3) - 1)
-    def _finish():
-        dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
-
-
-def _window_q_sweep_specs(window, q_per_kv, block_q, block_k, nk, D):
-    """Block specs of the kernels whose grid is (B, Hq, q tiles, sweep
-    steps), forward and dq: a q tile's own block (q, o, do, dq), the kv
-    tile its sweep is at (GQA: q head h reads kv head h // q_per_kv), its
-    rows' residuals (lse, delta)."""
-
-    def kv_idx(b, h, iq, j):
-        first, last = _band_span(iq, block_q, block_k, window - 1, 0, nk)
-        return (b, h // q_per_kv, jnp.minimum(first + j, last), 0)
-
-    q_spec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, j: (b, h, iq, 0))
-    kv_spec = pl.BlockSpec((1, 1, block_k, D), kv_idx)
-    row_spec = pl.BlockSpec((1, 1, 8, block_q), lambda b, h, iq, j: (b, h, 0, iq))
-    return q_spec, kv_spec, row_spec
-
-
-def _window_forward_impl(qt, kt, vt, window, block_q, block_k, interpret):
-    B, Hq, S, D = qt.shape
-    nk = S // block_k
-    steps = max(_band_sweeps(S, window, block_q, block_k)[0])
-    q_spec, kv_spec, row_spec = _window_q_sweep_specs(
-        window, Hq // kt.shape[1], block_q, block_k, nk, D
-    )
-    return pl.pallas_call(
-        functools.partial(
-            _flash_window_kernel, scale=1.0 / math.sqrt(D), window=window,
-            block_q=block_q, block_k=block_k, nk=nk,
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hq, S, D), qt.dtype),
-            jax.ShapeDtypeStruct((B, Hq, 8, S), jnp.float32),
-        ],
-        grid=(B, Hq, S // block_q, steps),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[q_spec, row_spec],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qt, kt, vt)
-
-
-def _window_backward_impl(
-    qt, kt, vt, do, lse, delta, window, block_q, block_k, interpret
-):
-    B, Hq, S, D = qt.shape
-    Hkv = kt.shape[1]
-    q_per_kv = Hq // Hkv
-    nq, nk = S // block_q, S // block_k
-    kv_sweeps, q_sweeps = _band_sweeps(S, window, block_q, block_k)
-    static = dict(
-        scale=1.0 / math.sqrt(D), window=window, block_q=block_q, block_k=block_k
-    )
-
-    q_spec, kv_spec, row_spec = _window_q_sweep_specs(
-        window, q_per_kv, block_q, block_k, nk, D
-    )
-    dq = pl.pallas_call(
-        functools.partial(_flash_window_bwd_dq_kernel, nk=nk, **static),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, S, D), qt.dtype),
-        grid=(B, Hq, nq, max(kv_sweeps)),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        interpret=interpret,
-    )(qt, kt, vt, do, lse, delta)
-
-    sweep = max(q_sweeps)
-
-    def q_at(hk, ik, inner):
-        first, last = _band_span(ik, block_k, block_q, 0, window - 1, nq)
-        return hk * q_per_kv + inner // sweep, jnp.minimum(first + inner % sweep, last)
-
-    def q_idx(b, hk, ik, inner):
-        head, iq = q_at(hk, ik, inner)
-        return b, head, iq, 0
-
-    def row_idx(b, hk, ik, inner):
-        head, iq = q_at(hk, ik, inner)
-        return b, head, 0, iq
-
-    q_spec2 = pl.BlockSpec((1, 1, block_q, D), q_idx)
-    row_spec2 = pl.BlockSpec((1, 1, 8, block_q), row_idx)
-    kv_spec2 = pl.BlockSpec(
-        (1, 1, block_k, D), lambda b, hk, ik, inner: (b, hk, ik, 0)
-    )
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _flash_window_bwd_dkv_kernel, nq=nq, sweep=sweep, **static
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, S, D), kt.dtype),
-            jax.ShapeDtypeStruct((B, Hkv, S, D), vt.dtype),
-        ],
-        grid=(B, Hkv, nk, q_per_kv * sweep),
-        in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2, row_spec2],
-        out_specs=[kv_spec2, kv_spec2],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qt, kt, vt, do, lse, delta)
-    return dq, dk, dv
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_window(qt, kt, vt, window, block_q, block_k, interpret):
-    return _window_forward_impl(qt, kt, vt, window, block_q, block_k, interpret)[0]
-
-
-def _flash_window_fwd(qt, kt, vt, window, block_q, block_k, interpret):
-    out, lse = _window_forward_impl(qt, kt, vt, window, block_q, block_k, interpret)
-    return out, (qt, kt, vt, out, lse)
-
-
-def _flash_window_bwd(window, block_q, block_k, interpret, res, do):
-    qt, kt, vt, out, lse = res
-    return _window_backward_impl(
-        qt, kt, vt, do, lse, _row_delta(do, out), window, block_q, block_k,
-        interpret,
-    )
-
-
-_flash_window.defvjp(_flash_window_fwd, _flash_window_bwd)
-
-
 @functools.partial(
     jax.jit, static_argnames=("window", "block_q", "block_k", "interpret")
 )
@@ -1926,7 +1316,7 @@ def flash_attention_window(
     row i keeps the columns j <= i with i - j < ``window``. q: [B,S,Hq,D];
     k/v: [B,S,Hkv,D] with Hq % Hkv == 0. Returns [B,S,Hq,D] in q's dtype.
     A window of at least the sequence is the causal mask, and runs the
-    causal kernels. ``block_q``, ``block_k``: the largest tiles to take
+    causal family. ``block_q``, ``block_k``: the largest tiles to take
     (``choose_tiles``). The kernels' trace names start
     ``flash_attention_window`` (a kernel is named for the jit around it)."""
     B, S, Hq, D = q.shape
@@ -1940,9 +1330,6 @@ def flash_attention_window(
             f"({block_q},{block_k}); use dense_attention under the band mask"
         )
     itp = _interpret() if interpret is None else interpret
-    qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
-    if window >= S:
-        out = _flash(qt, kt, vt, True, *tiles, itp)
-    else:
-        out = _flash_window(qt, kt, vt, window, *tiles, itp)
-    return jnp.swapaxes(out, 1, 2)
+    qt, kt, vt = _heads_first(q, k, v)
+    family = _Causal(S, S, *tiles) if window >= S else _Window(S, S, *tiles, window)
+    return jnp.swapaxes(_flash(family, (), (qt,), (kt,), vt, itp), 1, 2)
