@@ -22,6 +22,7 @@ the in-repo flagship for the BASELINE.json HSDP Llama-3-8B config.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 from typing import Any, Callable, Optional
 
@@ -1131,61 +1132,197 @@ def _permute_rows_bwd(inv, g):
 _permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
 
 
+def _held_tile(rows: int) -> int:
+    """The row tile the held dispatch's loops step by, read from the
+    buffer's length: ``HELD_ROW_TILE`` where it divides ``rows``, else the
+    whole buffer (one tile: a buffer under a tile, the small models')."""
+    return HELD_ROW_TILE if rows % HELD_ROW_TILE == 0 else rows
+
+
+# What a row of the held dispatch's buffer holds where no tile was run.
+# Zero is what the whole-buffer formula gave such a row, so nothing after
+# it has to know; a test sets NaN here to show that no such row is read.
+_UNFILLED = 0.0
+
+
+def _filled_tiles(n_tiles, fn, *operands, over=0):
+    """``fn`` row tile by row tile over the first ``n_tiles`` (uint32)
+    tiles of ``operands`` (arrays of R rows each): one ``while`` whose
+    trip count the program reads, so the traced text is one body whatever
+    R is. ``fn`` takes the operands' tiles and returns a tuple of arrays
+    of as many rows. Its first ``over`` results are written over the
+    first ``over`` operands (of their shape and type), in place where
+    nothing reads the operand afterwards: their rows of the tiles not run
+    stay what the operand held, which is what a grouped matmul left past
+    its groups. The other results start as buffers of ``_UNFILLED``. A
+    buffer of one tile (``_held_tile``) is run whole, with no loop.
+    Counting in uint32 keeps jax from wrapping every start in the
+    arithmetic of a negative index."""
+    rows = operands[0].shape[0]
+    tile = _held_tile(rows)
+    if tile == rows:
+        return fn(*operands)
+    outs = jax.eval_shape(fn, *(
+        jax.ShapeDtypeStruct((tile,) + o.shape[1:], o.dtype) for o in operands
+    ))
+    if len(outs) > over:
+        # The fill waits for the operands: buffers of a constant can be set
+        # up at any time, and the v5e compiler set up every layer's before
+        # the first layer ran (5.4 GB in smallthinker-raw's step; PERF.md
+        # section 6, PR 64).
+        fill, operands = jax.lax.optimization_barrier(
+            (jnp.float32(_UNFILLED), operands)
+        )
+    zero = jnp.uint32(0)
+
+    def body(i, results):
+        start = i * jnp.uint32(tile)
+        at = lambda a: (start,) + (zero,) * (a.ndim - 1)  # noqa: E731
+        new = fn(*(
+            jax.lax.dynamic_slice(o, at(o), (tile,) + o.shape[1:])
+            for o in results[:over] + operands[over:]
+        ))
+        return tuple(
+            jax.lax.dynamic_update_slice(buf, part, at(buf))
+            for buf, part in zip(results, new)
+        )
+
+    return jax.lax.fori_loop(zero, n_tiles, body, operands[:over] + tuple(
+        jnp.full((rows,) + o.shape[1:], fill.astype(o.dtype)) for o in outs[over:]
+    ))
+
+
 @jax.custom_vjp
-def _held_rows(x: jax.Array, token: jax.Array, slot: jax.Array) -> jax.Array:
-    """Row ``token[r]`` of ``x`` [T, H] for each of the R rows of the held
-    dispatch's buffer. ``slot`` [T, K] is each assignment's row in the
-    buffer, R for one that has none. The transpose gathers by ``slot``
-    from the row gradients with a zero row appended and sums a token's K
-    copies: a gather again, where autodiff's own is a scatter-add."""
-    return x[token]
+def _held_rows(x, token, slot, n_tiles):
+    """Row ``token[r]`` of ``x`` [T, H] for each row r of the first
+    ``n_tiles`` tiles of the held dispatch's R-row buffer. ``slot`` [T, K]
+    is each assignment's row in the buffer, R for one that has none
+    (uint32, like ``token`` and ``n_tiles``: ``_filled_tiles``). The
+    transpose gathers by ``slot`` from the row gradients and sums a
+    token's K copies: a gather again, where autodiff's own is a
+    scatter-add."""
+    return _filled_tiles(n_tiles, lambda tok: (x[tok],), token)[0]
 
 
-def _held_rows_fwd(x, token, slot):
-    return x[token], slot
+def _held_rows_fwd(x, token, slot, n_tiles):
+    return _held_rows(x, token, slot, n_tiles), slot
 
 
 def _gather_sum(rows, slot, weights=None):
-    """sum_k weights[:, k] * rows_with_a_zero_row_appended[slot[:, k]] in
-    float32, one [T, H] gather a k: the [T, K, H] tensor of all of them at
-    once is K times a layer's activations, most of it the zero row."""
-    padded = jnp.concatenate([rows, jnp.zeros((1, rows.shape[-1]), rows.dtype)])
-    total = jnp.zeros((slot.shape[0], rows.shape[-1]), jnp.float32)
-    for k in range(slot.shape[1]):
-        part = padded[slot[:, k]].astype(jnp.float32)
-        total = total + (part if weights is None else part * weights[:, k, None])
-    return total
+    """sum_k weights[:, k] * rows[slot[:, k]] in float32, a slot past the
+    rows (R: no row) adding zero; one [T, H] gather a k: the [T, K, H]
+    tensor of all of them at once is K times a layer's activations, most
+    of it zero. A slot past the rows reads the last row and the select
+    drops what it read: only rows that hold an assignment reach the sum.
+    K times in every pass of every layer, so written to trace short: the
+    columns are split once and the gather is ``lax.gather`` itself."""
+    last = rows.shape[0] - 1
+    columns = lambda a: jnp.split(a, a.shape[1], axis=1)  # noqa: E731
+    take = functools.partial(
+        jax.lax.gather, rows, slice_sizes=(1, rows.shape[1]),
+        dimension_numbers=jax.lax.GatherDimensionNumbers((1,), (0,), (0,)),
+        mode="promise_in_bounds",
+    )
+    parts = [
+        jnp.where(keep, take(at), 0).astype(jnp.float32)
+        for at, keep in zip(columns(jnp.minimum(slot, last)), columns(slot <= last))
+    ]
+    if weights is not None:
+        parts = [part * w for part, w in zip(parts, columns(weights))]
+    return sum(parts[1:], parts[0])
 
 
 def _held_rows_bwd(slot, g):
-    return _gather_sum(g, slot).astype(g.dtype), None, None
+    return _gather_sum(g, slot).astype(g.dtype), None, None, None
 
 
 _held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
 
 
 @jax.custom_vjp
-def _combine_held(ys, gates, slot, rows, valid):
+def _held_rows_twice(xs, n_tiles):
+    """The held buffer's rows for each of the two grouped matmuls that
+    read them (gate and up): their two gradients are summed over the first
+    ``n_tiles`` tiles, where autodiff's own sum walks all R rows."""
+    return xs, xs
+
+
+def _held_rows_twice_bwd(n_tiles, gs):
+    return _filled_tiles(n_tiles, lambda a, b: (a + b,), *gs, over=1) + (None,)
+
+
+_held_rows_twice.defvjp(
+    lambda xs, n_tiles: ((xs, xs), n_tiles), _held_rows_twice_bwd
+)
+
+
+def _expert_act(kind: str, *hidden):
+    """What an expert puts between its matmuls, over ``hidden`` = (gate,
+    up) for a ``GATED_EXPERT_ACTS`` kind and (up,) for relu^2."""
+    if kind == "relu2":
+        (up,) = hidden
+        return jnp.square(nn.relu(up))
+    gate, up = hidden
+    return GATED_EXPERT_ACTS[kind](gate) * up
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_act(kind, n_tiles, *hidden):
+    """``_expert_act`` over the first ``n_tiles`` row tiles of the held
+    buffer's hidden rows, and its gradient over the same tiles."""
+    return _filled_tiles(n_tiles, lambda *h: (_expert_act(kind, *h),), *hidden)[0]
+
+
+def _held_act_fwd(kind, n_tiles, *hidden):
+    return _held_act(kind, n_tiles, *hidden), (n_tiles, hidden)
+
+
+def _held_act_bwd(kind, res, g):
+    n_tiles, hidden = res
+
+    def tile(g, *h):
+        return jax.vjp(functools.partial(_expert_act, kind), *h)[1](g)
+
+    # the gradients take the place of ``g`` and of the first hidden rows
+    return (None,) + tuple(
+        _filled_tiles(n_tiles, tile, g, *hidden, over=len(hidden))
+    )
+
+
+_held_act.defvjp(_held_act_fwd, _held_act_bwd)
+
+
+@jax.custom_vjp
+def _combine_held(ys, gates, slot, rows, valid, n_tiles):
     """out[t] = sum_k gates[t, k] * ys[slot[t, k]] in float32, a row past
     the buffer (``slot`` = R) counting as zero. ``rows`` [R] is each
     buffer row's assignment (token * K + k), ``valid`` [R] whether the row
-    holds one. Both transposes are written from the buffer's side: R-row
-    gathers and a gather of scalars, no scatter-add."""
+    holds one. Both transposes are written from the buffer's side, over
+    its first ``n_tiles`` tiles: row gathers and a gather of scalars, no
+    scatter-add."""
     return _gather_sum(ys, slot, gates)
 
 
-def _combine_held_fwd(ys, gates, slot, rows, valid):
-    return _gather_sum(ys, slot, gates), (ys, gates, slot, rows, valid)
+def _combine_held_fwd(ys, gates, slot, rows, valid, n_tiles):
+    return _gather_sum(ys, slot, gates), (ys, gates, slot, rows, valid, n_tiles)
 
 
 def _combine_held_bwd(res, g):
-    ys, gates, slot, rows, valid = res
-    g_rows = g[rows // gates.shape[1]]  # [R, H] float32
-    row_gate = jnp.where(valid, gates.reshape(-1)[rows], 0.0)
-    d_ys = (g_rows * row_gate[:, None]).astype(ys.dtype)
-    dots = jnp.where(valid, jnp.sum(ys.astype(jnp.float32) * g_rows, axis=-1), 0.0)
-    d_gates = jnp.concatenate([dots, jnp.zeros((1,), dots.dtype)])[slot]
-    return d_ys, d_gates.astype(gates.dtype), None, None, None
+    ys, gates, slot, rows, valid, n_tiles = res
+    flat_gates = gates.reshape(-1)
+
+    def tile(ys, rows, valid):
+        g_rows = g[rows // gates.shape[1]]  # [tile, H] float32
+        row_gate = jnp.where(valid, flat_gates[rows], 0.0)
+        dots = jnp.sum(ys.astype(jnp.float32) * g_rows, axis=-1)
+        return (
+            (g_rows * row_gate[:, None]).astype(ys.dtype),
+            jnp.where(valid, dots, 0.0),
+        )
+
+    d_ys, dots = _filled_tiles(n_tiles, tile, ys, rows, valid, over=1)
+    d_gates = jnp.where(slot < dots.shape[0], dots[slot], 0.0)
+    return d_ys, d_gates.astype(gates.dtype), None, None, None, None
 
 
 _combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
@@ -1251,9 +1388,10 @@ class MoEMLP(nn.Module):
     ``moe_max_load`` (largest expert's assignments over the mean),
     ``moe_dropped`` (assignments not computed) and, from a layer that
     holds a share, ``moe_held_share`` (the share of all assignments that
-    landed on it); where ``router_bias_update_rate`` > 0, ``moe_load`` (the
-    assignments each of the E experts got, a vector). The reference has no
-    MoE/EP anywhere (SURVEY.md §2.3).
+    landed on it) and ``moe_held_run_share`` (the share of its row buffer
+    in the row tiles its loops ran); where ``router_bias_update_rate`` > 0,
+    ``moe_load`` (the assignments each of the E experts got, a vector). The
+    reference has no MoE/EP anywhere (SURVEY.md §2.3).
     """
 
     cfg: LlamaConfig
@@ -1434,7 +1572,16 @@ class MoEMLP(nn.Module):
         far above what a router under its balance term sends (the sown
         ``moe_held_share`` says how far). An assignment to a held expert
         that does not fit is not computed and is counted in ``moe_dropped``,
-        which a run checks is 0."""
+        which a run checks is 0.
+
+        The grouped matmuls stop at the rows filled by their group sizes.
+        Whatever else has R rows (the gather into the buffer, the
+        activation and its gradient, the row gradients' sum, the combine's
+        transpose) runs over the first ceil(rows filled / tile) row tiles,
+        in loops whose trip count is read from the step's own ``n_fit``
+        (``_filled_tiles``; the sown ``moe_held_run_share`` is the share of
+        R they ran). The two argsorts still walk the T*K assignments and
+        the gathers that bring a token's K rows back walk T rows."""
         cfg = self.cfg
         first, count = cfg.experts_held
         E, K, H = cfg.num_experts, cfg.num_experts_per_tok, x.shape[-1]
@@ -1463,22 +1610,34 @@ class MoEMLP(nn.Module):
             )
             self.sow("intermediates", "moe_dropped", load.sum() - n_fit)
             self.sow("intermediates", "moe_held_share", load.sum() / (T * K))
+            tile = _held_tile(R)
+            n_tiles = (n_fit.astype(jnp.uint32) + (tile - 1)) // tile
+            self.sow(
+                "intermediates", "moe_held_run_share",
+                n_tiles * (tile / R) if tile < R else jnp.ones(()),
+            )
             self._sow_load(all_sizes.astype(jnp.float32))
 
             local = flat_idx - first
             key = jnp.where((local >= 0) & (local < count), local, count)
             order = jnp.argsort(key, stable=True)  # sorted row -> assignment
             inv = jnp.argsort(order)  # assignment -> sorted row
-            rows = order[:R]
+            rows = order[:R].astype(jnp.uint32)
             valid = jnp.arange(R) < n_fit
-            slot = jnp.where(inv < n_fit, inv, R).reshape(T, K)
+            slot = jnp.where(inv < n_fit, inv, R).astype(jnp.uint32).reshape(T, K)
         with jax.named_scope("moe/experts"):
-            xs = _held_rows(x.reshape(T, H).astype(cfg.dtype), rows // K, slot)
+            xs = _held_rows(
+                x.reshape(T, H).astype(cfg.dtype), rows // K, slot, n_tiles
+            )
             gmm = lambda a, w: jax.lax.ragged_dot(  # noqa: E731
                 a, w, fit, preferred_element_type=cfg.dtype
             )
-            ys = self._ffn(xs, weights, gmm)  # [R,H]
-            out = _combine_held(ys, gate_vals.reshape(T, K), slot, rows, valid)
+            reads = _held_rows_twice(xs, n_tiles) if len(weights) == 3 else (xs,)
+            hidden = [gmm(a, w) for a, w in zip(reads, weights[:-1])]  # [R,I] each
+            ys = gmm(_held_act(cfg.expert_act, n_tiles, *hidden), weights[-1])
+            out = _combine_held(
+                ys, gate_vals.reshape(T, K), slot, rows, valid, n_tiles
+            )
         return out.reshape(x.shape).astype(x.dtype)
 
     def _capacity(self, x, probs, gate_vals, gate_idx, w_gate, w_up, w_down):

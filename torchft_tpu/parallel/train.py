@@ -202,6 +202,9 @@ _SOWN_OVER_LAYERS = (
     ("moe_max_load", jnp.max),
     ("moe_dropped", jnp.sum),
     ("moe_held_share", jnp.mean),
+    # Of the held dispatch's row buffer, the share in the row tiles its
+    # loops ran (``MoEMLP._sorted_held``): 1 where the buffer is one tile.
+    ("moe_held_run_share", jnp.mean),
     # From the attention under the block-diffusion mask: of the score
     # entries it computes, the share its mask keeps
     # (``block_diffusion_attention``).
