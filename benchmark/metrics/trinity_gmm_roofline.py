@@ -1,0 +1,11 @@
+"""The grouped matmuls' share of their roofline in Trinity-Mini's expert
+layers, as ``gated_gmm_roofline`` reads it: the least time the chip needs
+for the matmuls of the rows the traced steps really filled (this
+architecture's flops.py, four expert layers of three-matrix SiLU-gated
+experts, at the mean ``moe_held_share`` those steps counted; the shared
+expert's plain matmuls are not in it) over the device time of XLA's
+``ragged-dot`` kernels (which times remat's second forward too). At about
+1,024 rows an expert of [2048, 1024] weights the matmuls are
+compute-bound."""
+
+from benchmark.metrics.gated_gmm_roofline import read  # noqa: F401

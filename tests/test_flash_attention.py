@@ -725,10 +725,45 @@ def test_the_grids_run_the_tiles_the_band_touches_and_no_others(seq, window, bq,
     assert (family.q_steps, family.kv_sweeps) == (max(kv_sweeps), ((0, nk, max(q_sweeps)),))
     if bq == bk:
         assert max(kv_sweeps) == max(q_sweeps) == min(-(-(w - 1) // bq) + 1, nq)
+    # ``window_tiles`` takes bounds: a band under four of a measured-good
+    # tile wide takes the next one down (the last case: 512 under 1,024)
     kept, run = fa.window_tiles(seq, window, bq, bk)
-    assert run == len(pairs) * bq * bk
+    cq, ck = fa.choose_tiles("window", seq, (), bq, bk, window=window)
+    assert (cq, ck) == ((512, 512) if window == 1000 else (bq, bk))
+    assert run == sum(fa._band_sweeps(seq, w, cq, ck)[0]) * cq * ck
+    assert (cq, ck) != (bq, bk) or run == len(pairs) * bq * bk
     assert kept == sum(min(i + 1, w) for i in range(seq))
     assert kept == flops.window_kept_entries(dict(PUBLISHED, sliding_window_size=window), seq)
+
+
+@pytest.mark.parametrize("seq,window,bounds,tiles", [
+    (16384, 4096, (1024, 1024), (1024, 1024)),   # smallthinker-raw: four tiles wide
+    (16384, 4097, (1024, 1024), (1024, 1024)),
+    (16384, 4095, (1024, 1024), (512, 512)),
+    (16384, 2048, (1024, 1024), (512, 512)),     # trinity-raw: four tiles of 512
+    (16384, 1024, (1024, 1024), (512, 512)),     # no measured-good tile under 512
+    (16384, 2048, (512, 512), (512, 512)),
+    (16384, 2048, (1024, 512), (512, 512)),
+    (16384, 16384, (1024, 1024), (1024, 1024)),  # the whole sequence: causal
+    (1024, 2048, (1024, 1024), (1024, 1024)),
+    (1024, 512, (128, 128), (128, 128)),         # the reference check's sample
+    (1024, 256, (128, 128), (128, 128)),
+    (64, 16, (16, 16), (16, 16)),                # the CPU tests' tiles
+    (256, 64, (1024, 1024), (256, 256)),         # a sequence under a tile
+])
+def test_a_narrow_band_takes_the_next_tile_down(seq, window, bounds, tiles):
+    """``choose_tiles("window", ..., window=)``: the causal family's tiles
+    where the band is at least four of them wide, else the next
+    measured-good tile down; bounds under every measured-good tile, a window
+    of the whole sequence and a call that names no window are untouched."""
+    assert fa.choose_tiles("window", seq, (128,), *bounds, window=window) == tiles
+    assert fa.choose_tiles("window", seq, (128,), *bounds) == fa.choose_tiles(
+        "causal", seq, (128,), *bounds)
+    assert fa.supports_window(seq, window, *bounds)
+    sweep = max(fa._band_sweeps(seq, min(window, seq), *tiles)[0])
+    assert fa.window_tiles(seq, window, *bounds)[1] == sum(
+        fa._band_sweeps(seq, min(window, seq), *tiles)[0]) * tiles[0] * tiles[1]
+    assert sweep >= 1
 
 
 def test_the_cells_schedule_is_seventy_tiles_of_the_causal_136():

@@ -568,7 +568,7 @@ def test_the_presets():
     plain = llama.LlamaConfig()
     assert (plain.sliding_window, plain.router_ahead, plain.expert_act) == (None, False, "swiglu")
     assert all("W" not in (PRESETS[n]().layer_pattern or "") for n in PRESETS
-               if not n.startswith("smallthinker"))
+               if not n.startswith(("smallthinker", "trinity")))
     with pytest.raises(ValueError, match="'W'"):
         llama.MixerLayer(small, "Q").init(jax.random.PRNGKey(0), jnp.zeros((1, 8, 64)))
 

@@ -16,7 +16,11 @@ its matmul operands rounded to that type in the system's place: the next
 precision down, which a tolerance has to refuse. ``--program-window N``
 gives the PROGRAM's windowed layers a window of N positions and leaves the
 reference its own: a band that is misplaced or missing, which a tolerance
-has to refuse too.
+has to refuse too. ``--departure NAME`` (an architecture whose reference
+names its ``DEPARTURES``: afmoe's dropped post-norm, missing embedding
+scale, gate on the stream, rotated global layer, bias in the gates) hands
+the comparison the reference computing that other model in the system's
+place.
 
     python tools/reference_compare.py --workload smallthinker-raw \\
         --seeds 3000000001,2999999877 --query-block 256 --out chiprun_out/compare.jsonl
@@ -44,6 +48,7 @@ if ROOT not in sys.path:
 def comparisons(
     cell: Any, seq: int, seeds: Sequence[int], query_block: Optional[int] = None,
     operand_dtype: Optional[str] = None, program_window: Optional[int] = None,
+    departure: Optional[str] = None,
 ) -> Iterator[Dict[str, Any]]:
     """One comparison a seed (weights and tokens from it), its readings
     beside the reference's tolerances; the programs are compiled once."""
@@ -71,11 +76,15 @@ def comparisons(
         out_shardings=shardings.params,
     )
     ref = jax.jit(lambda p, b: reference.loss_and_grads(p, b, config, **options))
-    if operand_dtype is None:
+    if operand_dtype is None and departure is None:
         system = make_grad_step(model, mesh, shardings)
     else:
-        system = jax.jit(lambda p, b: reference.loss_and_grads(
-            p, b, config, operand_dtype=jnp.dtype(operand_dtype), **options))
+        other = dict(options)
+        if operand_dtype is not None:
+            other["operand_dtype"] = jnp.dtype(operand_dtype)
+        if departure is not None:
+            other["departure"] = departure
+        system = jax.jit(lambda p, b: reference.loss_and_grads(p, b, config, **other))
     rel = jax.jit(lambda a, b: jnp.linalg.norm((a - b).ravel()) / jnp.linalg.norm(b.ravel()))
     for seed in seeds:
         toks = jax.random.randint(
@@ -104,6 +113,7 @@ def comparisons(
             "seed": seed,
             "compared": (
                 f"reference in {operand_dtype}" if operand_dtype is not None
+                else f"reference under {departure}" if departure is not None
                 else "system" if program_window is None
                 else f"system under a window of {program_window}"
             ),
@@ -130,7 +140,10 @@ def compare(cell: Any, seq: int, seed: int, **options: Any) -> Dict[str, Any]:
 
 
 def main() -> int:
+    from _train_common import enable_compile_cache
     from benchmark import cells
+
+    enable_compile_cache()  # one call makes several comparisons of one reference
 
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -139,6 +152,7 @@ def main() -> int:
     ap.add_argument("--query-block", type=int, default=0)
     ap.add_argument("--operand-dtype", default=None)
     ap.add_argument("--program-window", type=int, default=0)
+    ap.add_argument("--departure", default=None)
     ap.add_argument("--out", default="")
     args = ap.parse_args()
     cell = cells.load_cell(args.workload)
@@ -146,6 +160,7 @@ def main() -> int:
     for out in comparisons(
         cell, args.seq or int(cell.mix["seq"]), [int(x) for x in args.seeds.split(",")],
         args.query_block or None, args.operand_dtype, args.program_window or None,
+        args.departure,
     ):
         line = json.dumps(out)
         print(line, flush=True)

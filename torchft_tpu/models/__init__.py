@@ -37,6 +37,8 @@ from torchft_tpu.models.llama import (  # noqa: F401
     smallthinker_debug,
     solar_open2_250b,
     solar_open2_debug,
+    trinity_debug,
+    trinity_mini,
 )
 
 # What ``train_hsdp.py --model`` names: each architecture's small preset, the 125M
@@ -55,4 +57,6 @@ PRESETS = {
     "solar_open2_debug": solar_open2_debug,
     "smallthinker_21b": smallthinker_21b,
     "smallthinker_debug": smallthinker_debug,
+    "trinity_mini": trinity_mini,
+    "trinity_debug": trinity_debug,
 }

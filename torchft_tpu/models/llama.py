@@ -89,6 +89,10 @@ class LlamaConfig:
     # holds a share of each layer's experts (``experts_held``) needs it to
     # keep its routing spread under random weights (PERF.md section 6, PR 60).
     embed_init_std: Optional[float] = None
+    # What the embedded rows are multiplied by where they are read, in
+    # ``dtype`` (muP's sqrt(hidden_size): AFMoE's ``mup_enabled``). 1.0: no
+    # multiply is emitted.
+    embed_scale: float = 1.0
     remat: bool = True
     # 'dense' | 'flash' | 'ring' | 'ulysses'. flash = Pallas on-chip blocked attention
     # (ops/flash_attention.py, dense fallback for odd seq lens); ring
@@ -167,8 +171,10 @@ class LlamaConfig:
     # Where a ``layer_pattern`` layer's RMSNorm stands. False: before the
     # mixer, x + mixer(norm(x)). True: after it, x + norm(mixer(x)), the
     # residual stream itself feeding the mixer (OLMo 2's layer,
-    # arXiv:2501.00656 section 3).
-    norm_after_mixer: bool = False
+    # arXiv:2501.00656 section 3). "both": one before and a second
+    # (``post_norm``) after, x + post_norm(mixer(norm(x))), four norms a
+    # published layer of two sub-layers (AFMoE's sandwich).
+    norm_after_mixer: Any = False
     # The dense feed-forward's width where it is not the experts'
     # (``intermediate_size`` is then an expert's). None: one width for both.
     dense_intermediate_size: Optional[int] = None
@@ -759,6 +765,87 @@ def smallthinker_debug(**overrides: Any) -> LlamaConfig:
     return dataclasses.replace(cfg, **overrides)
 
 
+def trinity_mini(**overrides: Any) -> LlamaConfig:
+    """Trinity-Mini (arcee-ai/Trinity-Mini config.json, model_type ``afmoe``,
+    26B-A3B) at its published sizes: 32 layers of an attention (32 heads on
+    4 of width 128, per-head QK norms, an elementwise sigmoid output gate)
+    and a feed-forward, each sub-layer between a norm before AND a norm
+    after it; three layers in four a sliding window of 2,048 with rotary
+    embedding (theta 1e4), the fourth a global attention without; two
+    leading dense SwiGLU feed-forwards of width 6144, then 128 SiLU-gated
+    experts of width 1024, 8 a token by sigmoid scores plus a selection
+    bias the step moves, gates renormalised and scaled 2.826, one shared
+    expert; the embedded rows times sqrt(2048) (muP); an untied 200,192-row
+    head. Override ``layer_pattern``, ``experts_held`` and ``vocab_size``
+    for what one chip holds. The gate, the QK norms, the norms' places and
+    the rope-free global layers are the model's code and no key of the
+    published file (benchmark/configs/trinity-mini-l5e16.json,
+    ``assumed``)."""
+    # Two characters a published layer: its attention ('*' at layers 3, 7,
+    # ..., 'W' elsewhere), then its feed-forward ('D' in the two leading
+    # layers, 'E' after).
+    pattern = "".join(
+        ("*" if i % 4 == 3 else "W") + ("D" if i < 2 else "E") for i in range(32)
+    )
+    cfg = LlamaConfig(
+        vocab_size=200192,
+        hidden_size=2048,
+        intermediate_size=1024,
+        dense_intermediate_size=6144,
+        num_layers=32,
+        layer_pattern=pattern,
+        num_heads=32,
+        num_kv_heads=4,
+        head_dim=128,
+        max_seq_len=131072,
+        rope_theta=10000.0,
+        norm_eps=1e-5,
+        embed_scale=2048 ** 0.5,
+        rope=False,
+        sliding_window=2048,
+        qk_norm="head",
+        attn_gate=True,
+        norm_after_mixer="both",
+        num_experts=128,
+        num_experts_per_tok=8,
+        expert_capacity_factor=None,
+        router_score="sigmoid",
+        routed_scaling=2.826,
+        shared_expert_size=1024,
+        router_aux_coef=0.0,
+        router_z_coef=0.0,
+        router_bias_update_rate=1e-3,
+    )
+    return dataclasses.replace(cfg, **overrides)
+
+
+def trinity_debug(**overrides: Any) -> LlamaConfig:
+    """Tiny Trinity (published layers 1-5: a windowed attention and a dense
+    feed-forward, then one period of windowed, global, windowed, windowed
+    over 16 positions with an expert layer after each; 16 experts of which
+    4 are held) for tests and ``train_hsdp.py --model trinity_debug``."""
+    cfg = trinity_mini(
+        vocab_size=256,
+        hidden_size=64,
+        intermediate_size=48,
+        dense_intermediate_size=160,
+        num_layers=5,
+        layer_pattern="WDWE*EWEWE",
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        max_seq_len=128,
+        embed_scale=8.0,
+        sliding_window=16,
+        num_experts=16,
+        num_experts_per_tok=3,
+        experts_held=(0, 4),
+        shared_expert_size=48,
+        remat=False,
+    )
+    return dataclasses.replace(cfg, **overrides)
+
+
 def llama_moe_debug(**overrides: Any) -> LlamaConfig:
     """Tiny MoE config (4 experts, top-2) for tests and the ep dryrun."""
     cfg = llama_debug(num_experts=4, num_experts_per_tok=2)
@@ -895,7 +982,8 @@ def window_attention(cfg: LlamaConfig, seq_len: int) -> tuple:
     tiles = None
     if cfg.attn_impl == "flash" and seq_len >= cfg.flash_min_seq:
         tiles = fa.choose_tiles(
-            "window", seq_len, (cfg.head_dim,), cfg.flash_block_q, cfg.flash_block_k
+            "window", seq_len, (cfg.head_dim,), cfg.flash_block_q, cfg.flash_block_k,
+            window=window,
         )
     if tiles is not None:
         kept, run = fa.window_tiles(
@@ -1754,9 +1842,11 @@ class ShortConvMixer(nn.Module):
 
 
 class MixerLayer(nn.Module):
-    """One layer of a ``layer_pattern`` stack: x + mixer(RMSNorm(x)) or,
-    under ``norm_after_mixer``, x + RMSNorm(mixer(x)); the mixer named for
-    its kind so that the sharding rules find it. Under
+    """One layer of a ``layer_pattern`` stack: x + mixer(RMSNorm(x)),
+    under ``norm_after_mixer`` x + RMSNorm(mixer(x)) or, where that is
+    "both", x + RMSNorm(mixer(RMSNorm(x))), the second norm named
+    ``post_norm``; the mixer named for its kind so that the sharding rules
+    find it. Under
     ``cfg.router_ahead`` an attention layer ('*', 'W') also holds the
     router of the expert layer after it and returns (x, that router's
     float32 logits from its own normed input), and an expert layer is
@@ -1773,7 +1863,19 @@ class MixerLayer(nn.Module):
     ) -> Any:
         cfg = self.cfg
         norm = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="norm")
-        h, after = (x, norm) if cfg.norm_after_mixer else (norm(x), lambda y: y)
+        place = cfg.norm_after_mixer
+        if place not in (False, True, "both"):
+            raise ValueError(f"norm_after_mixer {place!r} is none of False, True, 'both'")
+        if place == "both":
+            post_norm = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="post_norm")
+
+            def after(y):
+                with jax.named_scope("norm/post"):
+                    return post_norm(y)
+
+            h = norm(x)
+        else:
+            h, after = (x, norm) if place else (norm(x), lambda y: y)
         if self.kind == "M":
             return x + after(Mamba2Mixer(
                 cfg.mamba, cfg.hidden_size, cfg.norm_eps, cfg.residual_init_scale,
@@ -1947,7 +2049,7 @@ class Transformer(nn.Module):
             ),
             name="embed",
         )
-        x = embed(tokens)
+        x = self._embedded(embed, tokens)
         if cfg.router_ahead and (cfg.layer_pattern is None or cfg.norm_after_mixer):
             raise ValueError(
                 "router_ahead is a layer_pattern stack's, of pre-normed layers"
@@ -1966,7 +2068,8 @@ class Transformer(nn.Module):
                 nxt = jnp.roll(tokens, -1, axis=1) if next_tokens is None else next_tokens
                 for k in range(cfg.mtp_layers):
                     predicted.append(MTPModule(cfg, name=f"mtp_{k}")(
-                        predicted[-1] if predicted else x, embed(nxt), *rotary
+                        predicted[-1] if predicted else x,
+                        self._embedded(embed, nxt), *rotary
                     ))
                     nxt = jnp.roll(nxt, -1, axis=1)
             return self._head(embed, x, return_hidden, predicted)
@@ -1992,6 +2095,14 @@ class Transformer(nn.Module):
         )(cfg, name="layers")
         x, _ = stack(x, cos, sin)
         return self._head(embed, x, return_hidden)
+
+    def _embedded(self, embed: nn.Embed, tokens: jax.Array) -> jax.Array:
+        """The tokens' rows of the table, times ``embed_scale``."""
+        x, scale = embed(tokens), self.cfg.embed_scale
+        if scale == 1.0:
+            return x
+        with jax.named_scope("embed/scale"):
+            return x * jnp.asarray(scale, x.dtype)
 
     def _head(
         self, embed: nn.Embed, x: jax.Array, return_hidden: bool, predicted=(),

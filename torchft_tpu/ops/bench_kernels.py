@@ -253,6 +253,8 @@ def kda_section(compiled: bool) -> dict:
 # every benchmark cell's shape. (family, cell, (B, S, Hq, Hkv), head widths
 # (qk, ..., v), block length): causal heads are one width; the latent
 # family's are (rope-free, rotary, value); block diffusion's S is both streams.
+# The last number is block diffusion's block length and the banded family's
+# window.
 SWEEP_SHAPES = [
     ("causal", "mistral", (4, 4096, 32, 8), (128,), 0),
     ("causal", "internlm2", (2, 8192, 16, 8), (128,), 0),
@@ -261,6 +263,8 @@ SWEEP_SHAPES = [
     ("causal", "lfm2", (2, 8192, 32, 8), (64,), 0),
     ("block_diffusion", "sdar", (2, 16384, 32, 4), (128,), 4),
     ("mla", "joyai", (2, 8192, 32, 32), (128, 64, 128), 0),
+    ("window", "smallthinker", (1, 16384, 28, 4), (128,), 4096),
+    ("window", "trinity", (1, 16384, 32, 4), (128,), 2048),
 ]
 SWEEP_TILES = [(512, 512), (1024, 512), (512, 1024), (1024, 1024), (2048, 512), (512, 2048)]
 _V5E_BF16_FLOPS = 197e12  # benchmark/peaks.json, "TPU v5 lite"
@@ -293,6 +297,9 @@ def _sweep_case(family, dims, widths, block_length, tiles, interpret):
         args = (rand(0, B, Hq, S, d), rand(1, B, Hkv, S, d), rand(2, B, Hkv, S, d))
         if family == "causal":
             value, entries = fa._Causal(S, S, bq, bk), S * S / 2
+        elif family == "window":
+            window = block_length
+            value, entries = fa._Window(S, S, bq, bk, window), fa.window_kept(S, window)
         else:
             L = S // 2
             value, entries = fa._BlockDiffusion(S, S, bq, bq, block_length), L * L + L * block_length

@@ -150,6 +150,28 @@ def _tile(length: int, largest: int, multiple: int = 16) -> Optional[int]:
     return None
 
 
+# A banded sweep's first and last tile are crossed by a mask edge, whatever
+# the tile: a band narrower than this many of a tile takes the next
+# measured-good tile down. On a v5e at S = 16,384, 32 heads on 4 of width 128
+# (forward + dq + dkv, ms): a window of 2,048, two tiles of 1,024 wide, a
+# sweep of three, 23.34 at 1,024 against 21.60 at 512 (38.8 at 256), the
+# same to 0.1% on three repeats; a window of 4,096, four wide, a sweep of
+# five, 31.0 at 1,024 against 31.5 at 512 (PERF.md section 6, PR 65).
+_BAND_TILES = 4
+
+
+def _band_tile(window: int, largest: int) -> int:
+    """The largest tile a band of ``window`` positions admits under
+    ``largest``: the first of the measured-good tiles the band is at least
+    ``_BAND_TILES`` wide in, else the smallest of them; ``largest`` itself
+    where it is under them all (the CPU tests' tiles, the reference
+    check's 128)."""
+    good = [t for t in _GOOD_TILES if t <= largest]
+    if not good:
+        return largest
+    return next((t for t in good if window >= _BAND_TILES * t), good[-1])
+
+
 def choose_tiles(
     family: str,
     seq_len: int,
@@ -158,14 +180,18 @@ def choose_tiles(
     block_k: int = _MAX_TILE,
     kv_len: Optional[int] = None,
     block_length: int = 0,
+    window: int = 0,
 ) -> Optional[tuple]:
     """The (q rows, kv columns) a call's VMEM tiles hold, from what the
     call can see of its input, or None where the kernels do not take the
     shape (the caller then runs dense attention). The one rule of every
     entry and ``supports*`` predicate below.
 
-    ``family``: 'causal', 'window' (the band's tiles are the causal
-    family's: the window decides the sweep, not the tile), 'block' (the
+    ``family``: 'causal', 'window' (a band of ``window`` positions inside
+    the sequence: the causal family's tiles where the band is at least
+    ``_BAND_TILES`` of them wide, else the next measured-good tile down,
+    ``_band_tile``; a window of the whole sequence or none given: the
+    causal family's), 'block' (the
     ring's offset block: ``kv_len``
     keys against ``seq_len`` queries), 'block_diffusion' (``seq_len`` the
     length of ONE stream, ``block_length`` its blocks) or 'mla'
@@ -195,6 +221,8 @@ def choose_tiles(
         nope, rope, v_dim = widths
         if nope % _LANES or v_dim % _LANES or rope % (_LANES // 2):
             return None
+    if family == "window" and 0 < window < seq_len:
+        block_q, block_k = _band_tile(window, block_q), _band_tile(window, block_k)
     bq = _tile(seq_len, block_q)
     bk = _tile(seq_len if kv_len is None else kv_len, block_k)
     return None if bq is None or bk is None else (bq, bk)
@@ -1274,7 +1302,9 @@ def supports_window(
     """Whether the banded kernels handle this sequence under a window of
     ``window`` positions and these largest tiles (by shape alone; the
     caller falls back to dense attention under the band mask otherwise)."""
-    return window >= 1 and choose_tiles("window", seq_len, (), block_q, block_k) is not None
+    return window >= 1 and choose_tiles(
+        "window", seq_len, (), block_q, block_k, window=window
+    ) is not None
 
 
 def window_kept(seq_len: int, window: int) -> int:
@@ -1291,7 +1321,7 @@ def window_tiles(
     and sequence, forward, at the tiles the kernels choose under the
     bounds; the backward kernels run the same tiles. A window of at least
     the sequence runs the causal schedule."""
-    tiles = choose_tiles("window", seq_len, (), block_q, block_k)
+    tiles = choose_tiles("window", seq_len, (), block_q, block_k, window=window)
     if tiles is None:
         raise ValueError(
             f"window_tiles: {seq_len} positions do not tile under ({block_q},{block_k})"
@@ -1323,7 +1353,7 @@ def flash_attention_window(
     assert Hq % k.shape[2] == 0, (Hq, k.shape[2])
     if window < 1:
         raise ValueError(f"flash_attention_window: window {window} keeps nothing")
-    tiles = choose_tiles("window", S, (D,), block_q, block_k)
+    tiles = choose_tiles("window", S, (D,), block_q, block_k, window=window)
     if tiles is None:
         raise ValueError(
             f"flash_attention_window: seq_len {S} not divisible by blocks "
