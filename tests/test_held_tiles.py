@@ -1,5 +1,6 @@
-"""The held expert dispatch over the rows it filled (``MoEMLP._sorted_held``,
-``llama._filled_tiles``): values, gradients and counters against the
+"""The held expert dispatch over the rows it filled and the tokens that
+hold one (``MoEMLP._sorted_held``, ``llama._filled_tiles``,
+``llama._gather_sum``): values, gradients and counters against the
 whole-buffer formula it replaced, which this file keeps as the oracle; and
 the lowered layer, which may not grow with the number of row tiles."""
 
@@ -156,6 +157,22 @@ def inputs(cfg, tokens: int, seed: int = 1):
     return params, x
 
 
+def tokens_touched(cfg, logits, n_fit: int) -> int:
+    """How many tokens hold a row of the buffer, counted apart from the
+    layer: the top-K of the logits, the held assignments in the order of
+    their experts, the first ``n_fit`` of them."""
+    first, count = cfg.experts_held
+    top = np.argsort(-np.asarray(logits[0]), axis=-1, kind="stable")[:, :K]
+    local = top.reshape(-1) - first
+    key = np.where((local >= 0) & (local < count), local, count)
+    fitted = np.argsort(key, kind="stable")[:n_fit]
+    return len(np.unique(fitted // K))
+
+
+def token_run_share(touched: int, tokens: int) -> float:
+    return -(-touched // HELD_ROW_TILE) * HELD_ROW_TILE / tokens
+
+
 def assert_same(tiled, whole):
     for got, want in zip(jax.tree_util.tree_leaves(tiled), jax.tree_util.tree_leaves(whole)):
         assert np.isfinite(np.asarray(got)).all()
@@ -186,6 +203,12 @@ def test_the_tiled_dispatch_is_the_whole_buffer_formula(form, n_held):
     assert sown["moe_dropped"] == want_sown["moe_dropped"] == n_held - n_fit
     tiles = -(-n_fit // HELD_ROW_TILE)
     assert sown["moe_held_run_share"] == tiles * HELD_ROW_TILE / ROWS
+    # the token side: none of the tokens, the first n_held of them, all of
+    # them; past R, the 750 whose row of the first held expert fitted (the
+    # second expert's 274 rows that fitted are tokens among those)
+    touched = tokens_touched(cfg, logits, n_fit)
+    assert touched == (min(n_held, TOKENS) if n_held <= ROWS else 750)
+    assert sown["moe_held_token_run_share"] == token_run_share(touched, TOKENS)
     if n_held:  # the held experts' part reached the output and the gradients
         assert float(jnp.abs(grads[0]["experts_up"]).max()) > 0
 
@@ -204,19 +227,31 @@ def test_a_buffer_under_one_tile_is_run_whole(form, n_held):
     want_out, want_sown, want_grads = run(WholeBufferMoE, cfg, params, x, logits)
     assert_same((out, grads), (want_out, want_grads))
     assert sown["moe_dropped"] == want_sown["moe_dropped"] == max(n_held - rows, 0)
-    assert sown["moe_held_run_share"] == 1.0
+    assert sown["moe_held_run_share"] == sown["moe_held_token_run_share"] == 1.0
     lowered = jax.jit(
         lambda p, x, lg: MoEMLP(cfg).apply({"params": p}, x, lg)
     ).lower(params, x, logits).as_text()
     assert "while" not in lowered
 
 
+@pytest.fixture
+def nan_where_no_tile_ran(monkeypatch):
+    """NaN in every row a loop does not write, the token side's compact
+    buffer among them. The token side is jitted and reads ``_UNFILLED`` as
+    it is traced, so what was traced before is forgotten, and after."""
+    monkeypatch.setattr(llama, "_UNFILLED", jnp.nan)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
 @pytest.mark.parametrize("form", ["gated", "relu2"])
 @pytest.mark.parametrize("n_held", [0, 1, HELD_ROW_TILE + 1], ids=["none", "one", "tile+1"])
-def test_no_row_of_a_tile_not_run_reaches_a_result(form, n_held, monkeypatch):
+def test_no_row_of_a_tile_not_run_reaches_a_result(form, n_held, nan_where_no_tile_ran):
     """With NaN where the loops write nothing, outputs and gradients are
-    still the oracle's: what reads the buffer reads filled rows only."""
-    monkeypatch.setattr(llama, "_UNFILLED", jnp.nan)
+    still the oracle's: what reads the buffer reads filled rows only, and
+    what reads the token side's compact buffer reads the tokens of the
+    tiles run only."""
     cfg = layer_config(form)
     params, x = inputs(cfg, TOKENS, seed=3)
     logits = logits_for(n_held, TOKENS, seed=3)
@@ -225,14 +260,31 @@ def test_no_row_of_a_tile_not_run_reaches_a_result(form, n_held, monkeypatch):
     assert_same((out, grads), (want_out, want_grads))
 
 
-def test_bfloat16_rows_agree_too():
-    """The cells compute in bfloat16: the same rows, the same roundings."""
+@pytest.mark.parametrize(
+    "n_held", [0, HELD_ROW_TILE + 1, ROWS], ids=["no-token", "tile+1", "every-token"]
+)
+def test_bfloat16_rows_agree_too(n_held):
+    """The cells compute in bfloat16: the same rows, the same roundings,
+    with no token holding a row, with some, and with every one."""
     cfg = layer_config("gated", dtype=jnp.bfloat16)
     params, x = inputs(cfg, TOKENS)
-    logits = logits_for(HELD_ROW_TILE + 1, TOKENS)
-    out, _, grads = run(MoEMLP, cfg, params, x, logits)
+    logits = logits_for(n_held, TOKENS)
+    out, sown, grads = run(MoEMLP, cfg, params, x, logits)
     want_out, _, want_grads = run(WholeBufferMoE, cfg, params, x, logits)
     assert_same((out, grads), (want_out, want_grads))
+    assert sown["moe_held_token_run_share"] == token_run_share(n_held, TOKENS)
+
+
+def test_a_token_that_holds_no_row_gets_exact_zeros():
+    """Tokens past the first ``n_held`` hold no row: their outputs are
+    zeros to the bit (no shared expert here), and every token before them
+    got something."""
+    cfg = layer_config("gated", shared_expert_size=0)
+    params, x = inputs(cfg, TOKENS)
+    n_held = HELD_ROW_TILE + 1
+    out, _, _ = run(MoEMLP, cfg, params, x, logits_for(n_held, TOKENS))
+    assert not np.asarray(out)[0, n_held:].any()
+    assert np.asarray(out)[0, :n_held].any(axis=-1).all()
 
 
 # -- the lowered layer does not grow with the number of tiles -----------------
@@ -247,9 +299,9 @@ def lowered_layer(cfg, tokens: int) -> str:
     )
 
     def scalar(params, x):
-        return jnp.sum(MoEMLP(cfg).apply({"params": params}, x))
+        return jnp.sum(jnp.square(MoEMLP(cfg).apply({"params": params}, x)))
 
-    traced = jax.jit(jax.grad(scalar, argnums=(0, 1))).trace(params, x)
+    traced = jax.jit(jax.value_and_grad(scalar, argnums=(0, 1))).trace(params, x)
     return traced.lower(lowering_platforms=("tpu",)).as_text()
 
 
@@ -266,19 +318,33 @@ def test_the_lowered_layer_does_not_grow_with_the_tile_count(form, matrices):
     shapes' digits only. Three grouped matmuls a matrix (forward, and the
     two of its transpose), each over all R rows; one loop each for the
     rows in, the activation, its gradient, the combine's transpose and,
-    where two matrices read the rows, the sum of their two gradients."""
+    where two matrices read the rows, the sum of their two gradients; and
+    one each for the token side's two passes, the combine and the
+    transpose of the rows in, each with ONE gather of T rows by H after
+    it. No scatter moves a row (the one there is puts the K gates'
+    gradients among the router's E scores), and no tensor is [T, K, H]."""
     cfg = layer_config(form, shared_expert_size=0)
-    texts = {}
-    for tiles in (2, 32):
-        tokens = tiles * TOKENS // 2
+    texts, tokens_at = {}, {tiles: tiles * TOKENS // 2 for tiles in (2, 32)}
+    for tiles, tokens in tokens_at.items():
         assert held_buffer_rows(cfg, tokens) == tiles * HELD_ROW_TILE
+        assert tokens == tiles * llama._held_tile(tokens)
         texts[tiles] = lowered_layer(cfg, tokens)
     counts = op_counts(texts[32])
     assert counts == op_counts(texts[2])
     assert counts["ragged_dot"] == 3 * matrices
-    assert counts["while"] == matrices + 2
+    assert counts["while"] == matrices + 2 + 2
     assert counts["case"] == counts["if"] == 0
     rows = 32 * HELD_ROW_TILE
     assert len(re.findall(rf'"chlo.ragged_dot".*\(tensor<{rows}x', texts[32])) == 3 * matrices
     assert texts[2].count("\n") == texts[32].count("\n")
     assert abs(len(texts[32]) - len(texts[2])) <= 0.03 * len(texts[2])
+    H = cfg.hidden_size
+    for tiles, text in texts.items():
+        tokens = tokens_at[tiles]
+        gathers = re.findall(r'"stablehlo\.gather".*-> (tensor<[^>]*>)', text)
+        assert len(gathers) == counts["gather"]
+        assert gathers.count(f"tensor<{tokens}x{H}xf32>") == 2  # one a pass
+        assert f"tensor<{tokens}x{K}x{H}x" not in text
+        scattered = re.findall(r'"stablehlo\.scatter"[^\n]*\n(?:[^\n]*\n)*?\s*\}\) : \(([^\n]*)', text)
+        assert len(scattered) == counts["scatter"] == 1
+        assert f"x{H}x" not in scattered[0] and f"x{E}xf32>" in scattered[0]

@@ -243,7 +243,8 @@ def test_the_step_moves_the_biases_by_its_own_loads_and_reports_them(caplog):
     data = _data(cfg, 2, 64)
     new, metrics = make_train_step(model, mesh, sh, donate=False)(state, data)
     assert set(metrics) == {"loss", "grad_norm", "router_aux", "moe_max_load", "moe_dropped",
-                            "moe_held_share", "moe_held_run_share", "router_bias_abs_max"}
+                            "moe_held_share", "moe_held_run_share",
+                            "moe_held_token_run_share", "router_bias_abs_max"}
     assert float(metrics["router_bias_abs_max"]) == pytest.approx(cfg.router_bias_update_rate)
     assert float(metrics["moe_dropped"]) == 0.0
     assert float(metrics["moe_held_share"]) == pytest.approx(4 / 16, abs=0.06)

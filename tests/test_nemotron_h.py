@@ -223,7 +223,8 @@ def test_the_step_hands_on_the_expert_layers_counters():
             "mask": jnp.ones((2, 64), jnp.int32)}
     _, metrics = make_train_step(model, mesh, sh, donate=False)(state, data)
     assert set(metrics) == {"loss", "grad_norm", "router_aux", "moe_max_load",
-                            "moe_dropped", "moe_held_share", "moe_held_run_share"}
+                            "moe_dropped", "moe_held_share", "moe_held_run_share",
+                            "moe_held_token_run_share"}
     assert float(metrics["moe_held_run_share"]) == 1.0  # a buffer under a tile, run whole
     assert float(metrics["moe_dropped"]) == 0.0
     assert float(metrics["moe_held_share"]) == pytest.approx(4 / 16, abs=0.06)

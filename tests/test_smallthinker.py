@@ -279,6 +279,7 @@ def test_the_step_hands_on_the_bands_and_the_experts_counters(caplog):
     assert "WARNING" not in [r.levelname for r in caplog.records]
     assert set(metrics) == {
         "loss", "grad_norm", "swa_kept_share", "moe_held_share", "moe_held_run_share",
+        "moe_held_token_run_share",
         "moe_dropped", "moe_max_load", "router_aux", "router_z"}
     assert int(new.step) == 1 and np.isfinite(float(metrics["loss"]))
     # four tiles a side, a sweep of two: 7 tiles of 16 x 16 hold the 904 kept entries

@@ -429,7 +429,8 @@ def test_the_step_hands_on_the_mixers_and_the_experts_counters(caplog):
     assert "WARNING" not in [r.levelname for r in caplog.records]
     assert set(metrics) == {
         "loss", "grad_norm", "kda_state_abs_max", "kda_decay_min", "kda_beta_mean",
-        "moe_held_share", "moe_held_run_share", "moe_dropped", "moe_max_load", "router_aux"}
+        "moe_held_share", "moe_held_run_share",
+        "moe_held_token_run_share", "moe_dropped", "moe_max_load", "router_aux"}
     assert int(new.step) == 1 and np.isfinite(float(metrics["loss"]))
     assert 0.0 <= float(metrics["kda_decay_min"]) < 1.0
     assert 0.5 < float(metrics["kda_beta_mean"]) < 1.5  # sigmoid's mean, doubled

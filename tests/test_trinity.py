@@ -353,6 +353,7 @@ def test_the_step_moves_the_bias_against_the_load_and_hands_on_the_counters(capl
     assert "WARNING" not in [r.levelname for r in caplog.records]
     assert set(metrics) == {
         "loss", "grad_norm", "swa_kept_share", "moe_held_share", "moe_held_run_share",
+        "moe_held_token_run_share",
         "moe_dropped", "moe_max_load", "router_aux", "router_bias_abs_max"}
     assert int(new.step) == 1 and np.isfinite(float(metrics["loss"]))
     assert float(metrics["router_bias_abs_max"]) == pytest.approx(1e-3)

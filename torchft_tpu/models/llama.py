@@ -1235,17 +1235,17 @@ _UNFILLED = 0.0
 
 def _filled_tiles(n_tiles, fn, *operands, over=0):
     """``fn`` row tile by row tile over the first ``n_tiles`` (uint32)
-    tiles of ``operands`` (arrays of R rows each): one ``while`` whose
-    trip count the program reads, so the traced text is one body whatever
-    R is. ``fn`` takes the operands' tiles and returns a tuple of arrays
-    of as many rows. Its first ``over`` results are written over the
-    first ``over`` operands (of their shape and type), in place where
-    nothing reads the operand afterwards: their rows of the tiles not run
-    stay what the operand held, which is what a grouped matmul left past
-    its groups. The other results start as buffers of ``_UNFILLED``. A
-    buffer of one tile (``_held_tile``) is run whole, with no loop.
-    Counting in uint32 keeps jax from wrapping every start in the
-    arithmetic of a negative index."""
+    tiles of ``operands`` (arrays of as many rows each: the buffer's R, or
+    the step's T tokens): one ``while`` whose trip count the program reads,
+    so the traced text is one body whatever the length is. ``fn`` takes the
+    operands' tiles and returns a tuple of arrays of as many rows. Its
+    first ``over`` results are written over the first ``over`` operands (of
+    their shape and type), in place where nothing reads the operand
+    afterwards: their rows of the tiles not run stay what the operand held,
+    which is what a grouped matmul left past its groups. The other results
+    start as buffers of ``_UNFILLED``. A length of one tile
+    (``_held_tile``) is run whole, with no loop. Counting in uint32 keeps
+    jax from wrapping every start in the arithmetic of a negative index."""
     rows = operands[0].shape[0]
     tile = _held_tile(rows)
     if tile == rows:
@@ -1280,48 +1280,102 @@ def _filled_tiles(n_tiles, fn, *operands, over=0):
     ))
 
 
+def _take_rows(a, at):
+    """``a[at[:, 0]]`` for a column ``at`` [n, 1] of row numbers (uint32,
+    all of them rows of ``a``): ``lax.gather`` itself, which traces to one
+    instruction where indexing wraps it in the masking of an index out of
+    bounds."""
+    return jax.lax.gather(
+        a, at, slice_sizes=(1,) + a.shape[1:],
+        dimension_numbers=jax.lax.GatherDimensionNumbers(
+            tuple(range(1, a.ndim)), (0,), (0,)
+        ),
+        mode="promise_in_bounds",
+    )
+
+
+@functools.partial(jax.jit, static_argnames="rows")
+def _touched_tokens(slot, rows):
+    """The token side of the held dispatch, from ``slot`` [T, K] (each
+    assignment's row of the ``rows``-row buffer, ``rows`` for one that has
+    none), as columns [T, 1] (``_take_rows``): ``order``, the tokens that
+    hold at least one row first, in ascending order; ``place``, a token's
+    place in that order; ``has``, whether it holds a row; and the number
+    of token tiles that hold all of them (uint32 like ``slot``:
+    ``_filled_tiles``), with the share of T that is. Jitted like
+    ``_gather_sum``: one function of the step's text for the layers of one
+    shape."""
+    tokens = slot.shape[0]
+    has = jnp.any(slot < rows, axis=1, keepdims=True)
+    upto = jnp.cumsum(has, axis=0, dtype=jnp.uint32)
+    order = jnp.argsort(~has, axis=0, stable=True).astype(jnp.uint32)
+    tile = _held_tile(tokens)
+    n_tiles = (upto[-1, 0] + (tile - 1)) // tile
+    share = n_tiles * (tile / tokens) if tile < tokens else jnp.ones(())
+    return (order, jnp.maximum(upto, 1) - 1, has, n_tiles), share
+
+
 @jax.custom_vjp
-def _held_rows(x, token, slot, n_tiles):
+def _held_rows(x, token, slot, n_tiles, touched):
     """Row ``token[r]`` of ``x`` [T, H] for each row r of the first
     ``n_tiles`` tiles of the held dispatch's R-row buffer. ``slot`` [T, K]
     is each assignment's row in the buffer, R for one that has none
-    (uint32, like ``token`` and ``n_tiles``: ``_filled_tiles``). The
-    transpose gathers by ``slot`` from the row gradients and sums a
-    token's K copies: a gather again, where autodiff's own is a
-    scatter-add."""
+    (uint32, like ``token`` and ``n_tiles``: ``_filled_tiles``);
+    ``touched`` is ``_touched_tokens``' of it. The transpose gathers by
+    ``slot`` from the row gradients and sums a token's K copies: a gather
+    again, where autodiff's own is a scatter-add."""
     return _filled_tiles(n_tiles, lambda tok: (x[tok],), token)[0]
 
 
-def _held_rows_fwd(x, token, slot, n_tiles):
-    return _held_rows(x, token, slot, n_tiles), slot
+def _held_rows_fwd(x, token, slot, n_tiles, touched):
+    return _held_rows(x, token, slot, n_tiles, touched), (slot, touched)
 
 
-def _gather_sum(rows, slot, weights=None):
-    """sum_k weights[:, k] * rows[slot[:, k]] in float32, a slot past the
-    rows (R: no row) adding zero; one [T, H] gather a k: the [T, K, H]
-    tensor of all of them at once is K times a layer's activations, most
-    of it zero. A slot past the rows reads the last row and the select
-    drops what it read: only rows that hold an assignment reach the sum.
-    K times in every pass of every layer, so written to trace short: the
-    columns are split once and the gather is ``lax.gather`` itself."""
+@functools.partial(jax.jit, static_argnames="dtype")
+def _gather_sum(rows, slot, weights, touched, dtype):
+    """out[t] = sum_k weights[t, k] * rows[slot[t, k]] (``weights`` None:
+    ones) summed in float32 and cast to ``dtype``, a slot past the rows
+    (R: no row) adding zero. It runs over the tokens that hold a row
+    (``touched``: ``_touched_tokens``), a tile of them at a time: the
+    tile's K slots and weights are read by its tokens, one [tile, H]
+    gather a k (the [T, K, H] tensor of all of them at once is K times a
+    layer's activations, most of it zero), a slot past the rows reading
+    the last row and a select dropping what it read, the sum over k in the
+    order k = 0 .. K-1. A tile's result goes into a compact buffer at the
+    tile's own offset, of which no row of a tile not run is read, and ONE
+    [T, H] gather puts every token's row in its place, a token that holds
+    none getting exact zeros. Three times in every layer and K gathers
+    each time, so written to trace short: jitted, so that the passes and
+    the layers of one shape are one function of the step's text, which
+    they call; the columns split once; the gathers ``lax.gather`` itself."""
+    order, place, has, n_tiles = touched
+    # The loop's operand is the order alone, which the routing gives long
+    # before the rows are there: the compact buffer's fill waits for both
+    # (``_filled_tiles`` ties it to the operand).
+    order, rows = jax.lax.optimization_barrier((order, rows))
     last = rows.shape[0] - 1
     columns = lambda a: jnp.split(a, a.shape[1], axis=1)  # noqa: E731
-    take = functools.partial(
-        jax.lax.gather, rows, slice_sizes=(1, rows.shape[1]),
-        dimension_numbers=jax.lax.GatherDimensionNumbers((1,), (0,), (0,)),
-        mode="promise_in_bounds",
-    )
-    parts = [
-        jnp.where(keep, take(at), 0).astype(jnp.float32)
-        for at, keep in zip(columns(jnp.minimum(slot, last)), columns(slot <= last))
-    ]
-    if weights is not None:
-        parts = [part * w for part, w in zip(parts, columns(weights))]
-    return sum(parts[1:], parts[0])
+
+    def tile(tokens):
+        at = _take_rows(slot, tokens)
+        parts = [
+            jnp.where(keep, _take_rows(rows, k), 0).astype(jnp.float32)
+            for k, keep in zip(columns(jnp.minimum(at, last)), columns(at <= last))
+        ]
+        if weights is not None:
+            parts = [
+                part * w
+                for part, w in zip(parts, columns(_take_rows(weights, tokens)))
+            ]
+        return (sum(parts[1:], parts[0]).astype(dtype),)
+
+    (compact,) = _filled_tiles(n_tiles, tile, order)
+    return jnp.where(has, _take_rows(compact, place), 0)
 
 
-def _held_rows_bwd(slot, g):
-    return _gather_sum(g, slot).astype(g.dtype), None, None, None
+def _held_rows_bwd(res, g):
+    slot, touched = res
+    return _gather_sum(g, slot, None, touched, g.dtype), None, None, None, None
 
 
 _held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
@@ -1381,18 +1435,20 @@ _held_act.defvjp(_held_act_fwd, _held_act_bwd)
 
 
 @jax.custom_vjp
-def _combine_held(ys, gates, slot, rows, valid, n_tiles):
+def _combine_held(ys, gates, slot, rows, valid, n_tiles, touched):
     """out[t] = sum_k gates[t, k] * ys[slot[t, k]] in float32, a row past
-    the buffer (``slot`` = R) counting as zero. ``rows`` [R] is each
-    buffer row's assignment (token * K + k), ``valid`` [R] whether the row
-    holds one. Both transposes are written from the buffer's side, over
-    its first ``n_tiles`` tiles: row gathers and a gather of scalars, no
+    the buffer (``slot`` = R) counting as zero, over the tokens that hold
+    a row (``touched``: ``_gather_sum``). ``rows`` [R] is each buffer
+    row's assignment (token * K + k), ``valid`` [R] whether the row holds
+    one. Both transposes are written from the buffer's side, over its
+    first ``n_tiles`` tiles: row gathers and a gather of scalars, no
     scatter-add."""
-    return _gather_sum(ys, slot, gates)
+    return _gather_sum(ys, slot, gates, touched, jnp.float32)
 
 
-def _combine_held_fwd(ys, gates, slot, rows, valid, n_tiles):
-    return _gather_sum(ys, slot, gates), (ys, gates, slot, rows, valid, n_tiles)
+def _combine_held_fwd(ys, gates, slot, rows, valid, n_tiles, touched):
+    out = _combine_held(ys, gates, slot, rows, valid, n_tiles, touched)
+    return out, (ys, gates, slot, rows, valid, n_tiles)
 
 
 def _combine_held_bwd(res, g):
@@ -1410,7 +1466,7 @@ def _combine_held_bwd(res, g):
 
     d_ys, dots = _filled_tiles(n_tiles, tile, ys, rows, valid, over=1)
     d_gates = jnp.where(slot < dots.shape[0], dots[slot], 0.0)
-    return d_ys, d_gates.astype(gates.dtype), None, None, None, None
+    return d_ys, d_gates.astype(gates.dtype), None, None, None, None, None
 
 
 _combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
@@ -1476,10 +1532,12 @@ class MoEMLP(nn.Module):
     ``moe_max_load`` (largest expert's assignments over the mean),
     ``moe_dropped`` (assignments not computed) and, from a layer that
     holds a share, ``moe_held_share`` (the share of all assignments that
-    landed on it) and ``moe_held_run_share`` (the share of its row buffer
-    in the row tiles its loops ran); where ``router_bias_update_rate`` > 0,
-    ``moe_load`` (the assignments each of the E experts got, a vector). The
-    reference has no MoE/EP anywhere (SURVEY.md §2.3).
+    landed on it), ``moe_held_run_share`` (the share of its row buffer
+    in the row tiles its loops ran) and ``moe_held_token_run_share`` (the
+    share of the step's tokens in the token tiles its token side ran);
+    where ``router_bias_update_rate`` > 0, ``moe_load`` (the assignments
+    each of the E experts got, a vector). The reference has no MoE/EP
+    anywhere (SURVEY.md §2.3).
     """
 
     cfg: LlamaConfig
@@ -1668,8 +1726,12 @@ class MoEMLP(nn.Module):
         transpose) runs over the first ceil(rows filled / tile) row tiles,
         in loops whose trip count is read from the step's own ``n_fit``
         (``_filled_tiles``; the sown ``moe_held_run_share`` is the share of
-        R they ran). The two argsorts still walk the T*K assignments and
-        the gathers that bring a token's K rows back walk T rows."""
+        R they ran). What brings a token's K rows back, forward and as the
+        transpose of the gather into the buffer, runs over the tokens that
+        hold a row, in token tiles, and places the result once
+        (``_gather_sum``; the sown ``moe_held_token_run_share`` is the
+        share of T it ran). The two argsorts still walk the T*K
+        assignments."""
         cfg = self.cfg
         first, count = cfg.experts_held
         E, K, H = cfg.num_experts, cfg.num_experts_per_tok, x.shape[-1]
@@ -1713,9 +1775,12 @@ class MoEMLP(nn.Module):
             rows = order[:R].astype(jnp.uint32)
             valid = jnp.arange(R) < n_fit
             slot = jnp.where(inv < n_fit, inv, R).astype(jnp.uint32).reshape(T, K)
+            touched, token_share = _touched_tokens(slot, R)
+            self.sow("intermediates", "moe_held_token_run_share", token_share)
         with jax.named_scope("moe/experts"):
             xs = _held_rows(
-                x.reshape(T, H).astype(cfg.dtype), rows // K, slot, n_tiles
+                x.reshape(T, H).astype(cfg.dtype), rows // K, slot, n_tiles,
+                touched,
             )
             gmm = lambda a, w: jax.lax.ragged_dot(  # noqa: E731
                 a, w, fit, preferred_element_type=cfg.dtype
@@ -1724,7 +1789,7 @@ class MoEMLP(nn.Module):
             hidden = [gmm(a, w) for a, w in zip(reads, weights[:-1])]  # [R,I] each
             ys = gmm(_held_act(cfg.expert_act, n_tiles, *hidden), weights[-1])
             out = _combine_held(
-                ys, gate_vals.reshape(T, K), slot, rows, valid, n_tiles
+                ys, gate_vals.reshape(T, K), slot, rows, valid, n_tiles, touched
             )
         return out.reshape(x.shape).astype(x.dtype)
 

@@ -205,6 +205,10 @@ _SOWN_OVER_LAYERS = (
     # Of the held dispatch's row buffer, the share in the row tiles its
     # loops ran (``MoEMLP._sorted_held``): 1 where the buffer is one tile.
     ("moe_held_run_share", jnp.mean),
+    # Of the step's tokens, the share in the token tiles that the same
+    # dispatch's token side ran (``llama._gather_sum``): 1 where the step
+    # is one tile.
+    ("moe_held_token_run_share", jnp.mean),
     # From the attention under the block-diffusion mask: of the score
     # entries it computes, the share its mask keeps
     # (``block_diffusion_attention``).
