@@ -2,12 +2,14 @@
 on the CPU, every family against plain attention under its mask, values and
 gradients: the causal family and the ring's offset block, the chooser of
 tiles, block diffusion against the reference's mask, latent attention at
-unlike widths, the band; and, for every family value, that the q-sweep and
-the kv-sweep run exactly the tile pairs in which the mask keeps an entry.
+unlike widths, the band; and, for every family value, that the sweep runs
+exactly the tile pairs in which the mask keeps an entry, once each, inside
+the brackets of the kv heads the backward holds.
 The kernels compiled for a described chip are in tests/test_tpu_compile.py;
 the models that call them have their own files."""
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -599,12 +601,17 @@ def _kept_pairs(family, keeps):
     }
 
 
-def _swept_pairs(family, scalars=()):
-    """The pairs the q-sweep runs and the pairs the kv-sweeps run, walked
-    step by step as the grids walk them. No sweep runs a pair twice; a step
-    that runs fetches its own tile; and where no scalar decides the skip, a
-    step that does not run names a tile its sweep fetches anyway."""
-    q_side, kv_side = [], []
+def _swept_pairs(family, scalars=(), heads=4):
+    """The pairs the sweep runs (forward and the one backward), walked step
+    by step as the grid walks a batch row: q head, q tile, step. The sweep
+    runs no pair twice; a step that runs fetches its own tile; where no
+    scalar decides the skip, a step that does not run names a tile its sweep
+    fetches anyway; and for kv heads that one, two and all ``heads`` q heads
+    add to, a head's bracket (``_bracket``: what zeroes and flushes the
+    backward's resident dk and dv) opens once before and closes once after
+    every step that adds to the head, brackets of one part never overlap,
+    and every q head of the group adds the same pairs."""
+    pairs = []
     for iq in range(family.nq):
         span = family.q_span(iq)
         steps = [(*family.q_sweep(span, j, *scalars), int(family.q_fetch(span, j)))
@@ -613,19 +620,34 @@ def _swept_pairs(family, scalars=()):
         assert all(ikv == fetch for ikv, fetch in ran)
         if not scalars:
             assert {fetch for _, _, fetch in steps} <= {ikv for ikv, _ in ran}
-        q_side += [(iq, ikv) for ikv, _ in ran]
-    for sweep in family.kv_sweeps:
-        for ik in range(sweep.tiles):
-            span = family.kv_span(sweep, ik)
-            steps = [(*family.kv_sweep(span, s, *scalars), int(family.kv_fetch(span, s)))
-                     for s in range(sweep.steps)]
-            ran = [(int(iq), fetch) for iq, run, fetch in steps if run]
-            assert all(iq == fetch for iq, fetch in ran)
-            if not scalars:
-                assert {fetch for _, _, fetch in steps} <= {iq for iq, _ in ran}
-            kv_side += [(iq, sweep.first + ik) for iq, _ in ran]
-    assert len(set(q_side)) == len(q_side) and len(set(kv_side)) == len(kv_side)
-    return set(q_side), set(kv_side)
+        pairs += [(iq, ikv) for ikv, _ in ran]
+    assert len(set(pairs)) == len(pairs)
+    for group in (1, 2, heads):
+        open_head, added, closed = None, {}, []
+        for h in range(heads):
+            for iq in range(family.nq):
+                span = family.q_span(iq)
+                for j in range(family.q_steps):
+                    opens, closes = fa._bracket(family, group, h, iq, j)
+                    if opens:
+                        assert open_head is None
+                        open_head = h // group
+                        added[open_head] = []
+                    ikv, run = family.q_sweep(span, j, *scalars)
+                    if run:
+                        assert open_head == h // group
+                        added[open_head].append((h, iq, int(ikv)))
+                    if closes:
+                        assert open_head == h // group
+                        closed.append(open_head)
+                        open_head = None
+        assert open_head is None and closed == list(range(heads // group))
+        for head, steps in added.items():
+            assert steps == [
+                (h, iq, ikv) for h in range(head * group, (head + 1) * group)
+                for iq, ikv in pairs
+            ]
+    return set(pairs)
 
 
 def _assert_the_mask_is_the_dense_one(family, keeps, pairs, scalars=()):
@@ -670,14 +692,16 @@ FAMILY_CASES = [
 @pytest.mark.parametrize(
     "family,keeps,scalars", FAMILY_CASES, ids=[f"{f}{s or ''}" for f, _, s in FAMILY_CASES]
 )
-def test_both_sweeps_run_the_tile_pairs_the_mask_keeps_and_no_others(family, keeps, scalars):
+def test_the_sweep_runs_the_tile_pairs_the_mask_keeps_once_each_inside_a_kv_heads_bracket(
+    family, keeps, scalars
+):
     """What a family value answers is consistent with itself: the pairs a
-    q tile's sweep runs (forward and dq), the pairs a kv tile's sweep runs
-    (dkv) and the pairs in which the dense mask keeps an entry are one set,
-    and on each of them the mask closure is the dense mask's tile."""
+    q tile's sweep runs (forward and backward) and the pairs in which the
+    dense mask keeps an entry are one set, each run exactly once and inside
+    its kv head's bracket, and on each of them the mask closure is the dense
+    mask's tile."""
     kept = _kept_pairs(family, keeps)
-    q_side, kv_side = _swept_pairs(family, scalars)
-    assert q_side == kv_side == kept
+    assert _swept_pairs(family, scalars) == kept
     _assert_the_mask_is_the_dense_one(family, keeps, kept, scalars)
 
 
@@ -685,14 +709,14 @@ def test_block_diffusion_at_one_block_a_tile_runs_the_diagonal_tiles_it_empties(
     """The one place a sweep runs more than the mask keeps: where a tile is
     ONE block (no cell's: ``sdar-raw`` has 256 blocks a tile), noisy tile i
     on clean tile i holds only the own block, which a noisy row sees among
-    the noisy keys and not the clean ones. Both sweeps still run those n
+    the noisy keys and not the clean ones. The sweep still runs those n
     pairs (as ``block_diffusion_tiles`` counts them) and the mask empties
     them: nothing wrong comes out, n tiles of work do."""
     family = fa._BlockDiffusion(256, 256, 32, 32, 32)
     kept = _kept_pairs(family, _two_streams(128, 32))
     emptied = {(i, family.n + i) for i in range(family.n)}
     assert not emptied & kept
-    assert _swept_pairs(family) == (kept | emptied,) * 2
+    assert _swept_pairs(family) == kept | emptied
     assert len(kept | emptied) * 32 * 32 == block_diffusion_tiles(128, 32, 32)[1]
     for iq, ik in emptied:
         masked = family.mask(jnp.int32(iq), jnp.int32(ik))(jnp.zeros((32, 32)))
@@ -717,20 +741,18 @@ def test_the_grids_run_the_tiles_the_band_touches_and_no_others(seq, window, bq,
 
     pairs = {(iq, ik) for iq in range(nq) for ik in range(nk) if touched(iq, ik)}
     family = fa._Window(seq, seq, bq, bk, w)
-    assert _swept_pairs(family) == (pairs, pairs)
-    kv_sweeps, q_sweeps = fa._band_sweeps(seq, w, bq, bk)
-    assert kv_sweeps == [sum((iq, ik) in pairs for ik in range(nk)) for iq in range(nq)]
-    assert q_sweeps == [sum((iq, ik) in pairs for iq in range(nq)) for ik in range(nk)]
-    assert sum(kv_sweeps) == sum(q_sweeps) == len(pairs)
-    assert (family.q_steps, family.kv_sweeps) == (max(kv_sweeps), ((0, nk, max(q_sweeps)),))
+    assert _swept_pairs(family) == pairs
+    sweeps = fa._band_sweeps(seq, w, bq, bk)
+    assert sweeps == [sum((iq, ik) in pairs for ik in range(nk)) for iq in range(nq)]
+    assert sum(sweeps) == len(pairs) and family.q_steps == max(sweeps)
     if bq == bk:
-        assert max(kv_sweeps) == max(q_sweeps) == min(-(-(w - 1) // bq) + 1, nq)
+        assert max(sweeps) == min(-(-(w - 1) // bq) + 1, nq)
     # ``window_tiles`` takes bounds: a band under four of a measured-good
     # tile wide takes the next one down (the last case: 512 under 1,024)
     kept, run = fa.window_tiles(seq, window, bq, bk)
     cq, ck = fa.choose_tiles("window", seq, (), bq, bk, window=window)
     assert (cq, ck) == ((512, 512) if window == 1000 else (bq, bk))
-    assert run == sum(fa._band_sweeps(seq, w, cq, ck)[0]) * cq * ck
+    assert run == sum(fa._band_sweeps(seq, w, cq, ck)) * cq * ck
     assert (cq, ck) != (bq, bk) or run == len(pairs) * bq * bk
     assert kept == sum(min(i + 1, w) for i in range(seq))
     assert kept == flops.window_kept_entries(dict(PUBLISHED, sliding_window_size=window), seq)
@@ -760,9 +782,9 @@ def test_a_narrow_band_takes_the_next_tile_down(seq, window, bounds, tiles):
     assert fa.choose_tiles("window", seq, (128,), *bounds) == fa.choose_tiles(
         "causal", seq, (128,), *bounds)
     assert fa.supports_window(seq, window, *bounds)
-    sweep = max(fa._band_sweeps(seq, min(window, seq), *tiles)[0])
+    sweep = max(fa._band_sweeps(seq, min(window, seq), *tiles))
     assert fa.window_tiles(seq, window, *bounds)[1] == sum(
-        fa._band_sweeps(seq, min(window, seq), *tiles)[0]) * tiles[0] * tiles[1]
+        fa._band_sweeps(seq, min(window, seq), *tiles)) * tiles[0] * tiles[1]
     assert sweep >= 1
 
 
@@ -770,7 +792,7 @@ def test_the_cells_schedule_is_seventy_tiles_of_the_causal_136():
     kept, run = fa.window_tiles(16384, 4096)
     assert (kept, run) == (58_722_304, 70 * 1024 * 1024)
     assert kept / run == pytest.approx(0.800, abs=5e-4)
-    assert sum(fa._band_sweeps(16384, 16384, 1024, 1024)[0]) == 136
+    assert sum(fa._band_sweeps(16384, 16384, 1024, 1024)) == 136
     assert fa.window_tiles(16384, 4096, 512, 512)[1] == 252 * 512 * 512
     assert fa.supports_window(16384, 4096) and fa.supports_window(1024, 4096)
     assert not fa.supports_window(16384 + 8, 4096) and not fa.supports_window(16384, 0)
@@ -791,3 +813,196 @@ def test_the_other_families_programs_are_what_they_were():
     banded = str(jax.make_jaxpr(
         lambda *a: fa.flash_attention_window(*a, window=40, block_q=32, block_k=32))(q, k, v))
     assert "grid=(2, 4, 4, 3)" in banded and "flash_attention_window" in banded
+
+
+# -- the one backward: a call a family, the residents, the rule of what fits ---------
+
+
+def _latent_args(seq=128, heads=4, widths=(32, 16, 32), batch=2, seed=3):
+    dn, dr, dv = widths
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q_nope, k_nope = (jax.random.normal(k, (batch, seq, heads, dn)) for k in keys[:2])
+    q_rope = jax.random.normal(keys[2], (batch, seq, heads, dr))
+    k_rope = jax.random.normal(keys[3], (batch, seq, dr))
+    v, w = (jax.random.normal(k, (batch, seq, heads, dv)) for k in keys[4:])
+    return (q_nope, q_rope, k_nope, k_rope, v), w
+
+
+# (entry under tiles of 32, its arguments): every family, the causal one over
+# tensors of one part and of two.
+def _entries():
+    q, k, v, _ = _qkv(128, hq=4, hkv=2)
+    tiles = dict(block_q=32, block_k=32)
+    return {
+        "causal": (functools.partial(fa.flash_attention, **tiles), (q, k, v)),
+        "offset": (lambda *a: fa.flash_attention_block(*a, 40, 8, **tiles)[0], (q, k, v)),
+        "offset_lse": (lambda *a: fa.flash_attention_block(*a, 40, 8, **tiles)[1], (q, k, v)),
+        "block_diffusion": (
+            functools.partial(fa.flash_attention_block_diffusion, block_length=4, block=32),
+            (q, k, v)),
+        "window": (functools.partial(fa.flash_attention_window, window=40, **tiles), (q, k, v)),
+        "mla": (functools.partial(fa.flash_attention_mla, **tiles), _latent_args()[0]),
+    }
+
+
+@pytest.mark.parametrize(
+    "entry", ["causal", "offset", "offset_lse", "block_diffusion", "window", "mla"]
+)
+def test_the_backward_of_every_family_is_one_call(entry):
+    """The jaxpr of a gradient holds two ``pallas_call``s, the forward that
+    keeps its residuals and ONE backward that gives dq, dk and dv (the
+    parent made a dq call and a dkv call a run of kv tiles: three, and four
+    under block diffusion), on the forward's grid."""
+    fn, args = _entries()[entry]
+    grad = jax.grad(lambda *a: jnp.sum(fn(*a)), argnums=tuple(range(len(args))))
+    text = str(jax.make_jaxpr(grad)(*args))
+    assert text.count("pallas_call[") == 2, text.count("pallas_call[")
+    forward = str(jax.make_jaxpr(fn)(*args))
+    assert forward.count("pallas_call[") == 1
+    grids = set(re.findall(r"grid=\([0-9, ]*\)", text))
+    assert len(grids) == 1 and grids == set(re.findall(r"grid=\([0-9, ]*\)", forward))
+
+
+@pytest.mark.parametrize("group", [4, 7])
+@pytest.mark.parametrize("family", ["causal", "window", "block_diffusion"])
+def test_a_groups_dk_and_dv_are_summed_over_its_q_heads_in_the_residents(family, group):
+    """Two kv heads of ``group`` q heads each, four tiles a sequence: dk and
+    dv against dense attention, where a kv head's gradient is the sum over
+    its group (the resident accumulators take it head after head), and
+    against the per-head gradients summed outside."""
+    seq, hkv, d = 128, 2, 16
+    q, k, v, w = _qkv(seq, hq=hkv * group, hkv=hkv, d=d, batch=1, seed=group)
+    if family == "causal":
+        flash = functools.partial(fa.flash_attention, block_q=32, block_k=32)
+        mask = None
+    elif family == "window":
+        flash = functools.partial(fa.flash_attention_window, window=48, block_q=32, block_k=32)
+        mask = window_mask(seq, 48)
+    else:
+        flash = functools.partial(fa.flash_attention_block_diffusion, block_length=4, block=32)
+        mask = block_diffusion_mask(seq // 2, 4)
+    dense = functools.partial(dense_attention, mask=mask)  # None: causal
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * w), (1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), (1, 2))(q, k, v)
+    # every q head on a kv head of its own, then summed a group
+    alone = jax.grad(lambda q, k, v: jnp.sum(dense(q, k, v) * w), (1, 2))(
+        q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2))
+    for name, a, b, c in zip(("dk", "dv"), got, want, alone):
+        assert a.shape == (1, seq, hkv, d)
+        assert jnp.allclose(a, b, atol=5e-5), (name, float(jnp.abs(a - b).max()))
+        summed = c.reshape(1, seq, hkv, group, d).sum(axis=3)
+        assert jnp.allclose(a, summed, atol=5e-5), name
+
+
+@pytest.mark.parametrize("blocks", [(32, 32), (64, 32), (32, 64)])
+def test_the_shared_rotary_keys_gradient_is_the_sum_over_four_heads(blocks):
+    """The latent family's one rotary key a position: its bracket is a whole
+    batch row (every head adds into one resident), the rope-free key's and
+    the values' a head. Over four tiles a head, equal and unequal."""
+    args, w = _latent_args()
+    heads, seq, dr = 4, 128, 16
+    flash = functools.partial(flash_attention_mla, block_q=blocks[0], block_k=blocks[1])
+    grads = lambda f, *a: jax.grad(  # noqa: E731
+        lambda *b: (f(*b) * w).sum(), argnums=(0, 1, 2, 3, 4))(*a)
+    got = grads(flash, *args)
+    a_head = jnp.broadcast_to(args[3][:, :, None], (2, seq, heads, dr))
+    per_head = grads(_per_head_dense, args[0], args[1], args[2], a_head, args[4])
+    assert got[3].shape == (2, seq, dr)
+    assert jnp.allclose(got[3], per_head[3].sum(axis=2), atol=1e-4)
+    for h in range(heads):  # no head's own share is the whole
+        assert float(jnp.abs(per_head[3][:, :, h] - got[3]).max()) > 1e-2
+    for g, r in zip(got[:3] + got[4:], per_head[:3] + per_head[4:]):
+        assert jnp.allclose(g, r, atol=1e-4)
+
+
+@pytest.mark.parametrize("bq,bk", [(64, 32), (32, 64), (128, 32), (32, 128)])
+@pytest.mark.parametrize("family", ["causal", "offset", "window"])
+def test_the_backward_at_unequal_tiles_matches_dense(family, bq, bk):
+    """dq, dk and dv where a q tile spans several kv tiles and the other way
+    round: the residents are addressed by the kv tile, dq's accumulator by
+    the q tile."""
+    seq = 128
+    q, k, v, w = _qkv(seq, hq=4, hkv=2, batch=1, seed=bq + bk)
+    tiles = dict(block_q=bq, block_k=bk)
+    if family == "causal":
+        flash, mask = functools.partial(fa.flash_attention, **tiles), None
+    elif family == "offset":  # keys 24 positions behind the queries' start
+        flash = lambda *a: fa.flash_attention_block(*a, 24, 0, **tiles)[0]  # noqa: E731
+        mask = (jnp.arange(seq)[:, None] + 24) >= jnp.arange(seq)[None, :]
+    else:
+        flash = functools.partial(fa.flash_attention_window, window=50, **tiles)
+        mask = window_mask(seq, 50)
+    dense = functools.partial(dense_attention, mask=mask)  # None: causal
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), (0, 1, 2))(q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        assert jnp.allclose(a, b, atol=5e-5), (name, float(jnp.abs(a - b).max()))
+
+
+# (predicate of a key length, the widths the backward holds): head width
+# 128's two residents, the latent cell's three (the rotary 64 a lane tile).
+FITS = {
+    "supports": (lambda n: fa.supports(n), (128, 128)),
+    "supports_window": (lambda n: fa.supports_window(n, 4096), (128, 128)),
+    "supports_mla": (lambda n: fa.supports_mla(n, 128, 64, 128), (128, 64, 128)),
+    "supports_block_diffusion": (
+        lambda n: fa.supports_block_diffusion(n // 2, 4), (128, 128)),
+    "ring": (lambda n: fa.choose_tiles("block", 1024, (128,), kv_len=n) is not None,
+             (128, 128)),
+}
+
+
+@pytest.mark.parametrize("name", list(FITS))
+def test_a_key_length_one_tile_past_what_the_residents_fit_is_refused(name):
+    """One rule for every predicate and entry: the backward's float32
+    residents and the two buffers of their output blocks, at float32
+    outputs, and a step's working set within a v5e core's 128 MiB. The
+    last length of whole 2,048s that fits is taken, the next is not (block
+    diffusion's keys are both streams, so its streams step by 1,024)."""
+    fits, widths = FITS[name]
+    lanes = sum(-(-w // 128) * 128 for w in widths)
+    room = fa._VMEM_BYTES - fa._STEP_VMEM_BYTES
+    last = room // (lanes * 12) // 2048 * 2048
+    assert last >= 16384, last  # every cell's key length is held
+    assert fa._backward_vmem_bytes(last, widths) <= fa._VMEM_BYTES
+    assert fa._backward_vmem_bytes(last + 2048, widths) > fa._VMEM_BYTES
+    assert fits(last) and not fits(last + 2048)
+    assert fits(16384) and not fits(1 << 20)
+    # what the call asks the compiler for is the same sum at its own dtype
+    assert fa._backward_vmem_bytes(16384, (128, 128), 2) == 32 * 2**20 + fa._STEP_VMEM_BYTES
+
+
+def test_an_entry_refuses_a_key_length_the_residents_do_not_fit():
+    q = jax.ShapeDtypeStruct((1, 1 << 17, 2, 128), jnp.bfloat16)
+    with pytest.raises(ValueError, match="dense_attention"):
+        jax.eval_shape(fa.flash_attention, q, q, q)
+
+
+def test_the_hash_tool_finds_the_device_program_in_a_serialized_executable():
+    """tools/flash_program_hash.py: a varint length, then a message whose
+    field 8 holds the program in its field 3, past fields of every other
+    wire type."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(__file__), os.pardir, "tools", "flash_program_hash.py")
+    spec = importlib.util.spec_from_file_location("flash_program_hash", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+
+    def varint(n):
+        out = bytearray()
+        while True:
+            out.append((n & 0x7F) | (0x80 if n > 0x7F else 0))
+            n >>= 7
+            if not n:
+                return bytes(out)
+
+    def delimited(number, payload):
+        return varint(number << 3 | 2) + varint(len(payload)) + payload
+
+    program = bytes(range(256)) * 3
+    inner = varint(1 << 3) + varint(300) + delimited(2, b"name") + delimited(3, program)
+    outer = (varint(5 << 3 | 1) + b"\0" * 8 + varint(6 << 3 | 5) + b"\0" * 4
+             + delimited(7, b"x" * 200) + delimited(8, inner))
+    assert tool.device_program(varint(len(outer)) + outer + b"trailing") == program

@@ -111,16 +111,17 @@ CELL_FLASH_SHAPES = [
 
 @pytest.mark.parametrize("shape", CELL_FLASH_SHAPES, ids=str)
 def test_flash_at_the_cells_shapes_compiles(one_chip, shape):
-    """Forward, dq and dkv at the tiles the kernels choose, 1,024 x 1,024
-    at every cell's length (the lane-wise softmax state at head widths 128
-    and 64): three kernels named for the jit around them, which is how
-    ``flash_ms`` finds them in a trace."""
+    """Forward and the one backward at the tiles the kernels choose, 1,024 x
+    1,024 at every cell's length (the lane-wise softmax state at head widths
+    128 and 64; the backward's residents of a kv head's 8,192 keys): two
+    kernels named for the jit around them, which is how ``flash_ms`` finds
+    them in a trace."""
     from torchft_tpu.ops.flash_attention import choose_tiles
 
     assert choose_tiles("causal", shape[1], shape[-1:]) == (1024, 1024)
     fn = jax.value_and_grad(_flash_loss, argnums=(0, 1, 2))
     calls = _custom_calls(jax.jit(fn).lower(*_qkv(one_chip, *shape)).compile().as_text())
-    assert len(calls) == 3 and all("flash_attention" in c for c in calls), calls
+    assert len(calls) == 2 and all("flash_attention" in c for c in calls), calls
 
 
 def _flash_block(q, k, v, q_offset, k_offset):
@@ -441,14 +442,14 @@ def _entry_instructions(text):
 
 def test_flash_at_head_width_64_compiles_under_the_name_the_metrics_match(one_chip):
     """Half a lane tile a head, four query heads a key/value head: the
-    blocks take the array's own last dimension. Alone the three kernels
+    blocks take the array's own last dimension. Alone the two kernels
     are named for the jit around them; inside a step program they are
     ``flash_attention.N``, which the test below pins."""
     text = jax.jit(
         jax.value_and_grad(_flash_loss, argnums=(0, 1, 2))
     ).lower(*_qkv(one_chip, *LFM2_FLASH_SHAPE)).compile().as_text()
     calls = _custom_calls(text)
-    assert len(calls) == 3 and all("flash_attention" in c for c in calls), calls
+    assert len(calls) == 2 and all("flash_attention" in c for c in calls), calls
     assert any("bf16[2,32,8192,64]" in c for c in calls), calls
 
 
@@ -456,7 +457,7 @@ def test_flash_at_head_width_64_compiles_under_the_name_the_metrics_match(one_ch
 def test_the_lfm2_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
     """The whole fused step of ``lfm2-raw`` at the published widths for a
     described v5e: what the compiler says it needs is under what the
-    allocator gives, the flash kernels (forward, remat's forward, dq, dkv)
+    allocator gives, the flash kernels (forward, remat's forward, backward)
     and the grouped matmuls are in it under the names the metrics match."""
     import re
 
@@ -477,7 +478,7 @@ def test_the_lfm2_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
     text = compiled.as_text()
     calls = _custom_calls(text)
     flash = [c for c in calls if re.search(flash_ms.PATTERN, c)]
-    assert len(flash) == 4 and all("8192,64]" in c for c in flash), flash
+    assert len(flash) == 3 and all("8192,64]" in c for c in flash), flash
     assert sum(c.startswith("ragged-dot") for c in calls) >= 4 * 9
     # What ``short_conv_ms`` names of the four mixers, and nothing else of
     # the step: the float32 B*u of each forward and of remat's forward, and
@@ -611,9 +612,9 @@ BLOCK_DIFFUSION_SHAPES = [
 
 @pytest.mark.parametrize("shape", BLOCK_DIFFUSION_SHAPES)
 def test_flash_block_diffusion_compiles_under_the_name_the_metrics_match(one_chip, shape):
-    """Forward, dq and the two dkv kernels (the noisy stream's kv tiles and
-    the clean one's) at the cell's widths: four kernels, each named for
-    the jit around it, which ``flash_ms``'s pattern finds in a step."""
+    """Forward and the one backward (a kv head's residents hold both
+    streams' keys) at the cell's widths: two kernels, each named for the
+    jit around it, which ``flash_ms``'s pattern finds in a step."""
     from torchft_tpu.ops.flash_attention import choose_tiles, flash_attention_block_diffusion
 
     *qkv_shape, b, tile = shape
@@ -631,7 +632,7 @@ def test_flash_block_diffusion_compiles_under_the_name_the_metrics_match(one_chi
         *_qkv(one_chip, *qkv_shape)
     ).compile().as_text()
     calls = _custom_calls(text)
-    assert len(calls) == 4 and all("flash_attention_block_diffusion" in c for c in calls), calls
+    assert len(calls) == 2 and all("flash_attention_block_diffusion" in c for c in calls), calls
 
 
 # -- the smallthinker-raw cell: the flash kernels under a sliding window -----
@@ -651,8 +652,8 @@ WINDOW_SHAPES = [
 
 @pytest.mark.parametrize("shape", WINDOW_SHAPES)
 def test_flash_window_compiles_under_the_name_the_metrics_match(one_chip, shape):
-    """Forward, dq and dkv at the cell's widths, seven query heads a
-    key/value head: three kernels, each named for the jit around it,
+    """Forward and the one backward at the cell's widths, seven query heads
+    a key/value head: two kernels, each named for the jit around it,
     ``flash_attention_window``, which ``swa_ms`` tells from the causal
     family's by and ``flash_ms`` counts with them; the grids' innermost
     dimension is the band's sweep, not the causal one."""
@@ -672,7 +673,7 @@ def test_flash_window_compiles_under_the_name_the_metrics_match(one_chip, shape)
         *_qkv(one_chip, *qkv_shape)
     ).compile().as_text()
     calls = _custom_calls(text)
-    assert len(calls) == 3 and all("flash_attention_window" in c for c in calls), calls
+    assert len(calls) == 2 and all("flash_attention_window" in c for c in calls), calls
     assert f"f32[{S},{S}]" not in text and f"bf16[{S},{S}]" not in text
 
 
@@ -690,9 +691,9 @@ MLA_SHAPES = [
 
 @pytest.mark.parametrize("shape", MLA_SHAPES)
 def test_flash_mla_compiles_under_the_name_the_metrics_match(one_chip, shape):
-    """Forward, dq and dkv at the cell's widths (a 128-wide and a 64-wide
-    contraction a score, 128-wide values, the rotary key one head in HBM):
-    three kernels, each named for the jit around it, ``flash_attention_mla``;
+    """Forward and the one backward at the cell's widths (a 128-wide and a
+    64-wide contraction a score, 128-wide values, the rotary key one head in
+    HBM, its gradient resident for a batch row): two kernels, each named for the jit around it, ``flash_attention_mla``;
     the shared key and its gradient stay [B,1,S,Dr]."""
     from torchft_tpu.ops.flash_attention import choose_tiles, flash_attention_mla
 
@@ -713,7 +714,7 @@ def test_flash_mla_compiles_under_the_name_the_metrics_match(one_chip, shape):
         *specs
     ).compile().as_text()
     calls = _custom_calls(text)
-    assert len(calls) == 3 and all("flash_attention_mla" in c for c in calls), calls
+    assert len(calls) == 2 and all("flash_attention_mla" in c for c in calls), calls
     assert f"bf16[{B},1,{S},{dr}]" in text and f"bf16[{B},{H},{S},{dr}]" in text
 
 
@@ -725,7 +726,7 @@ def test_the_joyai_cells_step_compiles_with_the_kernels_under_the_names_the_metr
     dense layer and the prediction module for the compile's length (two
     latent attentions, one expert layer, the head and loss twice): inside
     a step program the kernels are ``flash_attention_mla.N`` (forward,
-    remat's forward, dq, dkv a layer), which ``flash_ms`` finds and
+    remat's forward, backward a layer), which ``flash_ms`` finds and
     ``mla_proj_ms`` leaves out; the projections ``mla_proj_ms`` names are
     there under its patterns."""
     import dataclasses
@@ -744,7 +745,7 @@ def test_the_joyai_cells_step_compiles_with_the_kernels_under_the_names_the_metr
     text = prog.lower(*args).compile().as_text()
     calls = _custom_calls(text)
     flash = [c for c in calls if re.search(flash_ms.PATTERN, c)]
-    assert len(flash) == 2 * 4 and all(c.startswith("flash_attention_mla.") for c in flash), flash
+    assert len(flash) == 2 * 3 and all(c.startswith("flash_attention_mla.") for c in flash), flash
     assert sum(c.startswith("ragged-dot") for c in calls) >= 9
     entry = _entry_instructions(text)
     shapes = {"b": 2, "s": 8192, "h": 32, "rq": 1536, "rkv": 512, "dn": 128, "dr": 64, "dv": 128}
@@ -802,7 +803,7 @@ def test_the_olmo_hybrid_cells_step_fits_and_leads_with_the_shapes_the_metrics_m
     text = compiled.as_text()
     calls = _custom_calls(text)
     flash = [c for c in calls if re.search(flash_ms.PATTERN, c)]
-    assert len(flash) == 4 and all("8192,128]" in c for c in flash), flash
+    assert len(flash) == 3 and all("8192,128]" in c for c in flash), flash
     d = GDN_DIMS
     assert gdn_ms.dims({"cell": cell}) == d
     running = [
@@ -887,8 +888,12 @@ def test_the_solar_open2_cells_step_fits_and_leads_with_the_shapes_the_metrics_m
     text = compiled.as_text()
     calls = _custom_calls(text)
     flash = [c for c in calls if re.search(flash_ms.PATTERN, c)]
-    assert len(flash) == 4 and all("8192,128]" in c for c in flash), flash
-    assert any("bf16[2,1,8192,128]" in c for c in flash), flash  # the one key/value head
+    assert len(flash) == 3 and all("8192,128]" in c for c in flash), flash
+    # the one key/value head: the backward's dk and dv, after its dq
+    assert any(
+        "flash_attention" in line and "bf16[2,1,8192,128]" in line.split(" custom-call(")[0]
+        for line in text.splitlines() if " custom-call(" in line
+    )
     gmm = [c for c in calls if re.search(moe_gmm_ms.PATTERN, c)]
     assert len(gmm) >= 4 * 9 and not [c for c in calls if c.startswith("gdn_")], len(gmm)
     d = KDA_DIMS
@@ -942,7 +947,7 @@ def test_the_smallthinker_cells_step_fits_and_holds_no_square_of_the_sequence(
     through two periods of a global and three windowed attentions and eight
     expert layers of 8 held experts): it fits the chip; the six windowed
     layers are banded kernel calls under the name ``swa_ms`` tells from the
-    two global layers' causal ones by (forward, remat's forward, dq and dkv a
+    two global layers' causal ones by (forward, remat's forward and backward a
     layer), all of them among what ``flash_ms`` finds; no tensor of the
     program is a square of the sequence; the grouped matmuls run over the
     49,152-row buffer; and every sub-layer's router logits leave their
@@ -969,7 +974,7 @@ def test_the_smallthinker_cells_step_fits_and_holds_no_square_of_the_sequence(
     calls = _custom_calls(text)
     flash = [c for c in calls if re.search(flash_ms.PATTERN, c)]
     banded = [c for c in flash if re.search(swa_ms.PATTERN, c)]
-    assert len(banded) == 6 * 4 and len(flash) == 8 * 4, (len(banded), len(flash))
+    assert len(banded) == 6 * 3 and len(flash) == 8 * 3, (len(banded), len(flash))
     assert all("16384,128]" in c for c in flash), flash
     assert not re.search(r"\[(?:\d+,)*16384,16384\]", text)
     gmm = [c for c in calls if re.search(moe_gmm_ms.PATTERN, c)]
@@ -986,7 +991,7 @@ def test_the_trinity_cells_step_fits_and_holds_no_square_of_the_sequence(topo, m
     experts and a shared one after each, a norm before and after every
     sub-layer): it fits the chip; the four windowed layers are banded kernel
     calls at the tiles of 512 the band's rule takes for a window of 2,048
-    (forward, remat's forward, dq and dkv a layer), the global layer's the
+    (forward, remat's forward and backward a layer), the global layer's the
     causal family's, all of them among what ``flash_ms`` finds; no tensor of
     the program is a square of the sequence; and the grouped matmuls run
     over the 65,536-row buffer."""
@@ -1015,7 +1020,7 @@ def test_the_trinity_cells_step_fits_and_holds_no_square_of_the_sequence(topo, m
     calls = _custom_calls(text)
     flash = [c for c in calls if re.search(flash_ms.PATTERN, c)]
     banded = [c for c in flash if re.search(swa_ms.PATTERN, c)]
-    assert len(banded) == 4 * 4 and len(flash) == 5 * 4, (len(banded), len(flash))
+    assert len(banded) == 4 * 3 and len(flash) == 5 * 3, (len(banded), len(flash))
     assert all("16384,128]" in c for c in flash), flash
     assert not re.search(r"\[(?:\d+,)*16384,16384\]", text)
     gmm = [c for c in calls if re.search(moe_gmm_ms.PATTERN, c)]

@@ -607,8 +607,8 @@ def test_the_checks_sample_keeps_a_band_five_tiles_wide():
     assert fa.choose_tiles("window", 1024, (128,), 128, 128, window=512) == (128, 128)
     kept, run = fa.window_tiles(1024, 512, 128, 128)
     assert (kept, run) == (fa.window_kept(1024, 512), 30 * 128 * 128)
-    assert max(fa._band_sweeps(1024, 512, 128, 128)[0]) == 5
-    assert max(fa._band_sweeps(16384, 2048, 512, 512)[0]) == 5
+    assert max(fa._band_sweeps(1024, 512, 128, 128)) == 5
+    assert max(fa._band_sweeps(16384, 2048, 512, 512)) == 5
     assert fa.window_tiles(16384, 2048) == (31_458_304, 150 * 512 * 512)
     long = adapter.sample_config(cfg, 16384)
     assert (long.sliding_window, long.flash_block_q, long.flash_min_seq) == (2048, 1024, 2048)

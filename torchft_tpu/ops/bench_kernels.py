@@ -271,9 +271,10 @@ _V5E_BF16_FLOPS = 197e12  # benchmark/peaks.json, "TPU v5 lite"
 
 
 def _sweep_case(family, dims, widths, block_length, tiles, interpret):
-    """(inputs in the kernels' [B,H,S,D] layout, forward, (dq, dkv) given
-    the forward's residuals, forward FLOPs by the benchmark's flops.py
-    convention: 2 FLOP a kept score entry and channel of QK^T and of PV)."""
+    """(inputs in the kernels' [B,H,S,D] layout, forward, the forward that
+    keeps its residuals, the backward given them, forward FLOPs by the
+    benchmark's flops.py convention: 2 FLOP a kept score entry and channel
+    of QK^T and of PV)."""
     import jax
     import jax.numpy as jnp
 
@@ -312,16 +313,17 @@ def _sweep_case(family, dims, widths, block_length, tiles, interpret):
         dq, dk, dv_ = fa._backward_impl(
             value, (), *parts(a), do, lse, fa._row_delta(do, out), None, interpret
         )
-        return (*dq, *dk, dv_)
+        return (*dq, *dk, *dv_)
 
     flops = 2.0 * width * entries * Hq * B
-    return args, fwd, res, bwd, split, flops
+    return args, fwd, res, bwd, flops
 
 
 def tile_sweep(shapes=None, tiles=None, reps: int = 8) -> list:
-    """Forward, forward + backward, and dq and dkv alone (each a
-    ``pallas_call`` of its own: the other is dead code to the compiler),
-    ms a call and share of the v5e's bf16 peak, every family at every
+    """Forward, forward + backward, and the one backward call alone (dq,
+    dk and dv from the forward's residuals), ms a call and share of the
+    v5e's bf16 peak by the architecture's work (the backward's: twice the
+    forward's, nothing recomputed), every family at every
     cell's shape over ``tiles``. The public entries choose their tiles
     (``flash_attention.choose_tiles``), so the sweep drives the
     ``custom_vjp`` functions under them, at the tiles it is asked for, on
@@ -339,7 +341,7 @@ def tile_sweep(shapes=None, tiles=None, reps: int = 8) -> list:
             row = {"family": family, "cell": cell, "shape": list(dims),
                    "widths": list(widths), "tiles": list(tile)}
             try:
-                args, fwd, res, bwd, split, flops = _sweep_case(
+                args, fwd, res, bwd, flops = _sweep_case(
                     family, dims, widths, b, tile, not compiled
                 )
                 n = len(args)
@@ -347,17 +349,15 @@ def tile_sweep(shapes=None, tiles=None, reps: int = 8) -> list:
                 both = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(n))))
                 out, lse = jax.jit(res)(*args)
                 # the output stands in for its cotangent: a time needs no other
-                dq = jax.jit(lambda a, out, lse: bwd(a, out, out, lse)[:split])
-                dkv = jax.jit(lambda a, out, lse: bwd(a, out, out, lse)[split:])
+                back = jax.jit(lambda a, out, lse: bwd(a, out, out, lse))
                 ms = {
                     "fwd": _time_call(jax.jit(fwd), *args, reps=reps),
                     "fwd_bwd": _time_call(both, *args, reps=reps),
-                    "dq": _time_call(dq, args, out, lse, reps=reps),
-                    "dkv": _time_call(dkv, args, out, lse, reps=reps),
+                    "bwd": _time_call(back, args, out, lse, reps=reps),
                 }
                 row["ms"] = {k: round(v, 3) for k, v in ms.items()}
                 if compiled:  # a share of the chip's peak is the chip's to give
-                    work = {"fwd": flops, "fwd_bwd": 3 * flops, "dq": flops, "dkv": flops}
+                    work = {"fwd": flops, "fwd_bwd": 3 * flops, "bwd": 2 * flops}
                     row["roofline_pct"] = {
                         k: round(100 * work[k] / _V5E_BF16_FLOPS / (v / 1e3), 2)
                         for k, v in ms.items()

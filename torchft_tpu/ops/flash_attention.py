@@ -9,22 +9,19 @@ K/V blocks through VMEM with an online softmax so scores never leave
 the chip (reference for the FLOPs budget: SURVEY.md §6; technique:
 Dao et al. 2022, standard TPU formulation as in jax's pallas examples).
 
-One scaffold, five families. The step math (``_fwd_step``,
-``_bwd_dq_step``, ``_bwd_dkv_step``), the three kernel bodies around it
-(``_fwd_kernel``, ``_dq_kernel``, ``_dkv_kernel``: init at a sweep's first
-step, the step under ``pl.when(run)``, finish at its last), the two
-drivers that build block specs, out shapes and scratch (``_forward_impl``,
-``_backward_impl``) and the ``custom_vjp`` (``_flash``) are written once. A
-family is a small frozen value (``_Family``) that answers four questions,
-and is the only thing that answers them:
+One scaffold, five families. The step math (``_fwd_step``, ``_bwd_step``),
+the two kernel bodies around it (``_fwd_kernel``, ``_bwd_kernel``: init at a
+sweep's first step, the step under ``pl.when(run)``, finish at its last),
+the two drivers that build block specs, out shapes and scratch
+(``_forward_impl``, ``_backward_impl``) and the ``custom_vjp`` (``_flash``)
+are written once. A family is a small frozen value (``_Family``) that
+answers three questions, and is the only thing that answers them:
 
-- the q-sweep (forward and dq): a q tile's span (``q_span``: what the
-  steps of its sweep share), then for step ``j`` the kv tile and whether
-  the step runs (``q_sweep``) or the tile the index map names, which
-  repeats a tile the sweep holds where the step does not run, so nothing
-  is fetched for it (``q_fetch``); ``q_steps`` sizes the grid;
-- the kv-sweep (dkv), the mirror: ``kv_span``, ``kv_sweep``, ``kv_fetch``,
-  and ``kv_sweeps``, the runs of kv tiles that are one call each;
+- the sweep (forward and backward alike): a q tile's span (``q_span``: what
+  the steps of its sweep share), then for step ``j`` the kv tile and
+  whether the step runs (``q_sweep``) or the tile the index map names,
+  which repeats a tile the sweep holds where the step does not run, so
+  nothing is fetched for it (``q_fetch``); ``q_steps`` sizes the grid;
 - the mask closure of a (q tile, kv tile) pair (``mask``);
 - its operands: how many SMEM scalars lead them (``scalars``) and whether
   ``lse`` is an output with a cotangent (``lse_cotangent``). A tensor is a
@@ -62,17 +59,46 @@ Layout: model-native [B, S, H, D] in/out (matching
 kv-head mapping into the K/V BlockSpec index maps — no K/V replication
 in HBM or VMEM.
 
-Grid = (B, Hq, q tiles, kv steps), kv innermost: TPU grids execute
-sequentially, so the fp32 accumulator + online-softmax stats live in VMEM
-scratch across the kv sweep and the output block is written once at the
-final kv step. A step whose tile the mask empties is skipped via
-``pl.when`` (no compute), and under the static masks its index map names
-the tile the sweep already holds, so nothing is fetched for it either;
+Grid = (B, Hq, q tiles, kv steps), kv innermost, of the forward and of the
+backward: TPU grids execute sequentially, so the fp32 accumulator +
+online-softmax stats live in VMEM scratch across the kv sweep and the
+output block is written once at the final kv step. A step whose tile the
+mask empties is skipped via ``pl.when`` (no compute), and under the static
+masks its index map names the tile the sweep already holds, so nothing is
+fetched for it either;
 the ring's offset kernels, whose skip is decided by a dynamic scalar,
 still fetch the tile they skip. The forward keeps its softmax state by
 the lane (``_fwd_step``): the row max replicated across 128 lanes, the
 row sum as 128 partial sums reduced once at the sweep's end, so a step
 has one cross-lane reduction (the max) and broadcasts nothing to store.
+
+One backward: every kept (q tile, kv tile) pair is visited once, and the
+step computes S, the mask, P = exp(S - lse), dP = dO V^T and dS once
+(``_bwd_ds``) and adds the pair's three products from them: dq += dS K,
+dv += P^T dO, dk += dS^T Q (five matmuls and one exponential a pair; a dq
+call and a dkv call ran seven and two). dq accumulates with the q tile, as
+the forward's output does. dk's parts and dv are RESIDENT for a kv head:
+float32 scratch of [kv_len, D] a part, zeroed at the first step of the kv
+head's first q head, added to at the step's kv tile, cast and flushed at the
+last step of its last q head into an output block of the whole [kv_len, D],
+whose index is constant over that bracket so it is written once
+(``_bracket``). The q heads of a group are consecutive in Hq, so GQA's sum
+happens in VMEM; a key part that all heads share (the latent family's rotary
+key) is a bracket of a whole batch row. For one kv tile the contributions
+arrive head by head, q tile ascending, and dq's kv tile ascending: the
+order, the operand dtypes and the casts of the dq and dkv kernels this
+replaced, whose dq, dk and dv the interpreter reproduces to the bit.
+
+VMEM: the residents cost ``kv_len`` x (dk's widths + dv's, in whole lane
+tiles) x (4 bytes of accumulator + two buffers of the output block), 32 MiB
+at 16,384 keys of head width 128 in bf16. That is past the compiler's
+default scoped limit (16 MiB) and inside a v5e core's 128 MiB, so the call
+states ``vmem_limit_bytes`` from its own shapes (``_backward_vmem_bytes``),
+and ``choose_tiles``, which every entry and ``supports*`` predicate asks,
+answers None for a key length whose residents would not fit
+(``_residents_fit``: past 37,888 keys at head widths 128 and 64, 24,576 at
+the latent widths). The ring's ``flash_attention_block`` sees one streamed
+block a call, so longer sequences reach the kernel in bounded pieces.
 
 Tiles: no caller names the tile. ``choose_tiles`` takes it from the shape
 a call is given, 1,024 x 1,024 wherever 1,024 divides the sequence (6 to
@@ -94,7 +120,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import NamedTuple, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -172,6 +198,35 @@ def _band_tile(window: int, largest: int) -> int:
     return next((t for t in good if window >= _BAND_TILES * t), good[-1])
 
 
+# The backward holds a kv head's dk parts and dv in VMEM for the head's whole
+# bracket (``_bwd_kernel``), so a key length has a price in VMEM that the
+# tiles do not bound. A v5e core has 128 MiB (``pltpu.get_tpu_info()`` on the
+# chip, PERF.md section 6, PR 68); the compiler's default scoped limit is 16 MiB
+# of it, so the call states its own.
+_VMEM_BYTES = 128 * 2**20
+# What a step works in beside the residents (the streamed blocks, double-
+# buffered, dq's accumulators and the compiler's own scratch): that default,
+# in which the kernels of every tile the chooser takes have always compiled.
+# Compiled for a described v5e at tiles of 1,024 the call needs the residents
+# and 1.7 MiB more in bf16, 11.5 MiB more in float32.
+_STEP_VMEM_BYTES = 16 * 2**20
+
+
+def _backward_vmem_bytes(kv_len: int, widths, itemsize: int = 4) -> int:
+    """VMEM bytes the backward call asks for at ``kv_len`` keys: a float32
+    accumulator and two buffers of the output block (the pipeline's, of
+    ``itemsize`` bytes an entry) for each of dk's parts and dv, ``widths``
+    their channels (whole lane tiles in VMEM), and a step's working set."""
+    lanes = sum(-(-w // _LANES) * _LANES for w in widths)
+    return kv_len * lanes * (4 + 2 * itemsize) + _STEP_VMEM_BYTES
+
+
+def _residents_fit(kv_len: int, widths) -> bool:
+    """The one rule of what key length the backward holds: its VMEM bytes
+    at float32 outputs, the widest the kernels take, within the core's."""
+    return _backward_vmem_bytes(kv_len, widths) <= _VMEM_BYTES
+
+
 def choose_tiles(
     family: str,
     seq_len: int,
@@ -201,6 +256,12 @@ def choose_tiles(
     tile the caller admits (``LlamaConfig.flash_block_q`` /
     ``flash_block_k``), not the tile.
 
+    Length: the backward holds dk's parts and dv of a kv head's every key
+    in VMEM, so a key length whose residents do not fit the core is refused
+    whatever its tiles (``_residents_fit``; ``widths`` the latent family's
+    three, else the one head width for keys and values, 128 where none is
+    given).
+
     Alignment: tiles of whole 16-row groups (fp32 tiles are 8 rows; bf16
     blocks enter VMEM in their own dtype, so the stricter multiple).
     Block diffusion takes one square tile that lies in one stream and cuts
@@ -211,6 +272,10 @@ def choose_tiles(
     a rotary part of half a tile or whole ones; the interpreter takes any.
     """
     compiled = not _interpret()
+    keys = 2 * seq_len if family == "block_diffusion" else kv_len or seq_len
+    held = widths if family == "mla" else 2 * (tuple(widths) or (_LANES,))
+    if not _residents_fit(keys, held):
+        return None
     if family == "block_diffusion":
         if block_length <= 0:
             return None
@@ -335,28 +400,28 @@ def _bwd_ds(q, k, v_ref, do_ref, lse_ref, delta_ref, dlse_ref, scale, mask_fn):
     return p, p * dsum
 
 
-def _bwd_dq_step(q, k, residuals, dq_acc, scale, mask_fn):
-    _, ds = _bwd_ds(q, k, *residuals, scale, mask_fn)
+def _bwd_step(q, k, residuals, dq_acc, dk_acc, dv_acc, keys, scale, mask_fn):
+    """One tile pair of the backward: P and dS once, then the pair's three
+    products from them. dq's accumulators hold the q tile, dk's and dv's a
+    kv head's every key: ``keys`` is the pair's kv tile in them."""
+    p, ds = _bwd_ds(q, k, *residuals, scale, mask_fn)
+    do = residuals[1][0, 0]
+    # dq += dS @ K * scale, a part of the query each
     for k_part, acc in zip(k, dq_acc):
         kk = k_part[0, 0]
         acc[:] = acc[:] + jax.lax.dot_general(
             ds.astype(kk.dtype), kk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
-
-
-def _bwd_dkv_step(q, k, residuals, dk_acc, dv_acc, scale, mask_fn):
-    p, ds = _bwd_ds(q, k, *residuals, scale, mask_fn)
-    qs = [q_part[0, 0] for q_part in q]
-    do = residuals[1][0, 0]
     # dv += P^T @ dO
-    dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
+    dv_acc[keys, :] = dv_acc[keys, :] + jax.lax.dot_general(
         p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
     # dk += dS^T @ Q * scale, a part of the key each
-    for qq, acc in zip(qs, dk_acc):
-        acc[:] = acc[:] + jax.lax.dot_general(
+    for q_part, acc in zip(q, dk_acc):
+        qq = q_part[0, 0]
+        acc[keys, :] = acc[keys, :] + jax.lax.dot_general(
             ds.astype(qq.dtype), qq, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
@@ -368,26 +433,17 @@ def _bwd_dkv_step(q, k, residuals, dk_acc, dv_acc, scale, mask_fn):
 # ---------------------------------------------------------------------------
 
 
-class _Sweep(NamedTuple):
-    """One dkv call's kv tiles: ``tiles`` of them from tile ``first`` on,
-    each swept in ``steps`` steps a q head."""
-    first: int
-    tiles: int
-    steps: int
-
-
 @dataclasses.dataclass(frozen=True)
 class _Family:
-    """What the frame asks of a family (the module's docstring has the four
-    questions). Hashable and of static ints: it is a non-differentiable
-    argument of the ``custom_vjp``. A sweep is asked in two steps: a tile's
-    span (``q_span``, ``kv_span``: what its steps share, by default the
-    tile itself), then step by step the tile the sweep is at and whether
-    it runs (``q_sweep``, ``kv_sweep``) or the tile to fetch (``q_fetch``,
-    ``kv_fetch``). ``scalars`` SMEM scalars lead a call's operands and are
-    handed to ``q_sweep``, ``kv_sweep`` and ``mask`` after the tiles; the
-    index maps do not see them. This base is the full rectangle of tiles,
-    every step run."""
+    """What the frame asks of a family (the module's docstring has the
+    three questions). Hashable and of static ints: it is a
+    non-differentiable argument of the ``custom_vjp``. The sweep is asked in
+    two steps: a q tile's span (``q_span``: what its steps share, by default
+    the tile itself), then step by step the kv tile the sweep is at and
+    whether it runs (``q_sweep``) or the tile to fetch (``q_fetch``).
+    ``scalars`` SMEM scalars lead a call's operands and are handed to
+    ``q_sweep`` and ``mask`` after the tiles; the index maps do not see
+    them. This base is the full rectangle of tiles, every step run."""
     q_len: int
     kv_len: int
     block_q: int
@@ -408,10 +464,6 @@ class _Family:
     def q_steps(self) -> int:
         return self.nk
 
-    @property
-    def kv_sweeps(self) -> tuple:
-        return (_Sweep(0, self.nk, self.nq),)
-
     def q_span(self, iq):
         return iq
 
@@ -420,15 +472,6 @@ class _Family:
 
     def q_fetch(self, span, j):
         return j
-
-    def kv_span(self, sweep, ik):
-        return ik
-
-    def kv_sweep(self, span, s, *scalars):
-        return s, True
-
-    def kv_fetch(self, span, s):
-        return s
 
     def mask(self, iq, ikv, *scalars):
         return lambda s: s
@@ -473,16 +516,6 @@ class _Causal(_Family):
     def q_fetch(self, span, j):
         return j if span[1] is None else jnp.minimum(j, span[1])
 
-    def kv_sweep(self, ik, s):
-        return s, self._runs(s, ik)
-
-    def kv_fetch(self, ik, s):
-        # The first q tile of the sweep is traced here and not in a span: an
-        # index map then computes it after the head's index, as it always has.
-        if not self.causal:
-            return s
-        return jnp.maximum(s, (ik * self.block_k) // self.block_q)
-
     def mask(self, iq, ikv):
         if not self.causal:
             return lambda s: s
@@ -515,9 +548,6 @@ class _Offset(_Family):
     def q_sweep(self, iq, j, qoff, koff):
         return j, self._runs(iq, j, qoff, koff)
 
-    def kv_sweep(self, ik, s, qoff, koff):
-        return s, self._runs(s, ik, qoff, koff)
-
     def mask(self, iq, ikv, qoff, koff):
         q_start, k_start = self._starts(iq, ikv)
 
@@ -539,9 +569,7 @@ class _BlockDiffusion(_Family):
     n(n+1)/2 kept clean-on-clean tiles, as many noisy-on-clean and n
     noisy-on-noisy: n^2 + 2n of 4n^2. No kernel's grid walks the square: a
     q tile sweeps its own kept kv tiles (n + 1 steps at most, as a causal
-    sweep over L would take n), a clean kv tile the q tiles of both streams
-    from its own on, a noisy kv tile its one q tile (two dkv calls, a
-    stream's kv tiles each). A step past the end of a sweep repeats the
+    sweep over L would take n). A step past the end of a sweep repeats the
     sweep's last tile, so nothing is fetched for it. Everything is static:
     no scalar reaches the kernels."""
     b: int = 0
@@ -553,10 +581,6 @@ class _BlockDiffusion(_Family):
     @property
     def q_steps(self) -> int:
         return self.n + 1
-
-    @property
-    def kv_sweeps(self) -> tuple:
-        return (_Sweep(0, self.n, 1), _Sweep(self.n, self.n, 2 * self.n))
 
     def q_sweep(self, iq, j):
         """A noisy tile i starts on the noisy tile i, which holds every
@@ -573,23 +597,6 @@ class _BlockDiffusion(_Family):
 
     def q_fetch(self, iq, j):
         return self.q_sweep(iq, j)[0]
-
-    def kv_span(self, sweep, ik):
-        return sweep, ik
-
-    def kv_sweep(self, span, s):
-        """A noisy kv tile: its one q tile. The clean kv tile ``ik`` of the
-        stream: the noisy q tiles ik..n-1 (steps ik..n-1), then the clean
-        ones (steps n+ik..2n-1); a step before them repeats the first."""
-        sweep, ik = span
-        if sweep.first == 0:
-            return ik, True
-        n = self.n
-        i = s % n
-        return jnp.where(s >= n, n, 0) + jnp.maximum(i, ik), i >= ik
-
-    def kv_fetch(self, span, s):
-        return self.kv_sweep(span, s)[0]
 
     def mask(self, iq, ikv):
         """A row at position p of block first(p)..first(p)+b-1 keeps the
@@ -613,23 +620,22 @@ class _BlockDiffusion(_Family):
         return mask_fn
 
 
-def _band_span(i, a, c, back, ahead, n, lo=jnp.maximum, hi=jnp.minimum):
-    """(first, last) of the ``n`` tiles of ``c`` positions that hold any of
-    the positions i*a - back .. i*a + a - 1 + ahead: the kv tiles of q tile
-    ``i`` (``back`` = window - 1, ``ahead`` = 0) or the q tiles of kv tile
-    ``i`` (the mirror). On traced indices, or on Python ints with
-    ``lo=max, hi=min``."""
-    return lo(i * a - back, 0) // c, hi((i * a + a - 1 + ahead) // c, n - 1)
+def _band_span(i, a, c, back, n, lo=jnp.maximum, hi=jnp.minimum):
+    """(first, last) of the ``n`` kv tiles of ``c`` positions that hold any
+    of the positions i*a - back .. i*a + a - 1, q tile ``i``'s rows and the
+    ``back`` = window - 1 keys behind its first. On traced indices, or on
+    Python ints with ``lo=max, hi=min``."""
+    return lo(i * a - back, 0) // c, hi((i * a + a - 1) // c, n - 1)
 
 
-def _band_sweeps(seq_len: int, window: int, block_q: int, block_k: int):
-    """Per q tile the kv tiles its sweep runs, per kv tile the q tiles
-    (two lists of Python ints): what the grids are sized from."""
-    nq, nk = seq_len // block_q, seq_len // block_k
-    span = lambda *a: _band_span(*a, lo=max, hi=min)  # noqa: E731
-    kv = [span(i, block_q, block_k, window - 1, 0, nk) for i in range(nq)]
-    q = [span(i, block_k, block_q, 0, window - 1, nq) for i in range(nk)]
-    return [b - a + 1 for a, b in kv], [b - a + 1 for a, b in q]
+def _band_sweeps(seq_len: int, window: int, block_q: int, block_k: int) -> list:
+    """Per q tile the kv tiles its sweep runs (Python ints): what the grids
+    are sized from."""
+    spans = [
+        _band_span(i, block_q, block_k, window - 1, seq_len // block_k, lo=max, hi=min)
+        for i in range(seq_len // block_q)
+    ]
+    return [b - a + 1 for a, b in spans]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -639,9 +645,7 @@ class _Window(_Family):
     position itself counted (``window`` keys at most). Static, over one
     sequence. No kernel's grid walks the causal triangle: a q tile sweeps
     the kv tiles from the one that holds its first row's oldest key to its
-    diagonal, a kv tile the q tiles from its diagonal to the one that holds
-    the last row that still sees its last column (the mirrored sweep), and
-    the grid's innermost dimension is the LONGEST such sweep
+    diagonal, and the grid's innermost dimension is the LONGEST such sweep
     (ceil((window - 1) / tile) + 1 steps where the tiles are square). A
     step past the end of a sweep repeats the sweep's last tile, so nothing
     is fetched for it. The first tile of a q tile's sweep can hold rows
@@ -650,19 +654,12 @@ class _Window(_Family):
     tile's first finite max."""
     window: int = 0
 
-    def _longest(self, side: int) -> int:
-        return max(_band_sweeps(self.q_len, self.window, self.block_q, self.block_k)[side])
-
     @property
     def q_steps(self) -> int:
-        return self._longest(0)
-
-    @property
-    def kv_sweeps(self) -> tuple:
-        return (_Sweep(0, self.nk, self._longest(1)),)
+        return max(_band_sweeps(self.q_len, self.window, self.block_q, self.block_k))
 
     def q_span(self, iq):
-        return _band_span(iq, self.block_q, self.block_k, self.window - 1, 0, self.nk)
+        return _band_span(iq, self.block_q, self.block_k, self.window - 1, self.nk)
 
     def q_sweep(self, span, j):
         ik = span[0] + j
@@ -670,16 +667,6 @@ class _Window(_Family):
 
     def q_fetch(self, span, j):
         return jnp.minimum(span[0] + j, span[1])
-
-    def kv_span(self, sweep, ik):
-        return _band_span(ik, self.block_k, self.block_q, 0, self.window - 1, self.nq)
-
-    def kv_sweep(self, span, s):
-        iq = span[0] + s
-        return iq, iq <= span[1]
-
-    def kv_fetch(self, span, s):
-        return jnp.minimum(span[0] + s, span[1])
 
     def mask(self, iq, ikv):
         starts = self._starts(iq, ikv)
@@ -693,15 +680,18 @@ class _Window(_Family):
 
 
 # ---------------------------------------------------------------------------
-# The frame: three kernel bodies (and the one for a key part that all heads
-# share), two drivers ([B,H,S,D] layout) and the custom_vjp.
+# The frame: two kernel bodies, two drivers ([B,H,S,D] layout) and the
+# custom_vjp.
 #
-# Forward and dq: grid = (B, Hq, q tiles, family.q_steps). dkv: grid =
-# (B, Hkv, a sweep's kv tiles, q_per_kv * its steps): everything that
-# accumulates into THIS kv block, the q-head group and each head's sweep
-# over the q tiles, is the single innermost dimension, so the output
-# block's VMEM residency is one consecutive run and the scratch init/flush
-# brackets exactly it. A kernel's refs are its operands in the order the
+# Forward and backward: grid = (B, Hq, q tiles, family.q_steps). The forward's
+# output block and the backward's dq stay with the q tile: scratch zeroed at
+# the sweep's first step, flushed at its last. The backward's dk parts and dv
+# are RESIDENT for a kv head: float32 scratch of [kv_len, D] a part, whose
+# bracket is every grid step of the q heads that read the head (consecutive
+# in Hq, so GQA's sum over the group happens in VMEM; a key part that all
+# heads share is the same with a bracket of a whole batch row), and an output
+# block of the whole [kv_len, D] whose index is constant over the bracket, so
+# it is written once. A kernel's refs are its operands in the order the
 # drivers pass them: the family's SMEM scalars, q's parts, k's parts, v,
 # (backward: do, lse, delta and, where lse has a cotangent, dlse), the
 # outputs, the scratch.
@@ -761,91 +751,66 @@ def _backward_refs(refs, family, parts):
     return [ref[0, 0] for ref in scalars], q, k, (*residuals, None)[:5], rest
 
 
-def _dq_kernel(*refs, family, parts, scale):
-    iq, j = pl.program_id(2), pl.program_id(3)
+def _bracket(family, group, h, iq, j):
+    """(opens, closes): whether grid step (h, iq, j) of a batch row is the
+    first, the last of the bracket of a kv head that ``group`` consecutive
+    q heads add to (all of them: a part every head shares)."""
+    opens = (h % group == 0) & (iq == 0) & (j == 0)
+    closes = (
+        (h % group == group - 1) & (iq == family.nq - 1) & (j == family.q_steps - 1)
+    )
+    return opens, closes
+
+
+def _bwd_kernel(*refs, family, parts, groups, scale):
+    # dq [1,1,block_q,D] a part, dk's parts and dv [1,1,kv_len,D]; VMEM
+    # accumulators of the same shapes in f32. ``groups``: for each of dk's
+    # parts and dv, the consecutive q heads that add to one of its heads.
+    h, iq, j = pl.program_id(1), pl.program_id(2), pl.program_id(3)
     at, q, k, residuals, rest = _backward_refs(refs, family, parts)
-    dq, dq_acc = rest[:parts], rest[parts:]
+    dq, dkv, dq_acc, dkv_acc = _cut(rest, parts, parts + 1, parts)
+    bk = family.block_k
+    tile = lambda i: pl.ds(pl.multiple_of(i * bk, bk), bk)  # noqa: E731
+    # The residents are zeroed and flushed a tile at a time (the whole
+    # [kv_len, D] as one value would be unrolled, thousands of vregs).
+    brackets = [
+        (_bracket(family, group, h, iq, j),
+         [pair for pair, g in zip(zip(dkv, dkv_acc), groups) if g == group])
+        for group in sorted(set(groups))
+    ]
 
     @pl.when(j == 0)
     def _init():
         _zero(*dq_acc)
 
+    for (opens, _), held in brackets:
+        @pl.when(opens)
+        def _open(held=held):
+            @pl.loop(0, family.nk)
+            def _(i):
+                for _, acc in held:
+                    acc[tile(i), :] = jnp.zeros((bk, acc.shape[1]), acc.dtype)
+
     ikv, run = family.q_sweep(family.q_span(iq), j, *at)
 
     @pl.when(run)
     def _step():
-        _bwd_dq_step(q, k, residuals, dq_acc, scale, family.mask(iq, ikv, *at))
+        _bwd_step(
+            q, k, residuals, dq_acc, dkv_acc[:parts], dkv_acc[parts], tile(ikv),
+            scale, family.mask(iq, ikv, *at),
+        )
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _finish():
         _flush(dq, dq_acc)
 
-
-def _dkv_kernel(*refs, family, sweep, parts, scale):
-    ik, inner = pl.program_id(2), pl.program_id(3)
-    s = inner % sweep.steps
-    at, q, k, residuals, rest = _backward_refs(refs, family, parts)
-    outs, accs = rest[:parts + 1], rest[parts + 1:]  # dk's parts, then dv
-
-    @pl.when(inner == 0)
-    def _init():
-        _zero(*accs)
-
-    ikv = sweep.first + ik
-    iq, run = family.kv_sweep(family.kv_span(sweep, ik), s, *at)
-
-    @pl.when(run)
-    def _step():
-        _bwd_dkv_step(
-            q, k, residuals, accs[:parts], accs[parts], scale,
-            family.mask(iq, ikv, *at),
-        )
-
-    @pl.when(inner == pl.num_programs(3) - 1)
-    def _finish():
-        _flush(outs, accs)
-
-
-def _shared_dkv_kernel(*refs, family, sweep, shared, scale):
-    # The dkv body for a key with a part that ALL heads share (the latent
-    # family's rotary key). A body of its own because it has no kv-head
-    # grid axis and two brackets: grid = (B, kv tiles, H * steps), head
-    # after head innermost; a head's own parts (dk_nope, dv) are bracketed
-    # by its own steps (their output blocks move on with the head), the
-    # shared part's gradient by the whole sweep. Folded into ``_dkv_kernel``
-    # it would be a grid of another rank there, chosen by a flag.
-    parts = len(shared) - 1  # ``shared``: of dk's parts and dv, which all heads share
-    ik, inner = pl.program_id(1), pl.program_id(2)
-    s = inner % sweep.steps
-    at, q, k, residuals, rest = _backward_refs(refs, family, parts)
-    outs, accs = rest[:parts + 1], rest[parts + 1:]  # dk's parts, then dv
-    held = [pair for pair, one in zip(zip(outs, accs), shared) if one]
-    own = [pair for pair, one in zip(zip(outs, accs), shared) if not one]
-
-    @pl.when(inner == 0)
-    def _init_shared():
-        _zero(*(acc for _, acc in held))
-
-    @pl.when(s == 0)
-    def _init_head():
-        _zero(*(acc for _, acc in own))
-
-    iq, run = family.kv_sweep(family.kv_span(sweep, ik), s, *at)
-
-    @pl.when(run)
-    def _step():
-        _bwd_dkv_step(
-            q, k, residuals, accs[:parts], accs[parts], scale,
-            family.mask(iq, sweep.first + ik, *at),
-        )
-
-    @pl.when(s == sweep.steps - 1)
-    def _finish_head():
-        _flush(*zip(*own))
-
-    @pl.when(inner == pl.num_programs(2) - 1)
-    def _finish_shared():
-        _flush(*zip(*held))
+    for (_, closes), held in brackets:
+        @pl.when(closes)
+        def _close(held=held):
+            @pl.loop(0, family.nk)
+            def _(i):
+                for out, acc in held:
+                    out[0, 0, tile(i), :] = acc[tile(i), :].astype(out.dtype)
 
 
 def _smem_spec():
@@ -862,13 +827,13 @@ def _shared(k) -> tuple:
     """Which of a key's parts all heads share: one of ONE head beside a
     first part of more (the latent family's rotary key, [B,1,S,Dr]; it
     stays one head in HBM, every head's index map names that head, and its
-    gradient is accumulated over the heads inside the dkv kernel)."""
+    gradient is accumulated over the heads inside the backward kernel)."""
     return tuple(part.shape[1] != k[0].shape[1] for part in k)
 
 
 def _q_sweep_specs(family, q, k, v):
-    """Block specs of the calls whose grid is (B, Hq, q tiles, sweep
-    steps), forward and dq: q's parts, k's parts, v, a q tile's own block
+    """Block specs of the calls, whose grid is (B, Hq, q tiles, sweep
+    steps): q's parts, k's parts, v, a q tile's own block
     of values' width (o, do), its rows' residuals (lse, delta, dlse). GQA
     folds into the index maps: q head h reads kv head h // q_per_kv."""
     bq, bk = family.block_q, family.block_k
@@ -927,134 +892,38 @@ def _forward_impl(family, scalars, q, k, v, interpret):
 
 
 def _backward_impl(family, scalars, q, k, v, do, lse, delta, dlse, interpret):
-    """(dq's parts, dk's parts, dv) given the forward's residuals; ``dlse``
-    is None unless the family's lse carries a cotangent. A run of kv tiles
-    (``family.kv_sweeps``) is a dkv call of its own."""
+    """(dq's parts, dk's parts, dv) given the forward's residuals, in one
+    call; ``dlse`` is None unless the family's lse carries a cotangent."""
     B, Hq = q[0].shape[:2]
     bq = family.block_q
     rows = (lse, delta) if dlse is None else (lse, delta, dlse)
-    operands = (*scalars, *q, *k, v, do, *rows)
     q_specs, kv_specs, o_spec, row_spec = _q_sweep_specs(family, q, k, v)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, family=family, parts=len(q), scale=_scale(q)),
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in q],
+    groups = tuple(Hq // x.shape[1] for x in (*k, v))
+    held = [(family.kv_len, x.shape[-1]) for x in (*k, v)]
+    return _cut(pl.pallas_call(
+        functools.partial(
+            _bwd_kernel, family=family, parts=len(q), groups=groups, scale=_scale(q)
+        ),
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (*q, *k, v)],
         grid=(B, Hq, family.nq, family.q_steps),
         in_specs=[
             *[_smem_spec()] * len(scalars), *q_specs, *kv_specs, o_spec,
             *[row_spec] * len(rows),
         ],
-        out_specs=q_specs,
-        scratch_shapes=[pltpu.VMEM((bq, x.shape[-1]), jnp.float32) for x in q],
+        # dk's parts and dv: the whole of a head, constant over its bracket.
+        out_specs=[*q_specs, *[
+            pl.BlockSpec((1, 1, *shape), lambda b, h, iq, j, g=g: (b, h // g, 0, 0))
+            for shape, g in zip(held, groups)
+        ]],
+        scratch_shapes=[
+            *[pltpu.VMEM((bq, x.shape[-1]), jnp.float32) for x in q],
+            *[pltpu.VMEM(shape, jnp.float32) for shape in held],
+        ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_backward_vmem_bytes(
+            family.kv_len, [d for _, d in held], v.dtype.itemsize
+        )),
         interpret=interpret,
-    )(*operands)
-
-    dkv_call = _shared_dkv_call if any(_shared(k)) else _dkv_call
-    runs = [
-        dkv_call(family, sweep, len(scalars), q, k, v, len(rows), interpret)(*operands)
-        for sweep in family.kv_sweeps
-    ]
-    *dk, dv = runs[0] if len(runs) == 1 else [
-        jnp.concatenate(pieces, axis=2) for pieces in zip(*runs)
-    ]
-    return tuple(dq), tuple(dk), dv
-
-
-def _dkv_call(family, sweep, n_scalars, q, k, v, n_rows, interpret):
-    """The dkv call of one run of kv tiles: dk's parts and dv, [B, Hkv, the
-    run's length, D] each, from ``n_scalars`` SMEM scalars, q, k, v, do and
-    ``n_rows`` per-row residuals."""
-    B, Hq = q[0].shape[:2]
-    Hkv = k[0].shape[1]
-    q_per_kv, steps = Hq // Hkv, sweep.steps
-    bq, bk = family.block_q, family.block_k
-
-    def q_at(hk, ik, inner):
-        # A q tile the mask empties adds nothing: its index repeats a tile
-        # the sweep holds (``kv_fetch``), so nothing is fetched for it.
-        span = family.kv_span(sweep, ik)
-        return hk * q_per_kv + inner // steps, family.kv_fetch(span, inner % steps)
-
-    def q_idx(b, hk, ik, inner):
-        head, iq = q_at(hk, ik, inner)
-        return b, head, iq, 0
-
-    def row_idx(b, hk, ik, inner):
-        head, iq = q_at(hk, ik, inner)
-        return b, head, 0, iq
-
-    return pl.pallas_call(
-        functools.partial(
-            _dkv_kernel, family=family, sweep=sweep, parts=len(k), scale=_scale(q)
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, sweep.tiles * bk, x.shape[-1]), x.dtype)
-            for x in (*k, v)
-        ],
-        grid=(B, Hkv, sweep.tiles, q_per_kv * steps),
-        in_specs=[
-            *[_smem_spec()] * n_scalars,
-            *_blocks(bq, q_idx, q),
-            *_blocks(bk, lambda b, hk, ik, inner: (b, hk, sweep.first + ik, 0), (*k, v)),
-            *_blocks(bq, q_idx, [v]),
-            *[pl.BlockSpec((1, 1, 8, bq), row_idx)] * n_rows,
-        ],
-        out_specs=_blocks(bk, lambda b, hk, ik, inner: (b, hk, ik, 0), (*k, v)),
-        scratch_shapes=[pltpu.VMEM((bk, x.shape[-1]), jnp.float32) for x in (*k, v)],
-        interpret=interpret,
-    )
-
-
-def _shared_dkv_call(family, sweep, n_scalars, q, k, v, n_rows, interpret):
-    """``_dkv_call`` where all heads share a part of the key: that part's
-    gradient [B, 1, the run's length, D], summed over the heads."""
-    B, H = q[0].shape[:2]
-    steps = sweep.steps
-    bq, bk = family.block_q, family.block_k
-    shared = (*_shared(k), False)
-
-    def q_at(ik, inner):
-        span = family.kv_span(sweep, ik)
-        return inner // steps, family.kv_fetch(span, inner % steps)
-
-    def q_idx(b, ik, inner):
-        head, iq = q_at(ik, inner)
-        return b, head, iq, 0
-
-    def row_idx(b, ik, inner):
-        head, iq = q_at(ik, inner)
-        return b, head, 0, iq
-
-    def kv_specs(first):
-        return [
-            pl.BlockSpec(
-                (1, 1, bk, x.shape[-1]),
-                (lambda b, ik, inner: (b, 0, first + ik, 0)) if one
-                else (lambda b, ik, inner: (b, inner // steps, first + ik, 0)),
-            )
-            for x, one in zip((*k, v), shared)
-        ]
-
-    return pl.pallas_call(
-        functools.partial(
-            _shared_dkv_kernel, family=family, sweep=sweep, shared=shared,
-            scale=_scale(q),
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((*x.shape[:2], sweep.tiles * bk, x.shape[-1]), x.dtype)
-            for x in (*k, v)
-        ],
-        grid=(B, sweep.tiles, H * steps),
-        in_specs=[
-            *[_smem_spec()] * n_scalars,
-            *_blocks(bq, q_idx, q),
-            *kv_specs(sweep.first),
-            *_blocks(bq, q_idx, [v]),
-            *[pl.BlockSpec((1, 1, 8, bq), row_idx)] * n_rows,
-        ],
-        out_specs=kv_specs(0),
-        scratch_shapes=[pltpu.VMEM((bk, x.shape[-1]), jnp.float32) for x in (*k, v)],
-        interpret=interpret,
-    )
+    )(*scalars, *q, *k, v, do, *rows), len(q), len(k))
 
 
 def _row_delta(do, out):
@@ -1088,7 +957,7 @@ def _flash_bwd(family, interpret, res, ct):
     # kernels read sublane 0, which is exactly where the slice cotangent
     # lands.
     do, dlse = ct if family.lse_cotangent else (ct, None)
-    dq, dk, dv = _backward_impl(
+    dq, dk, (dv,) = _backward_impl(
         family, scalars, q, k, v, do, lse, _row_delta(do, out),
         None if dlse is None else dlse.astype(jnp.float32), interpret,
     )
@@ -1326,7 +1195,7 @@ def window_tiles(
         raise ValueError(
             f"window_tiles: {seq_len} positions do not tile under ({block_q},{block_k})"
         )
-    sweeps = _band_sweeps(seq_len, min(window, seq_len), *tiles)[0]
+    sweeps = _band_sweeps(seq_len, min(window, seq_len), *tiles)
     return window_kept(seq_len, window), sum(sweeps) * tiles[0] * tiles[1]
 
 
