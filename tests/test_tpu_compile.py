@@ -1026,3 +1026,117 @@ def test_the_trinity_cells_step_fits_and_holds_no_square_of_the_sequence(topo, m
     gmm = [c for c in calls if re.search(moe_gmm_ms.PATTERN, c)]
     rows = [c for c in gmm if "ragged-dot-none" in c]
     assert len(rows) == 4 * 12 and all("[65536," in c or "[16," in c for c in rows), rows
+
+
+# -- the keye-raw cell: a selection that is data -------------------------------
+
+# (B, S, Hq, Hkv, D, largest tile) of the cell's selected attention at the
+# tiles it chooses (1,024: a kv tile is two groups of the packed columns, 512
+# words a row), held to 512 (one group), and of the reference check's sample
+# (``keye_vl2/adapter.py`` ``sample_config``: 1,024 tokens at tiles of 128, a
+# group a tile, 128 words a row).
+SELECTED_SHAPES = [
+    (1, 16384, 32, 4, 128, 1024), (1, 16384, 32, 4, 128, 512), (1, 1024, 32, 4, 128, 128),
+]
+
+
+@pytest.mark.parametrize("shape", SELECTED_SHAPES)
+def test_flash_selected_compiles_under_the_name_the_metrics_match(one_chip, shape):
+    """Forward and the one backward with the packed selection a VMEM block
+    of a q tile's rows and the table of tile pairs in SMEM: two kernels,
+    each named for the jit around it, ``flash_attention_selected``, which
+    ``flash_ms`` counts with the other families'."""
+    import re
+
+    from benchmark.metrics import flash_ms
+    from torchft_tpu.ops.flash_attention import choose_tiles, flash_attention_selected
+    from torchft_tpu.ops.sparse_index import mask_width
+
+    B, S, Hq, Hkv, D, tile = shape
+    tiles = choose_tiles("selected", S, (D,), tile, tile)
+    assert tiles == (tile, tile)
+    words = jax.ShapeDtypeStruct((B, S, mask_width(S)), jnp.int32, sharding=one_chip)
+    runs = jax.ShapeDtypeStruct((B, S // tile, S // tile), jnp.int32, sharding=one_chip)
+
+    def loss(q, k, v, words, runs):
+        out, _ = flash_attention_selected(
+            q, k, v, words, runs, block_q=tile, block_k=tile, interpret=False
+        )
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        *_qkv(one_chip, B, S, Hq, Hkv, D), words, runs
+    ).compile().as_text()
+    calls = _custom_calls(text)
+    assert len(calls) == 2 and all("flash_attention_selected" in c for c in calls), calls
+    assert re.search(flash_ms.PATTERN, "flash_attention_selected.3 bf16[1,32,16384,128]")
+    assert f"f32[{S},{S}]" not in text and f"bf16[{S},{S}]" not in text
+
+
+def test_the_indexer_kernels_compile_under_the_names_the_metrics_match(one_chip):
+    """The score pass, the probabilities' pass (the rows' sums and G) and
+    the score pass's transpose at the cell's shapes: one kernel each, named
+    for the jit around it, ``dsa_index...``, which ``dsa_index_ms`` reads."""
+    import re
+
+    from benchmark.metrics import dsa_index_ms
+    from torchft_tpu.ops import sparse_index as dsa
+
+    B, S, Hq, Hkv, D, J, Di = 1, 16384, 32, 4, 128, 16, 64
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    index = (sd((B, S, J, Di), jnp.bfloat16), sd((B, S, Di), jnp.bfloat16),
+             sd((B, S, J), jnp.float32))
+    rest = (sd((B, S, Hq, D), jnp.bfloat16), sd((B, S, Hkv, D), jnp.bfloat16),
+            sd((B, Hq, S), jnp.float32), sd((B, S, dsa.mask_width(S)), jnp.int32),
+            sd((B, S), jnp.float32))
+    for fn, args in (
+        (dsa.dsa_index_scores, index),
+        (dsa.dsa_index_kl, (*index, *rest)),
+        (lambda *a: dsa.dsa_index_kl(*a, grad=True), (*index, *rest)),
+        (dsa.dsa_index_scores_bwd, (sd((B, S, S), jnp.bfloat16), *index)),
+    ):
+        calls = _custom_calls(jax.jit(fn).lower(*args).compile().as_text())
+        assert len(calls) == 1 and re.search(dsa_index_ms.KERNELS, calls[0]), calls
+
+
+@pytest.mark.timeout(900)
+def test_the_keye_cells_step_fits_and_selects_once_a_layer(topo, monkeypatch):
+    """The fused step of ``keye-raw`` (one sequence of 16,384 tokens through
+    six layers of a selected attention and 16 held experts): it fits the
+    chip; every layer's attention is the selected family's kernels (forward,
+    remat's forward and the backward), all of them among what ``flash_ms``
+    finds; the score pass runs ONCE a layer, so remat's second forward neither
+    scores nor selects and the [S, S] float32 scores are no residual (six
+    such tensors in the program, each the forward's own temporary); the
+    probabilities' pass runs once forward and once backward, the transpose
+    once."""
+    import re
+
+    from benchmark import cells
+    from benchmark.metrics import dsa_index_ms, flash_ms
+    from benchmark.tests.test_v5e_compile import _programs
+    from torchft_tpu.ops import flash_attention, sparse_index
+
+    monkeypatch.setattr(flash_attention, "_interpret", lambda: False)
+    monkeypatch.setattr(sparse_index, "_kernels", lambda seq: seq % sparse_index.CHUNK == 0)
+    cell = cells.load_cell("keye-raw")
+    programs, resident = _programs(cell, topo)
+    prog, args = programs["step"]
+    compiled = prog.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    print(f"keye-raw/step needs {need / 2**30:.2f} GiB, resident {resident / 2**30:.2f}")
+    assert resident == 12 * 659_190_016 + 8  # weights, two moments, two counters
+    assert resident < need < 15.75 * 2**30, need
+    text = compiled.as_text()
+    calls = _custom_calls(text)
+    flash = [c for c in calls if re.search(flash_ms.PATTERN, c)]
+    assert len(flash) == 6 * 3 and all("flash_attention_selected" in c for c in flash), flash
+    index = [c for c in calls if re.search(dsa_index_ms.KERNELS, c)]
+    by_name = {n: sum(c.startswith(n + ".") or c.startswith(n + " ") for c in index)
+               for n in ("dsa_index_scores", "dsa_index_kl", "dsa_index_scores_bwd")}
+    assert by_name == {"dsa_index_scores": 6, "dsa_index_kl": 12, "dsa_index_scores_bwd": 6}, (
+        by_name, index)
+    assert len(re.findall(r"= f32\[1,16384,16384\]", text)) <= 2 * 6
+    assert not re.search(r"f32\[32,(?:\d+,)*16384,16384\]", text)

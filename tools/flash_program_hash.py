@@ -82,6 +82,13 @@ def main(root: str, what: str = "fwd") -> int:
             args = (spec(B, S, Hq, dn), spec(B, S, Hq, dr), spec(B, S, Hq, dn),
                     spec(B, S, dr), spec(B, S, Hq, dv))
             fn = lambda *a: fa.flash_attention_mla(*a, interpret=False)  # noqa: E731
+        elif family == "selected":
+            # The selection's words and table are operands: any values compile alike.
+            bq, bk = fa.choose_tiles("selected", S, widths)
+            ints = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one)  # noqa: E731
+            args = (spec(B, S, Hq, *widths), *[spec(B, S, Hkv, *widths)] * 2,
+                    ints(B, S, fa.mask_width(S)), ints(B, S // bq, S // bk))
+            fn = lambda *a: fa.flash_attention_selected(*a, interpret=False)[0]  # noqa: E731
         else:
             args = (spec(B, S, Hq, *widths), *[spec(B, S, Hkv, *widths)] * 2)
             fn = {
@@ -95,7 +102,8 @@ def main(root: str, what: str = "fwd") -> int:
         if what == "grad":
             fwd = fn
             fn = jax.grad(  # noqa: E731
-                lambda *a: jnp.sum(fwd(*a).astype(jnp.float32)), argnums=tuple(range(len(args)))
+                lambda *a: jnp.sum(fwd(*a).astype(jnp.float32)),
+                argnums=tuple(range(3 if family == "selected" else len(args))),
             )
         compiled = jax.jit(fn).lower(*args).compile()
         program = device_program(compiled.runtime_executable().serialize())

@@ -25,6 +25,17 @@ place.
     python tools/reference_compare.py --workload smallthinker-raw \\
         --seeds 3000000001,2999999877 --query-block 256 --out chiprun_out/compare.jsonl
 
+For a cell whose attention SELECTS its keys (``keye-raw``: the reference
+names its ``selections``) a line also carries ``selection_agreement``: of
+the entries the reference's float32 indexer selected in a layer, the share
+the program's own indexer (bf16 operands, its passes) selected too, the
+least over the layers, and ``selection_agreement_layers``, every layer's in
+the layers' order (lengths whose [S, S] fits; neither at the cell's 16,384).
+``--departure selection_off_by_<n>`` hands the comparison the reference
+attending to a selection whose last n keys a row are exchanged for the next
+n, ``selection_random`` to one that owes the indexer nothing: how far off a
+selection has to be before a tolerance refuses it.
+
 One JSON line a seed; exit code 1 where a tolerance is passed. Through the chip
 tool at the published widths; on the CPU only at a test's size
 (``compare`` is what the tests call).
@@ -43,6 +54,47 @@ from typing import Any, Dict, Iterator, Optional, Sequence
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
+
+
+# The longest sequence whose every layer's [S, S] selection is compared.
+AGREEMENT_MAX_SEQ = 4096
+
+
+def selection_agreement(cell: Any, cfg: Any, mesh: Any, params: Any, sample: Dict) -> Optional[list]:
+    """A share a published layer, in the layers' order: of the entries the
+    reference selected there, the share the program's own indexer (its
+    parameters, its dtype, its passes) selected too. None for an
+    architecture that selects nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    from torchft_tpu.models.llama import Indexer
+    from torchft_tpu.ops import sparse_index as dsa
+    from torchft_tpu.parallel.train import build_model
+
+    seq = sample["inputs"].shape[1]
+    if not hasattr(cell.reference, "selections") or seq > AGREEMENT_MAX_SEQ:
+        return None
+    model = build_model(dataclasses.replace(cfg, remat=False), mesh)
+    _, state = model.apply(
+        {"params": params}, sample["inputs"], mutable=["intermediates"],
+        capture_intermediates=lambda module, _: isinstance(module, Indexer),
+    )
+    # By the layer's NUMBER: a tree's keys come sorted as strings, layers_10
+    # ahead of layers_2, and the reference's come in the layers' order.
+    found = {
+        int(name.split("_")[1]): layer["attn"]["indexer"]["__call__"][0]
+        for name, layer in state["intermediates"].items() if "attn" in layer
+    }
+    want = cell.reference.selections(params, sample, cell.config)
+    assert len(found) == len(want), (sorted(found), len(want))
+    shares = []
+    for number, kept in zip(sorted(found), want):
+        q_index, k_index, weights = found[number]
+        words = dsa.select(dsa.index_scores(q_index, k_index, weights), cfg.sparse_topk, seq, seq)[0]
+        both = jnp.sum(dsa.unpack(words, seq) & kept)
+        shares.append(float(both / jnp.sum(kept)))
+    return shares
 
 
 def comparisons(
@@ -94,6 +146,10 @@ def comparisons(
         sample = {"inputs": toks[:, :-1], "targets": toks[:, 1:],
                   "mask": jnp.ones((1, seq), jnp.int32)}
         params = init(jax.random.PRNGKey(seed), sample["inputs"])
+        agreement = (
+            selection_agreement(cell, cfg, mesh, params, sample)
+            if operand_dtype is None and departure is None else None
+        )
         loss_sys, g_sys = system(params, sample)
         # To the host: the two gradient trees never share the device's memory.
         loss_sys, g_sys = float(loss_sys), jax.tree_util.tree_map(np.asarray, g_sys)
@@ -126,6 +182,8 @@ def comparisons(
             "grad_rel_l2_second": ranked[-2] if len(ranked) > 1 else None,
             "grad_rel_l2_median": ranked[len(ranked) // 2],
             "leaves": len(errs),
+            **({} if agreement is None else {
+                "selection_agreement": min(agreement), "selection_agreement_layers": agreement}),
             "loss_rel_tol": reference.LOSS_REL_TOL,
             "grad_rel_l2_tol": reference.GRAD_REL_L2_TOL,
             "ok": bool(loss_rel <= reference.LOSS_REL_TOL

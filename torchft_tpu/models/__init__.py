@@ -20,6 +20,8 @@ from torchft_tpu.models.llama import (  # noqa: F401
     Transformer,
     joyai_flash_debug,
     joyai_llm_flash,
+    keye_vl2_30b_a3b,
+    keye_vl2_debug,
     llama3_8b,
     llama_debug,
     llama_moe_debug,
@@ -58,5 +60,7 @@ PRESETS = {
     "smallthinker_21b": smallthinker_21b,
     "smallthinker_debug": smallthinker_debug,
     "trinity_mini": trinity_mini,
+    "keye_vl2_30b_a3b": keye_vl2_30b_a3b,
+    "keye_vl2_debug": keye_vl2_debug,
     "trinity_debug": trinity_debug,
 }

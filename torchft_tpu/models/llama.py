@@ -47,11 +47,12 @@ _ATTN_NOTED: set = set()
 
 def _note_attention(
     asked: str, traced: str, seq_len: int, tiles: Optional[tuple] = None,
-    window: Optional[int] = None,
+    window: Optional[int] = None, topk: Optional[int] = None,
 ) -> None:
     """Says once per (asked, traced, seq_len), at trace time, which
     attention implementation a step really took, of a windowed layer its
-    window (``window=4096``) and, of a flash kernel, the tiles it chose
+    window (``window=4096``), of a selected one the keys a query keeps
+    (``topk=2048``) and, of a flash kernel, the tiles it chose
     (``tiles=1024x1024``) — 'flash' routes to dense below ``flash_min_seq``
     or on unsupported tilings, and a chip run must be able to prove which
     branch it compiled."""
@@ -60,8 +61,9 @@ def _note_attention(
         _ATTN_NOTED.add(key)
         logger.log(
             logging.INFO if asked == traced else logging.WARNING,
-            "attention: asked=%s traced=%s seq=%d%s%s", asked, traced, seq_len,
+            "attention: asked=%s traced=%s seq=%d%s%s%s", asked, traced, seq_len,
             " window=%d" % window if window else "",
+            " topk=%d" % topk if topk else "",
             " tiles=%dx%d" % tiles if tiles else "",
         )
 
@@ -145,6 +147,32 @@ class LlamaConfig:
     # with i - j < ``sliding_window``, the position itself counted
     # (SmallThinker's ``sliding_window_size``).
     sliding_window: Optional[int] = None
+    # A learned sparse attention (DeepSeek Sparse Attention, DeepSeek-V3.2,
+    # in its masked training form; Keye-VL-2.0's ``sa_config``): the global
+    # attention kind gains an indexer of ``indexer_heads`` heads of
+    # ``indexer_head_dim`` on ONE shared index key a position, which scores
+    # every earlier key, I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s]);
+    # a query attends to its ``sparse_topk`` best keys (all of them while
+    # it has no more; ties to the lower index); and the indexer learns from
+    # ``indexer_loss_coef`` x the KL of the attention's head-summed
+    # probabilities over that selection against softmax(I) there, a mean
+    # over the rows and the layers (sown as ``dsa_index_kl``). The indexer
+    # reads the layer's normed input detached and nothing else of the loss
+    # reaches it; the selection is not differentiable. None: dense.
+    # ops/sparse_index.py has the passes, ops/flash_attention.py the
+    # kernels' family (``flash_attention_selected``).
+    sparse_topk: Optional[int] = None
+    indexer_heads: int = 0
+    indexer_head_dim: int = 0
+    indexer_loss_coef: float = 1.0
+    # Multimodal rotary positions (Qwen2-VL's M-RoPE, chunked sections): a
+    # token's position is three ids (temporal, height, width), and of the
+    # head's ``head_dim / 2`` frequency pairs the first ``mrope_section[0]``
+    # take the temporal id, the next ``[1]`` the height id, the last ``[2]``
+    # the width id. ``positions`` is then [3, B, S] ([B, S]: all three
+    # equal, which is the plain rotary embedding, bit for bit). An
+    # indexer's rotary takes the temporal id. None: one id a token.
+    mrope_section: Optional[tuple] = None
     # True: the attention's output is gated elementwise before W_o,
     # W_o(Y * sigmoid(x W_gate)), x the layer's (normed) input and W_gate
     # as wide as W_q (arXiv:2505.06708's head-specific elementwise gate;
@@ -846,6 +874,74 @@ def trinity_debug(**overrides: Any) -> LlamaConfig:
     return dataclasses.replace(cfg, **overrides)
 
 
+def keye_vl2_30b_a3b(**overrides: Any) -> LlamaConfig:
+    """Keye-VL-2.0-30B-A3B's language model (Kwai-Keye/Keye-VL-2.0-30B-A3B
+    config.json, model_type ``KeyeVL2``) at its published sizes: 48 layers,
+    each a rotary GQA attention (32 heads on 4 at head width 128, per-head
+    QK norms, theta 1e7, multimodal rotary sections [16, 24, 24]) whose
+    keys a 16-head indexer of width 64 selects, 2,048 a query
+    (``sa_config``: DeepSeek Sparse Attention), and 128 SiLU-gated experts
+    of width 768, 8 a token under a softmax router with renormalised gates;
+    an untied 151,936-row head. The vision tower is no part of it: what it
+    asks of the language model, three-component positions, is. Override
+    ``num_layers``, ``experts_held`` and ``vocab_size`` for what one chip
+    holds. The QK norms, the indexer's LayerNorm, rotary and scale, its
+    loss and both loss coefficients are not in the published file
+    (benchmark/configs/keye-vl2-30b-a3b-l6e16.json, ``assumed``)."""
+    cfg = LlamaConfig(
+        vocab_size=151936,
+        hidden_size=2048,
+        intermediate_size=768,
+        num_layers=48,
+        layer_pattern="*E" * 48,
+        num_heads=32,
+        num_kv_heads=4,
+        head_dim=128,
+        max_seq_len=262144,
+        rope_theta=1e7,
+        mrope_section=(16, 24, 24),
+        norm_eps=1e-6,
+        qk_norm="head",
+        sparse_topk=2048,
+        indexer_heads=16,
+        indexer_head_dim=64,
+        indexer_loss_coef=1.0,
+        num_experts=128,
+        num_experts_per_tok=8,
+        expert_capacity_factor=None,
+        norm_topk_prob=True,
+        router_aux_coef=0.001,
+        router_z_coef=0.0,
+    )
+    return dataclasses.replace(cfg, **overrides)
+
+
+def keye_vl2_debug(**overrides: Any) -> LlamaConfig:
+    """Tiny Keye-VL-2.0 language model (2 layers, a 2-head indexer of width
+    8 that keeps 16 keys a query, sections [2, 3, 3], 16 experts of which
+    4 are held) for tests and ``train_hsdp.py --model keye_vl2_debug``."""
+    cfg = keye_vl2_30b_a3b(
+        vocab_size=256,
+        hidden_size=64,
+        intermediate_size=48,
+        num_layers=2,
+        layer_pattern="*E*E",
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        max_seq_len=128,
+        mrope_section=(2, 3, 3),
+        sparse_topk=16,
+        indexer_heads=2,
+        indexer_head_dim=8,
+        num_experts=16,
+        num_experts_per_tok=3,
+        experts_held=(0, 4),
+        remat=False,
+    )
+    return dataclasses.replace(cfg, **overrides)
+
+
 def llama_moe_debug(**overrides: Any) -> LlamaConfig:
     """Tiny MoE config (4 experts, top-2) for tests and the ep dryrun."""
     cfg = llama_debug(num_experts=4, num_experts_per_tok=2)
@@ -869,13 +965,28 @@ def llama_debug(**overrides: Any) -> LlamaConfig:
 
 
 def rope_table(
-    positions: jax.Array, head_dim: int, theta: float, dtype: Dtype
+    positions: jax.Array, head_dim: int, theta: float, dtype: Dtype,
+    mrope_section: Optional[tuple] = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """(cos, sin) tables of shape [..., head_dim/2] for given positions."""
+    """(cos, sin) tables of shape [..., head_dim/2] for given positions.
+    With ``mrope_section`` (``LlamaConfig.mrope_section``) the positions are
+    [3, B, S] (or [B, S]: the three ids equal) and frequency pair i takes
+    the id of its section: tables [B, S, head_dim/2] still."""
     freqs = 1.0 / (
         theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
     )
-    angles = positions[..., None].astype(jnp.float32) * freqs
+    at = positions[..., None]
+    if mrope_section is not None:
+        if sum(mrope_section) != head_dim // 2 or len(mrope_section) != 3:
+            raise ValueError(
+                f"mrope_section {mrope_section!r}: three sections that add up "
+                f"to the head's {head_dim // 2} frequency pairs"
+            )
+        if positions.ndim == 3:
+            component = [c for c, n in enumerate(mrope_section) for _ in range(n)]
+            # [head_dim/2, B, S] -> [B, S, head_dim/2]: pair i's own id.
+            at = jnp.moveaxis(positions[jnp.asarray(component)], 0, -1)
+    angles = at.astype(jnp.float32) * freqs
     return jnp.cos(angles).astype(dtype), jnp.sin(angles).astype(dtype)
 
 
@@ -993,6 +1104,43 @@ def window_attention(cfg: LlamaConfig, seq_len: int) -> tuple:
     return None, fa.window_kept(seq_len, window) / (seq_len * seq_len)
 
 
+def selected_dense_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, kept: jax.Array
+) -> tuple:
+    """Plain GQA attention under a boolean selection ``kept`` [B,S,S] (True:
+    row t attends to column s): ``(out [B,S,Hq,Dh], lse [B,Hq,S] fp32)``,
+    lse a constant to differentiation. What the selected flash kernels
+    compute, for the sequences they do not take."""
+    b, s, hq, dh = q.shape
+    hkv = k.shape[2]
+    qg = q.reshape(b, s, hkv, hq // hkv, dh)
+    scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k).astype(jnp.float32) * dh**-0.5
+    scores = jnp.where(kept[:, None, None], scores, -jnp.inf)
+    lse = jax.nn.logsumexp(scores, axis=-1)
+    probs = jnp.exp(scores - lse[..., None]).astype(v.dtype)
+    out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v).reshape(b, s, hq, dh)
+    return out, jax.lax.stop_gradient(lse.reshape(b, hq, s))
+
+
+def selected_attention_tiles(cfg: LlamaConfig, seq_len: int) -> Optional[tuple]:
+    """The tiles the selected flash kernels take for ``seq_len`` rows, None
+    where dense attention under the unpacked selection runs."""
+    from torchft_tpu.ops import flash_attention as fa
+
+    if cfg.attn_impl != "flash" or seq_len < cfg.flash_min_seq:
+        return None
+    return fa.choose_tiles(
+        "selected", seq_len, (cfg.head_dim,), cfg.flash_block_q, cfg.flash_block_k
+    )
+
+
+# What a selected attention layer keeps of its forward pass under remat, so
+# that the backward's second forward does not score and select again: the
+# packed selection (32 MiB a layer at 16,384 positions), the table of tile
+# pairs to run and the rows' log-sum-exp of the index scores.
+SELECTION_NAMES = ("dsa_words", "dsa_runs", "dsa_lse_index")
+
+
 class RMSNorm(nn.Module):
     eps: float = 1e-5
     param_dtype: Dtype = jnp.float32
@@ -1010,17 +1158,52 @@ class RMSNorm(nn.Module):
         return (norm * scale).astype(dtype)
 
 
+class Indexer(nn.Module):
+    """A selected attention's indexer (``LlamaConfig.sparse_topk``): from
+    the layer's normed input, DETACHED by the caller, ``indexer_heads``
+    index queries of ``indexer_head_dim`` a position, ONE index key a
+    position under a LayerNorm, both rotated over their whole width at the
+    temporal position, and a float32 weight a position and head, scaled by
+    heads^-1/2 width^-1/2."""
+
+    cfg: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array, cos: jax.Array, sin: jax.Array) -> tuple:
+        cfg = self.cfg
+        heads, width = cfg.indexer_heads, cfg.indexer_head_dim
+        kw = dict(use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype)
+        q = nn.DenseGeneral(features=(heads, width), axis=-1, name="wq_index", **kw)(x)
+        k = nn.Dense(width, name="wk_index", **kw)(x)
+        k = nn.LayerNorm(
+            epsilon=1e-6, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            name="k_index_norm",
+        )(k)
+        weights = nn.Dense(heads, name="w_index", **kw)(x).astype(jnp.float32)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k[:, :, None], cos, sin)[:, :, 0]
+        return q, k, weights * (heads**-0.5 * width**-0.5)
+
+
 class Attention(nn.Module):
-    """GQA attention of one of two kinds: the global one (causal; rotary
-    where ``cfg.rope``) or, ``window=True``, the windowed one (causal
-    within ``cfg.sliding_window`` positions; always rotary)."""
+    """GQA attention of one of three kinds: the global one (causal; rotary
+    where ``cfg.rope``); ``window=True``, the windowed one (causal within
+    ``cfg.sliding_window`` positions; always rotary); and, under
+    ``cfg.sparse_topk``, the global one over the keys its ``Indexer``
+    selects (``cos`` and ``sin`` are then pairs: the heads' tables and the
+    indexer's)."""
 
     cfg: LlamaConfig
     window: bool = False
 
     @nn.compact
-    def __call__(self, x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    def __call__(self, x: jax.Array, cos: Any, sin: Any) -> jax.Array:
         cfg = self.cfg
+        selected = cfg.sparse_topk is not None and not self.window
+        if selected:
+            (cos, cos_index), (sin, sin_index) = cos, sin
+        elif cfg.sparse_topk is not None:
+            cos, sin = cos[0], sin[0]
         dense = lambda heads, name: nn.DenseGeneral(  # noqa: E731
             features=(heads, cfg.head_dim),
             axis=-1,
@@ -1075,6 +1258,8 @@ class Attention(nn.Module):
                     out = dense_attention(
                         q, k, v, mask=window_mask(seq, cfg.sliding_window)
                     )
+        elif selected:
+            out = self._selected(x, q, k, v, cos_index, sin_index)
         elif cfg.objective == "block_diffusion":
             # x holds [x_t | x_0]: two streams, one mask over both.
             if cfg.attn_impl not in ("flash", "dense"):
@@ -1146,6 +1331,79 @@ class Attention(nn.Module):
             param_dtype=cfg.param_dtype,
             name="wo",
         )(out)
+
+    def _selected(self, x, q, k, v, cos_index, sin_index):
+        """The attention over each query's selected keys (the class's third
+        kind): (a) the indexer scores every earlier key, (b) each row's
+        ``sparse_topk`` best are selected and packed, (c) the selected flash
+        family (below ``flash_min_seq`` or where it takes no tiles: dense
+        attention under the same selection) runs over them, (d) the
+        indexer's loss against the attention's own probabilities is sown."""
+        from jax.ad_checkpoint import checkpoint_name
+
+        from torchft_tpu.ops import sparse_index as dsa
+        from torchft_tpu.ops.flash_attention import flash_attention_selected
+
+        cfg = self.cfg
+        if cfg.attn_impl not in ("flash", "dense") or cfg.objective != "next_token":
+            raise ValueError(
+                f"selected attention under attn_impl={cfg.attn_impl!r}, "
+                f"objective={cfg.objective!r}: the selection exists for "
+                "'flash' and 'dense' under 'next_token' (no ring, all-to-all "
+                "or two-stream form of it is built)"
+            )
+        if min(cfg.sparse_topk, cfg.indexer_heads, cfg.indexer_head_dim) < 1:
+            raise ValueError(
+                f"sparse_topk {cfg.sparse_topk} under an indexer of "
+                f"{cfg.indexer_heads} heads of {cfg.indexer_head_dim}"
+            )
+        batch, seq = q.shape[:2]
+        tiles = selected_attention_tiles(cfg, seq)
+        _note_attention(
+            f"{cfg.attn_impl}/selected", f"{'flash' if tiles else 'dense'}/selected",
+            seq, tiles, topk=cfg.sparse_topk,
+        )
+        with jax.named_scope("attention/selected"):
+            detached = jax.lax.stop_gradient(x)
+            q_index, k_index, weights = Indexer(cfg, name="indexer")(
+                detached, cos_index, sin_index
+            )
+            # Constants going in: the selection has no derivative to trace.
+            scores = dsa.index_scores(
+                *map(jax.lax.stop_gradient, (q_index, k_index, weights))
+            )
+            words, runs, lse_index = dsa.select(
+                scores, cfg.sparse_topk, *(tiles or (seq, seq))
+            )
+            words, runs, lse_index = (
+                checkpoint_name(a, name)
+                for a, name in zip((words, runs, lse_index), SELECTION_NAMES)
+            )
+            if tiles is not None:
+                out, lse = flash_attention_selected(
+                    q, k, v, words, runs,
+                    block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
+                )
+            else:
+                out, lse = selected_dense_attention(q, k, v, dsa.unpack(words, seq))
+            loss = dsa.index_kl(
+                q_index, k_index, weights, jax.lax.stop_gradient(q),
+                jax.lax.stop_gradient(k), lse, words, lse_index,
+            )
+            nq, nk = runs.shape[1:]
+            causal = (
+                jnp.arange(nk)[None, :] * (seq // nk)
+                <= jnp.arange(nq)[:, None] * (seq // nq) + seq // nq - 1
+            )
+            self.sow("intermediates", "dsa_index_kl", loss)
+            self.sow("intermediates", "dsa_kept_share", (
+                jnp.sum(jax.lax.population_count(words), dtype=jnp.float32)
+                / (batch * (seq * (seq + 1) // 2))
+            ))
+            self.sow("intermediates", "dsa_tiles_run_share", (
+                jnp.sum(runs * causal, dtype=jnp.float32) / (batch * jnp.sum(causal))
+            ))
+        return out
 
 
 class MLP(nn.Module):
@@ -1995,7 +2253,14 @@ def _stack_layers(cfg: LlamaConfig, pattern: str, x: jax.Array, rotary: tuple,
     # remat'd alone (outside a scan XLA would otherwise merge the
     # recomputation with the forward pass and keep every layer's
     # activations: prevent_cse stays on).
-    layer = nn.remat(MixerLayer) if cfg.remat else MixerLayer
+    layer = MixerLayer
+    if cfg.remat and cfg.sparse_topk is not None:
+        # The selection is kept: the second forward neither scores nor selects.
+        layer = nn.remat(MixerLayer, policy=jax.checkpoint_policies.save_only_these_names(
+            *SELECTION_NAMES
+        ))
+    elif cfg.remat:
+        layer = nn.remat(MixerLayer)
     ahead = None
     for i, kind in enumerate(pattern):
         mod = layer(cfg, kind, name=name(i))
@@ -2067,7 +2332,8 @@ class _ScanBlock(Block):
 
 
 class Transformer(nn.Module):
-    """Decoder-only LM. __call__(tokens [B,S], positions [B,S]) -> logits.
+    """Decoder-only LM. __call__(tokens [B,S], positions [B,S], or
+    [3,B,S] under ``mrope_section``) -> logits.
     Under ``objective="block_diffusion"`` the S tokens are two streams of
     S/2, [x_t | x_0] (``LlamaConfig.objective``)."""
 
@@ -2091,10 +2357,13 @@ class Transformer(nn.Module):
         rolled by one, whose last position reads the first token; the loss
         gives that row no weight)."""
         cfg = self.cfg
-        if (cfg.mla is not None or cfg.mtp_layers) and cfg.layer_pattern is None:
+        if (
+            cfg.mla is not None or cfg.mtp_layers or cfg.sparse_topk is not None
+            or cfg.mrope_section is not None
+        ) and cfg.layer_pattern is None:
             raise ValueError(
-                "latent attention and prediction modules are a "
-                "layer_pattern stack's"
+                "latent attention, prediction modules, a selected attention "
+                "and multimodal rotary positions are a layer_pattern stack's"
             )
         if positions is None:
             if cfg.objective == "block_diffusion":
@@ -2103,6 +2372,10 @@ class Transformer(nn.Module):
             else:
                 at = jnp.arange(tokens.shape[1])
             positions = jnp.broadcast_to(at, tokens.shape)
+        elif positions.ndim == 3 and cfg.mrope_section is None:
+            raise ValueError(
+                "positions of three ids a token need mrope_section"
+            )
         embed = nn.Embed(
             cfg.vocab_size,
             cfg.hidden_size,
@@ -2122,9 +2395,22 @@ class Transformer(nn.Module):
         if cfg.layer_pattern is not None:
             rotary_dim = cfg.head_dim if cfg.mla is None else cfg.mla.qk_rope_head_dim
             rotary = (
-                rope_table(positions, rotary_dim, cfg.rope_theta, cfg.dtype)
+                rope_table(
+                    positions, rotary_dim, cfg.rope_theta, cfg.dtype, cfg.mrope_section
+                )
                 if cfg.rope or "W" in cfg.layer_pattern else ()
             )
+            if cfg.sparse_topk is not None:
+                if not rotary or cfg.mla is not None:
+                    raise ValueError(
+                        "sparse_topk: the selected attention is the rotary "
+                        "global kind's, never the latent one's"
+                    )
+                # Each table beside the indexer's own, at the temporal id.
+                rotary = tuple(zip(rotary, rope_table(
+                    positions[0] if positions.ndim == 3 else positions,
+                    cfg.indexer_head_dim, cfg.rope_theta, cfg.dtype,
+                )))
             x = _stack_layers(cfg, cfg.layer_pattern, x, rotary, "layers_{}".format)
             predicted = []
             # The modules serve the training loss alone (and ``init``,

@@ -253,8 +253,9 @@ def kda_section(compiled: bool) -> dict:
 # every benchmark cell's shape. (family, cell, (B, S, Hq, Hkv), head widths
 # (qk, ..., v), block length): causal heads are one width; the latent
 # family's are (rope-free, rotary, value); block diffusion's S is both streams.
-# The last number is block diffusion's block length and the banded family's
-# window.
+# The last number is block diffusion's block length, the banded family's
+# window and the selected family's keys a query (a scattered selection from
+# seeded scores: every causal tile pair runs, as in the cell).
 SWEEP_SHAPES = [
     ("causal", "mistral", (4, 4096, 32, 8), (128,), 0),
     ("causal", "internlm2", (2, 8192, 16, 8), (128,), 0),
@@ -265,6 +266,7 @@ SWEEP_SHAPES = [
     ("mla", "joyai", (2, 8192, 32, 32), (128, 64, 128), 0),
     ("window", "smallthinker", (1, 16384, 28, 4), (128,), 4096),
     ("window", "trinity", (1, 16384, 32, 4), (128,), 2048),
+    ("selected", "keye", (1, 16384, 32, 4), (128,), 2048),
 ]
 SWEEP_TILES = [(512, 512), (1024, 512), (512, 1024), (1024, 1024), (2048, 512), (512, 2048)]
 _V5E_BF16_FLOPS = 197e12  # benchmark/peaks.json, "TPU v5 lite"
@@ -286,7 +288,9 @@ def _sweep_case(family, dims, widths, block_length, tiles, interpret):
         jax.random.PRNGKey(i), shape, jnp.float32
     ).astype(jnp.bfloat16)
     # One name a stage for every family: the ``custom_vjp``, the forward that
-    # keeps its residuals, the backward given them; a family is its value.
+    # keeps its residuals, the backward given them; a family is its value
+    # and the operands that lead its calls.
+    lead = ()
     if family == "mla":
         dn, dr, dv = widths
         args = (rand(0, B, Hq, S, dn), rand(1, B, Hq, S, dr), rand(2, B, Hq, S, dn),
@@ -301,17 +305,26 @@ def _sweep_case(family, dims, widths, block_length, tiles, interpret):
         elif family == "window":
             window = block_length
             value, entries = fa._Window(S, S, bq, bk, window), fa.window_kept(S, window)
+        elif family == "selected":
+            from torchft_tpu.ops import sparse_index as dsa
+
+            scores = jax.random.normal(jax.random.PRNGKey(9), (B, S, S), jnp.float32)
+            words, runs, _ = dsa.select(scores, block_length, bq, bk)
+            lead = (runs.reshape(-1), words)
+            value = fa._Selected(S, S, bq, bk, width=words.shape[-1])
+            entries = fa.window_kept(S, block_length)  # min(topk, t + 1) a row: the band's count
         else:
             L = S // 2
             value, entries = fa._BlockDiffusion(S, S, bq, bq, block_length), L * L + L * block_length
         split, width = 1, 2 * d
     parts = lambda a: (a[:split], a[split:-1], a[-1])  # noqa: E731 - q's parts, k's, v
-    fwd = lambda *a: fa._flash(value, (), *parts(a), interpret)  # noqa: E731
-    res = lambda *a: fa._forward_impl(value, (), *parts(a), interpret)  # noqa: E731
+    out_of = (lambda o: o[0]) if value.lse_out else (lambda o: o)  # noqa: E731
+    fwd = lambda *a: out_of(fa._flash(value, lead, *parts(a), interpret))  # noqa: E731
+    res = lambda *a: fa._forward_impl(value, lead, *parts(a), interpret)  # noqa: E731
 
     def bwd(a, do, out, lse):
         dq, dk, dv_ = fa._backward_impl(
-            value, (), *parts(a), do, lse, fa._row_delta(do, out), None, interpret
+            value, lead, *parts(a), do, lse, fa._row_delta(do, out), None, interpret
         )
         return (*dq, *dk, *dv_)
 

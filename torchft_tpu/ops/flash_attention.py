@@ -1,5 +1,5 @@
-"""Pallas TPU flash attention (GQA, forward and backward, four masks;
-latent attention's two-part heads under the causal one).
+"""Pallas TPU flash attention (GQA, forward and backward, five masks, one
+of them data; latent attention's two-part heads under the causal one).
 
 The reference has no attention kernel of its own (it delegates compute to
 torchtitan); this kernel exists because the flagship bench model's dense
@@ -9,7 +9,7 @@ K/V blocks through VMEM with an online softmax so scores never leave
 the chip (reference for the FLOPs budget: SURVEY.md §6; technique:
 Dao et al. 2022, standard TPU formulation as in jax's pallas examples).
 
-One scaffold, five families. The step math (``_fwd_step``, ``_bwd_step``),
+One scaffold, six families. The step math (``_fwd_step``, ``_bwd_step``),
 the two kernel bodies around it (``_fwd_kernel``, ``_bwd_kernel``: init at a
 sweep's first step, the step under ``pl.when(run)``, finish at its last),
 the two drivers that build block specs, out shapes and scratch
@@ -23,12 +23,16 @@ answers three questions, and is the only thing that answers them:
   which repeats a tile the sweep holds where the step does not run, so
   nothing is fetched for it (``q_fetch``); ``q_steps`` sizes the grid;
 - the mask closure of a (q tile, kv tile) pair (``mask``);
-- its operands: how many SMEM scalars lead them (``scalars``) and whether
-  ``lse`` is an output with a cotangent (``lse_cotangent``). A tensor is a
-  tuple of parts (the latent family's query and key are two).
+- its operands: how many operands lead a call ahead of q (``scalars``:
+  SMEM scalars, of which the kernels hand the sweep and the mask the values;
+  a family whose mask is data says itself what leads, ``lead_specs``, and
+  what of it the sweep and the mask are handed, ``at``), whether ``lse`` is
+  an output (``lse_out``) and whether it then has a cotangent
+  (``lse_cotangent``). A tensor is a tuple of parts (the latent family's
+  query and key are two).
 
-A sixth family is a sixth subclass beside these and an entry that builds
-its value; nothing in the frame names a family.
+A seventh family is a seventh subclass beside these and an entry that
+builds its value; nothing in the frame names a family.
 
 - ``flash_attention`` (``_Causal``): causal (or none) over one sequence,
   static.
@@ -52,6 +56,15 @@ its value; nothing in the frame names a family.
   of another shape: a query and key of two parts (rope-free and rotary,
   the rotary key one a position for all heads) and values of a width of
   their own.
+- ``flash_attention_selected`` (``_Selected``): causal, and of a row's
+  earlier keys those an indexer SELECTED (a learned sparse attention in its
+  masked form, ``ops/sparse_index.py``): the one mask that is data. Two
+  operands lead the call: the selection packed a bit an entry, of which a
+  q tile's rows are one VMEM block for its whole sweep and a kv tile's
+  columns an elementwise shift of it, and the table of tile pairs that hold
+  any selected entry, in SMEM, which gates the step forward and backward.
+  ``lse`` is an output (the indexer's loss reads it), a constant to
+  differentiation.
 
 Layout: model-native [B, S, H, D] in/out (matching
 ``models/llama.py:dense_attention``); internally transposed to
@@ -66,8 +79,8 @@ output block is written once at the final kv step. A step whose tile the
 mask empties is skipped via ``pl.when`` (no compute), and under the static
 masks its index map names the tile the sweep already holds, so nothing is
 fetched for it either;
-the ring's offset kernels, whose skip is decided by a dynamic scalar,
-still fetch the tile they skip. The forward keeps its softmax state by
+the ring's offset kernels and the selected family's, whose skip is decided
+by a dynamic scalar, still fetch the tile they skip. The forward keeps its softmax state by
 the lane (``_fwd_step``): the row max replicated across 128 lanes, the
 row sum as 128 partial sums reduced once at the sweep's end, so a step
 has one cross-lane reduction (the max) and broadcasts nothing to store.
@@ -127,11 +140,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from torchft_tpu.ops.sparse_index import mask_width, tile_bits
+
 __all__ = [
     "flash_attention",
     "flash_attention_block",
     "flash_attention_block_diffusion",
     "flash_attention_mla",
+    "flash_attention_selected",
     "flash_attention_window",
     "block_diffusion_tiles",
     "window_kept",
@@ -140,6 +156,7 @@ __all__ = [
     "supports",
     "supports_block_diffusion",
     "supports_mla",
+    "supports_selected",
     "supports_window",
 ]
 
@@ -290,7 +307,15 @@ def choose_tiles(
         block_q, block_k = _band_tile(window, block_q), _band_tile(window, block_k)
     bq = _tile(seq_len, block_q)
     bk = _tile(seq_len if kv_len is None else kv_len, block_k)
-    return None if bq is None or bk is None else (bq, bk)
+    if bq is None or bk is None:
+        return None
+    if family == "selected":
+        # A kv tile is whole groups of the packed selection's columns or a
+        # group whole tiles; compiled, both in whole lane tiles.
+        width = mask_width(seq_len)
+        if bk % width and width % bk or compiled and (width % _LANES or bk % _LANES):
+            return None
+    return bq, bk
 
 
 def supports(
@@ -451,6 +476,21 @@ class _Family:
 
     scalars = 0
     lse_cotangent = False
+
+    @property
+    def lse_out(self) -> bool:
+        """Whether ``lse`` is an output of the entry: where it carries a
+        cotangent, and where a family says so itself."""
+        return self.lse_cotangent
+
+    def lead_specs(self) -> list:
+        """Block specs of the ``scalars`` operands that lead a call."""
+        return [_smem_spec()] * self.scalars
+
+    def at(self, refs) -> list:
+        """What a kernel hands ``q_sweep`` and ``mask`` of those operands'
+        refs: the scalars' values."""
+        return [ref[0, 0] for ref in refs]
 
     @property
     def nq(self) -> int:
@@ -679,6 +719,58 @@ class _Window(_Family):
         return mask_fn
 
 
+@dataclasses.dataclass(frozen=True)
+class _Selected(_Causal):
+    """models/llama.py:Attention's selected kind (a learned sparse
+    attention in its masked form): causal, and of the causal entries a row
+    keeps those its indexer SELECTED, which is data. Two operands lead the
+    call beside no scalar: ``runs``, int32 [B * nq * nk] in SMEM, 1 where a
+    tile pair holds any selected entry (``ops/sparse_index.py:tile_runs``),
+    which gates the step under ``pl.when``, forward and backward alike; and
+    ``words``, the selection packed as int32 [B, S, width] (bit g of word c
+    of a row: column g * width + c), of which a q tile's rows [block_q,
+    width] are ONE block for its whole kv sweep, so it is fetched once a
+    head and q tile and a kv tile's columns are those words shifted by the
+    tile's own g: an elementwise unpack. The causal edge stays this
+    family's own position test (and its sweep and fetch the causal
+    family's: a tile the table skips is still fetched, its index is not a
+    scalar's to move). A row may keep nothing of a tile that runs, or of
+    every tile before its last: what it accumulates there is scaled to
+    exactly 0 by its first finite max, as under the band. ``lse`` is an
+    output (the indexer's loss reads it) and carries no cotangent."""
+    width: int = 0
+
+    scalars = 2
+    lse_out = True
+
+    def lead_specs(self):
+        return [
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(
+                (1, self.block_q, self.width), lambda b, h, iq, j: (b, iq, 0)
+            ),
+        ]
+
+    def at(self, refs):
+        return list(refs)
+
+    def q_sweep(self, span, j, runs, words):
+        ikv, causal = super().q_sweep(span, j)
+        pair = (pl.program_id(0) * self.nq + span[0]) * self.nk + ikv
+        return ikv, causal & (runs[pair] != 0)
+
+    def mask(self, iq, ikv, runs, words):
+        starts = self._starts(iq, ikv)
+        width, bk = self.width, self.block_k
+
+        def mask_fn(s):
+            bits = tile_bits(words, ikv, bk, width)
+            rows, cols = _positions(s, *starts)
+            return _keep(s, (bits != 0) & (rows >= cols))
+
+        return mask_fn
+
+
 # ---------------------------------------------------------------------------
 # The frame: two kernel bodies, two drivers ([B,H,S,D] layout) and the
 # custom_vjp.
@@ -723,7 +815,7 @@ def _fwd_kernel(*refs, family, parts, scale):
     # m and l [block_q,128] f32 (row max lane-broadcast, partial row sums).
     scalars, q, k, (v, o, lse, acc, m, l) = _cut(refs, family.scalars, parts, parts)
     iq, j = pl.program_id(2), pl.program_id(3)
-    at = [ref[0, 0] for ref in scalars]
+    at = family.at(scalars)
 
     @pl.when(j == 0)
     def _init():
@@ -748,7 +840,7 @@ def _backward_refs(refs, family, parts):
     scalars, q, k, residuals, rest = _cut(
         refs, family.scalars, parts, parts, 4 + family.lse_cotangent
     )
-    return [ref[0, 0] for ref in scalars], q, k, (*residuals, None)[:5], rest
+    return family.at(scalars), q, k, (*residuals, None)[:5], rest
 
 
 def _bracket(family, group, h, iq, j):
@@ -878,7 +970,7 @@ def _forward_impl(family, scalars, q, k, v, interpret):
             jax.ShapeDtypeStruct((B, Hq, 8, family.q_len), jnp.float32),
         ],
         grid=(B, Hq, family.nq, family.q_steps),
-        in_specs=[*[_smem_spec()] * len(scalars), *q_specs, *kv_specs],
+        in_specs=[*family.lead_specs(), *q_specs, *kv_specs],
         # Constant in the sweep's step: blocks stay resident in VMEM across
         # the kv sweep and are flushed once.
         out_specs=[o_spec, row_spec],
@@ -907,7 +999,7 @@ def _backward_impl(family, scalars, q, k, v, do, lse, delta, dlse, interpret):
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (*q, *k, v)],
         grid=(B, Hq, family.nq, family.q_steps),
         in_specs=[
-            *[_smem_spec()] * len(scalars), *q_specs, *kv_specs, o_spec,
+            *family.lead_specs(), *q_specs, *kv_specs, o_spec,
             *[row_spec] * len(rows),
         ],
         # dk's parts and dv: the whole of a head, constant over its bracket.
@@ -945,7 +1037,7 @@ def _flash(family, scalars, q, k, v, interpret):
 
 def _flash_fwd(family, scalars, q, k, v, interpret):
     out, lse = _forward_impl(family, scalars, q, k, v, interpret)
-    primal = (out, lse) if family.lse_cotangent else out
+    primal = (out, lse) if family.lse_out else out
     return primal, (scalars, q, k, v, out, lse)
 
 
@@ -956,7 +1048,9 @@ def _flash_bwd(family, interpret, res, ct):
     # sublane slice happens in the public wrapper, outside this vjp); the
     # kernels read sublane 0, which is exactly where the slice cotangent
     # lands.
-    do, dlse = ct if family.lse_cotangent else (ct, None)
+    do, dlse = ct if family.lse_out else (ct, None)
+    if not family.lse_cotangent:
+        dlse = None  # an output that is read as a constant
     dq, dk, (dv,) = _backward_impl(
         family, scalars, q, k, v, do, lse, _row_delta(do, out),
         None if dlse is None else dlse.astype(jnp.float32), interpret,
@@ -1232,3 +1326,50 @@ def flash_attention_window(
     qt, kt, vt = _heads_first(q, k, v)
     family = _Causal(S, S, *tiles) if window >= S else _Window(S, S, *tiles, window)
     return jnp.swapaxes(_flash(family, (), (qt,), (kt,), vt, itp), 1, 2)
+
+
+def supports_selected(
+    seq_len: int, block_q: int = _MAX_TILE, block_k: int = _MAX_TILE
+) -> bool:
+    """Whether the selected kernels handle this sequence under these
+    largest tiles (``choose_tiles`` has the rules; the caller runs dense
+    attention under the unpacked selection otherwise)."""
+    return choose_tiles("selected", seq_len, (), block_q, block_k) is not None
+
+
+@functools.partial(jax.jit, static_argnames=("block_q", "block_k", "interpret"))
+def flash_attention_selected(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    words: jax.Array,
+    runs: jax.Array,
+    block_q: int = _MAX_TILE,
+    block_k: int = _MAX_TILE,
+    interpret: Optional[bool] = None,
+) -> tuple:
+    """Causal GQA flash attention over a SELECTION of each row's earlier
+    keys, differentiable in q, k and v. q: [B,S,Hq,D]; k/v: [B,S,Hkv,D];
+    ``words`` int32 [B,S,W], the selection packed as
+    ``ops/sparse_index.py`` packs it (W its ``mask_width(S)``), and
+    ``runs`` int32 [B,nq,nk], 1 where a tile pair of the tiles this call
+    chooses (``choose_tiles("selected", ...)``) holds any selected entry.
+    Returns ``(out [B,S,Hq,D], lse [B,Hq,S] fp32)``; lse is a constant to
+    differentiation. The kernels' trace names start
+    ``flash_attention_selected`` (a kernel is named for the jit around it)."""
+    B, S, Hq, D = q.shape
+    assert Hq % k.shape[2] == 0, (Hq, k.shape[2])
+    tiles = choose_tiles("selected", S, (D,), block_q, block_k)
+    if tiles is None or words.shape[-1] != mask_width(S):
+        raise ValueError(
+            f"flash_attention_selected: seq_len {S} under blocks ({block_q},"
+            f"{block_k}) with words of {words.shape[-1]}: use dense attention "
+            "under the unpacked selection"
+        )
+    assert runs.shape == (B, S // tiles[0], S // tiles[1]), (runs.shape, tiles)
+    itp = _interpret() if interpret is None else interpret
+    qt, kt, vt = _heads_first(q, k, v)
+    family = _Selected(S, S, *tiles, width=words.shape[-1])
+    lead = (runs.reshape(-1).astype(jnp.int32), words)
+    out, lse = _flash(family, lead, (qt,), (kt,), vt, itp)
+    return jnp.swapaxes(out, 1, 2), jax.lax.stop_gradient(lse[:, :, 0, :])

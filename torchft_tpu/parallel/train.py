@@ -176,11 +176,12 @@ def _apply_with_aux(model: Transformer, params, inputs, **kw):
     by name and stacked over the layers ({} for a model with none of
     them): ``router_aux``, ``router_z``, ``moe_max_load``, ``moe_dropped``;
     the ``gdn_*`` and ``kda_*`` counters; a windowed attention layer's
-    ``swa_kept_share``."""
+    ``swa_kept_share``; a selected one's ``dsa_index_kl`` (the indexer's
+    loss, which the step's loss adds) and its two shares."""
     cfg = model.cfg
     if (
         cfg.num_experts <= 0 and cfg.gated_delta is None and cfg.kda is None
-        and "W" not in (cfg.layer_pattern or "")
+        and "W" not in (cfg.layer_pattern or "") and cfg.sparse_topk is None
     ):
         return model.apply({"params": params}, inputs, **kw), {}
     out, inter = model.apply(
@@ -216,6 +217,14 @@ _SOWN_OVER_LAYERS = (
     # From the windowed attention layers: of the score entries the tiles
     # they run hold, the share the band keeps (``window_attention``).
     ("swa_kept_share", jnp.mean),
+    # From the selected attention layers (``Attention._selected``): the
+    # indexer's loss (nats a token; the step's loss adds
+    # ``indexer_loss_coef`` x its mean over the layers), the selected
+    # entries over the causal ones, and the tile pairs the kernels ran over
+    # the causal tile pairs.
+    ("dsa_index_kl", jnp.mean),
+    ("dsa_kept_share", jnp.mean),
+    ("dsa_tiles_run_share", jnp.mean),
     # From the gated-delta mixers (models/gated_delta.py): the largest
     # |entry| of a state at a sequence's end (with eigenvalues down to -1
     # a state that grows is the failure to see), the smallest decay and
@@ -418,8 +427,10 @@ def diffusion_streams(cfg: LlamaConfig, inputs, mask):
     return jnp.concatenate([x_t, inputs], axis=1), weights, masked
 
 
-def _loss_and_metrics(model: Transformer, params, inputs, targets, mask):
-    """(loss, metrics), by the model's ``objective``.
+def _loss_and_metrics(model: Transformer, params, inputs, targets, mask, positions=None):
+    """(loss, metrics), by the model's ``objective``. ``positions``: a
+    batch's own ``position_ids`` ([3,B,S] under ``mrope_section``), None for
+    the model's default.
 
     "next_token": the mean over the data positions (``mask``) of the
     cross-entropy of each position's successor (``targets``).
@@ -470,6 +481,8 @@ def _loss_and_metrics(model: Transformer, params, inputs, targets, mask):
     if two_streams and cfg.mtp_layers:
         raise ValueError("prediction modules under block diffusion: not built")
 
+    at = {} if positions is None else {"positions": positions}
+
     def with_router_terms(ce, sown):
         if not sown:
             # The zero term is the dense step's jaxpr as it always was: a
@@ -484,6 +497,8 @@ def _loss_and_metrics(model: Transformer, params, inputs, targets, mask):
             loss = ce + cfg.router_aux_coef * metrics["router_aux"]
             if cfg.router_z_coef:
                 loss = loss + cfg.router_z_coef * metrics["router_z"]
+        if "dsa_index_kl" in metrics:
+            loss = loss + cfg.indexer_loss_coef * metrics["dsa_index_kl"]
         return loss, jax.lax.stop_gradient({**metrics, **extra})
 
     def data_rows(x):
@@ -499,7 +514,7 @@ def _loss_and_metrics(model: Transformer, params, inputs, targets, mask):
 
     if cfg.mtp_layers:
         (h, *predicted), sown = _apply_with_aux(
-            model, params, inputs, return_hidden=True, next_tokens=targets
+            model, params, inputs, return_hidden=True, next_tokens=targets, **at
         )
         chunk = C if S % C == 0 else S  # an odd length: one chunk
         main, mtp = head_loss(h, targets, weights, chunk) / denom, 0.0
@@ -515,18 +530,41 @@ def _loss_and_metrics(model: Transformer, params, inputs, targets, mask):
         return with_router_terms(main + cfg.mtp_loss_coef * mtp, sown)
 
     if S % C != 0:  # odd seq len: the plain full-logits path
-        logits, sown = _apply_with_aux(model, params, inputs)
+        logits, sown = _apply_with_aux(model, params, inputs, **at)
         losses = optax.softmax_cross_entropy_with_integer_labels(
             data_rows(logits), targets
         )
         return with_router_terms((losses * weights).sum() / denom, sown)
 
-    h, sown = _apply_with_aux(model, params, inputs, return_hidden=True)
+    h, sown = _apply_with_aux(model, params, inputs, return_hidden=True, **at)
     return with_router_terms(head_loss(data_rows(h), targets, weights) / denom, sown)
 
 
-def _loss_fn(model: Transformer, params, inputs, targets, mask):
-    return _loss_and_metrics(model, params, inputs, targets, mask)[0]
+def _loss_fn(model: Transformer, params, inputs, targets, mask, positions=None):
+    return _loss_and_metrics(model, params, inputs, targets, mask, positions)[0]
+
+
+def _batch_shardings(model: Transformer, mesh: Mesh):
+    """The jit's shardings of a batch: the three [B,S] arrays by key; None
+    for a model with ``mrope_section``, whose batch may carry
+    "position_ids" [3,B,S] besides: ``_placed`` constrains what it has."""
+    if model.cfg.mrope_section is not None:
+        return None
+    bsh = batch_sharding(mesh)
+    return {"inputs": bsh, "targets": bsh, "mask": bsh}
+
+
+def _placed(model: Transformer, mesh: Mesh, batch: dict) -> dict:
+    """The batch as the step reads it: as handed in where the jit placed
+    it (``_batch_shardings``), else each array constrained by its key."""
+    if model.cfg.mrope_section is None:
+        return batch
+    bsh = batch_sharding(mesh)
+    ids = NamedSharding(mesh, P(None, *bsh.spec))
+    return {
+        k: jax.lax.with_sharding_constraint(v, ids if k == "position_ids" else bsh)
+        for k, v in batch.items()
+    }
 
 
 def make_train_step(
@@ -537,7 +575,10 @@ def make_train_step(
     donate: bool = True,
     accum_steps: int = 1,
 ) -> Callable[[TrainState, Any], Tuple[TrainState, Any]]:
-    """batch = {"inputs": [B,S] i32, "targets": [B,S] i32, "mask": [B,S]}.
+    """batch = {"inputs": [B,S] i32, "targets": [B,S] i32, "mask": [B,S]}
+    and, for a model with ``mrope_section``, optionally "position_ids"
+    [3,B,S] i32 (a token's temporal, height and width ids; without them the
+    model counts positions itself).
     Returns jitted (state, batch) -> (state, metrics): ``loss``,
     ``grad_norm`` and, for a model with experts, the router metrics of
     ``_loss_and_metrics`` (means over the microbatches when accumulating).
@@ -558,19 +599,21 @@ def make_train_step(
     """
     optimizer = optimizer or _DEFAULT_OPT
     bsh = batch_sharding(mesh)
-    batch_sh = {"inputs": bsh, "targets": bsh, "mask": bsh}
+    batch_sh = _batch_shardings(model, mesh)
 
     def grads_and_loss(params, batch):
         inputs = jax.lax.with_sharding_constraint(batch["inputs"], bsh)
         (loss, router), grads = jax.value_and_grad(
             lambda p: _loss_and_metrics(
-                model, p, inputs, batch["targets"], batch["mask"]
+                model, p, inputs, batch["targets"], batch["mask"],
+                batch.get("position_ids"),
             ),
             has_aux=True,
         )(params)
         return loss, router, grads
 
     def step_fn(state: TrainState, batch) -> Tuple[TrainState, Any]:
+        batch = _placed(model, mesh, batch)
         if accum_steps <= 1:
             loss, router, grads = grads_and_loss(state.params, batch)
         else:
@@ -596,7 +639,13 @@ def make_train_step(
                     0,
                 )
                 for k, v in batch.items()
+                if k != "position_ids"
             }
+            if "position_ids" in batch:  # [3,B,S]: the rows are its axis 1
+                ids = batch["position_ids"]
+                micro["position_ids"] = jnp.moveaxis(
+                    ids.reshape(3, B // accum_steps, accum_steps, -1), 2, 0
+                )
             g0 = jax.tree_util.tree_map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), state.params
             )
@@ -646,15 +695,15 @@ def make_grad_step(
     shardings: TrainState,
     with_metrics: bool = False,
 ) -> Callable[[Any, Any], Tuple[Any, Any]]:
-    """(params, batch) -> (loss, grads): the bare gradient program.
+    """(params, batch) -> (loss, grads): the bare gradient program
+    (the batch as ``make_train_step``'s).
     ``with_metrics``: ((loss, router metrics), grads), the metrics those
     of ``_loss_and_metrics`` ({} for a dense model), ``moe_load`` among
     them; a loop takes ``make_split_grad_step`` and ``make_apply_step``.
     Without ``with_metrics`` no load leaves the step, so a loop built on
     it would train a model with ``router_bias_update_rate`` > 0 with its
     selection biases standing still: said once here, as a warning."""
-    bsh = batch_sharding(mesh)
-    batch_sh = {"inputs": bsh, "targets": bsh, "mask": bsh}
+    batch_sh = _batch_shardings(model, mesh)
     if model.cfg.router_bias_update_rate and not with_metrics:
         logger.warning(
             "make_grad_step(with_metrics=False) for a model with "
@@ -667,9 +716,11 @@ def make_grad_step(
         )
 
     def fn(params, batch):
+        batch = _placed(model, mesh, batch)
         return jax.value_and_grad(
             lambda p: (_loss_and_metrics if with_metrics else _loss_fn)(
-                model, p, batch["inputs"], batch["targets"], batch["mask"]
+                model, p, batch["inputs"], batch["targets"], batch["mask"],
+                batch.get("position_ids"),
             ),
             has_aux=with_metrics,
         )(params)
