@@ -144,6 +144,36 @@ def test_a_departure_is_another_result(departure, least):
     assert departure != "no_indexer_loss" or ("indexer" in worst and errs[worst] == 1.0)
 
 
+def test_dsa_probs_ms_is_the_probabilities_pass_alone_a_part_of_dsa_index_ms():
+    """The table's entry, and the reading on a hand-made trace of two steps
+    whose operations are named as the compiled step names them: the two
+    modes of ``dsa_index_kl`` and nothing else of the indexer's."""
+    import os
+
+    from benchmark import trace_reduce, worker
+
+    table = cells.load_json(os.path.join(cells.ROOT, "BENCHMARK.json"))["per_layer"]
+    assert {k: v for k, v in table[-1].items() if k != "unit"} == {
+        "name": "dsa_probs_ms", "better": "lower", "source": "device_trace",
+        "layer": "indexer and selection", "moves": "tok_s_chip", "workloads": ["keye-raw"]}
+    cell = cells.load_cell("keye-raw")
+    readers = worker.load_metric_readers(cell, "")
+    ops = {
+        "dsa_index_kl.7 bf16[1,16384,16384]": 0.125, "dsa_index_kl.3 f32[1,16384,128]": 0.0625,
+        "dsa_index_scores.4 f32[1,16384,16384]": 0.5, "dsa_index_scores_bwd.2 (f32[1,16,16384,64]": 0.25,
+        "flash_attention_selected.3 bf16[1,32,16384,128]": 1.0, "fusion.9 (pred[512,16384]{1,0}, s32[512]{0})": 2.0,
+    }
+    trace = trace_reduce.Trace((0.0, 8.0), 1, sum(ops.values()), ops, [], {})
+    run = {"cell": cell, "records": [], "trace": trace, "traced_steps": 2}
+    assert readers["dsa_probs_ms"](run) == (0.125 + 0.0625) * 1e3 / 2
+    assert readers["dsa_index_ms"](run) == readers["dsa_probs_ms"](run) + (0.5 + 0.25) * 1e3 / 2
+    # no trace, or a trace without the kernel (the ``jax.numpy`` form): nothing
+    assert readers["dsa_probs_ms"]({**run, "trace": None, "traced_steps": 0}) is None
+    bare = trace_reduce.Trace((0.0, 8.0), 1, 1.0, {"fusion.3 (f32[1,8,512,16384]{3,2,1,0}, f32[1": 1.0}, [], {})
+    assert readers["dsa_probs_ms"]({**run, "trace": bare}) is None
+    assert readers["dsa_index_ms"]({**run, "trace": bare}) == 500.0
+
+
 def test_the_indexers_loss_reaches_the_indexer_alone():
     """L_I's gradient reaches only the indexer's leaves, and the rest's
     gradient is what the reference gives with ``indexer_loss_coef`` 0; the

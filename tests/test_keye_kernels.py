@@ -76,12 +76,17 @@ def test_the_selected_kernels_are_dense_attention_under_the_same_selection(seq, 
         assert jnp.allclose(got, ref, atol=5e-5)
 
 
-def test_the_indexer_kernels_are_the_passes_they_replace():
+@pytest.mark.parametrize("seq", [1024, 1536])
+@pytest.mark.parametrize("hq,hkv", [(4, 2), (8, 1), (4, 4)])
+def test_the_indexer_kernels_are_the_passes_they_replace(hq, hkv, seq):
     """``dsa_index_scores``, ``dsa_index_kl`` (the rows' KL sums and G) and
-    ``dsa_index_scores_bwd`` through the interpreter at two tiles of 512 a
-    side, against ``index_scores`` and ``index_kl`` with its gradients in
-    their ``jax.numpy`` forms."""
-    batch, seq, hq, hkv, d, heads, width, topk = 1, 1024, 4, 2, 16, 4, 8, 200
+    ``dsa_index_scores_bwd`` through the interpreter at tiles of 512, against
+    ``index_scores`` and ``index_kl`` with its gradients in their
+    ``jax.numpy`` forms. The probabilities' pass takes a kv head's group of
+    query heads a grid step over the causal tile pairs: two groups of two,
+    one group of eight (the cell's group) and groups of one; three pairs,
+    and six (a row of three pairs, which no power of two has)."""
+    batch, d, heads, width, topk = 1, 16, 4, 8, 200
     ks = jax.random.split(jax.random.PRNGKey(1), 6)
     q, k, v = (jax.random.normal(key, (batch, seq, h, d)) for key, h in zip(ks, (hq, hkv, hkv)))
     q_index = jax.random.normal(ks[3], (batch, seq, heads, width))

@@ -404,6 +404,18 @@ def _custom_calls(text):
     ]
 
 
+def _pallas_grids(fn, *args):
+    """The grid of every ``pallas_call`` that tracing ``fn`` reaches."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield tuple(eqn.params["grid_mapping"].grid)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
 def _computations(text):
     """name -> (header line, whole block) of every computation of a
     compiled module's text."""
@@ -1076,7 +1088,10 @@ def test_flash_selected_compiles_under_the_name_the_metrics_match(one_chip, shap
 def test_the_indexer_kernels_compile_under_the_names_the_metrics_match(one_chip):
     """The score pass, the probabilities' pass (the rows' sums and G) and
     the score pass's transpose at the cell's shapes: one kernel each, named
-    for the jit around it, ``dsa_index...``, which ``dsa_index_ms`` reads."""
+    for the jit around it, ``dsa_index...``, which ``dsa_index_ms`` reads.
+    The probabilities' pass, in both modes, steps through the 528 causal
+    tile pairs and the 4 kv heads (a group of 8 query heads a step) where
+    the others walk the square of tile pairs."""
     import re
 
     from benchmark.metrics import dsa_index_ms
@@ -1089,14 +1104,16 @@ def test_the_indexer_kernels_compile_under_the_names_the_metrics_match(one_chip)
     rest = (sd((B, S, Hq, D), jnp.bfloat16), sd((B, S, Hkv, D), jnp.bfloat16),
             sd((B, Hq, S), jnp.float32), sd((B, S, dsa.mask_width(S)), jnp.int32),
             sd((B, S), jnp.float32))
-    for fn, args in (
-        (dsa.dsa_index_scores, index),
-        (dsa.dsa_index_kl, (*index, *rest)),
-        (lambda *a: dsa.dsa_index_kl(*a, grad=True), (*index, *rest)),
-        (dsa.dsa_index_scores_bwd, (sd((B, S, S), jnp.bfloat16), *index)),
+    square, causal = (1, 32, 32), (1, 528, 4)  # 32 tiles of 512 a side, 32 * 33 / 2 pairs
+    for fn, args, grid in (
+        (dsa.dsa_index_scores, index, square),
+        (dsa.dsa_index_kl, (*index, *rest), causal),
+        (lambda *a: dsa.dsa_index_kl(*a, grad=True), (*index, *rest), causal),
+        (dsa.dsa_index_scores_bwd, (sd((B, S, S), jnp.bfloat16), *index), square),
     ):
         calls = _custom_calls(jax.jit(fn).lower(*args).compile().as_text())
         assert len(calls) == 1 and re.search(dsa_index_ms.KERNELS, calls[0]), calls
+        assert _pallas_grids(fn, *args) == [grid]
 
 
 @pytest.mark.timeout(900)

@@ -43,12 +43,16 @@ for sequences of whole tiles of ``CHUNK``, three Pallas kernels take the
 passes the device trace showed above a tenth of the step (PERF.md section
 6, PR 69; the jit around each names it in the trace): ``dsa_index_scores``
 (a tile pair's 16 small matmuls, ReLU and weighted sum in VMEM, I written
-once), ``dsa_index_kl`` (the main attention's heads innermost in the grid,
-so a tile pair's head-summed probabilities stay in VMEM; at the last head
-the tile's I is recomputed, and the tile's share of L_I or of G comes out)
-and ``dsa_index_scores_bwd`` (G's transpose through the score pass: dqI a
-q tile, dkI resident for the sequence, dw). The selection stays
-``jax.numpy``: its bisections are 32 fused counts of a chunk.
+once), ``dsa_index_kl`` (a grid step is a causal tile pair and a kv head's
+GROUP of query heads: the grid walks the n(n+1)/2 pairs on or under the
+diagonal from two prefetched tables, the kv heads innermost, so the key
+tile is fetched once a group, the group's probabilities are summed before
+they touch the accumulator, and a pair's head-summed probabilities stay in
+VMEM; at the last kv head the tile's I is recomputed, and the tile's share
+of L_I or of G comes out) and ``dsa_index_scores_bwd`` (G's transpose
+through the score pass: dqI a q tile, dkI resident for the sequence, dw).
+The selection stays ``jax.numpy``: its bisections are 32 fused counts of a
+chunk.
 """
 
 from __future__ import annotations
@@ -58,6 +62,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -343,9 +348,10 @@ index_kl.defvjp(_index_kl_fwd, _index_kl_bwd)
 
 # ---------------------------------------------------------------------------
 # The Pallas kernels of the passes above. Tiles are CHUNK x CHUNK (the
-# published q_chunk_size x kv_chunk_size); a tile pair above the diagonal is
-# skipped and its blocks' indices clamped to the diagonal pair's, so nothing
-# is fetched or written for it.
+# published q_chunk_size x kv_chunk_size). The score pass and its transpose
+# step through every tile pair: one above the diagonal is skipped and its
+# blocks' indices clamped to the diagonal pair's, so nothing is fetched or
+# written for it. The probabilities' pass steps through the causal pairs only.
 # ---------------------------------------------------------------------------
 
 _VMEM_LIMIT = 64 * 2**20
@@ -427,31 +433,34 @@ def dsa_index_scores(q_index, k_index, weights, interpret=False):
 
 
 def _kl_kernel(
-    words_ref, q_ref, k_ref, lse_ref, qi_ref, ki_ref, w_ref, lsei_ref, out_ref,
-    acc_ref, *, heads, scale, width, rows,
+    iq_ref, ik_ref, words_ref, q_ref, k_ref, lse_ref, qi_ref, ki_ref, w_ref, lsei_ref,
+    out_ref, acc_ref, *, heads, scale, width, rows,
 ):
-    """One (q tile, kv tile, head) step. ``rows`` None: the tile's share of
-    the rows' KL sums, folded into 128 lanes and added to ``out_ref``
-    [1, tq, 128]; else G's tile, (softmax(I) - p) / rows, to ``out_ref``
-    [1, tq, tk]."""
-    iq, ik, h = pl.program_id(1), pl.program_id(2), pl.program_id(3)
-    run = ik <= iq
+    """One (causal tile pair, kv head) step: the pair is ``iq_ref``'s and
+    ``ik_ref``'s entry (SMEM), q_ref [1, G, tq, d] the kv head's group of
+    query heads. ``rows`` None: the tile's share of the rows' KL sums,
+    folded into 128 lanes and added to ``out_ref`` [1, tq, 128]; else G's
+    tile, (softmax(I) - p) / rows, to ``out_ref`` [1, tq, tk]."""
+    pair, kv = pl.program_id(1), pl.program_id(2)
+    iq, ik = iq_ref[pair], ik_ref[pair]
 
-    @pl.when(run & (h == 0))
+    @pl.when(kv == 0)
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     if rows is None:
-        @pl.when((ik == 0) & (h == 0))
+        @pl.when((ik == 0) & (kv == 0))
         def _():
             out_ref[:] = jnp.zeros_like(out_ref)
 
-    @pl.when(run)
-    def _():
-        s = _nt(q_ref[0, 0], k_ref[0, 0]) * scale
-        acc_ref[:] = acc_ref[:] + jnp.exp(s - lse_ref[0, 0, 0][:, None])
+    keys, group = k_ref[0, 0], None
+    for g in range(q_ref.shape[1]):
+        s = _nt(q_ref[0, g], keys) * scale
+        probs = jnp.exp(s - lse_ref[0, g, 0][:, None])
+        group = probs if group is None else group + probs
+    acc_ref[:] = acc_ref[:] + group
 
-    @pl.when(run & (h == heads - 1))
+    @pl.when(kv == pl.num_programs(2) - 1)
     def _():
         scores = _scores_tile(qi_ref, ki_ref, w_ref)
         at_row = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0) + iq * CHUNK
@@ -470,27 +479,37 @@ def _kl_kernel(
             )
 
 
+def _causal_pairs(n: int):
+    """The n(n+1)/2 tile pairs on or under the diagonal, a q tile's pairs
+    consecutive: (iq, ik), int32 each."""
+    iq, ik = np.tril_indices(n)
+    return jnp.asarray(iq, jnp.int32), jnp.asarray(ik, jnp.int32)
+
+
 @functools.partial(jax.jit, static_argnames=("grad", "interpret"))
 def dsa_index_kl(
     q_index, k_index, weights, q, k, lse, words, lse_index, grad=False, interpret=False
 ):
     """The probabilities' pass as a kernel: the rows' KL sums [B, S, 128]
     (their sum over the last axis), or under ``grad`` G [B, S, S] in the
-    indexer's dtype."""
+    indexer's dtype. The grid is (B, causal tile pairs, kv heads): a q
+    tile's pairs are consecutive, which the rows' sums rely on."""
     b, seq_len, hq, d = q.shape
     hkv = k.shape[2]
     heads, width = q_index.shape[2:]
-    n, mask_w = seq_len // CHUNK, words.shape[-1]
+    mask_w = words.shape[-1]
+    group, pairs = hq // hkv, _causal_pairs(seq_len // CHUNK)
+
+    def at(place):  # an index map of the grid from ``place(b, kv, iq, ik)``
+        return lambda b, pair, kv, iq_ref, ik_ref: place(b, kv, iq_ref[pair], ik_ref[pair])
+
     rows8 = lambda x: jnp.broadcast_to(x[:, :, None, :], (*x.shape[:2], 8, seq_len))  # noqa: E731
-    per_row = lambda head: pl.BlockSpec(  # noqa: E731
-        (1, 1, 8, CHUNK), lambda b, iq, ik, h: (b, head(h), 0, iq)
-    )
     out_shape, out_spec = (
         (jax.ShapeDtypeStruct((b, seq_len, seq_len), q_index.dtype),
-         pl.BlockSpec((1, CHUNK, CHUNK), lambda b, iq, ik, h: (b, iq, _diag(iq, ik))))
+         pl.BlockSpec((1, CHUNK, CHUNK), at(lambda b, kv, iq, ik: (b, iq, ik))))
         if grad else
         (jax.ShapeDtypeStruct((b, seq_len, _LANES), jnp.float32),
-         pl.BlockSpec((1, CHUNK, _LANES), lambda b, iq, ik, h: (b, iq, 0)))
+         pl.BlockSpec((1, CHUNK, _LANES), at(lambda b, kv, iq, ik: (b, iq, 0))))
     )
     return pl.pallas_call(
         functools.partial(
@@ -498,23 +517,26 @@ def dsa_index_kl(
             rows=b * seq_len if grad else None,
         ),
         out_shape=out_shape,
-        grid=(b, n, n, hq),
-        in_specs=[
-            pl.BlockSpec((1, CHUNK, mask_w), lambda b, iq, ik, h: (b, iq, 0)),
-            pl.BlockSpec((1, 1, CHUNK, d), lambda b, iq, ik, h: (b, h, iq, 0)),
-            pl.BlockSpec(
-                (1, 1, CHUNK, d), lambda b, iq, ik, h: (b, h // (hq // hkv), _diag(iq, ik), 0)
-            ),
-            per_row(lambda h: h),
-            *_index_specs(heads, width),
-            per_row(lambda h: 0),
-        ],
-        out_specs=out_spec,
-        scratch_shapes=[pltpu.VMEM((CHUNK, CHUNK), jnp.float32)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, pairs[0].size, hkv),
+            in_specs=[
+                pl.BlockSpec((1, CHUNK, mask_w), at(lambda b, kv, iq, ik: (b, iq, 0))),
+                pl.BlockSpec((1, group, CHUNK, d), at(lambda b, kv, iq, ik: (b, kv, iq, 0))),
+                pl.BlockSpec((1, 1, CHUNK, d), at(lambda b, kv, iq, ik: (b, kv, ik, 0))),
+                pl.BlockSpec((1, group, 8, CHUNK), at(lambda b, kv, iq, ik: (b, kv, 0, iq))),
+                pl.BlockSpec((1, heads, CHUNK, width), at(lambda b, kv, iq, ik: (b, 0, iq, 0))),
+                pl.BlockSpec((1, CHUNK, width), at(lambda b, kv, iq, ik: (b, ik, 0))),
+                pl.BlockSpec((1, CHUNK, heads), at(lambda b, kv, iq, ik: (b, iq, 0))),
+                pl.BlockSpec((1, 1, 8, CHUNK), at(lambda b, kv, iq, ik: (b, 0, 0, iq))),
+            ],
+            out_specs=out_spec,
+            scratch_shapes=[pltpu.VMEM((CHUNK, CHUNK), jnp.float32)],
+        ),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(
-        words, jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), rows8(lse),
+        *pairs, words, jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), rows8(lse),
         jnp.swapaxes(q_index, 1, 2), k_index, weights, rows8(lse_index[:, None, :]),
     )
 
