@@ -153,7 +153,8 @@ def test_dsa_probs_ms_is_the_probabilities_pass_alone_a_part_of_dsa_index_ms():
     from benchmark import trace_reduce, worker
 
     table = cells.load_json(os.path.join(cells.ROOT, "BENCHMARK.json"))["per_layer"]
-    assert {k: v for k, v in table[-1].items() if k != "unit"} == {
+    entry = next(e for e in table if e["name"] == "dsa_probs_ms")  # the table grows at its end
+    assert {k: v for k, v in entry.items() if k != "unit"} == {
         "name": "dsa_probs_ms", "better": "lower", "source": "device_trace",
         "layer": "indexer and selection", "moves": "tok_s_chip", "workloads": ["keye-raw"]}
     cell = cells.load_cell("keye-raw")

@@ -133,7 +133,8 @@ def test_the_builders_long_comparison_at_a_small_size(tmp_path):
         96, 32, "system")
     assert out["grad_rel_l2_worst"] < 1e-4 and out["loss_rel_diff"] < 1e-5
     assert out["selection_agreement"] == 1.0  # float32 on both sides
-    off = reference_compare.compare(cell, 96, 3000000001, departure="selection_off_by_one")
+    off = reference_compare.compare(
+        cell, 96, 3000000001, query_block=32, departure="selection_off_by_one")
     assert off["compared"] == "reference under selection_off_by_one"
     assert off["grad_rel_l2_worst"] > 100 * CPU_GRAD_TOL
     assert "selection_agreement" not in off

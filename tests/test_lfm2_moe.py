@@ -34,6 +34,7 @@ from torchft_tpu.parallel.train import (
     state_shardings,
     update_router_bias,
 )
+from tests.harness_controls import shared_check
 
 adapter = cells.arch_module("lfm2_moe", "adapter")
 reference = cells.arch_module("lfm2_moe", "reference")
@@ -47,6 +48,28 @@ tiny = _reference_tests.tiny
 for _name, _obj in vars(_reference_tests).items():
     if _name.startswith("test_") and callable(_obj):
         globals()[_name] = _obj
+
+
+def test_the_harness_check_passes_and_a_lower_precision_or_a_missing_norm_fails(tmp_path):  # noqa: F811
+    """benchmark/tests/test_lfm2_reference.py's test of this name on ONE
+    compiled sample (``tests/harness_controls.py``; there every control
+    traces and compiles the whole check again): worker.reference_check as
+    the chip run makes it, at a small size in float32; then the same check
+    with the reference computed as another model or in another precision
+    handed to it in the system's place: the harness's own comparison says
+    not correct, by the reference's limits."""
+    cell = cells.load_cell("w", _reference_tests._tiny_table(tmp_path, tiny()))
+    cell.mix.update(batch=1, seq=48)
+    check = shared_check(cell, 48)
+    out = check.sound
+    assert out["ok"] and out["grad_rel_l2_worst"] < 1e-3 and out["loss_rel_diff"] < 1e-5
+    assert "router_bias" not in out["grad_rel_l2_worst_leaf"]
+    assert reference.GRAD_REL_L2_MEDIAN_TOL < reference.GRAD_REL_L2_TOL == out["grad_rel_l2_tol"]
+    unnormed = check.control(check.departed(per_head_norm=False))
+    assert not unnormed["ok"] and unnormed["grad_rel_l2_worst"] > reference.GRAD_REL_L2_TOL
+    fp8 = check.control(check.departed(operand_dtype=jnp.float8_e4m3fn))
+    bf16 = check.control(check.departed(operand_dtype=jnp.bfloat16))
+    assert fp8["grad_rel_l2_worst"] > bf16["grad_rel_l2_worst"] > 1e-3
 
 
 def _setup(c, seq, batch=2, seed=0):

@@ -58,6 +58,25 @@ PARENT = {
     ("past_one_chunk", 8): ("5fd6ebd76d0f5197", "79a07bc6e3de0ea2", "b0f73af2d00698e8"),
     ("past_one_chunk", 4): ("3c5dde7ba4e80b2e", "881e34e9a3997928", "73c1ad81a509b03c"),
 }
+# The scales' last ulp is the CPU compiler's. At XLA's default level the
+# division by 127 or 7 is folded into a reciprocal multiply (``PARENT``'s
+# scales and what the host dequantizer makes of them, from 035766e); at
+# level 0, the suite's (tests/conftest.py), it is a division: this tree's
+# digests there, whose payloads, and whose scales at the default level, are
+# ``PARENT``'s (``XLA_FLAGS=--xla_backend_optimization_level=0 python
+# tests/test_quant_bucket_programs.py`` prints them).
+LEVEL_0 = {
+    ("aligned_leaf", 8): ("7ea083f23cac1dba", "f6d1af74d4d385bb"),
+    ("aligned_leaf", 4): ("f7029af672b130aa", "825ad057643f602d"),
+    ("unaligned_leaf", 8): ("7e0c61141360d2f3", "876f091df6bd3654"),
+    ("unaligned_leaf", 4): ("3dd7ea531580d8a3", "24cc2f19a3369845"),
+    ("multi_leaf", 8): ("16af072220167d0e", "2dbbabf7ce2784c0"),
+    ("multi_leaf", 4): ("a3ab32a7d3176670", "c068d47f6e3f6de2"),
+    ("past_one_chunk", 8): ("b49b9b5dfb2c538c", "cba6e4ece3775bed"),
+    ("past_one_chunk", 4): ("e8af83c3b0922604", "4f705040064808d1"),
+}
+if "xla_backend_optimization_level=0" in os.environ.get("XLA_FLAGS", ""):
+    PARENT = {case: (PARENT[case][0], *LEVEL_0[case]) for case in PARENT}
 
 
 def make_leaves(spec, seed=0):

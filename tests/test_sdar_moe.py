@@ -35,6 +35,7 @@ from torchft_tpu.parallel.train import (
     make_train_step,
     state_shardings,
 )
+from tests.harness_controls import dead_leaf, shared_check
 from tests.test_ft_step import two_replicas
 
 adapter = cells.arch_module("sdar_moe", "adapter")
@@ -46,6 +47,40 @@ tiny = _reference_tests.tiny
 for _name, _obj in vars(_reference_tests).items():
     if _name.startswith("test_") and callable(_obj):
         globals()[_name] = _obj
+
+
+def test_the_harness_check_passes_and_float8_a_dropped_weight_and_a_dead_leaf_fail(tmp_path):  # noqa: F811
+    """benchmark/tests/test_sdar_reference.py's test of this name on ONE
+    compiled sample (``tests/harness_controls.py``; there every control
+    traces and compiles the whole check again): worker.reference_check as
+    the chip run makes it, at a small size in float32; then the same check
+    with a planted fault handed to it in the system's place: the reference
+    computed in float8, or without the 1/t, or with one leaf's gradient
+    left at zero. Each comes out not correct through the harness's own
+    comparison, by one of the reference's two limits; the reference in
+    bfloat16 reads under float8 on both (at 64 wide its loss is over the
+    limit sized at 2,048 wide, so ``ok`` is not asked of it here)."""
+    cell = cells.load_cell("w", _reference_tests._tiny_table(tmp_path, tiny()))
+    cell.mix.update(batch=1, seq=48)
+    check = shared_check(cell, 48)
+    out = check.sound
+    assert out["ok"] and out["grad_rel_l2_worst"] < 1e-3 and out["loss_rel_diff"] < 1e-5
+    grad_tol, loss_tol = reference.GRAD_REL_L2_TOL, reference.LOSS_REL_TOL
+    assert (out["grad_rel_l2_tol"], out["loss_rel_tol"]) == (grad_tol, loss_tol)
+    assert grad_tol < 1.0  # a leaf whose gradient never moves reads 1.0
+    unweighted = check.control(check.departed(weigh_by_t=False))
+    assert not unweighted["ok"] and unweighted["loss_rel_diff"] > loss_tol
+    fp8 = check.control(check.departed(operand_dtype=jnp.float8_e4m3fn))
+    bf16 = check.control(check.departed(operand_dtype=jnp.bfloat16))
+    assert not fp8["ok"] and (
+        fp8["grad_rel_l2_worst"] > grad_tol or fp8["loss_rel_diff"] > loss_tol)
+    assert fp8["grad_rel_l2_worst"] > bf16["grad_rel_l2_worst"] > 1e-3
+    assert bf16["grad_rel_l2_worst"] < grad_tol and bf16["loss_rel_diff"] < fp8["loss_rel_diff"]
+    loss, grads = check.kept["reference"]
+    dead = check.control((loss, dead_leaf(grads, "layers_1", "mlp", "router", "kernel")))
+    assert not dead["ok"] and dead["loss_rel_diff"] == 0.0
+    assert dead["grad_rel_l2_worst"] == pytest.approx(1.0)
+    assert dead["grad_rel_l2_worst_leaf"] == "['layers_1']['mlp']['router']['kernel']"
 
 
 def test_the_cell_is_found_by_its_arch_key_with_its_metrics(monkeypatch):

@@ -36,6 +36,16 @@ attending to a selection whose last n keys a row are exchanged for the next
 n, ``selection_random`` to one that owes the indexer nothing: how far off a
 selection has to be before a tolerance refuses it.
 
+For a looped model (``ouro-raw``: one stack applied four times) the
+timed shape is 8,192 tokens, ``--query-block 512`` keeps the reference's
+[16, S, S] scores and its four steps' full logits inside the chip, and the
+departures are ``unshared`` (a layer's gradient its last visit's alone),
+``norm_outside``, ``no_entropy`` and ``gate_entropy_only`` (the gate
+learning from the entropy term alone). ``--leaves exit_gate`` adds
+``leaf_readings``, every leaf's reading whose path holds that text: the
+gate's leaf (weights and, as its last row, the bias) learns from small
+differences and is read beside the worst leaf before a limit is set.
+
 One JSON line a seed; exit code 1 where a tolerance is passed. Through the chip
 tool at the published widths; on the CPU only at a test's size
 (``compare`` is what the tests call).
@@ -58,6 +68,9 @@ if ROOT not in sys.path:
 
 # The longest sequence whose every layer's [S, S] selection is compared.
 AGREEMENT_MAX_SEQ = 4096
+# A cell's sample's init (a length and sample configuration) and its sound
+# reference (a length and query block), each compiled once a process.
+_COMPILED_ONCE: Dict[Any, Any] = {}
 
 
 def selection_agreement(cell: Any, cfg: Any, mesh: Any, params: Any, sample: Dict) -> Optional[list]:
@@ -100,7 +113,7 @@ def selection_agreement(cell: Any, cfg: Any, mesh: Any, params: Any, sample: Dic
 def comparisons(
     cell: Any, seq: int, seeds: Sequence[int], query_block: Optional[int] = None,
     operand_dtype: Optional[str] = None, program_window: Optional[int] = None,
-    departure: Optional[str] = None,
+    departure: Optional[str] = None, leaves: Optional[str] = None,
 ) -> Iterator[Dict[str, Any]]:
     """One comparison a seed (weights and tokens from it), its readings
     beside the reference's tolerances; the programs are compiled once."""
@@ -123,11 +136,20 @@ def comparisons(
     options = {}
     if query_block and "query_block" in inspect.signature(reference.loss_and_grads).parameters:
         options["query_block"] = query_block
-    init = jax.jit(
+    # One process compares one cell's shape several ways (the system, the
+    # reference in a lower precision, a departure): the sample's init and
+    # the sound reference are the same programs each time.
+    def once(key, make):
+        if key not in _COMPILED_ONCE:
+            _COMPILED_ONCE[key] = (cell, make())  # the cell kept alive: its id is in the key
+        return _COMPILED_ONCE[key][1]
+
+    init = once(("init", id(cell), seq, cfg), lambda: jax.jit(
         lambda rng, tokens: model.init(rng, tokens)["params"],
         out_shardings=shardings.params,
-    )
-    ref = jax.jit(lambda p, b: reference.loss_and_grads(p, b, config, **options))
+    ))
+    ref = once(("reference", id(cell), seq, options.get("query_block")), lambda: jax.jit(
+        lambda p, b: reference.loss_and_grads(p, b, config, **options)))
     if operand_dtype is None and departure is None:
         system = make_grad_step(model, mesh, shardings)
     else:
@@ -182,6 +204,8 @@ def comparisons(
             "grad_rel_l2_second": ranked[-2] if len(ranked) > 1 else None,
             "grad_rel_l2_median": ranked[len(ranked) // 2],
             "leaves": len(errs),
+            **({} if leaves is None else {
+                "leaf_readings": {k: v for k, v in errs.items() if leaves in k}}),
             **({} if agreement is None else {
                 "selection_agreement": min(agreement), "selection_agreement_layers": agreement}),
             "loss_rel_tol": reference.LOSS_REL_TOL,
@@ -211,6 +235,7 @@ def main() -> int:
     ap.add_argument("--operand-dtype", default=None)
     ap.add_argument("--program-window", type=int, default=0)
     ap.add_argument("--departure", default=None)
+    ap.add_argument("--leaves", default=None, help="also report the leaves whose path holds this")
     ap.add_argument("--out", default="")
     args = ap.parse_args()
     cell = cells.load_cell(args.workload)
@@ -218,7 +243,7 @@ def main() -> int:
     for out in comparisons(
         cell, args.seq or int(cell.mix["seq"]), [int(x) for x in args.seeds.split(",")],
         args.query_block or None, args.operand_dtype, args.program_window or None,
-        args.departure,
+        args.departure, args.leaves,
     ):
         line = json.dumps(out)
         print(line, flush=True)

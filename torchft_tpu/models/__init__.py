@@ -33,6 +33,8 @@ from torchft_tpu.models.llama import (  # noqa: F401
     olmo_hybrid_7b,
     olmo_hybrid_debug,
     olmoe_1b_7b,
+    ouro_2_6b,
+    ouro_debug,
     sdar_30b_a3b,
     sdar_moe_debug,
     smallthinker_21b,
@@ -63,4 +65,6 @@ PRESETS = {
     "keye_vl2_30b_a3b": keye_vl2_30b_a3b,
     "keye_vl2_debug": keye_vl2_debug,
     "trinity_debug": trinity_debug,
+    "ouro_2_6b": ouro_2_6b,
+    "ouro_debug": ouro_debug,
 }

@@ -4,8 +4,12 @@ Design notes (why this is not a torch port):
 - flax.linen + einsum contractions keep every FLOP on the MXU; compute in
   bfloat16, params in float32 (standard TPU mixed precision).
 - The layer stack is an ``nn.scan`` over a single remat'd block (one XLA
-  while-loop body compiled once regardless of depth) or, for the unlike layers
-  of a ``layer_pattern``, a loop over ``MixerLayer``s, each remat'd alone.
+  while-loop body compiled once regardless of depth, its parameters stacked
+  along the scanned axis) or, for the unlike layers of a ``layer_pattern``, a
+  Python loop over ``MixerLayer``s, each its own module, remat'd alone. A
+  looped model (``loop_steps`` > 1) runs that pattern stack inside a second
+  kind of scan, over the loop's steps with the parameters broadcast: one
+  copy of the layers in the program and in the tree, visited several times.
 - Attention is pluggable: ``dense`` (single-chip / short context) or
   ``ring`` (context parallelism over a mesh axis via shard_map + ppermute —
   see torchft_tpu/parallel/ring_attention.py). Long-context is first-class,
@@ -277,6 +281,22 @@ class LlamaConfig:
     # main head's. A ``layer_pattern`` stack's.
     mtp_layers: int = 0
     mtp_loss_coef: float = 0.3
+    # A looped language model (Ouro, arXiv:2510.25741; the Universal
+    # Transformer's recurrence over depth): the WHOLE ``layer_pattern``
+    # stack is applied ``loop_steps`` times on ONE set of parameters,
+    # x_t = final_norm(stack(x_{t-1})), x_0 the embedded tokens, the final
+    # norm inside the loop (the next step reads the normed states). More
+    # than one step also makes the exit gate exist: one linear map to a
+    # value with a bias (``ExitGate``: one leaf, the bias the kernel's last
+    # row) on every step's normed states, z_t, whose sigmoids turn the steps
+    # into a distribution over depths a token, p_t = sigma(z_t) prod_{j<t}
+    # (1 - sigma(z_j)), the last step taking what is left. The training
+    # loss is then the expectation under p of the steps' cross-entropies
+    # (one head, shared) less ``loop_entropy_coef`` x p's entropy
+    # (parallel/train.py:_loss_and_metrics); logits are the last step's.
+    # A ``layer_pattern`` stack's.
+    loop_steps: int = 1
+    loop_entropy_coef: float = 0.0
     # Bound by parallel.train when attn_impl is 'ring' or 'ulysses'.
     attn_fn: Optional[Callable[..., jax.Array]] = None
 
@@ -942,6 +962,57 @@ def keye_vl2_debug(**overrides: Any) -> LlamaConfig:
     return dataclasses.replace(cfg, **overrides)
 
 
+def ouro_2_6b(**overrides: Any) -> LlamaConfig:
+    """Ouro-2.6B (ByteDance/Ouro-2.6B config.json, model_type ``ouro``;
+    "Scaling Latent Reasoning via Looped Language Models",
+    arXiv:2510.25741) at its published sizes: 48 layers of a rotary
+    multi-head attention (16 heads on 16 of width 128, theta 1e6) and a
+    SwiGLU feed-forward of width 5,632, each sub-layer between a norm
+    before AND a norm after it, the whole stack applied ``total_ut_steps``
+    = 4 times on the same weights with the final norm inside the loop, an
+    exit gate over the four depths and the expected-cross-entropy-less-
+    entropy loss (``loop_steps``, ``loop_entropy_coef``); an untied
+    49,152-row head. Override ``layer_pattern`` for what one chip holds.
+    The norms' places, the gate and the loss's coefficient are not in the
+    published file (benchmark/configs/ouro-2.6b-l6t4.json, ``assumed``)."""
+    cfg = LlamaConfig(
+        vocab_size=49152,
+        hidden_size=2048,
+        intermediate_size=5632,
+        num_layers=48,
+        layer_pattern="*D" * 48,
+        num_heads=16,
+        num_kv_heads=16,
+        head_dim=128,
+        max_seq_len=65536,
+        rope_theta=1e6,
+        norm_eps=1e-6,
+        norm_after_mixer="both",
+        embed_init_std=1.0,
+        loop_steps=4,
+        loop_entropy_coef=0.05,
+    )
+    return dataclasses.replace(cfg, **overrides)
+
+
+def ouro_debug(**overrides: Any) -> LlamaConfig:
+    """Tiny Ouro (2 layers applied 4 times) for tests and
+    ``train_hsdp.py --model ouro_debug``."""
+    cfg = ouro_2_6b(
+        vocab_size=256,
+        hidden_size=64,
+        intermediate_size=160,
+        num_layers=2,
+        layer_pattern="*D*D",
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=16,
+        max_seq_len=128,
+        remat=False,
+    )
+    return dataclasses.replace(cfg, **overrides)
+
+
 def llama_moe_debug(**overrides: Any) -> LlamaConfig:
     """Tiny MoE config (4 experts, top-2) for tests and the ep dryrun."""
     cfg = llama_debug(num_experts=4, num_experts_per_tok=2)
@@ -1156,6 +1227,36 @@ class RMSNorm(nn.Module):
             jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.eps
         )
         return (norm * scale).astype(dtype)
+
+
+class ExitGate(nn.Module):
+    """A looped stack's exit gate (``LlamaConfig.loop_steps``): one linear
+    map to a value with a bias, z = w . x + b a position, in float32 at the
+    highest precision (one value a row costs nothing, and the gate learns
+    from small differences). ONE leaf, ``kernel`` [H + 1, 1]: rows 0..H-1
+    are w (lecun-normal), the LAST row is b (0). As a leaf of its own b is
+    one number whose gradient, a sum over every position of terms of both
+    signs, passes through zero from seed to seed, so that no relative error
+    of it is bounded (PERF.md section 6, PR 71); as a row of the kernel it
+    is compared, clipped and saved with the vector it belongs to."""
+
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        width = x.shape[-1]
+
+        def init(key, shape, dtype):
+            w = nn.initializers.lecun_normal()(key, (width, 1), dtype)
+            return jnp.concatenate([w, jnp.zeros((1, 1), dtype)])
+
+        kernel = self.param("kernel", init, (width + 1, 1), self.param_dtype)
+        kernel = kernel.astype(jnp.float32)
+        z = jnp.dot(
+            x.astype(jnp.float32), kernel[:width, 0],
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        return z + kernel[width, 0]
 
 
 class Indexer(nn.Module):
@@ -2248,7 +2349,10 @@ def _stack_layers(cfg: LlamaConfig, pattern: str, x: jax.Array, rotary: tuple,
     module that calls it, each remat'd alone. Only an attention layer is
     handed the rotary tables: the other kinds' calls (and a rope-free
     stack's) stay as they were. Under ``router_ahead`` an attention
-    layer's second output is carried to the expert layer after it."""
+    layer's second output is carried to the expert layer after it. One
+    call makes one module a character and visits each once; a looped model
+    calls it once too, as the body of ``Transformer._looped``'s scan over
+    the steps, which is what visits the modules again."""
     # Unlike layers cannot share one scanned body: each is its own module,
     # remat'd alone (outside a scan XLA would otherwise merge the
     # recomputation with the forward pass and keep every layer's
@@ -2331,11 +2435,28 @@ class _ScanBlock(Block):
         return super().__call__(x, cos, sin), None
 
 
+_LOOP_NOTED: set = set()
+
+
+def _note_loop(steps: int, layers: int, sublayers: int) -> None:
+    """Says once per shape of the loop, at trace time, that a looped stack
+    was traced as one scanned body."""
+    key = (steps, layers, sublayers)
+    if key not in _LOOP_NOTED:
+        _LOOP_NOTED.add(key)
+        logger.info(
+            "loop: steps=%d layers=%d sublayers=%d traced=scan", steps, layers,
+            sublayers,
+        )
+
+
 class Transformer(nn.Module):
     """Decoder-only LM. __call__(tokens [B,S], positions [B,S], or
     [3,B,S] under ``mrope_section``) -> logits.
     Under ``objective="block_diffusion"`` the S tokens are two streams of
-    S/2, [x_t | x_0] (``LlamaConfig.objective``)."""
+    S/2, [x_t | x_0] (``LlamaConfig.objective``). Under ``loop_steps`` > 1
+    the ``layer_pattern`` stack runs that many times on one set of
+    parameters (``_looped``) and the logits are the last step's."""
 
     cfg: LlamaConfig
 
@@ -2355,15 +2476,18 @@ class Transformer(nn.Module):
         the main hidden states and each prediction module's, and reads
         ``next_tokens`` [B,S], each position's successor (None: ``tokens``
         rolled by one, whose last position reads the first token; the loss
-        gives that row no weight)."""
+        gives that row no weight). A looped model (``loop_steps`` > 1)
+        returns the pair ``(h [T,B,S,H], z [T,B,S])``: every step's normed
+        states and the exit gate's float32 logits on them."""
         cfg = self.cfg
         if (
             cfg.mla is not None or cfg.mtp_layers or cfg.sparse_topk is not None
-            or cfg.mrope_section is not None
+            or cfg.mrope_section is not None or cfg.loop_steps != 1
         ) and cfg.layer_pattern is None:
             raise ValueError(
-                "latent attention, prediction modules, a selected attention "
-                "and multimodal rotary positions are a layer_pattern stack's"
+                "latent attention, prediction modules, a selected attention, "
+                "multimodal rotary positions and a loop over the stack are a "
+                "layer_pattern stack's"
             )
         if positions is None:
             if cfg.objective == "block_diffusion":
@@ -2411,6 +2535,8 @@ class Transformer(nn.Module):
                     positions[0] if positions.ndim == 3 else positions,
                     cfg.indexer_head_dim, cfg.rope_theta, cfg.dtype,
                 )))
+            if cfg.loop_steps != 1:
+                return self._looped(embed, x, rotary, return_hidden)
             x = _stack_layers(cfg, cfg.layer_pattern, x, rotary, "layers_{}".format)
             predicted = []
             # The modules serve the training loss alone (and ``init``,
@@ -2447,6 +2573,59 @@ class Transformer(nn.Module):
         x, _ = stack(x, cos, sin)
         return self._head(embed, x, return_hidden)
 
+    def _looped(self, embed: nn.Embed, x: jax.Array, rotary: tuple, return_hidden: bool):
+        """The ``layer_pattern`` stack ``loop_steps`` times on ONE set of
+        parameters: x_t = final_norm(stack(x_{t-1})), and the exit gate's
+        logit z_t = w_g . x_t + b_g a position. One ``nn.scan`` over the
+        steps whose body is the stack and whose parameters are broadcast
+        (closed over, not stacked: ``layers_<i>``, ``final_norm`` and
+        ``exit_gate`` sit in the tree where an unlooped stack's do), so
+        the program holds one copy of the layers however often they run,
+        a shared leaf's gradient is summed over its visits by the scan's
+        own backward pass, and whatever a layer sows gains a leading step
+        axis. Per-sub-layer remat, the barrier after a layer and the flash
+        kernels are the body's as they are an unlooped stack's."""
+        cfg = self.cfg
+        steps = cfg.loop_steps
+        if steps < 1 or cfg.mtp_layers or cfg.objective != "next_token":
+            raise ValueError(
+                "a loop over the stack: loop_steps >= 1 of a next-token model "
+                "without prediction modules"
+            )
+        _note_loop(steps, cfg.num_layers, len(cfg.layer_pattern))
+
+        def tail(mdl, y):
+            h = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")(y)
+            with jax.named_scope("loop/gate"):
+                z = ExitGate(cfg.param_dtype, name="exit_gate")(h)
+            return h, z
+
+        if cfg.remat:
+            # As a layer's: its input alone is kept for the backward pass.
+            # Left to itself the norm and the gate keep four float32
+            # [B, S, H] tensors a step (the cast stream, the normed rows,
+            # the gate's promoted input): 2 GiB of the 16,384-token step's
+            # four visits at hidden 2,048 by the v5e compiler's account.
+            tail = nn.remat(tail, prevent_cse=False)
+
+        def step(mdl, x, rotary):
+            with jax.named_scope("loop/step"):
+                y = _stack_layers(cfg, cfg.layer_pattern, x, rotary, "layers_{}".format)
+                h, z = tail(mdl, y)
+            return h, (h, z)
+
+        _, (h, z) = nn.scan(
+            step,
+            variable_broadcast="params",
+            variable_axes={"intermediates": 0},
+            split_rngs={"params": False},
+            in_axes=nn.broadcast,
+            length=steps,
+        )(self, x, rotary)
+        if return_hidden:
+            return h, z
+        return self._logits(embed, h[-1])
+
     def _embedded(self, embed: nn.Embed, tokens: jax.Array) -> jax.Array:
         """The tokens' rows of the table, times ``embed_scale``."""
         x, scale = embed(tokens), self.cfg.embed_scale
@@ -2465,6 +2644,11 @@ class Transformer(nn.Module):
             if predicted:
                 return (x, *(final_norm(h) for h in predicted))
             return x
+        return self._logits(embed, x)
+
+    def _logits(self, embed: nn.Embed, x: jax.Array) -> jax.Array:
+        """Float32 logits of normed states ``x`` through the head."""
+        cfg = self.cfg
         if cfg.tie_embeddings:
             logits = embed.attend(x.astype(cfg.param_dtype))
         else:

@@ -114,6 +114,9 @@ def _check_cfg(cfg: LlamaConfig, n_stages: int) -> None:
                          "through shard_map; use the ep axis instead")
     if cfg.attn_impl in ("ring", "ulysses"):
         raise ValueError("pipeline: compose with sp later; use dense/flash")
+    if cfg.loop_steps != 1:
+        raise ValueError("pipeline: its copy of the stack visits a layer once; "
+                         "a loop over the stack (loop_steps) is not built here")
 
 
 def make_pipeline_loss(

@@ -306,16 +306,54 @@ def _head_loss_chunks(h, w, targets, weights, C: int):
     return w.astype(h.dtype), chunks
 
 
-def _chunk_loss(hc, tc, mc, wc):
-    """A chunk's weighted loss sum (``mc`` each row's weight), its
-    float32 logits [R,V], their log-sum-exp and where the targets sit in
-    them."""
+def _unchunked(x, B: int, S: int, C: int):
+    """[S/C, B*C, ...] as the chunks' scan stacks it -> [B, S, ...]."""
+    return jnp.moveaxis(x.reshape(S // C, B, C, *x.shape[2:]), 0, 1).reshape(
+        B, S, *x.shape[2:]
+    )
+
+
+def _chunk_rows(hc, tc, wc):
+    """A chunk's rows' cross-entropies [R], its float32 logits [R,V],
+    their log-sum-exp and where the targets sit in them."""
     logits = jnp.dot(hc, wc, preferred_element_type=jnp.float32)
     top = logits.max(axis=-1, keepdims=True)
     lse = jnp.log(jnp.exp(logits - top).sum(axis=-1)) + top[..., 0]
     hit = jnp.arange(logits.shape[-1]) == tc[..., None]
     picked = jnp.where(hit, logits, 0.0).sum(axis=-1)
-    return ((lse - picked) * mc).sum(), logits, lse, hit
+    return lse - picked, logits, lse, hit
+
+
+def _chunk_loss(hc, tc, mc, wc):
+    """A chunk's weighted loss sum (``mc`` each row's weight), its
+    float32 logits [R,V], their log-sum-exp and where the targets sit in
+    them."""
+    rows, logits, lse, hit = _chunk_rows(hc, tc, wc)
+    return (rows * mc).sum(), logits, lse, hit
+
+
+def _chunk_grads(hc, mc, wc, logits, lse, hit):
+    """A chunk's gradients while its logits are live: of its hidden states
+    [R,H] in their dtype and, float32, of the head [H,V] (its part of the
+    sum over the chunks), under the rows' weights ``mc``."""
+    # Into the MXU in the compute dtype, as the cotangent of float32
+    # logits goes at the chip's default precision, and written out
+    # once: fused into its two matmuls it is formed again from the
+    # float32 logits for every tile of each (a v5e, 4 x 4096 tokens of
+    # a 32k vocabulary: dW 38.9 ms a step so, 25.9 + 4.5 behind the
+    # barrier).
+    dlogits = jax.lax.optimization_barrier((
+        (jnp.exp(logits - lse[..., None]) - hit) * mc[..., None]
+    ).astype(hc.dtype))
+    dh = jax.lax.dot_general(
+        dlogits, wc, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ).astype(hc.dtype)  # [R,H]
+    dw = jax.lax.dot_general(
+        hc, dlogits, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )  # [H,V]
+    return dh, dw
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -334,7 +372,14 @@ def _head_loss_sum(h, w, targets, weights, C: int):
     pass does not hold there, so the vocabulary-wide matmul runs three
     times a chunk (logits, dh, dW) and never a fourth for logits
     recomputed, and the backward pass is two multiplies by the scalar
-    cotangent. dW accumulates across the chunks in float32."""
+    cotangent. dW accumulates across the chunks in float32.
+
+    ``weights`` gets no cotangent here: every caller's are constants of
+    the batch. ``_head_loss_rows`` is the sibling whose weights are
+    functions of parameters (a looped model's exit distribution); it
+    shares the chunk's two bodies (``_chunk_rows``, ``_chunk_grads``) and
+    is a function of its own so that this one's program, which every other
+    model's step holds, stays what it was."""
     wc, chunks = _head_loss_chunks(h, w, targets, weights, C)
 
     def chunk(total, xs):
@@ -344,30 +389,15 @@ def _head_loss_sum(h, w, targets, weights, C: int):
 
 
 def _head_loss_sum_fwd(h, w, targets, weights, C: int):
-    B, S, H = h.shape
+    B, S, _ = h.shape
     wc, chunks = _head_loss_chunks(h, w, targets, weights, C)
 
     def chunk(carry, xs):
         total, dw = carry
         hc, tc, mc = xs
         loss, logits, lse, hit = _chunk_loss(hc, tc, mc, wc)
-        # Into the MXU in the compute dtype, as the cotangent of float32
-        # logits goes at the chip's default precision, and written out
-        # once: fused into its two matmuls it is formed again from the
-        # float32 logits for every tile of each (a v5e, 4 x 4096 tokens of
-        # a 32k vocabulary: dW 38.9 ms a step so, 25.9 + 4.5 behind the
-        # barrier).
-        dlogits = jax.lax.optimization_barrier((
-            (jnp.exp(logits - lse[..., None]) - hit) * mc[..., None]
-        ).astype(h.dtype))
-        dh = jax.lax.dot_general(
-            dlogits, wc, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(h.dtype)  # [R,H]
-        dw = dw + jax.lax.dot_general(
-            hc, dlogits, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [H,V]
+        dh, dw_chunk = _chunk_grads(hc, mc, wc, logits, lse, hit)
+        dw = dw + dw_chunk
         return (total + loss, dw), dh
 
     (total, dw), dh = jax.lax.scan(
@@ -375,8 +405,7 @@ def _head_loss_sum_fwd(h, w, targets, weights, C: int):
         (jnp.zeros((), jnp.float32), jnp.zeros(w.shape, jnp.float32)),
         chunks,
     )
-    dh = jnp.moveaxis(dh.reshape(S // C, B, C, H), 0, 1).reshape(B, S, H)
-    return total, (dh, dw)
+    return total, (_unchunked(dh, B, S, C), dw)
 
 
 def _head_loss_sum_bwd(C, res, g):
@@ -385,6 +414,61 @@ def _head_loss_sum_bwd(C, res, g):
 
 
 _head_loss_sum.defvjp(_head_loss_sum_fwd, _head_loss_sum_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _head_loss_rows_vjp(h, w, targets, weights, C: int):
+    wc, chunks = _head_loss_chunks(h, w, targets, weights, C)
+
+    def chunk(total, xs):
+        hc, tc, mc = xs
+        rows = _chunk_rows(hc, tc, wc)[0]
+        return total + (rows * mc).sum(), rows
+
+    total, rows = jax.lax.scan(chunk, jnp.zeros((), jnp.float32), chunks)
+    return total, _unchunked(rows, *h.shape[:2], C)
+
+
+def _head_loss_rows_fwd(h, w, targets, weights, C: int):
+    B, S, _ = h.shape
+    wc, chunks = _head_loss_chunks(h, w, targets, weights, C)
+
+    def chunk(carry, xs):
+        total, dw = carry
+        hc, tc, mc = xs
+        rows, logits, lse, hit = _chunk_rows(hc, tc, wc)
+        dh, dw_chunk = _chunk_grads(hc, mc, wc, logits, lse, hit)
+        return (total + (rows * mc).sum(), dw + dw_chunk), (dh, rows)
+
+    (total, dw), (dh, rows) = jax.lax.scan(
+        chunk,
+        (jnp.zeros((), jnp.float32), jnp.zeros(w.shape, jnp.float32)),
+        chunks,
+    )
+    rows = _unchunked(rows, B, S, C)
+    return (total, rows), (_unchunked(dh, B, S, C), dw, rows)
+
+
+def _head_loss_rows_bwd(C, res, g):
+    dh, dw, rows = res
+    g = g[0]  # the rows' own cotangent is dropped: ``_head_loss_rows``
+    return (dh * g).astype(dh.dtype), dw * g, None, rows * g
+
+
+_head_loss_rows_vjp.defvjp(_head_loss_rows_fwd, _head_loss_rows_bwd)
+
+
+def _head_loss_rows(h, w, targets, weights, C: int):
+    """``_head_loss_sum`` for weights that are functions of parameters:
+    ``(sum, rows)``, the same weighted sum and the rows' own cross-entropies
+    [B,S] float32. Differentiated, the sum hands ``weights`` its cotangent,
+    each row's cross-entropy (``lse - picked``, which a chunk has in hand),
+    beside the hidden states' and the head's formed as there, under the
+    weights' values. ``rows`` is for the caller's metrics and carries no
+    gradient (detached here: the one pass forms gradients under ``weights``
+    alone)."""
+    total, rows = _head_loss_rows_vjp(h, w, targets, weights, C)
+    return total, jax.lax.stop_gradient(rows)
 
 
 # The constant folded with a batch's tokens into its noise key.
@@ -427,6 +511,21 @@ def diffusion_streams(cfg: LlamaConfig, inputs, mask):
     return jnp.concatenate([x_t, inputs], axis=1), weights, masked
 
 
+def exit_distribution(z):
+    """A looped model's distribution over its steps, a position at a time:
+    for the exit gate's logits ``z`` [T, ...] (float32) ``(log p, p)`` with
+    p_t = sigma(z_t) prod_{j<t} (1 - sigma(z_j)) for t < T and p_T =
+    prod_{j<T} (1 - sigma(z_j)), what is left (z_T is not read). Formed
+    from logs, log(1 - sigma(z)) = -softplus(z) and log sigma(z) =
+    -softplus(-z), so that a saturated gate gives a small p and a finite
+    log, never a 0 whose log is not."""
+    stay = -jax.nn.softplus(z[:-1])  # log(1 - lambda_j), j < T
+    before = jnp.concatenate([jnp.zeros_like(z[:1]), jnp.cumsum(stay, axis=0)])
+    leave = jnp.concatenate([-jax.nn.softplus(-z[:-1]), jnp.zeros_like(z[:1])])
+    log_p = before + leave
+    return log_p, jnp.exp(log_p)
+
+
 def _loss_and_metrics(model: Transformer, params, inputs, targets, mask, positions=None):
     """(loss, metrics), by the model's ``objective``. ``positions``: a
     batch's own ``position_ids`` ([3,B,S] under ``mrope_section``), None for
@@ -447,6 +546,20 @@ def _loss_and_metrics(model: Transformer, params, inputs, targets, mask, positio
     the targets rolled by k, the last k rows' weight 0 (their successors
     lie past the batch) and the others' the product of the masks they
     span; ``loss_main`` and ``loss_mtp`` are among the metrics.
+
+    A looped model (``LlamaConfig.loop_steps`` = T > 1; next-token) is
+    handed every step's normed states and exit logits and trains on
+    sum_p mask_p (sum_t p_t[p] CE_t[p] - ``loop_entropy_coef`` H(p[p])) /
+    sum_p mask_p: the expectation under ``exit_distribution`` of the
+    steps' cross-entropies less beta x its entropy, in float32. The T x B
+    rows go through the shared head in ONE pass of ``_head_loss_rows``
+    (targets and mask tiled, weights p x mask), whose cotangent of the
+    weights, the rows' cross-entropies, is how the gate learns from the
+    data. Its metrics: ``loop_ce_1`` .. ``loop_ce_T`` (each step's mean
+    cross-entropy; scalars, because a step's metrics are averaged over
+    microbatches and read as floats), ``loop_exit_step_mean`` (mean of
+    sum_t t p_t), ``loop_exit_entropy`` (mean H, nats) and ``loop_p_last``
+    (mean p_T).
 
     Either way a model with experts adds ``router_aux_coef`` x the
     load-balancing term and ``router_z_coef`` x the router z-loss, each a
@@ -511,6 +624,36 @@ def _loss_and_metrics(model: Transformer, params, inputs, targets, mask, positio
         return _head_loss_sum(
             h.astype(head_dtype), w.astype(jnp.float32), targets, weights, chunk
         )
+
+    if cfg.loop_steps > 1:
+        if two_streams:
+            raise ValueError("a looped stack under block diffusion: not built")
+        (h, z), sown = _apply_with_aux(model, params, inputs, return_hidden=True, **at)
+        T = cfg.loop_steps
+        with jax.named_scope("loop/exit"):
+            log_p, p = exit_distribution(z)
+            entropy = -(p * log_p).sum(axis=0)
+        chunk = loss_chunk(T * B, S, cfg.vocab_size) if _LOSS_CHUNK <= 0 else C
+        w, head_dtype = _lm_head_projection(model, params)
+        expected, rows = _head_loss_rows(
+            h.reshape(T * B, S, -1).astype(head_dtype), w.astype(jnp.float32),
+            jnp.tile(targets, (T, 1)), (p * mask_f).reshape(T * B, S),
+            chunk if S % chunk == 0 else S,
+        )
+        with jax.named_scope("loop/exit"):
+            over_data = lambda x: (x * mask_f).sum(axis=(-2, -1)) / denom  # noqa: E731
+            mean_entropy = over_data(entropy)
+            loss = expected / denom - cfg.loop_entropy_coef * mean_entropy
+            step_ce = over_data(rows.reshape(T, B, S))
+            extra = jax.lax.stop_gradient({
+                **{f"loop_ce_{t + 1}": step_ce[t] for t in range(T)},
+                "loop_exit_step_mean": over_data(
+                    (p * jnp.arange(1, T + 1, dtype=p.dtype)[:, None, None]).sum(axis=0)
+                ),
+                "loop_exit_entropy": mean_entropy,
+                "loop_p_last": over_data(p[-1]),
+            })
+        return with_router_terms(loss, sown)
 
     if cfg.mtp_layers:
         (h, *predicted), sown = _apply_with_aux(
